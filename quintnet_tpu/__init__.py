@@ -26,10 +26,6 @@ multi-device test story that needs no real multi-host hardware.
 
 __version__ = "0.2.0"
 
-from quintnet_tpu.core import compat as _compat  # installs jax shims
-
-_compat.install()
-
 from quintnet_tpu.core.config import Config, load_config
 from quintnet_tpu.core.mesh import MeshSpec, build_mesh
 

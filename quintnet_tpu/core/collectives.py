@@ -153,10 +153,6 @@ def shard_map_fn(
     subset of stages (e.g. loss on the last pp stage — the situation the
     reference handles by re-reading labels on the last stage,
     pipeline_parallel/trainer.py:222-253).
-
-    On older jax releases ``jax.shard_map`` is the translating shim
-    from ``core/compat.py`` (installed at package import), so the
-    current kwarg spelling works everywhere.
     """
     return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
                          out_specs=out_specs, check_vma=check_vma)

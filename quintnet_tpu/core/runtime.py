@@ -27,6 +27,7 @@ training to single-process parity this way.
 
 from __future__ import annotations
 
+import os
 from typing import Optional, Sequence
 
 import numpy as np
@@ -66,29 +67,42 @@ def initialize(
     return jax
 
 
-def enable_compilation_cache(directory: str = "~/.cache/quintnet_tpu_xla",
-                             *, min_compile_time_secs: float = 1.0):
+# The one place the persistent compile cache lives when the environment
+# names none: inside the checkout (.gitignore lists it). The path is
+# part of every cache key's provenance story — a directory that moves
+# (a home, a temp name, a pid, a time) never hits on the next run.
+COMPILATION_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
+
+def enable_compilation_cache() -> str:
     """Persist compiled XLA executables across processes.
 
     First TPU compile of a big training step costs 20-40s+; with the
     cache, relaunching the same program (same jaxpr + compile options +
     topology) loads in well under a second. Call BEFORE the first jit
-    execution. Safe to call on CPU too (useful for the simulated-mesh
-    examples' dev loop).
+    execution — every entry point does (chip_smoke.py, bench.py,
+    examples/common.setup_platform, fleet/proc.replica_main,
+    tools/serve_bench.py, tools/fleet_bench.py).
+
+    The directory is placed from OUTSIDE: where
+    ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and this
+    function sets no directory in code; where it is not, the cache goes
+    to the fixed :data:`COMPILATION_CACHE_DIR` inside the checkout.
+    Either way every jit-compiled program is cached, however small or
+    quick. Returns the directory in use.
 
     The reference has no analogue (torch eager pays no compile, and its
     NCCL init cost is unavoidable per launch).
     """
-    import os
-
     import jax
 
-    path = os.path.expanduser(directory)
-    os.makedirs(path, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", path)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                      float(min_compile_time_secs))
-    # cache everything jit-compiled, not only top-level programs
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = COMPILATION_CACHE_DIR
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     return path
 

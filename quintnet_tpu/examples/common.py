@@ -47,9 +47,10 @@ def setup_platform(simulate: int, args=None):
             + f" --xla_force_host_platform_device_count={simulate}")
     import jax
 
-    if multihost:
-        from quintnet_tpu.core import runtime
+    from quintnet_tpu.core import runtime
 
+    runtime.enable_compilation_cache()
+    if multihost:
         runtime.initialize(
             coordinator_address=args.coordinator,
             num_processes=args.num_processes,

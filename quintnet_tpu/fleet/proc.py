@@ -113,8 +113,23 @@ def replica_main(name: str, host: str, port: int, token: str,
     the dispatcher at ``(host, port)``, identifies itself with
     ``token`` in its hello (so concurrent restarts cannot cross-wire),
     then serves frames until told to stop — or until chaos/a real
-    fault kills it, which is the point of being a process."""
+    fault kills it, which is the point of being a process.
+
+    Devices: this process takes whatever ``jax.devices()`` gives it —
+    nothing here (or in the parent, which spawns every child with its
+    own environment unchanged) narrows that. On a TPU host a chip
+    belongs to ONE process at a time, so today a ``ProcessFleet`` on
+    TPU can hold one replica, and only if the parent stays off JAX: a
+    second child collides on the chip, and on a four-chip host every
+    child asks for all four. Giving each child its own chip is a
+    change of its own (ROADMAP D6); until then the process mode is
+    for the CPU (``platform="cpu"``) and the chip is served by one
+    process through the thread fleet (chip_smoke.py)."""
     import queue as _queue
+
+    from quintnet_tpu.core.runtime import enable_compilation_cache
+
+    enable_compilation_cache()  # before first backend use
 
     import jax
 
@@ -402,7 +417,12 @@ class ProcReplica:
     (``state``/``paused``/``in_flight``/``max_dispatch``/
     ``outstanding_tokens``/``adapter_resident``) so
     :func:`router.eligible` and the :class:`Router` policies apply
-    unchanged."""
+    unchanged.
+
+    The child is spawned with the parent's environment as it is: no
+    per-replica device is chosen here, so on TPU hardware (one process
+    per chip) the fleet can hold one replica and the parent must not
+    touch JAX — see :func:`replica_main`."""
 
     def __init__(self, name: str, fleet: "ProcessFleet",
                  chaos_spec: Optional[Dict], *,

@@ -106,8 +106,8 @@ class GPT2Config:
     # the GPT-2 convention (cross-document attention accepted).
     segment_eos_id: Optional[int] = None
     # --- lax.scan unroll factor for the layer stack (>1 lets XLA
-    # software-pipeline adjacent layers; measured knob, see
-    # artifacts/remat_unroll_r04.json)
+    # software-pipeline adjacent layers; a knob awaiting its one chip
+    # measurement — ROADMAP S5/D4)
     scan_unroll: int = 1
 
     @property

@@ -179,13 +179,10 @@ def flash_attention(q, k, v, *, causal: bool = False,
     use_drop = key is not None and pdrop > 0.0
     if (jax.default_backend() == "tpu" and s % bq == 0 and s % bk == 0
             and s >= min_seq_for_pallas and not use_drop):
-        try:
-            from quintnet_tpu.ops.pallas_attention import pallas_flash_attention
+        from quintnet_tpu.ops.pallas_attention import pallas_flash_attention
 
-            return pallas_flash_attention(q, k, v, causal, bq, bk,
-                                          segment_ids=segment_ids)
-        except ImportError:
-            pass
+        return pallas_flash_attention(q, k, v, causal, bq, bk,
+                                      segment_ids=segment_ids)
     return blockwise_attention(q, k, v, causal=causal,
                                block_q=block_q, block_k=block_k,
                                pdrop=pdrop, key=key,

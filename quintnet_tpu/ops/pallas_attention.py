@@ -38,13 +38,7 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # pallas TPU backend is unavailable on some hosts; dispatcher guards
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAVE_PLTPU = True
-except ImportError:  # pragma: no cover
-    _HAVE_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30  # avoid literal -inf inside the kernel (exp/max safety)
 
@@ -224,7 +218,7 @@ def _flash_fwd(q, k, v, segments, causal: bool, block_q: int, block_k: int,
             pltpu.VMEM((bq, 128), jnp.float32),
             pltpu.VMEM((bq, 128), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
-        ] if _HAVE_PLTPU else None,
+        ],
         interpret=interpret,
     )(*inputs)
     return out.reshape(b, h, s, d), lse.reshape(b, h, s, 1)
@@ -397,7 +391,7 @@ def _flash_bwd(q, k, v, segments, out, lse, do, causal: bool, block_q: int,
         scratch_shapes=[
             pltpu.VMEM((bk, d), jnp.float32),
             pltpu.VMEM((bk, d), jnp.float32),
-        ] if _HAVE_PLTPU else None,
+        ],
         interpret=interpret,
     )(*kv_inputs)
 
@@ -428,7 +422,7 @@ def _flash_bwd(q, k, v, segments, out, lse, do, causal: bool, block_q: int,
         out_shape=jax.ShapeDtypeStruct((b * h, s, d), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((bq, d), jnp.float32),
-        ] if _HAVE_PLTPU else None,
+        ],
         interpret=interpret,
     )(*dq_inputs)
 

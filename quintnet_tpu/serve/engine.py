@@ -259,23 +259,17 @@ class ServeEngine:
             # sp-only mesh: params/pool replicated, no tp collectives
             self.tp_axis = None
         # attention backend (ops/paged_attention.py): "xla" is the
-        # gathered-view reference oracle (default — also the fallback
-        # story where Pallas is unavailable), "pallas" the fused
+        # gathered-view reference oracle (default), "pallas" the fused
         # block-table-walking kernel, bit-parity-pinned against it
         # (tests/test_paged_attention.py). Same program ladder, same
-        # compile bounds, same collective census either way.
+        # compile bounds, same collective census either way. Nothing
+        # falls back from one to the other: a Pallas engine off-TPU
+        # fails at lowering, and a shape the kernel's VMEM cannot hold
+        # is refused below (_check_pallas_vmem).
         if attn_kernel not in ("xla", "pallas"):
             raise ValueError(
                 f"unknown attn_kernel {attn_kernel!r}; expected 'xla' "
                 f"or 'pallas'")
-        if attn_kernel == "pallas":
-            from quintnet_tpu.ops.paged_attention import _HAVE_PLTPU
-
-            if not _HAVE_PLTPU:
-                raise RuntimeError(
-                    "attn_kernel='pallas' needs "
-                    "jax.experimental.pallas.tpu, which this jax "
-                    "install does not provide — use attn_kernel='xla'")
         if attn_kernel == "pallas" and self.sp_axis is not None:
             raise NotImplementedError(
                 "attn_kernel='pallas' does not yet compose with "
@@ -592,6 +586,8 @@ class ServeEngine:
         # (always 0 by step phasing; surfaced so the bench can gate it)
         self._decode_blocked_demotions = 0
         self.table_width = self.pool.blocks_for(self.max_seq_len)
+        if self.attn_kernel == "pallas":
+            self._check_pallas_vmem()
         self.scheduler = Scheduler(self.pool, policy=policy)
         self.metrics = ServeMetrics(clock=clock)
 
@@ -665,6 +661,35 @@ class ServeEngine:
                 k: RecompileSentinel(f"serve.verify[{k}]", verify_fn,
                                      max_compiles=1)
                 for k in self.spec.buckets}
+
+    def _check_pallas_vmem(self) -> None:
+        """Refuse, at construction and with the computed number, any
+        program whose fused paged-attention call cannot fit the chip's
+        VMEM (ops/paged_attention.require_vmem): decode (1 query a
+        row), each verify bucket (k + 1) and each prefill bucket.
+        Heads are the LOCAL ones under tp."""
+        from quintnet_tpu.ops.paged_attention import require_vmem
+
+        tp = (1 if self.mesh is None or self.tp_axis is None
+              else int(self.mesh.shape[self.tp_axis]))
+        cfg = self.family.cfg
+        # query heads: GPT2Config.n_head / LlamaConfig.n_heads
+        hq = cfg.n_head if hasattr(cfg, "n_head") else cfg.n_heads
+        programs = [("decode", 1)]
+        if self.spec is not None:
+            programs += [(f"verify[{k}]", k + 1)
+                         for k in self.spec.buckets]
+        programs += [(f"prefill[{b}]", b) for b in self.prefill_buckets]
+        for name, width in programs:
+            require_vmem(
+                what=f"attn_kernel='pallas' program {name}",
+                n_q_heads=hq // tp,
+                n_kv_heads=self.family.n_kv_heads // tp,
+                n_queries=width, head_dim=self.family.head_dim,
+                block_size=self.pool.block_size,
+                table_width=self.table_width,
+                pool_dtype=self.kv_policy.store_dtype,
+                scaled=self.kv_policy.scaled)
 
     # ------------------------------------------------------------------
     # compiled programs

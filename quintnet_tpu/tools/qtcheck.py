@@ -60,8 +60,8 @@ import os
 import sys
 
 # Load analysis/lint.py and analysis/threads.py by FILE PATH, not
-# through the package: `import quintnet_tpu` pulls in jax (core/compat
-# installs shims at import), and this CLI's contract is to lint source
+# through the package: `import quintnet_tpu` pulls in jax (core/mesh
+# imports it at module level), and this CLI's contract is to lint source
 # with zero jax — it must work (and stay instant) in a lint-only
 # environment. Order matters: threads.py reuses whichever lint module
 # is already in sys.modules, so registering "_qtcheck_lint" first

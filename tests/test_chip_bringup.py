@@ -1,0 +1,242 @@
+"""What the TPU's compiler and the chip's entry script must keep doing,
+checked without a chip (PR 21, the bring-up round).
+
+1. The main-path KERNELS compile for a described ``v5e:2x2`` topology
+   at GPT-2 124M's real widths — the on-chip-measurement guide's third
+   rehearsal, kept as tests: ``pallas_flash_attention`` forward +
+   backward at seq 4096 with and without ``segment_ids``, and
+   ``paged_attention(interpret=False)`` at decode / verify / prefill
+   shapes for 12 heads x Dh 64 x table width 64 (1024 positions),
+   passthrough and int8-scaled. The parent commit's paged kernel was
+   REFUSED at every one of these shapes (16 MiB default scoped-VMEM
+   budget; a (1, Hkv) scale block; a lane-splitting reshape) —
+   interpret mode cannot see any of that. Nothing runs: a compile that
+   passes is not a chip run.
+2. ``core/runtime.enable_compilation_cache`` is placed from outside.
+3. ``chip_smoke.py`` refuses to print its success line off-TPU, and
+   its train / serve phases run to their end at a tiny size on the CPU
+   (the first rehearsal), called as functions — ``main`` is never
+   reached, so no success line can appear for a non-TPU platform.
+"""
+
+import importlib
+import json
+import os
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+# GPT-2 124M serving geometry: 12 heads x Dh 64, 16-token blocks, table
+# width 64 = 1024 positions, 8 decode rows
+H, D, BS, M, NB, ROWS = 12, 64, 16, 64, 512, 8
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """A described (not attached) v5e chip to compile for, with the
+    persistent compile cache off around the compiles — a topology
+    compile is written to the cache but cannot be read back without a
+    chip, so the next one would warn and compile again."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu, no description
+        pytest.skip(f"cannot describe a v5e:2x2 topology here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, *shapes):
+    text = jax.jit(fn).lower(*shapes).compile().as_text()
+    assert "tpu_custom_call" in text
+    return text
+
+
+# ---------------------------------------------------------------------
+# 1-2: flash attention, forward + backward, seq 4096
+# ---------------------------------------------------------------------
+@pytest.mark.parametrize("segments", [False, True],
+                         ids=["causal", "causal+segments"])
+def test_flash_fwd_bwd_compiles_for_v5e(chip, segments):
+    from quintnet_tpu.ops import pallas_flash_attention
+    from quintnet_tpu.ops.flash_attention import (PALLAS_BLOCK_K,
+                                                  PALLAS_BLOCK_Q)
+
+    S = 4096
+    qkv = jax.ShapeDtypeStruct((1, H, S, D), jnp.bfloat16, sharding=chip)
+    seg = jax.ShapeDtypeStruct((1, S), jnp.int32, sharding=chip)
+
+    def loss(q, k, v, s=None):
+        o = pallas_flash_attention(q, k, v, True, PALLAS_BLOCK_Q,
+                                   PALLAS_BLOCK_K, segment_ids=s)
+        return jnp.sum(o.astype(jnp.float32))
+
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)),
+                    *((qkv, qkv, qkv, seg) if segments
+                      else (qkv, qkv, qkv)))
+    assert text.count("tpu_custom_call") >= 3     # fwd, dkv, dq
+
+
+# ---------------------------------------------------------------------
+# 3-8: the paged kernel at GPT-2 124M widths, 1024 positions
+# ---------------------------------------------------------------------
+@pytest.mark.parametrize("scaled", [False, True],
+                         ids=["bf16-passthrough", "int8-scaled"])
+@pytest.mark.parametrize("rows,queries", [(ROWS, 1), (ROWS, 5), (1, 128)],
+                         ids=["decode", "verify", "prefill"])
+def test_paged_attention_compiles_for_v5e(chip, rows, queries, scaled):
+    pa = importlib.import_module("quintnet_tpu.ops.paged_attention")
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    q = sds((rows, H, queries, D), jnp.float32)
+    tables, starts = sds((rows, M), jnp.int32), sds((rows,), jnp.int32)
+    if scaled:
+        pool = sds((NB * BS, H, D), jnp.int8)
+        scale = sds((NB, H), jnp.float32)
+
+        def fn(q, k, v, t, s, ks, vs, fk, fv):
+            return pa.paged_attention(
+                q, k, v, t, s, block_size=BS, kv_scales=(ks, vs),
+                fresh_kv=(fk, fv), interpret=False)
+
+        _compile(fn, q, pool, pool, tables, starts, scale, scale, q, q)
+    else:
+        pool = sds((NB * BS, H, D), jnp.bfloat16)
+
+        def fn(q, k, v, t, s):
+            return pa.paged_attention(q, k, v, t, s, block_size=BS,
+                                      interpret=False)
+
+        _compile(fn, q, pool, pool, tables, starts)
+    # the limit handed to the compiler is the kernel's own estimate,
+    # inside the cap
+    need = pa.paged_attention_vmem_bytes(
+        n_q_heads=H, n_kv_heads=H, n_queries=queries, head_dim=D,
+        block_size=BS, table_width=M, pool_dtype=pool.dtype,
+        scaled=scaled)
+    assert 16 * 2 ** 20 < need <= pa.VMEM_CAP_BYTES
+
+
+# ---------------------------------------------------------------------
+# the compile cache is placed from outside
+# ---------------------------------------------------------------------
+@pytest.fixture
+def cache_config():
+    """Put the three cache options back after a test moved them."""
+    names = ("jax_compilation_cache_dir",
+             "jax_persistent_cache_min_compile_time_secs",
+             "jax_persistent_cache_min_entry_size_bytes")
+    was = {n: getattr(jax.config, n) for n in names}
+    yield
+    for n, v in was.items():
+        jax.config.update(n, v)
+
+
+def test_cache_dir_from_env_is_left_alone(monkeypatch, tmp_path,
+                                          cache_config):
+    """With JAX_COMPILATION_CACHE_DIR set JAX reads the variable
+    itself; the helper sets no directory in code, only the two
+    thresholds."""
+    from quintnet_tpu.core import runtime
+
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert runtime.enable_compilation_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+    assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
+    assert jax.config.jax_persistent_cache_min_entry_size_bytes == 0
+
+
+def test_cache_dir_default_is_fixed_inside_checkout(monkeypatch, tmp_path,
+                                                    cache_config):
+    """Unset: one fixed path inside the checkout — the same from two
+    calls and two working directories, never a home, a temp name, a
+    pid or a time."""
+    from quintnet_tpu.core import runtime
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    first = runtime.enable_compilation_cache()
+    monkeypatch.chdir(tmp_path)
+    second = runtime.enable_compilation_cache()
+    assert first == second == os.path.join(REPO, ".jax_cache")
+    assert jax.config.jax_compilation_cache_dir == first
+    with open(os.path.join(REPO, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()
+
+
+# ---------------------------------------------------------------------
+# chip_smoke.py
+# ---------------------------------------------------------------------
+def test_chip_smoke_refuses_a_cpu():
+    out = subprocess.run(
+        [sys.executable, os.path.join(REPO, "chip_smoke.py")],
+        capture_output=True, text=True, timeout=120, cwd=REPO,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    assert out.returncode != 0
+    assert '"ok": true' not in out.stdout
+    assert "needs a TPU" in out.stderr
+
+
+def test_chip_smoke_cpu_rehearsal(tmp_path, capsys):
+    """The first rehearsal: the train and serve phases (and the Pallas
+    engine's half of the kernel phase, in the interpreter conftest
+    turned on) run to their end at a tiny size on the CPU. ``main`` is
+    never called, so the success line cannot appear."""
+    import chip_smoke as cs
+    from quintnet_tpu.models.gpt2 import GPT2Config
+
+    tiny = cs.Size(
+        cfg=GPT2Config.tiny(n_positions=128, n_layer=2), batch=4, seq=32,
+        train_steps=5, slots=4, block_size=8, num_blocks=64,
+        max_seq_len=96, prompt_lens=(5, 9, 14, 20, 27, 35, 44, 60),
+        max_new=8, dense_check=(1, 6), kernel_check=(0, 2),
+        pallas_prefill_len=16)
+    meter = cs.CompileMeter()
+    with open(tmp_path / "phases.jsonl", "a") as sink:
+        train = cs.run_phase(
+            "train", lambda: cs.phase_train(tiny, str(tmp_path), 0),
+            meter, sink)
+        served = {}
+
+        def serve():
+            rec, served["engine"], served["prompts"] = cs.phase_serve(
+                tiny, 0)
+            return rec
+
+        serve_rec = cs.run_phase("serve", serve, meter, sink)
+        paged = cs.check_pallas_engine(tiny, served["engine"],
+                                       served["prompts"], 0)
+
+    assert len(train["losses"]) == 5
+    assert train["losses"][-1] < train["losses"][0]
+    assert train["checkpoint"]["restored_step"] == 5
+    assert not os.path.exists(tmp_path / "ckpt")   # scratch, removed
+    assert serve_rec["requests"] == 8 and serve_rec["new_tokens"] == 64
+    assert serve_rec["paged_vs_dense_logits"]["max_abs_diff"] < 0.01
+    # interpret mode: bit-parity with the gathered view, and (the
+    # reason the kernel phase is chip-only) no custom call to find
+    assert paged["pallas_vs_xla_logits"]["max_abs_diff"] == 0.0
+    assert paged["tpu_custom_call"] is False
+
+    lines = capsys.readouterr().out.strip().splitlines()
+    assert [json.loads(x)["phase"] for x in lines] == ["train", "serve"]
+    assert all("wall_s" in json.loads(x) and "compile_s" in json.loads(x)
+               for x in lines)
+    assert not any('"device"' in x for x in lines)  # no success line

@@ -424,11 +424,12 @@ class TestStructure:
         with pytest.raises(ValueError, match="attn_kernel"):
             _engine(params, "triton")
 
-    def test_pallas_unavailable_rejected_at_construction(self, params,
-                                                         monkeypatch):
-        """A jax install without pallas TPU support must fail at
-        ServeEngine construction, not deep inside the first serving
-        step."""
+    def test_pallas_vmem_rejected_at_construction(self, params,
+                                                  monkeypatch):
+        """A program whose whole-row scratch cannot fit the chip's
+        VMEM must fail at ServeEngine construction, naming the program
+        and the computed number — not deep inside the first serving
+        step, and never by falling back to the gathered-view path."""
         import importlib
 
         # the ops package re-exports the paged_attention FUNCTION, so
@@ -436,9 +437,16 @@ class TestStructure:
         # importlib for the module object
         pa = importlib.import_module(
             "quintnet_tpu.ops.paged_attention")
-        monkeypatch.setattr(pa, "_HAVE_PLTPU", False)
-        with pytest.raises(RuntimeError, match="pallas"):
+        monkeypatch.setattr(pa, "VMEM_CAP_BYTES", 64 * 1024)
+        with pytest.raises(ValueError,
+                           match=r"program decode .*MiB of VMEM"):
             _engine(params, "pallas")
+        # the kernel itself refuses the same way when called directly
+        q = jnp.zeros((1, 2, 1, 8))
+        pool = jnp.zeros((4 * 8, 2, 8))
+        with pytest.raises(ValueError, match="MiB of VMEM"):
+            pa.paged_attention(q, pool, pool, jnp.zeros((1, 4), jnp.int32),
+                               jnp.zeros((1,), jnp.int32), block_size=8)
 
     def test_pallas_sp_rejected(self, params):
         from jax.sharding import Mesh

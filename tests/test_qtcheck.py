@@ -805,12 +805,10 @@ class TestRecompileSentinel:
 
 class TestDtypeReport:
     def test_flags_f64_upcast(self):
-        from jax.experimental import enable_x64
-
         def f(x):
             return jnp.sum(x.astype(jnp.float64))
 
-        with enable_x64():
+        with jax.enable_x64(True):
             issues = dtype_report(f, jnp.zeros((4,), jnp.float32))
         assert any(i.kind == "f64-upcast" for i in issues), issues
 

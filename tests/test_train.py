@@ -160,12 +160,21 @@ def test_parity_report_flags_stale_legs(tmp_path, monkeypatch):
     assert md.count("PASS") == 1
 
 
-def test_compilation_cache_helper(tmp_path):
+def test_compilation_cache_helper(tmp_path, monkeypatch):
+    """The helper takes no directory: the cache is placed from outside
+    (JAX_COMPILATION_CACHE_DIR, which JAX reads itself at import — here
+    the config is pointed at the same place by hand, as a process
+    started under the variable would find it) and the helper only
+    lowers the two thresholds so even a tiny program is cached."""
+    import os
+
     from quintnet_tpu.core import runtime
 
-    d = runtime.enable_compilation_cache(str(tmp_path / "xla"),
-                                         min_compile_time_secs=0.0)
-    import os
+    d = str(tmp_path / "xla")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", d)
+    jax.config.update("jax_compilation_cache_dir", d)
+    assert runtime.enable_compilation_cache() == d
+    assert jax.config.jax_compilation_cache_dir == d
 
     import jax.numpy as jnp
 

@@ -893,7 +893,12 @@ def main():
                          "(fleet/proc.py) instead of threads; the "
                          "armed kill becomes an abrupt process exit "
                          "and migration runs off the dispatcher's "
-                         "write-ahead journal")
+                         "write-ahead journal. CPU-only for now (also "
+                         "--disagg, --slo): this parent never touches "
+                         "a JAX backend, but every child takes "
+                         "whatever jax.devices() gives it, and a TPU "
+                         "chip belongs to one process — two replicas "
+                         "on TPU hardware collide (ROADMAP D6)")
     ap.add_argument("--disagg", action="store_true",
                     help="TTFT-vs-ITL interference A/B: a "
                          "disaggregated prefill/decode process fleet "
@@ -964,6 +969,10 @@ def main():
     args = ap.parse_args()
     if args.burst is None:
         args.burst = args.requests
+
+    from quintnet_tpu.core.runtime import enable_compilation_cache
+
+    enable_compilation_cache()  # config only: touches no backend
 
     records = []
     if args.slo:

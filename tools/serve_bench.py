@@ -620,7 +620,6 @@ def run(args) -> dict:
         # HBM-traffic win the kernel exists for. CPU wall clocks ride
         # along for the record but are NOT gated: off-TPU the kernel
         # runs in the Pallas interpreter, which prices emulation.
-        import jax as _jax
         import jax.numpy as _jnp
 
         from quintnet_tpu.analysis import gathered_view_gathers
@@ -682,7 +681,7 @@ def run(args) -> dict:
             "xla_tokens_per_sec": s_x["tokens_per_sec"],
             "xla_wall_s": s_x["wall_s"],
             "xla_finished": s_x["finished"],
-            "cpu_interpret_mode": _jax.default_backend() != "tpu",
+            "cpu_interpret_mode": _paged_kernel_module().INTERPRET,
             "speedup_vs_xla": ratio,
         })
         return {
@@ -1237,11 +1236,24 @@ def run(args) -> dict:
     }
 
 
+def _paged_kernel_module():
+    """ops/paged_attention.py as a MODULE (``quintnet_tpu.ops``
+    re-exports the function under the same name, so attribute access
+    finds the function)."""
+    import importlib
+
+    return importlib.import_module("quintnet_tpu.ops.paged_attention")
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--model", default="gpt2", choices=("gpt2", "llama"))
     ap.add_argument("--synthetic", action="store_true",
-                    help="tiny random-init config (CPU-testable)")
+                    help="tiny random-init config (CPU-testable); "
+                         "runs the Pallas paged kernel in INTERPRET "
+                         "mode (recorded as cpu_interpret_mode) — "
+                         "without this flag the kernel compiles for "
+                         "real and a non-TPU process fails at lowering")
     ap.add_argument("--requests", type=int, default=16)
     ap.add_argument("--rate", type=float, default=0.5,
                     help="Poisson arrival rate (requests per engine step)")
@@ -1416,6 +1428,14 @@ def main():
         # the tiny config's default positions cannot hold a document;
         # size it to the trace instead of failing admission
         args.n_positions = args.long_prompt + args.max_new + 16
+
+    from quintnet_tpu.core.runtime import enable_compilation_cache
+
+    enable_compilation_cache()  # before first backend use
+    if args.synthetic:
+        # the tiny CPU configuration: the kernel never chooses
+        # interpret mode for itself, so this mode does, in the open
+        _paged_kernel_module().INTERPRET = True
 
     out = run(args)
     line = json.dumps(out)
