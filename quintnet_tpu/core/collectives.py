@@ -31,16 +31,27 @@ from jax.sharding import Mesh, PartitionSpec as P
 AxisName = Union[str, Sequence[str]]
 
 
+def _scope(wrapper: str, axis: AxisName):
+    """``jax.named_scope("<wrapper>_<axis>")`` (``all_reduce_tp``,
+    ``reduce_scatter_dp``): metadata only, so a device trace names a
+    collective by the wrapper that issued it and the mesh axis it
+    crosses."""
+    names = (axis,) if isinstance(axis, str) else tuple(axis)
+    return jax.named_scope("_".join((wrapper, *names)))
+
+
 def all_reduce(x, axis: AxisName):
     """Sum-all-reduce over a named mesh axis (reference All_Reduce forward:
     communication.py:509-518; backward identity comes from psum's transpose)."""
-    return lax.psum(x, axis)
+    with _scope("all_reduce", axis):
+        return lax.psum(x, axis)
 
 
 def all_reduce_mean(x, axis: AxisName):
     """Mean-all-reduce — the DP gradient average the reference's DDP bucket
     path intends (gradient_reducer.py:64-99 + mean in ddp.py:125)."""
-    return lax.pmean(x, axis)
+    with _scope("all_reduce_mean", axis):
+        return lax.pmean(x, axis)
 
 
 def all_gather(x, axis: AxisName, *, gather_dim: int = -1, tiled: bool = True):
@@ -49,15 +60,17 @@ def all_gather(x, axis: AxisName, *, gather_dim: int = -1, tiled: bool = True):
     ``tiled=True`` concatenates (the reference's all_gather+cat on dim -1,
     communication.py:407-424); ``tiled=False`` stacks a new leading axis.
     """
-    return lax.all_gather(x, axis, axis=gather_dim if not tiled else gather_dim,
-                          tiled=tiled)
+    with _scope("all_gather", axis):
+        return lax.all_gather(x, axis, axis=gather_dim, tiled=tiled)
 
 
 def reduce_scatter(x, axis: AxisName, *, scatter_dim: int = -1):
     """Sum-reduce then scatter chunks along ``scatter_dim``
     (reference ReduceScatter forward: communication.py:565-580)."""
-    return lax.psum_scatter(x, axis, scatter_dimension=_canon(scatter_dim, x.ndim),
-                            tiled=True)
+    with _scope("reduce_scatter", axis):
+        return lax.psum_scatter(
+            x, axis, scatter_dimension=_canon(scatter_dim, x.ndim),
+            tiled=True)
 
 
 def all_to_all(x, axis: AxisName, *, split_dim: int, concat_dim: int):
@@ -67,8 +80,9 @@ def all_to_all(x, axis: AxisName, *, split_dim: int, concat_dim: int):
     all_to_all is never used there; here it powers Ulysses sequence
     parallelism (ops/ulysses_attention.py) and MoE expert dispatch
     (nn/moe.py)."""
-    return lax.all_to_all(x, axis, _canon(split_dim, x.ndim),
-                          _canon(concat_dim, x.ndim), tiled=True)
+    with _scope("all_to_all", axis):
+        return lax.all_to_all(x, axis, _canon(split_dim, x.ndim),
+                              _canon(concat_dim, x.ndim), tiled=True)
 
 
 def _canon(dim: int, ndim: int) -> int:
@@ -100,7 +114,8 @@ def ppermute_shift(x, axis: str, *, shift: int = 1, wrap: bool = True):
         perm = [(i, (i + shift) % n) for i in range(n)]
     else:
         perm = [(i, i + shift) for i in range(n) if 0 <= i + shift < n]
-    return lax.ppermute(x, axis, perm)
+    with _scope("ppermute_shift", axis):
+        return lax.ppermute(x, axis, perm)
 
 
 def send_forward(x, axis: str = "pp"):
@@ -123,18 +138,21 @@ def broadcast_from(x, axis: str, *, src: int = 0):
     # jnp.where (not multiply-by-mask) so NaN/Inf garbage on non-src ranks
     # cannot poison the psum — e.g. pipeline outputs that are only
     # meaningful on the last stage.
-    return lax.psum(jnp.where(idx == src, x, jnp.zeros_like(x)), axis)
+    with _scope("broadcast_from", axis):
+        return lax.psum(jnp.where(idx == src, x, jnp.zeros_like(x)), axis)
 
 
 def tree_all_reduce(tree, axis: AxisName):
     """psum every leaf — the whole DDP bucketing machinery
     (bucket.py/bucket_manager.py/gradient_reducer.py, ~470 LoC) in one line;
     XLA fuses/buckets collectives itself."""
-    return jax.tree.map(lambda g: lax.psum(g, axis), tree)
+    with _scope("all_reduce", axis):
+        return jax.tree.map(lambda g: lax.psum(g, axis), tree)
 
 
 def tree_all_reduce_mean(tree, axis: AxisName):
-    return jax.tree.map(lambda g: lax.pmean(g, axis), tree)
+    with _scope("all_reduce_mean", axis):
+        return jax.tree.map(lambda g: lax.pmean(g, axis), tree)
 
 
 def shard_map_fn(

@@ -257,18 +257,28 @@ def prefetch_batches(batches: Iterator, n: int = 2) -> Iterator:
     iteration; this additionally overlaps slow host data work (CSV
     tokenisation, HF arrow reads) with the whole step, which matters
     once datasets stop being synthetic. Exceptions re-raise at the
-    consuming site."""
+    consuming site. (Imports jax for the producer's profiler span
+    alone.)"""
     import queue
     import threading
+
+    import jax
 
     q: "queue.Queue" = queue.Queue(maxsize=n)
     _END = object()
 
     def feed():
+        # making a batch is a ``qn.data.produce`` span on the profiler's
+        # clock (a no-op without a session); the wait for room in the
+        # queue is not part of it
         try:
-            for b in batches:
+            it = iter(batches)
+            while True:
+                with jax.profiler.TraceAnnotation("qn.data.produce"):
+                    b = next(it, _END)
                 q.put(b)
-            q.put(_END)
+                if b is _END:
+                    return
         except BaseException as e:  # noqa: BLE001 — re-raised by consumer
             q.put(e)
 

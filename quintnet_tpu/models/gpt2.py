@@ -253,22 +253,23 @@ def gpt2_embed(params, input_ids, *, sp_axis: Optional[str] = None,
     defined-but-unused VocabParallelEmbedding, layers.py:224-297)."""
     emb = params["embedding"]
     T = input_ids.shape[-1]
-    if vp_axis is not None:
-        from quintnet_tpu.parallel.tp import vocab_parallel_embedding
+    with jax.named_scope("embed"):
+        if vp_axis is not None:
+            from quintnet_tpu.parallel.tp import vocab_parallel_embedding
 
-        tok = vocab_parallel_embedding({"table": emb["wte"]}, input_ids,
-                                       axis=vp_axis)
-    else:
-        tok = jnp.take(emb["wte"], input_ids, axis=0)
-    start = 0
-    if sp_axis is not None:
-        start = jax.lax.axis_index(sp_axis) * T
-    pos = jax.lax.dynamic_slice_in_dim(emb["wpe"], start, T, axis=0)
-    h = tok + pos[None, :, :]
-    if key is not None and embd_pdrop > 0.0:
-        from quintnet_tpu.nn.layers import dropout
+            tok = vocab_parallel_embedding({"table": emb["wte"]},
+                                           input_ids, axis=vp_axis)
+        else:
+            tok = jnp.take(emb["wte"], input_ids, axis=0)
+        start = 0
+        if sp_axis is not None:
+            start = jax.lax.axis_index(sp_axis) * T
+        pos = jax.lax.dynamic_slice_in_dim(emb["wpe"], start, T, axis=0)
+        h = tok + pos[None, :, :]
+        if key is not None and embd_pdrop > 0.0:
+            from quintnet_tpu.nn.layers import dropout
 
-        h = dropout(key, h, embd_pdrop, deterministic=False)
+            h = dropout(key, h, embd_pdrop, deterministic=False)
     return h
 
 
@@ -282,25 +283,26 @@ def gpt2_blocks(params_blocks, h, cfg: GPT2Config, *,
     ``cfg.n_experts > 0``. ``key`` enables training dropout."""
     tp = 1 if tp_axis is None else jax.lax.axis_size(tp_axis)
     _, attn_p, resid_p = cfg.pdrops
-    return stacked_blocks_apply(
-        params_blocks, h,
-        num_heads=cfg.n_head // tp,
-        causal=True,
-        act=gelu,
-        tp_axis=tp_axis,
-        sp_axis=sp_axis,
-        sp_mode=sp_mode,
-        remat=remat,
-        use_flash=use_flash,
-        moe_args=cfg.moe_args,
-        ep_axis=ep_axis,
-        attn_pdrop=attn_p,
-        resid_pdrop=resid_p,
-        key=key,
-        scan_unroll=cfg.scan_unroll,
-        segment_ids=segment_ids,
-        fsdp=fsdp,
-    )
+    with jax.named_scope("blocks"):
+        return stacked_blocks_apply(
+            params_blocks, h,
+            num_heads=cfg.n_head // tp,
+            causal=True,
+            act=gelu,
+            tp_axis=tp_axis,
+            sp_axis=sp_axis,
+            sp_mode=sp_mode,
+            remat=remat,
+            use_flash=use_flash,
+            moe_args=cfg.moe_args,
+            ep_axis=ep_axis,
+            attn_pdrop=attn_p,
+            resid_pdrop=resid_p,
+            key=key,
+            scan_unroll=cfg.scan_unroll,
+            segment_ids=segment_ids,
+            fsdp=fsdp,
+        )
 
 
 def gpt2_logits(params, h, cfg: GPT2Config):
@@ -315,11 +317,16 @@ def gpt2_logits(params, h, cfg: GPT2Config):
     emit an id >= vocab_size. Vocab-SHARDED tables (local rows under
     vp) are masked inside clm_loss_vp instead, which knows the shard
     offset."""
-    h = layer_norm_apply(params["head"]["ln_f"], h, eps=cfg.layer_norm_epsilon)
-    logits = jnp.dot(h, params["embedding"]["wte"].T).astype(jnp.float32)
-    if (cfg.padded_vocab_size
-            and params["embedding"]["wte"].shape[0] == cfg.table_vocab_size):
-        logits = mask_padded_cols(logits, cfg)
+    with jax.named_scope("final_norm"):
+        h = layer_norm_apply(params["head"]["ln_f"], h,
+                             eps=cfg.layer_norm_epsilon)
+    with jax.named_scope("lm_head"):
+        logits = jnp.dot(h, params["embedding"]["wte"].T
+                         ).astype(jnp.float32)
+        if (cfg.padded_vocab_size
+                and params["embedding"]["wte"].shape[0]
+                == cfg.table_vocab_size):
+            logits = mask_padded_cols(logits, cfg)
     return logits
 
 
@@ -386,15 +393,16 @@ def clm_loss(logits, labels):
     """Shifted causal-LM cross entropy with IGNORE_INDEX masking, mean
     over valid tokens (reference: HF-internal shift + CE ignore_index=-100,
     GPT2_Trainer.py:105-118)."""
-    logits = logits[:, :-1]
-    targets = labels[:, 1:]
-    valid = targets != IGNORE_INDEX
-    safe = jnp.where(valid, targets, 0)
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-    nll = -jnp.take_along_axis(logp, safe[..., None], axis=-1)[..., 0]
-    nll = jnp.where(valid, nll, 0.0)
-    count = jnp.maximum(jnp.sum(valid), 1)
-    return jnp.sum(nll) / count
+    with jax.named_scope("loss"):
+        logits = logits[:, :-1]
+        targets = labels[:, 1:]
+        valid = targets != IGNORE_INDEX
+        safe = jnp.where(valid, targets, 0)
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+        nll = -jnp.take_along_axis(logp, safe[..., None], axis=-1)[..., 0]
+        nll = jnp.where(valid, nll, 0.0)
+        count = jnp.maximum(jnp.sum(valid), 1)
+        return jnp.sum(nll) / count
 
 
 def clm_loss_chunked(params, h, labels, cfg: "GPT2Config", *, chunk: int):
@@ -408,8 +416,9 @@ def clm_loss_chunked(params, h, labels, cfg: "GPT2Config", *, chunk: int):
 
     Single-device / dp/tp-replicated-activation path only (sp shards
     the sequence -> clm_loss_sp; vocab_parallel -> clm_loss_vp)."""
-    h = layer_norm_apply(params["head"]["ln_f"], h,
-                         eps=cfg.layer_norm_epsilon)
+    with jax.named_scope("final_norm"):
+        h = layer_norm_apply(params["head"]["ln_f"], h,
+                             eps=cfg.layer_norm_epsilon)
     wte = params["embedding"]["wte"]
     h_pred = h[:, :-1]
     targets = labels[:, 1:]
@@ -428,9 +437,10 @@ def clm_loss_chunked(params, h, labels, cfg: "GPT2Config", *, chunk: int):
     @jax.checkpoint
     def body(carry, xs):
         hc, tc = xs
-        logits = jnp.dot(hc, wte.T).astype(jnp.float32)
-        if mask_pad_cols:
-            logits = mask_padded_cols(logits, cfg)
+        with jax.named_scope("lm_head"):
+            logits = jnp.dot(hc, wte.T).astype(jnp.float32)
+            if mask_pad_cols:
+                logits = mask_padded_cols(logits, cfg)
         valid = tc != IGNORE_INDEX
         safe = jnp.where(valid, tc, 0)
         logp = jax.nn.log_softmax(logits, axis=-1)
@@ -439,10 +449,11 @@ def clm_loss_chunked(params, h, labels, cfg: "GPT2Config", *, chunk: int):
         return (nll_sum + jnp.sum(jnp.where(valid, nll, 0.0)),
                 count + jnp.sum(valid)), None
 
-    (nll_sum, count), _ = jax.lax.scan(
-        body, (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.int32)),
-        (h_c, t_c))
-    return nll_sum / jnp.maximum(count, 1)
+    with jax.named_scope("loss"):
+        (nll_sum, count), _ = jax.lax.scan(
+            body, (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.int32)),
+            (h_c, t_c))
+        return nll_sum / jnp.maximum(count, 1)
 
 
 def _sp_shift_targets(labels, sp_axis: str):
@@ -737,12 +748,14 @@ def gpt2_model_spec(cfg: GPT2Config, *, remat: "bool | str" = False,
                                    use_flash=use_flash, key=key,
                                    fsdp=fsdp)
         if vp:
-            return clm_loss_vp(
-                logits, labels, tp_axis=tp_axis, sp_axis=sp_axis,
-                vocab_size=(cfg.vocab_size if cfg.padded_vocab_size
-                            else None)) + aux
+            with jax.named_scope("loss"):
+                return clm_loss_vp(
+                    logits, labels, tp_axis=tp_axis, sp_axis=sp_axis,
+                    vocab_size=(cfg.vocab_size if cfg.padded_vocab_size
+                                else None)) + aux
         if sp_axis is not None:
-            return clm_loss_sp(logits, labels, sp_axis=sp_axis) + aux
+            with jax.named_scope("loss"):
+                return clm_loss_sp(logits, labels, sp_axis=sp_axis) + aux
         return clm_loss(logits, labels) + aux
 
     def pipeline_fns(tp_axis=None, sp_axis=None, ep_axis=None):

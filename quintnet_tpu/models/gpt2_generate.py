@@ -102,12 +102,15 @@ def _logits(params, h, cfg: GPT2Config, tp_axis: Optional[str]):
     from quintnet_tpu.models.gpt2 import mask_padded_cols
     from quintnet_tpu.parallel.tp import vocab_parallel_logits
 
-    h = layer_norm_apply(params["head"]["ln_f"], h,
-                         eps=cfg.layer_norm_epsilon)
-    logits = vocab_parallel_logits(
-        params["embedding"]["wte"].T, h, axis=tp_axis).astype(jnp.float32)
-    if cfg.padded_vocab_size:
-        logits = mask_padded_cols(logits, cfg)
+    with jax.named_scope("final_norm"):
+        h = layer_norm_apply(params["head"]["ln_f"], h,
+                             eps=cfg.layer_norm_epsilon)
+    with jax.named_scope("lm_head"):
+        logits = vocab_parallel_logits(
+            params["embedding"]["wte"].T, h,
+            axis=tp_axis).astype(jnp.float32)
+        if cfg.padded_vocab_size:
+            logits = mask_padded_cols(logits, cfg)
     return logits
 
 

@@ -23,6 +23,7 @@ import jax.numpy as jnp
 from jax import lax
 from einops import rearrange
 
+from quintnet_tpu.core import collectives as cc
 from quintnet_tpu.nn.layers import (linear_init, linear_apply, lora_delta,
                                     quantized_matmul)
 
@@ -68,6 +69,53 @@ def repeat_kv(x, n_rep: int):
     b, h, s, d = x.shape
     return jnp.broadcast_to(x[:, :, None], (b, h, n_rep, s, d)
                             ).reshape(b, h * n_rep, s, d)
+
+
+def _qkv_heads(p, x, num_heads: int, lora=None, lora_scale=None):
+    """The fused qkv projection of ``x`` [B, S, D] (plus the per-slot
+    LoRA delta, landing before the head split), split into per-head
+    q, k, v [B, H, S, Dh]. Scope ``qkv`` on a device trace."""
+    with jax.named_scope("qkv"):
+        qkv = linear_apply(p["qkv"], x)  # [B, S, 3*D_local]
+        if lora is not None and "qkv" in lora:
+            qkv = qkv + lora_delta(x, lora["qkv"], lora_scale)
+        q, k, v = jnp.split(qkv, 3, axis=-1)
+        q = rearrange(q, "b s (h d) -> b h s d", h=num_heads)
+        k = rearrange(k, "b s (h d) -> b h s d", h=num_heads)
+        v = rearrange(v, "b s (h d) -> b h s d", h=num_heads)
+    return q, k, v
+
+
+def _proj_out(p, o, tp_axis: Optional[str], lora=None, lora_scale=None):
+    """Heads merged, output projection (plus the per-slot LoRA delta,
+    landing before the psum), the RowParallel all-reduce under
+    ``tp_axis`` (reference: layers.py:216 -> All_Reduce), then the
+    bias: [B, H, S, Dh] -> [B, S, D]. Scope ``proj``."""
+    with jax.named_scope("proj"):
+        o = rearrange(o, "b h s d -> b s (h d)")
+        y = quantized_matmul(o, p["proj"])
+        if lora is not None and "proj" in lora:
+            y = y + lora_delta(o, lora["proj"], lora_scale)
+        if tp_axis is not None:
+            y = cc.all_reduce(y, tp_axis)
+        if "b" in p["proj"]:
+            y = y + p["proj"]["b"]
+    return y
+
+
+def _masked_sdpa(q, k_all, v_all, valid):
+    """The score math every cached path shares: q [B, H, S, Dh] against
+    a whole row's keys and values [B, H, T, Dh], softmax in f32 over
+    the columns ``valid`` (broadcastable to [B, H, S, T]) allows.
+    Scope ``sdpa``."""
+    with jax.named_scope("sdpa"):
+        dh = q.shape[-1]
+        scores = jnp.einsum("bhsd,bhtd->bhst", q,
+                            k_all).astype(jnp.float32)
+        scores = scores / math.sqrt(dh)
+        scores = jnp.where(valid, scores, jnp.finfo(jnp.float32).min)
+        probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+        return jnp.einsum("bhst,bhtd->bhsd", probs, v_all)
 
 
 def sdpa(q, k, v, *, causal: bool, softmax_dtype=jnp.float32,
@@ -152,48 +200,42 @@ def mha_apply(
         k_attn, k_resid = jax.random.split(key)
     drop_kw = dict(pdrop=attn_pdrop, key=k_attn)
 
-    qkv = linear_apply(p["qkv"], x)  # [B, S, 3*D_local]
-    q, k, v = jnp.split(qkv, 3, axis=-1)
-    q = rearrange(q, "b s (h d) -> b h s d", h=num_heads)
-    k = rearrange(k, "b s (h d) -> b h s d", h=num_heads)
-    v = rearrange(v, "b s (h d) -> b h s d", h=num_heads)
+    q, k, v = _qkv_heads(p, x, num_heads)
 
-    if sp_axis is not None and sp_mode == "ulysses":
-        from quintnet_tpu.ops.ulysses_attention import ulysses_attention
+    with jax.named_scope("sdpa"):
+        if sp_axis is not None and sp_mode == "ulysses":
+            from quintnet_tpu.ops.ulysses_attention import \
+                ulysses_attention
 
-        o = ulysses_attention(q, k, v, axis=sp_axis, causal=causal,
-                              use_flash=use_flash,
-                              segment_ids=segment_ids, **drop_kw)
-    elif sp_axis is not None and sp_mode == "zigzag":
-        from quintnet_tpu.ops.ring_attention import zigzag_ring_attention
-
-        o = zigzag_ring_attention(q, k, v, axis=sp_axis, causal=causal,
+            o = ulysses_attention(q, k, v, axis=sp_axis, causal=causal,
+                                  use_flash=use_flash,
                                   segment_ids=segment_ids, **drop_kw)
-    elif sp_axis is not None:
-        if sp_mode != "ring":
-            raise ValueError(
-                f"unknown sp_mode {sp_mode!r}; expected 'ring', 'zigzag' "
-                "or 'ulysses'")
-        from quintnet_tpu.ops.ring_attention import ring_attention
+        elif sp_axis is not None and sp_mode == "zigzag":
+            from quintnet_tpu.ops.ring_attention import \
+                zigzag_ring_attention
 
-        o = ring_attention(q, k, v, axis=sp_axis, causal=causal,
-                           segment_ids=segment_ids, **drop_kw)
-    elif use_flash:
-        from quintnet_tpu.ops.flash_attention import flash_attention
+            o = zigzag_ring_attention(q, k, v, axis=sp_axis,
+                                      causal=causal,
+                                      segment_ids=segment_ids, **drop_kw)
+        elif sp_axis is not None:
+            if sp_mode != "ring":
+                raise ValueError(
+                    f"unknown sp_mode {sp_mode!r}; expected 'ring', "
+                    "'zigzag' or 'ulysses'")
+            from quintnet_tpu.ops.ring_attention import ring_attention
 
-        o = flash_attention(q, k, v, causal=causal,
-                            segment_ids=segment_ids, **drop_kw)
-    else:
-        o = sdpa(q, k, v, causal=causal, pdrop=attn_pdrop, key=k_attn,
-                 segment_ids=segment_ids)
+            o = ring_attention(q, k, v, axis=sp_axis, causal=causal,
+                               segment_ids=segment_ids, **drop_kw)
+        elif use_flash:
+            from quintnet_tpu.ops.flash_attention import flash_attention
 
-    o = rearrange(o, "b h s d -> b s (h d)")
-    y = quantized_matmul(o, p["proj"])
-    if tp_axis is not None:
-        # RowParallel all-reduce (reference: layers.py:216 -> All_Reduce)
-        y = lax.psum(y, tp_axis)
-    if "b" in p["proj"]:
-        y = y + p["proj"]["b"]
+            o = flash_attention(q, k, v, causal=causal,
+                                segment_ids=segment_ids, **drop_kw)
+        else:
+            o = sdpa(q, k, v, causal=causal, pdrop=attn_pdrop,
+                     key=k_attn, segment_ids=segment_ids)
+
+    y = _proj_out(p, o, tp_axis)
     if k_resid is not None and resid_pdrop > 0.0:
         from quintnet_tpu.nn.layers import dropout
 
@@ -216,11 +258,12 @@ def paged_cache_update(k_cache, v_cache, k, v, pos, *, block_tables,
     — garbage nobody reads (their scores are masked and the engine
     drops their outputs). Duplicate index-0 scatters are benign for the
     same reason."""
-    blk = jnp.take_along_axis(block_tables,
-                              (pos // block_size)[:, None], axis=1)[:, 0]
-    idx = blk * block_size + pos % block_size            # [B] flat slots
-    return (k_cache.at[idx].set(k.astype(k_cache.dtype)),
-            v_cache.at[idx].set(v.astype(v_cache.dtype)))
+    with jax.named_scope("kv_write"):
+        blk = jnp.take_along_axis(
+            block_tables, (pos // block_size)[:, None], axis=1)[:, 0]
+        idx = blk * block_size + pos % block_size        # [B] flat slots
+        return (k_cache.at[idx].set(k.astype(k_cache.dtype)),
+                v_cache.at[idx].set(v.astype(v_cache.dtype)))
 
 
 def paged_gather(cache, block_tables, *, block_size: int):
@@ -280,10 +323,11 @@ def _gather_kv(k_cache, v_cache, kv_scales, policy, block_tables, *,
     dispatch (``attn_kernel="pallas"``, ops/paged_attention.py) plugs
     into INSTEAD of — the Pallas path never calls this."""
     ks, vs = kv_scales if kv_scales is not None else (None, None)
-    k_all = paged_gather_dequant(policy, k_cache, ks, block_tables,
-                                 block_size=block_size)
-    v_all = paged_gather_dequant(policy, v_cache, vs, block_tables,
-                                 block_size=block_size)
+    with jax.named_scope("kv_gather"):
+        k_all = paged_gather_dequant(policy, k_cache, ks, block_tables,
+                                     block_size=block_size)
+        v_all = paged_gather_dequant(policy, v_cache, vs, block_tables,
+                                     block_size=block_size)
     return k_all, v_all
 
 
@@ -308,14 +352,15 @@ def _paged_attention_scaled(policy, k_cache, v_cache, ks, vs, q, k, v,
                         positions[:, 0], block_size=block_size,
                         kv_scales=(ks, vs), policy=policy,
                         fresh_kv=(k, v))
-    k_cache, ks = paged_quant_window_update(
-        policy, k_cache, ks, k, positions, lens,
-        block_tables=block_tables, block_size=block_size,
-        max_blocks=max_blocks)
-    v_cache, vs = paged_quant_window_update(
-        policy, v_cache, vs, v, positions, lens,
-        block_tables=block_tables, block_size=block_size,
-        max_blocks=max_blocks)
+    with jax.named_scope("kv_write"):
+        k_cache, ks = paged_quant_window_update(
+            policy, k_cache, ks, k, positions, lens,
+            block_tables=block_tables, block_size=block_size,
+            max_blocks=max_blocks)
+        v_cache, vs = paged_quant_window_update(
+            policy, v_cache, vs, v, positions, lens,
+            block_tables=block_tables, block_size=block_size,
+            max_blocks=max_blocks)
     return o, k_cache, v_cache, ks, vs
 
 
@@ -396,18 +441,19 @@ def paged_quant_update(policy, cache, scales, row_view, vals, positions,
     inert in both the scores and the pool."""
     S, H, T, Dh = row_view.shape
     P = positions.shape[1]
-    padded = jnp.concatenate(
-        [row_view, jnp.zeros((S, H, P, Dh), row_view.dtype)], axis=2)
-    padded = jax.vmap(
-        lambda row, val, st: lax.dynamic_update_slice_in_dim(
-            row, val, st, axis=1)
-    )(padded, vals.astype(jnp.float32), positions[:, 0])
-    row_view = padded[:, :, :T]
-    first = positions[:, 0] // block_size
-    last_pos = positions[:, 0] + lens - 1           # < first*bs if len 0
-    cache, scales = paged_requant_scatter(
-        policy, cache, scales, row_view, block_tables, first, last_pos,
-        block_size=block_size, max_blocks=max_blocks)
+    with jax.named_scope("kv_write"):
+        padded = jnp.concatenate(
+            [row_view, jnp.zeros((S, H, P, Dh), row_view.dtype)], axis=2)
+        padded = jax.vmap(
+            lambda row, val, st: lax.dynamic_update_slice_in_dim(
+                row, val, st, axis=1)
+        )(padded, vals.astype(jnp.float32), positions[:, 0])
+        row_view = padded[:, :, :T]
+        first = positions[:, 0] // block_size
+        last_pos = positions[:, 0] + lens - 1       # < first*bs if len 0
+        cache, scales = paged_requant_scatter(
+            policy, cache, scales, row_view, block_tables, first,
+            last_pos, block_size=block_size, max_blocks=max_blocks)
     return cache, scales, row_view
 
 
@@ -427,14 +473,15 @@ def paged_prefill_update(k_cache, v_cache, k, v, positions, tail_len, *,
     position past the table) scatter into the null block — memory
     nobody reads, the same convention as :func:`paged_cache_update`."""
     P = positions.shape[0]
-    blk_idx = jnp.clip(positions // block_size, 0,
-                       block_tables.shape[0] - 1)
-    idx = jnp.where(jnp.arange(P) < tail_len,
-                    block_tables[blk_idx] * block_size
-                    + positions % block_size, 0)
-    kin = k.transpose(1, 0, 2).astype(k_cache.dtype)   # [P, H, Dh]
-    vin = v.transpose(1, 0, 2).astype(v_cache.dtype)
-    return k_cache.at[idx].set(kin), v_cache.at[idx].set(vin)
+    with jax.named_scope("kv_write"):
+        blk_idx = jnp.clip(positions // block_size, 0,
+                           block_tables.shape[0] - 1)
+        idx = jnp.where(jnp.arange(P) < tail_len,
+                        block_tables[blk_idx] * block_size
+                        + positions % block_size, 0)
+        kin = k.transpose(1, 0, 2).astype(k_cache.dtype)   # [P, H, Dh]
+        vin = v.transpose(1, 0, 2).astype(v_cache.dtype)
+        return k_cache.at[idx].set(kin), v_cache.at[idx].set(vin)
 
 
 def mha_prefill_paged(p, x, k_cache, v_cache, positions, tail_len, *,
@@ -478,13 +525,7 @@ def mha_prefill_paged(p, x, k_cache, v_cache, positions, tail_len, *,
     pool write requantizes only the touched blocks
     (paged_quant_window_update) so the [H, M*bs, Dh] gathered view is
     never materialized."""
-    qkv = linear_apply(p["qkv"], x)  # [1, P, 3*D_local]
-    if lora is not None and "qkv" in lora:
-        qkv = qkv + lora_delta(x, lora["qkv"], lora_scale)
-    q, k, v = jnp.split(qkv, 3, axis=-1)
-    q = rearrange(q, "b s (h d) -> b h s d", h=num_heads)
-    k = rearrange(k, "b s (h d) -> b h s d", h=num_heads)
-    v = rearrange(v, "b s (h d) -> b h s d", h=num_heads)
+    q, k, v = _qkv_heads(p, x, num_heads, lora, lora_scale)
     ks = vs = None
     if attn_kernel == "pallas":
         tables = block_tables[None]
@@ -532,24 +573,9 @@ def mha_prefill_paged(p, x, k_cache, v_cache, positions, tail_len, *,
                 max_blocks=span)
         valid = (jnp.arange(k_all.shape[2])[None, :]
                  <= positions[:, None])               # [P, M*bs]
+        o = _masked_sdpa(q, k_all, v_all, valid[None, None])
 
-        dh = q.shape[-1]
-        scores = jnp.einsum("bhsd,bhtd->bhst", q,
-                            k_all).astype(jnp.float32)
-        scores = scores / math.sqrt(dh)
-        scores = jnp.where(valid[None, None], scores,
-                           jnp.finfo(jnp.float32).min)
-        probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-        o = jnp.einsum("bhst,bhtd->bhsd", probs, v_all)
-
-    o = rearrange(o, "b h s d -> b s (h d)")
-    y = quantized_matmul(o, p["proj"])
-    if lora is not None and "proj" in lora:
-        y = y + lora_delta(o, lora["proj"], lora_scale)
-    if tp_axis is not None:
-        y = lax.psum(y, tp_axis)
-    if "b" in p["proj"]:
-        y = y + p["proj"]["b"]
+    y = _proj_out(p, o, tp_axis, lora, lora_scale)
     if kv_scales is not None:
         return y, k_cache, v_cache, ks, vs
     return y, k_cache, v_cache
@@ -716,23 +742,14 @@ def mha_prefill_paged_sp(p, x, k_cache, v_cache, start, t0, *,
     pool write). The output projection is position-wise, so it stays
     local. LoRA is deliberately absent — the engine rejects the
     (adapters, sp) combination at construction."""
-    qkv = linear_apply(p["qkv"], x)
-    q, k, v = jnp.split(qkv, 3, axis=-1)
-    q = rearrange(q, "b s (h d) -> b h s d", h=num_heads)
-    k = rearrange(k, "b s (h d) -> b h s d", h=num_heads)
-    v = rearrange(v, "b s (h d) -> b h s d", h=num_heads)
-    out = ring_paged_prefill(
-        q, k, v, start, t0, k_cache, v_cache, sp_axis=sp_axis,
-        block_tables=block_tables, block_size=block_size,
-        kv_scales=kv_scales, policy=policy)
+    q, k, v = _qkv_heads(p, x, num_heads)
+    with jax.named_scope("sdpa"):
+        out = ring_paged_prefill(
+            q, k, v, start, t0, k_cache, v_cache, sp_axis=sp_axis,
+            block_tables=block_tables, block_size=block_size,
+            kv_scales=kv_scales, policy=policy)
     o, pools = out[0], out[1:]
-    o = rearrange(o, "b h s d -> b s (h d)")
-    y = quantized_matmul(o, p["proj"])
-    if tp_axis is not None:
-        y = lax.psum(y, tp_axis)
-    if "b" in p["proj"]:
-        y = y + p["proj"]["b"]
-    return (y, *pools)
+    return (_proj_out(p, o, tp_axis), *pools)
 
 
 def paged_verify_update(k_cache, v_cache, k, v, positions, tail_lens, *,
@@ -746,16 +763,17 @@ def paged_verify_update(k_cache, v_cache, k, v, positions, tail_lens, *,
     :func:`paged_prefill_update` batched over rows."""
     S, P = positions.shape
     M = block_tables.shape[1]
-    blk_idx = jnp.clip(positions // block_size, 0, M - 1)        # [S, P]
-    blk = jnp.take_along_axis(block_tables, blk_idx, axis=1)
-    idx = jnp.where(jnp.arange(P)[None, :] < tail_lens[:, None],
-                    blk * block_size + positions % block_size, 0)
-    H, Dh = k.shape[1], k.shape[3]
-    kin = k.transpose(0, 2, 1, 3).reshape(S * P, H, Dh)
-    vin = v.transpose(0, 2, 1, 3).reshape(S * P, H, Dh)
-    flat = idx.reshape(S * P)
-    return (k_cache.at[flat].set(kin.astype(k_cache.dtype)),
-            v_cache.at[flat].set(vin.astype(v_cache.dtype)))
+    with jax.named_scope("kv_write"):
+        blk_idx = jnp.clip(positions // block_size, 0, M - 1)    # [S, P]
+        blk = jnp.take_along_axis(block_tables, blk_idx, axis=1)
+        idx = jnp.where(jnp.arange(P)[None, :] < tail_lens[:, None],
+                        blk * block_size + positions % block_size, 0)
+        H, Dh = k.shape[1], k.shape[3]
+        kin = k.transpose(0, 2, 1, 3).reshape(S * P, H, Dh)
+        vin = v.transpose(0, 2, 1, 3).reshape(S * P, H, Dh)
+        flat = idx.reshape(S * P)
+        return (k_cache.at[flat].set(kin.astype(k_cache.dtype)),
+                v_cache.at[flat].set(vin.astype(v_cache.dtype)))
 
 
 def mha_verify_paged(p, x, k_cache, v_cache, positions, tail_lens, *,
@@ -787,13 +805,7 @@ def mha_verify_paged(p, x, k_cache, v_cache, positions, tail_lens, *,
     :func:`mha_decode`. ``attn_kernel="pallas"``: the fused
     block-table-walking kernel instead of the gathered view (exactly
     :func:`mha_prefill_paged`'s contract, batched over rows)."""
-    qkv = linear_apply(p["qkv"], x)  # [S, P, 3*D_local]
-    if lora is not None and "qkv" in lora:
-        qkv = qkv + lora_delta(x, lora["qkv"], lora_scale)
-    q, k, v = jnp.split(qkv, 3, axis=-1)
-    q = rearrange(q, "b s (h d) -> b h s d", h=num_heads)
-    k = rearrange(k, "b s (h d) -> b h s d", h=num_heads)
-    v = rearrange(v, "b s (h d) -> b h s d", h=num_heads)
+    q, k, v = _qkv_heads(p, x, num_heads, lora, lora_scale)
     ks = vs = None
     if attn_kernel == "pallas":
         if kv_scales is None:
@@ -837,24 +849,9 @@ def mha_verify_paged(p, x, k_cache, v_cache, positions, tail_lens, *,
                 max_blocks=span)
         valid = (jnp.arange(k_all.shape[2])[None, None, :]
                  <= positions[:, :, None])                # [S, P, T]
+        o = _masked_sdpa(q, k_all, v_all, valid[:, None])
 
-        dh = q.shape[-1]
-        scores = jnp.einsum("bhsd,bhtd->bhst", q,
-                            k_all).astype(jnp.float32)
-        scores = scores / math.sqrt(dh)
-        scores = jnp.where(valid[:, None], scores,
-                           jnp.finfo(jnp.float32).min)
-        probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-        o = jnp.einsum("bhst,bhtd->bhsd", probs, v_all)
-
-    o = rearrange(o, "b h s d -> b s (h d)")
-    y = quantized_matmul(o, p["proj"])
-    if lora is not None and "proj" in lora:
-        y = y + lora_delta(o, lora["proj"], lora_scale)
-    if tp_axis is not None:
-        y = lax.psum(y, tp_axis)
-    if "b" in p["proj"]:
-        y = y + p["proj"]["b"]
+    y = _proj_out(p, o, tp_axis, lora, lora_scale)
     if kv_scales is not None:
         return y, k_cache, v_cache, ks, vs
     return y, k_cache, v_cache
@@ -903,13 +900,7 @@ def mha_decode(p, x, k_cache, v_cache, pos, *, num_heads: int,
     block-table-walking kernel (ops/paged_attention.py) instead of the
     gathered-view math — bit-parity-pinned, never materializes the
     [B, H, M*bs, Dh] view."""
-    qkv = linear_apply(p["qkv"], x)  # [B, 1, 3D]
-    if lora is not None and "qkv" in lora:
-        qkv = qkv + lora_delta(x, lora["qkv"], lora_scale)
-    q, k, v = jnp.split(qkv, 3, axis=-1)
-    q = rearrange(q, "b s (h d) -> b h s d", h=num_heads)
-    k = rearrange(k, "b s (h d) -> b h s d", h=num_heads)
-    v = rearrange(v, "b s (h d) -> b h s d", h=num_heads)
+    q, k, v = _qkv_heads(p, x, num_heads, lora, lora_scale)
     ks = vs = None
     if block_tables is None:
         if kv_scales is not None:
@@ -920,8 +911,9 @@ def mha_decode(p, x, k_cache, v_cache, pos, *, num_heads: int,
             raise ValueError(
                 "attn_kernel='pallas' exists only for the paged pool "
                 "(block_tables is required)")
-        k_cache = lax.dynamic_update_slice(k_cache, k, (0, 0, pos, 0))
-        v_cache = lax.dynamic_update_slice(v_cache, v, (0, 0, pos, 0))
+        with jax.named_scope("kv_write"):
+            k_cache = lax.dynamic_update_slice(k_cache, k, (0, 0, pos, 0))
+            v_cache = lax.dynamic_update_slice(v_cache, v, (0, 0, pos, 0))
         k_all, v_all = k_cache, v_cache
         valid = (jnp.arange(k_cache.shape[2]) <= pos)[None, :]  # [1, T]
     elif attn_kernel == "pallas":
@@ -968,23 +960,9 @@ def mha_decode(p, x, k_cache, v_cache, pos, *, num_heads: int,
         valid = jnp.arange(k_all.shape[2])[None, :] <= pos[:, None]
 
     if k_all is not None:
-        dh = q.shape[-1]
-        scores = jnp.einsum("bhsd,bhtd->bhst", q,
-                            k_all).astype(jnp.float32)
-        scores = scores / math.sqrt(dh)
-        scores = jnp.where(valid[:, None, None, :], scores,
-                           jnp.finfo(jnp.float32).min)
-        probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-        o = jnp.einsum("bhst,bhtd->bhsd", probs, v_all)
+        o = _masked_sdpa(q, k_all, v_all, valid[:, None, None, :])
 
-    o = rearrange(o, "b h s d -> b s (h d)")
-    y = quantized_matmul(o, p["proj"])
-    if lora is not None and "proj" in lora:
-        y = y + lora_delta(o, lora["proj"], lora_scale)
-    if tp_axis is not None:
-        y = lax.psum(y, tp_axis)
-    if "b" in p["proj"]:
-        y = y + p["proj"]["b"]
+    y = _proj_out(p, o, tp_axis, lora, lora_scale)
     if kv_scales is not None:
         return y, k_cache, v_cache, ks, vs
     return y, k_cache, v_cache

@@ -22,6 +22,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from quintnet_tpu.core import collectives as cc
+
 
 def cast_floating(tree, dtype, *, exclude=None):
     """Cast floating-point leaves to ``dtype`` (None -> no-op).
@@ -197,7 +199,7 @@ def swiglu_apply(p, x, *, tp_axis: Optional[str] = None, lora=None,
     if lora is not None and "down" in lora:
         y = y + lora_delta(h, lora["down"], lora_scale)
     if tp_axis is not None:
-        y = lax.psum(y, tp_axis)
+        y = cc.all_reduce(y, tp_axis)
     return y
 
 
@@ -273,7 +275,7 @@ def mlp_apply(p, x, *, act=gelu, tp_axis: Optional[str] = None,
     if lora is not None and "proj" in lora:
         y = y + lora_delta(h, lora["proj"], lora_scale)
     if tp_axis is not None:
-        y = lax.psum(y, tp_axis)
+        y = cc.all_reduce(y, tp_axis)
     if "b" in p["proj"]:
         y = y + p["proj"]["b"]
     if key is not None and pdrop > 0.0:

@@ -76,35 +76,38 @@ def block_apply(
     k_attn = k_mlp = None
     if key is not None:
         k_attn, k_mlp = jax.random.split(key)
-    x = x + mha_apply(
-        p["attn"],
-        layer_norm_apply(p["ln1"], x),
-        num_heads=num_heads,
-        causal=causal,
-        tp_axis=tp_axis,
-        sp_axis=sp_axis,
-        sp_mode=sp_mode,
-        use_flash=use_flash,
-        attn_pdrop=attn_pdrop,
-        resid_pdrop=resid_pdrop,
-        key=k_attn,
-        segment_ids=segment_ids,
-    )
-    h = layer_norm_apply(p["ln2"], x)
-    if moe_args is not None:
-        y, aux = moe_apply(p["moe"], h, moe_args, ep_axis=ep_axis,
-                           tp_axis=tp_axis, act=act)
-        if k_mlp is not None and resid_pdrop > 0.0:
-            # Same resid_pdrop as the dense branch so MoE and dense
-            # configs with identical dropout settings regularize alike.
-            # Safe post-psum: the combined output is replicated across
-            # tp ranks, so the mask agrees on every rank.
-            from quintnet_tpu.nn.layers import dropout
+    with jax.named_scope("attn"):
+        x = x + mha_apply(
+            p["attn"],
+            layer_norm_apply(p["ln1"], x),
+            num_heads=num_heads,
+            causal=causal,
+            tp_axis=tp_axis,
+            sp_axis=sp_axis,
+            sp_mode=sp_mode,
+            use_flash=use_flash,
+            attn_pdrop=attn_pdrop,
+            resid_pdrop=resid_pdrop,
+            key=k_attn,
+            segment_ids=segment_ids,
+        )
+    with jax.named_scope("mlp"):
+        h = layer_norm_apply(p["ln2"], x)
+        if moe_args is not None:
+            y, aux = moe_apply(p["moe"], h, moe_args, ep_axis=ep_axis,
+                               tp_axis=tp_axis, act=act)
+            if k_mlp is not None and resid_pdrop > 0.0:
+                # Same resid_pdrop as the dense branch so MoE and dense
+                # configs with identical dropout settings regularize
+                # alike. Safe post-psum: the combined output is
+                # replicated across tp ranks, so the mask agrees on
+                # every rank.
+                from quintnet_tpu.nn.layers import dropout
 
-            y = dropout(k_mlp, y, resid_pdrop, deterministic=False)
-        return x + y, aux
-    return x + mlp_apply(p["mlp"], h, act=act, tp_axis=tp_axis,
-                         pdrop=resid_pdrop, key=k_mlp)
+                y = dropout(k_mlp, y, resid_pdrop, deterministic=False)
+            return x + y, aux
+        return x + mlp_apply(p["mlp"], h, act=act, tp_axis=tp_axis,
+                             pdrop=resid_pdrop, key=k_mlp)
 
 
 def stacked_blocks_apply(
@@ -239,14 +242,15 @@ def _block_mlp(p, x, *, act, moe_args, ep_axis, tp_axis, lora=None,
     aux loss has no serving consumer and stays dropped here. ``lora``:
     this layer's packed per-slot mlp adapters (fc/proj targets; serving
     multi-LoRA) — MoE blocks have no LoRA targets and ignore it."""
-    h = layer_norm_apply(p["ln2"], x)
-    if moe_args is not None:
-        y, _aux, stats = moe_apply(p["moe"], h, moe_args, ep_axis=ep_axis,
-                                   tp_axis=tp_axis, act=act,
-                                   return_stats=True)
-        return x + y, stats
-    return x + mlp_apply(p["mlp"], h, act=act, tp_axis=tp_axis,
-                         lora=lora, lora_scale=lora_scale), None
+    with jax.named_scope("mlp"):
+        h = layer_norm_apply(p["ln2"], x)
+        if moe_args is not None:
+            y, _aux, stats = moe_apply(p["moe"], h, moe_args,
+                                       ep_axis=ep_axis, tp_axis=tp_axis,
+                                       act=act, return_stats=True)
+            return x + y, stats
+        return x + mlp_apply(p["mlp"], h, act=act, tp_axis=tp_axis,
+                             lora=lora, lora_scale=lora_scale), None
 
 
 def block_prefill(p, x, *, num_heads: int, act: Callable = gelu,
@@ -256,10 +260,11 @@ def block_prefill(p, x, *, num_heads: int, act: Callable = gelu,
     [B, H, S, Dh] — the prefill half of KV-cache generation.
     ``tp_axis``: head-sharded — ``num_heads`` is LOCAL heads and the
     returned cache holds only this rank's heads."""
-    a, (k, v) = mha_apply(p["attn"], layer_norm_apply(p["ln1"], x),
-                          num_heads=num_heads, causal=True, return_kv=True,
-                          tp_axis=tp_axis)
-    x = x + a
+    with jax.named_scope("attn"):
+        a, (k, v) = mha_apply(p["attn"], layer_norm_apply(p["ln1"], x),
+                              num_heads=num_heads, causal=True,
+                              return_kv=True, tp_axis=tp_axis)
+        x = x + a
     x, _stats = _block_mlp(p, x, act=act, moe_args=moe_args, ep_axis=None,
                            tp_axis=tp_axis)
     return x, (k, v)
@@ -287,14 +292,16 @@ def block_prefill_paged(p, x, k_cache, v_cache, positions, tail_len, *,
     (x, k_cache, v_cache[, k_scale, v_scale][, moe_stats]) — MoE
     blocks append their routing-stats dict."""
     attn_lora = lora.get("attn") if lora is not None else None
-    out = mha_prefill_paged(
-        p["attn"], layer_norm_apply(p["ln1"], x), k_cache, v_cache,
-        positions, tail_len, num_heads=num_heads, tp_axis=tp_axis,
-        block_tables=block_tables, block_size=block_size,
-        lora=attn_lora, lora_scale=lora_scale,
-        kv_scales=kv_scales, policy=policy, attn_kernel=attn_kernel)
+    with jax.named_scope("attn"):
+        out = mha_prefill_paged(
+            p["attn"], layer_norm_apply(p["ln1"], x), k_cache, v_cache,
+            positions, tail_len, num_heads=num_heads, tp_axis=tp_axis,
+            block_tables=block_tables, block_size=block_size,
+            lora=attn_lora, lora_scale=lora_scale,
+            kv_scales=kv_scales, policy=policy, attn_kernel=attn_kernel)
+        x = x + out[0]
     x, stats = _block_mlp(
-        p, x + out[0], act=act, moe_args=moe_args, ep_axis=ep_axis,
+        p, x, act=act, moe_args=moe_args, ep_axis=ep_axis,
         tp_axis=tp_axis,
         lora=lora.get("mlp") if lora is not None else None,
         lora_scale=lora_scale)
@@ -318,14 +325,16 @@ def block_prefill_paged_sp(p, x, k_cache, v_cache, start, t0, *,
     LN/MLP halves are position-wise and stay local. Returns
     (x, k_cache, v_cache[, k_scale, v_scale]) with the whole chunk's
     K/V scattered into the (sp-replicated) pool."""
-    out = mha_prefill_paged_sp(
-        p["attn"], layer_norm_apply(p["ln1"], x), k_cache, v_cache,
-        start, t0, num_heads=num_heads, sp_axis=sp_axis, tp_axis=tp_axis,
-        block_tables=block_tables, block_size=block_size,
-        kv_scales=kv_scales, policy=policy)
+    with jax.named_scope("attn"):
+        out = mha_prefill_paged_sp(
+            p["attn"], layer_norm_apply(p["ln1"], x), k_cache, v_cache,
+            start, t0, num_heads=num_heads, sp_axis=sp_axis,
+            tp_axis=tp_axis, block_tables=block_tables,
+            block_size=block_size, kv_scales=kv_scales, policy=policy)
+        x = x + out[0]
     # sp prefill never composes with MoE (the engine rejects the pair
     # at construction), so the stats-free return shape is invariant
-    x, _stats = _block_mlp(p, x + out[0], act=act, moe_args=moe_args,
+    x, _stats = _block_mlp(p, x, act=act, moe_args=moe_args,
                            ep_axis=None, tp_axis=tp_axis)
     return (x, *out[1:])
 
@@ -349,14 +358,16 @@ def block_verify_paged(p, x, k_cache, v_cache, positions, tail_lens, *,
     MoE blocks (nn/moe.py). Returns
     (x, k_cache, v_cache[, k_scale, v_scale][, moe_stats])."""
     attn_lora = lora.get("attn") if lora is not None else None
-    out = mha_verify_paged(
-        p["attn"], layer_norm_apply(p["ln1"], x), k_cache, v_cache,
-        positions, tail_lens, num_heads=num_heads, tp_axis=tp_axis,
-        block_tables=block_tables, block_size=block_size,
-        lora=attn_lora, lora_scale=lora_scale,
-        kv_scales=kv_scales, policy=policy, attn_kernel=attn_kernel)
+    with jax.named_scope("attn"):
+        out = mha_verify_paged(
+            p["attn"], layer_norm_apply(p["ln1"], x), k_cache, v_cache,
+            positions, tail_lens, num_heads=num_heads, tp_axis=tp_axis,
+            block_tables=block_tables, block_size=block_size,
+            lora=attn_lora, lora_scale=lora_scale,
+            kv_scales=kv_scales, policy=policy, attn_kernel=attn_kernel)
+        x = x + out[0]
     x, stats = _block_mlp(
-        p, x + out[0], act=act, moe_args=moe_args, ep_axis=ep_axis,
+        p, x, act=act, moe_args=moe_args, ep_axis=ep_axis,
         tp_axis=tp_axis,
         lora=lora.get("mlp") if lora is not None else None,
         lora_scale=lora_scale)
@@ -385,14 +396,16 @@ def block_decode(p, x, k_cache, v_cache, pos, *, num_heads: int,
     parallelism for MoE blocks (nn/moe.py) — returns
     (x, k_cache, v_cache[, k_scale, v_scale][, moe_stats])."""
     attn_lora = lora.get("attn") if lora is not None else None
-    out = mha_decode(
-        p["attn"], layer_norm_apply(p["ln1"], x), k_cache, v_cache, pos,
-        num_heads=num_heads, tp_axis=tp_axis,
-        block_tables=block_tables, block_size=block_size,
-        lora=attn_lora, lora_scale=lora_scale,
-        kv_scales=kv_scales, policy=policy, attn_kernel=attn_kernel)
+    with jax.named_scope("attn"):
+        out = mha_decode(
+            p["attn"], layer_norm_apply(p["ln1"], x), k_cache, v_cache,
+            pos, num_heads=num_heads, tp_axis=tp_axis,
+            block_tables=block_tables, block_size=block_size,
+            lora=attn_lora, lora_scale=lora_scale,
+            kv_scales=kv_scales, policy=policy, attn_kernel=attn_kernel)
+        x = x + out[0]
     x, stats = _block_mlp(
-        p, x + out[0], act=act, moe_args=moe_args, ep_axis=ep_axis,
+        p, x, act=act, moe_args=moe_args, ep_axis=ep_axis,
         tp_axis=tp_axis,
         lora=lora.get("mlp") if lora is not None else None,
         lora_scale=lora_scale)

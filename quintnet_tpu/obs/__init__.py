@@ -43,12 +43,24 @@ recorded:
   ``rebalance_recommended`` events — the contract the elastic-sizing
   autoscaler will actuate.
 
+Two modules are NOT imported here and are reached by their own path:
+
+- :mod:`spans`    — the phases of an engine step: a
+  ``jax.profiler.TraceAnnotation("qn.serve.<phase>")`` on the
+  profiler's clock plus the phase's exclusive time in the step's ring
+  record. The one module of the package that imports jax; only
+  modules that already import jax import it;
+- :mod:`scopes`   — from a device trace's operation back to the
+  ``jax.named_scope`` of the program that issued it (a map made from
+  the compiled text; text in, dict out).
+
 The hard guarantee, engine-wide: **observation is inert**. Tracing on
 is token-BIT-identical to tracing off (greedy and sampled, all
-features composed), adds zero compiled programs (nothing in this
-package imports jax), and never blocks the step loop — every hook
-reads host-side state the engine already computed; no host syncs, no
-device traffic (tests/test_obs.py pins all three).
+features composed), adds zero compiled programs (nothing this package
+imports on ``import quintnet_tpu.obs`` imports jax — every module but
+``spans``), and never blocks the step loop — every hook reads
+host-side state the engine already computed; no host syncs, no device
+traffic (tests/test_obs.py pins all three).
 """
 
 from quintnet_tpu.obs.crashdump import load_crash_dump, write_crash_dump

@@ -27,12 +27,19 @@ never forces a sync — the step's ``t1 - t0`` therefore measures
 dispatch + any blocking the step itself did, which is the honest
 number for a recorder that must never add blocking of its own (the
 bench's timed A/B keeps its own explicit drains).
+
+The ring is ON BY DEFAULT: every ``ServeEngine`` owns one and registers
+it in :func:`live`, the process-wide handle through which a reader that
+holds no engine (a benchmark's per-layer metric, an operator attached
+to a running replica) finds it, together with the engine's ``static``
+facts (weight bytes, KV bytes a token, slots, program names).
 """
 
 from __future__ import annotations
 
 import threading
 import time
+import weakref
 from collections import deque
 from dataclasses import asdict, dataclass, field
 from typing import Dict, List, Optional
@@ -61,6 +68,12 @@ class StepRecord:
     spec_step: bool = False
     draft_tokens: int = 0
     accepted_draft_tokens: int = 0
+    # exclusive seconds by phase of the step (obs/spans.py PHASES);
+    # they sum to t1 - t0
+    phases: Dict[str, float] = field(default_factory=dict)
+    host_syncs: int = 0         # blocking device-to-host reads
+    h2d_bytes: int = 0          # bytes of the host arrays uploaded
+    context_tokens: int = 0     # sum of positions over the decoding slots
     attrs: Dict = field(default_factory=dict)
 
     def to_dict(self) -> Dict:
@@ -90,6 +103,9 @@ class StepRecorder:
         self._ring: "deque[StepRecord]" = deque(maxlen=self.capacity)
         self._total = 0          # records ever appended
         self._drained = 0        # records shipped via drain_new()
+        # what does not change from step to step, filled once by the
+        # engine that owns the ring (ServeEngine.recorder)
+        self.static: Dict = {}
 
     def record(self, rec: StepRecord) -> None:
         with self._lock:
@@ -140,3 +156,20 @@ class StepRecorder:
             window = list(self._ring)[-undrained:]
             self._drained += take
             return [r.to_dict() for r in window[:take]]
+
+
+_LIVE: "weakref.WeakSet[StepRecorder]" = weakref.WeakSet()
+_LIVE_LOCK = threading.Lock()
+
+
+def register(recorder: StepRecorder) -> None:
+    """Make an engine's ring findable through :func:`live`. Held
+    weakly: a ring goes with the engine that owns it."""
+    with _LIVE_LOCK:
+        _LIVE.add(recorder)
+
+
+def live() -> List[StepRecorder]:
+    """The rings of the engines alive in this process."""
+    with _LIVE_LOCK:
+        return list(_LIVE)

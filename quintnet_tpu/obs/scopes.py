@@ -1,0 +1,133 @@
+"""Names on the device trace: from an operation back to the scope of
+the program that issued it.
+
+The programs open ``jax.named_scope`` at each layer boundary (the
+vocabulary is :data:`SCOPES`). A scope is metadata only: it lands in
+``metadata={op_name="jit(serve_decode)/blocks/while/body/attn/qkv/
+dot_general"}`` of the compiled program's instructions and changes
+nothing the program computes. A device trace names an operation by its
+HLO instruction (``fusion.573``), and the reader the benchmark shares
+with ``tools/trace_view.py`` keeps only that name and the times — so
+the way back from ``fusion.573`` to ``blocks/attn/qkv`` is a map made
+from the compiled text, written beside the xplane by the process that
+traced (:func:`write_scope_maps`) and read by ``trace_view --xplane``.
+
+Nothing here imports jax: it is text in, dict out.
+"""
+
+from __future__ import annotations
+
+import json
+import os
+import re
+from typing import Dict, Iterable
+
+# The scope vocabulary. Model programs: embed, blocks (the layer scan),
+# per block attn (qkv, sdpa or paged_attn, kv_gather, kv_write, proj)
+# and mlp, then final_norm, lm_head, loss, sample. The train step:
+# grads, grad_reduce, grad_clip, optimizer. ``rematted_computation`` is
+# jax.checkpoint's own mark on what the backward pass recomputes.
+SCOPES = frozenset({
+    "embed", "blocks", "attn", "qkv", "sdpa", "paged_attn", "kv_gather",
+    "kv_write", "proj", "mlp", "final_norm", "lm_head", "loss", "sample",
+    "grads", "grad_reduce", "grad_clip", "optimizer",
+    "rematted_computation",
+})
+# core/collectives.py's wrappers open ``<wrapper>_<axis>``
+COLLECTIVE_SCOPES = ("all_reduce_mean_", "all_reduce_", "all_gather_",
+                     "reduce_scatter_", "all_to_all_", "ppermute_shift_",
+                     "broadcast_from_")
+
+SCOPES_FILE = "qn_scopes.json"
+
+_WRAPPED = re.compile(r"^(?:\w+\()*([^()]*)\)*$")
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(.*)$")
+_OP_NAME = re.compile(r'\bop_name="([^"]*)"')
+_OPERANDS = re.compile(r"\s[a-z][\w\-]*\((.*?)\)(?:, |$)")
+_OPERAND = re.compile(r"%([\w.\-]+)")
+_MODULE = re.compile(r"^HloModule\s+([\w.\-]+)")
+
+
+def _in_vocabulary(component: str) -> bool:
+    m = _WRAPPED.match(component)
+    core = m.group(1) if m else component
+    return core in SCOPES or core.startswith(COLLECTIVE_SCOPES)
+
+
+def scope_path(op_name: str) -> str:
+    """``jit(local_step)/shard_map/grads/transpose(jvp(blocks))/while/
+    body/closed_call/checkpoint/attn/proj/all_reduce_tp/psum`` ->
+    ``grads/transpose(jvp(blocks))/attn/proj/all_reduce_tp``: the
+    components that are scopes of the vocabulary, as JAX wrote them
+    (``jvp(...)`` is the forward pass of a differentiated scope,
+    ``transpose(jvp(...))`` its backward pass). ``""`` where there is
+    none. XLA joins the names of operations it merged with ``;``: the
+    first that carries a scope speaks for the instruction."""
+    for name in op_name.split(";"):
+        kept = [c for c in name.split("/")[:-1] if _in_vocabulary(c)]
+        if kept:
+            return "/".join(kept)
+    return ""
+
+
+def scope_map(hlo_text: str) -> Dict[str, str]:
+    """``{HLO instruction name: scope path}`` of one compiled program
+    (``jitted.lower(*args).compile().as_text()``), instructions outside
+    every scope left out.
+
+    An instruction the compiler made itself carries no metadata at all
+    (the ``convert`` it split off a gather's result, a ``copy`` for a
+    layout): it is given the scope of the nearest operand that has one,
+    through other such instructions if need be — the cast of a gathered
+    view belongs with the gather. An instruction that HAS a name, but
+    no scope in it, is outside every scope and stays out."""
+    direct: Dict[str, str] = {}
+    bare: Dict[str, list] = {}       # no metadata -> operand names
+    for line in hlo_text.splitlines():
+        m = _INSTRUCTION.match(line)
+        if not m:
+            continue
+        name, rest = m.groups()
+        op_name = _OP_NAME.search(rest)
+        if op_name:
+            path = scope_path(op_name.group(1))
+            if path:
+                direct[name] = path
+        else:
+            args = _OPERANDS.search(" " + rest)
+            bare[name] = _OPERAND.findall(args.group(1)) if args else []
+    out = dict(direct)
+
+    def inherit(name: str, hops: int = 8) -> str:
+        if name in out or name not in bare or hops == 0:
+            return out.get(name, "")
+        for operand in bare[name]:
+            path = inherit(operand, hops - 1)
+            if path:
+                out[name] = path
+                return path
+        return ""
+
+    for name in bare:
+        inherit(name)
+    return out
+
+
+def module_name(hlo_text: str) -> str:
+    """``jit_serve_decode`` from the text's ``HloModule`` line: the name
+    the program runs under on a trace's ``XLA Modules`` line."""
+    for line in hlo_text.splitlines():
+        m = _MODULE.match(line)
+        if m:
+            return m.group(1)
+    raise ValueError("no HloModule line: not a compiled program's text")
+
+
+def write_scope_maps(trace_dir: str, hlo_texts: Iterable[str]) -> str:
+    """Write ``{module name: scope_map}`` of the compiled programs to
+    ``<trace_dir>/qn_scopes.json``, beside the xplane of the run that
+    traced them. Returns the path."""
+    path = os.path.join(trace_dir, SCOPES_FILE)
+    with open(path, "w") as f:
+        json.dump({module_name(t): scope_map(t) for t in hlo_texts}, f)
+    return path

@@ -380,13 +380,15 @@ def paged_attention(q, k_pool, v_pool, block_tables, starts, *,
             pltpu.VMEM((T + spare, Hkv, D), jnp.float32),
         ],
     )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, Hq, P, D), q.dtype),
-        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem),
-        interpret=interpret,
-    )(tables, starts, *inputs)
+    with jax.named_scope("paged_attn"):
+        return pl.pallas_call(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((S, Hq, P, D), q.dtype),
+            compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem),
+            interpret=interpret,
+            name="paged_attention",
+        )(tables, starts, *inputs)
 
 
 def paged_quant_window_update(policy, cache, scales, vals, positions,

@@ -220,6 +220,7 @@ def _flash_fwd(q, k, v, segments, causal: bool, block_q: int, block_k: int,
             pltpu.VMEM((bq, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention_fwd",
     )(*inputs)
     return out.reshape(b, h, s, d), lse.reshape(b, h, s, 1)
 
@@ -393,6 +394,7 @@ def _flash_bwd(q, k, v, segments, out, lse, do, causal: bool, block_q: int,
             pltpu.VMEM((bk, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention_bwd_dkv",
     )(*kv_inputs)
 
     # dQ: q blocks on grid dim 1, k innermost (dim 2)
@@ -424,6 +426,7 @@ def _flash_bwd(q, k, v, segments, out, lse, do, causal: bool, block_q: int,
             pltpu.VMEM((bq, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention_bwd_dq",
     )(*dq_inputs)
 
     rs = lambda x: x.reshape(b, h, s, d)

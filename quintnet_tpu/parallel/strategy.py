@@ -178,17 +178,19 @@ class Strategy:
         specs = self.batch_partition_specs(model)
         if isinstance(specs, P):
             specs = jax.tree.map(lambda _: specs, batch)
-        if self.is_multiprocess:
-            from quintnet_tpu.core.runtime import global_array_from_host_data
+        with jax.profiler.TraceAnnotation("qn.train.shard_batch"):
+            if self.is_multiprocess:
+                from quintnet_tpu.core.runtime import \
+                    global_array_from_host_data
 
+                return jax.tree.map(
+                    lambda x, s: global_array_from_host_data(
+                        NamedSharding(self.mesh, s), x),
+                    batch, specs)
             return jax.tree.map(
-                lambda x, s: global_array_from_host_data(
-                    NamedSharding(self.mesh, s), x),
-                batch, specs)
-        return jax.tree.map(
-            lambda x, s: jax.device_put(x, NamedSharding(self.mesh, s)),
-            batch, specs,
-        )
+                lambda x, s: jax.device_put(x, NamedSharding(self.mesh, s)),
+                batch, specs,
+            )
 
     def shard_batch_local(self, local_batch,
                           model: Optional[ModelSpec] = None,

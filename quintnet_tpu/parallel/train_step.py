@@ -221,64 +221,79 @@ def make_parallel_train_step(
             # in chunk space (parallel/zero.py accumulate_grads_zero2)
             from quintnet_tpu.parallel import zero
 
-            out, g_chunk = zero.accumulate_grads_zero2(
-                loss_fn, params, batch, grad_accum_steps,
-                axis=zero1_axis, data_axes=data_axes, model_axes=maxes,
-                partial_axes=paxes, param_specs=param_specs,
-                has_aux=has_aux, key=key)
+            with jax.named_scope("grads"):
+                out, g_chunk = zero.accumulate_grads_zero2(
+                    loss_fn, params, batch, grad_accum_steps,
+                    axis=zero1_axis, data_axes=data_axes,
+                    model_axes=maxes, partial_axes=paxes,
+                    param_specs=param_specs, has_aux=has_aux, key=key)
             if data_axes:
-                out = jax.tree.map(lambda x: lax.pmean(x, data_axes), out)
+                with jax.named_scope("grad_reduce"):
+                    out = jax.tree.map(
+                        lambda x: lax.pmean(x, data_axes), out)
             _, _, update_from_chunk = zero.make_zero2(
                 optimizer, param_specs, axis=zero1_axis,
                 mesh_axes=mesh_axes, clip_norm=grad_clip_norm)
-            params, opt_state = update_from_chunk(g_chunk, opt_state,
-                                                  params)
+            with jax.named_scope("optimizer"):
+                params, opt_state = update_from_chunk(g_chunk, opt_state,
+                                                      params)
             return params, opt_state, out
-        if grad_fn is not None:
-            out, grads = (grad_fn(params, batch, key) if needs_rng
-                          else grad_fn(params, batch))
-        else:
-            out, grads = accumulate_grads(loss_fn, params, batch,
-                                          grad_accum_steps, has_aux,
-                                          key=key)
-        grads = reduce_grads(
-            grads, param_specs,
-            # ZeRO-2: the zero-axis mean happens inside update_local as
-            # a reduce-scatter straight into the rank's chunk
-            data_axes=(tuple(a for a in data_axes if a != zero1_axis)
-                       if zero2 else data_axes),
-            model_axes=maxes, partial_axes=paxes)
-        if data_axes:
-            out = jax.tree.map(lambda x: lax.pmean(x, data_axes), out)
+        # the scopes below are metadata on the compiled program: a
+        # device trace names each operation by the part of the step it
+        # belongs to (forward and backward need none of their own —
+        # jvp(...)/transpose(...) is already in an operation's name)
+        with jax.named_scope("grads"):
+            if grad_fn is not None:
+                out, grads = (grad_fn(params, batch, key) if needs_rng
+                              else grad_fn(params, batch))
+            else:
+                out, grads = accumulate_grads(loss_fn, params, batch,
+                                              grad_accum_steps, has_aux,
+                                              key=key)
+        with jax.named_scope("grad_reduce"):
+            grads = reduce_grads(
+                grads, param_specs,
+                # ZeRO-2: the zero-axis mean happens inside update_local
+                # as a reduce-scatter straight into the rank's chunk
+                data_axes=(tuple(a for a in data_axes if a != zero1_axis)
+                           if zero2 else data_axes),
+                model_axes=maxes, partial_axes=paxes)
+            if data_axes:
+                out = jax.tree.map(lambda x: lax.pmean(x, data_axes), out)
         if grad_clip_norm is not None and not zero2:
             # pp-sharded leaves are partial across pp too, and MoE expert
             # leaves are sharded over a data axis (ep): include both so
             # the global norm sums every shard exactly once. (ZeRO-2
             # clips inside update_local, in chunk space.)
-            grads, _ = clip_sharded_grads(grads, param_specs, grad_clip_norm,
-                                          model_axes=maxes + paxes + data_axes)
-        if zero2:
-            from quintnet_tpu.parallel import zero
+            with jax.named_scope("grad_clip"):
+                grads, _ = clip_sharded_grads(
+                    grads, param_specs, grad_clip_norm,
+                    model_axes=maxes + paxes + data_axes)
+        with jax.named_scope("optimizer"):
+            if zero2:
+                from quintnet_tpu.parallel import zero
 
-            _, update_local, _ = zero.make_zero2(
-                optimizer, param_specs, axis=zero1_axis,
-                mesh_axes=mesh_axes, clip_norm=grad_clip_norm)
-            params, opt_state = update_local(grads, opt_state, params)
-        elif zero1_axis is not None:
-            from quintnet_tpu.parallel import zero
+                _, update_local, _ = zero.make_zero2(
+                    optimizer, param_specs, axis=zero1_axis,
+                    mesh_axes=mesh_axes, clip_norm=grad_clip_norm)
+                params, opt_state = update_local(grads, opt_state, params)
+            elif zero1_axis is not None:
+                from quintnet_tpu.parallel import zero
 
-            _, update_local = zero.make_zero1(optimizer, axis=zero1_axis)
-            params, opt_state = update_local(grads, opt_state, params)
-        else:
-            updates, opt_state = optimizer.update(grads, opt_state, params)
-            params = optax.apply_updates(params, updates)
+                _, update_local = zero.make_zero1(optimizer,
+                                                  axis=zero1_axis)
+                params, opt_state = update_local(grads, opt_state, params)
+            else:
+                updates, opt_state = optimizer.update(grads, opt_state,
+                                                      params)
+                params = optax.apply_updates(params, updates)
         return params, opt_state, out
 
     # opt state specs need a params template; derive lazily on first call
     # so the builder does not require materialised params.
     compiled = {}
 
-    def step(params, opt_state, batch, seed=None):
+    def jitted(params):
         if "fn" not in compiled:
             if zero1_axis is not None:
                 from quintnet_tpu.parallel import zero
@@ -300,7 +315,21 @@ def make_parallel_train_step(
             compiled["fn"] = jax.jit(
                 smapped, donate_argnums=(0, 1) if donate else ()
             )
-        return compiled["fn"](params, opt_state, batch,
-                              jnp.uint32(seed if seed is not None else 0))
+        return compiled["fn"]
 
+    def step(params, opt_state, batch, seed=None):
+        with jax.profiler.TraceAnnotation("qn.train.dispatch"):
+            return jitted(params)(
+                params, opt_state, batch,
+                jnp.uint32(seed if seed is not None else 0))
+
+    def lower(params, opt_state, batch, seed=None):
+        """The step program lowered for these arguments (or their
+        shapes), not run: ``.compile().as_text()`` is what
+        obs/scopes.scope_map reads."""
+        return jitted(params).lower(
+            params, opt_state, batch,
+            jnp.uint32(seed if seed is not None else 0))
+
+    step.lower = lower
     return step

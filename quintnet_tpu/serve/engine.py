@@ -109,6 +109,9 @@ from quintnet_tpu.analysis.recompile import (RecompileError,
 from quintnet_tpu.analysis.specs import lora_rank_buckets as _rank_buckets
 from quintnet_tpu.analysis.specs import prefill_buckets as _spec_buckets
 from quintnet_tpu.models.gpt2_generate import sample_logits
+from quintnet_tpu.obs.recorder import StepRecord, StepRecorder
+from quintnet_tpu.obs.recorder import register as register_recorder
+from quintnet_tpu.obs.spans import SERVE_STEP, StepPhases
 from quintnet_tpu.serve.adapters import (AdapterRegistry, adapter_paths,
                                          nest, tree_at)
 from quintnet_tpu.serve.families import Family
@@ -346,18 +349,21 @@ class ServeEngine:
         self.logger = logger
         self.log_every = int(log_every)
         self.clock = clock
-        # observability (quintnet_tpu/obs/): an obs.Tracer records
-        # per-request spans, an obs.StepRecorder the per-step flight-
-        # recorder ring. Both default OFF and both are INERT when on:
+        # observability (quintnet_tpu/obs/): an obs.StepRecorder keeps
+        # the per-step flight-recorder ring — ON by default, host-only
+        # and bounded: the engine owns one unless handed one — and an
+        # obs.Tracer the per-request spans (opt-in). Both are INERT:
         # every hook reads host-side state the step already computed —
         # no device traffic, no host syncs, no key/sampling influence —
         # so tracing on is token-BIT-identical to tracing off and the
         # compiled-program census is unchanged (tests/test_obs.py).
         # Plain assignable attributes, not construction-only config:
-        # the process fleet attaches them AFTER the builder spec ran
-        # (fleet/proc.py replica_main).
+        # the fleets attach their own AFTER the builder spec ran
+        # (fleet/proc.py replica_main); ``recorder`` is attached at the
+        # end of construction, once the facts it carries exist.
         self.tracer = tracer
-        self.recorder = recorder
+        self._phases = StepPhases(clock)
+        self._recorder: Optional[StepRecorder] = None
         self.prefix_cache = bool(prefix_cache)
         # speculative decoding (serve/spec.py): None/False -> off,
         # True -> defaults, or a SpecConfig. Drafting is host-side;
@@ -448,13 +454,14 @@ class ServeEngine:
             # One static signature (slot is a traced scalar); warmup()
             # compiles it beside the serving programs so binds inside
             # a zero-recompile trace stay compile-free.
-            def _pack_update(dev, slot, new):
+            def serve_pack_update(dev, slot, new):
                 return jax.tree.map(
                     lambda d, n: jax.lax.dynamic_update_slice_in_dim(
                         d, n[:, None].astype(d.dtype), slot, axis=1),
                     dev, new)
 
-            self._pack_update = jax.jit(_pack_update, donate_argnums=(0,))
+            self._pack_update = jax.jit(serve_pack_update,
+                                        donate_argnums=(0,))
 
         self.max_seq_len = int(max_seq_len or family.max_positions)
         if self.max_seq_len > family.max_positions:
@@ -614,8 +621,11 @@ class ServeEngine:
         # bucket (and the decode step) carries its own sentinel with
         # max_compiles=1, so a drifting abstract signature raises
         # RecompileError naming the leaf instead of silently
-        # recompiling (analysis/recompile.py). All buckets share ONE
-        # jitted callable — the bucket width is just the ids shape.
+        # recompiling (analysis/recompile.py). Every program is its own
+        # jitted callable under its own STABLE name (PROGRAM_NAME):
+        # ``jit_serve_prefill_b128`` on a device trace, where one shared
+        # callable read ``jit_body`` for all of them. A name carries no
+        # id or counter, so the persistent compile cache keeps hitting.
         # donation sets = the aliasable args (jaxpr_audit.donation_report):
         # pools update in place; prefill's t0 aliases the sampled token,
         # key_data its evolved key; decode's tok row aliases the next-
@@ -626,41 +636,78 @@ class ServeEngine:
         # (k, v, k_scale, v_scale), passthrough ones 2.
         n_pool = len(self.pool.caches())
         pool_idx = tuple(range(1, n_pool + 1))
-        prefill_fn = self._build_prefill(
-            donate=pool_idx + (n_pool + 3, n_pool + 7))
         self._prefills: Dict[int, RecompileSentinel] = {
-            b: RecompileSentinel(f"serve.prefill[{b}]", prefill_fn,
-                                 max_compiles=1)
+            b: RecompileSentinel(
+                f"serve.prefill[{b}]",
+                self._build_prefill(
+                    f"serve_prefill_b{b}",
+                    donate=pool_idx + (n_pool + 3, n_pool + 7)),
+                max_compiles=1)
             for b in self.prefill_buckets}
         # decode: ONE program for adapter-blind engines; with adapters,
         # one program per LoRA rank bucket (the packed factors' rank
-        # dim is the only signature difference — all buckets share one
-        # jitted callable), chosen per step by the largest bound
-        # adapter. Keyed by bucket; None = the adapter-blind program.
-        decode_fn = self._build_decode(
-            donate=pool_idx + (n_pool + 1, n_pool + 4))
+        # dim is the only signature difference), chosen per step by the
+        # largest bound adapter. Keyed by bucket; None = the
+        # adapter-blind program.
+        decode_donate = pool_idx + (n_pool + 1, n_pool + 4)
         if self.adapters is None:
-            self._decode = RecompileSentinel("serve.decode", decode_fn,
-                                             max_compiles=1)
+            self._decode = RecompileSentinel(
+                "serve.decode",
+                self._build_decode("serve_decode", donate=decode_donate),
+                max_compiles=1)
             self._decodes: Dict[Optional[int], RecompileSentinel] = {
                 None: self._decode}
         else:
             self._decodes = {
-                r: RecompileSentinel(f"serve.decode[r{r}]", decode_fn,
-                                     max_compiles=1)
+                r: RecompileSentinel(
+                    f"serve.decode[r{r}]",
+                    self._build_decode(f"serve_decode_r{r}",
+                                       donate=decode_donate),
+                    max_compiles=1)
                 for r in self.lora_rank_buckets}
-        # verify programs (speculative decoding): one sentinel per
-        # draft-length bucket sharing ONE jitted callable — the bucket
-        # only changes the run width P = k + 1. ids donates into the
+        # verify programs (speculative decoding): one per draft-length
+        # bucket — the bucket only changes the run width P = k + 1. ids
+        # donates into the
         # candidate-token output (same [S, P] int32 row); key_data does
         # NOT alias anything (the chain output is [S, P, keysize]).
         self._verifies: Dict[int, RecompileSentinel] = {}
         if self.spec is not None:
-            verify_fn = self._build_verify(donate=pool_idx + (n_pool + 1,))
             self._verifies = {
-                k: RecompileSentinel(f"serve.verify[{k}]", verify_fn,
-                                     max_compiles=1)
+                k: RecompileSentinel(
+                    f"serve.verify[{k}]",
+                    self._build_verify(f"serve_verify_b{k}",
+                                       donate=pool_idx + (n_pool + 1,)),
+                    max_compiles=1)
                 for k in self.spec.buckets}
+
+        # the flight-recorder ring, last: its ``static`` facts exist now
+        self.recorder = (recorder if recorder is not None
+                         else StepRecorder(capacity=4096, clock=clock))
+
+    @property
+    def recorder(self) -> Optional[StepRecorder]:
+        return self._recorder
+
+    @recorder.setter
+    def recorder(self, rec: Optional[StepRecorder]) -> None:
+        """Attach a ring: fill its ``static`` facts and make it
+        findable (obs.recorder.live) — the only handle a reader with
+        no engine in hand has. The fleets attach their own rings after
+        construction, so this is a setter, not an argument alone."""
+        self._recorder = rec
+        if rec is None:
+            return
+        rec.static.update(
+            # every byte of the parameter tree: what a decode step
+            # reads (``weight_bytes`` counts the packed targets alone)
+            param_bytes=sum(int(x.nbytes)
+                            for x in jax.tree.leaves(self.params)),
+            kv_bytes_per_token=self.pool.bytes_per_token,
+            max_slots=self.max_slots,
+            programs=sorted(s.fn.__name__ for s in (
+                *self._prefills.values(), *self._decodes.values(),
+                *self._verifies.values())))
+        register_recorder(rec)
 
     def _check_pallas_vmem(self) -> None:
         """Refuse, at construction and with the computed number, any
@@ -706,7 +753,7 @@ class ServeEngine:
                 top_k=self.top_k, top_p=self.top_p)[0]
         )(logits, subkeys).astype(jnp.int32)
 
-    def _build_prefill(self, *, donate):
+    def _build_prefill(self, name: str, *, donate):
         family, bs = self.family, self.pool.block_size
         tp_axis = self.tp_axis
         sp_axis = self.sp_axis
@@ -767,17 +814,19 @@ class ServeEngine:
                     kv_scales=kv_scales, policy=policy)
             logits, pools = out[0], out[1:]
 
-            key = jax.random.wrap_key_data(key_data)
-            key2, sub = jax.random.split(key)
-            tok = sample_logits(logits, sub, temperature=self.temperature,
-                                top_k=self.top_k, top_p=self.top_p)[0]
-            return (*pools, tok.astype(jnp.int32),
-                    jax.random.key_data(key2))
+            with jax.named_scope("sample"):
+                key = jax.random.wrap_key_data(key_data)
+                key2, sub = jax.random.split(key)
+                tok = sample_logits(logits, sub,
+                                    temperature=self.temperature,
+                                    top_k=self.top_k, top_p=self.top_p)[0]
+                return (*pools, tok.astype(jnp.int32),
+                        jax.random.key_data(key2))
 
-        return self._wrap(body, n_rest=7, donate=donate,
+        return self._wrap(body, name, n_rest=7, donate=donate,
                           ids_sharded=True)
 
-    def _build_decode(self, *, donate):
+    def _build_decode(self, name: str, *, donate):
         family, bs = self.family, self.pool.block_size
         tp_axis = self.tp_axis
         ep_axis = self.ep_axis
@@ -798,14 +847,15 @@ class ServeEngine:
                 kv_scales=(k_scale, v_scale) if scaled else None,
                 policy=policy, attn_kernel=attn_kernel)
             logits, pools = out[0], out[1:]
-            keys = jax.random.wrap_key_data(key_data)
-            pairs = jax.vmap(lambda k: jax.random.split(k, 2))(keys)
-            nxt = self._sample_rows(logits, pairs[:, 1])
-            return (*pools, nxt, jax.random.key_data(pairs[:, 0]))
+            with jax.named_scope("sample"):
+                keys = jax.random.wrap_key_data(key_data)
+                pairs = jax.vmap(lambda k: jax.random.split(k, 2))(keys)
+                nxt = self._sample_rows(logits, pairs[:, 1])
+                return (*pools, nxt, jax.random.key_data(pairs[:, 0]))
 
-        return self._wrap(body, n_rest=4, donate=donate)
+        return self._wrap(body, name, n_rest=4, donate=donate)
 
-    def _build_verify(self, *, donate):
+    def _build_verify(self, name: str, *, donate):
         """The speculative verify step (serve/spec.py): ONE forward
         scores every slot's short token run — its last sampled token +
         up to k drafted continuations — through the paged decode math
@@ -860,11 +910,13 @@ class ServeEngine:
                         top_p=self.top_p)[0]))(logits, subs)
             return (*pools, toks.astype(jnp.int32), chain)
 
-        return self._wrap(body, n_rest=5, donate=donate)
+        return self._wrap(body, name, n_rest=5, donate=donate)
 
-    def _wrap(self, body, *, n_rest: int, donate,
+    def _wrap(self, body, name: str, *, n_rest: int, donate,
               ids_sharded: bool = False):
-        """jit, donating the aliasable arguments: the pool buffers
+        """jit under ``name`` (the callable's ``__name__`` is what XLA
+        names the module after: ``jit_<name>`` on a device trace, and
+        shard_map keeps it), donating the aliasable arguments: the pool buffers
         (decode-state updates are in-place on device) plus the per-step
         host-shipped rows that alias an output (tok/t0/key_data are
         rebuilt from host state each call, so their device buffers are
@@ -889,6 +941,7 @@ class ServeEngine:
         [L, nb, H] scale arrays, head-sharded over tp exactly like the
         pools — so the pool-spec prefix widens from 2 to 4; everything
         downstream of it is unchanged."""
+        body.__name__ = body.__qualname__ = name
         if self.mesh is None:
             return jax.jit(body, donate_argnums=donate)
         from jax.sharding import PartitionSpec as P
@@ -1579,7 +1632,8 @@ class ServeEngine:
             return pools
         *pools, st = pools
         if note:
-            self._moe_acc.append(jax.tree.map(np.asarray, st))
+            with self._phases.wait(1):
+                self._moe_acc.append(jax.tree.map(np.asarray, st))
         return tuple(pools)
 
     def _drain_moe(self) -> Dict[str, object]:
@@ -1606,6 +1660,7 @@ class ServeEngine:
         chain, prefill only the uncached tail in the smallest bucket
         that holds it. Returns (tail tokens prefilled, cached tokens
         reused)."""
+        ph = self._phases
         t0 = req.total_len
         tokens = req.output_ids()
         ev0 = self.pool.cache_evictions
@@ -1613,51 +1668,57 @@ class ServeEngine:
         self._trace_admit(req, plan,
                           evictions=self.pool.cache_evictions - ev0,
                           chunked=False)
-        row = self._tables[slot]
+        with ph.phase("prefill"):
+            row = self._tables[slot]
 
-        start = plan.cached_tokens
-        tail = tokens[start:t0]
-        bucket = self._bucket_for(len(tail))
-        ids = np.zeros((1, bucket), np.int32)
-        ids[0, :len(tail)] = tail
-        extra = ()
-        if self.adapters is not None:
-            # bind BEFORE the prefill: the tail runs under the
-            # request's adapter (a base request leaves the slot's rows
-            # zero — exactly the base program)
-            if req.adapter_id is not None:
-                self._bind_slot_adapter(slot, req.adapter_id)
-            extra = self._lora_args("prefill", slot=slot)
-        *pools, tok0, key2 = self._prefills[bucket](
-            self.params, *self.pool.caches(), jnp.asarray(ids),
-            jnp.int32(start), jnp.int32(t0), jnp.asarray(row),
-            jnp.int32(plan.cow_src if plan.cow_src is not None else 0),
-            jnp.int32(plan.cow_len), jnp.asarray(req.key_data), *extra)
-        self.pool.update(*self._pop_moe(pools))
-        if plan.cow_src is not None:
-            # the COW source was pinned only for the copy above
-            self.pool.release([plan.cow_src])
-        self._key_data[slot] = np.asarray(key2)
-        tok0 = int(tok0)
-        self._tok[slot] = tok0
-        self._pos[slot] = t0
-        self.metrics.record_admit()
-        if self.tracer is not None:
-            self.tracer.event(req.trace_id, "prefill",
-                              tokens=len(tail), bucket=bucket,
-                              start=int(start))
-        done = self._append_token(slot, tok0)
-        if not done and req.prefill_only:
-            # disaggregated prefill phase: the first token is committed
-            # and emitted with its REAL last flag above (max_new was
-            # never capped, so EOS and one-token budgets retired via
-            # ``done``); what remains is decode-pool work. Retire with
-            # blocks PUBLISHED — the published chain is exactly the
-            # handoff payload export_kv_chain ships.
-            req.handed_off = True
-            done = True
-        if done:
-            self._retire(slot)
+            start = plan.cached_tokens
+            tail = tokens[start:t0]
+            bucket = self._bucket_for(len(tail))
+            ids = np.zeros((1, bucket), np.int32)
+            ids[0, :len(tail)] = tail
+            extra = ()
+            if self.adapters is not None:
+                # bind BEFORE the prefill: the tail runs under the
+                # request's adapter (a base request leaves the slot's
+                # rows zero — exactly the base program)
+                if req.adapter_id is not None:
+                    self._bind_slot_adapter(slot, req.adapter_id)
+                extra = self._lora_args("prefill", slot=slot)
+            args = ph.upload(
+                ids, np.int32(start), np.int32(t0), row,
+                np.int32(plan.cow_src if plan.cow_src is not None else 0),
+                np.int32(plan.cow_len), req.key_data)
+            with ph.phase("dispatch"):
+                *pools, tok0, key2 = self._prefills[bucket](
+                    self.params, *self.pool.caches(), *args, *extra)
+            self.pool.update(*self._pop_moe(pools))
+            if plan.cow_src is not None:
+                # the COW source was pinned only for the copy above
+                self.pool.release([plan.cow_src])
+            with ph.wait(2):
+                self._key_data[slot] = np.asarray(key2)
+                tok0 = int(tok0)
+            self._tok[slot] = tok0
+            self._pos[slot] = t0
+        with ph.phase("commit"):
+            self.metrics.record_admit()
+            if self.tracer is not None:
+                self.tracer.event(req.trace_id, "prefill",
+                                  tokens=len(tail), bucket=bucket,
+                                  start=int(start))
+            done = self._append_token(slot, tok0)
+            if not done and req.prefill_only:
+                # disaggregated prefill phase: the first token is
+                # committed and emitted with its REAL last flag above
+                # (max_new was never capped, so EOS and one-token
+                # budgets retired via ``done``); what remains is
+                # decode-pool work. Retire with blocks PUBLISHED — the
+                # published chain is exactly the handoff payload
+                # export_kv_chain ships.
+                req.handed_off = True
+                done = True
+            if done:
+                self._retire(slot)
         return len(tail), start
 
     # ------------------------------------------------------------------
@@ -1702,50 +1763,54 @@ class ServeEngine:
         chunks discard the program's sampled token and split key (the
         chain must advance exactly once per prefill); the final chunk
         adopts both, exactly like a single-shot admission."""
-        tokens = req.output_ids()
-        chunk = tokens[st.next:st.next + n]
-        bucket = self._bucket_for(n)
-        ids = np.zeros((1, bucket), np.int32)
-        ids[0, :n] = chunk
-        cow = st.cow_pinned
-        extra = (self._lora_args("prefill", slot=slot)
-                 if self.adapters is not None else ())
-        *pools, tok0, key2 = self._prefills[bucket](
-            self.params, *self.pool.caches(), jnp.asarray(ids),
-            jnp.int32(st.next), jnp.int32(st.next + n),
-            jnp.asarray(self._tables[slot]),
-            jnp.int32(st.cow_src if cow else 0),
-            jnp.int32(st.cow_len if cow else 0),
-            jnp.asarray(self._key_data[slot]), *extra)
-        self.pool.update(*self._pop_moe(pools))
-        if cow:
-            # the COW source was pinned only for the copy above
-            self.pool.release([st.cow_src])
-            st.cow_pinned = False
-        st.next += n
-        st.chunks_done += 1
-        self._pos[slot] = st.next
-        req.prefilled = st.next
-        if self.tracer is not None:
-            self.tracer.event(req.trace_id, "prefill_chunk",
-                              tokens=int(n), bucket=bucket,
-                              start=st.next - n, final=st.done)
-        if not st.done:
-            return  # intermediate chunk: tok0/key2 discarded
-        self._slot_chunk[slot] = None
-        self._key_data[slot] = np.asarray(key2)
-        tok0 = int(tok0)
-        self._tok[slot] = tok0
-        self.metrics.record_admit()
-        done = self._append_token(slot, tok0)
-        if not done and req.prefill_only:
-            # same handoff retirement as the single-shot path in
-            # _admit_one — a chunked prefill-phase request hands off
-            # after its final chunk commits the first token
-            req.handed_off = True
-            done = True
-        if done:
-            finished.append(self._retire(slot))
+        ph = self._phases
+        with ph.phase("prefill"):
+            tokens = req.output_ids()
+            chunk = tokens[st.next:st.next + n]
+            bucket = self._bucket_for(n)
+            ids = np.zeros((1, bucket), np.int32)
+            ids[0, :n] = chunk
+            cow = st.cow_pinned
+            extra = (self._lora_args("prefill", slot=slot)
+                     if self.adapters is not None else ())
+            args = ph.upload(
+                ids, np.int32(st.next), np.int32(st.next + n),
+                self._tables[slot], np.int32(st.cow_src if cow else 0),
+                np.int32(st.cow_len if cow else 0), self._key_data[slot])
+            with ph.phase("dispatch"):
+                *pools, tok0, key2 = self._prefills[bucket](
+                    self.params, *self.pool.caches(), *args, *extra)
+            self.pool.update(*self._pop_moe(pools))
+            if cow:
+                # the COW source was pinned only for the copy above
+                self.pool.release([st.cow_src])
+                st.cow_pinned = False
+            st.next += n
+            st.chunks_done += 1
+            self._pos[slot] = st.next
+            req.prefilled = st.next
+            if self.tracer is not None:
+                self.tracer.event(req.trace_id, "prefill_chunk",
+                                  tokens=int(n), bucket=bucket,
+                                  start=st.next - n, final=st.done)
+            if not st.done:
+                return  # intermediate chunk: tok0/key2 discarded
+            self._slot_chunk[slot] = None
+            with ph.wait(2):
+                self._key_data[slot] = np.asarray(key2)
+                tok0 = int(tok0)
+            self._tok[slot] = tok0
+        with ph.phase("commit"):
+            self.metrics.record_admit()
+            done = self._append_token(slot, tok0)
+            if not done and req.prefill_only:
+                # same handoff retirement as the single-shot path in
+                # _admit_one — a chunked prefill-phase request hands
+                # off after its final chunk commits the first token
+                req.handed_off = True
+                done = True
+            if done:
+                finished.append(self._retire(slot))
 
     def _feed_chunks(self, finished: List[int]) -> Tuple[int, int]:
         """Stream queued chunk work through the bucket programs — at
@@ -1881,15 +1946,25 @@ class ServeEngine:
 
         extra = (self._lora_args("verify")
                  if self.adapters is not None else ())
-        *pools, toks, chain = self._verifies[k_bucket](
-            self.params, *self.pool.caches(), jnp.asarray(ids),
-            jnp.asarray(starts), jnp.asarray(tail_lens),
-            jnp.asarray(self._tables), jnp.asarray(self._key_data),
-            *extra)
+        ph = self._phases
+        args = ph.upload(ids, starts, tail_lens, self._tables,
+                         self._key_data)
+        with ph.phase("dispatch"):
+            *pools, toks, chain = self._verifies[k_bucket](
+                self.params, *self.pool.caches(), *args, *extra)
         self.pool.update(*self._pop_moe(pools))
-        toks = np.asarray(toks)
-        chain = np.asarray(chain)
+        with ph.wait(2):
+            toks = np.asarray(toks)
+            chain = np.asarray(chain)
+        with ph.phase("commit"):
+            return self._commit_verified(active, drafts, tentative, toks,
+                                         chain, finished)
 
+    def _commit_verified(self, active, drafts, tentative, toks, chain,
+                         finished) -> Tuple[int, int, int]:
+        """The host half of :meth:`_verify_step`: per slot, commit the
+        longest matching prefix + the bonus token, adopt the key after
+        exactly that many splits, resolve the tentative blocks."""
         committed = drafted = accepted = 0
         for slot in active:
             d = drafts[slot]
@@ -1942,71 +2017,83 @@ class ServeEngine:
         """One scheduler iteration: admit -> (chunked mode) feed
         budget-capped prefill chunks -> grow/preempt -> one decode
         step for every GENERATING slot -> retire finished rows.
-        Returns the request ids that finished this step."""
+        Returns the request ids that finished this step.
+
+        The step runs under a ``qn.serve.step`` annotation and every
+        part of it inside a phase (obs/spans.py): a host span on the
+        profiler's clock when a session is on, and always the step's
+        exclusive phase times in the flight-recorder ring."""
+        with jax.profiler.StepTraceAnnotation(
+                SERVE_STEP, step_num=self.metrics.steps + 1):
+            return self._step()
+
+    def _step(self) -> List[int]:
         finished: List[int] = []
         prefill_tokens = 0
         prefix_hit_tokens = 0
         # flight recorder (obs/recorder.py): the step's wall window is
         # read from the injectable clock WITHOUT any device drain —
         # the recorder must never add blocking to the step loop, so it
-        # times dispatch + whatever blocking the step itself did
-        rec_t0 = self.clock() if self.recorder is not None else None
-        if self.recorder is not None:
-            m = self.metrics
-            rec_admitted0 = m.admitted
-            rec_preempted0 = m.preempted
+        # times dispatch + whatever blocking the step itself did. Until
+        # another phase opens, the step is in ``schedule``.
+        ph = self._phases
+        rec_t0 = ph.begin()
+        m = self.metrics
+        rec_admitted0 = m.admitted
+        rec_preempted0 = m.preempted
 
-        # 0. deadline enforcement — running slots AND the waiting queue
-        self._sweep_deadlines(finished)
+        with ph.phase("schedule"):
+            # 0. deadline enforcement — running slots AND the waiting queue
+            self._sweep_deadlines(finished)
 
-        # 1a. host-tier promotion feed: stream at most the per-step
-        # block budget of host->device chain re-imports (the PROMOTING
-        # queue head) — decode below still runs for every generating
-        # slot, so promotions never stall in-flight streams
-        if self._promoting:
-            self._feed_promotions()
+            # 1a. host-tier promotion feed: stream at most the per-step
+            # block budget of host->device chain re-imports (the PROMOTING
+            # queue head) — decode below still runs for every generating
+            # slot, so promotions never stall in-flight streams
+            if self._promoting:
+                self._feed_promotions()
 
-        # 1. admissions — chunked mode allocates slot + table only
-        # (the budget-capped chunk feed below does the compute); plain
-        # mode prefills the whole tail here, as always
-        while not self._admissions_paused:
-            free = self._free_slots()
-            if self.kv_tier is not None:
-                w = self.scheduler.waiting
-                # third admission outcome, host-hit: the head's chain
-                # extends onto the host tier — park it PROMOTING (one
-                # round per admission try) instead of re-prefilling
-                # what the tier still holds
-                if (w and w[0].state == WAITING
-                        and w[0].rid not in self._promotion_done
-                        and self._start_promotion(w[0])):
+            # 1. admissions — chunked mode allocates slot + table only
+            # (the budget-capped chunk feed below does the compute); plain
+            # mode prefills the whole tail here, as always
+            while not self._admissions_paused:
+                free = self._free_slots()
+                if self.kv_tier is not None:
+                    w = self.scheduler.waiting
+                    # third admission outcome, host-hit: the head's chain
+                    # extends onto the host tier — park it PROMOTING (one
+                    # round per admission try) instead of re-prefilling
+                    # what the tier still holds
+                    if (w and w[0].state == WAITING
+                            and w[0].rid not in self._promotion_done
+                            and self._start_promotion(w[0])):
+                        break
+                req = self.scheduler.next_admission(len(free))
+                if req is None:
                     break
-            req = self.scheduler.next_admission(len(free))
-            if req is None:
-                break
-            self._promotion_done.discard(req.rid)
-            slot = free[0]
+                self._promotion_done.discard(req.rid)
+                slot = free[0]
+                if self.chunked_prefill:
+                    prefix_hit_tokens += self._admit_slot_chunked(slot, req)
+                else:
+                    tail, hit = self._admit_one(slot, req)
+                    prefill_tokens += tail
+                    prefix_hit_tokens += hit
+                    if self._slot_req[slot] is None:  # instant retire
+                        finished.append(req.rid)
+
+            # 1b. chunk feed (chunked mode): at most prefill_chunk_budget
+            # prompt tokens through the bucket programs this step — the
+            # decode step below still runs for every generating slot, so
+            # in-flight streams emit a token per step no matter how long
+            # the prompt being prefilled is (Sarathi-Serve)
+            prefill_chunks = 0
             if self.chunked_prefill:
-                prefix_hit_tokens += self._admit_slot_chunked(slot, req)
-            else:
-                tail, hit = self._admit_one(slot, req)
-                prefill_tokens += tail
-                prefix_hit_tokens += hit
-                if self._slot_req[slot] is None:  # instant retire
-                    finished.append(req.rid)
+                fed, prefill_chunks = self._feed_chunks(finished)
+                prefill_tokens += fed
 
-        # 1b. chunk feed (chunked mode): at most prefill_chunk_budget
-        # prompt tokens through the bucket programs this step — the
-        # decode step below still runs for every generating slot, so
-        # in-flight streams emit a token per step no matter how long
-        # the prompt being prefilled is (Sarathi-Serve)
-        prefill_chunks = 0
-        if self.chunked_prefill:
-            fed, prefill_chunks = self._feed_chunks(finished)
-            prefill_tokens += fed
-
-        # 2. block growth / preemption for the upcoming writes
-        self._grow_or_preempt()
+            # 2. block growth / preemption for the upcoming writes
+            self._grow_or_preempt()
 
         # 3. one decode step for every GENERATING slot (mid-prefill
         # slots sit out — their first token comes from their final
@@ -2018,6 +2105,9 @@ class ServeEngine:
         decoding = [s for s in active if self._slot_chunk[s] is None]
         prefilling = [s for s in active
                       if self._slot_chunk[s] is not None]
+        # what the decode program reads of the pool this step: every
+        # position the rows that ride it hold
+        context_tokens = int(self._pos[decoding].sum())
         decode_tokens = 0
         draft_tokens = accepted_draft = 0
         spec_step = False
@@ -2055,69 +2145,70 @@ class ServeEngine:
                         tok[s] = 0
                         pos[s] = 0
                         tables[s] = 0
-                *pools, nxt, key2 = sentinel(
-                    self.params, *self.pool.caches(),
-                    jnp.asarray(tok), jnp.asarray(pos),
-                    jnp.asarray(tables),
-                    jnp.asarray(self._key_data), *extra)
+                args = ph.upload(tok, pos, tables, self._key_data)
+                with ph.phase("dispatch"):
+                    *pools, nxt, key2 = sentinel(
+                        self.params, *self.pool.caches(), *args, *extra)
                 self.pool.update(*self._pop_moe(pools))
-                nxt = np.asarray(nxt)
-                key2 = np.array(key2)
-                for s in prefilling:
-                    # a mid-prefill slot's chain must not advance —
-                    # its one split happens on its final chunk
-                    key2[s] = self._key_data[s]
-                self._key_data = key2
-                for slot in decoding:
-                    token = int(nxt[slot])
-                    self._tok[slot] = token
-                    self._pos[slot] += 1
-                    decode_tokens += 1
-                    if self.tracer is not None:
-                        self.tracer.event(
-                            self._slot_req[slot].trace_id, "decode",
-                            token=token, pos=int(self._pos[slot]))
-                    if self._append_token(slot, token):
-                        finished.append(self._retire(slot))
-                if self.kv_tier is not None:
-                    self._decode_blocked_demotions += (
-                        self.kv_tier.demotions - demo0)
+                with ph.wait(2):
+                    nxt = np.asarray(nxt)
+                    key2 = np.array(key2)
+                with ph.phase("commit"):
+                    for s in prefilling:
+                        # a mid-prefill slot's chain must not advance —
+                        # its one split happens on its final chunk
+                        key2[s] = self._key_data[s]
+                    self._key_data = key2
+                    for slot in decoding:
+                        token = int(nxt[slot])
+                        self._tok[slot] = token
+                        self._pos[slot] += 1
+                        decode_tokens += 1
+                        if self.tracer is not None:
+                            self.tracer.event(
+                                self._slot_req[slot].trace_id, "decode",
+                                token=token, pos=int(self._pos[slot]))
+                        if self._append_token(slot, token):
+                            finished.append(self._retire(slot))
+                    if self.kv_tier is not None:
+                        self._decode_blocked_demotions += (
+                            self.kv_tier.demotions - demo0)
 
         # 4. metrics — MoE families additionally drain the routing
         # stats their programs returned this step (per-expert demand,
         # capacity drops, router entropy) into the same ledger
-        moe_kw = self._drain_moe() if self._moe_on else {}
-        tier = self.kv_tier
-        self.metrics.record_step(
-            running=len(self._active_slots()),
-            waiting=len(self.scheduler.waiting),
-            kv_blocks_used=self.pool.num_used,
-            kv_blocks_total=self.pool.usable_blocks,
-            kv_pool_bytes=self.pool.pool_bytes,
-            kv_bytes_per_token=self.pool.bytes_per_token,
-            weight_bytes=self.weight_bytes,
-            weights_dtype=self.weights_dtype,
-            prefill_tokens=prefill_tokens,
-            decode_tokens=decode_tokens,
-            prefix_hit_tokens=prefix_hit_tokens,
-            spec_step=spec_step,
-            draft_tokens=draft_tokens,
-            accepted_draft_tokens=accepted_draft,
-            prefill_chunks=prefill_chunks,
-            kv_cache_evictions=self.pool.cache_evictions,
-            kv_demotions=0 if tier is None else tier.demotions,
-            kv_promotions=0 if tier is None else tier.promotions,
-            kv_host_evictions=0 if tier is None else tier.evictions,
-            host_hit_tokens=0 if tier is None else tier.promoted_tokens,
-            host_tier_bytes=0 if tier is None else tier.bytes_used,
-            decode_blocked_demotions=self._decode_blocked_demotions,
-            **moe_kw)
-        if self.recorder is not None:
-            from quintnet_tpu.obs.recorder import StepRecord
-
-            m = self.metrics
-            self.recorder.record(StepRecord(
-                step=m.steps, t0=rec_t0, t1=self.clock(),
+        with ph.phase("commit"):
+            moe_kw = self._drain_moe() if self._moe_on else {}
+            tier = self.kv_tier
+            self.metrics.record_step(
+                running=len(self._active_slots()),
+                waiting=len(self.scheduler.waiting),
+                kv_blocks_used=self.pool.num_used,
+                kv_blocks_total=self.pool.usable_blocks,
+                kv_pool_bytes=self.pool.pool_bytes,
+                kv_bytes_per_token=self.pool.bytes_per_token,
+                weight_bytes=self.weight_bytes,
+                weights_dtype=self.weights_dtype,
+                prefill_tokens=prefill_tokens,
+                decode_tokens=decode_tokens,
+                prefix_hit_tokens=prefix_hit_tokens,
+                spec_step=spec_step,
+                draft_tokens=draft_tokens,
+                accepted_draft_tokens=accepted_draft,
+                prefill_chunks=prefill_chunks,
+                kv_cache_evictions=self.pool.cache_evictions,
+                kv_demotions=0 if tier is None else tier.demotions,
+                kv_promotions=0 if tier is None else tier.promotions,
+                kv_host_evictions=0 if tier is None else tier.evictions,
+                host_hit_tokens=(0 if tier is None
+                                 else tier.promoted_tokens),
+                host_tier_bytes=0 if tier is None else tier.bytes_used,
+                decode_blocked_demotions=self._decode_blocked_demotions,
+                **moe_kw)
+        rec_t1 = ph.end()
+        if self._recorder is not None:
+            self._recorder.record(StepRecord(
+                step=m.steps, t0=rec_t0, t1=rec_t1,
                 running=m.running, waiting=m.waiting,
                 decoding=len(decoding), prefilling=len(prefilling),
                 admitted=m.admitted - rec_admitted0,
@@ -2131,12 +2222,47 @@ class ServeEngine:
                 prefill_chunks=prefill_chunks,
                 spec_step=spec_step, draft_tokens=draft_tokens,
                 accepted_draft_tokens=accepted_draft,
+                phases=ph.seconds, host_syncs=ph.host_syncs,
+                h2d_bytes=ph.h2d_bytes, context_tokens=context_tokens,
                 attrs={k: (v.tolist() if isinstance(v, np.ndarray)
                            else v)
                        for k, v in moe_kw.items()} if moe_kw else {}))
         if self.log_every:
             self.metrics.log_step(self.logger, every=self.log_every)
         return finished
+
+    def _warmup_calls(self):
+        """(sentinel, arguments) of EVERY program — each prefill
+        bucket, each decode program, each verify bucket — with all-zero
+        block tables: every write scatters into the pool's null block.
+        Lazy, so a caller that runs the programs (which donate the
+        pool) finds the live pool in each next tuple."""
+        zrow = jnp.zeros((self.table_width,), jnp.int32)
+        lora_on = self.adapters is not None
+        p_extra = self._lora_args("prefill", slot=0) if lora_on else ()
+        for b, sentinel in self._prefills.items():
+            yield sentinel, (
+                self.params, *self.pool.caches(),
+                jnp.zeros((1, b), jnp.int32), jnp.int32(0), jnp.int32(1),
+                zrow, jnp.int32(0), jnp.int32(0),
+                jnp.asarray(jax.random.key_data(jax.random.key(0))),
+                *p_extra)
+        for R, sentinel in self._decodes.items():
+            extra = (self._lora_args("decode", rank_bucket=R)
+                     if lora_on else ())
+            yield sentinel, (
+                self.params, *self.pool.caches(), jnp.asarray(self._tok),
+                jnp.asarray(self._pos), jnp.asarray(self._tables),
+                jnp.asarray(self._key_data), *extra)
+        v_extra = self._lora_args("verify") if lora_on else ()
+        for k, sentinel in self._verifies.items():
+            yield sentinel, (
+                self.params, *self.pool.caches(),
+                jnp.zeros((self.max_slots, k + 1), jnp.int32),
+                jnp.zeros((self.max_slots,), jnp.int32),
+                jnp.zeros((self.max_slots,), jnp.int32),
+                jnp.zeros((self.max_slots, self.table_width), jnp.int32),
+                jnp.asarray(self._key_data), *v_extra)
 
     def warmup(self) -> None:
         """Compile EVERY prefill bucket and the decode step before
@@ -2148,42 +2274,23 @@ class ServeEngine:
         hit each bucket cannot cover the largest bucket when
         ``prefill_len`` sits within the admission margin of the
         previous one; calling the programs directly can."""
-        key = jnp.asarray(jax.random.key_data(jax.random.key(0)))
-        zrow = jnp.zeros((self.table_width,), jnp.int32)
-        lora_on = self.adapters is not None
-        if lora_on:
+        if self.adapters is not None:
             # compile the pack-maintenance program too (a zero write is
             # a no-op on the zeroed pack): the first real bind must not
             # be the first compile
             self._apply_pack_update(0, self._zero_slot_update())
-        p_extra = self._lora_args("prefill", slot=0) if lora_on else ()
-        for b, sentinel in self._prefills.items():
-            *pools, _tok, _k = sentinel(
-                self.params, *self.pool.caches(),
-                jnp.zeros((1, b), jnp.int32), jnp.int32(0), jnp.int32(1),
-                zrow, jnp.int32(0), jnp.int32(0), key, *p_extra)
+        for sentinel, args in self._warmup_calls():
+            *pools, _tokens, _keys = sentinel(*args)
             self.pool.update(*self._pop_moe(pools, note=False))
-            key = jnp.asarray(np.asarray(_k))
-        for R, sentinel in self._decodes.items():
-            extra = (self._lora_args("decode", rank_bucket=R)
-                     if lora_on else ())
-            *pools, _nxt, _keys = sentinel(
-                self.params, *self.pool.caches(), jnp.asarray(self._tok),
-                jnp.asarray(self._pos), jnp.asarray(self._tables),
-                jnp.asarray(self._key_data), *extra)
-            self.pool.update(*self._pop_moe(pools, note=False))
-        v_extra = self._lora_args("verify") if lora_on else ()
-        for k, sentinel in self._verifies.items():
-            # all-zero tables + zero tail_lens: every write lands in
-            # the null block, candidate tokens and chains are discarded
-            *pools, _t, _c = sentinel(
-                self.params, *self.pool.caches(),
-                jnp.zeros((self.max_slots, k + 1), jnp.int32),
-                jnp.zeros((self.max_slots,), jnp.int32),
-                jnp.zeros((self.max_slots,), jnp.int32),
-                jnp.zeros((self.max_slots, self.table_width), jnp.int32),
-                jnp.asarray(self._key_data), *v_extra)
-            self.pool.update(*self._pop_moe(pools, note=False))
+
+    def program_texts(self) -> List[str]:
+        """The compiled text of every program (a second lowering: a
+        load from the compile cache where it is warm). What
+        obs/scopes.write_scope_maps wants beside an xplane, so that
+        ``tools/trace_view.py --xplane`` can name device time by
+        scope."""
+        return [sentinel.fn.lower(*args).compile().as_text()
+                for sentinel, args in self._warmup_calls()]
 
     def run(self, *, max_steps: Optional[int] = None) -> None:
         """Step until all submitted work is finished (or ``max_steps``)."""

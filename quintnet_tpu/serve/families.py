@@ -156,6 +156,15 @@ def _scan_layer(layer, lora, scaled: bool = False):
     return blk, kc, vc, sc, lr
 
 
+def _scan_blocks(body, h, xs):
+    """The layer scan of every serving program, under the scope
+    ``blocks``: what the scan itself does with its xs and ys (slicing
+    a layer's pool view out, stacking it back) is named on a device
+    trace like the layers' own work."""
+    with jax.named_scope("blocks"):
+        return lax.scan(body, h, xs)
+
+
 def _reduce_moe_stats(st):
     """Layer-stacked routing stats (each leaf leading [L], the scan's
     ys) -> per-program totals: counts summed over layers, entropy
@@ -191,8 +200,9 @@ def gpt2_family(cfg) -> Family:
         positions = start + jnp.arange(P, dtype=jnp.int32)
         # pad rows may sit past n_positions; clip their (ignored) wpe read
         safe_pos = jnp.clip(positions, 0, emb["wpe"].shape[0] - 1)
-        h = (_embed_tok(emb, ids, cfg, tp_axis)
-             + jnp.take(emb["wpe"], safe_pos, axis=0)[None])
+        with jax.named_scope("embed"):
+            h = (_embed_tok(emb, ids, cfg, tp_axis)
+                 + jnp.take(emb["wpe"], safe_pos, axis=0)[None])
         heads = _local_heads(cfg, tp_axis)
         tail_len = t0 - start
         scaled = kv_scales is not None
@@ -208,7 +218,7 @@ def gpt2_family(cfg) -> Family:
                 kv_scales=sc, policy=policy, attn_kernel=attn_kernel)
             return out[0], out[1:]
 
-        h, pools = lax.scan(
+        h, pools = _scan_blocks(
             body, h, _scan_xs(params["blocks"], k_pool, v_pool, lora,
                               kv_scales))
         if cfg.moe_args is not None:
@@ -221,8 +231,9 @@ def gpt2_family(cfg) -> Family:
                tp_axis=None, ep_axis=None, lora=None, lora_scale=None,
                kv_scales=None, policy=None, attn_kernel="xla"):
         emb = params["embedding"]
-        x = (_embed_tok(emb, tok[:, None], cfg, tp_axis)
-             + jnp.take(emb["wpe"], pos, axis=0)[:, None, :])
+        with jax.named_scope("embed"):
+            x = (_embed_tok(emb, tok[:, None], cfg, tp_axis)
+                 + jnp.take(emb["wpe"], pos, axis=0)[:, None, :])
         heads = _local_heads(cfg, tp_axis)
         scaled = kv_scales is not None
 
@@ -238,7 +249,7 @@ def gpt2_family(cfg) -> Family:
                                attn_kernel=attn_kernel)
             return out[0], out[1:]
 
-        h, pools = lax.scan(
+        h, pools = _scan_blocks(
             body, x, _scan_xs(params["blocks"], k_pool, v_pool, lora,
                               kv_scales))
         if cfg.moe_args is not None:
@@ -255,8 +266,9 @@ def gpt2_family(cfg) -> Family:
         positions = (starts[:, None]
                      + jnp.arange(P, dtype=jnp.int32)[None, :])  # [S, P]
         safe_pos = jnp.clip(positions, 0, emb["wpe"].shape[0] - 1)
-        h = (_embed_tok(emb, ids, cfg, tp_axis)
-             + jnp.take(emb["wpe"], safe_pos, axis=0))
+        with jax.named_scope("embed"):
+            h = (_embed_tok(emb, ids, cfg, tp_axis)
+                 + jnp.take(emb["wpe"], safe_pos, axis=0))
         heads = _local_heads(cfg, tp_axis)
         scaled = kv_scales is not None
 
@@ -271,7 +283,7 @@ def gpt2_family(cfg) -> Family:
                 kv_scales=sc, policy=policy, attn_kernel=attn_kernel)
             return out[0], out[1:]
 
-        h, pools = lax.scan(
+        h, pools = _scan_blocks(
             body, h, _scan_xs(params["blocks"], k_pool, v_pool, lora,
                               kv_scales))
         if cfg.moe_args is not None:
@@ -292,8 +304,9 @@ def gpt2_family(cfg) -> Family:
         positions = (start + idx * Pl
                      + jnp.arange(Pl, dtype=jnp.int32))
         safe_pos = jnp.clip(positions, 0, emb["wpe"].shape[0] - 1)
-        h = (_embed_tok(emb, ids, cfg, tp_axis)
-             + jnp.take(emb["wpe"], safe_pos, axis=0)[None])
+        with jax.named_scope("embed"):
+            h = (_embed_tok(emb, ids, cfg, tp_axis)
+                 + jnp.take(emb["wpe"], safe_pos, axis=0)[None])
         heads = _local_heads(cfg, tp_axis)
         scaled = kv_scales is not None
 
@@ -306,7 +319,7 @@ def gpt2_family(cfg) -> Family:
                 block_size=block_size, kv_scales=sc, policy=policy)
             return out[0], out[1:]
 
-        h, pools = lax.scan(
+        h, pools = _scan_blocks(
             body, h, _scan_xs(params["blocks"], k_pool, v_pool, None,
                               kv_scales))
         h_last = sp_last_hidden(h, start, t0, sp_axis=sp_axis)
@@ -370,7 +383,7 @@ def llama_family(cfg) -> Family:
                 kv_scales=sc, policy=policy, attn_kernel=attn_kernel)
             return x, pools
 
-        h, pools = lax.scan(
+        h, pools = _scan_blocks(
             body, h, _scan_xs(params["blocks"], k_pool, v_pool, lora,
                               kv_scales))
         if cfg.moe_args is not None:
@@ -398,7 +411,7 @@ def llama_family(cfg) -> Family:
                 kv_scales=sc, policy=policy, attn_kernel=attn_kernel)
             return h, pools
 
-        h, pools = lax.scan(
+        h, pools = _scan_blocks(
             body, x, _scan_xs(params["blocks"], k_pool, v_pool, lora,
                               kv_scales))
         if cfg.moe_args is not None:
@@ -427,7 +440,7 @@ def llama_family(cfg) -> Family:
                 kv_scales=sc, policy=policy, attn_kernel=attn_kernel)
             return x, pools
 
-        h, pools = lax.scan(
+        h, pools = _scan_blocks(
             body, h, _scan_xs(params["blocks"], k_pool, v_pool, lora,
                               kv_scales))
         if cfg.moe_args is not None:
@@ -457,7 +470,7 @@ def llama_family(cfg) -> Family:
                 kv_scales=sc, policy=policy)
             return x, pools
 
-        h, pools = lax.scan(
+        h, pools = _scan_blocks(
             body, h, _scan_xs(params["blocks"], k_pool, v_pool, None,
                               kv_scales))
         h_last = sp_last_hidden(h, start, t0, sp_axis=sp_axis)

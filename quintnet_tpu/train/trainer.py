@@ -21,6 +21,8 @@ Differences from the reference worth knowing:
 
 from __future__ import annotations
 
+import functools
+import itertools
 import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, Iterable, List, Optional
@@ -212,6 +214,13 @@ def _call_batches_fn(fn, epoch: int, skip: int):
     return fn(epoch), False
 
 
+def _span(name: str):
+    """Decorator: the call is a host span ``name`` on the profiler's
+    clock — a no-op without a profiler session. The ``qn.train.*``
+    vocabulary is in docs/observability.md."""
+    return functools.partial(jax.profiler.annotate_function, name=name)
+
+
 class Trainer:
     """fit() over (x, y) batch iterables.
 
@@ -373,6 +382,7 @@ class Trainer:
             epoch, {"params": params, "opt": opt_state, "epoch": epoch},
             wait=False)
 
+    @_span("qn.train.save")
     def save_state(self, params, opt_state, cursor, *,
                    wait: bool = False, boundary: bool = False) -> float:
         """Checkpoint arrays + train cursor at orbax step
@@ -415,6 +425,7 @@ class Trainer:
         self._bad_ckpt_steps.discard(step)
         return time.time() - t
 
+    @_span("qn.train.save")
     def save_best(self, epoch: int, params, opt_state, val_loss: float):
         """Best-by-val-loss retention in a sibling ``<dir>-best``
         directory (one kept), alongside the rolling epoch saves —
@@ -427,6 +438,7 @@ class Trainer:
             epoch, {"params": params, "opt": opt_state, "epoch": epoch,
                     "val_loss": val_loss}, wait=False)
 
+    @_span("qn.train.save")
     def wait_for_saves(self):
         """Barrier on in-flight async checkpoint writes."""
         for mgr in getattr(self, "_mgrs", {}).values():
@@ -490,7 +502,9 @@ class Trainer:
                     p, b, tp_axis=tp_axis, sp_axis=sp_axis, ep_axis=ep_axis,
                     **fsdp_kw)}
 
-        def local_eval(p, b):
+        # named for the device trace: the program reads ``jit_eval_step``
+        # there, beside the train step's ``jit_local_step``
+        def eval_step(p, b):
             mets = metrics_fn(p, b)
             if strat.batch_axes:
                 mets = jax.tree.map(
@@ -508,12 +522,13 @@ class Trainer:
         self._eval_fn = RecompileSentinel(
             "train.eval",
             jax.jit(cc.shard_map_fn(
-                local_eval, strat.mesh,
+                eval_step, strat.mesh,
                 in_specs=(specs, batch_spec),
                 out_specs=P()), donate_argnums=(1,)),
             on_recompile=self._on_recompile)
         return self._eval_fn
 
+    @_span("qn.train.eval")
     def evaluate(self, params, batches: Iterable) -> Dict[str, float]:
         import warnings
 
@@ -645,11 +660,12 @@ class Trainer:
 
             def flush():
                 nonlocal n_flushed, loss_sum, loss_count
-                for dev_loss in losses[n_flushed:]:
-                    # deliberate sync: flush runs only at checkpoint
-                    # boundaries and epoch end, never per step
-                    loss_sum += float(dev_loss)  # qtcheck: ok[QT104]
-                    loss_count += 1
+                with jax.profiler.TraceAnnotation("qn.train.sync"):
+                    for dev_loss in losses[n_flushed:]:
+                        # deliberate sync: flush runs only at checkpoint
+                        # boundaries and epoch end, never per step
+                        loss_sum += float(dev_loss)  # qtcheck: ok[QT104]
+                        loss_count += 1
                 n_flushed = len(losses)
 
             def cursor_at(next_epoch, next_step):
@@ -677,64 +693,83 @@ class Trainer:
 
                 batches = prefetch_batches(
                     iter(batches), n=self.config.training.prefetch)
-            for i, (xb, yb) in enumerate(batches, start=skip):
-                batch = self.strategy.shard_batch(
-                    (jnp.asarray(xb), jnp.asarray(yb)), self.model)
-                # per-step dropout seed: deterministic in (config seed,
-                # epoch, step) so a step-granular resume (ft/TrainCursor)
-                # replays the exact same dropout sequence mid-epoch
-                seed = (self.config.training.seed * 2_000_003
-                        + epoch * 1_000_003 + i) & 0x7FFFFFFF
-                params, opt_state, loss = self.step_fn(params, opt_state,
-                                                       batch, seed)
-                losses.append(loss)
-                global_step += 1
-                if sync_every and (i + 1) % sync_every == 0:
-                    # bound async run-ahead (training.sync_every docs)
-                    float(loss)  # qtcheck: ok[QT104] — windowed by design
-                if log_every and (i + 1) % log_every == 0:
-                    # the float() is the device sync for the window, so
-                    # the wall clock measured here is honest throughput
-                    window = float(  # qtcheck: ok[QT104] — window sync
-                        jnp.mean(jnp.stack(losses[-log_every:])))
-                    dt = time.time() - t_win
-                    sps = log_every * len(xb) / max(dt, 1e-9)
-                    msg = (f"epoch {epoch} step {i + 1}: "
-                           f"loss {window:.4f} | {sps:.1f} samples/s")
-                    if self.task_type == "clm":
-                        msg += f" ({sps * xb.shape[1] / 1e3:.1f}k tok/s)"
-                    self.log(msg)
-                    t_win = time.time()
-                # -- fault-tolerance boundary (after the step landed) --
-                if ft is not None:
-                    if ft.goodput is not None:
-                        # the loss rides along so the meter can sync on
-                        # the last step's device work before reading its
-                        # wall clock (ft/goodput.py report)
-                        ft.goodput.on_step(global_step, loss)
-                    if ft.chaos is not None:
-                        # may os._exit / SIGTERM self / raise ChaosKilled
-                        ft.chaos.on_step_end(global_step)
-                if ft is not None and ft.preemption_requested:
-                    # finish-the-step-then-save: the in-flight step above
-                    # already landed; one SYNCHRONOUS emergency snapshot
-                    flush()
-                    blocked = self.save_state(
-                        params, opt_state, cursor_at(epoch, i + 1),
-                        wait=True)
-                    if ft.goodput is not None:
-                        ft.goodput.on_save(blocked)
-                    self.log(f"preempted: emergency snapshot at epoch "
-                             f"{epoch} step {i + 1} (global step "
-                             f"{global_step})")
-                    raise TrainingPreempted(epoch, i + 1, global_step)
-                if cadence.should_save(global_step):
-                    flush()
-                    blocked = self.save_state(
-                        params, opt_state, cursor_at(epoch, i + 1))
-                    if ft is not None and ft.goodput is not None:
-                        ft.goodput.on_save(blocked)
-                    cadence.saved(global_step)
+            it = iter(batches)
+            for i in itertools.count(skip):
+                # one step = one ``qn.train.step`` on the profiler's
+                # clock, with the wait for data, the dispatch (inside
+                # step_fn), the windowed syncs and the saves inside it
+                with jax.profiler.StepTraceAnnotation(
+                        "qn.train.step", step_num=global_step):
+                    with jax.profiler.TraceAnnotation(
+                            "qn.train.data_wait"):
+                        item = next(it, None)
+                    if item is None:
+                        break
+                    xb, yb = item
+                    batch = self.strategy.shard_batch(
+                        (jnp.asarray(xb), jnp.asarray(yb)), self.model)
+                    # per-step dropout seed: deterministic in (config
+                    # seed, epoch, step) so a step-granular resume
+                    # (ft/TrainCursor) replays the exact same dropout
+                    # sequence mid-epoch
+                    seed = (self.config.training.seed * 2_000_003
+                            + epoch * 1_000_003 + i) & 0x7FFFFFFF
+                    params, opt_state, loss = self.step_fn(
+                        params, opt_state, batch, seed)
+                    losses.append(loss)
+                    global_step += 1
+                    if sync_every and (i + 1) % sync_every == 0:
+                        # bound async run-ahead (training.sync_every docs)
+                        with jax.profiler.TraceAnnotation("qn.train.sync"):
+                            float(loss)  # qtcheck: ok[QT104] — windowed
+                    if log_every and (i + 1) % log_every == 0:
+                        # the float() is the device sync for the window,
+                        # so the wall clock measured here is honest
+                        # throughput
+                        with jax.profiler.TraceAnnotation("qn.train.sync"):
+                            window = float(  # qtcheck: ok[QT104] — sync
+                                jnp.mean(jnp.stack(losses[-log_every:])))
+                        dt = time.time() - t_win
+                        sps = log_every * len(xb) / max(dt, 1e-9)
+                        msg = (f"epoch {epoch} step {i + 1}: "
+                               f"loss {window:.4f} | {sps:.1f} samples/s")
+                        if self.task_type == "clm":
+                            msg += (f" ({sps * xb.shape[1] / 1e3:.1f}k "
+                                    f"tok/s)")
+                        self.log(msg)
+                        t_win = time.time()
+                    # -- fault-tolerance boundary (after the step landed)
+                    if ft is not None:
+                        if ft.goodput is not None:
+                            # the loss rides along so the meter can
+                            # sync on the last step's device work before
+                            # reading its wall clock (ft/goodput.py)
+                            ft.goodput.on_step(global_step, loss)
+                        if ft.chaos is not None:
+                            # may os._exit / SIGTERM self / raise
+                            # ChaosKilled
+                            ft.chaos.on_step_end(global_step)
+                    if ft is not None and ft.preemption_requested:
+                        # finish-the-step-then-save: the in-flight step
+                        # above already landed; one SYNCHRONOUS emergency
+                        # snapshot
+                        flush()
+                        blocked = self.save_state(
+                            params, opt_state, cursor_at(epoch, i + 1),
+                            wait=True)
+                        if ft.goodput is not None:
+                            ft.goodput.on_save(blocked)
+                        self.log(f"preempted: emergency snapshot at epoch "
+                                 f"{epoch} step {i + 1} (global step "
+                                 f"{global_step})")
+                        raise TrainingPreempted(epoch, i + 1, global_step)
+                    if cadence.should_save(global_step):
+                        flush()
+                        blocked = self.save_state(
+                            params, opt_state, cursor_at(epoch, i + 1))
+                        if ft is not None and ft.goodput is not None:
+                            ft.goodput.on_save(blocked)
+                        cadence.saved(global_step)
             flush()
             # host-side sequential f64 mean (not a device jnp.mean):
             # identical value whether the epoch ran in one process or

@@ -622,8 +622,8 @@ def test_aggregate_weights_capped_reservoirs_by_true_count():
 
 def test_zero_traffic_aggregation_no_nan():
     """aggregate() and FleetMetrics.summary() over zero-step engines:
-    zeroed dicts, finite floats, NO RuntimeWarning (the StepTimer fix
-    from PR 4, applied one layer up)."""
+    zeroed dicts, finite floats, NO RuntimeWarning (PR 4's empty-
+    summary fix, applied one layer up)."""
     def _all_finite(obj):
         if isinstance(obj, dict):
             return all(_all_finite(v) for v in obj.values())
@@ -838,3 +838,356 @@ def test_trace_id_rides_the_wire():
     payload = wire.progress_to_wire(p)
     del payload["trace_id"]
     assert wire.progress_from_wire(payload).trace_id is None
+
+
+# ---------------------------------------------------------------------
+# names on the device trace, phases of a step, the default ring (PR 25)
+# ---------------------------------------------------------------------
+
+# greedy tokens of _golden_prompts() from the tree BEFORE the programs
+# were named and scoped (commit bf72991): names and scopes are metadata
+GOLDEN_GREEDY = [
+    [95, 95, 64, 64, 95, 95, 15, 64], [95, 95, 95, 95, 15, 15, 15, 64],
+    [115, 115, 115, 117, 64, 64, 64, 64], [64, 64, 127, 14, 14, 14, 14, 95],
+    [95, 95, 95, 15, 15, 15, 15, 15]]
+GOLDEN_COMPILE_STATS = {"prefill": 1, "decode": 1}
+PROGRAM_NAME = r"serve_(prefill_b\d+|decode(_r\d+)?|verify_b\d+|pack_update)"
+
+
+def _golden_prompts():
+    g = np.random.default_rng(25)
+    return [np.asarray(g.integers(0, CFG.vocab_size, (t,)), np.int32)
+            for t in (5, 9, 3, 7, 12)]
+
+
+class _TickClock:
+    """A clock that moves by one tick at every reading: phase times are
+    then exact counts of readings, whatever the machine is doing."""
+
+    def __init__(self, tick=1e-3):
+        self.t, self.tick = 0.0, tick
+
+    def __call__(self):
+        self.t += self.tick
+        return self.t
+
+
+def test_named_and_scoped_programs_keep_the_golden_tokens(params):
+    """The default engine — ring on, programs named, scopes in the
+    model — gives the parent tree's greedy tokens and compiles what the
+    parent compiled."""
+    eng = _engine(params)
+    prompts = _golden_prompts()
+    rids = [eng.submit(p, 8) for p in prompts]
+    eng.run()
+    got = [[int(t) for t in eng.result(r)[len(p):]]
+           for r, p in zip(rids, prompts)]
+    assert got == GOLDEN_GREEDY
+    assert eng.compile_stats() == GOLDEN_COMPILE_STATS
+    assert len(eng.recorder) > 0          # the ring is on by default
+    assert eng.recorder.capacity == 4096
+
+
+def test_step_phases_are_exclusive_and_sum_to_the_wall(params):
+    from quintnet_tpu.obs.spans import PHASES
+
+    eng = _engine(params, clock=_TickClock())
+    for p in _golden_prompts():
+        eng.submit(p, 6)
+    eng.run()
+    recs = eng.recorder.snapshot()
+    assert len(recs) > 4
+    for r in recs:
+        assert set(r["phases"]) <= set(PHASES), r["phases"]
+        assert all(v >= 0.0 for v in r["phases"].values())
+        wall = r["t1"] - r["t0"]
+        assert abs(sum(r["phases"].values()) - wall) <= max(
+            0.02 * wall, 50e-6), (r["phases"], wall)
+    # a step that admitted was in every phase but none twice over
+    admitted = next(r for r in recs if r["admitted"])
+    assert {"schedule", "prefill", "upload", "dispatch", "wait",
+            "commit"} <= set(admitted["phases"])
+
+
+def test_a_pure_decode_step_counts_two_syncs_and_four_uploads(params):
+    eng = _engine(params)
+    for p in _golden_prompts()[:2]:
+        eng.submit(p, 6)
+    eng.step()                            # admits both: not pure
+    first = eng.recorder.last()
+    assert first["admitted"] == 2
+    # two reads per admission (key, first token) + two for the decode
+    assert first["host_syncs"] == 2 * 2 + 2
+    four = sum(a.nbytes for a in (eng._tok, eng._pos, eng._tables,
+                                  eng._key_data))
+    eng.step()
+    pure = eng.recorder.last()
+    assert pure["admitted"] == 0 and pure["decoding"] == 2
+    assert pure["host_syncs"] == 2
+    assert pure["h2d_bytes"] == four
+    assert "prefill" not in pure["phases"]
+
+
+def test_context_tokens_is_the_sum_of_positions(params):
+    eng = _engine(params, max_slots=3)
+    lens = (5, 9, 3)
+    for p in _golden_prompts()[:3]:
+        eng.submit(p, 6)
+    eng.step()
+    # after the admitting step each row holds its prompt; that step's
+    # decode read exactly those positions
+    assert eng.recorder.last()["context_tokens"] == sum(lens)
+    eng.step()
+    assert eng.recorder.last()["context_tokens"] == sum(lens) + 3
+    assert [int(x) for x in eng._pos[:3]] == [n + 2 for n in lens]
+
+
+def _lowered_names(eng):
+    import re
+
+    return [re.search(r"module @(\S+)",
+                      s.fn.lower(*args).as_text()).group(1)
+            for s, args in eng._warmup_calls()]
+
+
+@pytest.mark.parametrize("tp", [False, True], ids=["mesh-less", "tp2"])
+def test_every_engine_program_has_a_stable_name(params, tp):
+    """What reaches jax.jit is named: the lowered modules read
+    jit_serve_*, never jit_body, with no id or counter in the name —
+    on the mesh-less path and under the tp shard_map alike, spec and
+    adapters armed."""
+    import re
+
+    from quintnet_tpu.serve import AdapterRegistry
+
+    kw = dict(spec=True, adapters=AdapterRegistry())
+    p = params
+    if tp:
+        from quintnet_tpu.core.mesh import mesh_from_sizes
+        from quintnet_tpu.models.gpt2 import gpt2_to_tp_layout
+
+        p = gpt2_to_tp_layout(params, CFG, 2)
+        kw["mesh"] = mesh_from_sizes(tp=2)
+    eng = _engine(p, **kw)
+    names = _lowered_names(eng)
+    assert len(names) == (len(eng.prefill_buckets)
+                          + len(eng.lora_rank_buckets)
+                          + len(eng.spec.buckets))
+    for n in names:
+        assert re.fullmatch("jit_" + PROGRAM_NAME, n), n
+    assert len(set(names)) == len(names)
+    assert eng._pack_update.__name__ == "serve_pack_update"
+    assert sorted(n[len("jit_"):] for n in names) \
+        == eng.recorder.static["programs"]
+    # the same names from a second engine: nothing counts up
+    assert _lowered_names(_engine(p, **kw)) == names
+
+
+def test_train_step_text_carries_the_scope_vocabulary():
+    """The train step's lowered text names the layers, the loss, the
+    gradient reduction and the optimizer; the scope map reads them back
+    per instruction from the compiled text."""
+    import jax.numpy as jnp
+
+    from quintnet_tpu.core.config import Config
+    from quintnet_tpu.models.gpt2 import gpt2_model_spec
+    from quintnet_tpu.obs.scopes import module_name, scope_map
+    from quintnet_tpu.parallel.strategy import get_strategy
+    from quintnet_tpu.train.trainer import Trainer
+
+    cfg = Config.from_dict({
+        "mesh_dim": [2, 2], "mesh_name": ["dp", "tp"],
+        "training": {"batch_size": 4, "epochs": 1, "optimizer": "adamw",
+                     "grad_clip_norm": 1.0, "log_every": 0}})
+    model = gpt2_model_spec(CFG)
+    strategy = get_strategy("auto", cfg, devices=jax.devices()[:4])
+    trainer = Trainer(cfg, model, strategy=strategy, task_type="clm",
+                      log_fn=lambda _m: None)
+    params = strategy.shard_params(model, model.init(jax.random.key(0)))
+    opt = strategy.init_opt_state(model, trainer.optimizer, params)
+    ids = np.zeros((4, 16), np.int32)
+    batch = strategy.shard_batch((jnp.asarray(ids), jnp.asarray(ids)),
+                                 model)
+    import re
+
+    lowered = trainer.step_fn.fn.lower(params, opt, batch)
+    assert "module @jit_local_step" in lowered.as_text()
+    compiled = lowered.compile().as_text()
+    assert module_name(compiled) == "jit_local_step"
+    parts = {c for name in re.findall(r'op_name="([^"]+)"', compiled)
+             for c in re.split(r"[/()]", name)}
+    assert {"attn", "mlp", "lm_head", "loss", "grad_reduce", "optimizer",
+            "embed", "final_norm", "grad_clip", "blocks", "qkv", "sdpa",
+            "proj", "grads", "all_reduce_tp"} <= parts
+    paths = set(scope_map(compiled).values())
+    assert any(p.endswith("attn/qkv") for p in paths), sorted(paths)[:20]
+    assert any(p.startswith("grads/transpose(jvp(blocks))")
+               for p in paths)
+    assert "optimizer" in paths and "grad_reduce" in paths
+    # the eval program has a name of its own
+    trainer._build_eval()
+    assert trainer._eval_fn.fn.__name__ == "eval_step"
+
+
+def test_scope_path_keeps_only_the_vocabulary():
+    from quintnet_tpu.obs.scopes import scope_path
+
+    assert scope_path(
+        "jit(local_step)/shard_map/grads/transpose(jvp(blocks))/while/"
+        "body/closed_call/checkpoint/rematted_computation/attn/proj/"
+        "all_reduce_tp/psum") == (
+        "grads/transpose(jvp(blocks))/rematted_computation/attn/proj/"
+        "all_reduce_tp")
+    assert scope_path("jit(serve_decode)/blocks/while/body/closed_call/"
+                      "attn/kv_write/jit(floor_divide)/div") \
+        == "blocks/attn/kv_write"
+    assert scope_path("jit(f)/jit(_where)/select_n") == ""
+    assert scope_path("mul;jit(s)/optimizer/add") == "optimizer"
+
+
+def test_obs_package_import_pulls_in_no_jax():
+    """``import quintnet_tpu.obs`` must stay jax-free: every module
+    obs/__init__ imports is scanned for an import of jax. The one
+    module that needs the profiler's annotations (obs/spans.py) is
+    not among them."""
+    import ast
+
+    obs_dir = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "quintnet_tpu", "obs")
+    with open(os.path.join(obs_dir, "__init__.py")) as f:
+        tree = ast.parse(f.read())
+    mods = sorted({n.module.rsplit(".", 1)[1] for n in ast.walk(tree)
+                   if isinstance(n, ast.ImportFrom)
+                   and n.module.startswith("quintnet_tpu.obs.")})
+    assert "recorder" in mods and "spans" not in mods
+    for mod in mods + ["scopes"]:
+        with open(os.path.join(obs_dir, mod + ".py")) as f:
+            for node in ast.walk(ast.parse(f.read())):
+                names = ([a.name for a in node.names]
+                         if isinstance(node, ast.Import) else
+                         [node.module or ""]
+                         if isinstance(node, ast.ImportFrom) else [])
+                assert not any(n == "jax" or n.startswith("jax.")
+                               for n in names), (mod, names)
+
+
+def test_live_finds_an_engines_ring_and_forgets_it(params):
+    import gc
+
+    from quintnet_tpu.obs.recorder import live
+
+    gc.collect()
+    before = set(map(id, live()))
+    eng = _engine(params)
+    ring = eng.recorder
+    assert ring in live()
+    assert ring.static["max_slots"] == 2
+    assert ring.static["kv_bytes_per_token"] == eng.pool.bytes_per_token
+    assert ring.static["param_bytes"] == sum(
+        x.nbytes for x in jax.tree.leaves(eng.params))
+    # a ring attached later (the fleets do) is found and filled too
+    mine = StepRecorder(capacity=8, clock=eng.clock)
+    eng.recorder = mine
+    assert mine in live() and mine.static["max_slots"] == 2
+    del eng, ring, mine
+    gc.collect()
+    assert set(map(id, live())) <= before
+
+
+def test_trace_view_xplane_on_the_recorded_v5e_trace(tmp_path):
+    """``--xplane`` on artifacts/trace_r04 (ten 40.7 ms steps of
+    jit_local_step on a v5e): programs by name, and device own-time by
+    scope through a qn_scopes.json beside the xplane."""
+    import shutil
+
+    from benchmarks.lib import trace_reduce as tr
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    run = tmp_path / "trace"
+    shutil.copytree(os.path.join(root, "artifacts", "trace_r04"), run)
+    import tools.trace_view as tv
+
+    bare = tv.xplane_tables(str(run))
+    assert bare["programs"]["jit_local_step"]["count"] == 10
+    assert abs(bare["programs"]["jit_local_step"]["ms"] - 406.977) < 0.01
+    assert list(bare["device_ms_by_scope"]) == [tv.NO_MAP]
+    # name half of the trace's operations and see them come back
+    ops = tr.read_trace(tr.find_xplane(str(run)))["devices"][
+        "/device:TPU:0"]["ops"]
+    names = sorted({tr.short_name(n) for n, _s, _e in ops})
+    scoped = {n: "grads/blocks/attn" for n in names[::2]}
+    with open(run / "qn_scopes.json", "w") as f:
+        json.dump({"jit_local_step": scoped}, f)
+    out = tv.xplane_tables(str(run))
+    by = out["device_ms_by_scope"]
+    assert set(by) == {"grads/blocks/attn", tv.NO_SCOPE}
+    assert abs(sum(by.values()) - sum(
+        bare["device_ms_by_scope"].values())) < 1e-6
+    assert 0.0 < out["device_named_share_pct"] < 100.0
+    assert out["chips"] == 1 and "collective_ms_by_scope" not in out
+
+
+def test_trace_view_xplane_idle_by_span_and_exposed_collectives(
+        tmp_path, monkeypatch):
+    """The interval arithmetic of ``--xplane`` on a hand-made two-chip
+    trace: idle goes to the innermost qn.* span, a collective's exposed
+    part is what no other operation overlaps."""
+    from benchmarks.lib import trace_reduce as tr
+
+    ar = "%psum.1 = f32[8]{0} all-reduce(f32[8]{0} %x), replica_groups={}"
+    mm = "%fusion.1 = f32[8]{0} fusion(f32[8]{0} %x), kind=kLoop"
+    wh = "%while.1 = (f32[8]{0}) while((f32[8]{0}) %t), body=%b"
+    us = 1e3                               # the trace's times are ns
+    chip = {"modules": [("jit_step(1)", 0.0, 100 * us)],
+            # a while that encloses a matmul and an all-reduce, then a
+            # second all-reduce that a (pretend) overlapping op half hides
+            "ops": [(wh, 0.0, 60 * us), (mm, 0.0, 30 * us),
+                    (ar, 30 * us, 60 * us), (ar, 70 * us, 90 * us),
+                    (mm, 80 * us, 100 * us)]}
+    trace = {"devices": {"/device:TPU:0": chip, "/device:TPU:1": chip},
+             "host_spans": [("qn.serve.step", 55 * us, 75 * us),
+                            ("qn.serve.wait", 62 * us, 68 * us)]}
+    monkeypatch.setattr(tr, "read_trace", lambda *_a, **_k: trace)
+    monkeypatch.setattr(tr, "find_xplane", lambda d: d + "/x.xplane.pb")
+    with open(tmp_path / "qn_scopes.json", "w") as f:
+        json.dump({"jit_step": {"psum.1": "grad_reduce",
+                                "fusion.1": "blocks/mlp"}}, f)
+    import tools.trace_view as tv
+
+    assert tv.opcode(ar) == "all-reduce" and tv.opcode(wh) == "while"
+    out = tv.xplane_tables(str(tmp_path))
+    assert out["chips"] == 2
+    coll = out["collective_ms_by_scope"]["grad_reduce"]
+    assert coll["ms"] == pytest.approx(50e-3)          # 30 + 20 us
+    assert coll["exposed_ms"] == pytest.approx(40e-3)  # 30 + 10 us
+    # idle 60..70: 60-62 and 68-70 under the step, 62-68 under wait
+    assert out["idle_ms_by_span"] == pytest.approx(
+        {"qn.serve.wait": 6e-3, "qn.serve.step": 4e-3})
+    assert out["idle_named_share_pct"] == 100.0
+    by = out["device_ms_by_scope"]
+    assert by["blocks/mlp"] > 0 and by["grad_reduce"] > 0
+    assert by[tv.NO_SCOPE] == 0.0          # the while: no time of its own
+
+
+def test_scope_map_gives_a_bare_instruction_its_producers_scope():
+    """A convert the compiler split off carries no metadata: it takes
+    the scope of the nearest producer that has one; a named instruction
+    outside every scope stays out."""
+    from quintnet_tpu.obs.scopes import module_name, scope_map
+
+    text = """HloModule jit_serve_decode, is_scheduled=true
+
+ENTRY %main (p: bf16[8]) -> f32[8] {
+  %p = bf16[8]{0} parameter(0)
+  %fusion.1 = bf16[8]{0} fusion(%p), kind=kCustom, calls=%fc, metadata={op_name="jit(serve_decode)/blocks/while/body/attn/kv_gather/gather"}
+  %custom-call.2 = bf16[8]{0} custom-call(%fusion.1), custom_call_target="ConcatBitcast"
+  %convert.3 = f32[8]{0} convert(%custom-call.2), backend_config={"x":[]}
+  %select.4 = f32[8]{0} select(%p, %p, %p), metadata={op_name="jit(serve_decode)/jit(_where)/select_n"}
+  ROOT %add.5 = f32[8]{0} add(%convert.3, %select.4), metadata={op_name="jit(serve_decode)/sample/add"}
+}
+"""
+    assert module_name(text) == "jit_serve_decode"
+    assert scope_map(text) == {
+        "fusion.1": "blocks/attn/kv_gather",
+        "custom-call.2": "blocks/attn/kv_gather",
+        "convert.3": "blocks/attn/kv_gather", "add.5": "sample"}

@@ -33,9 +33,35 @@ Timestamps are microseconds (the format's unit), re-based to the
 earliest event so Perfetto opens at t=0 instead of hours into a
 monotonic clock.
 
+A second mode reads a PROFILER trace of a run on the chip
+(``--xplane DIR``: the directory given to ``jax.profiler.start_trace``,
+or the ``*.xplane.pb`` itself) and prints one JSON object of tables —
+the operator's view of where a traced stretch went:
+
+- ``programs``: per jitted program (``jit_serve_decode``,
+  ``jit_local_step``, ...) executions and milliseconds, chip 0;
+- ``device_ms_by_scope``: device own-time by the program's
+  ``jax.named_scope`` path (``blocks/attn/sdpa``), mean over chips,
+  with ``named_share_pct``: the share that lies in some scope. An
+  operation's scope comes from ``DIR/qn_scopes.json``, which the
+  traced process writes from its compiled programs
+  (quintnet_tpu/obs/scopes.py); without the file everything reads
+  ``(no scope map)``;
+- ``idle_ms_by_span``: chip 0's idle time by the innermost ``qn.*``
+  host span that covered it (``qn.serve.wait``, ``qn.train.dispatch``:
+  the program's own spans, on the profiler's clock), with
+  ``named_share_pct``;
+- ``collective_ms_by_scope`` (a mesh): time of the collective
+  operations by scope, and ``exposed_ms``: the part during which no
+  other operation ran on that chip.
+
+The xplane is read with the benchmark's reader
+(benchmarks/lib/trace_reduce.read_trace), so both see one trace alike.
+
 Usage:
   python tools/trace_view.py DUMP.json -o trace.json
   python tools/trace_view.py DUMP.json            # stdout
+  python tools/trace_view.py --xplane DIR         # tables, stdout
 
 Library surface: :func:`chrome_trace` (dict in, dict out — the bench
 and tests call this), :func:`validate_chrome_trace` (structural check
@@ -45,9 +71,12 @@ used by CI so the export can never drift off-format).
 from __future__ import annotations
 
 import argparse
+import bisect
 import json
+import os
+import re
 import sys
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 _US = 1e6
 
@@ -199,6 +228,135 @@ def validate_chrome_trace(obj: Dict) -> int:
     return len(events)
 
 
+# ---------------------------------------------------------------------
+# --xplane: a profiler trace of a chip run, as tables
+# ---------------------------------------------------------------------
+NO_SCOPE = "(no scope)"
+NO_MAP = "(no scope map)"
+_OPCODE = re.compile(r"\s([a-z][\w\-]*)\(")
+_COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter",
+                "collective-permute", "all-to-all", "collective-broadcast")
+
+
+def opcode(event_name: str) -> str:
+    """``%psum.3 = bf16[8,128]{1,0} all-reduce(%x), ...`` ->
+    ``all-reduce``: a trace names an operation by its whole HLO line,
+    and the instruction's own name need not say what it is."""
+    rest = event_name.split(" = ", 1)[-1]
+    m = _OPCODE.search(" " + rest)
+    return m.group(1) if m else ""
+
+
+def _leaves(events: List[Tuple]) -> List[Tuple]:
+    """The events that enclose no other event (a ``while`` encloses the
+    operations of its body): the ones that occupy the device."""
+    out = []
+    ordered = sorted(events, key=lambda ev: (ev[1], -ev[2]))
+    for ev, nxt in zip(ordered, ordered[1:] + [None]):
+        if nxt is None or nxt[1] >= ev[2] or nxt[2] > ev[2]:
+            out.append(ev)
+    return out
+
+
+def _overlap(merged: List[Tuple[float, float]], starts: List[float],
+             s: float, e: float) -> float:
+    """Length of [s, e) covered by the disjoint sorted ``merged``."""
+    total = 0.0
+    i = max(bisect.bisect_right(starts, s) - 1, 0)
+    while i < len(merged) and merged[i][0] < e:
+        total += max(0.0, min(e, merged[i][1]) - max(s, merged[i][0]))
+        i += 1
+    return total
+
+
+def xplane_tables(path: str) -> Dict:
+    """The tables of ``--xplane`` (module docstring) as one dict."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmarks.lib import trace_reduce as tr
+    from quintnet_tpu.obs.scopes import SCOPES_FILE
+
+    trace_dir = path if os.path.isdir(path) else os.path.dirname(path)
+    xplane = tr.find_xplane(path) if os.path.isdir(path) else path
+    trace = tr.read_trace(xplane, span_prefix="qn.")
+    maps = None
+    scopes_path = os.path.join(trace_dir, SCOPES_FILE)
+    if os.path.isfile(scopes_path):
+        with open(scopes_path) as f:
+            maps = json.load(f)
+
+    # programs, window and idle-by-span are the benchmark's own
+    # reduction, here over the program's qn.* spans
+    reduced = tr.reduce_trace(trace, top=1000)
+    if reduced is None:
+        raise SystemExit(f"{xplane}: no operation ran on a device")
+    planes = [d for _n, d in sorted(trace["devices"].items()) if d["ops"]]
+    n = reduced["chips"]
+    by_scope: Dict[str, float] = {}
+    coll: Dict[str, List[float]] = {}      # scope -> [ns, exposed ns]
+    for d in planes:
+        mods = sorted((s, e, name.split("(", 1)[0])
+                      for name, s, e in d["modules"])
+        mod_starts = [m[0] for m in mods]
+
+        def scope_of(name: str, start: float) -> str:
+            if maps is None:
+                return NO_MAP
+            i = bisect.bisect_right(mod_starts, start) - 1
+            prog = mods[i][2] if i >= 0 and start < mods[i][1] else None
+            return maps.get(prog, {}).get(tr.short_name(name), NO_SCOPE)
+
+        # own time by scope: the scope rides in the name self_times
+        # keys on, so equal instruction names of two programs stay apart
+        keyed = [(scope_of(nm, s), s, e) for nm, s, e in d["ops"]]
+        for scope, ns in tr.self_times(keyed).items():
+            by_scope[scope] = by_scope.get(scope, 0.0) + ns
+        leaves = _leaves(d["ops"])
+        is_coll = [opcode(nm).startswith(_COLLECTIVES)
+                   for nm, _s, _e in leaves]
+        others = tr.merge([(s, e) for (nm, s, e), c
+                           in zip(leaves, is_coll) if not c])
+        starts = [iv[0] for iv in others]
+        for (nm, s, e), c in zip(leaves, is_coll):
+            if c:
+                rec = coll.setdefault(scope_of(nm, s), [0.0, 0.0])
+                rec[0] += e - s
+                rec[1] += (e - s) - _overlap(others, starts, s, e)
+
+    idle = {name: 1e9 * sec for name, sec in reduced["idle_gaps"]}
+
+    def table(d: Dict[str, float], scale: float) -> Dict[str, float]:
+        return {k: v / scale for k, v in
+                sorted(d.items(), key=lambda kv: -kv[1])}
+
+    def named_share(d: Dict[str, float], unnamed) -> float:
+        total = sum(d.values())
+        named = sum(v for k, v in d.items() if k not in unnamed)
+        return 100.0 * named / total if total else 0.0
+
+    host_names = sorted({nm for nm, _s, _e in trace["host_spans"]})
+    out = {
+        "xplane": xplane, "chips": n,
+        "window_ms": 1e3 * reduced["window_s"],
+        "scope_map": scopes_path if maps is not None else None,
+        "programs": {k: {"count": c, "ms": 1e3 * sec} for k, (c, sec)
+                     in sorted(reduced["modules"].items())},
+        "device_ms_by_scope": table(by_scope, 1e6 * n),
+        "device_named_share_pct": named_share(by_scope,
+                                              (NO_SCOPE, NO_MAP)),
+        "host_spans": host_names,
+        "idle_ms_by_span": table(idle, 1e6),
+        "idle_named_share_pct": named_share(
+            idle, ("unannotated", "between_ops")),
+    }
+    if n > 1:
+        out["collective_ms_by_scope"] = {
+            k: {"ms": v[0] / 1e6 / n, "exposed_ms": v[1] / 1e6 / n}
+            for k, v in sorted(coll.items(), key=lambda kv: -kv[1][0])}
+    return out
+
+
 def _load_dump(path: str) -> Dict:
     with open(path) as f:
         payload = json.load(f)
@@ -218,10 +376,25 @@ def main(argv=None) -> int:
         prog="trace_view",
         description="crash dump / obs dump -> Chrome trace-event JSON "
                     "(Perfetto)")
-    ap.add_argument("dump", help="crash-dump or obs-dump JSON file")
+    ap.add_argument("dump", nargs="?",
+                    help="crash-dump or obs-dump JSON file")
+    ap.add_argument("--xplane", default=None, metavar="DIR",
+                    help="a profiler trace directory (or *.xplane.pb): "
+                         "print per-program, per-scope, idle-by-span "
+                         "and collective tables as JSON")
     ap.add_argument("-o", "--out", default=None,
                     help="output file (default: stdout)")
     args = ap.parse_args(argv)
+    if (args.dump is None) == (args.xplane is None):
+        ap.error("give a DUMP.json or --xplane DIR (one of them)")
+    if args.xplane is not None:
+        text = json.dumps(xplane_tables(args.xplane), indent=1)
+        if args.out:
+            with open(args.out, "w") as f:
+                f.write(text)
+        else:
+            print(text)
+        return 0
 
     payload = _load_dump(args.dump)
     label = payload.get("replica") or "quintnet-serve"
