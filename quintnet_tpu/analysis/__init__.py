@@ -31,6 +31,7 @@ from quintnet_tpu.analysis.jaxpr_audit import (
     donation_report,
     dtype_report,
     gathered_view_gathers,
+    widened_view_dots,
 )
 from quintnet_tpu.analysis.lint import (
     RULES,
@@ -71,6 +72,7 @@ __all__ = [
     "donation_report",
     "dtype_report",
     "gathered_view_gathers",
+    "widened_view_dots",
     "RULES",
     "Violation",
     "collect_sources",

@@ -392,3 +392,49 @@ def gathered_view_gathers(fn: Callable, *args, num_blocks: int,
 
     _walk_skip_kernels(closed.jaxpr, visit)
     return found
+
+
+def widened_view_dots(fn: Callable, *args, table_width: int,
+                      block_size: int, **kwargs) -> int:
+    """Count the ``dot_general`` eqns that contract a gathered row view
+    in a dtype WIDER than the one it is stored in: one operand carries
+    a whole row's positions — a ``table_width * block_size`` dim (the
+    ``[B, H, T, Dh]`` view of `paged_gather`) or that dim still split
+    by block, ``table_width, block_size`` side by side — and its float
+    dtype is narrower than the other operand's. A TPU has no mixed
+    dot, so the compiler widens the BIG operand — a second, f32 copy
+    of every row written to HBM and read back each layer (at GPT-2
+    XL's serving shapes 75 of a 128-ms decode step).
+    nn/attention._masked_sdpa casts the small operand down instead, so
+    every paged program reads ZERO here, whatever the pool's dtype;
+    two a layer (scores and weighted values) is the mixed form.
+
+    Structural like :func:`gathered_view_gathers` (a scan body counts
+    once; ``pallas_call`` interiors are skipped). CALLER CONTRACT: pick
+    a geometry whose row length collides with no other dim of a dot
+    operand of rank 4 or more."""
+    closed = jax.make_jaxpr(fn)(*args, **kwargs)
+    row = table_width * block_size
+    found = 0
+
+    def is_view(aval) -> bool:
+        shape = tuple(aval.shape)
+        return len(shape) >= 4 and (
+            row in shape
+            or (table_width, block_size) in zip(shape, shape[1:]))
+
+    def visit(eqn):
+        nonlocal found
+        if eqn.primitive.name != "dot_general":
+            return
+        a, b = (v.aval for v in eqn.invars[:2])
+        for view, other in ((a, b), (b, a)):
+            if (is_view(view)
+                    and jax.dtypes.issubdtype(view.dtype, np.floating)
+                    and jax.dtypes.issubdtype(other.dtype, np.floating)
+                    and view.dtype.itemsize < other.dtype.itemsize):
+                found += 1
+                return
+
+    _walk_skip_kernels(closed.jaxpr, visit)
+    return found

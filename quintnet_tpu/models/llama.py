@@ -32,8 +32,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from quintnet_tpu.core.pytree import tree_stack
-from quintnet_tpu.nn.attention import (apply_rope, repeat_kv, rope_cos_sin,
-                                       sdpa)
+from quintnet_tpu.nn.attention import (_masked_sdpa, apply_rope, repeat_kv,
+                                       rope_cos_sin, sdpa)
 from quintnet_tpu.nn.layers import (cast_floating, linear_init,
                                     quantized_matmul, rms_norm_apply,
                                     rms_norm_init, swiglu_apply,
@@ -530,16 +530,10 @@ def llama_block_prefill_paged(p, x, kc, vc, positions, tail_len,
                 block_size=block_size, max_blocks=span)
             pools = (kc, vc, ks, vs)
         rep = q.shape[1] // kg.shape[1]
-        kf, vf = repeat_kv(kg, rep), repeat_kv(vg, rep)
-        valid = (jnp.arange(kf.shape[2])[None, :]
+        valid = (jnp.arange(kg.shape[2])[None, :]
                  <= positions[:, None])[None, None]      # [1,1,P,M*bs]
-        scores = (jnp.einsum("bhqd,bhtd->bhqt", q,
-                             kf).astype(jnp.float32)
-                  / math.sqrt(cfg.head_dim))
-        scores = jnp.where(valid, scores, jnp.finfo(jnp.float32).min)
-        o = jnp.einsum("bhqt,bhtd->bhqd",
-                       jax.nn.softmax(scores,
-                                      axis=-1).astype(q.dtype), vf)
+        o = _masked_sdpa(q, repeat_kv(kg, rep), repeat_kv(vg, rep), valid,
+                         page=block_size)
     x = llama_attn_residual(p["attn"], x, o, tp_axis=tp_axis,
                             lora=attn_lora, lora_scale=lora_scale)
     x, _aux, stats = llama_mlp_residual(
@@ -660,16 +654,10 @@ def llama_block_verify_paged(p, x, kc, vc, positions, tail_lens,
                 max_blocks=span)
             pools = (kc, vc, ks, vs)
         rep = q.shape[1] // kg.shape[1]
-        kf, vf = repeat_kv(kg, rep), repeat_kv(vg, rep)
-        valid = (jnp.arange(kf.shape[2])[None, None, :]
+        valid = (jnp.arange(kg.shape[2])[None, None, :]
                  <= positions[:, :, None])[:, None]   # [S, 1, P, M*bs]
-        scores = (jnp.einsum("bhqd,bhtd->bhqt", q,
-                             kf).astype(jnp.float32)
-                  / math.sqrt(cfg.head_dim))
-        scores = jnp.where(valid, scores, jnp.finfo(jnp.float32).min)
-        o = jnp.einsum("bhqt,bhtd->bhqd",
-                       jax.nn.softmax(scores,
-                                      axis=-1).astype(q.dtype), vf)
+        o = _masked_sdpa(q, repeat_kv(kg, rep), repeat_kv(vg, rep), valid,
+                         page=block_size)
     x = llama_attn_residual(p["attn"], x, o, tp_axis=tp_axis,
                             lora=attn_lora, lora_scale=lora_scale)
     x, _aux, stats = llama_mlp_residual(
@@ -778,13 +766,7 @@ def llama_block_decode(p, x, kc, vc, pos, cfg: LlamaConfig, cos, sin,
         valid = (jnp.arange(kf.shape[2])[None, :]
                  <= pos[:, None])[:, None, None, :]
     if kf is not None:
-        scores = (jnp.einsum("bhqd,bhtd->bhqt", q,
-                             kf).astype(jnp.float32)
-                  / math.sqrt(cfg.head_dim))
-        scores = jnp.where(valid, scores, jnp.finfo(jnp.float32).min)
-        o = jnp.einsum("bhqt,bhtd->bhqd",
-                       jax.nn.softmax(scores,
-                                      axis=-1).astype(q.dtype), vf)
+        o = _masked_sdpa(q, kf, vf, valid, page=block_size)
     x = llama_attn_residual(p["attn"], x, o, tp_axis=tp_axis,
                             lora=attn_lora, lora_scale=lora_scale)
     x, _aux, stats = llama_mlp_residual(

@@ -103,19 +103,82 @@ def _proj_out(p, o, tp_axis: Optional[str], lora=None, lora_scale=None):
     return y
 
 
-def _masked_sdpa(q, k_all, v_all, valid):
+def _as_stored(x, view):
+    """``x`` in the dtype a cached ``view`` is STORED in, where that is
+    a float narrower than ``x``'s that a dot takes natively (bf16,
+    f16); ``x`` itself otherwise. The view is the big operand — a
+    mixed-dtype dot makes the compiler widen IT, a second copy of every
+    row in HBM each layer — so the small operand goes down instead."""
+    if (view.dtype in (jnp.bfloat16, jnp.float16)
+            and jnp.issubdtype(x.dtype, jnp.floating)
+            and x.dtype.itemsize > view.dtype.itemsize):
+        return x.astype(view.dtype)
+    return x
+
+
+# rows a lone query is padded to: one sublane tile of the matrix unit
+_MIN_DOT_ROWS = 8
+
+
+def _masked_sdpa(q, k_all, v_all, valid, *, page: Optional[int] = None):
     """The score math every cached path shares: q [B, H, S, Dh] against
     a whole row's keys and values [B, H, T, Dh], softmax in f32 over
     the columns ``valid`` (broadcastable to [B, H, S, T]) allows.
-    Scope ``sdpa``."""
+    Scope ``sdpa``.
+
+    Both contractions take the view in the dtype it is STORED in and
+    accumulate in f32 (:func:`_as_stored`): ``q`` and the probabilities
+    are rounded to a bf16 view's dtype — the arithmetic the matrix unit
+    gives every other f32 x bf16 product at default precision. An f32
+    view (every f32 pool, every dequantized one) takes the plain branch
+    it always took. Two more steps make the stored bytes the only copy
+    of the view a TPU program reads (each was measured on the v5e:
+    PERF.md, PR 26):
+
+    - a contraction with ONE query row is a vector-matrix product,
+      which the compiler runs as an f32 multiply-reduce over an f32
+      copy of the view whatever the operands' dtype; a lone row is
+      padded with zero rows to :data:`_MIN_DOT_ROWS` and stays a matmul
+      (the pad rows see the same mask, and are dropped);
+    - ``page``: the view is a gather of pool blocks of ``page``
+      positions each (:func:`paged_gather`; the paged callers pass
+      their ``block_size``). A matmul over ``[T, Dh]`` wants a block's
+      positions beside the next block's, so the compiler transposes
+      the gathered view in HBM first; contracted block by block,
+      ``[T // page, page, Dh]``, it is read as the gather left it.
+
+    The output keeps the dtype ``q`` and the view promote to (``q``'s,
+    for a narrower view)."""
     with jax.named_scope("sdpa"):
-        dh = q.shape[-1]
-        scores = jnp.einsum("bhsd,bhtd->bhst", q,
-                            k_all).astype(jnp.float32)
+        b, h, s, dh = q.shape
+        t = k_all.shape[2]
+        out_dtype = jnp.result_type(q, v_all)
+        qs = _as_stored(q, k_all)
+        stored = qs is not q
+        by_page = stored and bool(page)
+        if stored and s == 1:
+            qs = jnp.pad(qs, ((0, 0), (0, 0), (0, _MIN_DOT_ROWS - s), (0, 0)))
+        if by_page:
+            k_all = k_all.reshape(b, h, t // page, page, dh)
+            v_all = v_all.reshape(b, h, t // page, page, dh)
+            scores = jnp.einsum("bhsd,bhmtd->bhsmt", qs, k_all,
+                                preferred_element_type=jnp.float32
+                                ).reshape(b, h, -1, t)
+        else:
+            scores = jnp.einsum("bhsd,bhtd->bhst", qs, k_all,
+                                preferred_element_type=jnp.float32)
         scores = scores / math.sqrt(dh)
         scores = jnp.where(valid, scores, jnp.finfo(jnp.float32).min)
-        probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-        return jnp.einsum("bhst,bhtd->bhsd", probs, v_all)
+        probs = _as_stored(
+            jax.nn.softmax(scores, axis=-1).astype(q.dtype), v_all)
+        if by_page:
+            o = jnp.einsum("bhsmt,bhmtd->bhsd",
+                           probs.reshape(b, h, -1, t // page, page), v_all,
+                           preferred_element_type=jnp.float32)
+        else:
+            o = jnp.einsum("bhst,bhtd->bhsd", probs, v_all,
+                           preferred_element_type=jnp.float32)
+        return o[:, :, :s].astype(out_dtype)
 
 
 def sdpa(q, k, v, *, causal: bool, softmax_dtype=jnp.float32,
@@ -573,7 +636,8 @@ def mha_prefill_paged(p, x, k_cache, v_cache, positions, tail_len, *,
                 max_blocks=span)
         valid = (jnp.arange(k_all.shape[2])[None, :]
                  <= positions[:, None])               # [P, M*bs]
-        o = _masked_sdpa(q, k_all, v_all, valid[None, None])
+        o = _masked_sdpa(q, k_all, v_all, valid[None, None],
+                         page=block_size)
 
     y = _proj_out(p, o, tp_axis, lora, lora_scale)
     if kv_scales is not None:
@@ -849,7 +913,7 @@ def mha_verify_paged(p, x, k_cache, v_cache, positions, tail_lens, *,
                 max_blocks=span)
         valid = (jnp.arange(k_all.shape[2])[None, None, :]
                  <= positions[:, :, None])                # [S, P, T]
-        o = _masked_sdpa(q, k_all, v_all, valid[:, None])
+        o = _masked_sdpa(q, k_all, v_all, valid[:, None], page=block_size)
 
     y = _proj_out(p, o, tp_axis, lora, lora_scale)
     if kv_scales is not None:
@@ -960,7 +1024,8 @@ def mha_decode(p, x, k_cache, v_cache, pos, *, num_heads: int,
         valid = jnp.arange(k_all.shape[2])[None, :] <= pos[:, None]
 
     if k_all is not None:
-        o = _masked_sdpa(q, k_all, v_all, valid[:, None, None, :])
+        o = _masked_sdpa(q, k_all, v_all, valid[:, None, None, :],
+                         page=block_size)
 
     y = _proj_out(p, o, tp_axis, lora, lora_scale)
     if kv_scales is not None:

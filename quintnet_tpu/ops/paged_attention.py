@@ -34,8 +34,10 @@ Oracle contract (what the parity tests pin, tests/test_paged_attention
 operation — dequantized blocks assemble into full-row K/V VMEM
 scratch, then the IDENTICAL head-batched score dot / ``/ sqrt(dh)`` /
 mask / ``jax.nn.softmax`` / ``probs @ V`` sequence the XLA path
-runs — so f32 and fake_quant outputs are BIT-exact against the oracle
-and bf16/int8 hold to a pinned tolerance. For scaled policies the
+runs — including its rounding of ``q`` and the probabilities to a bf16
+pool's dtype before the f32-accumulating dots — so f32 and fake_quant
+outputs are BIT-exact against the oracle and bf16/int8 hold to a
+pinned tolerance. For scaled policies the
 kernel reads the PRE-write pool and overrides the current run's
 columns with the exact f32 fresh K/V (the oracle scores the
 post-insert f32 view, not the quantized round-trip), and :func:`paged_quant_window_update` then requantizes
@@ -84,6 +86,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from quintnet_tpu.nn.attention import _as_stored
 
 # Module-level interpret switch, read when ``paged_attention`` is
 # called without ``interpret=``. False = lower the real Mosaic kernel.
@@ -239,7 +243,12 @@ def _kernel(tbl_ref, st_ref, *refs, block_size: int, n_queries: int,
         # variant was measured 1-2 ulp off, this one is bit-exact),
         # then scores / sqrt(dh) -> positional mask to finfo.min ->
         # jax.nn.softmax -> probs @ V
-        qf = q_ref[0].astype(jnp.float32)           # [Hq, P, Dh]
+        # the oracle rounds q and the probabilities to a bf16 pool's
+        # dtype before its f32-accumulating dots (nn/attention
+        # _as_stored); products of bf16 values are exact in f32, so
+        # the same rounding followed by f32 dots is the same math. An
+        # f32 or scaled (int8) pool rounds nothing
+        qf = _as_stored(q_ref[0], k_ref).astype(jnp.float32)  # [Hq, P, Dh]
         kr = _rep_heads(k_scr[pl.ds(0, T)], rep)    # [Hq, T, Dh]
         sc = jax.lax.dot_general(
             qf, kr, (((2,), (2,)), ((0,), (0,))),
@@ -252,7 +261,7 @@ def _kernel(tbl_ref, st_ref, *refs, block_size: int, n_queries: int,
         probs = jax.nn.softmax(sc, axis=-1).astype(o_ref.dtype)
         vr = _rep_heads(v_scr[pl.ds(0, T)], rep)    # [Hq, T, Dh]
         o_ref[0] = jax.lax.dot_general(
-            probs.astype(jnp.float32), vr,
+            _as_stored(probs, v_ref).astype(jnp.float32), vr,
             (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32).astype(o_ref.dtype)
 

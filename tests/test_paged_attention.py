@@ -24,6 +24,11 @@ The contract ladder:
    (analysis.gathered_view_gathers) proves the pallas programs issue
    ZERO full-row block-table gathers where the xla ones issue 2-4 per
    layer; compile counts and sentinels are unchanged per backend.
+5. **Arithmetic contract** — every gathered-view program contracts the
+   view in the dtype it is STORED in (analysis.widened_view_dots reads
+   zero for f32 and bf16 pools, gpt2 and llama), a bf16 pool's decode
+   stays inside the bound 8 bits of ``q`` and of the probabilities
+   earn, and an f32 pool's program rounds and pads nothing.
 """
 
 import math
@@ -33,7 +38,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from quintnet_tpu.analysis import gathered_view_gathers
+from quintnet_tpu.analysis import (gathered_view_gathers,
+                                   widened_view_dots)
 from quintnet_tpu.analysis.specs import attn_kernels, kv_layout_policies
 from quintnet_tpu.models.gpt2 import GPT2Config, gpt2_init
 from quintnet_tpu.serve import ServeEngine, SpecConfig, gpt2_family
@@ -357,25 +363,30 @@ class TestEngineGoldens:
 # 4. structural win + validation + import surface
 # ---------------------------------------------------------------------
 
+def _program_args(eng, params, which, bucket=None):
+    """Arguments of one engine program (decode / verify bucket /
+    prefill bucket), for tracing."""
+    caches = eng.pool.caches()
+    if which == "decode":
+        return (params, *caches, jnp.asarray(eng._tok),
+                jnp.asarray(eng._pos), jnp.asarray(eng._tables),
+                jnp.asarray(eng._key_data))
+    if which == "verify":
+        S = eng.max_slots
+        ids = np.zeros((S, bucket + 1), np.int32)
+        return (params, *caches, jnp.asarray(ids),
+                jnp.asarray(eng._pos),
+                jnp.asarray(np.ones(S, np.int32)),
+                jnp.asarray(eng._tables), jnp.asarray(eng._key_data))
+    ids = np.zeros((1, bucket), np.int32)
+    row = np.zeros((eng.table_width,), np.int32)
+    return (params, *caches, jnp.asarray(ids), jnp.int32(1),
+            jnp.int32(3), jnp.asarray(row), jnp.int32(0),
+            jnp.int32(0), jnp.asarray(eng._key_data[0]))
+
+
 class TestStructure:
-    def _args(self, eng, params, which, bucket=None):
-        caches = eng.pool.caches()
-        if which == "decode":
-            return (params, *caches, jnp.asarray(eng._tok),
-                    jnp.asarray(eng._pos), jnp.asarray(eng._tables),
-                    jnp.asarray(eng._key_data))
-        if which == "verify":
-            S = eng.max_slots
-            ids = np.zeros((S, bucket + 1), np.int32)
-            return (params, *caches, jnp.asarray(ids),
-                    jnp.asarray(eng._pos),
-                    jnp.asarray(np.ones(S, np.int32)),
-                    jnp.asarray(eng._tables), jnp.asarray(eng._key_data))
-        ids = np.zeros((1, bucket), np.int32)
-        row = np.zeros((eng.table_width,), np.int32)
-        return (params, *caches, jnp.asarray(ids), jnp.int32(1),
-                jnp.int32(3), jnp.asarray(row), jnp.int32(0),
-                jnp.int32(0), jnp.asarray(eng._key_data[0]))
+    _args = staticmethod(_program_args)
 
     @pytest.mark.parametrize("kv_dtype", ("f32", "int8"))
     def test_pallas_issues_zero_gathered_view_gathers(self, params,
@@ -476,6 +487,189 @@ class TestStructure:
             paged_attention(q, pool, pool, _tables()[:1],
                             jnp.zeros((1,), jnp.int32), block_size=BS,
                             kv_scales=(sc, sc))
+
+
+# ---------------------------------------------------------------------
+# 5. the arithmetic contract: the view is contracted as it is stored
+# ---------------------------------------------------------------------
+
+def _eqns(closed):
+    """Every eqn of a traced program, sub-jaxprs (pjit, scan) included."""
+    from quintnet_tpu.analysis.jaxpr_audit import _walk_skip_kernels
+
+    found = []
+    _walk_skip_kernels(closed.jaxpr, found.append)
+    return found
+
+
+class TestStoredDtypeContract:
+    # a row of 48 positions: collides with no dim of either tiny model
+    # (widths 32 / 64 / 96 / 128) — the auditor's caller contract
+    SEQ = 48
+
+    @pytest.fixture(scope="class")
+    def engines(self, params):
+        """(family, kv_dtype) -> (engine, its params), built on first
+        use: tracing only, nothing compiles."""
+        from quintnet_tpu.models.llama import LlamaConfig, llama_init
+        from quintnet_tpu.serve import llama_family
+
+        built = {}
+
+        def get(family, kv_dtype):
+            if (family, kv_dtype) not in built:
+                fam, p = None, params
+                if family == "llama":
+                    cfg = LlamaConfig.tiny()
+                    fam, p = llama_family(cfg), llama_init(
+                        jax.random.key(4), cfg)
+                eng = _engine(params, "xla", family=fam, fam_params=p,
+                              kv_dtype=kv_dtype, max_seq_len=self.SEQ,
+                              spec=SpecConfig(max_draft=4))
+                built[family, kv_dtype] = (eng, p)
+            return built[family, kv_dtype]
+
+        return get
+
+    @pytest.mark.parametrize("kv_dtype", ("f32", "bf16"))
+    @pytest.mark.parametrize("family", ("gpt2", "llama"))
+    @pytest.mark.parametrize("which", ("decode", "verify", "prefill"))
+    def test_no_dot_widens_the_view(self, engines, which, family,
+                                    kv_dtype):
+        """The mixed form (f32 q against a bf16 view) reads 2 here: a
+        TPU compile would write an f32 copy of both views a layer."""
+        eng, p = engines(family, kv_dtype)
+        fn, bucket = {
+            "decode": (eng._decode.fn, None),
+            "verify": (eng._verifies[2].fn, 2),
+            "prefill": (eng._prefills[eng.prefill_buckets[0]].fn,
+                        eng.prefill_buckets[0])}[which]
+        bs = eng.pool.block_size
+        assert eng.table_width * bs == self.SEQ
+        assert widened_view_dots(
+            fn, *_program_args(eng, p, which, bucket),
+            table_width=eng.table_width, block_size=bs) == 0
+
+    def test_counter_sees_the_mixed_form(self):
+        """The zeroes above mean something: the same two contractions
+        written mixed count 2, cast down count 0."""
+        q = jnp.zeros((S, H, 1, D), jnp.float32)
+        view = jnp.zeros((S, H, M * BS, D), jnp.bfloat16)
+
+        def mixed(q, k, v):
+            pr = jnp.einsum("bhsd,bhtd->bhst", q, k)
+            return jnp.einsum("bhst,bhtd->bhsd", pr, v)
+
+        def stored(q, k, v):
+            pr = jnp.einsum("bhsd,bhtd->bhst", q.astype(k.dtype), k,
+                            preferred_element_type=jnp.float32)
+            return jnp.einsum("bhst,bhtd->bhsd", pr.astype(v.dtype), v,
+                              preferred_element_type=jnp.float32)
+
+        def mixed_by_page(q, k, v):
+            k, v = (x.reshape(S, H, M, BS, D) for x in (k, v))
+            pr = jnp.einsum("bhsd,bhmtd->bhsmt", q, k)
+            return jnp.einsum("bhsmt,bhmtd->bhsd", pr, v)
+
+        kw = dict(table_width=M, block_size=BS)
+        assert widened_view_dots(mixed, q, view, view, **kw) == 2
+        assert widened_view_dots(mixed_by_page, q, view, view, **kw) == 2
+        assert widened_view_dots(stored, q, view, view, **kw) == 0
+        f32 = view.astype(jnp.float32)
+        assert widened_view_dots(mixed, q, f32, f32, **kw) == 0
+
+    def _decode_inputs(self, pool_dtype):
+        rng = np.random.default_rng(21)
+        attn = _mha_params(jax.random.key(5))
+        x = jnp.asarray(rng.standard_normal((S, 1, H * D)), jnp.float32)
+        kp = jnp.asarray(rng.standard_normal((NB * BS, H, D)), pool_dtype)
+        vp = jnp.asarray(rng.standard_normal((NB * BS, H, D)), pool_dtype)
+        pos = jnp.asarray([5, 0, 17], jnp.int32)
+        return attn, x, kp, vp, pos
+
+    @pytest.mark.parametrize("pool_dtype", ("bfloat16", "float16"))
+    def test_decode_inside_the_bound_rounding_earns(self, pool_dtype):
+        """mha_decode on a narrow pool against plain f32 math on the
+        SAME stored K and V. What differs is that q and the
+        probabilities are rounded to the pool's dtype before products
+        that accumulate in f32, each with relative error u = 2^-p at
+        most (p = 8 significand bits for bf16, 11 for f16):
+
+        - a score s_t = q.k_t / sqrt(Dh) moves by at most
+          e_t = u * sum_d |q_d k_td| / sqrt(Dh);
+        - softmax: dp_t = p_t (ds_t - sum_j p_j ds_j), so
+          |dp_t| <= p_t (e_t + sum_j p_j e_j); rounding p_t adds u p_t;
+        - o_d = sum_t p_t v_td moves by at most sum_t |dp_t| |v_td|;
+        - y_j = sum_i o_i W_ij + b_j by at most sum_i |do_i| |W_ij|.
+
+        First order in u; a tenth of room covers the second order and
+        the f32 sums."""
+        from quintnet_tpu.nn.attention import _qkv_heads, mha_decode
+
+        dt = jnp.dtype(pool_dtype)
+        attn, x, kp, vp, pos = self._decode_inputs(dt)
+        tables = _tables()
+        y, kp2, vp2 = jax.jit(
+            lambda x, kp, vp: mha_decode(
+                attn, x, kp, vp, pos, num_heads=H, block_tables=tables,
+                block_size=BS))(x, kp, vp)
+        assert y.dtype == jnp.float32
+
+        q, _k, _v = _qkv_heads(attn, x, H)
+        q = np.asarray(q, np.float64)[:, :, 0]              # [S, H, D]
+        rows = (np.asarray(tables)[:, :, None] * BS
+                + np.arange(BS)[None, None, :]).reshape(S, M * BS)
+        k = np.asarray(kp2.astype(jnp.float32), np.float64)[rows]
+        v = np.asarray(vp2.astype(jnp.float32), np.float64)[rows]
+        k, v = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
+        live = (np.arange(M * BS)[None, :]
+                <= np.asarray(pos)[:, None])[:, None]       # [S, 1, T]
+        sc = np.einsum("shd,shtd->sht", q, k) / math.sqrt(D)
+        sc = np.where(live, sc, -np.inf)
+        pr = np.exp(sc - sc.max(-1, keepdims=True))
+        pr /= pr.sum(-1, keepdims=True)
+        o = np.einsum("sht,shtd->shd", pr, v).reshape(S, H * D)
+        w = np.asarray(attn["proj"]["w"], np.float64)
+        want = o @ w + np.asarray(attn["proj"]["b"], np.float64)
+
+        u = 2.0 ** -(jnp.finfo(dt).nmant + 1)
+        e = u * np.einsum("shd,shtd->sht", np.abs(q),
+                          np.abs(k)) / math.sqrt(D)
+        dp = pr * (e + (pr * e).sum(-1, keepdims=True) + u)
+        do = np.einsum("sht,shtd->shd", dp, np.abs(v)).reshape(S, H * D)
+        bound = 1.1 * do @ np.abs(w)
+        gap = np.abs(np.asarray(y, np.float64)[:, 0] - want)
+        assert (gap <= bound).all(), (gap.max(), bound.max())
+        # and the bound says something: under a tenth of the output at
+        # bf16 (worst case: every error the same sign), and the
+        # rounding is really there (f32 products on the same K and V
+        # would sit at 1e-7)
+        assert bound.max() < 0.1 * np.abs(want).max(), bound.max()
+        assert gap.max() > 1e-6 * np.abs(want).max(), gap.max()
+
+    @pytest.mark.parametrize("pool_dtype,narrowed", (("float32", False),
+                                                     ("bfloat16", True)))
+    def test_f32_pool_rounds_and_pads_nothing(self, pool_dtype, narrowed):
+        """An f32 view takes the branch it always took: no convert of q
+        or of the probabilities to a 16-bit float, no pad of the query
+        row. The bf16 pool is the positive control: q and the
+        probabilities go down, the lone row is padded once."""
+        from quintnet_tpu.nn.attention import mha_decode
+
+        attn, x, kp, vp, pos = self._decode_inputs(jnp.dtype(pool_dtype))
+        jaxpr = jax.make_jaxpr(
+            lambda x, kp, vp: mha_decode(
+                attn, x, kp, vp, pos, num_heads=H,
+                block_tables=_tables(), block_size=BS))(x, kp, vp)
+        eqns = _eqns(jaxpr)
+        down = [e for e in eqns
+                if e.primitive.name == "convert_element_type"
+                and e.invars[0].aval.dtype == jnp.float32
+                and e.params["new_dtype"].itemsize == 2
+                and e.invars[0].aval.ndim == 4]
+        pads = [e for e in eqns if e.primitive.name == "pad"]
+        assert len(down) == (2 if narrowed else 0), down
+        assert len(pads) == (1 if narrowed else 0), pads
 
 
 def test_ops_import_surface():
