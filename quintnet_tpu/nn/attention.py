@@ -120,11 +120,14 @@ def _as_stored(x, view):
 _MIN_DOT_ROWS = 8
 
 
-def _masked_sdpa(q, k_all, v_all, valid, *, page: Optional[int] = None):
+def _masked_sdpa(q, k_all, v_all, valid, *, page: Optional[int] = None,
+                 scale: Optional[float] = None):
     """The score math every cached path shares: q [B, H, S, Dh] against
     a whole row's keys and values [B, H, T, Dh], softmax in f32 over
     the columns ``valid`` (broadcastable to [B, H, S, T]) allows.
-    Scope ``sdpa``.
+    Scope ``sdpa``. ``scale`` multiplies the scores where a model
+    states its own (``attention_multiplier``); None divides them by
+    ``sqrt(Dh)``.
 
     Both contractions take the view in the dtype it is STORED in and
     accumulate in f32 (:func:`_as_stored`): ``q`` and the probabilities
@@ -167,7 +170,8 @@ def _masked_sdpa(q, k_all, v_all, valid, *, page: Optional[int] = None):
         else:
             scores = jnp.einsum("bhsd,bhtd->bhst", qs, k_all,
                                 preferred_element_type=jnp.float32)
-        scores = scores / math.sqrt(dh)
+        scores = (scores / math.sqrt(dh) if scale is None
+                  else scores * scale)
         scores = jnp.where(valid, scores, jnp.finfo(jnp.float32).min)
         probs = _as_stored(
             jax.nn.softmax(scores, axis=-1).astype(q.dtype), v_all)

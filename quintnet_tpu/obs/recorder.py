@@ -74,6 +74,10 @@ class StepRecord:
     host_syncs: int = 0         # blocking device-to-host reads
     h2d_bytes: int = 0          # bytes of the host arrays uploaded
     context_tokens: int = 0     # sum of positions over the decoding slots
+    # bytes of per-slot recurrent state the step's programs read and
+    # wrote (2 x state_bytes_per_slot a decoding slot and a prefill
+    # program); 0 for a KV-only family
+    state_bytes: int = 0
     attrs: Dict = field(default_factory=dict)
 
     def to_dict(self) -> Dict:

@@ -24,12 +24,16 @@ from typing import Dict, Iterable
 
 # The scope vocabulary. Model programs: embed, blocks (the layer scan),
 # per block attn (qkv, sdpa or paged_attn, kv_gather, kv_write, proj)
-# and mlp, then final_norm, lm_head, loss, sample. The train step:
+# and mlp, then final_norm, lm_head, loss, sample; a Mamba-2 block:
+# mamba (in_proj, conv, ssd, state_update, gate_norm, out_proj). The
+# train step:
 # grads, grad_reduce, grad_clip, optimizer. ``rematted_computation`` is
 # jax.checkpoint's own mark on what the backward pass recomputes.
 SCOPES = frozenset({
     "embed", "blocks", "attn", "qkv", "sdpa", "paged_attn", "kv_gather",
     "kv_write", "proj", "mlp", "final_norm", "lm_head", "loss", "sample",
+    "mamba", "in_proj", "conv", "ssd", "state_update", "gate_norm",
+    "out_proj",
     "grads", "grad_reduce", "grad_clip", "optimizer",
     "rematted_computation",
 })

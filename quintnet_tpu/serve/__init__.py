@@ -61,7 +61,9 @@ engine and emits a one-line JSON throughput/latency report.
 from quintnet_tpu.serve.adapters import AdapterEntry, AdapterRegistry
 from quintnet_tpu.serve.api import generate, generate_stream
 from quintnet_tpu.serve.engine import (ServeEngine, check_admissible)
-from quintnet_tpu.serve.families import gpt2_family, llama_family
+from quintnet_tpu.serve.families import (gpt2_family,
+                                          granite_hybrid_family,
+                                          llama_family)
 from quintnet_tpu.serve.kv_pool import AdmitPlan, KVPool
 from quintnet_tpu.serve.kv_quant import (KVLayoutPolicy, LayoutPolicy,
                                          make_policy)
@@ -95,6 +97,7 @@ __all__ = [
     "generate",
     "generate_stream",
     "gpt2_family",
+    "granite_hybrid_family",
     "llama_family",
     "make_policy",
     "make_weight_policy",

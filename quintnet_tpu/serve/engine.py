@@ -88,6 +88,19 @@ whatever the prefix cache still holds); the continuation samples from
 the checkpointed key state, so even sampled runs survive eviction
 bit-identically.
 
+Recurrent families (``family.state`` set — state-space layers among
+attention ones, serve/families.granite_hybrid_family): the pool keeps a
+fixed-size state per SLOT beside the blocks (serve/kv_pool.py), every
+program carries the two state buffers beside the pools (donated,
+updated in place), and prefill takes the slot's index. What assumes a
+sequence is its KV alone — the prefix cache, the host tier, chain
+export/import, speculation, adapters, a mesh — is refused at
+construction with the missing piece named (:func:`_refuse_for_state`).
+Preemption and migration re-prefill ``prompt + generated`` from
+position 0 through the chunk program: correct to the arithmetic, not
+bit-equal to the run that was interrupted (docs/serving.md, "Recurrent
+families").
+
 All host<->device traffic per step is O(max_slots) scalars plus the
 sampled tokens — the pool and parameters never leave the device. Under
 a TP mesh the whole step runs in one shard_map (head-sharded pool,
@@ -187,6 +200,53 @@ def check_admissible(prompt_len: int, max_new_tokens: int, *,
             f"usable (block_size={block_size})")
 
 
+def _refuse_for_state(family: Family, **asked) -> None:
+    """A recurrent family's sequence is its KV AND its per-slot state.
+    Every feature below moves, shares or rolls back KV alone; each is
+    refused with the piece it lacks, none falls back quietly."""
+    missing = {
+        "prefix_cache": (
+            "prefix_cache=True",
+            "a cached block chain is reusable only with the recurrent "
+            "state AT ITS LAST BLOCK'S BOUNDARY, and no state snapshots "
+            "are kept there; pass prefix_cache=False"),
+        "kv_tier": (
+            "kv_tier_bytes > 0",
+            "the host tier spills the prefix cache, which needs state "
+            "snapshots at block boundaries"),
+        "spec": (
+            "spec (speculative decoding)",
+            "a rejected draft rolls its KV blocks back, and nothing "
+            "rolls the recurrent state back"),
+        "adapters": (
+            "adapters",
+            "LoRA deltas are not plumbed through the Mamba-2 "
+            "projections"),
+        "mesh": (
+            "a tp or sp mesh",
+            "the recurrent state and the Mamba-2 projections are not "
+            "head-sharded, and the chunked scan has no ring form"),
+        "pallas": (
+            "attn_kernel='pallas'",
+            "the fused kernel divides the scores by sqrt(head_dim); "
+            "this family states its own score scale"),
+        "kv_chain": (
+            "export_kv_chain / import_kv_chain",
+            "the handoff payload carries KV blocks and no recurrent "
+            "state"),
+        "prefill_only": (
+            "prefill_only (the disaggregated prefill phase)",
+            "a prefill-phase retirement hands off its KV chain, and "
+            "the chain carries no recurrent state"),
+    }
+    for key, on in asked.items():
+        if on:
+            what, why = missing[key]
+            raise NotImplementedError(
+                f"family {family.name!r} keeps recurrent state beside "
+                f"its KV; {what} is refused: {why} (ROADMAP M4)")
+
+
 class ServeEngine:
     def __init__(self, family: Family, params, *, max_slots: int = 8,
                  block_size: int = 16, num_blocks: int = 64,
@@ -218,6 +278,14 @@ class ServeEngine:
         self.family = family
         self.params = params
         self.max_slots = int(max_slots)
+        self._recurrent = family.state is not None
+        if self._recurrent:
+            _refuse_for_state(
+                family, prefix_cache=prefix_cache,
+                kv_tier=int(kv_tier_bytes) > 0,
+                spec=spec not in (None, False),
+                adapters=adapters not in (None, False),
+                mesh=mesh is not None, pallas=attn_kernel == "pallas")
         self.eos_token_id = eos_token_id
         self.temperature = float(temperature)
         self.top_k = int(top_k)
@@ -570,7 +638,8 @@ class ServeEngine:
             head_dim=family.head_dim, block_size=block_size,
             num_blocks=num_blocks, policy=self.kv_policy,
             sharding=sharding, scale_sharding=scale_sharding,
-            prefix_cache=self.prefix_cache, host_tier=self.kv_tier)
+            prefix_cache=self.prefix_cache, host_tier=self.kv_tier,
+            state=family.state, max_slots=self.max_slots)
         # per-step promotion budget in BLOCKS (Sarathi's budget
         # discipline applied to host->device memcpy): default 4 blocks
         # a step — enough to drain typical chains in a few steps
@@ -633,7 +702,9 @@ class ServeEngine:
         # output slot that is not already covered — donating them would
         # only earn XLA's "not usable" warning.) Indices shift with the
         # pool-arg count: scaled KV policies carry 4 pool buffers
-        # (k, v, k_scale, v_scale), passthrough ones 2.
+        # (k, v, k_scale, v_scale), a recurrent family 4 (k, v, ssm,
+        # conv), passthrough KV-only ones 2. (A recurrent prefill's
+        # slot index follows key_data: no index moves.)
         n_pool = len(self.pool.caches())
         pool_idx = tuple(range(1, n_pool + 1))
         self._prefills: Dict[int, RecompileSentinel] = {
@@ -703,6 +774,11 @@ class ServeEngine:
             param_bytes=sum(int(x.nbytes)
                             for x in jax.tree.leaves(self.params)),
             kv_bytes_per_token=self.pool.bytes_per_token,
+            # a recurrent family's fixed cost per slot, and the kinds
+            # of its layers in model order (0 and None for the rest)
+            state_bytes_per_slot=self.pool.state_bytes_per_slot,
+            layer_pattern=(None if self.family.layer_pattern is None
+                           else list(self.family.layer_pattern)),
             max_slots=self.max_slots,
             programs=sorted(s.fn.__name__ for s in (
                 *self._prefills.values(), *self._decodes.values(),
@@ -763,13 +839,21 @@ class ServeEngine:
         policy = self.kv_policy
         scaled = policy.scaled
 
+        recurrent = self._recurrent
+
         def body(params, k_pool, v_pool, *rest):
             if scaled:
                 k_scale, v_scale, *rest = rest
             else:
                 k_scale = v_scale = None
+            extra = {}
+            if recurrent:
+                ssm, conv, *rest = rest
             ids, start, t0, table_row, cow_src, cow_len, key_data, \
                 *rest = rest
+            if recurrent:
+                slot, *rest = rest
+                extra = {"state": (ssm, conv), "slot": slot}
             lora, lora_scale = rest if use_lora else (None, None)
             # copy-on-write: when the reusable prefix chain ends inside
             # a partially-filled cached block, its first cow_len slots
@@ -803,7 +887,7 @@ class ServeEngine:
                     params, k_pool, v_pool, ids, start, t0, table_row,
                     bs, tp_axis=tp_axis, ep_axis=ep_axis, lora=lora,
                     lora_scale=lora_scale, kv_scales=kv_scales,
-                    policy=policy, attn_kernel=attn_kernel)
+                    policy=policy, attn_kernel=attn_kernel, **extra)
             else:
                 # sequence-parallel chunk: ids arrives as this rank's
                 # [1, P/sp] slice (the shard_map below splits dim 1);
@@ -823,7 +907,7 @@ class ServeEngine:
                 return (*pools, tok.astype(jnp.int32),
                         jax.random.key_data(key2))
 
-        return self._wrap(body, name, n_rest=7, donate=donate,
+        return self._wrap(body, name, n_rest=7 + recurrent, donate=donate,
                           ids_sharded=True)
 
     def _build_decode(self, name: str, *, donate):
@@ -835,9 +919,15 @@ class ServeEngine:
         policy = self.kv_policy
         scaled = policy.scaled
 
+        recurrent = self._recurrent
+
         def body(params, k_pool, v_pool, *rest):
+            extra = {}
             if scaled:
                 k_scale, v_scale, *rest = rest
+            if recurrent:
+                ssm, conv, *rest = rest
+                extra = {"state": (ssm, conv)}
             tok, pos, tables, key_data, *rest = rest
             lora, lora_scale = rest if use_lora else (None, None)
             out = family.decode(
@@ -845,7 +935,7 @@ class ServeEngine:
                 tp_axis=tp_axis, ep_axis=ep_axis,
                 lora=lora, lora_scale=lora_scale,
                 kv_scales=(k_scale, v_scale) if scaled else None,
-                policy=policy, attn_kernel=attn_kernel)
+                policy=policy, attn_kernel=attn_kernel, **extra)
             logits, pools = out[0], out[1:]
             with jax.named_scope("sample"):
                 keys = jax.random.wrap_key_data(key_data)
@@ -1233,6 +1323,8 @@ class ServeEngine:
         engine-local id. Inert: never influences output."""
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         self._check_admissible(prompt, max_new_tokens)
+        if self._recurrent:
+            _refuse_for_state(self.family, prefill_only=prefill_only)
         if deadline_s is not None and deadline_s <= 0:
             raise ValueError(
                 f"deadline_s={deadline_s} already expired at submit")
@@ -1278,6 +1370,8 @@ class ServeEngine:
         blocks published (the disaggregated fleet's prefill-pool
         dispatch; see :class:`Request`.prefill_only)."""
         prompt = np.asarray(progress.prompt, np.int32).reshape(-1)
+        if self._recurrent:
+            _refuse_for_state(self.family, prefill_only=prefill_only)
         if progress.key_data is None:
             raise ValueError(
                 "progress.key_data is required to restore a request "
@@ -1574,6 +1668,12 @@ class ServeEngine:
             f"{self.prefill_buckets[-1]} — _check_admissible should "
             f"have rejected this request")
 
+    def _state_row(self, slot: int) -> Tuple:
+        """What a recurrent family's prefill takes beside the rest: the
+        row of the state buffers its request owns (its slot). Nothing
+        for a KV-only family."""
+        return (np.int32(slot),) if self._recurrent else ()
+
     def _allocate_slot(self, slot: int, req: Request):
         """The admission prologue both prefill paths share: resolve
         the plan the scheduler's budget check approved (same step, no
@@ -1687,7 +1787,8 @@ class ServeEngine:
             args = ph.upload(
                 ids, np.int32(start), np.int32(t0), row,
                 np.int32(plan.cow_src if plan.cow_src is not None else 0),
-                np.int32(plan.cow_len), req.key_data)
+                np.int32(plan.cow_len), req.key_data,
+                *self._state_row(slot))
             with ph.phase("dispatch"):
                 *pools, tok0, key2 = self._prefills[bucket](
                     self.params, *self.pool.caches(), *args, *extra)
@@ -1776,7 +1877,8 @@ class ServeEngine:
             args = ph.upload(
                 ids, np.int32(st.next), np.int32(st.next + n),
                 self._tables[slot], np.int32(st.cow_src if cow else 0),
-                np.int32(st.cow_len if cow else 0), self._key_data[slot])
+                np.int32(st.cow_len if cow else 0), self._key_data[slot],
+                *self._state_row(slot))
             with ph.phase("dispatch"):
                 *pools, tok0, key2 = self._prefills[bucket](
                     self.params, *self.pool.caches(), *args, *extra)
@@ -2108,6 +2210,14 @@ class ServeEngine:
         # what the decode program reads of the pool this step: every
         # position the rows that ride it hold
         context_tokens = int(self._pos[decoding].sum())
+        # bytes of recurrent state this step's programs read and wrote:
+        # every decoding slot's once each way, and each prefilled
+        # slot's per chunk program that ran (a chunk that starts at 0
+        # reads nothing it keeps, a floor's worth of difference)
+        per_slot = self.pool.state_bytes_per_slot
+        state_bytes = 2 * per_slot * (
+            len(decoding) + (prefill_chunks if self.chunked_prefill
+                             else m.admitted - rec_admitted0))
         decode_tokens = 0
         draft_tokens = accepted_draft = 0
         spec_step = False
@@ -2224,6 +2334,7 @@ class ServeEngine:
                 accepted_draft_tokens=accepted_draft,
                 phases=ph.seconds, host_syncs=ph.host_syncs,
                 h2d_bytes=ph.h2d_bytes, context_tokens=context_tokens,
+                state_bytes=state_bytes,
                 attrs={k: (v.tolist() if isinstance(v, np.ndarray)
                            else v)
                        for k, v in moe_kw.items()} if moe_kw else {}))
@@ -2240,13 +2351,17 @@ class ServeEngine:
         zrow = jnp.zeros((self.table_width,), jnp.int32)
         lora_on = self.adapters is not None
         p_extra = self._lora_args("prefill", slot=0) if lora_on else ()
+        # a recurrent family's warmup prefill writes the state's null
+        # row (the one past the slots), as its KV goes to block 0
+        p_state = ((jnp.int32(self.max_slots),) if self._recurrent
+                   else ())
         for b, sentinel in self._prefills.items():
             yield sentinel, (
                 self.params, *self.pool.caches(),
                 jnp.zeros((1, b), jnp.int32), jnp.int32(0), jnp.int32(1),
                 zrow, jnp.int32(0), jnp.int32(0),
                 jnp.asarray(jax.random.key_data(jax.random.key(0))),
-                *p_extra)
+                *p_state, *p_extra)
         for R, sentinel in self._decodes.items():
             extra = (self._lora_args("decode", rank_bucket=R)
                      if lora_on else ())
@@ -2372,6 +2487,8 @@ class ServeEngine:
         gone (evicted under pressure): the handoff caller falls back
         to local re-prefill, which is always correct — the chain is
         cache, not state."""
+        if self._recurrent:
+            _refuse_for_state(self.family, kv_chain=True)
         chain = self.pool.export_chain(tokens, namespace=namespace)
         if self.tracer is not None:
             self.tracer.event(trace_id, "kv_export",
@@ -2391,6 +2508,8 @@ class ServeEngine:
         off — the caller re-prefills locally). Raises ``ValueError``
         on a geometry/policy mismatch: mixed engine specs in one
         fleet are a deployment error, not a retryable fault."""
+        if self._recurrent:
+            _refuse_for_state(self.family, kv_chain=True)
         n = self.pool.import_chain(chain, namespace=namespace)
         if self.tracer is not None:
             self.tracer.event(trace_id, "kv_import",

@@ -69,6 +69,21 @@ scales. Each targeted matmul adds its row's low-rank delta
 take the full [S]-slot pack; prefill (one request at a time) takes the
 admitted slot's [1]-row slice. ``lora=None`` is byte-identical to the
 pre-adapter programs.
+
+Recurrent families (``Family.state`` set: state-space layers among
+attention ones, :func:`granite_hybrid_family`): the layers are of TWO
+kinds in a repeated pattern (``Family.layer_pattern``), only the
+attention layers hold paged KV (``n_layers`` counts those), and every
+other layer keeps a fixed-size state per engine slot
+(serve/kv_pool.StateShapes). Every contract additionally takes
+``state=(ssm, conv)`` — the per-slot buffers ``[L_r, slots + 1, ...]``,
+row = slot, the conv tail's rows flat — and returns ``(logits, k_pool, v_pool, ssm, conv)``;
+``prefill_from`` takes ``slot``, the row its request owns. A prefill
+that starts at position 0 starts from a ZERO state whatever the row
+held (admission); one that starts later continues from the state its
+predecessor left (chunked prefill). Pad columns of a bucket, rows with
+``tail_len`` 0 and decode rows whose table is all null blocks leave
+their state exactly as it was.
 """
 
 from __future__ import annotations
@@ -80,12 +95,14 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from quintnet_tpu.serve.kv_pool import NULL_BLOCK, StateShapes
+
 
 @dataclass(frozen=True)
 class Family:
     name: str
     cfg: Any
-    n_layers: int
+    n_layers: int            # layers that hold paged KV (all, if uniform)
     n_kv_heads: int          # GLOBAL kv heads (pool head dim)
     head_dim: int
     max_positions: int
@@ -128,6 +145,13 @@ class Family:
     # land on the wrong columns. None = identity (llama: separate
     # q/k/v, column order preserved per rank).
     lora_layout: Optional[Callable] = None
+    # every layer's kind in model order, where the layers differ (the
+    # serving scan then runs over periods of the pattern); None = one
+    # uniform layer
+    layer_pattern: Optional[Tuple[str, ...]] = None
+    # per-slot recurrent state beside the paged pool (module docstring);
+    # None = sequences are KV only
+    state: Optional[StateShapes] = None
 
 
 # --------------------------------------------------------------------
@@ -489,4 +513,167 @@ def llama_family(cfg) -> Family:
         weight_targets=(("attn", "q"), ("attn", "k"), ("attn", "v"),
                         ("attn", "o"), ("mlp", "gate"), ("mlp", "up"),
                         ("mlp", "down")),
+    )
+
+
+# --------------------------------------------------------------------
+# Granite 4.0-H (Mamba-2 layers with a GQA attention layer among every
+# few): a layer pattern in the scan, recurrent state beside the pool
+# --------------------------------------------------------------------
+
+def granite_hybrid_family(cfg) -> Family:
+    from quintnet_tpu.models.granite_hybrid import (
+        WEIGHT_TARGETS, attn_block_chunk, attn_block_step, granite_embed,
+        granite_hybrid_partition_specs, granite_logits, mamba_block_chunk,
+        mamba_block_step)
+
+    periods, before, after = cfg.pattern
+    per = before + after
+    dims = cfg.mamba
+
+    def only_plain(tp_axis, ep_axis, lora, kv_scales, attn_kernel, state):
+        if (tp_axis is not None or ep_axis is not None or lora is not None
+                or kv_scales is not None or attn_kernel != "xla"
+                or state is None):
+            raise NotImplementedError(
+                "the granite hybrid programs run on one device, without "
+                "adapters, on an unscaled pool, with attn_kernel='xla' "
+                "and with their state buffers (ServeEngine refuses the "
+                "rest at construction)")
+
+    def run_layers(params, k_pool, v_pool, state, h, row0, rows,
+                   mamba_fn, attn_fn):
+        """The scan over the layer pattern: one step a PERIOD — a short
+        scan of Mamba layers, the attention layer, a second short scan
+        of Mamba layers. The attention layers' pools ride the outer
+        scan as xs and ys like a uniform family's; the state buffers
+        ride every loop's CARRY, each layer reading rows ``[row0, row0
+        + rows)`` of its own slice and writing them back in place —
+        stacked as ys they would be a second copy of the whole state.
+        The Mamba layers' weights are indexed by layer inside the inner
+        loop, as a scan indexes its xs."""
+        mblocks = params["blocks"]["mamba"]
+
+        def mamba_run(carry, first, count):
+            def body(c, j):
+                x, ssm, conv = c
+                layer = first + j
+                blk = jax.tree.map(
+                    lambda a: lax.dynamic_index_in_dim(
+                        a, layer, keepdims=False), mblocks)
+                s = lax.dynamic_slice(
+                    ssm, (layer, row0, 0, 0, 0),
+                    (1, rows, *ssm.shape[2:]))[0]
+                t = lax.dynamic_slice(
+                    conv, (layer, row0, 0), (1, rows, conv.shape[2]))[0]
+                x, s, t = mamba_fn(
+                    blk, x, s, t.reshape(rows, dims.d_conv - 1, dims.d_xbc))
+                with jax.named_scope("mamba"), \
+                        jax.named_scope("state_update"):
+                    ssm = lax.dynamic_update_slice(
+                        ssm, s[None], (layer, row0, 0, 0, 0))
+                    conv = lax.dynamic_update_slice(
+                        conv, t.reshape(1, rows, -1), (layer, row0, 0))
+                return (x, ssm, conv), None
+
+            if count == 0:
+                return carry
+            return lax.scan(body, carry, jnp.arange(count))[0]
+
+        def period(carry, xs):
+            i, ablk, kc, vc = xs
+            carry = mamba_run(carry, i * per, before)
+            x, kc, vc = attn_fn(ablk, carry[0], kc, vc)
+            carry = mamba_run((x, *carry[1:]), i * per + before, after)
+            return carry, (kc, vc)
+
+        (h, ssm, conv), (k_pool, v_pool) = _scan_blocks(
+            period, (h, *state),
+            (jnp.arange(periods), params["blocks"]["attn"], k_pool,
+             v_pool))
+        return h, k_pool, v_pool, ssm, conv
+
+    def run_chunk(params, k_pool, v_pool, state, ids, positions, lens,
+                  tables, block_size, policy, row0, fresh):
+        """A run of tokens a row (prefill, chunked prefill, verify)."""
+        def mamba_fn(blk, x, s, t):
+            s = jnp.where(fresh, jnp.zeros_like(s), s)
+            t = jnp.where(fresh, jnp.zeros_like(t), t)
+            return mamba_block_chunk(blk, x, s, t, lens, cfg)
+
+        def attn_fn(blk, x, kc, vc):
+            return attn_block_chunk(blk, x, kc, vc, positions, lens,
+                                    tables, block_size, cfg, policy)
+
+        return run_layers(params, k_pool, v_pool, state,
+                          granite_embed(params, ids, cfg), row0,
+                          ids.shape[0], mamba_fn, attn_fn)
+
+    def prefill_from(params, k_pool, v_pool, ids, start, t0, table_row,
+                     block_size, tp_axis=None, ep_axis=None, lora=None,
+                     lora_scale=None, kv_scales=None, policy=None,
+                     attn_kernel="xla", state=None, slot=None):
+        only_plain(tp_axis, ep_axis, lora, kv_scales, attn_kernel, state)
+        P = ids.shape[1]
+        positions = (start + jnp.arange(P, dtype=jnp.int32))[None]
+        h, *bufs = run_chunk(
+            params, k_pool, v_pool, state, ids, positions,
+            jnp.reshape(t0 - start, (1,)), table_row[None], block_size,
+            policy, slot, start == 0)
+        h_last = lax.dynamic_slice_in_dim(h, t0 - 1 - start, 1, axis=1)
+        return (granite_logits(params, h_last, cfg)[:, 0, :], *bufs)
+
+    def verify(params, k_pool, v_pool, ids, starts, tail_lens, tables,
+               block_size, tp_axis=None, ep_axis=None, lora=None,
+               lora_scale=None, kv_scales=None, policy=None,
+               attn_kernel="xla", state=None):
+        # the chunk program for P tokens a row, every row from its
+        # CURRENT state. Not a speculative verify: nothing rolls a
+        # state back, and the engine refuses ``spec`` for this family
+        only_plain(tp_axis, ep_axis, lora, kv_scales, attn_kernel, state)
+        P = ids.shape[1]
+        positions = (starts[:, None]
+                     + jnp.arange(P, dtype=jnp.int32)[None, :])
+        h, *bufs = run_chunk(
+            params, k_pool, v_pool, state, ids, positions, tail_lens,
+            tables, block_size, policy, 0, False)
+        return (granite_logits(params, h, cfg), *bufs)
+
+    def decode(params, k_pool, v_pool, tok, pos, tables, block_size,
+               tp_axis=None, ep_axis=None, lora=None, lora_scale=None,
+               kv_scales=None, policy=None, attn_kernel="xla",
+               state=None):
+        only_plain(tp_axis, ep_axis, lora, kv_scales, attn_kernel, state)
+        # a row whose table is all null blocks is not decoding (an
+        # empty slot, or one in the middle of a chunked prefill): its
+        # state stays what it was
+        live = tables[:, 0] != NULL_BLOCK
+
+        def mamba_fn(blk, x, s, t):
+            x, s2, t2 = mamba_block_step(blk, x, s, t, cfg)
+            return (x, jnp.where(live[:, None, None, None], s2, s),
+                    jnp.where(live[:, None, None], t2, t))
+
+        def attn_fn(blk, x, kc, vc):
+            return attn_block_step(blk, x, kc, vc, pos, tables,
+                                   block_size, cfg, policy)
+
+        h, *bufs = run_layers(
+            params, k_pool, v_pool, state,
+            granite_embed(params, tok[:, None], cfg), 0, tok.shape[0],
+            mamba_fn, attn_fn)
+        return (granite_logits(params, h, cfg)[:, 0, :], *bufs)
+
+    return Family(
+        name="granite_hybrid", cfg=cfg, n_layers=periods,
+        n_kv_heads=cfg.num_key_value_heads, head_dim=cfg.head_dim,
+        max_positions=cfg.max_position_embeddings,
+        prefill_from=prefill_from, decode=decode, verify=verify,
+        partition_specs=granite_hybrid_partition_specs,
+        weight_targets=WEIGHT_TARGETS,
+        layer_pattern=cfg.layer_types,
+        state=StateShapes(
+            n_layers=cfg.n_mamba_layers,
+            ssm=(dims.n_heads, dims.d_head, dims.d_state),
+            conv=((dims.d_conv - 1) * dims.d_xbc,)),
     )

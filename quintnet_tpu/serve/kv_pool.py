@@ -27,6 +27,18 @@ Allocation is host-side bookkeeping — the device arrays never reshape;
 "allocating" a block just means an engine slot's block table starts
 referencing it.
 
+Recurrent families (state-space layers among attention ones,
+serve/families.py ``Family.state``) keep a SECOND kind of per-sequence
+memory in the same manager: beside the blocks of their attention
+layers, one fixed-size state per engine SLOT for every recurrent layer
+— ``ssm [L_r, max_slots + 1, ...]`` f32 and ``conv [L_r, max_slots + 1,
+...]`` in the pool's stored dtype (:class:`StateShapes`). Row = slot;
+the last row is the null row, the state's counterpart of block 0
+(warmup writes there). The buffers ride every program beside the pools
+(:meth:`KVPool.caches`) and are updated in place. State has no blocks
+to share: the prefix index, the host tier and chain export/import know
+nothing of it, and the engine refuses them for such a family.
+
 Prefix caching (the PagedAttention sharing model + SGLang-style prefix
 reuse, block-granular):
 
@@ -98,6 +110,21 @@ class AdmitPlan:
             [self.cow_src] if self.cow_src is not None else [])
 
 
+@dataclass(frozen=True)
+class StateShapes:
+    """What ONE slot keeps for ONE recurrent layer beside the paged
+    blocks: the f32 state (Mamba-2: heads x head size x state size) and
+    the causal conv's tail (kernel - 1 rows of its channels), the
+    latter in the pool's stored dtype and FLAT, its rows side by side:
+    three rows of 4,352 as the two minor dimensions of a bf16 array are
+    padded to sixteen by the TPU's tiling, 5.3 times the bytes in HBM
+    and in every decode step's traffic."""
+
+    n_layers: int                       # recurrent layers
+    ssm: Tuple[int, ...]
+    conv: Tuple[int, ...]
+
+
 class KVPool:
     """Refcounted block allocator + prefix cache over paged KV storage.
 
@@ -113,7 +140,9 @@ class KVPool:
                  policy: "KVLayoutPolicy | str | None" = None,
                  sharding=None, scale_sharding=None,
                  prefix_cache: bool = True,
-                 host_tier: Optional[HostTier] = None):
+                 host_tier: Optional[HostTier] = None,
+                 state: Optional[StateShapes] = None,
+                 max_slots: int = 0):
         if block_size < 1 or num_blocks < 2:
             raise ValueError(
                 f"need block_size >= 1 and num_blocks >= 2 (block 0 is "
@@ -152,6 +181,27 @@ class KVPool:
         self.v = v
         self.k_scale = k_scale
         self.v_scale = v_scale
+        # per-slot recurrent state (module docstring); None = KV only
+        self.state = state
+        self.ssm = self.conv = None
+        self._state_bytes_per_slot = 0
+        if state is not None:
+            if (self.policy.scaled or sharding is not None
+                    or not jnp.issubdtype(self.policy.store_dtype,
+                                          jnp.floating)
+                    or jnp.dtype(self.policy.store_dtype).itemsize < 2):
+                raise NotImplementedError(
+                    f"recurrent state beside a {self.policy.name!r} or "
+                    f"sharded pool is not implemented: the conv tail "
+                    f"is stored in the pool's dtype (f32 or bf16), "
+                    f"unscaled, on one device")
+            rows = int(max_slots) + 1
+            self.ssm = jnp.zeros((state.n_layers, rows, *state.ssm),
+                                 jnp.float32)
+            self.conv = jnp.zeros((state.n_layers, rows, *state.conv),
+                                  self.policy.store_dtype)
+            self._state_bytes_per_slot = int(
+                (self.ssm.nbytes + self.conv.nbytes) // rows)
         # LIFO free list: reuse recently-freed blocks first (warm pages).
         # The membership set keeps release's double-free check O(1)
         # instead of an O(free-list) scan per block.
@@ -209,6 +259,14 @@ class KVPool:
     def bytes_per_token(self) -> float:
         """Device bytes one resident token position costs."""
         return self.bytes_per_block / self.block_size
+
+    @property
+    def state_bytes_per_slot(self) -> int:
+        """Device bytes of ONE slot's recurrent state over all the
+        recurrent layers, whatever the sequence's length (0 for a
+        KV-only family). ``bytes_per_token`` counts the layers that
+        hold KV only."""
+        return self._state_bytes_per_slot
 
     @property
     def usable_blocks(self) -> int:
@@ -891,17 +949,28 @@ class KVPool:
         """The pool's device arrays, as carried through the jitted step
         functions (the engine writes the returned/donated results back
         via :meth:`update`): ``(k, v)`` for passthrough policies,
-        ``(k, v, k_scale, v_scale)`` for scaled ones — call sites splat
-        the tuple, so the policy never changes their shape."""
+        ``(k, v, k_scale, v_scale)`` for scaled ones, ``(k, v, ssm,
+        conv)`` for a recurrent family — call sites splat the tuple,
+        so neither the policy nor the family changes their shape."""
         if self.policy.scaled:
             return self.k, self.v, self.k_scale, self.v_scale
+        if self.state is not None:
+            return self.k, self.v, self.ssm, self.conv
         return self.k, self.v
 
-    def update(self, k, v, k_scale=None, v_scale=None) -> None:
+    def update(self, k, v, *rest) -> None:
+        """Adopt what a program returned for :meth:`caches`' buffers,
+        in that order."""
+        if len(rest) != len(self.caches()) - 2:
+            carries = ("scale arrays" if self.policy.scaled
+                       else "recurrent state buffers"
+                       if self.state is not None else "no other buffers")
+            raise ValueError(
+                f"policy {self.policy.name!r} carries {carries}; "
+                f"update() needs all {len(self.caches())} pool buffers, "
+                f"got {2 + len(rest)}")
         self.k, self.v = k, v
         if self.policy.scaled:
-            if k_scale is None or v_scale is None:
-                raise ValueError(
-                    f"policy {self.policy.name!r} carries scale arrays; "
-                    f"update() needs all four pool buffers")
-            self.k_scale, self.v_scale = k_scale, v_scale
+            self.k_scale, self.v_scale = rest
+        elif self.state is not None:
+            self.ssm, self.conv = rest

@@ -7,7 +7,9 @@ checked without a chip (PR 21, the bring-up round).
    backward at seq 4096 with and without ``segment_ids``, and
    ``paged_attention(interpret=False)`` at decode / verify / prefill
    shapes for 12 heads x Dh 64 x table width 64 (1024 positions),
-   passthrough and int8-scaled. The parent commit's paged kernel was
+   passthrough and int8-scaled; and (PR 27) the decode program of the
+   recurrent family at granite-4.0-h-micro's published widths, whose
+   plan must not hold a second copy of the per-slot state. The parent commit's paged kernel was
    REFUSED at every one of these shapes (16 MiB default scoped-VMEM
    budget; a (1, Hkv) scale block; a lane-splitting reshape) —
    interpret mode cannot see any of that. Nothing runs: a compile that
@@ -147,6 +149,62 @@ def cache_config():
     yield
     for n, v in was.items():
         jax.config.update(n, v)
+
+
+# ---------------------------------------------------------------------
+# the recurrent family's decode step updates its state in place
+# ---------------------------------------------------------------------
+def test_hybrid_decode_holds_one_copy_of_the_state_on_v5e(chip):
+    """``granite_hybrid_family(...).decode`` at the published widths
+    (shapes only: ``jax.eval_shape``), 16 slots, pools and state
+    donated, compiled for the described chip. Stacked as a scan's ys
+    the state would be planned twice (1.15 GiB each here); carried and
+    updated in place, the temporaries stay under a third of it."""
+    import numpy as np
+
+    from quintnet_tpu.models.granite_hybrid import (GraniteHybridConfig,
+                                                    granite_hybrid_init)
+    from quintnet_tpu.serve import granite_hybrid_family
+    from quintnet_tpu.serve.kv_quant import make_policy
+    from quintnet_tpu.serve.weight_quant import (make_weight_policy,
+                                                 present_targets,
+                                                 quantize_params)
+
+    with open(os.path.join(REPO, "benchmarks", "configs",
+                           "granite-4.0-h-micro.json")) as f:
+        cfg = GraniteHybridConfig.from_dict(json.load(f))
+    fam = granite_hybrid_family(cfg)
+    slots, bs, width = 16, 16, 64
+    policy = make_policy("bf16")
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    params = jax.tree.map(
+        lambda x: sds(x.shape, x.dtype),
+        jax.eval_shape(lambda k: (lambda p: quantize_params(
+            p, present_targets(p, fam.weight_targets),
+            make_weight_policy("bf16")))(granite_hybrid_init(k, cfg)),
+            jax.random.key(0)))
+    pool = sds((fam.n_layers, 32 * slots * bs, fam.n_kv_heads,
+                fam.head_dim), jnp.bfloat16)
+    ssm = sds((fam.state.n_layers, slots + 1, *fam.state.ssm), jnp.float32)
+    conv = sds((fam.state.n_layers, slots + 1, *fam.state.conv),
+               jnp.bfloat16)
+    rows = sds((slots,), jnp.int32)
+
+    def decode(params, k, v, ssm, conv, tok, pos, tables):
+        return fam.decode(params, k, v, tok, pos, tables, bs,
+                          policy=policy, state=(ssm, conv))
+
+    compiled = jax.jit(decode, donate_argnums=(1, 2, 3, 4)).lower(
+        params, pool, pool, ssm, conv, rows, rows,
+        sds((slots, width), jnp.int32)).compile()
+    plan = compiled.memory_analysis()
+    state_bytes = int(np.prod(ssm.shape)) * 4 + int(np.prod(conv.shape)) * 2
+    assert plan.alias_size_in_bytes >= state_bytes     # in and out alias
+    assert plan.temp_size_in_bytes < state_bytes / 3, (
+        plan.temp_size_in_bytes, state_bytes)
 
 
 def test_cache_dir_from_env_is_left_alone(monkeypatch, tmp_path,
