@@ -31,6 +31,7 @@ from quintnet_tpu.analysis.jaxpr_audit import (
     donation_report,
     dtype_report,
     gathered_view_gathers,
+    pool_scan_operands,
     widened_view_dots,
 )
 from quintnet_tpu.analysis.lint import (
@@ -72,6 +73,7 @@ __all__ = [
     "donation_report",
     "dtype_report",
     "gathered_view_gathers",
+    "pool_scan_operands",
     "widened_view_dots",
     "RULES",
     "Violation",

@@ -352,10 +352,12 @@ def _walk_skip_kernels(jaxpr, visit) -> None:
 def gathered_view_gathers(fn: Callable, *args, num_blocks: int,
                           table_width: int, **kwargs) -> int:
     """Count the XLA ``gather`` eqns that materialize a FULL
-    block-table row view: operand 0 is a pool-shaped array (leading
-    dim == ``num_blocks``) and the output carries a ``table_width``
-    dim — the `paged_gather`/`paged_gather_scales` signature, the HBM
-    round-trip the fused Pallas kernels exist to delete.
+    block-table row view: operand 0 is a pool-shaped array — the whole
+    pool by block, ``[L, num_blocks, ...]``, or one layer's slice of
+    it, ``[num_blocks, ...]`` — and the output carries a
+    ``table_width`` dim — the `paged_gather`/`paged_gather_scales`
+    signature, the HBM round-trip the fused Pallas kernels exist to
+    delete.
 
     The count is structural (one per eqn occurrence; a scan body
     counts once, not per trip), and the table dim is positional: a
@@ -383,8 +385,7 @@ def gathered_view_gathers(fn: Callable, *args, num_blocks: int,
         op = eqn.invars[0]
         if not (hasattr(op, "aval") and hasattr(op.aval, "shape")):
             return
-        shape = tuple(op.aval.shape)
-        if not shape or shape[0] != num_blocks:
+        if num_blocks not in tuple(op.aval.shape)[:2]:
             return
         out = tuple(eqn.outvars[0].aval.shape)
         if len(out) >= 2 and out[1] == table_width:
@@ -435,6 +436,43 @@ def widened_view_dots(fn: Callable, *args, table_width: int,
                     and view.dtype.itemsize < other.dtype.itemsize):
                 found += 1
                 return
+
+    _walk_skip_kernels(closed.jaxpr, visit)
+    return found
+
+
+def pool_scan_operands(fn: Callable, *args, pool_shape: Tuple[int, ...],
+                       **kwargs) -> int:
+    """Count the ``scan`` eqns of a serving program that take a
+    pool-shaped array as xs or give one back as ys: a leaf of
+    ``pool_shape`` sliced a layer at a time into the body and stacked
+    back out of it. On the chip that form re-lays every layer's slice
+    (four layer-sized copies a layer at GPT-2 XL, and two of the whole
+    pool at the loop's edge — PERF.md, PR 28). Every paged program
+    carries its pools whole in the scan's CARRY and addresses them by
+    ``(layer, slot)``, so this reads ZERO for every family, policy and
+    program; ``pool_shape`` is any buffer the program keeps per
+    sequence — ``k``'s (``v``'s is the same), a scale array's, a
+    recurrent state buffer's.
+
+    Structural (a scan counts once, nested scans each on their own;
+    ``pallas_call`` interiors are skipped). A carried operand is not
+    counted: in ``scan``'s operands the xs come after ``num_consts +
+    num_carry``, in its results the ys after ``num_carry``."""
+    closed = jax.make_jaxpr(fn)(*args, **kwargs)
+    pool_shape = tuple(pool_shape)
+    found = 0
+
+    def visit(eqn):
+        nonlocal found
+        if eqn.primitive.name != "scan":
+            return
+        skip = eqn.params["num_consts"] + eqn.params["num_carry"]
+        streamed = (list(eqn.invars[skip:])
+                    + list(eqn.outvars[eqn.params["num_carry"]:]))
+        if any(tuple(getattr(v.aval, "shape", ())) == pool_shape
+               for v in streamed):
+            found += 1
 
     _walk_skip_kernels(closed.jaxpr, visit)
     return found

@@ -249,7 +249,7 @@ def expected_serve_verify(n_layers: int, *,
                           vocab_parallel: bool = False) -> CensusDict:
     """One compiled verify bucket: the decode census exactly — verify
     is the decode step widened from 1 to bucket+1 tokens per row, and
-    the batched draft scatter/gather (nn/attention.paged_verify_update)
+    the batched draft scatter/gather (nn/attention.paged_write)
     adds no collectives. Independent of the bucket width, so every
     bucket program must match this same spec."""
     return expected_serve_decode(n_layers, tp_axis=tp_axis,

@@ -37,9 +37,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from quintnet_tpu.nn.attention import (_gather_kv, _masked_sdpa,
-                                       paged_cache_update,
-                                       paged_verify_update)
+from quintnet_tpu.nn.attention import paged_attend
 from quintnet_tpu.nn.layers import (linear_init, quantized_matmul,
                                     rms_norm_apply, rms_norm_init,
                                     swiglu_apply, swiglu_init)
@@ -309,48 +307,23 @@ def _attn_out(p, o, x, t: int, cfg: GraniteHybridConfig):
     return x + cfg.residual_multiplier * y
 
 
-def attn_block_chunk(p, x, kc, vc, positions, lens, tables, block_size,
-                     cfg: GraniteHybridConfig, policy=None):
-    """A run of tokens a row over the paged pool: x [R, T, D] at
-    absolute ``positions`` [R, T]; each row's (k, v) scatter through
-    its ``tables`` row (columns at or past ``lens`` go to the null
-    block), attention gathers the row's whole history back and masks
-    causally against absolute positions."""
-    t = x.shape[1]
-    g = cfg.num_attention_heads // cfg.num_key_value_heads
+def attn_block_chunk(p, x, k_pool, v_pool, layer, positions, lens, tables,
+                     block_size, cfg: GraniteHybridConfig, policy=None):
+    """A run of tokens a row over the paged pool (one, for a decode
+    step): x [R, T, D] at absolute ``positions`` [R, T]; each row's
+    (k, v) scatter into ``layer`` of the whole pool through its
+    ``tables`` row (columns at or past ``lens`` go to the null block),
+    attention gathers the row's whole history back and masks causally
+    against absolute positions (nn/attention.paged_attend)."""
     with jax.named_scope("attn"):
         u = rms_norm_apply(p["ln1"], x, eps=cfg.rms_norm_eps)
         q, k, v = _qkv(p["attn"], u, cfg)
-        kc, vc = paged_verify_update(kc, vc, k, v, positions, lens,
-                                     block_tables=tables,
-                                     block_size=block_size)
-        kg, vg = _gather_kv(kc, vc, None, policy, tables,
-                            block_size=block_size)
-        valid = (jnp.arange(kg.shape[2])[None, None, :]
-                 <= positions[:, :, None])[:, None]       # [R, 1, T, Tk]
-        o = _masked_sdpa(q, kg, vg, jnp.tile(valid, (1, 1, g, 1)),
-                         page=block_size, scale=cfg.attention_multiplier)
-        x = _attn_out(p["attn"], o, x, t, cfg)
-    return _mlp_residual(p, x, cfg), kc, vc
-
-
-def attn_block_step(p, x, kc, vc, pos, tables, block_size,
-                    cfg: GraniteHybridConfig, policy=None):
-    """One token a row: x [R, 1, D] at ``pos`` [R]."""
-    with jax.named_scope("attn"):
-        u = rms_norm_apply(p["ln1"], x, eps=cfg.rms_norm_eps)
-        q, k, v = _qkv(p["attn"], u, cfg)
-        kc, vc = paged_cache_update(kc, vc, k[:, :, 0], v[:, :, 0], pos,
-                                    block_tables=tables,
-                                    block_size=block_size)
-        kg, vg = _gather_kv(kc, vc, None, policy, tables,
-                            block_size=block_size)
-        valid = (jnp.arange(kg.shape[2])[None, :]
-                 <= pos[:, None])[:, None, None, :]
-        o = _masked_sdpa(q, kg, vg, valid, page=block_size,
-                         scale=cfg.attention_multiplier)
-        x = _attn_out(p["attn"], o, x, 1, cfg)
-    return _mlp_residual(p, x, cfg), kc, vc
+        o, (k_pool, v_pool) = paged_attend(
+            q, k, v, (k_pool, v_pool), layer, positions, lens, tables,
+            block_size=block_size, policy=policy,
+            scale=cfg.attention_multiplier)
+        x = _attn_out(p["attn"], o, x, x.shape[1], cfg)
+    return _mlp_residual(p, x, cfg), k_pool, v_pool
 
 
 def granite_hybrid_partition_specs(tp_axis: Optional[str] = None,

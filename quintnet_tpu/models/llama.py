@@ -446,108 +446,9 @@ def llama_block_prefill(p, x, cfg: LlamaConfig, cos, sin,
     return x, (k, v)
 
 
-def llama_block_prefill_paged(p, x, kc, vc, positions, tail_len,
-                              cfg: LlamaConfig, cos, sin,
-                              tp_axis: Optional[str] = None,
-                              ep_axis: Optional[str] = None,
-                              block_tables=None,
-                              block_size: Optional[int] = None,
-                              lora=None, lora_scale=None,
-                              kv_scales=None, policy=None,
-                              attn_kernel: str = "xla"):
-    """Chunked prefill over the paged pool (the serve engine's
-    prefix-cached path): x [1, P, D] tail hidden states at absolute
-    ``positions`` [P], caches are flat pool views
-    [N_blocks*block_size, Hkv(/tp), hd]. The tail's UNrepeated (k, v)
-    scatter through the request's ``block_tables`` row [M]; attention
-    gathers the whole row back — cached prefix blocks + fresh tail —
-    and masks causally against absolute positions (exactly
-    :func:`llama_block_decode`'s paged math, batched over the tail).
-    ``cos``/``sin`` [P, hd] must be built from the SAME absolute
-    positions. ``lora``/``lora_scale``: this layer's packed per-slot
-    adapters (serving multi-LoRA). ``kv_scales``/``policy``: scaled KV
-    layout (serve/kv_quant.py) — dequantized gathered view, quantize on
-    scatter. Returns (x, (kc, vc[, k_scale, v_scale])).
-    ``attn_kernel="pallas"``: the fused block-table-walking kernel
-    (ops/paged_attention.py) — same contract as
-    nn/attention.mha_prefill_paged's dispatch."""
-    from quintnet_tpu.nn.attention import (_gather_kv, _quant_span,
-                                           paged_prefill_update,
-                                           paged_quant_update)
-
-    tp = 1 if tp_axis is None else lax.axis_size(tp_axis)
-    attn_lora = lora.get("attn") if lora is not None else None
-    a_in = rms_norm_apply(p["ln1"], x, eps=cfg.rms_eps)
-    q, k, v = llama_qkv(p["attn"], a_in, cfg, cos, sin, tp=tp,
-                        lora=attn_lora, lora_scale=lora_scale)
-    if attn_kernel == "pallas":
-        tables = block_tables[None]
-        if kv_scales is None:
-            from quintnet_tpu.ops.paged_attention import paged_attention
-
-            kc, vc = paged_prefill_update(kc, vc, k[0], v[0], positions,
-                                          tail_len,
-                                          block_tables=block_tables,
-                                          block_size=block_size)
-            o = paged_attention(q, kc, vc, tables, positions[:1],
-                                block_size=block_size)
-            pools = (kc, vc)
-        else:
-            from quintnet_tpu.nn.attention import _paged_attention_scaled
-
-            ks, vs = kv_scales
-            o, kc, vc, ks, vs = _paged_attention_scaled(
-                policy, kc, vc, ks, vs, q, k, v, positions[None, :],
-                jnp.reshape(tail_len, (1,)), tables,
-                block_size=block_size,
-                max_blocks=_quant_span(positions.shape[0], block_size,
-                                       block_tables.shape[0]))
-            pools = (kc, vc, ks, vs)
-    else:
-        if kv_scales is None:
-            kc, vc = paged_prefill_update(kc, vc, k[0], v[0], positions,
-                                          tail_len,
-                                          block_tables=block_tables,
-                                          block_size=block_size)
-            kg, vg = _gather_kv(kc, vc, None, policy,
-                                block_tables[None],
-                                block_size=block_size)
-            pools = (kc, vc)
-        else:
-            ks, vs = kv_scales
-            tables = block_tables[None]
-            kg, vg = _gather_kv(kc, vc, (ks, vs), policy, tables,
-                                block_size=block_size)
-            span = _quant_span(positions.shape[0], block_size,
-                               block_tables.shape[0])
-            pos2 = positions[None, :]
-            lens = jnp.reshape(tail_len, (1,))
-            kc, ks, kg = paged_quant_update(
-                policy, kc, ks, kg, k, pos2, lens, block_tables=tables,
-                block_size=block_size, max_blocks=span)
-            vc, vs, vg = paged_quant_update(
-                policy, vc, vs, vg, v, pos2, lens, block_tables=tables,
-                block_size=block_size, max_blocks=span)
-            pools = (kc, vc, ks, vs)
-        rep = q.shape[1] // kg.shape[1]
-        valid = (jnp.arange(kg.shape[2])[None, :]
-                 <= positions[:, None])[None, None]      # [1,1,P,M*bs]
-        o = _masked_sdpa(q, repeat_kv(kg, rep), repeat_kv(vg, rep), valid,
-                         page=block_size)
-    x = llama_attn_residual(p["attn"], x, o, tp_axis=tp_axis,
-                            lora=attn_lora, lora_scale=lora_scale)
-    x, _aux, stats = llama_mlp_residual(
-        p, x, cfg, tp_axis=tp_axis, ep_axis=ep_axis,
-        lora=lora.get("mlp") if lora is not None else None,
-        lora_scale=lora_scale, return_stats=True)
-    if "moe" in p:
-        return x, (*pools, stats)
-    return x, pools
-
-
 def llama_block_prefill_paged_sp(p, x, kc, vc, start, t0,
                                  cfg: LlamaConfig, cos, sin, *,
-                                 sp_axis: str,
+                                 sp_axis: str, layer,
                                  tp_axis: Optional[str] = None,
                                  block_tables=None,
                                  block_size: Optional[int] = None,
@@ -559,24 +460,25 @@ def llama_block_prefill_paged_sp(p, x, kc, vc, start, t0,
     arange(Pl)``) so rope lands exactly where the dense path puts it.
     Attention runs through nn/attention.ring_paged_prefill — K/V
     sharded over ``sp_axis`` during the score pass (GQA UNrepeated on
-    the wire), reassembled by one all_gather for the sp-replicated pool
-    scatter. Returns (x, (kc, vc[, k_scale, v_scale]))."""
-    from quintnet_tpu.nn.attention import ring_paged_prefill
+    the wire), reassembled by one all_gather for the scatter into
+    ``layer`` of the sp-replicated pool. Returns
+    (x, (kc, vc[, k_scale, v_scale]))."""
+    from quintnet_tpu.nn.attention import _pool_tuple, ring_paged_prefill
 
     tp = 1 if tp_axis is None else lax.axis_size(tp_axis)
     a_in = rms_norm_apply(p["ln1"], x, eps=cfg.rms_eps)
     q, k, v = llama_qkv(p["attn"], a_in, cfg, cos, sin, tp=tp)
-    out = ring_paged_prefill(
-        q, k, v, start, t0, kc, vc, sp_axis=sp_axis,
-        block_tables=block_tables, block_size=block_size,
-        kv_scales=kv_scales, policy=policy)
-    x = llama_attn_residual(p["attn"], x, out[0], tp_axis=tp_axis)
+    o, pools = ring_paged_prefill(
+        q, k, v, start, t0, _pool_tuple(kc, vc, kv_scales), layer,
+        sp_axis=sp_axis, block_tables=block_tables, block_size=block_size,
+        policy=policy)
+    x = llama_attn_residual(p["attn"], x, o, tp_axis=tp_axis)
     x, _aux = llama_mlp_residual(p, x, cfg, tp_axis=tp_axis)
-    return x, out[1:]
+    return x, pools
 
 
 def llama_block_verify_paged(p, x, kc, vc, positions, tail_lens,
-                             cfg: LlamaConfig, cos, sin,
+                             cfg: LlamaConfig, cos, sin, *, layer,
                              tp_axis: Optional[str] = None,
                              ep_axis: Optional[str] = None,
                              block_tables=None,
@@ -584,80 +486,34 @@ def llama_block_verify_paged(p, x, kc, vc, positions, tail_lens,
                              lora=None, lora_scale=None,
                              kv_scales=None, policy=None,
                              attn_kernel: str = "xla"):
-    """Batched draft-verify block step over the paged pool (the serve
-    engine's speculative-decode scoring path, serve/spec.py): x
-    [S, P, D] per-slot token runs at absolute ``positions`` [S, P],
-    caches are flat pool views [N_blocks*block_size, Hkv(/tp), hd].
-    Every row's UNrepeated (k, v) run scatters through its
-    ``block_tables`` row (pad columns masked to the null block by
-    ``tail_lens``); attention gathers each row's whole history back and
-    masks causally against absolute positions — exactly
-    :func:`llama_block_decode`'s paged math widened from 1 to P tokens
-    per row. ``cos``/``sin`` [S, 1, P, hd] must be built from the SAME
-    absolute positions. ``lora``/``lora_scale``: this layer's packed
-    per-slot adapters. ``kv_scales``/``policy``: scaled KV layout
-    (serve/kv_quant.py). Returns (x, (kc, vc[, k_scale, v_scale])).
+    """The paged block step of every serving program
+    (nn/attention.paged_attend): x [S, P, D] per-slot token runs at
+    absolute ``positions`` [S, P] — a decode step at P == 1, a
+    (chunked) prefill at S == 1, speculative decoding's scoring run
+    between (serve/spec.py). ``kc``/``vc`` are the WHOLE pool
+    [L, N_blocks*block_size, F] (UNrepeated kv heads, LOCAL under tp),
+    written and read at ``layer``. Every row's (k, v) run scatters
+    through its ``block_tables`` row (pad columns masked to the null
+    block by ``tail_lens``); attention gathers each row's whole history
+    back, repeats the kv heads on the gathered view and masks causally
+    against absolute positions. ``cos``/``sin`` (broadcastable to
+    [S, 1, P, hd]) must be built from the SAME absolute positions.
+    ``lora``/``lora_scale``: this layer's packed per-slot adapters.
+    ``kv_scales``/``policy``: scaled KV layout (serve/kv_quant.py).
+    Returns (x, (kc, vc[, k_scale, v_scale][, moe_stats])).
     ``attn_kernel="pallas"``: the fused block-table-walking kernel
-    (ops/paged_attention.py), batched over rows."""
-    from quintnet_tpu.nn.attention import (_gather_kv, _quant_span,
-                                           paged_quant_update,
-                                           paged_verify_update)
+    (ops/paged_attention.py)."""
+    from quintnet_tpu.nn.attention import _pool_tuple, paged_attend
 
     tp = 1 if tp_axis is None else lax.axis_size(tp_axis)
     attn_lora = lora.get("attn") if lora is not None else None
     a_in = rms_norm_apply(p["ln1"], x, eps=cfg.rms_eps)
     q, k, v = llama_qkv(p["attn"], a_in, cfg, cos, sin, tp=tp,
                         lora=attn_lora, lora_scale=lora_scale)
-    if attn_kernel == "pallas":
-        if kv_scales is None:
-            from quintnet_tpu.ops.paged_attention import paged_attention
-
-            kc, vc = paged_verify_update(kc, vc, k, v, positions,
-                                         tail_lens,
-                                         block_tables=block_tables,
-                                         block_size=block_size)
-            o = paged_attention(q, kc, vc, block_tables,
-                                positions[:, 0], block_size=block_size)
-            pools = (kc, vc)
-        else:
-            from quintnet_tpu.nn.attention import _paged_attention_scaled
-
-            ks, vs = kv_scales
-            o, kc, vc, ks, vs = _paged_attention_scaled(
-                policy, kc, vc, ks, vs, q, k, v, positions, tail_lens,
-                block_tables, block_size=block_size,
-                max_blocks=_quant_span(positions.shape[1], block_size,
-                                       block_tables.shape[1]))
-            pools = (kc, vc, ks, vs)
-    else:
-        if kv_scales is None:
-            kc, vc = paged_verify_update(kc, vc, k, v, positions,
-                                         tail_lens,
-                                         block_tables=block_tables,
-                                         block_size=block_size)
-            kg, vg = _gather_kv(kc, vc, None, policy, block_tables,
-                                block_size=block_size)
-            pools = (kc, vc)
-        else:
-            ks, vs = kv_scales
-            kg, vg = _gather_kv(kc, vc, (ks, vs), policy, block_tables,
-                                block_size=block_size)
-            span = _quant_span(positions.shape[1], block_size,
-                               block_tables.shape[1])
-            kc, ks, kg = paged_quant_update(
-                policy, kc, ks, kg, k, positions, tail_lens,
-                block_tables=block_tables, block_size=block_size,
-                max_blocks=span)
-            vc, vs, vg = paged_quant_update(
-                policy, vc, vs, vg, v, positions, tail_lens,
-                block_tables=block_tables, block_size=block_size,
-                max_blocks=span)
-            pools = (kc, vc, ks, vs)
-        rep = q.shape[1] // kg.shape[1]
-        valid = (jnp.arange(kg.shape[2])[None, None, :]
-                 <= positions[:, :, None])[:, None]   # [S, 1, P, M*bs]
-        o = _masked_sdpa(q, repeat_kv(kg, rep), repeat_kv(vg, rep), valid,
-                         page=block_size)
+    o, pools = paged_attend(
+        q, k, v, _pool_tuple(kc, vc, kv_scales), layer, positions,
+        tail_lens, block_tables, block_size=block_size, policy=policy,
+        attn_kernel=attn_kernel)
     x = llama_attn_residual(p["attn"], x, o, tp_axis=tp_axis,
                             lora=attn_lora, lora_scale=lora_scale)
     x, _aux, stats = llama_mlp_residual(
@@ -670,113 +526,27 @@ def llama_block_verify_paged(p, x, kc, vc, positions, tail_lens,
 
 
 def llama_block_decode(p, x, kc, vc, pos, cfg: LlamaConfig, cos, sin,
-                       tp_axis: Optional[str] = None,
-                       ep_axis: Optional[str] = None,
-                       block_tables=None, block_size: Optional[int] = None,
-                       lora=None, lora_scale=None,
-                       kv_scales=None, policy=None,
-                       attn_kernel: str = "xla"):
-    """One cached token: x [B, 1, D], caches [B, Hkv(/tp), T, hd] ->
-    (x, updated caches). Masked attention over cache[:pos].
-
-    Paged path (``block_tables``/``block_size`` set, quintnet_tpu/serve):
-    caches are flat pool views [N_blocks*block_size, Hkv(/tp), hd]
-    shared across requests, ``pos`` is a [B] vector, and the caller
-    supplies per-row rope tables (cos/sin [B, 1, 1, hd]). The cache
-    stays UNrepeated either way — kv-head repeat happens on the
-    gathered view. ``lora``/``lora_scale``: this layer's packed
-    per-slot adapters (multi-tenant LoRA serving). ``kv_scales``/
-    ``policy``: scaled KV layout (serve/kv_quant.py; paged path only) —
-    the update tuple grows to (kc, vc, k_scale, v_scale)."""
+                       tp_axis: Optional[str] = None):
+    """One cached token on the dense single-request cache
+    (models/llama_generate.py): x [B, 1, D], caches [B, Hkv(/tp), T, hd]
+    -> (x, (kc, vc)). Masked attention over cache[:pos]; the cache stays
+    UNrepeated — kv-head repeat happens on the read. The
+    continuous-batching decode step is :func:`llama_block_verify_paged`
+    at one token a row."""
     tp = 1 if tp_axis is None else lax.axis_size(tp_axis)
-    attn_lora = lora.get("attn") if lora is not None else None
     a_in = rms_norm_apply(p["ln1"], x, eps=cfg.rms_eps)
-    q, k, v = llama_qkv(p["attn"], a_in, cfg, cos, sin, tp=tp,
-                        lora=attn_lora, lora_scale=lora_scale)
-    pools = None
-    kf = None
-    if block_tables is None:
-        if kv_scales is not None:
-            raise ValueError(
-                "scaled KV layout policies exist only for the paged "
-                "pool (block_tables is required)")
-        if attn_kernel != "xla":
-            raise ValueError(
-                "attn_kernel='pallas' exists only for the paged pool "
-                "(block_tables is required)")
-        kc = lax.dynamic_update_slice_in_dim(kc, k.astype(kc.dtype), pos,
-                                             axis=2)
-        vc = lax.dynamic_update_slice_in_dim(vc, v.astype(vc.dtype), pos,
-                                             axis=2)
-        rep = q.shape[1] // kc.shape[1]
-        kf, vf = repeat_kv(kc, rep), repeat_kv(vc, rep)
-        valid = jnp.arange(kf.shape[2])[None, None, None, :] <= pos
-    elif attn_kernel == "pallas":
-        if kv_scales is None:
-            from quintnet_tpu.nn.attention import paged_cache_update
-            from quintnet_tpu.ops.paged_attention import paged_attention
-
-            kc, vc = paged_cache_update(
-                kc, vc, k[:, :, 0].astype(kc.dtype),
-                v[:, :, 0].astype(vc.dtype), pos,
-                block_tables=block_tables, block_size=block_size)
-            o = paged_attention(q, kc, vc, block_tables, pos,
-                                block_size=block_size)
-        else:
-            from quintnet_tpu.nn.attention import _paged_attention_scaled
-
-            ks, vs = kv_scales
-            o, kc, vc, ks, vs = _paged_attention_scaled(
-                policy, kc, vc, ks, vs, q, k, v, pos[:, None],
-                jnp.ones(pos.shape, jnp.int32), block_tables,
-                block_size=block_size, max_blocks=1)
-            pools = (kc, vc, ks, vs)
-    elif kv_scales is None:
-        from quintnet_tpu.nn.attention import (_gather_kv,
-                                               paged_cache_update)
-
-        kc, vc = paged_cache_update(
-            kc, vc, k[:, :, 0].astype(kc.dtype), v[:, :, 0].astype(vc.dtype),
-            pos, block_tables=block_tables, block_size=block_size)
-        kg, vg = _gather_kv(kc, vc, None, policy, block_tables,
-                            block_size=block_size)
-        rep = q.shape[1] // kg.shape[1]
-        kf, vf = repeat_kv(kg, rep), repeat_kv(vg, rep)
-        valid = (jnp.arange(kf.shape[2])[None, :]
-                 <= pos[:, None])[:, None, None, :]
-    else:
-        from quintnet_tpu.nn.attention import (_gather_kv,
-                                               paged_quant_update)
-
-        ks, vs = kv_scales
-        kg, vg = _gather_kv(kc, vc, (ks, vs), policy, block_tables,
-                            block_size=block_size)
-        ones = jnp.ones(pos.shape, jnp.int32)
-        kc, ks, kg = paged_quant_update(
-            policy, kc, ks, kg, k, pos[:, None], ones,
-            block_tables=block_tables, block_size=block_size,
-            max_blocks=1)
-        vc, vs, vg = paged_quant_update(
-            policy, vc, vs, vg, v, pos[:, None], ones,
-            block_tables=block_tables, block_size=block_size,
-            max_blocks=1)
-        pools = (kc, vc, ks, vs)
-        rep = q.shape[1] // kg.shape[1]
-        kf, vf = repeat_kv(kg, rep), repeat_kv(vg, rep)
-        valid = (jnp.arange(kf.shape[2])[None, :]
-                 <= pos[:, None])[:, None, None, :]
-    if kf is not None:
-        o = _masked_sdpa(q, kf, vf, valid, page=block_size)
-    x = llama_attn_residual(p["attn"], x, o, tp_axis=tp_axis,
-                            lora=attn_lora, lora_scale=lora_scale)
-    x, _aux, stats = llama_mlp_residual(
-        p, x, cfg, tp_axis=tp_axis, ep_axis=ep_axis,
-        lora=lora.get("mlp") if lora is not None else None,
-        lora_scale=lora_scale, return_stats=True)
-    out_pools = pools if pools is not None else (kc, vc)
-    if "moe" in p:
-        return x, (*out_pools, stats)
-    return x, out_pools
+    q, k, v = llama_qkv(p["attn"], a_in, cfg, cos, sin, tp=tp)
+    kc = lax.dynamic_update_slice_in_dim(kc, k.astype(kc.dtype), pos,
+                                         axis=2)
+    vc = lax.dynamic_update_slice_in_dim(vc, v.astype(vc.dtype), pos,
+                                         axis=2)
+    rep = q.shape[1] // kc.shape[1]
+    kf, vf = repeat_kv(kc, rep), repeat_kv(vc, rep)
+    valid = jnp.arange(kf.shape[2])[None, None, None, :] <= pos
+    o = _masked_sdpa(q, kf, vf, valid)
+    x = llama_attn_residual(p["attn"], x, o, tp_axis=tp_axis)
+    x, _aux = llama_mlp_residual(p, x, cfg, tp_axis=tp_axis)
+    return x, (kc, vc)
 
 
 def _positions(b, s, sp_axis: Optional[str]):

@@ -312,60 +312,102 @@ def mha_apply(
     return y
 
 
-def paged_cache_update(k_cache, v_cache, k, v, pos, *, block_tables,
-                       block_size: int):
-    """Write one token's (k, v) into a PAGED pool at each row's own
-    position. ``k_cache``/``v_cache``: [N_blocks*block_size, H, Dh] flat
-    pool views shared by every request; ``k``/``v``: [B, H, Dh];
-    ``pos``: [B] per-row write positions; ``block_tables``: [B, M]
+# ---------------------------------------------------------------------
+# The paged pool on the device: ``[L, N_blocks * block_size, F]`` per k
+# and v — every layer's token slots in ONE buffer, a token's heads
+# flattened into the minor dim and padded with zero lanes up to the
+# width serve/kv_pool.py allocates (``F >= H * Dh``, a whole number of
+# 128-lane vregs, so the chip lays the buffer out row-major). Programs
+# carry the pool WHOLE through their layer loop and address it by
+# ``(layer, slot)``: one scatter a write, one gather a read, in place.
+# Only the gathered view is split back into heads. ``F`` is read off
+# the pool's shape, ``H`` and ``Dh`` off the fresh projections.
+# ---------------------------------------------------------------------
+def _pool_rows(x, width: int):
+    """[..., H, Dh] -> pool rows [..., width]: heads flattened, zero
+    lanes up to the pool's width."""
+    rows = x.reshape(*x.shape[:-2], -1)
+    pad = width - rows.shape[-1]
+    if pad:
+        rows = jnp.pad(rows, [(0, 0)] * (rows.ndim - 1) + [(0, pad)])
+    return rows
+
+
+def _pool_heads(rows, heads: int, head_dim: int):
+    """Pool rows [..., F] -> [..., H, Dh]; the pad lanes end here —
+    nothing downstream ever sees them."""
+    return rows[..., :heads * head_dim].reshape(*rows.shape[:-1], heads,
+                                                head_dim)
+
+
+def paged_write(k_pool, v_pool, layer, k, v, positions, lens, *,
+                block_tables, block_size: int):
+    """Write every row's run of (k, v) into the PAGED pool at ``layer``,
+    one scatter a pool. ``k``/``v``: [S, H, P, Dh] (decode P = 1, verify
+    P = drafts + 1, prefill S = 1 and P = the bucket); ``positions``:
+    [S, P] absolute token positions; ``block_tables``: [S, M]
     logical-block -> pool-block indirection (serve/kv_pool.py).
 
-    Block 0 is the pool's reserved null block: inactive rows carry an
-    all-zero table row and pos 0, so their writes land at flat index 0
-    — garbage nobody reads (their scores are masked and the engine
-    drops their outputs). Duplicate index-0 scatters are benign for the
-    same reason."""
+    Block 0 is the pool's reserved null block: columns at or beyond a
+    row's ``lens`` (bucket and draft pad) and inactive rows (an
+    all-zero table row, position 0) land at flat slot 0 — garbage
+    nobody reads (their scores are masked and the engine drops their
+    outputs). Duplicate slot-0 scatters are benign for the same
+    reason."""
+    S, H, P, Dh = k.shape
+    M = block_tables.shape[1]
     with jax.named_scope("kv_write"):
-        blk = jnp.take_along_axis(
-            block_tables, (pos // block_size)[:, None], axis=1)[:, 0]
-        idx = blk * block_size + pos % block_size        # [B] flat slots
-        return (k_cache.at[idx].set(k.astype(k_cache.dtype)),
-                v_cache.at[idx].set(v.astype(v_cache.dtype)))
+        blk_idx = jnp.clip(positions // block_size, 0, M - 1)    # [S, P]
+        blk = jnp.take_along_axis(block_tables, blk_idx, axis=1)
+        idx = jnp.where(jnp.arange(P)[None, :] < lens[:, None],
+                        blk * block_size + positions % block_size,
+                        0).reshape(S * P)
+
+        def put(pool, x):
+            rows = _pool_rows(x.transpose(0, 2, 1, 3).reshape(S * P, H, Dh),
+                              pool.shape[-1])
+            return pool.at[layer, idx].set(rows.astype(pool.dtype))
+
+        return put(k_pool, k), put(v_pool, v)
 
 
-def paged_gather(cache, block_tables, *, block_size: int):
-    """[N_blocks*block_size, H, Dh] pool + [B, M] tables -> the
-    position-ordered per-row view [B, H, M*block_size, Dh]. Token
-    position t of a row lives at (table[t // bs], t % bs), so the
-    gathered view is exactly position-ordered and the usual
-    ``arange <= pos`` length mask applies unchanged."""
-    nb = cache.shape[0] // block_size
-    pages = cache.reshape(nb, block_size, *cache.shape[1:])[block_tables]
-    # [B, M, bs, H, Dh] -> [B, H, M*bs, Dh]
+def paged_gather(pool, layer, block_tables, *, block_size: int,
+                 head_shape):
+    """Pool [L, N_blocks*block_size, F] + [B, M] tables -> the
+    position-ordered per-row view [B, H, M*block_size, Dh] of
+    ``layer``, gathered straight from the whole pool. Token position t
+    of a row lives at (table[t // bs], t % bs), so the gathered view is
+    exactly position-ordered and the usual ``arange <= pos`` length
+    mask applies unchanged. ``head_shape`` = (H, Dh)."""
+    L, n, f = pool.shape
+    pages = pool.reshape(L, n // block_size, block_size, f)[
+        layer, block_tables]                          # [B, M, bs, F]
+    pages = _pool_heads(pages, *head_shape)           # [B, M, bs, H, Dh]
     b, m, bs, h, dh = pages.shape
     return pages.transpose(0, 3, 1, 2, 4).reshape(b, h, m * bs, dh)
 
 
-def paged_gather_scales(scales, block_tables, *, block_size: int):
-    """Per-block-per-head scales [num_blocks, H] + tables [B, M] -> the
-    position-ordered broadcast view [B, H, M*block_size, 1] matching
-    :func:`paged_gather`'s output: every slot of a block shares its
-    block's per-head scale."""
-    sc = scales[block_tables]                       # [B, M, H]
+def paged_gather_scales(scales, layer, block_tables, *, block_size: int):
+    """Per-block-per-head scales [L, num_blocks, H] + tables [B, M] ->
+    the position-ordered broadcast view [B, H, M*block_size, 1]
+    matching :func:`paged_gather`'s output: every slot of a block
+    shares its block's per-head scale."""
+    sc = scales[layer, block_tables]                # [B, M, H]
     b, m, h = sc.shape
     sc = jnp.broadcast_to(sc.transpose(0, 2, 1)[:, :, :, None],
                           (b, h, m, block_size))
     return sc.reshape(b, h, m * block_size)[..., None]
 
 
-def paged_gather_dequant(policy, cache, scales, block_tables, *,
-                         block_size: int):
+def paged_gather_dequant(policy, pool, scales, layer, block_tables, *,
+                         block_size: int, head_shape):
     """The DEQUANT-INSIDE-THE-KERNEL read: gather a row's blocks into
     the position-ordered view and dequantize with their block scales —
     [B, H, M*bs, Dh] f32, ready for the existing f32-softmax math.
     With ``scales=None`` (passthrough policies) this IS
     :func:`paged_gather`."""
-    view = paged_gather(cache, block_tables, block_size=block_size)
+    view = paged_gather(pool, layer, block_tables, block_size=block_size,
+                        head_shape=head_shape)
     if scales is None:
         # float8 pools (unscaled fp8 policy) upcast HERE — float8 has no
         # implicit-promotion path in jax, so the view must be widened
@@ -375,69 +417,39 @@ def paged_gather_dequant(policy, cache, scales, block_tables, *,
             return view.astype(jnp.float32)
         return view
     return policy.dequant(
-        view, paged_gather_scales(scales, block_tables,
+        view, paged_gather_scales(scales, layer, block_tables,
                                   block_size=block_size))
 
 
-def _gather_kv(k_cache, v_cache, kv_scales, policy, block_tables, *,
-               block_size: int):
+def _gather_kv(pools, layer, policy, block_tables, *, block_size: int,
+               head_shape):
     """THE paired gathered-view read every paged attention entry point
-    shares (prefill / ring / verify / decode had four verbatim copies):
-    gather both pools' rows position-ordered and — under a scaled
-    layout policy — dequantize with their block scales
-    (:func:`paged_gather_dequant`; ``kv_scales=None`` is the plain
-    :func:`paged_gather` pair). Also the single seam the fused-kernel
-    dispatch (``attn_kernel="pallas"``, ops/paged_attention.py) plugs
-    into INSTEAD of — the Pallas path never calls this."""
-    ks, vs = kv_scales if kv_scales is not None else (None, None)
+    shares: gather both pools' rows position-ordered and — under a
+    scaled layout policy, ``pools`` = (k, v, k_scale, v_scale) —
+    dequantize with their block scales (:func:`paged_gather_dequant`).
+    Also the single seam the fused-kernel dispatch
+    (``attn_kernel="pallas"``, ops/paged_attention.py) plugs into
+    INSTEAD of — the Pallas path never calls this."""
+    k_pool, v_pool, *sc = pools
+    ks, vs = sc if sc else (None, None)
     with jax.named_scope("kv_gather"):
-        k_all = paged_gather_dequant(policy, k_cache, ks, block_tables,
-                                     block_size=block_size)
-        v_all = paged_gather_dequant(policy, v_cache, vs, block_tables,
-                                     block_size=block_size)
+        k_all = paged_gather_dequant(policy, k_pool, ks, layer,
+                                     block_tables, block_size=block_size,
+                                     head_shape=head_shape)
+        v_all = paged_gather_dequant(policy, v_pool, vs, layer,
+                                     block_tables, block_size=block_size,
+                                     head_shape=head_shape)
     return k_all, v_all
 
 
-def _paged_attention_scaled(policy, k_cache, v_cache, ks, vs, q, k, v,
-                            positions, lens, block_tables, *,
-                            block_size: int, max_blocks: int):
-    """The scaled-policy fused-kernel step every pallas branch shares
-    (gpt2 + llama, decode/verify/prefill — six call sites, one calling
-    convention): score the exact f32 fresh run against the PRE-write
-    pool (ops/paged_attention.paged_attention with the fresh-kv
-    override — the oracle's post-insert view), then requantize only
-    the run's touched blocks, k and v symmetrically
-    (paged_quant_window_update — pool bytes byte-identical to the
-    gathered-view oracle's). ``positions`` [S, P] contiguous runs;
-    ``lens`` [S]. Returns (o, k_cache, v_cache, ks, vs) — a future
-    kernel-convention change (the Flash-Decoding evolution) edits
-    exactly here."""
-    from quintnet_tpu.ops.paged_attention import (
-        paged_attention, paged_quant_window_update)
-
-    o = paged_attention(q, k_cache, v_cache, block_tables,
-                        positions[:, 0], block_size=block_size,
-                        kv_scales=(ks, vs), policy=policy,
-                        fresh_kv=(k, v))
-    with jax.named_scope("kv_write"):
-        k_cache, ks = paged_quant_window_update(
-            policy, k_cache, ks, k, positions, lens,
-            block_tables=block_tables, block_size=block_size,
-            max_blocks=max_blocks)
-        v_cache, vs = paged_quant_window_update(
-            policy, v_cache, vs, v, positions, lens,
-            block_tables=block_tables, block_size=block_size,
-            max_blocks=max_blocks)
-    return o, k_cache, v_cache, ks, vs
-
-
-def paged_requant_scatter(policy, cache, scales, row_view, block_tables,
-                          first_blk, last_pos, *, block_size: int,
-                          max_blocks: int):
+def paged_requant_scatter(policy, pool, scales, layer, row_view,
+                          block_tables, first_blk, last_pos, *,
+                          block_size: int, max_blocks: int):
     """Quantize-on-scatter: requantize each row's TOUCHED logical
     blocks ``[first_blk[s], last_pos[s] // bs]`` from its f32 gathered
     view ``row_view`` [S, H, M*bs, Dh] — fresh per-block-per-head
-    absmax scales — and write blocks + scales back into the pool.
+    absmax scales — and write blocks + scales back into the pool at
+    ``layer``.
 
     ``last_pos`` [S] is each row's last WRITTEN token position: block
     slots beyond it are zeroed before the absmax, so recycled blocks'
@@ -474,19 +486,20 @@ def paged_requant_scatter(policy, cache, scales, row_view, block_tables,
     tgt = jnp.where(touched,
                     jnp.take_along_axis(block_tables, j_c, axis=1), 0)
     flat = tgt.reshape(-1)
-    nb = cache.shape[0] // bs
+    L, n, f = pool.shape
     K = max_blocks
     q = q.transpose(0, 2, 3, 1, 4).reshape(S * K, bs, H, Dh)
-    cache = cache.reshape(nb, bs, H, Dh).at[flat].set(q)
-    cache = cache.reshape(nb * bs, H, Dh)
-    scales = scales.at[flat].set(sc.transpose(0, 2, 1).reshape(S * K, H))
-    return cache, scales
+    pool = pool.reshape(L, n // bs, bs, f).at[layer, flat].set(
+        _pool_rows(q, f)).reshape(L, n, f)
+    scales = scales.at[layer, flat].set(
+        sc.transpose(0, 2, 1).reshape(S * K, H))
+    return pool, scales
 
 
-def paged_quant_update(policy, cache, scales, row_view, vals, positions,
-                       lens, *, block_tables, block_size: int,
+def paged_quant_update(policy, pool, scales, layer, row_view, vals,
+                       positions, lens, *, block_tables, block_size: int,
                        max_blocks: int):
-    """The quantized pool WRITE all three paged kernels share: insert
+    """The quantized pool WRITE every paged program shares: insert
     each row's fresh values into its dequantized f32 gathered view,
     then requantize + scatter back exactly the touched blocks
     (:func:`paged_requant_scatter`).
@@ -495,7 +508,7 @@ def paged_quant_update(policy, cache, scales, row_view, vals, positions,
     write; ``vals`` [S, H, P, Dh]: the fresh k or v run; ``positions``
     [S, P] absolute CONTIGUOUS write positions (``start_s +
     arange(P)``); ``lens`` [S]: columns at or beyond a row's len are
-    pad. Returns (cache, scales, the post-insert f32 view — what the
+    pad. Returns (pool, scales, the post-insert f32 view — what the
     attention scores read, so the math on it matches the passthrough
     scatter-then-gather path exactly).
 
@@ -518,135 +531,156 @@ def paged_quant_update(policy, cache, scales, row_view, vals, positions,
         row_view = padded[:, :, :T]
         first = positions[:, 0] // block_size
         last_pos = positions[:, 0] + lens - 1       # < first*bs if len 0
-        cache, scales = paged_requant_scatter(
-            policy, cache, scales, row_view, block_tables, first,
+        pool, scales = paged_requant_scatter(
+            policy, pool, scales, layer, row_view, block_tables, first,
             last_pos, block_size=block_size, max_blocks=max_blocks)
-    return cache, scales, row_view
+    return pool, scales, row_view
 
 
 def _quant_span(p_tokens: int, block_size: int, table_width: int) -> int:
     """Static window width for :func:`paged_requant_scatter`: the most
-    blocks a ``p_tokens``-long write run can touch."""
-    return min(-(-p_tokens // block_size) + 1, table_width)
+    blocks a contiguous ``p_tokens``-long write run can touch (one for
+    a single token, wherever it lands)."""
+    return min((p_tokens + block_size - 2) // block_size + 1, table_width)
 
 
-def paged_prefill_update(k_cache, v_cache, k, v, positions, tail_len, *,
-                         block_tables, block_size: int):
-    """Write one request's TAIL of (k, v) projections into the paged
-    pool. ``k``/``v``: [H, P, Dh] (P = padded tail bucket);
-    ``positions``: [P] absolute token positions (``start + arange(P)``
-    — the chunked-prefill offset); ``block_tables``: [M] this request's
-    table row. Rows at or beyond ``tail_len`` (pad columns, plus any
-    position past the table) scatter into the null block — memory
-    nobody reads, the same convention as :func:`paged_cache_update`."""
-    P = positions.shape[0]
+def paged_kv_step(pools, layer, k, v, positions, lens, block_tables, *,
+                  block_size: int, policy=None):
+    """Write the rows' fresh (k, v) runs into ``layer`` of the pool and
+    read every row's whole history back: ``(k_all, v_all, pools)``,
+    the views [S, H, M*bs, Dh] holding the runs just written.
+
+    ``pools`` = (k, v) under a passthrough policy: scatter, then gather
+    (:func:`paged_write`, :func:`_gather_kv`). ``pools`` = (k, v,
+    k_scale, v_scale) under a scaled one (serve/kv_quant.py): gather +
+    DEQUANT, insert the run into the f32 view, quantize exactly the
+    touched blocks back (:func:`paged_quant_update`) — the scores read
+    the exact f32 run, the pool its quantized bytes."""
+    head_shape = (k.shape[1], k.shape[3])
+    if len(pools) == 2:
+        pools = paged_write(*pools, layer, k, v, positions, lens,
+                            block_tables=block_tables,
+                            block_size=block_size)
+        k_all, v_all = _gather_kv(pools, layer, policy, block_tables,
+                                  block_size=block_size,
+                                  head_shape=head_shape)
+        return k_all, v_all, pools
+    k_pool, v_pool, ks, vs = pools
+    k_all, v_all = _gather_kv(pools, layer, policy, block_tables,
+                              block_size=block_size, head_shape=head_shape)
+    span = _quant_span(positions.shape[1], block_size,
+                       block_tables.shape[1])
+    k_pool, ks, k_all = paged_quant_update(
+        policy, k_pool, ks, layer, k_all, k, positions, lens,
+        block_tables=block_tables, block_size=block_size, max_blocks=span)
+    v_pool, vs, v_all = paged_quant_update(
+        policy, v_pool, vs, layer, v_all, v, positions, lens,
+        block_tables=block_tables, block_size=block_size, max_blocks=span)
+    return k_all, v_all, (k_pool, v_pool, ks, vs)
+
+
+def _paged_attend_pallas(q, k, v, pools, layer, positions, lens,
+                         block_tables, *, block_size: int, policy):
+    """``attn_kernel="pallas"``: the fused block-table-walking kernel
+    (ops/paged_attention.py) on ONE layer's pool. SLICE-AND-RESHAPE:
+    the kernel and its touched-block requantizer take a layer's
+    ``[slots, H, Dh]`` view, cut out of the carried pool here (and,
+    under a scaled policy, put back whole) — a layer-sized copy the
+    gathered-view path does not make. No benchmark cell runs this
+    backend (ROADMAP D3 decides its fate); its parity tests pin it to
+    the gathered-view math.
+
+    Passthrough: the pool is written first and the kernel reads the
+    fresh run back like any other slot. Scaled: the kernel scores the
+    exact f32 run against the PRE-write pool (the fresh-kv override —
+    the oracle's post-insert view), then only the run's touched blocks
+    requantize, k and v symmetrically (paged_quant_window_update —
+    pool bytes byte-identical to the gathered-view oracle's)."""
+    from quintnet_tpu.ops.paged_attention import (
+        paged_attention, paged_quant_window_update)
+
+    head_shape = (k.shape[1], k.shape[3])
+
+    def view(pool):
+        return _pool_heads(
+            lax.dynamic_index_in_dim(pool, layer, keepdims=False),
+            *head_shape)
+
+    if len(pools) == 2:
+        pools = paged_write(*pools, layer, k, v, positions, lens,
+                            block_tables=block_tables,
+                            block_size=block_size)
+        o = paged_attention(q, view(pools[0]), view(pools[1]),
+                            block_tables, positions[:, 0],
+                            block_size=block_size)
+        return o, pools
+    k_pool, v_pool, ks, vs = pools
+    kc, vc, ksl, vsl = view(k_pool), view(v_pool), ks[layer], vs[layer]
+    o = paged_attention(q, kc, vc, block_tables, positions[:, 0],
+                        block_size=block_size, kv_scales=(ksl, vsl),
+                        policy=policy, fresh_kv=(k, v))
+    span = _quant_span(positions.shape[1], block_size,
+                       block_tables.shape[1])
+    out = []
     with jax.named_scope("kv_write"):
-        blk_idx = jnp.clip(positions // block_size, 0,
-                           block_tables.shape[0] - 1)
-        idx = jnp.where(jnp.arange(P) < tail_len,
-                        block_tables[blk_idx] * block_size
-                        + positions % block_size, 0)
-        kin = k.transpose(1, 0, 2).astype(k_cache.dtype)   # [P, H, Dh]
-        vin = v.transpose(1, 0, 2).astype(v_cache.dtype)
-        return k_cache.at[idx].set(kin), v_cache.at[idx].set(vin)
+        for pool, cache, sc, scl, vals in ((k_pool, kc, ks, ksl, k),
+                                           (v_pool, vc, vs, vsl, v)):
+            cache, scl = paged_quant_window_update(
+                policy, cache, scl, vals, positions, lens,
+                block_tables=block_tables, block_size=block_size,
+                max_blocks=span)
+            out.append((lax.dynamic_update_slice(
+                pool, cache.reshape(1, cache.shape[0], -1), (layer, 0, 0)),
+                sc.at[layer].set(scl)))
+    (k_pool, ks), (v_pool, vs) = out
+    return o, (k_pool, v_pool, ks, vs)
 
 
-def mha_prefill_paged(p, x, k_cache, v_cache, positions, tail_len, *,
-                      num_heads: int, tp_axis: Optional[str] = None,
-                      block_tables=None, block_size: Optional[int] = None,
-                      lora=None, lora_scale=None,
-                      kv_scales=None, policy=None,
-                      attn_kernel: str = "xla"):
-    """Chunked prefill over the paged pool: attention for ONE request's
-    uncached tail, reading the cached prefix from pool blocks.
+def paged_attend(q, k, v, pools, layer, positions, lens, block_tables, *,
+                 block_size: int, policy=None, attn_kernel: str = "xla",
+                 scale: Optional[float] = None):
+    """THE paged attention of every serving program, family and layout
+    policy: each row's run of queries against its own cached history,
+    the run's keys and values written into ``layer`` of the carried
+    pool on the way. Returns ``(o, pools)``.
 
-    ``x``: [1, P, D] tail hidden states (positions ``start ..
-    start + P``); the tail's (k, v) are scattered through the block
-    table first (:func:`paged_prefill_update`), then the WHOLE row —
-    cached prefix + fresh tail — is gathered back position-ordered
-    (:func:`paged_gather`) and each tail query attends causally against
-    it: column t is valid iff ``t <= positions[i]``. With ``start == 0``
-    this is ordinary causal prefill expressed on the paged layout
-    (the serve engine's single prefill family — cache-off and cache-on
-    run the SAME program, only ``start`` differs), and the math on the
-    gathered view matches :func:`mha_decode`'s paged path exactly.
+    ``q``: [S, Hq, G*P, Dh] — P tokens a row (decode 1, verify drafts +
+    1, prefill S = 1 and the bucket), for ``Hq`` a multiple of the pool's
+    kv heads (those are repeated over the gathered view) and/or with the
+    ``G`` query heads that share a kv head laid out as rows of one score
+    matrix (the view is then contracted once per kv head). ``k``/``v``:
+    [S, Hkv, P, Dh], the fresh UNrepeated projections at absolute
+    ``positions`` [S, P]; ``lens`` [S]: a row's columns at or beyond
+    its len are pad. Column t of a row's view is valid for query i iff
+    ``t <= positions[s, i]``: a decode step, a verify run and a
+    (chunked) prefill are this one mask at different widths, which is
+    what makes their tokens bit-equal.
 
-    Returns (y [1, P, D], k_cache, v_cache). ``num_heads`` is LOCAL
-    heads under ``tp_axis`` (head-sharded pool + RowParallel psum, same
-    as the decode path).
-
-    ``lora``/``lora_scale``: per-slot packed adapters (serving
-    multi-LoRA; nn/layers.lora_delta) — qkv's delta lands before the
-    head split, proj's before the psum.
-
-    ``kv_scales``/``policy`` (serve/kv_quant.py): a scaled layout
-    policy reads the row via gather + DEQUANT, inserts the tail into
-    the f32 view, runs the identical score math, and quantizes the
-    touched blocks back on scatter; the return grows to
-    (y, k_cache, v_cache, k_scale, v_scale).
-
-    ``attn_kernel``: "xla" (default) is the gathered-view math above;
-    "pallas" routes the attention through the fused block-table-walking
-    kernel (ops/paged_attention.py) — same mask, same softmax sequence,
-    bit-parity-pinned against this path — and under a scaled policy the
-    pool write requantizes only the touched blocks
-    (paged_quant_window_update) so the [H, M*bs, Dh] gathered view is
-    never materialized."""
-    q, k, v = _qkv_heads(p, x, num_heads, lora, lora_scale)
-    ks = vs = None
+    ``attn_kernel``: "xla" is the gathered-view math
+    (:func:`paged_kv_step` + :func:`_masked_sdpa`); "pallas" the fused
+    kernel (:func:`_paged_attend_pallas`) — same mask, same softmax
+    sequence, bit-parity-pinned against this path."""
     if attn_kernel == "pallas":
-        tables = block_tables[None]
-        if kv_scales is None:
-            from quintnet_tpu.ops.paged_attention import paged_attention
-
-            k_cache, v_cache = paged_prefill_update(
-                k_cache, v_cache, k[0], v[0], positions, tail_len,
-                block_tables=block_tables, block_size=block_size)
-            o = paged_attention(q, k_cache, v_cache, tables,
-                                positions[:1], block_size=block_size)
-        else:
-            ks, vs = kv_scales
-            o, k_cache, v_cache, ks, vs = _paged_attention_scaled(
-                policy, k_cache, v_cache, ks, vs, q, k, v,
-                positions[None, :], jnp.reshape(tail_len, (1,)),
-                tables, block_size=block_size,
-                max_blocks=_quant_span(positions.shape[0], block_size,
-                                       block_tables.shape[0]))
-    else:
-        if kv_scales is None:
-            k_cache, v_cache = paged_prefill_update(
-                k_cache, v_cache, k[0], v[0], positions, tail_len,
-                block_tables=block_tables, block_size=block_size)
-            k_all, v_all = _gather_kv(
-                k_cache, v_cache, None, policy, block_tables[None],
-                block_size=block_size)            # [1, H, M*bs, Dh]
-        else:
-            ks, vs = kv_scales
-            tables = block_tables[None]
-            k_all, v_all = _gather_kv(k_cache, v_cache, (ks, vs),
-                                      policy, tables,
-                                      block_size=block_size)
-            span = _quant_span(positions.shape[0], block_size,
-                               block_tables.shape[0])
-            pos2 = positions[None, :]
-            lens = jnp.reshape(tail_len, (1,))
-            k_cache, ks, k_all = paged_quant_update(
-                policy, k_cache, ks, k_all, k, pos2, lens,
-                block_tables=tables, block_size=block_size,
-                max_blocks=span)
-            v_cache, vs, v_all = paged_quant_update(
-                policy, v_cache, vs, v_all, v, pos2, lens,
-                block_tables=tables, block_size=block_size,
-                max_blocks=span)
-        valid = (jnp.arange(k_all.shape[2])[None, :]
-                 <= positions[:, None])               # [P, M*bs]
-        o = _masked_sdpa(q, k_all, v_all, valid[None, None],
-                         page=block_size)
-
-    y = _proj_out(p, o, tp_axis, lora, lora_scale)
-    if kv_scales is not None:
-        return y, k_cache, v_cache, ks, vs
-    return y, k_cache, v_cache
+        if scale is not None:
+            raise NotImplementedError(
+                "the fused paged kernel scales its scores by "
+                "1/sqrt(head_dim) only; a stated score scale needs "
+                "attn_kernel='xla'")
+        return _paged_attend_pallas(
+            q, k, v, pools, layer, positions, lens, block_tables,
+            block_size=block_size, policy=policy)
+    k_all, v_all, pools = paged_kv_step(
+        pools, layer, k, v, positions, lens, block_tables,
+        block_size=block_size, policy=policy)
+    rep = q.shape[1] // k.shape[1]
+    valid = (jnp.arange(k_all.shape[2])[None, None, :]
+             <= positions[:, :, None])[:, None]           # [S, 1, P, T]
+    groups = q.shape[2] // k.shape[2]
+    if groups > 1 and valid.shape[2] > 1:
+        valid = jnp.tile(valid, (1, 1, groups, 1))
+    o = _masked_sdpa(q, repeat_kv(k_all, rep), repeat_kv(v_all, rep),
+                     valid, page=block_size, scale=scale)
+    return o, pools
 
 
 def _online_merge(m, l, acc, m_new, l_new, o_new):
@@ -666,9 +700,9 @@ def _online_merge(m, l, acc, m_new, l_new, o_new):
             acc * c_old[..., None] + o_new * c_new[..., None])
 
 
-def ring_paged_prefill(q, k, v, start, t0, k_cache, v_cache, *,
+def ring_paged_prefill(q, k, v, start, t0, pools, layer, *,
                        sp_axis: str, block_tables, block_size: int,
-                       kv_scales=None, policy=None):
+                       policy=None):
     """Sequence-parallel chunk attention over the paged pool: ring
     attention (Liu et al., RingAttention — PAPERS.md) across mesh axis
     ``sp_axis`` for the chunk's own K/V, merged online with each local
@@ -683,7 +717,9 @@ def ring_paged_prefill(q, k, v, start, t0, k_cache, v_cache, *,
     the wire). ``start``/``t0`` are the chunk's dynamic token bounds:
     positions at or beyond ``t0`` are bucket pad — their keys are
     masked out of every score and their pool writes land in the null
-    block, exactly :func:`paged_prefill_update`'s convention.
+    block, exactly :func:`paged_write`'s convention. ``pools`` is the
+    carried pool tuple — (k, v), or (k, v, k_scale, v_scale) under a
+    scaled layout policy — addressed at ``layer``.
 
     Per call the sp wire carries ``2*sp`` ppermutes (the stacked K/V
     pair and its position vector rotate ``sp`` scan steps) plus one
@@ -694,7 +730,7 @@ def ring_paged_prefill(q, k, v, start, t0, k_cache, v_cache, *,
     with device count, not one chip's memory.
 
     Returns (o [1, Hq, Pl, Dh] normalized local attention output,
-    k_cache, v_cache with the WHOLE chunk scattered)."""
+    pools with the WHOLE chunk scattered)."""
     sp = lax.axis_size(sp_axis)
     idx = lax.axis_index(sp_axis)
     b, hq, pl, dh = q.shape
@@ -723,12 +759,10 @@ def ring_paged_prefill(q, k, v, start, t0, k_cache, v_cache, *,
     # Scaled layout policies (serve/kv_quant.py) dequantize the
     # gathered prefix here — the sp pool is replicated, so every rank
     # dequantizes (and later requantizes) identically.
-    ks = vs = None
-    if kv_scales is not None:
-        ks, vs = kv_scales
-    k_pool, v_pool = _gather_kv(k_cache, v_cache, kv_scales, policy,
-                                block_tables[None],
-                                block_size=block_size)
+    tables = block_tables[None]
+    k_pool, v_pool = _gather_kv(pools, layer, policy, tables,
+                                block_size=block_size,
+                                head_shape=(k.shape[1], dh))
     pool_mask = jnp.broadcast_to(
         jnp.arange(k_pool.shape[2])[None, :] < start,
         (pl, k_pool.shape[2]))
@@ -756,27 +790,24 @@ def ring_paged_prefill(q, k, v, start, t0, k_cache, v_cache, *,
     # they are start + arange(P) by construction
     kv_full = lax.all_gather(jnp.stack([k[0], v[0]]), sp_axis, axis=2,
                              tiled=True)               # [2, Hkv, P, Dh]
-    positions = start + jnp.arange(pl * sp, dtype=jnp.int32)
-    if kv_scales is None:
-        k_cache, v_cache = paged_prefill_update(
-            k_cache, v_cache, kv_full[0], kv_full[1], positions,
-            t0 - start, block_tables=block_tables, block_size=block_size)
-        return o, k_cache, v_cache
+    pos2 = (start + jnp.arange(pl * sp, dtype=jnp.int32))[None, :]
+    lens = jnp.reshape(t0 - start, (1,))
+    if len(pools) == 2:
+        return o, paged_write(*pools, layer, kv_full[0][None],
+                              kv_full[1][None], pos2, lens,
+                              block_tables=tables, block_size=block_size)
     # quantize-on-scatter (no extra collectives: the gathered prefix
     # views already hold the row, the chunk inserts into them and only
     # the touched private blocks requantize — every rank identically)
+    k_cache, v_cache, ks, vs = pools
     span = _quant_span(pl * sp, block_size, block_tables.shape[0])
-    pos2 = positions[None, :]
-    lens = jnp.reshape(t0 - start, (1,))
     k_cache, ks, _ = paged_quant_update(
-        policy, k_cache, ks, k_pool, kv_full[0][None], pos2, lens,
-        block_tables=block_tables[None], block_size=block_size,
-        max_blocks=span)
+        policy, k_cache, ks, layer, k_pool, kv_full[0][None], pos2, lens,
+        block_tables=tables, block_size=block_size, max_blocks=span)
     v_cache, vs, _ = paged_quant_update(
-        policy, v_cache, vs, v_pool, kv_full[1][None], pos2, lens,
-        block_tables=block_tables[None], block_size=block_size,
-        max_blocks=span)
-    return o, k_cache, v_cache, ks, vs
+        policy, v_cache, vs, layer, v_pool, kv_full[1][None], pos2, lens,
+        block_tables=tables, block_size=block_size, max_blocks=span)
+    return o, (k_cache, v_cache, ks, vs)
 
 
 def sp_last_hidden(h, start, t0, *, sp_axis: str):
@@ -797,13 +828,20 @@ def sp_last_hidden(h, start, t0, *, sp_axis: str):
                     sp_axis)
 
 
+def _pool_tuple(k_cache, v_cache, kv_scales):
+    """The carried pool tuple the paged helpers take, from the
+    ``k_cache, v_cache, kv_scales=`` arguments of the entry points."""
+    return (k_cache, v_cache, *(kv_scales if kv_scales is not None else ()))
+
+
 def mha_prefill_paged_sp(p, x, k_cache, v_cache, start, t0, *,
-                         num_heads: int, sp_axis: str,
+                         num_heads: int, sp_axis: str, layer,
                          tp_axis: Optional[str] = None,
                          block_tables=None,
                          block_size: Optional[int] = None,
                          kv_scales=None, policy=None):
-    """:func:`mha_prefill_paged`'s sequence-parallel sibling: ``x``
+    """A one-row :func:`mha_verify_paged`'s sequence-parallel sibling
+    (the chunked prefill of a long prompt): ``x``
     [1, Pl, D] is this sp rank's slice of the chunk's hidden states;
     the attention runs through :func:`ring_paged_prefill` (K/V sharded
     over ``sp_axis`` during the score pass, reassembled once for the
@@ -812,226 +850,85 @@ def mha_prefill_paged_sp(p, x, k_cache, v_cache, start, t0, *,
     (adapters, sp) combination at construction."""
     q, k, v = _qkv_heads(p, x, num_heads)
     with jax.named_scope("sdpa"):
-        out = ring_paged_prefill(
-            q, k, v, start, t0, k_cache, v_cache, sp_axis=sp_axis,
-            block_tables=block_tables, block_size=block_size,
-            kv_scales=kv_scales, policy=policy)
-    o, pools = out[0], out[1:]
+        o, pools = ring_paged_prefill(
+            q, k, v, start, t0, _pool_tuple(k_cache, v_cache, kv_scales),
+            layer, sp_axis=sp_axis, block_tables=block_tables,
+            block_size=block_size, policy=policy)
     return (_proj_out(p, o, tp_axis), *pools)
 
 
-def paged_verify_update(k_cache, v_cache, k, v, positions, tail_lens, *,
-                        block_tables, block_size: int):
-    """Write EVERY row's short token run into the paged pool in one
-    scatter — the speculative-verify write (serve/spec.py). ``k``/``v``:
-    [S, H, P, Dh] (P = draft bucket + 1); ``positions``: [S, P] absolute
-    per-row positions (``start_s + arange(P)``); ``tail_lens``: [S] —
-    row columns at or beyond a row's tail_len (draft pad, inactive
-    slots) scatter into the null block, the same convention as
-    :func:`paged_prefill_update` batched over rows."""
-    S, P = positions.shape
-    M = block_tables.shape[1]
-    with jax.named_scope("kv_write"):
-        blk_idx = jnp.clip(positions // block_size, 0, M - 1)    # [S, P]
-        blk = jnp.take_along_axis(block_tables, blk_idx, axis=1)
-        idx = jnp.where(jnp.arange(P)[None, :] < tail_lens[:, None],
-                        blk * block_size + positions % block_size, 0)
-        H, Dh = k.shape[1], k.shape[3]
-        kin = k.transpose(0, 2, 1, 3).reshape(S * P, H, Dh)
-        vin = v.transpose(0, 2, 1, 3).reshape(S * P, H, Dh)
-        flat = idx.reshape(S * P)
-        return (k_cache.at[flat].set(kin.astype(k_cache.dtype)),
-                v_cache.at[flat].set(vin.astype(v_cache.dtype)))
-
-
 def mha_verify_paged(p, x, k_cache, v_cache, positions, tail_lens, *,
-                     num_heads: int, tp_axis: Optional[str] = None,
+                     num_heads: int, layer,
+                     tp_axis: Optional[str] = None,
                      block_tables=None, block_size: Optional[int] = None,
                      lora=None, lora_scale=None,
                      kv_scales=None, policy=None,
                      attn_kernel: str = "xla"):
-    """Batched draft-verify attention over the paged pool: EVERY slot
-    scores a short run of tokens (its last sampled token + up to k
-    drafted continuations) against its own cached row in ONE forward —
-    the decode path widened from 1 to P tokens per row (speculative
-    decoding's target-scoring step, serve/spec.py).
+    """Paged attention of one layer, for every serving program: EVERY
+    slot scores a run of tokens against its own cached row in ONE
+    forward (:func:`paged_attend`) — speculative decoding's
+    target-scoring step (serve/spec.py) when the run is the last
+    sampled token + drafted continuations, a decode step at P == 1, a
+    (chunked) prefill at S == 1.
 
     ``x``: [S, P, D] per-slot token runs at absolute ``positions``
-    [S, P]; the runs' (k, v) scatter through each row's block table
-    first (:func:`paged_verify_update`, pad columns masked to the null
-    block by ``tail_lens``), then each row's whole history — cached
-    prefix + fresh run — is gathered back position-ordered
-    (:func:`paged_gather`) and each token attends causally against it:
-    column t is valid iff ``t <= positions[s, i]``. With P == 1 this IS
-    :func:`mha_decode`'s paged path; the math on the gathered view is
-    identical, so verify-committed tokens are bit-equal to plain
-    decoded ones.
+    [S, P]; ``k_cache``/``v_cache``: the WHOLE pool
+    [L, N_blocks*block_size, F] (serve/kv_pool.py), written and read at
+    ``layer``; ``block_tables`` [S, M] maps each row's logical blocks
+    to pool blocks. The runs' (k, v) scatter through each row's block
+    table first (pad columns masked to the null block by
+    ``tail_lens``), then each row's whole history — cached prefix +
+    fresh run — is gathered back position-ordered and each token
+    attends causally against it: column t is valid iff ``t <=
+    positions[s, i]``.
 
     Returns (y [S, P, D], k_cache, v_cache). ``num_heads`` is LOCAL
     heads under ``tp_axis`` (head-sharded pool + RowParallel psum).
-    ``lora``/``lora_scale``: per-slot packed adapters, exactly as in
-    :func:`mha_decode`. ``attn_kernel="pallas"``: the fused
-    block-table-walking kernel instead of the gathered view (exactly
-    :func:`mha_prefill_paged`'s contract, batched over rows)."""
+    ``lora``/``lora_scale``: per-slot packed adapters (serving
+    multi-LoRA; nn/layers.lora_delta) — qkv's delta lands before the
+    head split, proj's before the psum; zero-adapter rows are
+    base-model rows exactly.
+
+    ``kv_scales``/``policy`` (serve/kv_quant.py): under a scaled layout
+    policy ``kv_scales`` is the (k_scale, v_scale) pair of whole
+    [L, num_blocks, H] arrays, addressed at ``layer`` like the pools,
+    and the return grows to (y, k_cache, v_cache, k_scale, v_scale).
+
+    ``attn_kernel="pallas"``: the fused block-table-walking kernel
+    instead of the gathered view (ops/paged_attention.py)."""
     q, k, v = _qkv_heads(p, x, num_heads, lora, lora_scale)
-    ks = vs = None
-    if attn_kernel == "pallas":
-        if kv_scales is None:
-            from quintnet_tpu.ops.paged_attention import paged_attention
-
-            k_cache, v_cache = paged_verify_update(
-                k_cache, v_cache, k, v, positions, tail_lens,
-                block_tables=block_tables, block_size=block_size)
-            o = paged_attention(q, k_cache, v_cache, block_tables,
-                                positions[:, 0], block_size=block_size)
-        else:
-            ks, vs = kv_scales
-            o, k_cache, v_cache, ks, vs = _paged_attention_scaled(
-                policy, k_cache, v_cache, ks, vs, q, k, v,
-                positions, tail_lens, block_tables,
-                block_size=block_size,
-                max_blocks=_quant_span(positions.shape[1], block_size,
-                                       block_tables.shape[1]))
-    else:
-        if kv_scales is None:
-            k_cache, v_cache = paged_verify_update(
-                k_cache, v_cache, k, v, positions, tail_lens,
-                block_tables=block_tables, block_size=block_size)
-            k_all, v_all = _gather_kv(k_cache, v_cache, None, policy,
-                                      block_tables,
-                                      block_size=block_size)
-        else:
-            ks, vs = kv_scales
-            k_all, v_all = _gather_kv(k_cache, v_cache, (ks, vs),
-                                      policy, block_tables,
-                                      block_size=block_size)
-            span = _quant_span(positions.shape[1], block_size,
-                               block_tables.shape[1])
-            k_cache, ks, k_all = paged_quant_update(
-                policy, k_cache, ks, k_all, k, positions, tail_lens,
-                block_tables=block_tables, block_size=block_size,
-                max_blocks=span)
-            v_cache, vs, v_all = paged_quant_update(
-                policy, v_cache, vs, v_all, v, positions, tail_lens,
-                block_tables=block_tables, block_size=block_size,
-                max_blocks=span)
-        valid = (jnp.arange(k_all.shape[2])[None, None, :]
-                 <= positions[:, :, None])                # [S, P, T]
-        o = _masked_sdpa(q, k_all, v_all, valid[:, None], page=block_size)
-
-    y = _proj_out(p, o, tp_axis, lora, lora_scale)
-    if kv_scales is not None:
-        return y, k_cache, v_cache, ks, vs
-    return y, k_cache, v_cache
+    o, pools = paged_attend(
+        q, k, v, _pool_tuple(k_cache, v_cache, kv_scales), layer,
+        positions, tail_lens, block_tables, block_size=block_size,
+        policy=policy, attn_kernel=attn_kernel)
+    return (_proj_out(p, o, tp_axis, lora, lora_scale), *pools)
 
 
 def mha_decode(p, x, k_cache, v_cache, pos, *, num_heads: int,
-               tp_axis: Optional[str] = None,
-               block_tables=None, block_size: Optional[int] = None,
-               lora=None, lora_scale=None,
-               kv_scales=None, policy=None,
-               attn_kernel: str = "xla"):
-    """Single-token cached attention. Returns (y, k_cache, v_cache).
-
-    Dense (single-request fast path, ``block_tables=None``): x [B, 1, D],
-    caches [B, H, T, Dh], ``pos`` the (dynamic, scalar) write position
-    shared by the whole batch.
-
-    Paged (continuous-batching path): caches are FLAT POOL VIEWS
-    [N_blocks*block_size, H, Dh] shared by all requests, ``pos`` is a
-    [B] vector (each row decodes at its own depth) and ``block_tables``
-    [B, M] maps each row's logical blocks to pool blocks
-    (serve/kv_pool.py). Writes scatter through the table
-    (:func:`paged_cache_update`); reads gather the row's blocks back
-    into a position-ordered view (:func:`paged_gather`). Same math as
-    the dense path on the gathered view — tests/test_serve.py holds the
-    two token-for-token equal.
+               tp_axis: Optional[str] = None):
+    """Single-token cached attention on the DENSE single-request cache
+    (models/gpt2_generate.py): x [B, 1, D], caches [B, H, T, Dh],
+    ``pos`` the (dynamic, scalar) write position shared by the whole
+    batch. Returns (y, k_cache, v_cache). The continuous-batching
+    decode step is :func:`mha_verify_paged` at one token a row — same
+    math on the gathered view; tests/test_serve.py holds the two
+    token-for-token equal.
 
     The reference's generation loop re-runs the full prefix every step
     (utils/metrics.py:74-149, O(T^2) per token); here one token attends
     against the cache — O(T) per token, fully jittable (static shapes,
-    dynamic_update_slice / table-scatter for the cache write, masked
-    softmax over the not-yet-written tail).
+    dynamic_update_slice for the cache write, masked softmax over the
+    not-yet-written tail).
 
     ``tp_axis``: head-sharded decode — ``num_heads`` is LOCAL heads, the
     cache holds this rank's heads, and the output projection psums over
     the axis (RowParallel, same as mha_apply's training path). The
     reference skips generation entirely under any parallelism
-    (GPT2_Trainer.py:509-555).
-
-    ``lora``/``lora_scale``: per-slot packed adapters (multi-tenant
-    LoRA serving, serve/adapters.py) — row s applies ITS adapter's
-    low-rank delta on the qkv and proj matmuls (nn/layers.lora_delta);
-    zero-adapter rows are base-model rows exactly.
-
-    ``attn_kernel="pallas"`` (paged path only): the fused
-    block-table-walking kernel (ops/paged_attention.py) instead of the
-    gathered-view math — bit-parity-pinned, never materializes the
-    [B, H, M*bs, Dh] view."""
-    q, k, v = _qkv_heads(p, x, num_heads, lora, lora_scale)
-    ks = vs = None
-    if block_tables is None:
-        if kv_scales is not None:
-            raise ValueError(
-                "scaled KV layout policies exist only for the paged "
-                "pool (block_tables is required)")
-        if attn_kernel != "xla":
-            raise ValueError(
-                "attn_kernel='pallas' exists only for the paged pool "
-                "(block_tables is required)")
-        with jax.named_scope("kv_write"):
-            k_cache = lax.dynamic_update_slice(k_cache, k, (0, 0, pos, 0))
-            v_cache = lax.dynamic_update_slice(v_cache, v, (0, 0, pos, 0))
-        k_all, v_all = k_cache, v_cache
-        valid = (jnp.arange(k_cache.shape[2]) <= pos)[None, :]  # [1, T]
-    elif attn_kernel == "pallas":
-        if kv_scales is None:
-            from quintnet_tpu.ops.paged_attention import paged_attention
-
-            k_cache, v_cache = paged_cache_update(
-                k_cache, v_cache, k[:, :, 0], v[:, :, 0], pos,
-                block_tables=block_tables, block_size=block_size)
-            o = paged_attention(q, k_cache, v_cache, block_tables, pos,
-                                block_size=block_size)
-        else:
-            ks, vs = kv_scales
-            o, k_cache, v_cache, ks, vs = _paged_attention_scaled(
-                policy, k_cache, v_cache, ks, vs, q, k, v,
-                pos[:, None], jnp.ones(pos.shape, jnp.int32),
-                block_tables, block_size=block_size, max_blocks=1)
-        k_all = None
-    elif kv_scales is None:
-        # pool layout is [slot, H, Dh]: k here is [B, H, 1, Dh]
-        k_cache, v_cache = paged_cache_update(
-            k_cache, v_cache, k[:, :, 0], v[:, :, 0], pos,
-            block_tables=block_tables, block_size=block_size)
-        k_all, v_all = _gather_kv(k_cache, v_cache, None, policy,
-                                  block_tables, block_size=block_size)
-        valid = jnp.arange(k_all.shape[2])[None, :] <= pos[:, None]
-    else:
-        # scaled layout (serve/kv_quant.py): dequantized gathered view,
-        # token inserted in f32, ONE touched block per row requantized
-        # back — inactive rows (pos 0, null table) round-trip the null
-        # block, which nobody reads
-        ks, vs = kv_scales
-        k_all, v_all = _gather_kv(k_cache, v_cache, (ks, vs), policy,
-                                  block_tables, block_size=block_size)
-        ones = jnp.ones(pos.shape, jnp.int32)
-        k_cache, ks, k_all = paged_quant_update(
-            policy, k_cache, ks, k_all, k, pos[:, None], ones,
-            block_tables=block_tables, block_size=block_size,
-            max_blocks=1)
-        v_cache, vs, v_all = paged_quant_update(
-            policy, v_cache, vs, v_all, v, pos[:, None], ones,
-            block_tables=block_tables, block_size=block_size,
-            max_blocks=1)
-        valid = jnp.arange(k_all.shape[2])[None, :] <= pos[:, None]
-
-    if k_all is not None:
-        o = _masked_sdpa(q, k_all, v_all, valid[:, None, None, :],
-                         page=block_size)
-
-    y = _proj_out(p, o, tp_axis, lora, lora_scale)
-    if kv_scales is not None:
-        return y, k_cache, v_cache, ks, vs
-    return y, k_cache, v_cache
+    (GPT2_Trainer.py:509-555)."""
+    q, k, v = _qkv_heads(p, x, num_heads)
+    with jax.named_scope("kv_write"):
+        k_cache = lax.dynamic_update_slice(k_cache, k, (0, 0, pos, 0))
+        v_cache = lax.dynamic_update_slice(v_cache, v, (0, 0, pos, 0))
+    valid = (jnp.arange(k_cache.shape[2]) <= pos)[None, :]      # [1, T]
+    o = _masked_sdpa(q, k_cache, v_cache, valid[:, None, None, :])
+    return _proj_out(p, o, tp_axis), k_cache, v_cache

@@ -20,7 +20,6 @@ import jax
 import jax.numpy as jnp
 
 from quintnet_tpu.nn.attention import (mha_apply, mha_decode, mha_init,
-                                       mha_prefill_paged,
                                        mha_prefill_paged_sp,
                                        mha_verify_paged)
 from quintnet_tpu.nn.layers import (
@@ -270,48 +269,8 @@ def block_prefill(p, x, *, num_heads: int, act: Callable = gelu,
     return x, (k, v)
 
 
-def block_prefill_paged(p, x, k_cache, v_cache, positions, tail_len, *,
-                        num_heads: int, act: Callable = gelu,
-                        moe_args: Optional[MoEArgs] = None,
-                        ep_axis: Optional[str] = None,
-                        tp_axis: Optional[str] = None,
-                        block_tables=None,
-                        block_size: Optional[int] = None,
-                        lora=None, lora_scale=None,
-                        kv_scales=None, policy=None,
-                        attn_kernel: str = "xla"):
-    """Chunked-prefill block step over the paged pool (nn/attention.py
-    mha_prefill_paged): x [1, P, D] tail hidden states at absolute
-    ``positions``, caches are flat pool views — the serve engine's
-    prefix-cached prefill path. ``lora``/``lora_scale``: this layer's
-    packed per-slot adapters (serving multi-LoRA; serve/adapters.py).
-    ``kv_scales``/``policy``: scaled KV layout (serve/kv_quant.py) —
-    this layer's (k_scale, v_scale) ride along and come back.
-    ``ep_axis``: MoE expert parallelism — experts sharded over the
-    axis, one all_to_all each way inside the FFN (nn/moe.py). Returns
-    (x, k_cache, v_cache[, k_scale, v_scale][, moe_stats]) — MoE
-    blocks append their routing-stats dict."""
-    attn_lora = lora.get("attn") if lora is not None else None
-    with jax.named_scope("attn"):
-        out = mha_prefill_paged(
-            p["attn"], layer_norm_apply(p["ln1"], x), k_cache, v_cache,
-            positions, tail_len, num_heads=num_heads, tp_axis=tp_axis,
-            block_tables=block_tables, block_size=block_size,
-            lora=attn_lora, lora_scale=lora_scale,
-            kv_scales=kv_scales, policy=policy, attn_kernel=attn_kernel)
-        x = x + out[0]
-    x, stats = _block_mlp(
-        p, x, act=act, moe_args=moe_args, ep_axis=ep_axis,
-        tp_axis=tp_axis,
-        lora=lora.get("mlp") if lora is not None else None,
-        lora_scale=lora_scale)
-    if moe_args is not None:
-        return (x, *out[1:], stats)
-    return (x, *out[1:])
-
-
 def block_prefill_paged_sp(p, x, k_cache, v_cache, start, t0, *,
-                           num_heads: int, sp_axis: str,
+                           num_heads: int, sp_axis: str, layer,
                            act: Callable = gelu,
                            moe_args: Optional[MoEArgs] = None,
                            tp_axis: Optional[str] = None,
@@ -324,11 +283,11 @@ def block_prefill_paged_sp(p, x, k_cache, v_cache, start, t0, *,
     the attention rides ring_paged_prefill over ``sp_axis`` while the
     LN/MLP halves are position-wise and stay local. Returns
     (x, k_cache, v_cache[, k_scale, v_scale]) with the whole chunk's
-    K/V scattered into the (sp-replicated) pool."""
+    K/V scattered into ``layer`` of the (sp-replicated) pool."""
     with jax.named_scope("attn"):
         out = mha_prefill_paged_sp(
             p["attn"], layer_norm_apply(p["ln1"], x), k_cache, v_cache,
-            start, t0, num_heads=num_heads, sp_axis=sp_axis,
+            start, t0, num_heads=num_heads, sp_axis=sp_axis, layer=layer,
             tp_axis=tp_axis, block_tables=block_tables,
             block_size=block_size, kv_scales=kv_scales, policy=policy)
         x = x + out[0]
@@ -340,7 +299,7 @@ def block_prefill_paged_sp(p, x, k_cache, v_cache, start, t0, *,
 
 
 def block_verify_paged(p, x, k_cache, v_cache, positions, tail_lens, *,
-                       num_heads: int, act: Callable = gelu,
+                       num_heads: int, layer, act: Callable = gelu,
                        moe_args: Optional[MoEArgs] = None,
                        ep_axis: Optional[str] = None,
                        tp_axis: Optional[str] = None,
@@ -349,21 +308,26 @@ def block_verify_paged(p, x, k_cache, v_cache, positions, tail_lens, *,
                        lora=None, lora_scale=None,
                        kv_scales=None, policy=None,
                        attn_kernel: str = "xla"):
-    """Batched draft-verify block step (nn/attention.mha_verify_paged):
-    x [S, P, D] per-slot token runs at absolute ``positions`` [S, P],
-    caches are flat pool views — the serve engine's speculative-decode
-    scoring path (serve/spec.py). ``lora``/``lora_scale``: this layer's
-    packed per-slot adapters. ``kv_scales``/``policy``: scaled KV
-    layout (serve/kv_quant.py). ``ep_axis``: expert parallelism for
-    MoE blocks (nn/moe.py). Returns
-    (x, k_cache, v_cache[, k_scale, v_scale][, moe_stats])."""
+    """The paged block step of every serving program
+    (nn/attention.mha_verify_paged): x [S, P, D] per-slot token runs at
+    absolute ``positions`` [S, P] — a decode step at P == 1, a
+    (chunked) prefill at S == 1, speculative decoding's scoring run
+    between (serve/spec.py). The caches are the WHOLE pool, written and
+    read at ``layer``. ``lora``/``lora_scale``: this layer's packed
+    per-slot adapters (serving multi-LoRA; serve/adapters.py).
+    ``kv_scales``/``policy``: scaled KV layout (serve/kv_quant.py) —
+    the whole (k_scale, v_scale) arrays ride along and come back.
+    ``ep_axis``: MoE expert parallelism — experts sharded over the
+    axis, one all_to_all each way inside the FFN (nn/moe.py). Returns
+    (x, k_cache, v_cache[, k_scale, v_scale][, moe_stats]) — MoE
+    blocks append their routing-stats dict."""
     attn_lora = lora.get("attn") if lora is not None else None
     with jax.named_scope("attn"):
         out = mha_verify_paged(
             p["attn"], layer_norm_apply(p["ln1"], x), k_cache, v_cache,
-            positions, tail_lens, num_heads=num_heads, tp_axis=tp_axis,
-            block_tables=block_tables, block_size=block_size,
-            lora=attn_lora, lora_scale=lora_scale,
+            positions, tail_lens, num_heads=num_heads, layer=layer,
+            tp_axis=tp_axis, block_tables=block_tables,
+            block_size=block_size, lora=attn_lora, lora_scale=lora_scale,
             kv_scales=kv_scales, policy=policy, attn_kernel=attn_kernel)
         x = x + out[0]
     x, stats = _block_mlp(
@@ -379,36 +343,16 @@ def block_verify_paged(p, x, k_cache, v_cache, positions, tail_lens, *,
 def block_decode(p, x, k_cache, v_cache, pos, *, num_heads: int,
                  act: Callable = gelu,
                  moe_args: Optional[MoEArgs] = None,
-                 ep_axis: Optional[str] = None,
-                 tp_axis: Optional[str] = None,
-                 block_tables=None, block_size: Optional[int] = None,
-                 lora=None, lora_scale=None,
-                 kv_scales=None, policy=None,
-                 attn_kernel: str = "xla"):
-    """Single-token cached block step (nn/attention.py mha_decode).
-
-    With ``block_tables``/``block_size`` the caches are paged-pool flat
-    views and ``pos`` is per-row — the continuous-batching decode path
-    (quintnet_tpu/serve/); default is the dense single-request cache.
-    ``lora``/``lora_scale``: this layer's packed per-slot adapters
-    (multi-tenant LoRA serving). ``kv_scales``/``policy``: scaled KV
-    layout (serve/kv_quant.py; paged path only). ``ep_axis``: expert
-    parallelism for MoE blocks (nn/moe.py) — returns
-    (x, k_cache, v_cache[, k_scale, v_scale][, moe_stats])."""
-    attn_lora = lora.get("attn") if lora is not None else None
+                 tp_axis: Optional[str] = None):
+    """Single-token cached block step on the dense single-request
+    cache (nn/attention.py mha_decode; models/gpt2_generate.py). The
+    continuous-batching decode step is :func:`block_verify_paged` at
+    one token a row. Returns (x, k_cache, v_cache)."""
     with jax.named_scope("attn"):
-        out = mha_decode(
+        a, k_cache, v_cache = mha_decode(
             p["attn"], layer_norm_apply(p["ln1"], x), k_cache, v_cache,
-            pos, num_heads=num_heads, tp_axis=tp_axis,
-            block_tables=block_tables, block_size=block_size,
-            lora=attn_lora, lora_scale=lora_scale,
-            kv_scales=kv_scales, policy=policy, attn_kernel=attn_kernel)
-        x = x + out[0]
-    x, stats = _block_mlp(
-        p, x, act=act, moe_args=moe_args, ep_axis=ep_axis,
-        tp_axis=tp_axis,
-        lora=lora.get("mlp") if lora is not None else None,
-        lora_scale=lora_scale)
-    if moe_args is not None:
-        return (x, *out[1:], stats)
-    return (x, *out[1:])
+            pos, num_heads=num_heads, tp_axis=tp_axis)
+        x = x + a
+    x, _stats = _block_mlp(p, x, act=act, moe_args=moe_args, ep_axis=None,
+                           tp_axis=tp_axis)
+    return x, k_cache, v_cache

@@ -1,7 +1,7 @@
 """Fused paged-attention Pallas kernels for the serving hot paths.
 
-The serving attention entry points (nn/attention.py ``mha_decode``,
-``mha_prefill_paged``, ``mha_verify_paged`` and the llama twins) are
+The serving attention entry point (nn/attention.py ``paged_attend``,
+under ``mha_verify_paged`` and the llama and hybrid blocks) is
 gathered-view math on the XLA path: materialize every block of a row's
 block table into a position-ordered ``[S, H, T, Dh]`` HBM view
 (``paged_gather``), matmul against it, and — under a scaled KV layout

@@ -38,8 +38,8 @@ scheduling; vLLM-style paged KV blocks):
   requests (powers-of-two padded lengths, at most one compiled program
   per bucket), EOS / max-len retirement;
 - :mod:`families` — the GPT-2 / Llama model adapters (thin reuse of
-  nn/attention.mha_decode's paged path and the generate modules'
-  embed/logits helpers);
+  nn/attention.paged_attend and the generate modules' embed/logits
+  helpers);
 - :mod:`adapters` — multi-tenant LoRA: an adapter registry (host-side
   LRU of safetensors adapter weights, refcount pinning) + per-slot
   packed low-rank factors so heterogeneous-adapter requests batch into

@@ -611,8 +611,7 @@ class ServeEngine:
         if mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec as P
 
-            sharding = NamedSharding(mesh,
-                                     P(None, None, self.tp_axis, None))
+            sharding = NamedSharding(mesh, P(None, None, self.tp_axis))
             scale_sharding = NamedSharding(mesh,
                                            P(None, None, self.tp_axis))
         # host-RAM second tier under the prefix cache (serve/
@@ -871,8 +870,13 @@ class ServeEngine:
             dst = table_row[jnp.clip(start // bs, 0, M - 1)]
             dst_idx = jnp.where(sl < cow_len, dst * bs + sl, 0)
             src_idx = cow_src * bs + sl
-            k_pool = k_pool.at[:, dst_idx].set(k_pool[:, src_idx])
-            v_pool = v_pool.at[:, dst_idx].set(v_pool[:, src_idx])
+            # addressed (layer, slot) like every program's writes: a
+            # slice of all layers at once (``[:, idx]``) makes the
+            # chip's compiler re-lay the whole pool layer-minor for the
+            # copy and back after it (four pool-sized copies a prefill)
+            every = jnp.arange(k_pool.shape[0])[:, None]
+            k_pool = k_pool.at[every, dst_idx].set(k_pool[every, src_idx])
+            v_pool = v_pool.at[every, dst_idx].set(v_pool[every, src_idx])
             if scaled:
                 ksd = jnp.where(cow_len > 0, k_scale[:, cow_src],
                                 k_scale[:, dst])
@@ -1049,9 +1053,9 @@ class ServeEngine:
                 out_specs=(P(),) * n_pool + (P(), P()))
             return jax.jit(smapped, donate_argnums=donate)
 
-        pool_specs = (P(None, None, self.tp_axis, None),) * 2
-        if self.kv_policy.scaled:
-            pool_specs = pool_specs + (P(None, None, self.tp_axis),) * 2
+        # k, v [L, slots, F] and, under a scaled policy, their scales
+        # [L, blocks, H]: all head-sharded on dim 2
+        pool_specs = (P(None, None, self.tp_axis),) * n_pool
         pspecs = self.family.partition_specs(self.tp_axis, self.ep_axis)
         if self.weight_policy.scaled:
             # scaled weight policies add a w_scale leaf per target; its

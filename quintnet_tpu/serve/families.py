@@ -2,13 +2,21 @@
 
 One tiny record per family (GPT-2, Llama) giving the engine a uniform
 (chunked-prefill, paged-decode, partition-specs) surface. Nothing here
-forks model math: prefill scans the paged block bodies
-(nn/transformer.block_prefill_paged / models/llama.llama_block_prefill_paged
-— the same attention math as the decode path, batched over the tail),
-paged decode scans block_decode / llama_block_decode with
-``block_tables`` (the nn/attention.mha_decode paged path), and
-embedding/logits reuse the generate modules' vocab-parallel-aware
-helpers — a fix in any of those fixes serving too.
+forks model math: every paged program — prefill, decode, verify — scans
+ONE paged block body (nn/transformer.block_verify_paged /
+models/llama.llama_block_verify_paged, ending in
+nn/attention.paged_attend) at its own width: a decode step is one token
+a row, a prefill one row, and embedding/logits reuse the generate
+modules' vocab-parallel-aware helpers — a fix in any of those fixes
+serving too.
+
+The pool in the programs: ``k_pool``/``v_pool`` are the WHOLE pool
+``[L, num_blocks * block_size, F]`` (serve/kv_pool.py). They ride the
+layer scan's CARRY (:func:`_scan_layers`) and every layer writes and
+reads them in place at ``(layer, slot)``; the programs return the
+carried buffers, which the engine's donation aliases to the arguments.
+No pool-shaped array is ever a scan's xs or ys
+(analysis.pool_scan_operands).
 
 Prefill contract (chunked, prefix-cache aware): ``prefill_from(params,
 k_pool, v_pool, ids [1, P], start, t0, table_row [M], block_size,
@@ -44,7 +52,7 @@ Quantized KV (serve/kv_quant.py): every contract additionally takes
 ``kv_scales=None, policy=None`` — under a SCALED layout policy (int8,
 fake_quant) ``kv_scales`` is the ``(k_scale, v_scale)`` pair of
 ``[L, num_blocks, H_kv]`` per-block-per-head scale arrays that ride
-the layer scan beside the pools, and the return tuple widens
+the layer scan's carry beside the pools, and the return tuple widens
 symmetrically to ``(logits, k_pool, v_pool, k_scale, v_scale)``. The
 block bodies dequantize inside the gathered view and quantize on
 scatter; ``kv_scales=None`` (the passthrough policies) is
@@ -95,6 +103,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from quintnet_tpu.nn.attention import _pool_tuple
 from quintnet_tpu.serve.kv_pool import NULL_BLOCK, StateShapes
 
 
@@ -158,35 +167,80 @@ class Family:
 # GPT-2
 # --------------------------------------------------------------------
 
-def _scan_xs(blocks, k_pool, v_pool, lora, kv_scales=None):
-    """The layer-scan xs: block params + pool views (+ the per-layer
-    (k_scale, v_scale) pair for scaled KV layout policies, + the packed
-    lora tree when adapters ride — every leaf has leading L)."""
-    xs = (blocks, k_pool, v_pool)
-    if kv_scales is not None:
-        xs = xs + tuple(kv_scales)
-    if lora is not None:
-        xs = xs + (lora,)
-    return xs
+def _scan_layers(step, h, pools, blocks, lora, moe: bool):
+    """THE layer scan of every uniform family's serving program, under
+    the scope ``blocks``. The pool buffers ride the CARRY, whole —
+    ``step(blk, layer, lora_l, h, pools) -> (h, *pools[, moe_stats])``
+    writes and reads them in place at ``(layer, slot)`` — so the scan
+    slices nothing of the pool in and stacks nothing of it out. The xs
+    are the block weights, the layer index and the packed lora tree
+    (every leaf leading L; None when no adapters ride); the ys the MoE
+    routing stats only. Returns ``(h, *pools[, reduced stats])``."""
+    n = len(pools)
+    depth = jax.tree.leaves(blocks)[0].shape[0]
 
+    def body(carry, xs):
+        blk, layer, lr = xs
+        out = step(blk, layer, lr, carry[0], carry[1:])
+        return out[:1 + n], (out[1 + n] if moe else None)
 
-def _scan_layer(layer, lora, scaled: bool = False):
-    """(blk, kc, vc, (ks, vs)-or-None, per-layer-lora-or-None) from one
-    scan slice, mirroring :func:`_scan_xs`'s packing order."""
-    it = iter(layer)
-    blk, kc, vc = next(it), next(it), next(it)
-    sc = (next(it), next(it)) if scaled else None
-    lr = next(it) if lora is not None else None
-    return blk, kc, vc, sc, lr
-
-
-def _scan_blocks(body, h, xs):
-    """The layer scan of every serving program, under the scope
-    ``blocks``: what the scan itself does with its xs and ys (slicing
-    a layer's pool view out, stacking it back) is named on a device
-    trace like the layers' own work."""
     with jax.named_scope("blocks"):
-        return lax.scan(body, h, xs)
+        out, st = lax.scan(body, (h, *pools),
+                           (blocks, jnp.arange(depth), lora))
+    if moe:
+        return (*out, _reduce_moe_stats(st))
+    return out
+
+
+def _paged_contracts(run, logits):
+    """``prefill_from`` / ``decode`` / ``verify`` of a uniform family
+    (module docstring) from its ONE layer run at three widths:
+    ``run(params, ids [S, P], pools, positions [S, P], lens [S], tables
+    [S, M], block_size, **kw) -> (h [S, P, D], *pools[, moe_stats])``
+    and ``logits(params, h, tp_axis)``. A prefill is one row of the
+    bucket's width, a decode step one token a row."""
+    def call(params, k_pool, v_pool, kv_scales, ids, positions, lens,
+             tables, block_size, tp_axis, **kw):
+        return run(params, ids, _pool_tuple(k_pool, v_pool, kv_scales),
+                   positions, lens, tables, block_size, tp_axis=tp_axis,
+                   **kw)
+
+    def prefill_from(params, k_pool, v_pool, ids, start, t0, table_row,
+                     block_size, tp_axis=None, ep_axis=None, lora=None,
+                     lora_scale=None, kv_scales=None, policy=None,
+                     attn_kernel="xla"):
+        positions = (start + jnp.arange(ids.shape[1], dtype=jnp.int32))[None]
+        h, *pools = call(
+            params, k_pool, v_pool, kv_scales, ids, positions,
+            jnp.reshape(t0 - start, (1,)), table_row[None], block_size,
+            tp_axis, ep_axis=ep_axis, lora=lora, lora_scale=lora_scale,
+            policy=policy, attn_kernel=attn_kernel)
+        h_last = lax.dynamic_slice_in_dim(h, t0 - 1 - start, 1, axis=1)
+        return (logits(params, h_last, tp_axis)[:, 0, :], *pools)
+
+    def decode(params, k_pool, v_pool, tok, pos, tables, block_size,
+               tp_axis=None, ep_axis=None, lora=None, lora_scale=None,
+               kv_scales=None, policy=None, attn_kernel="xla"):
+        h, *pools = call(
+            params, k_pool, v_pool, kv_scales, tok[:, None], pos[:, None],
+            jnp.ones(pos.shape, jnp.int32), tables, block_size, tp_axis,
+            ep_axis=ep_axis, lora=lora, lora_scale=lora_scale,
+            policy=policy, attn_kernel=attn_kernel)
+        return (logits(params, h, tp_axis)[:, 0, :], *pools)
+
+    def verify(params, k_pool, v_pool, ids, starts, tail_lens, tables,
+               block_size, tp_axis=None, ep_axis=None, lora=None,
+               lora_scale=None, kv_scales=None, policy=None,
+               attn_kernel="xla"):
+        positions = (starts[:, None] + jnp.arange(
+            ids.shape[1], dtype=jnp.int32)[None, :])           # [S, P]
+        h, *pools = call(
+            params, k_pool, v_pool, kv_scales, ids, positions, tail_lens,
+            tables, block_size, tp_axis, ep_axis=ep_axis, lora=lora,
+            lora_scale=lora_scale, policy=policy, attn_kernel=attn_kernel)
+        return (logits(params, h, tp_axis), *pools)
+
+    return prefill_from, decode, verify
 
 
 def _reduce_moe_stats(st):
@@ -210,110 +264,37 @@ def gpt2_family(cfg) -> Family:
     from quintnet_tpu.models.lora import DEFAULT_TARGETS
     from quintnet_tpu.nn.attention import sp_last_hidden
     from quintnet_tpu.nn.layers import gelu
-    from quintnet_tpu.nn.transformer import (block_decode,
-                                             block_prefill_paged,
-                                             block_prefill_paged_sp,
+    from quintnet_tpu.nn.transformer import (block_prefill_paged_sp,
                                              block_verify_paged)
 
-    def prefill_from(params, k_pool, v_pool, ids, start, t0, table_row,
-                     block_size, tp_axis=None, ep_axis=None, lora=None,
-                     lora_scale=None, kv_scales=None, policy=None,
-                     attn_kernel="xla"):
-        B, P = ids.shape
+    moe = cfg.moe_args is not None
+
+    def embed(params, ids, positions, tp_axis):
         emb = params["embedding"]
-        positions = start + jnp.arange(P, dtype=jnp.int32)
         # pad rows may sit past n_positions; clip their (ignored) wpe read
         safe_pos = jnp.clip(positions, 0, emb["wpe"].shape[0] - 1)
         with jax.named_scope("embed"):
-            h = (_embed_tok(emb, ids, cfg, tp_axis)
-                 + jnp.take(emb["wpe"], safe_pos, axis=0)[None])
+            return (_embed_tok(emb, ids, cfg, tp_axis)
+                    + jnp.take(emb["wpe"], safe_pos, axis=0))
+
+    def run(params, ids, pools, positions, lens, tables, block_size, *,
+            tp_axis, ep_axis, lora, lora_scale, policy, attn_kernel):
         heads = _local_heads(cfg, tp_axis)
-        tail_len = t0 - start
-        scaled = kv_scales is not None
 
-        def body(x, layer):
-            blk, kc, vc, sc, lr = _scan_layer(layer, lora, scaled)
-            out = block_prefill_paged(
-                blk, x, kc, vc, positions, tail_len, num_heads=heads,
-                act=gelu, moe_args=cfg.moe_args, ep_axis=ep_axis,
-                tp_axis=tp_axis,
-                block_tables=table_row, block_size=block_size,
-                lora=lr, lora_scale=lora_scale,
-                kv_scales=sc, policy=policy, attn_kernel=attn_kernel)
-            return out[0], out[1:]
+        def step(blk, layer, lr, x, pools):
+            return block_verify_paged(
+                blk, x, *pools[:2], positions, lens, num_heads=heads,
+                layer=layer, act=gelu, moe_args=cfg.moe_args,
+                ep_axis=ep_axis, tp_axis=tp_axis, block_tables=tables,
+                block_size=block_size, lora=lr, lora_scale=lora_scale,
+                kv_scales=pools[2:] or None, policy=policy,
+                attn_kernel=attn_kernel)
 
-        h, pools = _scan_blocks(
-            body, h, _scan_xs(params["blocks"], k_pool, v_pool, lora,
-                              kv_scales))
-        if cfg.moe_args is not None:
-            *pools, st = pools
-            pools = (*pools, _reduce_moe_stats(st))
-        h_last = lax.dynamic_slice_in_dim(h, t0 - 1 - start, 1, axis=1)
-        return (_logits(params, h_last, cfg, tp_axis)[:, 0, :], *pools)
+        return _scan_layers(step, embed(params, ids, positions, tp_axis),
+                            pools, params["blocks"], lora, moe)
 
-    def decode(params, k_pool, v_pool, tok, pos, tables, block_size,
-               tp_axis=None, ep_axis=None, lora=None, lora_scale=None,
-               kv_scales=None, policy=None, attn_kernel="xla"):
-        emb = params["embedding"]
-        with jax.named_scope("embed"):
-            x = (_embed_tok(emb, tok[:, None], cfg, tp_axis)
-                 + jnp.take(emb["wpe"], pos, axis=0)[:, None, :])
-        heads = _local_heads(cfg, tp_axis)
-        scaled = kv_scales is not None
-
-        def body(h, layer):
-            blk, kc, vc, sc, lr = _scan_layer(layer, lora, scaled)
-            out = block_decode(blk, h, kc, vc, pos, num_heads=heads,
-                               act=gelu, moe_args=cfg.moe_args,
-                               ep_axis=ep_axis,
-                               tp_axis=tp_axis, block_tables=tables,
-                               block_size=block_size,
-                               lora=lr, lora_scale=lora_scale,
-                               kv_scales=sc, policy=policy,
-                               attn_kernel=attn_kernel)
-            return out[0], out[1:]
-
-        h, pools = _scan_blocks(
-            body, x, _scan_xs(params["blocks"], k_pool, v_pool, lora,
-                              kv_scales))
-        if cfg.moe_args is not None:
-            *pools, st = pools
-            pools = (*pools, _reduce_moe_stats(st))
-        return (_logits(params, h, cfg, tp_axis)[:, 0, :], *pools)
-
-    def verify(params, k_pool, v_pool, ids, starts, tail_lens, tables,
-               block_size, tp_axis=None, ep_axis=None, lora=None,
-               lora_scale=None, kv_scales=None, policy=None,
-               attn_kernel="xla"):
-        S, P = ids.shape
-        emb = params["embedding"]
-        positions = (starts[:, None]
-                     + jnp.arange(P, dtype=jnp.int32)[None, :])  # [S, P]
-        safe_pos = jnp.clip(positions, 0, emb["wpe"].shape[0] - 1)
-        with jax.named_scope("embed"):
-            h = (_embed_tok(emb, ids, cfg, tp_axis)
-                 + jnp.take(emb["wpe"], safe_pos, axis=0))
-        heads = _local_heads(cfg, tp_axis)
-        scaled = kv_scales is not None
-
-        def body(x, layer):
-            blk, kc, vc, sc, lr = _scan_layer(layer, lora, scaled)
-            out = block_verify_paged(
-                blk, x, kc, vc, positions, tail_lens, num_heads=heads,
-                act=gelu, moe_args=cfg.moe_args, ep_axis=ep_axis,
-                tp_axis=tp_axis,
-                block_tables=tables, block_size=block_size,
-                lora=lr, lora_scale=lora_scale,
-                kv_scales=sc, policy=policy, attn_kernel=attn_kernel)
-            return out[0], out[1:]
-
-        h, pools = _scan_blocks(
-            body, h, _scan_xs(params["blocks"], k_pool, v_pool, lora,
-                              kv_scales))
-        if cfg.moe_args is not None:
-            *pools, st = pools
-            pools = (*pools, _reduce_moe_stats(st))
-        return (_logits(params, h, cfg, tp_axis), *pools)
+    prefill_from, decode, verify = _paged_contracts(
+        run, lambda params, h, tp_axis: _logits(params, h, cfg, tp_axis))
 
     def prefill_from_sp(params, k_pool, v_pool, ids, start, t0,
                         table_row, block_size, *, sp_axis: str,
@@ -322,30 +303,23 @@ def gpt2_family(cfg) -> Family:
         # (the engine shard_maps the bucket over sp); positions are the
         # rank's absolute offsets, so embedding/rope/masking all land
         # exactly where the single-device program puts them
-        B, Pl = ids.shape
-        idx = lax.axis_index(sp_axis)
-        emb = params["embedding"]
-        positions = (start + idx * Pl
-                     + jnp.arange(Pl, dtype=jnp.int32))
-        safe_pos = jnp.clip(positions, 0, emb["wpe"].shape[0] - 1)
-        with jax.named_scope("embed"):
-            h = (_embed_tok(emb, ids, cfg, tp_axis)
-                 + jnp.take(emb["wpe"], safe_pos, axis=0)[None])
+        Pl = ids.shape[1]
+        positions = (start + lax.axis_index(sp_axis) * Pl
+                     + jnp.arange(Pl, dtype=jnp.int32))[None]
         heads = _local_heads(cfg, tp_axis)
-        scaled = kv_scales is not None
 
-        def body(x, layer):
-            blk, kc, vc, sc, _ = _scan_layer(layer, None, scaled)
-            out = block_prefill_paged_sp(
-                blk, x, kc, vc, start, t0, num_heads=heads,
-                sp_axis=sp_axis, act=gelu, moe_args=cfg.moe_args,
-                tp_axis=tp_axis, block_tables=table_row,
-                block_size=block_size, kv_scales=sc, policy=policy)
-            return out[0], out[1:]
+        def step(blk, layer, _lr, x, pools):
+            return block_prefill_paged_sp(
+                blk, x, *pools[:2], start, t0, num_heads=heads,
+                sp_axis=sp_axis, layer=layer, act=gelu,
+                moe_args=cfg.moe_args, tp_axis=tp_axis,
+                block_tables=table_row, block_size=block_size,
+                kv_scales=pools[2:] or None, policy=policy)
 
-        h, pools = _scan_blocks(
-            body, h, _scan_xs(params["blocks"], k_pool, v_pool, None,
-                              kv_scales))
+        h, *pools = _scan_layers(
+            step, embed(params, ids, positions, tp_axis),
+            _pool_tuple(k_pool, v_pool, kv_scales), params["blocks"],
+            None, False)
         h_last = sp_last_hidden(h, start, t0, sp_axis=sp_axis)
         return (_logits(params, h_last, cfg, tp_axis)[:, 0, :], *pools)
 
@@ -377,9 +351,7 @@ def gpt2_family(cfg) -> Family:
 # --------------------------------------------------------------------
 
 def llama_family(cfg) -> Family:
-    from quintnet_tpu.models.llama import (llama_block_decode,
-                                           llama_block_prefill_paged,
-                                           llama_block_prefill_paged_sp,
+    from quintnet_tpu.models.llama import (llama_block_prefill_paged_sp,
                                            llama_block_verify_paged,
                                            llama_partition_specs,
                                            llama_rope_tables)
@@ -387,116 +359,51 @@ def llama_family(cfg) -> Family:
     from quintnet_tpu.models.lora import LLAMA_TARGETS
     from quintnet_tpu.nn.attention import sp_last_hidden
 
-    def prefill_from(params, k_pool, v_pool, ids, start, t0, table_row,
-                     block_size, tp_axis=None, ep_axis=None, lora=None,
-                     lora_scale=None, kv_scales=None, policy=None,
-                     attn_kernel="xla"):
-        B, P = ids.shape
-        h = _embed(params, ids, cfg, tp_axis)
-        positions = start + jnp.arange(P, dtype=jnp.int32)
-        cos, sin = llama_rope_tables(positions, cfg)      # [P, hd]
-        tail_len = t0 - start
-        scaled = kv_scales is not None
+    moe = cfg.moe_args is not None
 
-        def body(x, layer):
-            blk, kc, vc, sc, lr = _scan_layer(layer, lora, scaled)
-            x, pools = llama_block_prefill_paged(
-                blk, x, kc, vc, positions, tail_len, cfg, cos, sin,
-                tp_axis=tp_axis, ep_axis=ep_axis, block_tables=table_row,
-                block_size=block_size, lora=lr, lora_scale=lora_scale,
-                kv_scales=sc, policy=policy, attn_kernel=attn_kernel)
-            return x, pools
-
-        h, pools = _scan_blocks(
-            body, h, _scan_xs(params["blocks"], k_pool, v_pool, lora,
-                              kv_scales))
-        if cfg.moe_args is not None:
-            *pools, st = pools
-            pools = (*pools, _reduce_moe_stats(st))
-        h_last = lax.dynamic_slice_in_dim(h, t0 - 1 - start, 1, axis=1)
-        return (_full_logits(params, h_last, cfg, tp_axis)[:, 0, :],
-                *pools)
-
-    def decode(params, k_pool, v_pool, tok, pos, tables, block_size,
-               tp_axis=None, ep_axis=None, lora=None, lora_scale=None,
-               kv_scales=None, policy=None, attn_kernel="xla"):
-        x = _embed(params, tok[:, None], cfg, tp_axis)        # [S, 1, D]
-        cos, sin = llama_rope_tables(pos, cfg)                # [S, hd]
-        cos, sin = cos[:, None, None, :], sin[:, None, None, :]
-        scaled = kv_scales is not None
-
-        def body(h, layer):
-            blk, kc, vc, sc, lr = _scan_layer(layer, lora, scaled)
-            h, pools = llama_block_decode(
-                blk, h, kc, vc, pos, cfg, cos, sin, tp_axis=tp_axis,
-                ep_axis=ep_axis,
-                block_tables=tables, block_size=block_size,
-                lora=lr, lora_scale=lora_scale,
-                kv_scales=sc, policy=policy, attn_kernel=attn_kernel)
-            return h, pools
-
-        h, pools = _scan_blocks(
-            body, x, _scan_xs(params["blocks"], k_pool, v_pool, lora,
-                              kv_scales))
-        if cfg.moe_args is not None:
-            *pools, st = pools
-            pools = (*pools, _reduce_moe_stats(st))
-        return (_full_logits(params, h, cfg, tp_axis)[:, 0, :], *pools)
-
-    def verify(params, k_pool, v_pool, ids, starts, tail_lens, tables,
-               block_size, tp_axis=None, ep_axis=None, lora=None,
-               lora_scale=None, kv_scales=None, policy=None,
-               attn_kernel="xla"):
-        S, P = ids.shape
-        h = _embed(params, ids, cfg, tp_axis)                 # [S, P, D]
-        positions = (starts[:, None]
-                     + jnp.arange(P, dtype=jnp.int32)[None, :])
+    def run(params, ids, pools, positions, lens, tables, block_size, *,
+            tp_axis, ep_axis, lora, lora_scale, policy, attn_kernel):
         cos, sin = llama_rope_tables(positions, cfg)          # [S, P, hd]
         cos, sin = cos[:, None], sin[:, None]                 # [S,1,P,hd]
-        scaled = kv_scales is not None
 
-        def body(x, layer):
-            blk, kc, vc, sc, lr = _scan_layer(layer, lora, scaled)
+        def step(blk, layer, lr, x, pools):
             x, pools = llama_block_verify_paged(
-                blk, x, kc, vc, positions, tail_lens, cfg, cos, sin,
-                tp_axis=tp_axis, ep_axis=ep_axis, block_tables=tables,
-                block_size=block_size, lora=lr, lora_scale=lora_scale,
-                kv_scales=sc, policy=policy, attn_kernel=attn_kernel)
-            return x, pools
+                blk, x, *pools[:2], positions, lens, cfg, cos, sin,
+                layer=layer, tp_axis=tp_axis, ep_axis=ep_axis,
+                block_tables=tables, block_size=block_size, lora=lr,
+                lora_scale=lora_scale, kv_scales=pools[2:] or None,
+                policy=policy, attn_kernel=attn_kernel)
+            return (x, *pools)
 
-        h, pools = _scan_blocks(
-            body, h, _scan_xs(params["blocks"], k_pool, v_pool, lora,
-                              kv_scales))
-        if cfg.moe_args is not None:
-            *pools, st = pools
-            pools = (*pools, _reduce_moe_stats(st))
-        return (_full_logits(params, h, cfg, tp_axis), *pools)
+        return _scan_layers(step, _embed(params, ids, cfg, tp_axis), pools,
+                            params["blocks"], lora, moe)
+
+    prefill_from, decode, verify = _paged_contracts(
+        run, lambda params, h, tp_axis: _full_logits(params, h, cfg,
+                                                     tp_axis))
 
     def prefill_from_sp(params, k_pool, v_pool, ids, start, t0,
                         table_row, block_size, *, sp_axis: str,
                         tp_axis=None, kv_scales=None, policy=None):
         # ids: [1, P/sp] — this sp rank's chunk slice; rope tables come
         # from the rank's LOCAL absolute positions
-        B, Pl = ids.shape
-        idx = lax.axis_index(sp_axis)
-        h = _embed(params, ids, cfg, tp_axis)
-        positions = (start + idx * Pl
+        Pl = ids.shape[1]
+        positions = (start + lax.axis_index(sp_axis) * Pl
                      + jnp.arange(Pl, dtype=jnp.int32))
         cos, sin = llama_rope_tables(positions, cfg)      # [Pl, hd]
-        scaled = kv_scales is not None
 
-        def body(x, layer):
-            blk, kc, vc, sc, _ = _scan_layer(layer, None, scaled)
+        def step(blk, layer, _lr, x, pools):
             x, pools = llama_block_prefill_paged_sp(
-                blk, x, kc, vc, start, t0, cfg, cos, sin,
-                sp_axis=sp_axis, tp_axis=tp_axis,
+                blk, x, *pools[:2], start, t0, cfg, cos, sin,
+                sp_axis=sp_axis, layer=layer, tp_axis=tp_axis,
                 block_tables=table_row, block_size=block_size,
-                kv_scales=sc, policy=policy)
-            return x, pools
+                kv_scales=pools[2:] or None, policy=policy)
+            return (x, *pools)
 
-        h, pools = _scan_blocks(
-            body, h, _scan_xs(params["blocks"], k_pool, v_pool, None,
-                              kv_scales))
+        h, *pools = _scan_layers(
+            step, _embed(params, ids, cfg, tp_axis),
+            _pool_tuple(k_pool, v_pool, kv_scales), params["blocks"],
+            None, False)
         h_last = sp_last_hidden(h, start, t0, sp_axis=sp_axis)
         return (_full_logits(params, h_last, cfg, tp_axis)[:, 0, :],
                 *pools)
@@ -523,7 +430,7 @@ def llama_family(cfg) -> Family:
 
 def granite_hybrid_family(cfg) -> Family:
     from quintnet_tpu.models.granite_hybrid import (
-        WEIGHT_TARGETS, attn_block_chunk, attn_block_step, granite_embed,
+        WEIGHT_TARGETS, attn_block_chunk, granite_embed,
         granite_hybrid_partition_specs, granite_logits, mamba_block_chunk,
         mamba_block_step)
 
@@ -545,18 +452,19 @@ def granite_hybrid_family(cfg) -> Family:
                    mamba_fn, attn_fn):
         """The scan over the layer pattern: one step a PERIOD — a short
         scan of Mamba layers, the attention layer, a second short scan
-        of Mamba layers. The attention layers' pools ride the outer
-        scan as xs and ys like a uniform family's; the state buffers
-        ride every loop's CARRY, each layer reading rows ``[row0, row0
-        + rows)`` of its own slice and writing them back in place —
-        stacked as ys they would be a second copy of the whole state.
-        The Mamba layers' weights are indexed by layer inside the inner
-        loop, as a scan indexes its xs."""
+        of Mamba layers. Every per-sequence buffer — the attention
+        layers' pools and the two state buffers — rides every loop's
+        CARRY, whole: an attention layer writes and reads the pool at
+        ``(period, slot)``, a Mamba layer reads rows ``[row0, row0 +
+        rows)`` of its own slice and writes them back in place —
+        sliced in as xs and stacked as ys they would be a second copy
+        of the buffer. The Mamba layers' weights are indexed by layer
+        inside the inner loop, as a scan indexes its xs."""
         mblocks = params["blocks"]["mamba"]
 
         def mamba_run(carry, first, count):
             def body(c, j):
-                x, ssm, conv = c
+                x, ssm, conv, *kv = c
                 layer = first + j
                 blk = jax.tree.map(
                     lambda a: lax.dynamic_index_in_dim(
@@ -574,23 +482,23 @@ def granite_hybrid_family(cfg) -> Family:
                         ssm, s[None], (layer, row0, 0, 0, 0))
                     conv = lax.dynamic_update_slice(
                         conv, t.reshape(1, rows, -1), (layer, row0, 0))
-                return (x, ssm, conv), None
+                return (x, ssm, conv, *kv), None
 
             if count == 0:
                 return carry
             return lax.scan(body, carry, jnp.arange(count))[0]
 
         def period(carry, xs):
-            i, ablk, kc, vc = xs
-            carry = mamba_run(carry, i * per, before)
-            x, kc, vc = attn_fn(ablk, carry[0], kc, vc)
-            carry = mamba_run((x, *carry[1:]), i * per + before, after)
-            return carry, (kc, vc)
+            i, ablk = xs
+            x, ssm, conv, kp, vp = mamba_run(carry, i * per, before)
+            x, kp, vp = attn_fn(ablk, x, kp, vp, i)
+            return mamba_run((x, ssm, conv, kp, vp), i * per + before,
+                             after), None
 
-        (h, ssm, conv), (k_pool, v_pool) = _scan_blocks(
-            period, (h, *state),
-            (jnp.arange(periods), params["blocks"]["attn"], k_pool,
-             v_pool))
+        with jax.named_scope("blocks"):
+            (h, ssm, conv, k_pool, v_pool), _ = lax.scan(
+                period, (h, *state, k_pool, v_pool),
+                (jnp.arange(periods), params["blocks"]["attn"]))
         return h, k_pool, v_pool, ssm, conv
 
     def run_chunk(params, k_pool, v_pool, state, ids, positions, lens,
@@ -601,9 +509,9 @@ def granite_hybrid_family(cfg) -> Family:
             t = jnp.where(fresh, jnp.zeros_like(t), t)
             return mamba_block_chunk(blk, x, s, t, lens, cfg)
 
-        def attn_fn(blk, x, kc, vc):
-            return attn_block_chunk(blk, x, kc, vc, positions, lens,
-                                    tables, block_size, cfg, policy)
+        def attn_fn(blk, x, kp, vp, layer):
+            return attn_block_chunk(blk, x, kp, vp, layer, positions,
+                                    lens, tables, block_size, cfg, policy)
 
         return run_layers(params, k_pool, v_pool, state,
                           granite_embed(params, ids, cfg), row0,
@@ -654,9 +562,11 @@ def granite_hybrid_family(cfg) -> Family:
             return (x, jnp.where(live[:, None, None, None], s2, s),
                     jnp.where(live[:, None, None], t2, t))
 
-        def attn_fn(blk, x, kc, vc):
-            return attn_block_step(blk, x, kc, vc, pos, tables,
-                                   block_size, cfg, policy)
+        def attn_fn(blk, x, kp, vp, layer):
+            return attn_block_chunk(
+                blk, x, kp, vp, layer, pos[:, None],
+                jnp.ones(pos.shape, jnp.int32), tables, block_size, cfg,
+                policy)
 
         h, *bufs = run_layers(
             params, k_pool, v_pool, state,

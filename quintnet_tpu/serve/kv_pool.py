@@ -7,16 +7,31 @@ shared by all in-flight requests; each request owns just the blocks its
 current length needs (vLLM's PagedAttention memory model). Fragmentation
 is bounded to < 1 block per request and T_max padding disappears.
 
-Device layout (per k and v): ``[L, num_blocks * block_size, H_kv, Dh]``
-— the flat "slot" dim is what nn/attention.paged_cache_update scatters
-into and paged_gather pages out of; keeping L leading lets the decode
-step lax.scan over layers exactly like the dense path. Under TP the
-H_kv dim is head-sharded over the mesh (each rank holds its local
-heads' pool, same invariant as the dense TP cache). WHAT a slot stores
-is a :class:`~quintnet_tpu.serve.kv_quant.KVLayoutPolicy`: f32/bf16
+Device layout (per k and v): ``[L, num_blocks * block_size, F]`` — one
+ROW per token slot, its heads flattened (``H_kv * Dh`` features) and
+padded with zero lanes to a multiple of 128 (:func:`feature_width`).
+The shape is the layout: a TPU gives a parameter the tiling that pads
+least for its shape, and for trailing dims ``25, 64`` (or ``1600``)
+that tiling has the SLOT dim minor — a token's row strided, re-laid by
+every program that touches it. With the minor dim a whole number of
+lanes the default is row-major, nothing strided (PERF.md, PR 28).
+Programs carry the pool WHOLE through their layer loop and address it
+by ``(layer, slot)`` (nn/attention.paged_write scatters rows in,
+paged_gather pages a row's blocks out and only then splits heads);
+the flat "slot" dim is ``block * block_size + offset``. Under TP the
+feature dim is head-sharded over the mesh: each rank holds its LOCAL
+heads' rows, padded on their own (``F = tp * feature_width(H_kv / tp
+* Dh)``, heads contiguous within a rank's part). Pad lanes are never
+read into a score and count for nothing: ``bytes_per_token`` is the
+model's own. Host-side records (chain export/import, the host tier,
+the disaggregated handoff) keep ``[L, block_size, H_kv, Dh]``; the
+reshape happens at the pool's edge (:meth:`KVPool.read_slots`,
+:meth:`KVPool.write_slots`). WHAT a slot stores is a
+:class:`~quintnet_tpu.serve.kv_quant.KVLayoutPolicy`: f32/bf16
 passthrough, or int8 with per-block-per-head absmax scales carried in
 ``[L, num_blocks, H_kv]`` f32 arrays beside the pools (head-sharded
-the same way) — same pool bytes, ~4x the blocks.
+the same way, carried and addressed by layer the same way) — same pool
+bytes, ~4x the blocks.
 
 Block 0 is permanently reserved as the NULL block: inactive engine
 slots point their table rows (and positions) at it, so masked rows'
@@ -82,6 +97,15 @@ from quintnet_tpu.serve.kv_tier import HostTier
 
 NULL_BLOCK = 0
 
+LANES = 128
+
+
+def feature_width(n_heads: int, head_dim: int) -> int:
+    """The pool's row width for ``n_heads`` heads of ``head_dim``:
+    their flattened features rounded up to whole 128-lane vregs (GPT-2
+    XL's 25 x 64 = 1600 -> 1664; 768, 1280 and 512 need no pad)."""
+    return -(-n_heads * head_dim // LANES) * LANES
+
 
 @dataclass
 class AdmitPlan:
@@ -129,8 +153,10 @@ class KVPool:
     """Refcounted block allocator + prefix cache over paged KV storage.
 
     ``n_kv_heads`` is the GLOBAL kv-head count; pass ``sharding`` (a
-    ``jax.sharding.NamedSharding`` with the head dim on the tp axis) to
-    lay the pool out head-sharded for a TP engine. ``prefix_cache=False``
+    ``jax.sharding.NamedSharding`` with the feature dim — dim 2 — on
+    the tp axis) to lay the pool out head-sharded for a TP engine: the
+    axis's size is the number of parts the heads are padded in
+    (module docstring). ``prefix_cache=False``
     disables the index entirely (lookup misses, publish is a no-op,
     release always frees) — the A/B switch tools/serve_bench.py flips.
     """
@@ -160,7 +186,13 @@ class KVPool:
         # head dim shards exactly like the pool's (``scale_sharding``).
         self.policy: KVLayoutPolicy = make_policy(
             policy if policy is not None else dtype)
-        shape = (n_layers, num_blocks * block_size, n_kv_heads, head_dim)
+        # head shards: the size of the mesh axis the feature dim is on
+        axis = sharding.spec[2] if sharding is not None else None
+        self.head_shards = 1 if axis is None else sharding.mesh.shape[axis]
+        self._local_width = feature_width(n_kv_heads // self.head_shards,
+                                          head_dim)
+        shape = (n_layers, num_blocks * block_size,
+                 self.head_shards * self._local_width)
         k = jnp.zeros(shape, self.policy.store_dtype)
         v = jnp.zeros(shape, self.policy.store_dtype)
         k_scale = v_scale = None
@@ -364,9 +396,8 @@ class KVPool:
         if key is None or fill <= 0:
             return False
         bs = self.block_size
-        rec = {"fill": int(fill),
-               "k": np.asarray(self.k[:, b * bs:(b + 1) * bs]),
-               "v": np.asarray(self.v[:, b * bs:(b + 1) * bs])}
+        k, v = self.read_slots(np.arange(b * bs, (b + 1) * bs))
+        rec = {"fill": int(fill), "k": k, "v": v}
         if self.policy.scaled:
             rec["k_scale"] = np.asarray(self.k_scale[:, b])
             rec["v_scale"] = np.asarray(self.v_scale[:, b])
@@ -753,14 +784,12 @@ class KVPool:
             bs = self.block_size
             idx = np.concatenate([np.arange(b * bs, (b + 1) * bs)
                                   for b in blocks])
-            k_new = np.concatenate([np.asarray(r["k"])
-                                    for _, r in todo], axis=1)
-            v_new = np.concatenate([np.asarray(r["v"])
-                                    for _, r in todo], axis=1)
-            k = self.k.at[:, idx].set(
-                jnp.asarray(k_new, self.policy.store_dtype))
-            v = self.v.at[:, idx].set(
-                jnp.asarray(v_new, self.policy.store_dtype))
+            k, v = self.write_slots(
+                idx,
+                np.concatenate([np.asarray(r["k"]) for _, r in todo],
+                               axis=1),
+                np.concatenate([np.asarray(r["v"]) for _, r in todo],
+                               axis=1))
             if self.policy.scaled:
                 barr = np.asarray(blocks, np.int32)
                 ks = np.stack([np.asarray(r["k_scale"])
@@ -818,8 +847,7 @@ class KVPool:
         if dev:
             idx = np.concatenate([np.arange(b * bs, (b + 1) * bs)
                                   for _, b in dev])
-            k_all = np.asarray(self.k[:, idx])
-            v_all = np.asarray(self.v[:, idx])
+            k_all, v_all = self.read_slots(idx)
             if self.policy.scaled:
                 barr = np.asarray([b for _, b in dev], np.int32)
                 ks_all = np.asarray(self.k_scale[:, barr])
@@ -918,14 +946,10 @@ class KVPool:
         # memcpys
         idx = np.concatenate([np.arange(b * bs, (b + 1) * bs)
                               for b in blocks])
-        k_new = np.concatenate([np.asarray(r["k"]) for r in records],
-                               axis=1)
-        v_new = np.concatenate([np.asarray(r["v"]) for r in records],
-                               axis=1)
-        k = self.k.at[:, idx].set(
-            jnp.asarray(k_new, self.policy.store_dtype))
-        v = self.v.at[:, idx].set(
-            jnp.asarray(v_new, self.policy.store_dtype))
+        k, v = self.write_slots(
+            idx,
+            np.concatenate([np.asarray(r["k"]) for r in records], axis=1),
+            np.concatenate([np.asarray(r["v"]) for r in records], axis=1))
         if self.policy.scaled:
             barr = np.asarray(blocks, np.int32)
             ks = np.stack([np.asarray(r["k_scale"]) for r in records],
@@ -943,6 +967,47 @@ class KVPool:
         self.publish(tokens, blocks, n_tokens, namespace=namespace)
         self.release(blocks)
         return n_tokens
+
+    # ---- the pool's edge: host records <-> device rows ---------------
+    def read_slots(self, idx) -> Tuple[np.ndarray, np.ndarray]:
+        """Flat slots ``idx`` [n] of every layer as HOST arrays in the
+        record shape ``[L, n, H_kv, Dh]`` (k, v), exactly as stored
+        (``store_dtype``), the pad lanes dropped. One gather a pool
+        array: a chain read costs O(chain bytes), never O(pool)."""
+        every = np.arange(self.n_layers)[:, None]
+
+        def heads(pool):
+            # (layer, slot) pairs, as the programs address the pool: an
+            # index over all layers at once re-lays the whole pool on
+            # the chip (PERF.md, PR 28)
+            rows = np.asarray(pool[every, np.asarray(idx)[None, :]])
+            L, n = rows.shape[:2]
+            local = self.n_kv_heads // self.head_shards * self.head_dim
+            return rows.reshape(L, n, self.head_shards, self._local_width)[
+                ..., :local].reshape(L, n, self.n_kv_heads, self.head_dim)
+
+        return heads(self.k), heads(self.v)
+
+    def write_slots(self, idx, k_new, v_new):
+        """The pool arrays with records ``k_new``/``v_new``
+        ``[L, n, H_kv, Dh]`` written at flat slots ``idx`` [n] — ONE
+        fused scatter a pool array (a per-block ``.at[].set`` would copy
+        the whole pool once per block). Returns ``(k, v)`` for
+        :meth:`update`; the pad lanes are written as zeros."""
+        every = np.arange(self.n_layers)[:, None]
+
+        def put(pool, new):
+            new = np.asarray(new)
+            L, n = new.shape[:2]
+            rows = new.reshape(L, n, self.head_shards, -1)
+            pad = self._local_width - rows.shape[-1]
+            if pad:
+                rows = np.pad(rows, ((0, 0),) * 3 + ((0, pad),))
+            return pool.at[every, np.asarray(idx)[None, :]].set(
+                jnp.asarray(rows.reshape(L, n, -1),
+                            self.policy.store_dtype))
+
+        return put(self.k, k_new), put(self.v, v_new)
 
     # ---- device views ----------------------------------------------
     def caches(self):

@@ -41,9 +41,8 @@ without forking any kernel:
   dequant-error bound, tests/test_kv_quant.py).
 
 Dequantization happens INSIDE the gathered-view attention kernels
-(nn/attention.py): the paged paths of ``mha_decode``,
-``mha_prefill_paged``, ``mha_verify_paged`` and ``ring_paged_prefill``
-gather int8 slots + their block scales, dequantize into the existing
+(nn/attention.py): ``paged_attend`` (every decode, verify and prefill
+program) and ``ring_paged_prefill`` gather int8 slots + their block scales, dequantize into the existing
 f32-softmax math, and quantize on scatter. The pool stores int8; the
 math never sees it.
 

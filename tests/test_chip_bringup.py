@@ -9,7 +9,10 @@ checked without a chip (PR 21, the bring-up round).
    shapes for 12 heads x Dh 64 x table width 64 (1024 positions),
    passthrough and int8-scaled; and (PR 27) the decode program of the
    recurrent family at granite-4.0-h-micro's published widths, whose
-   plan must not hold a second copy of the per-slot state. The parent commit's paged kernel was
+   plan must not hold a second copy of the per-slot state; and (PR 28)
+   the decode and prefill programs at GPT-2 XL's widths, which must
+   take the KV pool row-major and hold no copy of it or of a layer's
+   slice. The parent commit's paged kernel was
    REFUSED at every one of these shapes (16 MiB default scoped-VMEM
    budget; a (1, Hkv) scale block; a lane-splitting reshape) —
    interpret mode cannot see any of that. Nothing runs: a compile that
@@ -165,6 +168,7 @@ def test_hybrid_decode_holds_one_copy_of_the_state_on_v5e(chip):
     from quintnet_tpu.models.granite_hybrid import (GraniteHybridConfig,
                                                     granite_hybrid_init)
     from quintnet_tpu.serve import granite_hybrid_family
+    from quintnet_tpu.serve.kv_pool import feature_width
     from quintnet_tpu.serve.kv_quant import make_policy
     from quintnet_tpu.serve.weight_quant import (make_weight_policy,
                                                  present_targets,
@@ -186,8 +190,8 @@ def test_hybrid_decode_holds_one_copy_of_the_state_on_v5e(chip):
             p, present_targets(p, fam.weight_targets),
             make_weight_policy("bf16")))(granite_hybrid_init(k, cfg)),
             jax.random.key(0)))
-    pool = sds((fam.n_layers, 32 * slots * bs, fam.n_kv_heads,
-                fam.head_dim), jnp.bfloat16)
+    pool = sds((fam.n_layers, 32 * slots * bs,
+                feature_width(fam.n_kv_heads, fam.head_dim)), jnp.bfloat16)
     ssm = sds((fam.state.n_layers, slots + 1, *fam.state.ssm), jnp.float32)
     conv = sds((fam.state.n_layers, slots + 1, *fam.state.conv),
                jnp.bfloat16)
@@ -205,6 +209,79 @@ def test_hybrid_decode_holds_one_copy_of_the_state_on_v5e(chip):
     assert plan.alias_size_in_bytes >= state_bytes     # in and out alias
     assert plan.temp_size_in_bytes < state_bytes / 3, (
         plan.temp_size_in_bytes, state_bytes)
+    _assert_pool_row_major_and_uncopied(compiled.as_text(), pool)
+
+
+def _assert_pool_row_major_and_uncopied(hlo: str, pool):
+    """The compiled program takes ``pool``-shaped parameters in the
+    row-major layout and holds no ``copy`` / ``transpose`` of the whole
+    pool or of one layer's slice of it (tools/pool_layout_audit.py is
+    the same reading, for the cells' engines)."""
+    spec = importlib.util.spec_from_file_location(
+        "pool_layout_audit", os.path.join(REPO, "tools",
+                                          "pool_layout_audit.py"))
+    audit = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(audit)
+    layer_bytes = pool.shape[1] * pool.shape[2] * pool.dtype.itemsize
+    got = audit.read_hlo(hlo, {"k": pool.shape, "v": pool.shape},
+                         layer_bytes)
+    assert got["entry_layouts"] == {"k/v": ["{2,1,0}"] * 2}, got
+    slices = {tuple(pool.shape), (1, *pool.shape[1:]), tuple(pool.shape[1:])}
+    for c in got["big_copies"]:
+        assert tuple(c["dims"]) not in slices, c
+
+
+@pytest.mark.parametrize("width", ("decode", "prefill"))
+def test_xl_programs_take_the_pool_row_major_and_copy_none_of_it(chip,
+                                                                  width):
+    """``gpt2_family(...).decode`` and ``.prefill_from`` at GPT-2 XL's
+    published widths (25 heads x 64: 1600 features, padded to 1664), 4
+    of its 48 layers, 12 slots and 384 blocks as the serving cell has
+    them, compiled for the described chip (shapes only). With the
+    trailing dims ``25, 64`` — or ``1600`` — the chip lays the pool out
+    slot-minor and every program re-lays it (PERF.md, PR 28)."""
+    from quintnet_tpu.models.gpt2 import GPT2Config, gpt2_init
+    from quintnet_tpu.serve import gpt2_family
+    from quintnet_tpu.serve.kv_pool import feature_width
+    from quintnet_tpu.serve.kv_quant import make_policy
+
+    with open(os.path.join(REPO, "benchmarks", "configs",
+                           "gpt2-xl.json")) as f:
+        cfg = GPT2Config.from_dict({**json.load(f), "n_layer": 4})
+    fam = gpt2_family(cfg)
+    slots, bs, blocks, table = 12, 16, 384, 64
+    policy = make_policy("bf16")
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    params = jax.tree.map(
+        lambda x: sds(x.shape, x.dtype),
+        jax.eval_shape(lambda k: gpt2_init(k, cfg), jax.random.key(0)))
+    width_f = feature_width(fam.n_kv_heads, fam.head_dim)
+    assert width_f == 1664
+    pool = sds((fam.n_layers, blocks * bs, width_f), jnp.bfloat16)
+    scalar = sds((), jnp.int32)
+    if width == "decode":
+        rows = sds((slots,), jnp.int32)
+
+        def program(params, k, v, tok, pos, tables):
+            return fam.decode(params, k, v, tok, pos, tables, bs,
+                              policy=policy)
+
+        args = (rows, rows, sds((slots, table), jnp.int32))
+    else:
+        def program(params, k, v, ids, start, t0, row):
+            return fam.prefill_from(params, k, v, ids, start, t0, row, bs,
+                                    policy=policy)
+
+        args = (sds((1, 64), jnp.int32), scalar, scalar,
+                sds((table,), jnp.int32))
+    compiled = jax.jit(program, donate_argnums=(1, 2)).lower(
+        params, pool, pool, *args).compile()
+    assert compiled.memory_analysis().alias_size_in_bytes >= 2 * (
+        pool.shape[0] * pool.shape[1] * pool.shape[2] * 2)
+    _assert_pool_row_major_and_uncopied(compiled.as_text(), pool)
 
 
 def test_cache_dir_from_env_is_left_alone(monkeypatch, tmp_path,

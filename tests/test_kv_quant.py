@@ -306,8 +306,9 @@ class TestInt8Quality:
         p32, p8 = pools["f32"], pools["int8"]
         bs = p8.block_size
         nb = p8.num_blocks
-        for ref, q, sc in ((p32.k, p8.k, p8.k_scale),
-                           (p32.v, p8.v, p8.v_scale)):
+        every = np.arange(nb * bs)
+        for ref, q, sc in zip(p32.read_slots(every), p8.read_slots(every),
+                              (p8.k_scale, p8.v_scale)):
             # [L, nb, bs, H, Dh] block views; scales [L, nb, H]
             refb = np.asarray(ref).reshape(CFG.n_layer, nb, bs,
                                            CFG.n_head, -1)
@@ -337,32 +338,38 @@ class TestInt8Quality:
                                                paged_quant_update)
 
         policy = make_policy("int8")
-        bs, H, Dh, nb = 4, 2, 4, 3
-        cache = jnp.zeros((nb * bs, H, Dh), jnp.int8)
-        scales = jnp.ones((nb, H), jnp.float32)
+        bs, H, Dh, nb, F, layer = 4, 2, 4, 3, 12, 1
+        cache = jnp.zeros((2, nb * bs, F), jnp.int8)
+        scales = jnp.ones((2, nb, H), jnp.float32)
         table = jnp.asarray([[1, 0]], jnp.int32)
         # first owner fills pool block 1 with large values
-        row = paged_gather_dequant(policy, cache, scales, table,
-                                   block_size=bs)
+        row = paged_gather_dequant(policy, cache, scales, layer, table,
+                                   block_size=bs, head_shape=(H, Dh))
         cache, scales, _ = paged_quant_update(
-            policy, cache, scales, row, jnp.full((1, H, bs, Dh), 50.0),
+            policy, cache, scales, layer, row,
+            jnp.full((1, H, bs, Dh), 50.0),
             jnp.arange(bs, dtype=jnp.int32)[None, :],
             jnp.asarray([bs], jnp.int32),
             block_tables=table, block_size=bs, max_blocks=2)
-        assert float(scales[1].max()) > 0.3          # ~50/127
+        assert float(scales[layer, 1].max()) > 0.3          # ~50/127
         # block 1 recycled: new owner writes ONE small token at pos 0
-        row2 = paged_gather_dequant(policy, cache, scales, table,
-                                    block_size=bs)
+        row2 = paged_gather_dequant(policy, cache, scales, layer, table,
+                                    block_size=bs, head_shape=(H, Dh))
         cache, scales, view = paged_quant_update(
-            policy, cache, scales, row2, jnp.full((1, H, 1, Dh), 0.5),
+            policy, cache, scales, layer, row2,
+            jnp.full((1, H, 1, Dh), 0.5),
             jnp.zeros((1, 1), jnp.int32), jnp.asarray([1], jnp.int32),
             block_tables=table, block_size=bs, max_blocks=1)
-        sc = np.asarray(scales[1])
+        sc = np.asarray(scales[layer, 1])
         assert np.all(sc <= 0.5 / 127 + 1e-6), (
             f"stale bytes inflated the recycled block's scale: {sc}")
         got = np.asarray(policy.dequant(
-            cache.reshape(nb, bs, H, Dh)[1, 0], sc[:, None]))
+            cache[layer, bs, :H * Dh].reshape(H, Dh), sc[:, None]))
         assert np.all(np.abs(got - 0.5) <= sc.max() * 0.5 + 1e-6)
+        # the other layer and the pad lanes were never written
+        assert not np.asarray(cache[0]).any()
+        assert not np.asarray(cache[..., H * Dh:]).any()
+        assert np.all(np.asarray(scales[0]) == 1.0)
 
     def test_int8_serves_and_compile_bound_holds(self, params, rng):
         """Mixed staggered trace on int8: everything finishes, with

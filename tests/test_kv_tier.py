@@ -144,22 +144,21 @@ def _publish_chain(pool, toks, seed=0):
     rng = np.random.default_rng(seed)
     blocks = pool.acquire(pool.blocks_for(len(toks)))
     bs = pool.block_size
-    k, v = pool.k, pool.v
     ks, vs = pool.k_scale, pool.v_scale
+    shape = (pool.n_layers, bs, pool.n_kv_heads, pool.head_dim)
     for b in blocks:
-        sl = slice(b * bs, (b + 1) * bs)
-        shape = (pool.n_layers, bs, pool.n_kv_heads, pool.head_dim)
-        k = k.at[:, sl].set(rng.integers(-50, 50, shape)
-                            .astype(pool.k.dtype))
-        v = v.at[:, sl].set(rng.integers(-50, 50, shape)
-                            .astype(pool.v.dtype))
+        # records go in through the pool's edge, in the shape they
+        # have on the wire
+        k, v = pool.write_slots(np.arange(b * bs, (b + 1) * bs),
+                                rng.integers(-50, 50, shape),
+                                rng.integers(-50, 50, shape))
         if pool.policy.scaled:
             sshape = (pool.n_layers, pool.n_kv_heads)
             ks = ks.at[:, b].set(rng.uniform(0.5, 2.0, sshape)
                                  .astype(np.float32))
             vs = vs.at[:, b].set(rng.uniform(0.5, 2.0, sshape)
                                  .astype(np.float32))
-    pool.update(k, v, *(() if not pool.policy.scaled else (ks, vs)))
+        pool.update(k, v, *(() if not pool.policy.scaled else (ks, vs)))
     pool.publish(toks, blocks, len(toks))
     pool.release(blocks)
     return blocks

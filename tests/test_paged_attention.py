@@ -3,8 +3,8 @@ the XLA gathered-view oracle.
 
 The contract ladder:
 
-1. **Kernel parity matrix** — the real nn/attention entry points
-   (``mha_decode`` / ``mha_verify_paged`` / ``mha_prefill_paged``) run
+1. **Kernel parity matrix** — the real nn/attention entry point
+   (``mha_verify_paged`` at decode, verify and prefill widths) runs
    once per backend from identical pool state, across every
    ``kv_layout_policies`` entry x verify bucket widths x chunked
    prefill offsets, in CPU interpret mode: outputs BIT-exact for
@@ -72,13 +72,27 @@ def _mha_params(key):
     return mha_init(key, H * D)
 
 
-def _pool(policy):
-    k = jnp.zeros((NB * BS, H, D), policy.store_dtype)
-    v = jnp.zeros((NB * BS, H, D), policy.store_dtype)
+LAYERS, LAYER, PAD = 3, 1, 8            # the whole pool: 3 layers, the
+#                                         ops address the middle one;
+#                                         8 pad lanes past H * D
+
+
+def _pool(policy, h=H, d=D):
+    k = jnp.zeros((LAYERS, NB * BS, h * d + PAD), policy.store_dtype)
+    v = jnp.zeros_like(k)
     if policy.scaled:
-        return [k, v, jnp.ones((NB, H), jnp.float32),
-                jnp.ones((NB, H), jnp.float32)]
+        return [k, v, jnp.ones((LAYERS, NB, h), jnp.float32),
+                jnp.ones((LAYERS, NB, h), jnp.float32)]
     return [k, v, None, None]
+
+
+def _blocks(pool_array, h=H, d=D):
+    """The addressed layer of a pool array as [NB, BS, h, d] — after
+    checking that no other layer and no pad lane was ever written."""
+    a = np.asarray(pool_array)
+    assert not a[..., h * d:].any(), "a pad lane was written"
+    assert not np.delete(a, LAYER, axis=0).any(), "another layer written"
+    return a[LAYER, :, :h * d].reshape(NB, BS, h, d)
 
 
 def _scales(pool):
@@ -96,13 +110,12 @@ def _assert_pools_match(pa, pb, policy, tables):
     block legitimately collects both backends' masked-pad scatters)."""
     real = np.asarray(tables).reshape(-1)
     for a, b in zip(pa[:2], pb[:2]):
-        ra = np.asarray(a).reshape(NB, BS, H, D)[real]
-        rb = np.asarray(b).reshape(NB, BS, H, D)[real]
-        np.testing.assert_array_equal(ra, rb)
+        np.testing.assert_array_equal(_blocks(a)[real], _blocks(b)[real])
     if policy.scaled:
         for a, b in zip(pa[2:], pb[2:]):
-            np.testing.assert_array_equal(np.asarray(a)[real],
-                                          np.asarray(b)[real])
+            assert np.all(np.delete(np.asarray(a), LAYER, axis=0) == 1.0)
+            np.testing.assert_array_equal(np.asarray(a)[LAYER, real],
+                                          np.asarray(b)[LAYER, real])
 
 
 def _assert_out(ya, yb, policy):
@@ -140,7 +153,8 @@ class TestMhaParityMatrix:
             out = jax.jit(
                 lambda x, kp, vp, ks, vs: mha_verify_paged(
                     attn, x, kp, vp, positions, tail_lens,
-                    num_heads=H, block_tables=tables, block_size=BS,
+                    num_heads=H, layer=jnp.int32(LAYER),
+                    block_tables=tables, block_size=BS,
                     kv_scales=(ks, vs) if ks is not None else None,
                     policy=policy if kv is not None else None,
                     attn_kernel=kernel)
@@ -165,7 +179,7 @@ class TestMhaParityMatrix:
         """Chunked prefill: one row, two chunks at dynamic offsets
         (start 0 then 8) through the SAME bucket width — the
         prefix-cache tail shape."""
-        from quintnet_tpu.nn.attention import mha_prefill_paged
+        from quintnet_tpu.nn.attention import mha_verify_paged
 
         rng = np.random.default_rng(9)
         pool = _pool(policy)
@@ -178,9 +192,11 @@ class TestMhaParityMatrix:
             positions = start + jnp.arange(P, dtype=jnp.int32)
             kv = _scales(pool)
             out = jax.jit(
-                lambda x, kp, vp, ks, vs: mha_prefill_paged(
-                    attn, x, kp, vp, positions, jnp.int32(tail),
-                    num_heads=H, block_tables=tables, block_size=BS,
+                lambda x, kp, vp, ks, vs: mha_verify_paged(
+                    attn, x, kp, vp, positions[None],
+                    jnp.full((1,), tail, jnp.int32),
+                    num_heads=H, layer=jnp.int32(LAYER),
+                    block_tables=tables[None], block_size=BS,
                     kv_scales=(ks, vs) if ks is not None else None,
                     policy=policy if kv is not None else None,
                     attn_kernel=kernel)
@@ -217,13 +233,7 @@ class TestGQAParity:
         params = llama_init(jax.random.key(2), cfg)
         blk = jax.tree.map(lambda a: a[0], params["blocks"])
         hkv, hd = cfg.n_kv_heads, cfg.head_dim
-        pool = [jnp.zeros((NB * BS, hkv, hd), policy.store_dtype),
-                jnp.zeros((NB * BS, hkv, hd), policy.store_dtype)]
-        if policy.scaled:
-            pool += [jnp.ones((NB, hkv), jnp.float32),
-                     jnp.ones((NB, hkv), jnp.float32)]
-        else:
-            pool += [None, None]
+        pool = _pool(policy, hkv, hd)
         tables = _tables()
         rng = np.random.default_rng(3)
         starts = np.asarray([5, 0, 11], np.int32)
@@ -245,7 +255,8 @@ class TestGQAParity:
                 out = jax.jit(
                     lambda x, kp, vp, ks, vs: llama_block_verify_paged(
                         blk, x, kp, vp, positions, tails, cfg, cos,
-                        sin, block_tables=tables, block_size=BS,
+                        sin, layer=jnp.int32(LAYER),
+                        block_tables=tables, block_size=BS,
                         kv_scales=(ks, vs) if ks is not None else None,
                         policy=policy if kv is not None else None,
                         attn_kernel=kernel)
@@ -259,9 +270,8 @@ class TestGQAParity:
             _assert_out(a, b, policy)
         real = np.asarray(tables).reshape(-1)
         for a, b in zip(pa[:2], pb[:2]):
-            np.testing.assert_array_equal(
-                np.asarray(a).reshape(NB, BS, hkv, hd)[real],
-                np.asarray(b).reshape(NB, BS, hkv, hd)[real])
+            np.testing.assert_array_equal(_blocks(a, hkv, hd)[real],
+                                          _blocks(b, hkv, hd)[real])
 
 
 # ---------------------------------------------------------------------
@@ -467,15 +477,21 @@ class TestStructure:
             _engine(params, "pallas", mesh=mesh, sp_axis="sp",
                     prefill_bucket_sizes=(16, 32))
 
-    def test_dense_path_rejects_pallas(self):
-        from quintnet_tpu.nn.attention import mha_decode, mha_init
+    def test_pallas_refuses_a_stated_score_scale(self):
+        """The fused kernel scales its scores by 1/sqrt(head_dim) only:
+        a family that states its own scale (the hybrid's
+        ``attention_multiplier``) is refused by name, never served
+        with the wrong one."""
+        from quintnet_tpu.nn.attention import paged_attend
 
-        p = mha_init(jax.random.key(0), H * D)
-        x = jnp.zeros((1, 1, H * D))
-        kc = jnp.zeros((1, H, 8, D))
-        with pytest.raises(ValueError, match="paged"):
-            mha_decode(p, x, kc, kc, jnp.int32(0), num_heads=H,
-                       attn_kernel="pallas")
+        q = jnp.zeros((1, H, 1, D))
+        pool = jnp.zeros((1, NB * BS, H * D))
+        with pytest.raises(NotImplementedError, match="score scale"):
+            paged_attend(q, q, q, (pool, pool), 0,
+                         jnp.zeros((1, 1), jnp.int32),
+                         jnp.ones((1,), jnp.int32),
+                         jnp.zeros((1, M), jnp.int32), block_size=BS,
+                         attn_kernel="pallas", scale=0.25)
 
     def test_scaled_kernel_requires_fresh_kv(self):
         from quintnet_tpu.ops.paged_attention import paged_attention
@@ -582,14 +598,17 @@ class TestStoredDtypeContract:
         rng = np.random.default_rng(21)
         attn = _mha_params(jax.random.key(5))
         x = jnp.asarray(rng.standard_normal((S, 1, H * D)), jnp.float32)
-        kp = jnp.asarray(rng.standard_normal((NB * BS, H, D)), pool_dtype)
-        vp = jnp.asarray(rng.standard_normal((NB * BS, H, D)), pool_dtype)
+        # the whole pool, every layer and every pad lane full of noise:
+        # the step must read the addressed layer's real lanes only
+        shape = (LAYERS, NB * BS, H * D + PAD)
+        kp = jnp.asarray(rng.standard_normal(shape), pool_dtype)
+        vp = jnp.asarray(rng.standard_normal(shape), pool_dtype)
         pos = jnp.asarray([5, 0, 17], jnp.int32)
         return attn, x, kp, vp, pos
 
     @pytest.mark.parametrize("pool_dtype", ("bfloat16", "float16"))
     def test_decode_inside_the_bound_rounding_earns(self, pool_dtype):
-        """mha_decode on a narrow pool against plain f32 math on the
+        """A decode step on a narrow pool against plain f32 math on the
         SAME stored K and V. What differs is that q and the
         probabilities are rounded to the pool's dtype before products
         that accumulate in f32, each with relative error u = 2^-p at
@@ -604,23 +623,25 @@ class TestStoredDtypeContract:
 
         First order in u; a tenth of room covers the second order and
         the f32 sums."""
-        from quintnet_tpu.nn.attention import _qkv_heads, mha_decode
+        from quintnet_tpu.nn.attention import _qkv_heads, mha_verify_paged
 
         dt = jnp.dtype(pool_dtype)
         attn, x, kp, vp, pos = self._decode_inputs(dt)
         tables = _tables()
         y, kp2, vp2 = jax.jit(
-            lambda x, kp, vp: mha_decode(
-                attn, x, kp, vp, pos, num_heads=H, block_tables=tables,
-                block_size=BS))(x, kp, vp)
+            lambda x, kp, vp: mha_verify_paged(
+                attn, x, kp, vp, pos[:, None], jnp.ones_like(pos),
+                num_heads=H, block_tables=tables, block_size=BS,
+                layer=jnp.int32(LAYER)))(x, kp, vp)
         assert y.dtype == jnp.float32
 
         q, _k, _v = _qkv_heads(attn, x, H)
         q = np.asarray(q, np.float64)[:, :, 0]              # [S, H, D]
         rows = (np.asarray(tables)[:, :, None] * BS
                 + np.arange(BS)[None, None, :]).reshape(S, M * BS)
-        k = np.asarray(kp2.astype(jnp.float32), np.float64)[rows]
-        v = np.asarray(vp2.astype(jnp.float32), np.float64)[rows]
+        k, v = (np.asarray(p[LAYER, :, :H * D].astype(jnp.float32),
+                           np.float64).reshape(NB * BS, H, D)[rows]
+                for p in (kp2, vp2))
         k, v = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
         live = (np.arange(M * BS)[None, :]
                 <= np.asarray(pos)[:, None])[:, None]       # [S, 1, T]
@@ -654,20 +675,22 @@ class TestStoredDtypeContract:
         or of the probabilities to a 16-bit float, no pad of the query
         row. The bf16 pool is the positive control: q and the
         probabilities go down, the lone row is padded once."""
-        from quintnet_tpu.nn.attention import mha_decode
+        from quintnet_tpu.nn.attention import mha_verify_paged
 
         attn, x, kp, vp, pos = self._decode_inputs(jnp.dtype(pool_dtype))
         jaxpr = jax.make_jaxpr(
-            lambda x, kp, vp: mha_decode(
-                attn, x, kp, vp, pos, num_heads=H,
-                block_tables=_tables(), block_size=BS))(x, kp, vp)
+            lambda x, kp, vp: mha_verify_paged(
+                attn, x, kp, vp, pos[:, None], jnp.ones_like(pos),
+                num_heads=H, block_tables=_tables(), block_size=BS,
+                layer=jnp.int32(LAYER)))(x, kp, vp)
         eqns = _eqns(jaxpr)
         down = [e for e in eqns
                 if e.primitive.name == "convert_element_type"
                 and e.invars[0].aval.dtype == jnp.float32
                 and e.params["new_dtype"].itemsize == 2
                 and e.invars[0].aval.ndim == 4]
-        pads = [e for e in eqns if e.primitive.name == "pad"]
+        pads = [e for e in eqns if e.primitive.name == "pad"
+                and e.invars[0].aval.ndim == 4]  # not the pool rows' lanes
         assert len(down) == (2 if narrowed else 0), down
         assert len(pads) == (1 if narrowed else 0), pads
 

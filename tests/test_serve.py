@@ -101,27 +101,33 @@ class TestKVPool:
         assert p.utilization == 1.0
 
     def test_paged_write_gather_roundtrip(self):
-        """paged_cache_update + paged_gather give back a position-
-        ordered dense view through an arbitrary block table."""
-        from quintnet_tpu.nn.attention import (paged_cache_update,
-                                               paged_gather)
+        """paged_write + paged_gather give back a position-ordered
+        dense view of ONE layer through an arbitrary block table, the
+        other layer and the pad lanes untouched."""
+        from quintnet_tpu.nn.attention import paged_gather, paged_write
 
-        bs, nb, H, Dh = 4, 6, 2, 3
-        k = jnp.zeros((nb * bs, H, Dh))
+        bs, nb, H, Dh, F = 4, 6, 2, 3, 8
+        k = jnp.zeros((2, nb * bs, F))
         v = jnp.zeros_like(k)
         tables = jnp.asarray([[3, 1, 0], [5, 2, 4]], jnp.int32)
         # write token at position 5 of row 0 (block 1, offset 1) and
         # position 2 of row 1 (block 5, offset 2)
-        pos = jnp.asarray([5, 2], jnp.int32)
-        kin = jnp.arange(2 * H * Dh, dtype=jnp.float32).reshape(2, H, Dh)
-        k, v = paged_cache_update(k, v, kin, kin, pos,
-                                  block_tables=tables, block_size=bs)
-        view = paged_gather(k, tables, block_size=bs)  # [2, H, 12, Dh]
+        pos = jnp.asarray([[5], [2]], jnp.int32)
+        kin = 1 + jnp.arange(2 * H * Dh, dtype=jnp.float32).reshape(
+            2, H, 1, Dh)
+        k, v = paged_write(k, v, jnp.int32(1), kin, kin, pos,
+                           jnp.ones((2,), jnp.int32),
+                           block_tables=tables, block_size=bs)
+        view = paged_gather(k, 1, tables, block_size=bs,
+                            head_shape=(H, Dh))           # [2, H, 12, Dh]
         np.testing.assert_array_equal(np.asarray(view[0, :, 5]),
-                                      np.asarray(kin[0]))
+                                      np.asarray(kin[0, :, 0]))
         np.testing.assert_array_equal(np.asarray(view[1, :, 2]),
-                                      np.asarray(kin[1]))
+                                      np.asarray(kin[1, :, 0]))
         assert float(jnp.abs(view[0, :, :5]).sum()) == 0.0
+        assert float(jnp.abs(k[0]).sum()) == 0.0          # other layer
+        assert float(jnp.abs(k[..., H * Dh:]).sum()) == 0.0   # pad lanes
+        assert int((k != 0).sum()) == 2 * H * Dh
 
 
 class TestScheduler:
@@ -611,3 +617,319 @@ def test_tp2_engine_matches_single_device(params, rng):
     outs = generate(eng, prompts, max_new_tokens=[8, 6, 10], keys=keys)
     for p, m, k, o in zip(prompts, (8, 6, 10), keys, outs):
         np.testing.assert_array_equal(o, _oracle(params, p, m, k))
+
+
+# ---------------------------------------------------------------------
+# the pool in place (PR 28): carried whole through the layer scan,
+# addressed (layer, slot), its rows lane-aligned
+# ---------------------------------------------------------------------
+_POOL_KINDS = ("gpt2", "llama", "llama-int8", "hybrid")
+_BS, _LIVE = 4, {0: (5, 9, 2), 2: (7, 11, 3)}    # slot -> its blocks
+_POS = np.array([6, 0, 9], np.int32)             # slot 1 is dead
+
+
+def _pool_engine(kind):
+    """A three-slot engine of ``kind`` and its decode, smallest-bucket
+    prefill and verify programs as ``{name: (jitted, tail)}``: the
+    call is ``jitted(params, *pools, *tail)`` with the pools leading
+    the outputs. The tables hold two live rows; the prefill is a chunk
+    at an offset (positions 5..10 of slot 0); the verify scores 2 and
+    3 tokens on the live rows and none on the dead one."""
+    from quintnet_tpu.serve import SpecConfig
+
+    kw = dict(max_slots=3, block_size=_BS, num_blocks=24, max_seq_len=32)
+    if kind == "hybrid":
+        from quintnet_tpu.models.granite_hybrid import (
+            GraniteHybridConfig, granite_hybrid_init)
+        from quintnet_tpu.serve import granite_hybrid_family
+
+        cfg = GraniteHybridConfig.tiny()
+        eng = ServeEngine(granite_hybrid_family(cfg),
+                          granite_hybrid_init(jax.random.key(7), cfg),
+                          prefix_cache=False, **kw)
+    elif kind == "gpt2":
+        eng = ServeEngine(gpt2_family(CFG),
+                          gpt2_init(jax.random.key(0), CFG),
+                          spec=SpecConfig(max_draft=4), **kw)
+    else:
+        from quintnet_tpu.models.llama import LlamaConfig, llama_init
+        from quintnet_tpu.serve import llama_family
+
+        cfg = LlamaConfig.tiny()
+        eng = ServeEngine(
+            llama_family(cfg), llama_init(jax.random.key(4), cfg),
+            kv_dtype="int8" if kind == "llama-int8" else None,
+            spec=SpecConfig(max_draft=4), **kw)
+    tables = np.zeros((3, eng.table_width), np.int32)
+    for slot, blocks in _LIVE.items():
+        tables[slot, :len(blocks)] = blocks
+    tables, pos = jnp.asarray(tables), jnp.asarray(_POS)
+    keys = jnp.asarray(eng._key_data)
+    b0 = eng.prefill_buckets[0]
+    lens = jnp.asarray([2, 0, 3], jnp.int32)
+    progs = {
+        "decode": (eng._decode.fn,
+                   (jnp.zeros((3,), jnp.int32), pos, tables, keys)),
+        "prefill": (eng._prefills[b0].fn,
+                    (jnp.zeros((1, b0), jnp.int32), jnp.int32(5),
+                     jnp.int32(11), tables[0], jnp.int32(0), jnp.int32(0),
+                     keys[0])
+                    + ((jnp.int32(0),) if kind == "hybrid" else ())),
+    }
+    ids = jnp.zeros((3, 3), jnp.int32)
+    if kind == "hybrid":
+        fam, pool = eng.family, eng.pool
+
+        def verify(params, k, v, ssm, conv, ids, starts, lens, tables):
+            return fam.verify(params, k, v, ids, starts, lens, tables,
+                              _BS, policy=pool.policy,
+                              state=(ssm, conv))[1:]
+
+        progs["verify"] = (jax.jit(verify, donate_argnums=(1, 2, 3, 4)),
+                           (ids, pos, lens, tables))
+    else:
+        progs["verify"] = (eng._verifies[2].fn,
+                           (ids, pos, lens, tables, keys))
+    return eng, progs
+
+
+# what each program of _pool_engine writes: (slot, position) pairs
+_WRITTEN = {
+    "decode": [(0, 6), (2, 9)],
+    "prefill": [(0, p) for p in range(5, 11)],
+    "verify": [(0, 6), (0, 7), (2, 9), (2, 10), (2, 11)],
+}
+
+
+@pytest.mark.parametrize("kind", _POOL_KINDS)
+def test_pool_rides_no_scan_as_xs_or_ys(kind):
+    """THE structural gate of PR 28: no serving program of any family
+    slices a per-sequence buffer (k, v, scales, recurrent state) into
+    its layer scan as xs or stacks one out as ys — they ride the carry
+    whole (analysis.pool_scan_operands reads zero)."""
+    from quintnet_tpu.analysis import pool_scan_operands
+
+    eng, progs = _pool_engine(kind)
+    pools = eng.pool.caches()
+    for name, (fn, tail) in progs.items():
+        for buf in pools:
+            assert pool_scan_operands(
+                fn, eng.params, *pools, *tail,
+                pool_shape=buf.shape) == 0, (kind, name, buf.shape)
+
+
+def test_pool_scan_operands_sees_the_old_form():
+    """The counter is shown the form PR 28 deleted — the pool sliced in
+    as xs, stacked back as ys — and the form that replaced it."""
+    from quintnet_tpu.analysis import pool_scan_operands
+
+    pool = jnp.zeros((3, 8, 4))
+    idx = jnp.asarray([1, 5])
+    row = jnp.ones((2, 4))
+
+    def old_form(pool):
+        def body(h, layer_pool):
+            layer_pool = layer_pool.at[idx].set(row)
+            return h + layer_pool[idx].sum(), layer_pool
+        return jax.lax.scan(body, 0.0, pool)
+
+    def carried(pool):
+        def body(carry, layer):
+            h, pool = carry
+            pool = pool.at[layer, idx].set(row)
+            return (h + pool[layer, idx].sum(), pool), None
+        return jax.lax.scan(body, (0.0, pool), jnp.arange(3))[0]
+
+    def xs_only(pool):
+        return jax.lax.scan(lambda h, p: (h + p[idx].sum(), None), 0.0,
+                            pool)[0]
+
+    assert pool_scan_operands(old_form, pool, pool_shape=pool.shape) == 1
+    assert pool_scan_operands(xs_only, pool, pool_shape=pool.shape) == 1
+    assert pool_scan_operands(carried, pool, pool_shape=pool.shape) == 0
+    np.testing.assert_array_equal(old_form(pool)[1], carried(pool)[1])
+
+
+@pytest.mark.parametrize("kind", ("gpt2", "llama-int8", "hybrid"))
+def test_every_pool_buffer_is_donated_and_aliased(kind):
+    """k, v, the scale arrays, the recurrent state: every buffer a
+    program carries comes back in the buffer it arrived in."""
+    from quintnet_tpu.analysis import donation_report
+
+    eng, progs = _pool_engine(kind)
+    pools = eng.pool.caches()
+    for name in ("decode", "prefill"):
+        fn, tail = progs[name]
+        rep = donation_report(fn, eng.params, *pools, *tail)
+        rows = [a for a in rep.args
+                if any(a.path.startswith(f"[0][{i}]")
+                       for i in range(1, len(pools) + 1))]
+        assert [a.shape for a in rows] == [p.shape for p in pools]
+        assert all(a.donated and a.aliasable for a in rows), (
+            kind, name, rep.summary())
+
+
+def _noise(rng, like):
+    if like.dtype == jnp.int8:
+        return jnp.asarray(rng.integers(-100, 100, like.shape), jnp.int8)
+    if like.ndim == 3 and like.shape[-1] < 8:        # scales [L, nb, H]
+        return jnp.asarray(rng.uniform(0.5, 2.0, like.shape), like.dtype)
+    return jnp.asarray(rng.standard_normal(like.shape), like.dtype)
+
+
+@pytest.mark.parametrize("name", ("decode", "prefill", "verify"))
+@pytest.mark.parametrize("kind", _POOL_KINDS)
+def test_untouched_rows_stay_untouched(kind, name):
+    """One step of a program over a pool full of noise: every row it
+    does not address — every layer of every block in no live table,
+    every pad lane of those, every state row of another slot — is
+    bit-identical afterwards, and every slot it does address has moved
+    in EVERY layer (what a layer index off by one breaks, and one
+    step's logits do not show)."""
+    eng, progs = _pool_engine(kind)
+    fn, tail = progs[name]
+    rng = np.random.default_rng(11)
+    pools = [_noise(rng, p) for p in eng.pool.caches()]
+    before = [np.array(p) for p in pools]
+    after = [np.asarray(p) for p in fn(eng.params, *pools, *tail)[
+        :len(pools)]]
+    slots = np.array([_LIVE[s][p // _BS] * _BS + p % _BS
+                      for s, p in _WRITTEN[name]])
+    touched = np.unique(slots // _BS)
+    scaled = eng.pool.policy.scaled
+    hd = eng.pool.n_kv_heads * eng.pool.head_dim
+    for b, a in zip(before[:2], after[:2]):                     # k, v
+        L, n, f = b.shape
+        if scaled:      # a touched block is requantized whole
+            still = np.ones(n // _BS, bool)
+            still[touched] = False
+            still = np.repeat(still, _BS)
+        else:
+            still = np.ones(n, bool)
+            still[slots] = False
+        still[:_BS] = False            # the null block: anyone's scratch
+        np.testing.assert_array_equal(a[:, still], b[:, still])
+        moved = (a[:, slots, :hd] != b[:, slots, :hd]).any(axis=-1)
+        assert moved.all(), (kind, name, moved)
+    rest = list(zip(before[2:], after[2:]))
+    if scaled:                                  # k_scale, v_scale
+        for b, a in rest:
+            still = np.ones(b.shape[1], bool)
+            still[touched] = False
+            still[0] = False
+            np.testing.assert_array_equal(a[:, still], b[:, still])
+            assert (a[:, touched] != b[:, touched]).any(axis=-1).all()
+    elif rest:                                  # ssm, conv: row = slot
+        rows = sorted({s for s, _p in _WRITTEN[name]})
+        for b, a in rest:
+            still = np.ones(b.shape[1], bool)
+            still[rows] = False
+            np.testing.assert_array_equal(a[:, still], b[:, still])
+            flat = (a[:, rows] != b[:, rows]).reshape(
+                b.shape[0], len(rows), -1)
+            assert flat.any(axis=-1).all(), (kind, name)
+
+
+@pytest.mark.parametrize("n_head,head_dim,width",
+                         [(3, 64, 256), (2, 128, 256)])
+class TestThePadIsInvisible:
+    """Feature widths 3 x 64 = 192 (padded to two lanes' worth) and
+    2 x 128 (aligned, no pad): the pool's rows are lane-aligned, what
+    a token costs is the model's, no pad lane reaches a score or an
+    output, and every host-side record keeps the shape it has on the
+    wire, bit for bit."""
+
+    def _cfg(self, n_head, head_dim):
+        return GPT2Config.tiny(n_layer=2, n_head=n_head,
+                               n_embd=n_head * head_dim)
+
+    def _poison(self, pool, hd):
+        poisoned = [p.at[..., hd:].set(jnp.nan) for p in (pool.k, pool.v)]
+        pool.update(*poisoned)
+
+    def test_width_and_bytes_per_token(self, n_head, head_dim, width):
+        pool = KVPool(n_layers=2, n_kv_heads=n_head, head_dim=head_dim,
+                      block_size=4, num_blocks=4)
+        assert pool.k.shape == pool.v.shape == (2, 16, width)
+        assert pool.bytes_per_token == 2 * 2 * n_head * head_dim * 4
+        assert pool.bytes_per_block == 4 * pool.bytes_per_token
+
+    def test_pad_lanes_reach_no_output(self, n_head, head_dim, width, rng):
+        """NaN in every pad lane: the same tokens, greedy and sampled,
+        and the same real lanes in the pool afterwards."""
+        cfg = self._cfg(n_head, head_dim)
+        params = gpt2_init(jax.random.key(3), cfg)
+        prompts = [np.asarray(rng.integers(0, cfg.vocab_size, (t,)),
+                              np.int32) for t in (9, 5, 13)]
+        keys = [jax.random.key(i) for i in range(3)]
+        outs, lanes = [], []
+        for poison in (False, True):
+            eng = ServeEngine(gpt2_family(cfg), params, max_slots=2,
+                              block_size=4, num_blocks=24, max_seq_len=32,
+                              temperature=0.8, top_k=5)
+            hd = n_head * head_dim
+            if poison:
+                self._poison(eng.pool, hd)
+            rids = [eng.submit(p, 6, key=k) for p, k in zip(prompts, keys)]
+            eng.run()
+            outs.append([np.asarray(eng.result(r)) for r in rids])
+            lanes.append(np.asarray(eng.pool.k[..., :hd]))
+        for a, b in zip(*outs):
+            np.testing.assert_array_equal(a, b)
+        # block 0 is scratch; everything else holds the same bytes
+        np.testing.assert_array_equal(lanes[0][:, 4:], lanes[1][:, 4:])
+
+    def test_records_round_trip_bit_for_bit(self, n_head, head_dim, width,
+                                            rng):
+        """export_chain -> the handoff's wire -> import_chain, and a
+        host-tier spill and reload: the records are [L, bs, H, Dh] as
+        they always were and hold exactly what was written."""
+        import json
+
+        from quintnet_tpu.fleet import wire
+        from quintnet_tpu.serve.kv_tier import HostTier
+
+        def mk():
+            pool = KVPool(n_layers=2, n_kv_heads=n_head, head_dim=head_dim,
+                          block_size=4, num_blocks=4,
+                          host_tier=HostTier(byte_budget=1 << 24))
+            self._poison(pool, n_head * head_dim)
+            return pool
+
+        src, toks = mk(), np.arange(10, dtype=np.int32)
+        blocks = src.acquire(3)
+        shape = (2, 12, n_head, head_dim)
+        k_new = rng.standard_normal(shape).astype(np.float32)
+        v_new = rng.standard_normal(shape).astype(np.float32)
+        idx = np.concatenate([np.arange(b * 4, (b + 1) * 4)
+                              for b in blocks])
+        src.update(*src.write_slots(idx, k_new, v_new))
+        src.publish(toks, blocks, 10)
+        src.release(blocks)
+        chain = src.export_chain(toks)
+        for j, rec in enumerate(chain["blocks"]):
+            assert rec["k"].shape == (2, 4, n_head, head_dim)
+            np.testing.assert_array_equal(rec["k"],
+                                          k_new[:, j * 4:(j + 1) * 4])
+            np.testing.assert_array_equal(rec["v"],
+                                          v_new[:, j * 4:(j + 1) * 4])
+        # the disaggregated handoff: framed, shipped, imported
+        got, _ns = wire.kv_chain_from_wire(json.loads(json.dumps(
+            wire.kv_chain_to_wire(chain))))
+        dst = mk()
+        assert dst.import_chain(got) == 10
+        again = dst.export_chain(toks)
+        for a, b in zip(chain["blocks"], again["blocks"]):
+            np.testing.assert_array_equal(a["k"], b["k"])
+            np.testing.assert_array_equal(a["v"], b["v"])
+        # a host-tier spill (every block evicted) and reload
+        held = dst.acquire(dst.num_available)
+        assert dst.host_tier.summary()["records"] == 3
+        dst.release(held)
+        _covered, keys = dst.plan_promotion(toks)
+        assert len(keys) == 3
+        assert dst.promote_chain(keys) == (3, 3)
+        back = dst.export_chain(toks)
+        for a, b in zip(chain["blocks"], back["blocks"]):
+            np.testing.assert_array_equal(a["k"], b["k"])
+            np.testing.assert_array_equal(a["v"], b["v"])
