@@ -356,8 +356,7 @@ def attn_kernels() -> Tuple[str, ...]:
     ``pallas_call`` carries no collectives at all). What DOES differ
     is structural and audited separately:
     ``jaxpr_audit.gathered_view_gathers`` must be > 0 for xla programs
-    and exactly 0 for pallas ones (tests/test_qtcheck.py,
-    tests/test_serve_bench.py)."""
+    and exactly 0 for pallas ones (tests/test_paged_attention.py)."""
     return ("xla", "pallas")
 
 

@@ -82,9 +82,9 @@ def enable_compilation_cache() -> str:
     First TPU compile of a big training step costs 20-40s+; with the
     cache, relaunching the same program (same jaxpr + compile options +
     topology) loads in well under a second. Call BEFORE the first jit
-    execution — every entry point does (chip_smoke.py, bench.py,
-    examples/common.setup_platform, fleet/proc.replica_main,
-    tools/serve_bench.py, tools/fleet_bench.py).
+    execution — every entry point does (chip_smoke.py,
+    benchmarks/run.py, examples/common.setup_platform,
+    fleet/proc.replica_main).
 
     The directory is placed from OUTSIDE: where
     ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and this

@@ -45,12 +45,10 @@ ship its KV chain to a decode replica over a checksummed wire frame
 as the always-correct fallback, and pool loss walks an explicit
 degradation ladder surfaced at /healthz.
 
-tools/fleet_bench.py replays a trace against the fleet per routing
-policy — with a mid-trace replica kill and an over-capacity burst —
-and emits one JSON record per policy (threads:
-artifacts/fleet_r08.json; ``--process``: artifacts/fleet_r12.json;
-``--disagg``: the TTFT-vs-ITL interference A/B of
-artifacts/fleet_r16.json).
+No benchmark cell goes through the fleet yet (PERF.md section 3): what
+holds it is tests/test_fleet.py (threads, a mid-trace replica kill and
+an over-capacity burst), tests/test_fleet_proc.py + test_fleet_wire.py
+(processes) and tests/test_disagg.py (pools).
 """
 
 from quintnet_tpu.fleet.admission import AdmissionQueue, Overloaded

@@ -13,8 +13,7 @@ Terms (Google's goodput papers use the same decomposition):
 
 One meter lives per PROCESS (attempt); the supervisor in
 tools/ft_run.py merges the per-attempt reports into the run-level
-goodput record written to ``artifacts/ft_r07.json`` (schema:
-docs/fault_tolerance.md). Step timing is wall-clock around the loop —
+goodput record it prints (schema: docs/fault_tolerance.md). Step timing is wall-clock around the loop —
 under JAX async dispatch an individual step's host time is not its
 device time, but the SUM over a window is honest (the loop cannot run
 ahead of the device by more than ``training.sync_every`` steps).

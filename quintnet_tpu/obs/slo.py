@@ -5,7 +5,7 @@ PR 11's flight recorder COLLECTS everything but judges nothing: the
 fleet has per-step rings, spans, and Prometheus gauges, yet no notion
 of an objective — "is the disaggregated fleet meeting its latency
 contract, and which pool is the bottleneck?" was still a human reading
-``fleet_r16.json`` after the fact. DistServe and Splitwise (PAPERS.md)
+a finished run's record after the fact. DistServe and Splitwise (PAPERS.md)
 both define *goodput* as throughput under TTFT/TPOT SLO attainment and
 size prefill/decode pools from exactly these signals — so before the
 ROADMAP's elastic-pool-sizing item can act, the judgment layer has to

@@ -72,8 +72,7 @@ Interpret mode is the CALLER's decision, never the kernel's: the
 default is the compiled Mosaic kernel on whatever backend the process
 has, so a serving process that is not on a TPU fails at lowering
 instead of quietly emulating. The CPU test session (tests/conftest.py)
-and ``tools/serve_bench.py --synthetic`` set :data:`INTERPRET` once,
-in the open.
+sets :data:`INTERPRET` once, in the open.
 """
 
 from __future__ import annotations

@@ -54,8 +54,8 @@ scheduling; vLLM-style paged KV blocks):
 - :mod:`api` — blocking ``generate()`` + streaming per-token callbacks;
 - :mod:`metrics` — per-step counters and TTFT / tok/s percentiles.
 
-tools/serve_bench.py replays a synthetic Poisson trace through the
-engine and emits a one-line JSON throughput/latency report.
+How fast it is on the chip: ``benchmarks/`` (the serving cells of
+``BENCHMARK.json``) and ``PERF.md``.
 """
 
 from quintnet_tpu.serve.adapters import AdapterEntry, AdapterRegistry

@@ -158,7 +158,7 @@ class KVPool:
     axis's size is the number of parts the heads are padded in
     (module docstring). ``prefix_cache=False``
     disables the index entirely (lookup misses, publish is a no-op,
-    release always frees) — the A/B switch tools/serve_bench.py flips.
+    release always frees).
     """
 
     def __init__(self, *, n_layers: int, n_kv_heads: int, head_dim: int,
@@ -276,8 +276,8 @@ class KVPool:
         policy (k + v slot data across layers + the per-block scale
         rows when scaled). Policy-aware: int8 blocks cost ~1/4 of f32
         ones, so the same pool bytes hold ~4x the blocks — THE
-        capacity-is-concurrency equation tools/serve_bench.py's
-        --kv-capacity A/B solves for equal bytes."""
+        capacity-is-concurrency equation (tests/test_kv_quant.py
+        solves it for equal bytes)."""
         return self.policy.bytes_per_block(
             n_layers=self.n_layers, n_kv_heads=self.n_kv_heads,
             head_dim=self.head_dim, block_size=self.block_size)

@@ -2,8 +2,8 @@
 how it gets there.
 
 KV memory bounds ``num_blocks``, which bounds concurrent users,
-admission, and the prefix-cache hit rate — capacity IS concurrency
-(serve_r09 peaked at 0.95 KV utilization). KIVI (Liu et al., 2024) and
+admission, and the prefix-cache hit rate — capacity IS concurrency.
+KIVI (Liu et al., 2024) and
 KVQuant (Hooper et al., 2024) show low-bit KV caches with fine-grained
 scales preserve quality while 2-4x-ing resident context; this module
 makes the pool's block dtype/layout a POLICY OBJECT so the same pool
@@ -189,7 +189,7 @@ def make_policy(kv_dtype) -> KVLayoutPolicy:
 
 
 # ---------------------------------------------------------------------
-# quality gates (tests/test_kv_quant.py + tools/serve_bench.py)
+# quality gates (tests/test_kv_quant.py)
 # ---------------------------------------------------------------------
 
 def dequant_roundtrip_error(policy: KVLayoutPolicy, x,

@@ -6,9 +6,8 @@ KV-block utilization, prefill vs decode tokens) and per-request marks
 derived. Emission goes through utils/logger.py — the same stdout+file
 tee the trainer uses — so a serving process logs like a training one.
 
-All timing uses a caller-injectable clock so tests and the synthetic
-trace replayer (tools/serve_bench.py) can drive deterministic
-"wall time" without sleeping.
+All timing uses a caller-injectable clock so tests can drive
+deterministic "wall time" without sleeping.
 """
 
 from __future__ import annotations

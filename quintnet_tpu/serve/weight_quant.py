@@ -1,8 +1,8 @@
 """Weight layout policies: what dtype the serving matmul weights are
 stored in, and how they get there.
 
-KV capacity is solved (serve_r14: 4.1x usable blocks at equal bytes),
-which leaves decode WEIGHT-bandwidth-bound — at serving batch sizes
+KV capacity is solved (int8 blocks: ~4x the blocks at equal pool
+bytes), which leaves decode WEIGHT-bandwidth-bound — at serving batch sizes
 the weights dominate bytes moved per token (the KVQuant framing;
 AWQ/LLM.int8 attack the same bottleneck from the weights side). This
 module makes the packed-weight dtype a POLICY OBJECT on the shared

@@ -94,7 +94,7 @@ lint_paths = _lint.lint_paths
 load_baseline = _lint.load_baseline
 violations_to_baseline = _lint.violations_to_baseline
 
-DEFAULT_PATHS = ("quintnet_tpu", "tools", "bench.py")
+DEFAULT_PATHS = ("quintnet_tpu", "tools")
 
 
 def repo_root() -> str:

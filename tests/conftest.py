@@ -34,36 +34,35 @@ import pytest
 importlib.import_module(
     "quintnet_tpu.ops.paged_attention").INTERPRET = True
 
-# Whole files whose tests are multi-minute on one CPU core (subprocess
-# meshes, full-matrix parity, long schedules). Everything else is
-# auto-marked ``fast`` — `pytest -m fast` stays green in <5 min
-# single-core; `-m slow` (or no -m) runs the rest. Individual tests can
-# still carry an explicit @pytest.mark.slow inside fast files.
+# Whole files kept out of tier-1 (`-m 'not slow'`, six workers, one file
+# a worker). The seconds are one run of each file alone on one worker
+# of this machine (8 cores, CPU, `-m slow`, measured 2026-10-01, PR 29);
+# a file comes off this list when it runs under 120 s that way and no
+# test in it takes over 30 s (ROADMAP D15). Everything else is
+# auto-marked ``fast``; a test in a fast file can still carry an
+# explicit @pytest.mark.slow, and an explicit @pytest.mark.fast inside
+# a file listed here promotes that test into tier-1.
 SLOW_FILES = {
-    "test_5d.py",         # 32-device 5D subprocess run (~9 min budget)
-    "test_multihost.py",  # real 2-process jax.distributed rendezvous
-    "test_launcher.py",   # spawns multi-process demos
-    "test_sp.py",         # ring/zigzag/ulysses golden matrix (~4 min)
-    "test_vp.py",         # vocab-parallel loss/embedding matrix (~2 min)
-    "test_train.py",      # multi-epoch trainer runs + resume
-    "test_generate.py",   # KV-cache + tp decode goldens (~4 min)
-    "test_moe.py",        # MoE routing/dispatch matrix (~4 min)
-    "test_dropout.py",    # seed-discipline matrix across strategies (~5 min)
-    "test_gpt2.py",       # 3D training goldens + HF import (~2 min)
-    "test_dp.py",         # replica-identity/grad-accum goldens (~1.5 min)
-    "test_strategy.py",   # full strategy x schedule matrix (~2 min)
-    "test_flash.py",      # pallas interpret-mode kernels (~1.5 min)
-    "test_llama.py",      # HF goldens + strategy matrix (~3 min; the
-                          # HF-logits golden is promoted fast)
-    "test_lora.py",       # adapter goldens (~1.5 min; identity +
+    "test_sp.py",         # 23 tests, 196 s: ring/zigzag/ulysses goldens
+                          # (zigzag grads alone 42 s)
+    "test_moe.py",        # 20 tests, 173 s: routing/dispatch x pp matrix
+    "test_llama.py",      # 25 tests, 121 s: HF goldens + strategy matrix
+                          # (the HF-logits golden is promoted fast)
+    "test_dropout.py",    # 13 tests, 100 s: seed discipline x strategies
+    "test_fsdp.py",       # 12 tests, 92 s: ZeRO-3 golden matrix (spec-
+                          # transform + guard tests promoted fast)
+    "test_segments.py",   # 20 tests, 92 s: packed-segment matrix incl.
+                          # sp modes (sdpa/host-helper goldens promoted)
+    "test_gpt2.py",       # 10 tests, 85 s: 3D training goldens + HF import
+    "test_lora.py",       # 8 tests, 36 s: adapter goldens (identity +
                           # save/load promoted fast)
-    "test_beam.py",       # beam-search goldens (~1 min)
-    "test_remat_knobs.py",  # remat policy matrix (~1.5 min; plain
+    "test_generate.py",   # 11 tests, 29 s: KV-cache + tp decode goldens
+    "test_multihost.py",  # 1 test, 29 s: a real 2-process
+                          # jax.distributed rendezvous
+    "test_5d.py",         # 1 test, 26 s: 32-device 5D subprocess run
+    "test_remat_knobs.py",  # 3 tests, 21 s: remat policy matrix (plain
                             # policy goldens promoted fast)
-    "test_segments.py",   # packed-segment matrix incl. sp modes (~3 min;
-                          # sdpa/host-helper goldens promoted fast)
-    "test_fsdp.py",       # ZeRO-3 golden matrix (~4 min; spec-transform
-                          # + guard tests promoted fast)
+    "test_launcher.py",   # 2 tests, 5 s: spawns multi-process demos
 }
 
 

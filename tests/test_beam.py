@@ -11,8 +11,6 @@ import pytest
 from quintnet_tpu.models.gpt2 import GPT2Config, gpt2_apply, gpt2_init
 from quintnet_tpu.models.gpt2_generate import gpt2_beam_search, gpt2_generate
 
-pytestmark = pytest.mark.fast
-
 CFG = GPT2Config.tiny()
 
 

@@ -25,12 +25,14 @@ THE contract, in layers:
   work requeues behind the breaker.
 """
 
+import functools
 import http.client
 import json
 import os
 import time
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -41,6 +43,7 @@ from quintnet_tpu.models.gpt2 import GPT2Config, gpt2_init
 from quintnet_tpu.obs.events import EVENT_KINDS
 from quintnet_tpu.serve import ServeEngine, gpt2_family
 from quintnet_tpu.serve.kv_pool import KVPool
+from quintnet_tpu.serve.kv_quant import make_policy
 from quintnet_tpu.serve.scheduler import RequestProgress
 
 CFG = GPT2Config.tiny(n_layer=2)
@@ -225,12 +228,70 @@ class TestPrefillOnly:
         assert seen == [(int(t0), True)]
 
 
+BLOCK = 4
+
+
+@functools.partial(jax.jit, static_argnames="kv")
+def _verify_run(params, caches, ids, start, table, *, kv):
+    """One run of ``ids`` [1, n] at ``start`` through the family's
+    verify contract: logits at every run position + the updated pool."""
+    policy = make_policy(kv)
+    return gpt2_family(CFG).verify(
+        params, caches[0], caches[1], ids, start,
+        jnp.full((1,), ids.shape[1], jnp.int32), table, BLOCK,
+        kv_scales=caches[2:] if policy.scaled else None, policy=policy)
+
+
+def _logprobs_by_grouping(params, kv, tokens, first_run):
+    """Teacher-forced next-token log-probabilities read THROUGH a paged
+    pool that is filled the way an engine fills it: ``tokens
+    [:first_run]`` in one prefill run (one quantization pass a block),
+    every later token appended in a run of its own (each append
+    requantizes its block). Returns ``{n: log-probabilities of the
+    token after tokens[:n]}`` for every n from ``first_run``."""
+    pool = KVPool(n_layers=CFG.n_layer, n_kv_heads=CFG.n_head,
+                  head_dim=CFG.n_embd // CFG.n_head, block_size=BLOCK,
+                  num_blocks=8, policy=kv)
+    table = jnp.asarray([pool.acquire(pool.blocks_for(len(tokens)))])
+    out = {}
+    for start, n in [(0, first_run)] + [(i, 1) for i in
+                                        range(first_run, len(tokens))]:
+        logits, *bufs = _verify_run(
+            params, pool.caches(),
+            jnp.asarray(tokens[None, start:start + n]),
+            jnp.asarray([start], jnp.int32), table, kv=kv)
+        pool.update(*bufs)
+        out[start + n] = np.asarray(jax.nn.log_softmax(logits[0, -1]))
+    return out
+
+
 class TestDisaggGolden:
-    """Disaggregated output BIT-identical to colocated — greedy AND
-    sampled, prefix-cache-on, f32 AND int8 KV — through the in-process
-    engine pair (prefill engine -> exported chain -> decode engine),
-    both with the chain transferred (warm) and without (the local
-    re-prefill fallback)."""
+    """Disaggregated output against colocated — greedy AND sampled,
+    prefix-cache-on, f32 AND int8 KV — through the in-process engine
+    pair (prefill engine -> exported chain -> decode engine), both with
+    the chain transferred (warm) and without (cold: the local
+    re-prefill fallback).
+
+    Warm is BIT-identical under every policy: the chain carries the
+    exporter's pool bytes and scales. Cold is bit-identical to
+    colocated under f32 only. An int8 pool's bytes depend on how the
+    tokens were GROUPED when they were written: the colocated engine
+    prefilled the prompt and appended each generated token (every
+    append requantizes its block under the grown absmax), the cold
+    engine prefills ``prompt + generated`` in one pass, and a run
+    attends to its own keys and values before they are rounded. Both
+    are the int8 policy's arithmetic; they differ by its rounding (at
+    the first position the two engines score differently the logits
+    moved 4.6e-4 on a std of 0.115, measured PR 29), and on a tiny
+    random model whose five best logits lie within 0.01 of each other
+    that flips a sampled token (``[int8-True]`` left the colocated
+    tokens at position 7 of 13 on every tree since the seed). So the
+    int8 cold path is held to what it IS — token-identical to a fresh
+    engine given ``prompt + generated`` as its prompt and the advanced
+    key — and the two groupings to the policy's stated quality gate
+    (tests/test_kv_quant.py: 0.05 nats), here on every next-token
+    log-probability instead of on their mean (they differ by at most
+    1.3e-3 nats in these four cases; an f32 pool's by 1e-6)."""
 
     @pytest.mark.parametrize("kv,sample", [
         ("f32", False), ("f32", True), ("int8", True), ("int8", False),
@@ -272,7 +333,25 @@ class TestDisaggGolden:
                     _advance(key, len(gen)))),
                 max_new_tokens=8))
             C.run(max_steps=200)
-            np.testing.assert_array_equal(C.result(rc), want)
+            if kv == "int8":
+                whole = _engine(params, **kw)
+                rw = whole.submit(
+                    np.concatenate([prompt, np.asarray(gen, np.int32)]),
+                    8 - len(gen), key=_advance(key, len(gen)))
+                whole.run(max_steps=200)
+                np.testing.assert_array_equal(C.result(rc),
+                                              whole.result(rw))
+                # every position the cold engine sampled, teacher-
+                # forced on the colocated tokens
+                appended = _logprobs_by_grouping(params, kv, want[:-1],
+                                                 len(prompt))
+                regrouped = _logprobs_by_grouping(
+                    params, kv, want[:-1], len(prompt) + len(gen))
+                assert len(regrouped) == 8 - len(gen)
+                for n, lp in regrouped.items():
+                    assert np.abs(lp - appended[n]).max() < 0.05, n
+            else:
+                np.testing.assert_array_equal(C.result(rc), want)
 
 
 # ---------------------------------------------------------------------
@@ -464,6 +543,17 @@ def test_disagg_process_fleet_token_identical_smoke(params, rng):
         assert s["finished"] == s["accepted"] == 3
         # the decode replica really served from the transferred chains
         assert s["engines"]["decode0"]["prefill_tokens_saved"] > 0
+        # where the prefill compute ran: every prompt on the prefill
+        # pool, warm-hit tails only (the handed-off token and at most a
+        # copy-on-write slot a request) on the decode pool
+        # (engine counters ride the heartbeat: the last prefill's may
+        # still be on the wire when generate() returns)
+        _wait_until(
+            lambda: fleet.summary()["engines"]["prefill0"][
+                "prefill_tokens"] >= sum(len(p) for p in prompts),
+            timeout=10, msg="the prefill pool's prefill tokens")
+        assert (fleet.summary()["engines"]["decode0"]["prefill_tokens"]
+                <= 2 * len(prompts))
         assert s["replicas"]["prefill0"]["pool"] == "prefill"
         h = fleet.health()
         assert h["disaggregated"] is True

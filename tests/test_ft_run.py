@@ -1,8 +1,6 @@
 """tools/ft_run.py must never rot unexecuted: the fast suite runs the
 supervisor end-to-end (CPU, tiny run, one injected kill + relaunch) and
-checks the JSON goodput contract, and the bench.py staleness scanner
-must surface the committed ft artifact the same way it surfaces the
-serving and training records.
+checks the JSON goodput contract.
 """
 
 import json
@@ -10,16 +8,11 @@ import os
 import subprocess
 import sys
 
-import pytest
-
 REPO = os.path.join(os.path.dirname(__file__), "..")
-sys.path.insert(0, REPO)
-import bench  # noqa: E402
 
 FT_METRIC = "ft_goodput"
 
 
-@pytest.mark.fast
 def test_ft_run_smoke_survives_injected_kill(tmp_path):
     """One SIGTERM kill mid-run: the supervisor relaunches, the child
     resumes from the emergency snapshot, the run completes, and the
@@ -50,31 +43,3 @@ def test_ft_run_smoke_survives_injected_kill(tmp_path):
     assert 0 < rec["value"] <= 1
     # --out appends to an artifacts-style JSON list
     assert json.load(open(out_file)) == [rec]
-
-
-@pytest.mark.fast
-def test_committed_ft_artifact_surfaces_in_staleness_scan():
-    """artifacts/ft_r07.json is discoverable through the same
-    last_known_result scanner the perf benches use, so the goodput
-    evidence survives a dead backend like every other metric."""
-    last = bench.last_known_result(metric=FT_METRIC)
-    assert last is not None
-    assert last["stale"] is True
-    assert last["metric"] == FT_METRIC
-    assert 0 < last["value"] <= 1
-    assert last["source"].startswith("artifacts")
-    assert last["as_of"]
-
-
-@pytest.mark.fast
-def test_committed_ft_artifact_proves_acceptance_scenario():
-    """The committed record documents the end-to-end acceptance run:
-    >= 2 injected kills survived and the run still completed."""
-    recs = json.load(open(os.path.join(REPO, "artifacts", "ft_r07.json")))
-    rec = [r for r in recs if r.get("metric") == FT_METRIC][-1]
-    ex = rec["extras"]
-    assert ex["faults_injected"] >= 2
-    assert ex["faults_survived"] >= 2
-    assert ex["restarts"] >= 2
-    assert ex["completed"] is True
-    assert rec["rc"] == 0

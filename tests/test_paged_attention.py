@@ -7,13 +7,13 @@ The contract ladder:
    (``mha_verify_paged`` at decode, verify and prefill widths) runs
    once per backend from identical pool state, across every
    ``kv_layout_policies`` entry x verify bucket widths x chunked
-   prefill offsets, in CPU interpret mode: outputs BIT-exact for
-   f32/fake_quant, within the pinned tolerance for bf16/int8 (the
-   observed diff is 0.0 — the kernel mirrors the oracle's op
-   sequence — but only the passthrough-f32 and identity-scale cases
-   are *guaranteed* exact by construction, so the quantized dtypes pin
-   a bound instead of a bit pattern), and POOL BYTES + SCALES exactly
-   equal everywhere (the write paths are one math).
+   prefill offsets, in CPU interpret mode: outputs within four f32
+   ulps for f32/fake_quant (``F32_ATOL``: one op sequence compiled
+   twice may sum in two orders), within the pinned tolerance for
+   bf16/int8/fp8 (the kernel mirrors the oracle's op sequence and the
+   observed diff is at most 3e-08, but nothing guarantees it, so the
+   quantized dtypes pin a bound instead of a bit pattern), and POOL BYTES +
+   SCALES exactly equal everywhere (the write paths are one math).
 2. **GQA** — the same matrix through the llama blocks (4 query heads
    on 2 kv heads): the kernel resolves the repeat in its index maps.
 3. **Engine goldens** — ``ServeEngine(attn_kernel="pallas")`` serves
@@ -47,10 +47,28 @@ from quintnet_tpu.serve.kv_quant import make_policy
 
 CFG = GPT2Config.tiny(n_layer=2)
 
-# quantized-dtype tolerance: the kernel mirrors the oracle op for op,
-# so the OBSERVED diff is 0.0; the pin leaves headroom only for
-# platform-lowering drift in ops that are not exact by construction
+# quantized-dtype tolerance: the kernel mirrors the oracle op for op
+# (the OBSERVED diff is 0.0 to 3e-08 by case); the pin leaves headroom
+# only for platform-lowering drift in ops that are not exact by
+# construction
 QUANT_ATOL = 1e-6
+
+# f32 / fake_quant outputs: the two backends run the SAME op sequence
+# on the same f32 values (ops/paged_attention._kernel mirrors
+# _masked_sdpa op for op, head-batched dots included), but XLA:CPU
+# compiles it twice in different surroundings — once fused into the
+# whole attention program, once as the interpreter's grid-step body —
+# and is free to vectorise a reduction (a contraction, the softmax's
+# sum) in another order each time; float addition does not associate.
+# One case shows it, on every tree since the seed: the SECOND chunk of
+# the chunked prefill (one row of 8 at offset 8, 5 real tokens over 8
+# cached) differs by 5.96e-08 in a quarter of its outputs, an ulp or
+# two at their magnitude (up to 0.34 after the output projection);
+# every other f32 case of the matrix reads 0.0 on this machine. Bit
+# equality is therefore a property of the shapes, not of the kernel:
+# hold the outputs to four ulps of [0.5, 1) and the POOL, which both
+# backends write with one scatter, to bits (_assert_pools_match).
+F32_ATOL = 4 * 2.0 ** -24
 
 
 @pytest.fixture(scope="module")
@@ -120,10 +138,8 @@ def _assert_pools_match(pa, pb, policy, tables):
 
 def _assert_out(ya, yb, policy):
     ya, yb = np.asarray(ya), np.asarray(yb)
-    if policy.name in ("f32", "fake_quant"):
-        np.testing.assert_array_equal(ya, yb)
-    else:
-        np.testing.assert_allclose(ya, yb, atol=QUANT_ATOL, rtol=0)
+    atol = F32_ATOL if policy.name in ("f32", "fake_quant") else QUANT_ATOL
+    np.testing.assert_allclose(ya, yb, atol=atol, rtol=0)
 
 
 class TestMhaParityMatrix:

@@ -31,7 +31,7 @@ def params():
 
 # params whose greedy dynamics settle into long repetitive runs (so
 # acceptance-dependent assertions have something to accept) — verified
-# behaviour of this (init key, n_positions) pair, cf. serve_r10 notes
+# behaviour of this (init key, n_positions) pair
 CFG_REP = GPT2Config.tiny(n_layer=2, n_positions=256)
 
 

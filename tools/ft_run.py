@@ -22,13 +22,11 @@ Modes:
   python tools/ft_run.py                         # 2 hard kills, CPU-ok
   python tools/ft_run.py --kill-at 5,11 --kill-mode sigterm
   python tools/ft_run.py --epochs 2 --samples 48 --kill-at 2  # smoke
-      (CI runs this — tests/test_ft_bench.py — so the CLI can never rot)
+      (CI runs this — tests/test_ft_run.py — so the CLI can never rot)
   python tools/ft_run.py --child ...             # internal: one attempt
 
-``--out FILE`` appends the record to an artifacts JSON list the same
-way serve_bench.py artifacts are kept (bench.last_known_result scans
-them — goodput gets the same staleness story as the perf benches).
-Report schema: docs/fault_tolerance.md.
+``--out FILE`` appends the record to a JSON list in FILE. Report
+schema: docs/fault_tolerance.md.
 """
 
 from __future__ import annotations

@@ -6,8 +6,8 @@ the obs formats (quintnet_tpu/obs/):
 
 - a crash dump (``obs/crashdump.py``: ``{"kind": "crash_dump",
   "ring": [...], "traces": {...}}``) — the post-mortem, visualized;
-- a raw obs dump (``{"ring": [...], "traces": {...}}``) — what
-  ``tools/serve_bench.py --trace-out`` writes from a timed replay.
+- a raw obs dump (``{"ring": [...], "traces": {...}}``:
+  ``eng.recorder.snapshot()`` beside ``eng.tracer.snapshot()``).
 
 Mapping (the Chrome trace-event format, JSON Array/Object flavor):
 
@@ -366,8 +366,7 @@ def _load_dump(path: str) -> Dict:
             and "events" not in payload):
         raise SystemExit(
             f"{path}: no 'ring', 'traces' or 'events' — not a crash "
-            f"dump or obs dump (tools/serve_bench.py --trace-out "
-            f"writes one)")
+            f"dump or obs dump")
     return payload
 
 
