@@ -32,6 +32,7 @@ from quintnet_tpu.analysis.jaxpr_audit import (
     dtype_report,
     gathered_view_gathers,
     pool_scan_operands,
+    view_head_splits,
     widened_view_dots,
 )
 from quintnet_tpu.analysis.lint import (
@@ -74,6 +75,7 @@ __all__ = [
     "dtype_report",
     "gathered_view_gathers",
     "pool_scan_operands",
+    "view_head_splits",
     "widened_view_dots",
     "RULES",
     "Violation",

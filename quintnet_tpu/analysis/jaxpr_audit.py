@@ -400,9 +400,10 @@ def widened_view_dots(fn: Callable, *args, table_width: int,
     """Count the ``dot_general`` eqns that contract a gathered row view
     in a dtype WIDER than the one it is stored in: one operand carries
     a whole row's positions — a ``table_width * block_size`` dim (the
-    ``[B, H, T, Dh]`` view of `paged_gather`) or that dim still split
-    by block, ``table_width, block_size`` side by side — and its float
-    dtype is narrower than the other operand's. A TPU has no mixed
+    head-major ``[B, H, T, Dh]`` view of `paged_gather`, or its rows
+    as gathered, ``[B, T, F]``) or that dim still split by block,
+    ``table_width, block_size`` side by side — and its float dtype is
+    narrower than the other operand's. A TPU has no mixed
     dot, so the compiler widens the BIG operand — a second, f32 copy
     of every row written to HBM and read back each layer (at GPT-2
     XL's serving shapes 75 of a 128-ms decode step).
@@ -413,14 +414,14 @@ def widened_view_dots(fn: Callable, *args, table_width: int,
     Structural like :func:`gathered_view_gathers` (a scan body counts
     once; ``pallas_call`` interiors are skipped). CALLER CONTRACT: pick
     a geometry whose row length collides with no other dim of a dot
-    operand of rank 4 or more."""
+    operand of rank 3 or more."""
     closed = jax.make_jaxpr(fn)(*args, **kwargs)
     row = table_width * block_size
     found = 0
 
     def is_view(aval) -> bool:
         shape = tuple(aval.shape)
-        return len(shape) >= 4 and (
+        return len(shape) >= 3 and (
             row in shape
             or (table_width, block_size) in zip(shape, shape[1:]))
 
@@ -436,6 +437,42 @@ def widened_view_dots(fn: Callable, *args, table_width: int,
                     and view.dtype.itemsize < other.dtype.itemsize):
                 found += 1
                 return
+
+    _walk_skip_kernels(closed.jaxpr, visit)
+    return found
+
+
+def view_head_splits(fn: Callable, *args, table_width: int,
+                     block_size: int, **kwargs) -> int:
+    """Count the ``reshape`` eqns that split a gathered row view's LANE
+    dim into heads: the operand ends ``[..., table_width, block_size,
+    F]`` (`paged_gather`'s rows, pad lanes cut or not) and the result
+    ``[..., table_width, block_size, H, Dh]`` with ``H * Dh == F``. On
+    the chip a ``Dh``-wide minor dim is half a lane row, so the split
+    view is a COPY of the view, written padded to twice its bytes and
+    read back (at GPT-2 XL's decode shapes most of the step: PERF.md,
+    PR 28 and 30). A program with few query rows contracts the view
+    as gathered instead (nn/attention._lane_diag_sdpa): decode and
+    verify programs on a bf16/f16 pool read ZERO; a prefill bucket, an
+    f32 pool and a scaled policy's dequantized view keep the split, 2
+    a layer body (k and v).
+
+    Structural like :func:`gathered_view_gathers` (a scan body counts
+    once; ``pallas_call`` interiors are skipped)."""
+    closed = jax.make_jaxpr(fn)(*args, **kwargs)
+    found = 0
+
+    def visit(eqn):
+        nonlocal found
+        if eqn.primitive.name != "reshape":
+            return
+        src = tuple(eqn.invars[0].aval.shape)
+        out = tuple(eqn.outvars[0].aval.shape)
+        if (len(src) >= 3 and len(out) == len(src) + 1
+                and src[-3:-1] == (table_width, block_size)
+                and out[:-2] == src[:-1]
+                and out[-2] * out[-1] == src[-1]):
+            found += 1
 
     _walk_skip_kernels(closed.jaxpr, visit)
     return found

@@ -103,21 +103,40 @@ def _proj_out(p, o, tp_axis: Optional[str], lora=None, lora_scale=None):
     return y
 
 
+def _stored_narrower(x, view) -> bool:
+    """Whether a cached ``view`` is STORED in a float narrower than
+    ``x``'s that a dot takes natively (bf16, f16)."""
+    return (view.dtype in (jnp.bfloat16, jnp.float16)
+            and jnp.issubdtype(x.dtype, jnp.floating)
+            and x.dtype.itemsize > view.dtype.itemsize)
+
+
 def _as_stored(x, view):
     """``x`` in the dtype a cached ``view`` is STORED in, where that is
     a float narrower than ``x``'s that a dot takes natively (bf16,
     f16); ``x`` itself otherwise. The view is the big operand — a
     mixed-dtype dot makes the compiler widen IT, a second copy of every
     row in HBM each layer — so the small operand goes down instead."""
-    if (view.dtype in (jnp.bfloat16, jnp.float16)
-            and jnp.issubdtype(x.dtype, jnp.floating)
-            and x.dtype.itemsize > view.dtype.itemsize):
-        return x.astype(view.dtype)
-    return x
+    return x.astype(view.dtype) if _stored_narrower(x, view) else x
 
 
 # rows a lone query is padded to: one sublane tile of the matrix unit
 _MIN_DOT_ROWS = 8
+
+# The most query rows a paged program may have (query heads x tokens a
+# row, ``Hq * P``) and still contract the gathered view AS GATHERED,
+# heads on the lane diagonal (:func:`_lane_diag_sdpa`). That form pays
+# ``Hkv`` times the attention's FLOPs, which grow with the rows, to
+# save the view's head split, which does not. On the v5e, the engine's
+# own GPT-2 XL programs (25 heads, 12 x 1,024-position rows; my chip
+# run, PR 30), diagonal against split: decode (25 rows) 15.2 ms
+# against 50.6; a 16-wide prefill bucket (400 rows) 11.17 against
+# 11.21, a 32-wide (800) 12.7 against 11.8, a 64-wide (1,600) 17.3
+# against 12.9. The forms cross near 400 rows; 256 keeps every decode
+# step and a verify run of four drafts (XL 125 rows, an 8-kv-head GQA
+# model's 160) on the diagonal and every prefill bucket of a model
+# with 16 heads or more on the split.
+_MAX_DIAG_ROWS = 256
 
 
 def _masked_sdpa(q, k_all, v_all, valid, *, page: Optional[int] = None,
@@ -183,6 +202,62 @@ def _masked_sdpa(q, k_all, v_all, valid, *, page: Optional[int] = None,
             o = jnp.einsum("bhst,bhtd->bhsd", probs, v_all,
                            preferred_element_type=jnp.float32)
         return o[:, :, :s].astype(out_dtype)
+
+
+def _lane_diag_sdpa(q, k_rows, v_rows, valid, *, kv_heads: int,
+                    scale: Optional[float] = None):
+    """:func:`_masked_sdpa` for a program with FEW query rows, on the
+    view as :func:`paged_gather` leaves it: ``k_rows``/``v_rows``
+    [B, M, bs, F], a position's kv heads side by side in the lanes of
+    one pool row. The head structure goes into the SMALL operand: q
+    [B, Hq, S, Dh] is spread to ``R = Hq * S`` rows of ``F`` lanes,
+    each holding its ``Dh`` values in the lanes of the kv head it reads
+    (``h // (Hq // kv_heads)``: GQA needs no repeat of the view) and
+    exact zeros in every other lane, the pool's pad lanes included.
+    Scores [R, F] x [T, F] and values [R, T] x [T, F] then read the
+    stored bytes where they lie (``T = M * bs``: a row's blocks are
+    adjacent in the gathered rows, so the flat view is the same
+    bytes), and each query row takes its own head's lanes off the
+    diagonal of the small [R, F] result.
+
+    Splitting the view into heads instead is a copy of it a layer on
+    the chip — a ``Dh``-wide minor dim is half a lane row, so the copy
+    is written padded to twice the bytes and read back: 34 of a 48-ms
+    decode step at GPT-2 XL (PERF.md, PR 30) — where this costs
+    ``kv_heads`` times the FLOPs on rows that leave the matrix unit
+    mostly idle anyway. The arithmetic is :func:`_masked_sdpa`'s stored
+    branch: q and the probabilities rounded to the view's dtype, f32
+    sums, f32 softmax over ``valid`` [B, 1 | Hq, S, T]; the extra
+    products are exact zeros — given FINITE pad lanes, which every
+    writer of the pool zeroes (docs/serving.md, "The arithmetic
+    contract") — so a sum differs from the split form's by its order
+    alone. Fewer than :data:`_MIN_DOT_ROWS` rows are padded with
+    masked rows, and dropped. Scope ``sdpa``."""
+    with jax.named_scope("sdpa"):
+        b, hq, s, dh = q.shape
+        _, m, bs, f = k_rows.shape
+        rows, t = hq * s, m * bs
+        pad = max(_MIN_DOT_ROWS - rows, 0)
+        own = (jnp.arange(hq)[:, None] // (hq // kv_heads)
+               == jnp.arange(kv_heads)[None, :])[None, :, None, :, None]
+        qd = jnp.where(own, _as_stored(q, k_rows)[:, :, :, None, :], 0)
+        qd = jnp.pad(qd.reshape(b, rows, kv_heads * dh),
+                     ((0, 0), (0, pad), (0, f - kv_heads * dh)))
+        valid = jnp.pad(
+            jnp.broadcast_to(valid, (b, hq, s, t)).reshape(b, rows, t),
+            ((0, 0), (0, pad), (0, 0)))
+        scores = jnp.einsum("brf,btf->brt", qd, k_rows.reshape(b, t, f),
+                            preferred_element_type=jnp.float32)
+        scores = (scores / math.sqrt(dh) if scale is None
+                  else scores * scale)
+        scores = jnp.where(valid, scores, jnp.finfo(jnp.float32).min)
+        probs = _as_stored(
+            jax.nn.softmax(scores, axis=-1).astype(q.dtype), v_rows)
+        o = jnp.einsum("brt,btf->brf", probs, v_rows.reshape(b, t, f),
+                       preferred_element_type=jnp.float32)
+        o = o[:, :rows, :kv_heads * dh].reshape(b, hq, s, kv_heads, dh)
+        return jnp.where(own, o, 0).sum(axis=3).astype(
+            jnp.result_type(q, v_rows))
 
 
 def sdpa(q, k, v, *, causal: bool, softmax_dtype=jnp.float32,
@@ -320,8 +395,9 @@ def mha_apply(
 # 128-lane vregs, so the chip lays the buffer out row-major). Programs
 # carry the pool WHOLE through their layer loop and address it by
 # ``(layer, slot)``: one scatter a write, one gather a read, in place.
-# Only the gathered view is split back into heads. ``F`` is read off
-# the pool's shape, ``H`` and ``Dh`` off the fresh projections.
+# Only the gathered view is split back into heads, and only where a
+# program has many query rows (:data:`_MAX_DIAG_ROWS`). ``F`` is read
+# off the pool's shape, ``H`` and ``Dh`` off the fresh projections.
 # ---------------------------------------------------------------------
 def _pool_rows(x, width: int):
     """[..., H, Dh] -> pool rows [..., width]: heads flattened, zero
@@ -334,8 +410,7 @@ def _pool_rows(x, width: int):
 
 
 def _pool_heads(rows, heads: int, head_dim: int):
-    """Pool rows [..., F] -> [..., H, Dh]; the pad lanes end here —
-    nothing downstream ever sees them."""
+    """Pool rows [..., F] -> [..., H, Dh], the pad lanes cut off."""
     return rows[..., :heads * head_dim].reshape(*rows.shape[:-1], heads,
                                                 head_dim)
 
@@ -372,16 +447,21 @@ def paged_write(k_pool, v_pool, layer, k, v, positions, lens, *,
 
 
 def paged_gather(pool, layer, block_tables, *, block_size: int,
-                 head_shape):
-    """Pool [L, N_blocks*block_size, F] + [B, M] tables -> the
-    position-ordered per-row view [B, H, M*block_size, Dh] of
-    ``layer``, gathered straight from the whole pool. Token position t
-    of a row lives at (table[t // bs], t % bs), so the gathered view is
+                 head_shape=None):
+    """Pool [L, N_blocks*block_size, F] + [B, M] tables -> each row's
+    blocks of ``layer``, gathered straight from the whole pool:
+    ``[B, M, block_size, F]`` pool rows as stored. Token position t of
+    a row lives at (table[t // bs], t % bs), so the gathered view is
     exactly position-ordered and the usual ``arange <= pos`` length
-    mask applies unchanged. ``head_shape`` = (H, Dh)."""
+    mask applies unchanged. With ``head_shape`` = (H, Dh) the rows are
+    then cut into the head-major view [B, H, M*block_size, Dh] — the
+    form :func:`_masked_sdpa` takes, and on the chip a re-layout of
+    the whole view that :func:`_lane_diag_sdpa` does without."""
     L, n, f = pool.shape
     pages = pool.reshape(L, n // block_size, block_size, f)[
         layer, block_tables]                          # [B, M, bs, F]
+    if head_shape is None:
+        return pages
     pages = _pool_heads(pages, *head_shape)           # [B, M, bs, H, Dh]
     b, m, bs, h, dh = pages.shape
     return pages.transpose(0, 3, 1, 2, 4).reshape(b, h, m * bs, dh)
@@ -427,9 +507,11 @@ def _gather_kv(pools, layer, policy, block_tables, *, block_size: int,
     shares: gather both pools' rows position-ordered and — under a
     scaled layout policy, ``pools`` = (k, v, k_scale, v_scale) —
     dequantize with their block scales (:func:`paged_gather_dequant`).
-    Also the single seam the fused-kernel dispatch
-    (``attn_kernel="pallas"``, ops/paged_attention.py) plugs into
-    INSTEAD of — the Pallas path never calls this."""
+    ``head_shape`` None (passthrough pools only): the rows as gathered,
+    [B, M, bs, F], unsplit (:func:`paged_gather`). Also the single seam
+    the fused-kernel dispatch (``attn_kernel="pallas"``,
+    ops/paged_attention.py) plugs into INSTEAD of — the Pallas path
+    never calls this."""
     k_pool, v_pool, *sc = pools
     ks, vs = sc if sc else (None, None)
     with jax.named_scope("kv_gather"):
@@ -545,10 +627,12 @@ def _quant_span(p_tokens: int, block_size: int, table_width: int) -> int:
 
 
 def paged_kv_step(pools, layer, k, v, positions, lens, block_tables, *,
-                  block_size: int, policy=None):
+                  block_size: int, policy=None, split_heads: bool = True):
     """Write the rows' fresh (k, v) runs into ``layer`` of the pool and
     read every row's whole history back: ``(k_all, v_all, pools)``,
-    the views [S, H, M*bs, Dh] holding the runs just written.
+    the views [S, H, M*bs, Dh] holding the runs just written — or,
+    with ``split_heads`` off (passthrough pools only), the rows as
+    gathered, [S, M, bs, F] (:func:`paged_gather`).
 
     ``pools`` = (k, v) under a passthrough policy: scatter, then gather
     (:func:`paged_write`, :func:`_gather_kv`). ``pools`` = (k, v,
@@ -556,7 +640,7 @@ def paged_kv_step(pools, layer, k, v, positions, lens, block_tables, *,
     DEQUANT, insert the run into the f32 view, quantize exactly the
     touched blocks back (:func:`paged_quant_update`) — the scores read
     the exact f32 run, the pool its quantized bytes."""
-    head_shape = (k.shape[1], k.shape[3])
+    head_shape = (k.shape[1], k.shape[3]) if split_heads else None
     if len(pools) == 2:
         pools = paged_write(*pools, layer, k, v, positions, lens,
                             block_tables=block_tables,
@@ -657,9 +741,13 @@ def paged_attend(q, k, v, pools, layer, positions, lens, block_tables, *,
     what makes their tokens bit-equal.
 
     ``attn_kernel``: "xla" is the gathered-view math
-    (:func:`paged_kv_step` + :func:`_masked_sdpa`); "pallas" the fused
-    kernel (:func:`_paged_attend_pallas`) — same mask, same softmax
-    sequence, bit-parity-pinned against this path."""
+    (:func:`paged_kv_step`, then :func:`_masked_sdpa` on the view split
+    into heads or — a bf16/f16 pool under at most
+    :data:`_MAX_DIAG_ROWS` query rows, i.e. decode and verify —
+    :func:`_lane_diag_sdpa` on the rows as gathered: the same
+    arithmetic, chosen from shapes and dtypes alone); "pallas" the
+    fused kernel (:func:`_paged_attend_pallas`) — same mask, same
+    softmax sequence, bit-parity-pinned against this path."""
     if attn_kernel == "pallas":
         if scale is not None:
             raise NotImplementedError(
@@ -669,15 +757,23 @@ def paged_attend(q, k, v, pools, layer, positions, lens, block_tables, *,
         return _paged_attend_pallas(
             q, k, v, pools, layer, positions, lens, block_tables,
             block_size=block_size, policy=policy)
+    # the rows as gathered iff they are stored in a float narrower than
+    # q (what _masked_sdpa calls `stored`; a scaled or float8 pool's
+    # view is a widened f32 one) and the program has few query rows
+    diag = (len(pools) == 2 and _stored_narrower(q, pools[0])
+            and q.shape[1] * q.shape[2] <= _MAX_DIAG_ROWS)
     k_all, v_all, pools = paged_kv_step(
         pools, layer, k, v, positions, lens, block_tables,
-        block_size=block_size, policy=policy)
+        block_size=block_size, policy=policy, split_heads=not diag)
     rep = q.shape[1] // k.shape[1]
-    valid = (jnp.arange(k_all.shape[2])[None, None, :]
+    valid = (jnp.arange(block_tables.shape[1] * block_size)[None, None, :]
              <= positions[:, :, None])[:, None]           # [S, 1, P, T]
     groups = q.shape[2] // k.shape[2]
     if groups > 1 and valid.shape[2] > 1:
         valid = jnp.tile(valid, (1, 1, groups, 1))
+    if diag:
+        return _lane_diag_sdpa(q, k_all, v_all, valid, kv_heads=k.shape[1],
+                               scale=scale), pools
     o = _masked_sdpa(q, repeat_kv(k_all, rep), repeat_kv(v_all, rep),
                      valid, page=block_size, scale=scale)
     return o, pools

@@ -17,12 +17,15 @@ every program that touches it. With the minor dim a whole number of
 lanes the default is row-major, nothing strided (PERF.md, PR 28).
 Programs carry the pool WHOLE through their layer loop and address it
 by ``(layer, slot)`` (nn/attention.paged_write scatters rows in,
-paged_gather pages a row's blocks out and only then splits heads);
+paged_gather pages a row's blocks out, and only a program with many
+query rows then splits them into heads);
 the flat "slot" dim is ``block * block_size + offset``. Under TP the
 feature dim is head-sharded over the mesh: each rank holds its LOCAL
 heads' rows, padded on their own (``F = tp * feature_width(H_kv / tp
-* Dh)``, heads contiguous within a rank's part). Pad lanes are never
-read into a score and count for nothing: ``bytes_per_token`` is the
+* Dh)``, heads contiguous within a rank's part). Pad lanes are ZERO
+— every writer keeps them so, because decode multiplies them by zero
+query lanes instead of cutting them off (docs/serving.md, "The
+pad-lane rule") — and count for nothing: ``bytes_per_token`` is the
 model's own. Host-side records (chain export/import, the host tier,
 the disaggregated handoff) keep ``[L, block_size, H_kv, Dh]``; the
 reshape happens at the pool's edge (:meth:`KVPool.read_slots`,

@@ -12,7 +12,8 @@ checked without a chip (PR 21, the bring-up round).
    plan must not hold a second copy of the per-slot state; and (PR 28)
    the decode and prefill programs at GPT-2 XL's widths, which must
    take the KV pool row-major and hold no copy of it or of a layer's
-   slice. The parent commit's paged kernel was
+   slice — nor (PR 30) a decode program of a gathered view. The parent
+   commit's paged kernel was
    REFUSED at every one of these shapes (16 MiB default scoped-VMEM
    budget; a (1, Hkv) scale block; a lane-splitting reshape) —
    interpret mode cannot see any of that. Nothing runs: a compile that
@@ -209,14 +210,19 @@ def test_hybrid_decode_holds_one_copy_of_the_state_on_v5e(chip):
     assert plan.alias_size_in_bytes >= state_bytes     # in and out alias
     assert plan.temp_size_in_bytes < state_bytes / 3, (
         plan.temp_size_in_bytes, state_bytes)
-    _assert_pool_row_major_and_uncopied(compiled.as_text(), pool)
+    _assert_pool_row_major_and_uncopied(compiled.as_text(), pool,
+                                        view=(slots, width, bs))
 
 
-def _assert_pool_row_major_and_uncopied(hlo: str, pool):
+def _assert_pool_row_major_and_uncopied(hlo: str, pool, view=None):
     """The compiled program takes ``pool``-shaped parameters in the
     row-major layout and holds no ``copy`` / ``transpose`` of the whole
     pool or of one layer's slice of it (tools/pool_layout_audit.py is
-    the same reading, for the cells' engines)."""
+    the same reading, for the cells' engines) — nor, for a decode
+    program, of a gathered ``view`` (slots, table width, block size:
+    the rows as gathered lead with these dims, and so did the view
+    split into heads, which the compiler wrote out as a copy of it
+    every layer until PR 30)."""
     spec = importlib.util.spec_from_file_location(
         "pool_layout_audit", os.path.join(REPO, "tools",
                                           "pool_layout_audit.py"))
@@ -229,6 +235,9 @@ def _assert_pool_row_major_and_uncopied(hlo: str, pool):
     slices = {tuple(pool.shape), (1, *pool.shape[1:]), tuple(pool.shape[1:])}
     for c in got["big_copies"]:
         assert tuple(c["dims"]) not in slices, c
+        if view is not None:
+            assert tuple(c["dims"][:3]) != tuple(view), c
+            assert tuple(c["dims"][:2]) != (view[0] * view[1], view[2]), c
 
 
 @pytest.mark.parametrize("width", ("decode", "prefill"))
@@ -281,7 +290,9 @@ def test_xl_programs_take_the_pool_row_major_and_copy_none_of_it(chip,
         params, pool, pool, *args).compile()
     assert compiled.memory_analysis().alias_size_in_bytes >= 2 * (
         pool.shape[0] * pool.shape[1] * pool.shape[2] * 2)
-    _assert_pool_row_major_and_uncopied(compiled.as_text(), pool)
+    _assert_pool_row_major_and_uncopied(
+        compiled.as_text(), pool,
+        view=(slots, table, bs) if width == "decode" else None)
 
 
 def test_cache_dir_from_env_is_left_alone(monkeypatch, tmp_path,

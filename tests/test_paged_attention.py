@@ -39,7 +39,7 @@ import numpy as np
 import pytest
 
 from quintnet_tpu.analysis import (gathered_view_gathers,
-                                   widened_view_dots)
+                                   view_head_splits, widened_view_dots)
 from quintnet_tpu.analysis.specs import attn_kernels, kv_layout_policies
 from quintnet_tpu.models.gpt2 import GPT2Config, gpt2_init
 from quintnet_tpu.serve import ServeEngine, SpecConfig, gpt2_family
@@ -377,6 +377,54 @@ class TestEngineGoldens:
         _ab(None, prompts, 6, family=llama_family(cfg), fam_params=lp,
             kv_dtype="int8", max_slots=2)
 
+    @pytest.mark.parametrize("family", ("gpt2", "gpt2-tp2", "llama",
+                                        "granite_hybrid"))
+    def test_bf16_kv_tokens_equal_either_form(self, params, prompts,
+                                              family, monkeypatch):
+        """Greedy tokens of a bf16-KV engine are the same whether decode
+        contracts the view as gathered (heads on the lane diagonal) or
+        split into heads, the form every program took before: the two
+        differ by the order of f32 sums alone. Under tp each rank
+        spreads its LOCAL heads over its own part of the row."""
+        import quintnet_tpu.nn.attention as attention
+
+        kw = dict(kv_dtype="bf16")
+        if family == "gpt2-tp2":
+            from jax.sharding import Mesh
+
+            kw.update(mesh=Mesh(np.array(jax.devices()[:2]), ("tp",)))
+        elif family == "llama":
+            from quintnet_tpu.models.llama import LlamaConfig, llama_init
+            from quintnet_tpu.serve import llama_family
+
+            cfg = LlamaConfig.tiny()
+            kw.update(family=llama_family(cfg),
+                      fam_params=llama_init(jax.random.key(4), cfg))
+        elif family == "granite_hybrid":
+            from quintnet_tpu.models.granite_hybrid import (
+                GraniteHybridConfig, granite_hybrid_init)
+            from quintnet_tpu.serve import granite_hybrid_family
+
+            cfg = GraniteHybridConfig.tiny()
+            kw.update(family=granite_hybrid_family(cfg),
+                      fam_params=granite_hybrid_init(jax.random.key(6),
+                                                     cfg),
+                      prefix_cache=False)
+        served = {}
+        for form, most_rows in (("diagonal", attention._MAX_DIAG_ROWS),
+                                ("split", 0)):
+            monkeypatch.setattr(attention, "_MAX_DIAG_ROWS", most_rows)
+            eng = _engine(params, "xla", **kw)
+            served[form] = _serve(eng, prompts, 8)
+            args = next(a for s, a in eng._warmup_calls()
+                        if s.fn is eng._decode.fn)
+            assert view_head_splits(
+                eng._decode.fn, *args, table_width=eng.table_width,
+                block_size=eng.pool.block_size) == (
+                    0 if form == "diagonal" else 2)
+        for a, b in zip(served["diagonal"], served["split"]):
+            np.testing.assert_array_equal(a, b)
+
     def test_tp2_fake_quant(self, params, prompts):
         from jax.sharding import Mesh
 
@@ -603,9 +651,17 @@ class TestStoredDtypeContract:
             pr = jnp.einsum("bhsd,bhmtd->bhsmt", q, k)
             return jnp.einsum("bhsmt,bhmtd->bhsd", pr, v)
 
+        def mixed_as_gathered(q, k, v):     # rows [S, T, F], f32 q
+            pr = jnp.einsum("brf,btf->brt", q, k)
+            return jnp.einsum("brt,btf->brf", pr, v)
+
         kw = dict(table_width=M, block_size=BS)
         assert widened_view_dots(mixed, q, view, view, **kw) == 2
         assert widened_view_dots(mixed_by_page, q, view, view, **kw) == 2
+        rows = jnp.zeros((S, M * BS, H * D + PAD), jnp.bfloat16)
+        assert widened_view_dots(
+            mixed_as_gathered, jnp.zeros((S, H, H * D + PAD), jnp.float32),
+            rows, rows, **kw) == 2
         assert widened_view_dots(stored, q, view, view, **kw) == 0
         f32 = view.astype(jnp.float32)
         assert widened_view_dots(mixed, q, f32, f32, **kw) == 0
@@ -690,7 +746,9 @@ class TestStoredDtypeContract:
         """An f32 view takes the branch it always took: no convert of q
         or of the probabilities to a 16-bit float, no pad of the query
         row. The bf16 pool is the positive control: q and the
-        probabilities go down, the lone row is padded once."""
+        probabilities go down, and the spread query rows — two, a head
+        each, on the lane diagonal — are padded to a sublane tile, with
+        their mask."""
         from quintnet_tpu.nn.attention import mha_verify_paged
 
         attn, x, kp, vp, pos = self._decode_inputs(jnp.dtype(pool_dtype))
@@ -704,11 +762,260 @@ class TestStoredDtypeContract:
                 if e.primitive.name == "convert_element_type"
                 and e.invars[0].aval.dtype == jnp.float32
                 and e.params["new_dtype"].itemsize == 2
-                and e.invars[0].aval.ndim == 4]
+                and e.invars[0].aval.ndim >= 3]  # not the pool's rows
         pads = [e for e in eqns if e.primitive.name == "pad"
-                and e.invars[0].aval.ndim == 4]  # not the pool rows' lanes
+                and e.invars[0].aval.ndim >= 3]  # not the pool rows' lanes
         assert len(down) == (2 if narrowed else 0), down
-        assert len(pads) == (1 if narrowed else 0), pads
+        assert len(pads) == (2 if narrowed else 0), pads
+
+
+# ---------------------------------------------------------------------
+# 6. few query rows: the view is contracted as gathered, heads on the
+#    lane diagonal
+# ---------------------------------------------------------------------
+
+def _old_form(pool, tables):
+    """The gathered view cut into heads — what every program did before
+    decode and verify stopped: 1 split a view."""
+    pages = pool.reshape(LAYERS, NB, BS, -1)[LAYER, tables]
+    pages = pages[..., :H * D].reshape(S, M, BS, H, D)
+    return pages.transpose(0, 3, 1, 2, 4).reshape(S, H, M * BS, D)
+
+
+class TestLaneDiagonal:
+    SEQ = 128     # a 128-wide prefill bucket: 4 heads x 128 = 512 rows
+
+    @pytest.fixture(scope="class")
+    def engines(self):
+        """family -> (bf16-KV engine, its params), built on first use:
+        tracing only, nothing compiles."""
+        from quintnet_tpu.models.granite_hybrid import (
+            GraniteHybridConfig, granite_hybrid_init)
+        from quintnet_tpu.models.llama import LlamaConfig, llama_init
+        from quintnet_tpu.serve import granite_hybrid_family, llama_family
+
+        built = {}
+
+        def get(family, kv_dtype="bf16"):
+            if (family, kv_dtype) in built:
+                return built[family, kv_dtype]
+            kw = dict(kv_dtype=kv_dtype, max_seq_len=self.SEQ,
+                      num_blocks=160, spec=SpecConfig(max_draft=4))
+            if family == "gpt2":
+                cfg = GPT2Config.tiny(n_layer=2, n_positions=self.SEQ)
+                fam, p = gpt2_family(cfg), gpt2_init(jax.random.key(0), cfg)
+            elif family == "llama":
+                cfg = LlamaConfig.tiny(n_positions=self.SEQ)
+                fam, p = llama_family(cfg), llama_init(jax.random.key(4),
+                                                       cfg)
+            else:
+                cfg = GraniteHybridConfig.tiny()
+                fam, p = granite_hybrid_family(cfg), granite_hybrid_init(
+                    jax.random.key(6), cfg)
+                kw.update(prefix_cache=False, spec=None)
+            built[family, kv_dtype] = (
+                _engine(None, "xla", family=fam, fam_params=p, **kw), p)
+            return built[family, kv_dtype]
+
+        return get
+
+    @staticmethod
+    def _program(eng, which):
+        """(fn, args) of an engine program by name; a recurrent
+        family's verify is its contract, jitted by hand (the engine
+        refuses speculation there)."""
+        for sentinel, args in eng._warmup_calls():
+            if sentinel.fn.__name__ == which:
+                return sentinel.fn, args
+        assert which == "serve_verify_b4", which
+        fam, pool = eng.family, eng.pool
+        k, v, ssm, conv = pool.caches()
+        rows = eng.max_slots
+
+        def verify(params, k, v, ssm, conv, ids, starts, lens, tables):
+            return fam.verify(params, k, v, ids, starts, lens, tables,
+                              pool.block_size, policy=pool.policy,
+                              state=(ssm, conv))
+
+        return verify, (eng.params, k, v, ssm, conv,
+                        jnp.zeros((rows, 5), jnp.int32),
+                        jnp.zeros((rows,), jnp.int32),
+                        jnp.ones((rows,), jnp.int32),
+                        jnp.zeros((rows, eng.table_width), jnp.int32))
+
+    @pytest.mark.parametrize("family", ("gpt2", "llama", "granite_hybrid"))
+    @pytest.mark.parametrize("which,splits", (
+        ("serve_decode", 0), ("serve_verify_b4", 0),
+        ("serve_prefill_b16", 0), ("serve_prefill_b128", 2)))
+    def test_only_many_rows_split_the_view(self, engines, family, which,
+                                           splits):
+        """Decode (4 query rows a slot), a verify run (20) and a
+        16-wide prefill (64) contract the bf16 view as gathered; the
+        128-wide bucket's 512 rows pass the module's bound and take the
+        split, k and v; and no form widens a view."""
+        from quintnet_tpu.nn.attention import _MAX_DIAG_ROWS
+
+        assert 4 * 16 <= _MAX_DIAG_ROWS < 4 * 128
+        eng, _p = engines(family)
+        fn, args = self._program(eng, which)
+        kw = dict(table_width=eng.table_width,
+                  block_size=eng.pool.block_size)
+        assert view_head_splits(fn, *args, **kw) == splits
+        assert widened_view_dots(fn, *args, **kw) == 0
+
+    @pytest.mark.parametrize("kv_dtype", ("f32", "int8", "fp8"))
+    def test_other_pools_keep_the_split(self, engines, kv_dtype):
+        """Only a view stored in a float narrower than q goes on the
+        diagonal: an f32 pool, a scaled policy's dequantized view and a
+        float8 pool's widened one take the branch they always took."""
+        eng, _p = engines("gpt2", kv_dtype)
+        fn, args = self._program(eng, "serve_decode")
+        assert view_head_splits(fn, *args, table_width=eng.table_width,
+                                block_size=eng.pool.block_size) == 2
+
+    def test_counter_sees_the_old_form(self):
+        """The zeroes mean something: the gathered rows cut into heads
+        count one a view, with the pad lanes sliced off first or with
+        none to slice; the rows contracted as gathered count none."""
+        pool = jnp.zeros((LAYERS, NB * BS, H * D + PAD), jnp.bfloat16)
+        bare = jnp.zeros((LAYERS, NB * BS, H * D), jnp.bfloat16)
+        q = jnp.zeros((S, H, H * D + PAD), jnp.bfloat16)
+        tables = _tables()
+
+        def as_gathered(pool, q):
+            rows = pool.reshape(LAYERS, NB, BS, -1)[LAYER, tables]
+            return jnp.einsum("brf,btf->brt", q,
+                              rows.reshape(S, M * BS, -1))
+
+        kw = dict(table_width=M, block_size=BS)
+        assert view_head_splits(_old_form, pool, tables, **kw) == 1
+        assert view_head_splits(_old_form, bare, tables, **kw) == 1
+        assert view_head_splits(
+            lambda k, v: (_old_form(k, tables), _old_form(v, tables)),
+            pool, pool, **kw) == 2
+        assert view_head_splits(as_gathered, pool, q, **kw) == 0
+
+    def test_every_writer_leaves_the_pad_lanes_zero(self, params):
+        """The diagonal form multiplies the pool's pad lanes by exact
+        zeros, so they must hold finite numbers; every writer holds
+        them at ZERO: the allocation, the programs' scatter
+        (``_pool_rows``: prefill, decode, the copy-on-write copy of
+        whole rows) and the host's ``write_slots`` (import, the tier)."""
+        eng = _engine(params, "xla", kv_dtype="bf16")
+        pool = eng.pool
+        real = pool.n_kv_heads * pool.head_dim
+        assert pool.k.shape[-1] > real          # 32 features in 128 lanes
+
+        def pad_lanes_zero():
+            return not any(np.asarray(a[..., real:], np.float32).any()
+                           for a in (pool.k, pool.v))
+
+        assert pad_lanes_zero()
+        rng = np.random.default_rng(29)
+        shared = rng.integers(0, CFG.vocab_size, (10,)).astype(np.int32)
+        prompts = [np.concatenate([shared, rng.integers(
+            0, CFG.vocab_size, (t,)).astype(np.int32)]) for t in (3, 5, 2)]
+        _serve(eng, prompts, 6, arrivals=[0, 20, 40])
+        assert eng.metrics.summary()["prefix_hit_tokens"] > 0  # the copy ran
+        assert np.asarray(pool.k[..., :real], np.float32).any()
+        assert pad_lanes_zero()
+        idx = np.arange(8, 24)
+        rec = rng.standard_normal((pool.n_layers, len(idx),
+                                   pool.n_kv_heads, pool.head_dim))
+        pool.update(*pool.write_slots(idx, rec, -rec))
+        k_back, _v = pool.read_slots(idx)
+        np.testing.assert_array_equal(
+            np.asarray(k_back, np.float32),
+            np.asarray(jnp.asarray(rec, jnp.bfloat16), np.float32))
+        assert pad_lanes_zero()
+
+    @staticmethod
+    def _f64_attention(q, k, v, live, scale, u):
+        """Plain f64 attention of q [S, Hq, P, D] over stored k, v
+        [S, Hq, T, D] under ``live`` [S, P, T], and the first-order
+        bound on what rounding q and the probabilities to a dtype of
+        unit roundoff ``u`` may move it by (the derivation in
+        test_decode_inside_the_bound_rounding_earns, before the output
+        projection)."""
+        live = live[:, None]
+        sc = np.einsum("shpd,shtd->shpt", q, k) * scale
+        sc = np.where(live, sc, -np.inf)
+        pr = np.exp(sc - sc.max(-1, keepdims=True))
+        pr /= pr.sum(-1, keepdims=True)
+        want = np.einsum("shpt,shtd->shpd", pr, v)
+        e = np.where(live, u * np.einsum("shpd,shtd->shpt", np.abs(q),
+                                         np.abs(k)) * scale, 0.0)
+        dp = pr * (e + (pr * e).sum(-1, keepdims=True) + u)
+        return want, 1.1 * np.einsum("shpt,shtd->shpd", dp, np.abs(v))
+
+    @pytest.mark.parametrize("pool_dtype", ("bfloat16", "float16"))
+    @pytest.mark.parametrize("P", (1, 3, 5))
+    @pytest.mark.parametrize("heads", ("mha", "gqa", "grouped"))
+    def test_diagonal_equals_split_inside_the_rounding_bound(
+            self, heads, P, pool_dtype, monkeypatch):
+        """The two forms on the SAME narrow pool — every layer and
+        every pad lane full of noise — for MHA (3 heads), llama's GQA
+        (4 query heads on 2 kv heads, repeated) and the hybrid's
+        grouped rows (2 kv heads, the 2 query heads of each as rows of
+        one score matrix, a stated score scale): both inside the bound
+        that rounding q and the probabilities earns against f64 math on
+        the stored K and V, and each other's to the order of f32 sums;
+        the pool bytes equal to bits."""
+        import quintnet_tpu.nn.attention as attention
+
+        hq, hkv, g, scale = {"mha": (3, 3, 1, None), "gqa": (4, 2, 1, None),
+                             "grouped": (2, 2, 2, 0.25)}[heads]
+        dt = jnp.dtype(pool_dtype)
+        rng = np.random.default_rng(23)
+        shape = (LAYERS, NB * BS, hkv * D + PAD)
+        kp = jnp.asarray(rng.standard_normal(shape), dt)
+        vp = jnp.asarray(rng.standard_normal(shape), dt)
+        q = jnp.asarray(rng.standard_normal((S, hq, g * P, D)), jnp.float32)
+        k, v = (jnp.asarray(rng.standard_normal((S, hkv, P, D)),
+                            jnp.float32) for _ in range(2))
+        tables = _tables()
+        positions = (jnp.asarray([5, 0, 17])[:, None]
+                     + jnp.arange(P, dtype=jnp.int32)[None, :])
+        lens = jnp.asarray([P, max(P - 1, 1), P], jnp.int32)
+
+        def run(most_rows):
+            monkeypatch.setattr(attention, "_MAX_DIAG_ROWS", most_rows)
+            fn = lambda q, k, v, kp, vp: attention.paged_attend(  # noqa: E731
+                q, k, v, (kp, vp), jnp.int32(LAYER), positions, lens,
+                tables, block_size=BS, scale=scale)
+            assert view_head_splits(
+                fn, q, k, v, kp, vp, table_width=M,
+                block_size=BS) == (2 if most_rows == 0 else 0)
+            return jax.jit(fn)(q, k, v, kp, vp)
+
+        diag, pools = run(attention._MAX_DIAG_ROWS)
+        split, pools_split = run(0)
+        for a, b in zip(pools, pools_split):
+            np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                          np.asarray(b, np.float32))
+        assert diag.dtype == split.dtype == jnp.float32
+        assert diag.shape == split.shape == q.shape
+
+        rows = (np.asarray(tables)[:, :, None] * BS
+                + np.arange(BS)[None, None, :]).reshape(S, M * BS)
+        ks, vs = (np.asarray(p[LAYER, :, :hkv * D].astype(jnp.float32),
+                             np.float64).reshape(NB * BS, hkv, D)[rows]
+                  .transpose(0, 2, 1, 3) for p in pools)   # [S, Hkv, T, D]
+        rep = hq // hkv
+        ks, vs = np.repeat(ks, rep, axis=1), np.repeat(vs, rep, axis=1)
+        live = np.tile(np.arange(M * BS)[None, None, :]
+                       <= np.asarray(positions)[:, :, None], (1, g, 1))
+        u = 2.0 ** -(jnp.finfo(dt).nmant + 1)
+        want, bound = self._f64_attention(
+            np.asarray(q, np.float64), ks, vs, live,
+            1 / math.sqrt(D) if scale is None else scale, u)
+        for got in (diag, split):
+            gap = np.abs(np.asarray(got, np.float64) - want)
+            assert (gap <= bound).all(), (gap.max(), bound.max())
+            assert gap.max() > 1e-6 * np.abs(want).max(), gap.max()
+        assert bound.max() < 0.1 * np.abs(want).max(), bound.max()
+        np.testing.assert_allclose(np.asarray(diag), np.asarray(split),
+                                   atol=2e-6, rtol=0)
 
 
 def test_ops_import_surface():

@@ -8,8 +8,10 @@ read, per program,
   ``{2,1,0}`` means a token's features are contiguous; anything else
   that the slot dim is minor, and every read re-lays it;
 - every ``copy`` / ``transpose`` at least as large as ONE LAYER's slice
-  of the pool, with the computation it sits in (a loop body's run once
-  a layer);
+  of the pool — a gathered view is larger — with the computation it
+  sits in (a loop body's run once a layer); a ``transpose`` that
+  permutes nothing (``dimensions={0,1,..}``, as the gather fusions
+  carry) moves no byte and is not listed;
 - the sum of the compiler's own ``estimated_cycles`` over the layer
   loop's body (a guide, not a time; gathers carry no estimate);
 - the bytes the compiler plans (arguments, temporaries, aliased, live).
@@ -21,9 +23,14 @@ read, per program,
 Nothing runs and no chip is needed (benchmarks/tools/aot_sizes.py is
 the same kind of compile, for sizing); the weights are made at the
 cell's real size on the CPU, so a run takes minutes. One JSON line a
-program. The bar every paged program is held to (PERF.md, PR 28): the
-pools' layout row-major, ``big_copies`` empty apart from the gathered
-view's own (``[slots, table, block, heads, head_dim]``, ROADMAP S1).
+program. The bar every paged program is held to (PERF.md, PR 28 and
+30): the pools' layout row-major, and ``big_copies`` holding nothing
+pool- or view-shaped. A decode or verify program never splits the
+gathered view (nn/attention._lane_diag_sdpa); a prefill bucket does,
+inside its fusions, and the compiler has written no copy for it. What
+every program still lists is the f32 token table re-laid for the
+logits (``f32[vocab, width]``, outside the layer loop: PERF.md
+section 7).
 """
 
 from __future__ import annotations
@@ -47,6 +54,12 @@ _SHAPE = re.compile(r"(\w+)\[([\d,]*)\](\{[^}]*\})?")
 _INSTR = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(.+?)\s+"
                     r"([\w\-]+)\(")
 _CYCLES = re.compile(r'"estimated_cycles":"?(\d+)')
+
+
+def _permutes_nothing(line: str) -> bool:
+    m = re.search(r"\bdimensions=\{([\d,]*)\}", line)
+    return bool(m) and m.group(1) == ",".join(
+        str(i) for i in range(m.group(1).count(",") + 1))
 
 
 def _first_shape(text):
@@ -90,7 +103,7 @@ def read_hlo(text: str, buffers: dict, layer_bytes: int) -> dict:
             layouts.setdefault(want[dims], []).append(
                 layout.split(":")[0] + "}" if ":" in layout else layout)
         if (op in ("copy", "copy-start", "transpose")
-                and nbytes >= layer_bytes):
+                and nbytes >= layer_bytes and not _permutes_nothing(line)):
             copies.append({"op": name, "in": comp, "dims": list(dims),
                            "shape": f"{dtype}{list(dims)}{layout}",
                            "MB": round(nbytes / 1e6, 1)})
