@@ -283,6 +283,30 @@ def expected_serve_moe(n_layers: int, *,
     return c
 
 
+def expected_serve_latent_moe() -> Dict[str, object]:
+    """What every compiled serving program (prefill bucket, decode,
+    verify bucket; all named ``jit_serve_*`` like the others') of a
+    LATENT family with the dropless router
+    (serve/families.pangu_moe_family) reads under the structural audits
+    of analysis/jaxpr_audit.py, on one device — the only place it runs:
+    the engine refuses it a mesh.
+
+    - ``census``: no collective at all. The expert layer is told which
+      experts it holds and computes their part; nothing stands in for
+      the exchange with the chips that hold the others.
+    - ``pool_scan_operands`` 0: the one latent pool rides both layer
+      scans' carry (the dense stack's, then the MoE stack's).
+    - ``view_head_splits`` 0 in EVERY program, not in decode alone:
+      all heads read the one row, so neither form of the attention
+      cuts the gathered view into heads (nn/attention.py).
+    - ``widened_view_dots`` 0: both forms round the small operand to
+      a bf16 pool's dtype.
+    - ``gathered_view_gathers`` 2: one row kind, gathered once in the
+      body of each of the two layer scans."""
+    return {"census": {}, "pool_scan_operands": 0, "view_head_splits": 0,
+            "widened_view_dots": 0, "gathered_view_gathers": 2}
+
+
 def expected_serve_sp_prefill(n_layers: int, sp: int, *,
                               sp_axis: str = "sp") -> CensusDict:
     """One compiled SEQUENCE-PARALLEL prefill bucket (long-context

@@ -353,9 +353,10 @@ def kv_chain_to_wire(chain: Dict, *,
     for b in chain["blocks"]:
         rec, raws = {"fill": int(b["fill"])}, {}
         for key in ("k", "v", "k_scale", "v_scale"):
-            # k/v are mandatory in an exported chain; scales only
-            # exist under scaled layout policies
-            a = b[key] if key in ("k", "v") else b.get(key)
+            # k is mandatory in an exported chain; v is absent from a
+            # latent pool's records, scales only exist under scaled
+            # layout policies
+            a = b[key] if key == "k" else b.get(key)
             rec[key], raws[key] = enc(a)
         blocks.append(rec)
         raw_blocks.append(raws)

@@ -415,6 +415,19 @@ def _pool_heads(rows, heads: int, head_dim: int):
                                                 head_dim)
 
 
+def _write_index(positions, lens, block_tables, block_size: int):
+    """Flat pool slots [S * P] of the rows' runs at ``positions``
+    [S, P] through their ``block_tables`` [S, M]; columns at or beyond
+    a row's ``lens`` go to slot 0, the null block's first."""
+    S, P = positions.shape
+    M = block_tables.shape[1]
+    blk_idx = jnp.clip(positions // block_size, 0, M - 1)    # [S, P]
+    blk = jnp.take_along_axis(block_tables, blk_idx, axis=1)
+    return jnp.where(jnp.arange(P)[None, :] < lens[:, None],
+                     blk * block_size + positions % block_size,
+                     0).reshape(S * P)
+
+
 def paged_write(k_pool, v_pool, layer, k, v, positions, lens, *,
                 block_tables, block_size: int):
     """Write every row's run of (k, v) into the PAGED pool at ``layer``,
@@ -430,13 +443,8 @@ def paged_write(k_pool, v_pool, layer, k, v, positions, lens, *,
     outputs). Duplicate slot-0 scatters are benign for the same
     reason."""
     S, H, P, Dh = k.shape
-    M = block_tables.shape[1]
     with jax.named_scope("kv_write"):
-        blk_idx = jnp.clip(positions // block_size, 0, M - 1)    # [S, P]
-        blk = jnp.take_along_axis(block_tables, blk_idx, axis=1)
-        idx = jnp.where(jnp.arange(P)[None, :] < lens[:, None],
-                        blk * block_size + positions % block_size,
-                        0).reshape(S * P)
+        idx = _write_index(positions, lens, block_tables, block_size)
 
         def put(pool, x):
             rows = _pool_rows(x.transpose(0, 2, 1, 3).reshape(S * P, H, Dh),
@@ -777,6 +785,141 @@ def paged_attend(q, k, v, pools, layer, positions, lens, block_tables, *,
     o = _masked_sdpa(q, repeat_kv(k_all, rep), repeat_kv(v_all, rep),
                      valid, page=block_size, scale=scale)
     return o, pools
+
+
+# ---------------------------------------------------------------------
+# The LATENT paged cache (multi-head latent attention): ONE row a token,
+# ``[c | k_rope]`` — the normed compressed kv (``rank`` features) and
+# the rotated shared rotary key (``rope`` features) — padded with zero
+# lanes to the pool's width like every pool row; no V pool. All heads
+# read the same row, so there is no head to split the gathered view by:
+# both forms below contract it as :func:`paged_gather` leaves it.
+# ---------------------------------------------------------------------
+def latent_write(pool, layer, rows, positions, lens, *, block_tables,
+                 block_size: int):
+    """:func:`paged_write` for the latent pool: ``rows`` [S, P, rank +
+    rope] at ``positions`` [S, P] into ``layer`` of ``pool`` [L, slots,
+    F], the pad lanes written as zeros; columns at or beyond a row's
+    ``lens`` go to the null block. One scatter."""
+    S, P, _ = rows.shape
+    with jax.named_scope("kv_write"):
+        idx = _write_index(positions, lens, block_tables, block_size)
+        flat = _pool_rows(rows.reshape(S * P, 1, -1), pool.shape[-1])
+        return pool.at[layer, idx].set(flat.astype(pool.dtype))
+
+
+def latent_attend_absorbed(q_lat, q_rope, view, positions, *, scale: float):
+    """The ABSORBED form (decode, verify): the queries were carried
+    into the latent space (``q_lat`` [S, P, H, rank] = ``q_nope W_uk``
+    per head, ``q_rope`` [S, P, H, rope] rotated), so scores and
+    values contract the gathered rows ``view`` [S, M, bs, F] AS STORED
+    — all ``R = P * H`` query rows of a sequence against its ``T = M *
+    bs`` rows in one matmul each, the zero pad lanes of the query
+    meeting the row's. Returns ``o_lat`` [S, P, H, rank] (``W_uv``
+    comes after). The arithmetic is :func:`_masked_sdpa`'s stored
+    branch: the query and the probabilities rounded to a bf16/f16
+    view's dtype, f32 sums, f32 softmax; the values' product also
+    covers the rotary and pad lanes of the rows (a quarter more FLOPs
+    on rows the matrix unit has idle) and drops them from the small
+    result, where cutting them off the VIEW would copy it.
+
+    The scores are laid out ``[S, T, R]``, positions on the SUBLANES
+    and the query rows on the lanes (a decode step's 128 heads fill
+    them exactly): the softmax then reduces over whole registers. Laid
+    out ``[S, R, T]`` the same reduction ran across lanes and took 11.1
+    ms a layer at the published decode shapes where this takes 2.4 (my
+    chip run, PR 31). Scopes ``scores`` / ``values``."""
+    s, p, h, rank = q_lat.shape
+    _, m, bs, f = view.shape
+    t, r = m * bs, p * h
+    rows = view.reshape(s, t, f)
+    with jax.named_scope("scores"):
+        q = jnp.concatenate([q_lat, q_rope], axis=-1).reshape(s, r, -1)
+        qs = _as_stored(q, rows)
+        pad = max(_MIN_DOT_ROWS - r, 0) if qs is not q else 0
+        qs = jnp.pad(qs, ((0, 0), (0, pad), (0, f - qs.shape[-1])))
+        # column c of a row's view is valid for the query at
+        # positions[s, p] iff c <= positions[s, p] (paged_attend's
+        # mask); query rows are laid out p * H + h
+        ok = jnp.arange(t)[None, :, None] <= positions[:, None, :]
+        valid = jnp.pad(
+            jnp.broadcast_to(ok[..., None], (s, t, p, h)).reshape(s, t, r),
+            ((0, 0), (0, 0), (0, pad)))
+        scores = jnp.einsum("stf,srf->str", rows, qs,
+                            preferred_element_type=jnp.float32) * scale
+        scores = jnp.where(valid, scores, jnp.finfo(jnp.float32).min)
+        probs = _as_stored(
+            jax.nn.softmax(scores, axis=1).astype(q.dtype), rows)
+    with jax.named_scope("values"):
+        o = jnp.einsum("str,stf->srf", probs, rows,
+                       preferred_element_type=jnp.float32)
+        return o[:, :r, :rank].reshape(s, p, h, rank).astype(
+            jnp.result_type(q_lat, rows))
+
+
+def latent_attend_materialized(q_nope, q_rope, view, kv_up, positions, *,
+                               scale: float, v_dim: int,
+                               head_group: int):
+    """The MATERIALIZED form (prefill, chunked prefill): keys and
+    values are rebuilt from the gathered latent rows — an earlier
+    chunk's rows out of the pool among them — ``[k_nope | v] = c
+    W_ukv``, and the scores are ``q_nope . k_nope + q_rope . k_rope``
+    per head: ``rank + rope`` against ``nope + rope`` features a score,
+    a quarter of the absorbed form's products once the run has more
+    than a hundred-odd tokens to spread the rebuild over. ``q_nope``
+    [S, P, H, nope], ``q_rope`` [S, P, H, rope], ``view`` [S, M, bs,
+    F], ``kv_up`` [rank, H, nope + v_dim]. The heads go ``head_group``
+    at a time (a loop): a bucket's f32 scores for all 128 heads of the
+    published model at once would be gigabytes. Returns o [S, P, H,
+    v_dim]. Same rounding contract as the absorbed form, plus the
+    rebuilt keys and values held in the view's dtype. Scopes ``kv_up``
+    / ``scores`` / ``values``."""
+    s, p, h, nope = q_nope.shape
+    _, m, bs, f = view.shape
+    rank = kv_up.shape[0]
+    rope = q_rope.shape[-1]
+    t = m * bs
+    rows = view.reshape(s, t, f)
+    c, k_rope = rows[..., :rank], rows[..., rank:rank + rope]
+    # scores [S, g, T, P]: positions on the sublanes, the run's tokens
+    # on the lanes, so the softmax reduces over whole registers (one
+    # head group of a 1,024-token bucket: 1.3 ms where [S, g, P, T]
+    # took 10.1; my chip run, PR 31)
+    valid = (jnp.arange(t)[None, :, None]
+             <= positions[:, None, :])[:, None]          # [S, 1, T, P]
+    g = min(head_group, h)
+    if h % g:
+        raise ValueError(f"head_group {g} must divide the {h} heads")
+    out_dtype = jnp.result_type(q_nope, rows)
+
+    def group(_, i):
+        lo = i * g
+        w = lax.dynamic_slice_in_dim(kv_up, lo, g, axis=1)
+        qn = lax.dynamic_slice_in_dim(q_nope, lo, g, axis=2)
+        qr = lax.dynamic_slice_in_dim(q_rope, lo, g, axis=2)
+        with jax.named_scope("kv_up"):
+            kv = jnp.einsum("stc,chn->sthn", c, _as_stored(w, c),
+                            preferred_element_type=jnp.float32
+                            ).astype(rows.dtype)
+            k_nope, v = kv[..., :nope], kv[..., nope:]
+        with jax.named_scope("scores"):
+            scores = (jnp.einsum("sthn,sphn->shtp", k_nope,
+                                 _as_stored(qn, rows),
+                                 preferred_element_type=jnp.float32)
+                      + jnp.einsum("str,sphr->shtp", k_rope,
+                                   _as_stored(qr, rows),
+                                   preferred_element_type=jnp.float32)
+                      ) * scale
+            scores = jnp.where(valid, scores, jnp.finfo(jnp.float32).min)
+            probs = _as_stored(
+                jax.nn.softmax(scores, axis=2).astype(q_nope.dtype), rows)
+        with jax.named_scope("values"):
+            o = jnp.einsum("shtp,sthv->sphv", probs, v,
+                           preferred_element_type=jnp.float32)
+        return None, o.astype(out_dtype)
+
+    _, o = lax.scan(group, None, jnp.arange(h // g))   # [G, S, P, g, v]
+    return o.transpose(1, 2, 0, 3, 4).reshape(s, p, h, v_dim)
 
 
 def _online_merge(m, l, acc, m_new, l_new, o_new):

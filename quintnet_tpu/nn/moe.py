@@ -27,12 +27,29 @@ by ep instead of pmeaning them.
 Load-balance auxiliary loss follows the Switch-Transformer form
 (E * sum_e f_e * P_e over the k assignments) computed on the device-local
 token batch, plus an optional router z-loss.
+
+TWO ROUTERS, ONE LAYER. The path above (softmax gate, a per-expert
+capacity, drops) serves the tiny MoE families and training. ``MoEArgs(
+dropless=True)`` is the other (:func:`_moe_dropless`; inference only,
+no ``ep``/``tp`` axis yet): ``scoring`` softmax or sigmoid over the FULL
+router, the ``top_k`` largest, gates normalised over the chosen and
+scaled by ``routed_scale``, and NO capacity — the assignments are
+sorted by expert and the experts run as one grouped matmul
+(``lax.ragged_dot``) over rows that are exactly the routings, so
+nothing is dropped at any skew. ``experts_held = (first, count)`` tells
+the layer which experts of the router's range it holds (expert
+parallelism's share of one chip): it routes over all of them and
+computes the part of the result its own give; a routing to an absent
+expert adds nothing here. A ``shared`` SwiGLU in the params is added
+once, for every token. There the expert weights are linear NODES
+(``experts.{gate,up,down}.w`` [held, in, out]) that a weight layout
+policy packs like any other matmul (serve/weight_quant.py).
 """
 
 from __future__ import annotations
 
 import math
-from typing import NamedTuple, Optional
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -40,7 +57,7 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from quintnet_tpu.core import collectives as cc
-from quintnet_tpu.nn.layers import gelu
+from quintnet_tpu.nn.layers import gelu, linear_init, swiglu_apply, swiglu_init
 
 
 class MoEArgs(NamedTuple):
@@ -64,6 +81,16 @@ class MoEArgs(NamedTuple):
     # WRONG for autoregressive LMs (the causal model configs reject it;
     # GPT2Config/LlamaConfig.moe_args).
     router: str = "topk"
+    # the dropless router (module docstring): no capacity, a grouped
+    # matmul over the experts held. ``scoring``: "softmax" | "sigmoid"
+    # (sigmoid needs dropless: the capacity path's auxiliary loss is
+    # the softmax's). ``routed_scale`` multiplies the normalised gates.
+    # ``experts_held``: (first, count) of the router's experts whose
+    # weights this layer has; None = all of them.
+    dropless: bool = False
+    scoring: str = "softmax"
+    routed_scale: float = 1.0
+    experts_held: Optional[Tuple[int, int]] = None
 
 
 def moe_init(key, dim: int, hidden: int, n_experts: int, *,
@@ -99,6 +126,30 @@ def moe_init(key, dim: int, hidden: int, n_experts: int, *,
         "w2": u(kw2, (n_experts, hidden, dim), s2),
         "b2": u(kb2, (n_experts, dim), s2),
     }
+
+
+def moe_held_init(key, dim: int, hidden: int, n_experts: int, *,
+                  held: int, shared_hidden: int = 0, dtype=jnp.float32):
+    """Params of the dropless layer (module docstring): a router over
+    all ``n_experts``, SwiGLU weights of the ``held`` experts this
+    layer has as linear nodes ``experts.{gate,up,down}.w`` [held, in,
+    out] (fan-in uniform like :func:`moe_init`), and a ``shared``
+    SwiGLU of width ``shared_hidden`` where that is not 0."""
+    kr, kg, ku, kd, ks = jax.random.split(key, 5)
+
+    def stack(k, fin, fout):
+        return {"w": jnp.stack([
+            linear_init(kk, fin, fout, use_bias=False, dtype=dtype)["w"]
+            for kk in jax.random.split(k, held)])}
+
+    p = {"router": {"w": linear_init(kr, dim, n_experts, use_bias=False,
+                                     dtype=jnp.float32)["w"]},
+         "experts": {"gate": stack(kg, dim, hidden),
+                     "up": stack(ku, dim, hidden),
+                     "down": stack(kd, hidden, dim)}}
+    if shared_hidden:
+        p["shared"] = swiglu_init(ks, dim, shared_hidden, dtype=dtype)
+    return p
 
 
 def moe_specs(*, ep_axis: Optional[str] = "ep",
@@ -138,7 +189,8 @@ def _capacity(s_local: int, args: MoEArgs) -> int:
 
 def moe_apply(p, x, args: MoEArgs, *, ep_axis: Optional[str] = None,
               tp_axis: Optional[str] = None, act=gelu,
-              return_stats: bool = False):
+              return_stats: bool = False, token_mask=None,
+              expert_layer=None):
     """x: [B, T_local, D] -> (y, aux_loss[, stats]).
 
     All shapes static: S = B*T local tokens, E experts, per-rank
@@ -153,6 +205,13 @@ def moe_apply(p, x, args: MoEArgs, *, ep_axis: Optional[str] = None,
     past capacity (masked into the dump row); ``assigned`` — total
     assignments S*k; ``entropy`` — mean per-token router entropy in
     nats. The serving engine ships these to ServeMetrics per step.
+
+    ``token_mask`` [B, T] bool (dropless router only): tokens that are
+    padding — a bucket's pad columns, an empty slot's row. They are
+    routed nowhere: no expert row, no count in the stats.
+    ``expert_layer`` (dropless router only): the expert weights in ``p``
+    are a whole STACK's, ``[layers, held, in, out]``, and this index
+    picks the layer (:func:`_moe_dropless` says why).
     """
     B, T, D = x.shape
     S = B * T
@@ -161,6 +220,20 @@ def moe_apply(p, x, args: MoEArgs, *, ep_axis: Optional[str] = None,
     if not 1 <= k <= E:
         raise ValueError(
             f"top_k={k} must be in [1, n_experts={E}]")
+    if args.dropless:
+        if ep_axis is not None or tp_axis is not None:
+            raise NotImplementedError(
+                "the dropless router has no exchange over an ep axis "
+                "and no tp sharding yet (ROADMAP M1)")
+        return _moe_dropless(p, x, args, return_stats=return_stats,
+                             token_mask=token_mask,
+                             expert_layer=expert_layer)
+    if (args.scoring != "softmax" or args.experts_held is not None
+            or token_mask is not None or expert_layer is not None):
+        raise NotImplementedError(
+            "sigmoid scoring, experts_held, token_mask and "
+            "expert_layer belong to the dropless router: pass "
+            "MoEArgs(dropless=True)")
     ep = 1 if ep_axis is None else lax.axis_size(ep_axis)
     if E % ep != 0:
         raise ValueError(f"n_experts={E} must divide by ep={ep}")
@@ -225,6 +298,118 @@ def moe_apply(p, x, args: MoEArgs, *, ep_axis: Optional[str] = None,
     if return_stats:
         return y_out, aux, _routing_stats(oh, keep, probs, S * k)
     return y_out, aux
+
+
+def _moe_dropless(p, x, args: MoEArgs, *, return_stats: bool,
+                  token_mask=None, expert_layer=None):
+    """The dropless router of :func:`moe_apply` (module docstring): x
+    [B, T, D] -> (y, 0[, stats]). ``S * k`` assignments, sorted so that
+    the held experts' rows come first in expert order; ``ragged_dot``
+    runs each expert over exactly its rows (the rows routed elsewhere
+    trail behind every group and are skipped), and the gate-weighted
+    results are added back to their tokens. The stats keep the capacity
+    path's names — ``expert_tokens`` over ALL the router's experts,
+    ``dropped`` 0 by construction — and add ``held_rows`` (routings
+    that landed on held experts), ``touched`` (held experts with at
+    least one row) and ``elsewhere`` (routings to absent experts).
+    Scopes ``router`` / ``sort`` / ``experts`` / ``shared`` /
+    ``combine``.
+
+    With ``expert_layer`` the expert weights are a whole stack's,
+    ``[layers, held, in, out]``: the grouped matmul takes the stack as
+    ``layers * held`` groups of which only this layer's have rows. The
+    grouped matmul is one call on a whole operand, so a layer's slice
+    of the stack (what a layer scan hands its body) would be COPIED out
+    first — at the published widths 1.5 GB a layer a step, 21 of a
+    106-ms decode step (my chip run, PR 31); empty groups cost
+    nothing."""
+    B, T, D = x.shape
+    S, E, k = B * T, args.n_experts, args.top_k
+    first, held = (0, E) if args.experts_held is None else args.experts_held
+    w_gate, w_up, w_down = (p["experts"][n]["w"]
+                            for n in ("gate", "up", "down"))
+    if w_gate.shape[-3] != held or not 0 <= first <= E - held:
+        raise ValueError(
+            f"experts_held={args.experts_held} of {E} experts does not "
+            f"match the {w_gate.shape[-3]} experts in the params")
+    if (expert_layer is None) != (w_gate.ndim == 3):
+        raise ValueError(
+            "expert weights [held, in, out] take no expert_layer; a "
+            "stack's [layers, held, in, out] needs one")
+    xt = x.reshape(S, D)
+
+    with jax.named_scope("router"):
+        logits = jnp.dot(xt.astype(jnp.float32), p["router"]["w"])
+        if args.scoring == "sigmoid":
+            scores = jax.nn.sigmoid(logits)
+        elif args.scoring == "softmax":
+            scores = jax.nn.softmax(logits, axis=-1)
+        else:
+            raise ValueError(f"unknown scoring {args.scoring!r}")
+        gate_v, gate_i = lax.top_k(scores, k)                  # [S, k]
+        if args.normalize_gates:
+            gate_v = gate_v / jnp.sum(gate_v, axis=-1, keepdims=True)
+        gate_v = gate_v * args.routed_scale
+
+    with jax.named_scope("sort"):
+        local = gate_i.reshape(-1) - first                     # [S * k]
+        live = (jnp.ones((S * k,), bool) if token_mask is None
+                else jnp.repeat(token_mask.reshape(S), k))
+        here = (local >= 0) & (local < held) & live
+        # absent experts sort behind every held one, into no group
+        order = jnp.argsort(jnp.where(here, local, held), stable=True)
+        tok = order // k
+        # live routings by expert, over the whole router; the held
+        # experts' are the groups' sizes
+        counts = jnp.sum(jax.nn.one_hot(
+            jnp.where(live, gate_i.reshape(-1), E), E + 1,
+            dtype=jnp.int32), axis=0)[:E]
+        sizes = counts[first:first + held]
+        gates = jnp.where(here, gate_v.reshape(-1), 0.0)[order]
+        in_group = here[order]      # rows that belong to a group
+
+    with jax.named_scope("experts"):
+        # the small operand goes down to the weights' dtype, as the
+        # matrix unit would round it anyway; the sums stay f32
+        xs = xt.astype(w_gate.dtype)[tok] if (
+            w_gate.dtype.itemsize < xt.dtype.itemsize) else xt[tok]
+
+        if expert_layer is None:
+            groups = sizes
+        else:
+            groups = lax.dynamic_update_slice(
+                jnp.zeros((w_gate.shape[0] * held,), sizes.dtype), sizes,
+                (expert_layer * held,))
+
+        def grouped(a, w):
+            return lax.ragged_dot(a, w.reshape(-1, *w.shape[-2:]), groups,
+                                  preferred_element_type=jnp.float32)
+
+        hid = jax.nn.silu(grouped(xs, w_gate)) * grouped(xs, w_up)
+        ys = grouped(hid.astype(xs.dtype), w_down)             # [S*k, D]
+
+    with jax.named_scope("combine"):
+        # rows past every group are whatever the grouped matmul left
+        # there (not zeros, on the chip): selected out, not scaled out
+        yt = jnp.zeros((S, D), jnp.float32).at[tok].add(
+            jnp.where(in_group[:, None], ys * gates[:, None], 0.0))
+    if "shared" in p:
+        with jax.named_scope("shared"):
+            yt = yt + swiglu_apply(p["shared"], xt)
+    y_out = yt.astype(x.dtype).reshape(B, T, D)
+    aux = jnp.zeros((), jnp.float32)
+    if not return_stats:
+        return y_out, aux
+    pr = scores / jnp.sum(scores, axis=-1, keepdims=True)
+    return y_out, aux, {
+        "expert_tokens": counts.astype(jnp.float32),
+        "dropped": jnp.zeros((), jnp.float32),
+        "assigned": jnp.sum(counts).astype(jnp.float32),
+        "entropy": -jnp.mean(jnp.sum(pr * jnp.log(pr + 1e-9), axis=-1)),
+        "held_rows": jnp.sum(sizes).astype(jnp.float32),
+        "touched": jnp.sum(sizes > 0).astype(jnp.float32),
+        "elsewhere": (jnp.sum(counts) - jnp.sum(sizes)).astype(jnp.float32),
+    }
 
 
 def _routing_stats(oh, keep, probs, assigned: int):

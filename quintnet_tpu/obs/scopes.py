@@ -25,7 +25,10 @@ from typing import Dict, Iterable
 # The scope vocabulary. Model programs: embed, blocks (the layer scan),
 # per block attn (qkv, sdpa or paged_attn, kv_gather, kv_write, proj)
 # and mlp, then final_norm, lm_head, loss, sample; a Mamba-2 block:
-# mamba (in_proj, conv, ssd, state_update, gate_norm, out_proj). The
+# mamba (in_proj, conv, ssd, state_update, gate_norm, out_proj); a
+# latent-attention block: mla (q_down, q_up, kv_down, kv_write,
+# kv_gather, absorb, scores, values, kv_up, proj); a dropless
+# mixture: moe (router, sort, experts, shared, combine). The
 # train step:
 # grads, grad_reduce, grad_clip, optimizer. ``rematted_computation`` is
 # jax.checkpoint's own mark on what the backward pass recomputes.
@@ -34,6 +37,9 @@ SCOPES = frozenset({
     "kv_write", "proj", "mlp", "final_norm", "lm_head", "loss", "sample",
     "mamba", "in_proj", "conv", "ssd", "state_update", "gate_norm",
     "out_proj",
+    "mla", "q_down", "q_up", "kv_down", "absorb", "scores", "values",
+    "kv_up",
+    "moe", "router", "sort", "experts", "shared", "combine",
     "grads", "grad_reduce", "grad_clip", "optimizer",
     "rematted_computation",
 })

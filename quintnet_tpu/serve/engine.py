@@ -101,6 +101,14 @@ position 0 through the chunk program: correct to the arithmetic, not
 bit-equal to the run that was interrupted (docs/serving.md, "Recurrent
 families").
 
+Latent families (``family.latent`` set — multi-head latent attention,
+serve/families.pangu_moe_family): the pool holds ONE row a token and
+has no ``v`` buffer, so every program takes and returns one pool
+buffer. Blocks are blocks: the prefix cache, chunked prefill,
+preemption, speculation and chain export/import work as for any other
+family. A mesh, adapters, scaled or float8 KV, scaled weights and the
+Pallas kernel are refused at construction (:func:`_refuse_for_latent`).
+
 All host<->device traffic per step is O(max_slots) scalars plus the
 sampled tokens — the pool and parameters never leave the device. Under
 a TP mesh the whole step runs in one shard_map (head-sharded pool,
@@ -247,6 +255,45 @@ def _refuse_for_state(family: Family, **asked) -> None:
                 f"its KV; {what} is refused: {why} (ROADMAP M4)")
 
 
+def _refuse_for_latent(family: Family, **asked) -> None:
+    """A latent family caches ONE row a token that all heads read, and
+    routes without drops over the experts it holds. What that does not
+    compose with yet is refused here, each with the piece it lacks.
+    Any other family passes."""
+    if family.latent is None:
+        return
+    missing = {
+        "mesh": (
+            "a mesh (tp, sp or ep axes)",
+            "the latent row is not head-sharded, its prefill has no "
+            "ring form, and the dropless router has no exchange over "
+            "an ep axis"),
+        "adapters": (
+            "adapters",
+            "LoRA deltas are not plumbed through the low-rank "
+            "projections"),
+        "kv_policy": (
+            "a scaled or float8 KV layout (int8, fp8, fake_quant)",
+            "the latent row is one group with no per-head scale, and "
+            "the absorbed form contracts the rows as stored: pass "
+            "kv_dtype 'f32' or 'bf16'"),
+        "weights": (
+            "a scaled weight layout (int8, fp8, fake_quant)",
+            "the grouped expert matmul applies no per-channel scale: "
+            "pass weights_dtype 'f32' or 'bf16'"),
+        "pallas": (
+            "attn_kernel='pallas'",
+            "the fused kernel walks K and V pools of heads; there is "
+            "no kernel over latent rows"),
+    }
+    for key, on in asked.items():
+        if on:
+            what, why = missing[key]
+            raise NotImplementedError(
+                f"family {family.name!r} serves from a latent pool; "
+                f"{what} is refused: {why} (ROADMAP M1, M3)")
+
+
 class ServeEngine:
     def __init__(self, family: Family, params, *, max_slots: int = 8,
                  block_size: int = 16, num_blocks: int = 64,
@@ -286,6 +333,11 @@ class ServeEngine:
                 spec=spec not in (None, False),
                 adapters=adapters not in (None, False),
                 mesh=mesh is not None, pallas=attn_kernel == "pallas")
+        self._latent = family.latent is not None
+        _refuse_for_latent(
+            family, mesh=mesh is not None,
+            adapters=adapters not in (None, False),
+            pallas=attn_kernel == "pallas")
         self.eos_token_id = eos_token_id
         self.temperature = float(temperature)
         self.top_k = int(top_k)
@@ -581,6 +633,7 @@ class ServeEngine:
         # counts per policy (analysis/specs.weight_layout_policies).
         self.weight_policy = make_weight_policy(weights_dtype)
         self.weights_dtype = self.weight_policy.name
+        _refuse_for_latent(family, weights=self.weight_policy.scaled)
         self._weight_targets = present_targets(params,
                                                family.weight_targets)
         if self.weight_policy.name != "f32" and not self._weight_targets:
@@ -601,6 +654,9 @@ class ServeEngine:
         # policy are pinned unchanged, analysis/specs.py).
         self.kv_policy = make_policy(
             kv_dtype if kv_dtype is not None else family.kv_dtype)
+        _refuse_for_latent(
+            family, kv_policy=self.kv_policy.scaled
+            or self.kv_policy.name == "fp8")
         if self.attn_kernel == "pallas" and self.kv_policy.name == "fp8":
             raise NotImplementedError(
                 "attn_kernel='pallas' does not yet support the fp8 KV "
@@ -638,7 +694,8 @@ class ServeEngine:
             num_blocks=num_blocks, policy=self.kv_policy,
             sharding=sharding, scale_sharding=scale_sharding,
             prefix_cache=self.prefix_cache, host_tier=self.kv_tier,
-            state=family.state, max_slots=self.max_slots)
+            state=family.state, max_slots=self.max_slots,
+            latent=family.latent)
         # per-step promotion budget in BLOCKS (Sarathi's budget
         # discipline applied to host->device memcpy): default 4 blocks
         # a step — enough to drain typical chains in a few steps
@@ -702,8 +759,9 @@ class ServeEngine:
         # only earn XLA's "not usable" warning.) Indices shift with the
         # pool-arg count: scaled KV policies carry 4 pool buffers
         # (k, v, k_scale, v_scale), a recurrent family 4 (k, v, ssm,
-        # conv), passthrough KV-only ones 2. (A recurrent prefill's
-        # slot index follows key_data: no index moves.)
+        # conv), passthrough KV-only ones 2, a latent family 1 (the
+        # bodies then take no v_pool). (A recurrent prefill's slot
+        # index follows key_data: no index moves.)
         n_pool = len(self.pool.caches())
         pool_idx = tuple(range(1, n_pool + 1))
         self._prefills: Dict[int, RecompileSentinel] = {
@@ -773,6 +831,14 @@ class ServeEngine:
             param_bytes=sum(int(x.nbytes)
                             for x in jax.tree.leaves(self.params)),
             kv_bytes_per_token=self.pool.bytes_per_token,
+            # the part of ``param_bytes`` that is routed experts' (nodes
+            # under an ``experts`` key: the dropless router's): a step
+            # reads only those of them that received a row
+            expert_param_bytes=sum(
+                int(x.nbytes) for path, x
+                in jax.tree_util.tree_leaves_with_path(self.params)
+                if any(getattr(k, "key", None) == "experts"
+                       for k in path)),
             # a recurrent family's fixed cost per slot, and the kinds
             # of its layers in model order (0 and None for the rest)
             state_bytes_per_slot=self.pool.state_bytes_per_slot,
@@ -839,8 +905,12 @@ class ServeEngine:
         scaled = policy.scaled
 
         recurrent = self._recurrent
+        latent = self._latent
 
-        def body(params, k_pool, v_pool, *rest):
+        def body(params, k_pool, *rest):
+            v_pool = None
+            if not latent:
+                v_pool, *rest = rest
             if scaled:
                 k_scale, v_scale, *rest = rest
             else:
@@ -876,7 +946,9 @@ class ServeEngine:
             # copy and back after it (four pool-sized copies a prefill)
             every = jnp.arange(k_pool.shape[0])[:, None]
             k_pool = k_pool.at[every, dst_idx].set(k_pool[every, src_idx])
-            v_pool = v_pool.at[every, dst_idx].set(v_pool[every, src_idx])
+            if v_pool is not None:
+                v_pool = v_pool.at[every, dst_idx].set(
+                    v_pool[every, src_idx])
             if scaled:
                 ksd = jnp.where(cow_len > 0, k_scale[:, cow_src],
                                 k_scale[:, dst])
@@ -924,8 +996,12 @@ class ServeEngine:
         scaled = policy.scaled
 
         recurrent = self._recurrent
+        latent = self._latent
 
-        def body(params, k_pool, v_pool, *rest):
+        def body(params, k_pool, *rest):
+            v_pool = None
+            if not latent:
+                v_pool, *rest = rest
             extra = {}
             if scaled:
                 k_scale, v_scale, *rest = rest
@@ -969,8 +1045,12 @@ class ServeEngine:
         use_lora = self.adapters is not None
         policy = self.kv_policy
         scaled = policy.scaled
+        latent = self._latent
 
-        def body(params, k_pool, v_pool, *rest):
+        def body(params, k_pool, *rest):
+            v_pool = None
+            if not latent:
+                v_pool, *rest = rest
             if scaled:
                 k_scale, v_scale, *rest = rest
             ids, starts, tail_lens, tables, key_data, *rest = rest
@@ -1726,18 +1806,20 @@ class ServeEngine:
     # ------------------------------------------------------------------
     # MoE routing-stats ledger (serve/metrics.py)
     # ------------------------------------------------------------------
-    def _pop_moe(self, pools, *, note: bool = True):
+    def _pop_moe(self, pools, *, note: bool = True, decode: bool = False):
         """Split the trailing routing-stats dict off a MoE program's
         pool outputs (serve/families.py widens every MoE program's
-        return by one) and bank it for the step ledger. Dense families
-        pass through untouched; warmup calls pass ``note=False`` so
+        return by one) and bank it for the step ledger, marked with
+        whether the DECODE program produced it. Dense families pass
+        through untouched; warmup calls pass ``note=False`` so
         compile-time probes never pollute the serving numbers."""
         if not self._moe_on:
             return pools
         *pools, st = pools
         if note:
             with self._phases.wait(1):
-                self._moe_acc.append(jax.tree.map(np.asarray, st))
+                self._moe_acc.append(
+                    {**jax.tree.map(np.asarray, st), "decode": decode})
         return tuple(pools)
 
     def _drain_moe(self) -> Dict[str, object]:
@@ -1748,7 +1830,7 @@ class ServeEngine:
         acc, self._moe_acc = self._moe_acc, []
         if not acc:
             return {}
-        return {
+        out = {
             "moe_expert_tokens": np.sum(
                 [a["expert_tokens"] for a in acc], axis=0),
             "moe_routed_tokens": float(
@@ -1758,6 +1840,27 @@ class ServeEngine:
             "moe_router_entropy": float(
                 np.mean([a["entropy"] for a in acc])),
         }
+        if "held_rows" not in acc[0]:
+            return out
+        # the dropless router over the experts HELD here (nn/moe.py):
+        # the step's routings that landed on them, the (layer, expert)
+        # pairs among them that received a row, the routings that went
+        # to experts held elsewhere — and the same of the decode
+        # program alone, whose weight reads they decide
+        first, held = self.moe_args.experts_held or (
+            0, self.moe_args.n_experts)
+        dec = [a for a in acc if a["decode"]]
+        out.update(
+            expert_rows=float(np.sum([a["held_rows"] for a in acc])),
+            experts_touched=float(np.sum([a["touched"] for a in acc])),
+            routed_elsewhere=float(np.sum([a["elsewhere"] for a in acc])),
+            decode_expert_rows=float(np.sum([a["held_rows"] for a in dec])),
+            decode_experts_touched=float(
+                np.sum([a["touched"] for a in dec])),
+            decode_held_expert_tokens=np.sum(
+                [a["expert_tokens"][first:first + held] for a in dec],
+                axis=0) if dec else np.zeros((held,)))
+        return out
 
     def _admit_one(self, slot: int, req: Request) -> Tuple[int, int]:
         """Admit ``req`` into ``slot``: reuse the longest cached prefix
@@ -2263,7 +2366,7 @@ class ServeEngine:
                 with ph.phase("dispatch"):
                     *pools, nxt, key2 = sentinel(
                         self.params, *self.pool.caches(), *args, *extra)
-                self.pool.update(*self._pop_moe(pools))
+                self.pool.update(*self._pop_moe(pools, decode=True))
                 with ph.wait(2):
                     nxt = np.asarray(nxt)
                     key2 = np.array(key2)
@@ -2318,7 +2421,10 @@ class ServeEngine:
                                  else tier.promoted_tokens),
                 host_tier_bytes=0 if tier is None else tier.bytes_used,
                 decode_blocked_demotions=self._decode_blocked_demotions,
-                **moe_kw)
+                # the ledger's names; the ring's attrs take the held-
+                # expert counts too
+                **{k: v for k, v in moe_kw.items()
+                   if k.startswith("moe_")})
         rec_t1 = ph.end()
         if self._recorder is not None:
             self._recorder.record(StepRecord(

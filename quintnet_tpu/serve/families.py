@@ -92,6 +92,14 @@ held (admission); one that starts later continues from the state its
 predecessor left (chunked prefill). Pad columns of a bucket, rows with
 ``tail_len`` 0 and decode rows whose table is all null blocks leave
 their state exactly as it was.
+
+Latent families (``Family.latent`` set: multi-head latent attention,
+:func:`pangu_moe_family`): a token caches ONE row ``[c | k_rope]``
+(serve/kv_pool.py), so there is one pool buffer. The contracts keep
+their positions — ``v_pool`` is passed as None — and return ``(logits,
+k_pool, moe_stats)``. ``prefill_from`` runs the MATERIALIZED form of
+the attention, ``decode`` and ``verify`` the ABSORBED one
+(nn/attention.py, "The LATENT paged cache").
 """
 
 from __future__ import annotations
@@ -143,8 +151,10 @@ class Family:
     lora_targets: Tuple[str, ...] = ()
     # paths (relative to one block node) of the linear nodes a weight
     # layout policy packs (serve/weight_quant.py): the decode-bandwidth
-    # matmuls. Embeddings, head, LNs and MoE experts stay
-    # full-precision.
+    # matmuls. Embeddings, head and LNs stay full-precision, as do the
+    # capacity router's raw expert leaves (the tiny MoE families); the
+    # dropless router's experts are linear nodes ([held, in, out]) and
+    # ARE packed (pangu_moe_family).
     weight_targets: Tuple[Tuple[str, ...], ...] = ()
     # host-side layout hook: (path, b_factor [L, r, out], tp) -> the
     # factor permuted into the layout the SERVING weights use under tp.
@@ -161,6 +171,10 @@ class Family:
     # per-slot recurrent state beside the paged pool (module docstring);
     # None = sequences are KV only
     state: Optional[StateShapes] = None
+    # latent families (module docstring): the features of the ONE row a
+    # token caches; ``n_kv_heads`` is then 1 and ``head_dim`` this
+    # number, and the programs take and return no ``v_pool``
+    latent: Optional[int] = None
 
 
 # --------------------------------------------------------------------
@@ -254,6 +268,9 @@ def _reduce_moe_stats(st):
         "dropped": jnp.sum(st["dropped"]),
         "assigned": jnp.sum(st["assigned"]),
         "entropy": jnp.mean(st["entropy"]),
+        # the dropless router's own counts (nn/moe.py), where it ran
+        **{k: jnp.sum(st[k]) for k in ("held_rows", "touched", "elsewhere")
+           if k in st},
     }
 
 
@@ -586,4 +603,97 @@ def granite_hybrid_family(cfg) -> Family:
             n_layers=cfg.n_mamba_layers,
             ssm=(dims.n_heads, dims.d_head, dims.d_state),
             conv=((dims.d_conv - 1) * dims.d_xbc,)),
+    )
+
+
+# --------------------------------------------------------------------
+# openPangu-Ultra-MoE (latent attention, a leading dense stack before
+# the MoE stack, sandwich norms): one latent row a token in the pool
+# --------------------------------------------------------------------
+
+def pangu_moe_family(cfg) -> Family:
+    from quintnet_tpu.models.pangu_moe import (
+        ABSORBED, MATERIALIZED, WEIGHT_TARGETS, pangu_block, pangu_embed,
+        pangu_logits, pangu_moe_partition_specs)
+    from quintnet_tpu.nn.attention import rope_cos_sin
+
+    def only_plain(v_pool, tp_axis, ep_axis, lora, kv_scales, attn_kernel):
+        if (v_pool is not None or tp_axis is not None
+                or ep_axis is not None or lora is not None
+                or kv_scales is not None or attn_kernel != "xla"):
+            raise NotImplementedError(
+                "the pangu_ultra_moe programs run on one device, on the "
+                "one unscaled latent pool (v_pool=None), without "
+                "adapters, with attn_kernel='xla' (ServeEngine refuses "
+                "the rest at construction)")
+
+    def run(params, ids, pool, positions, lens, tables, block_size, form):
+        """Both stacks in turn, each a uniform :func:`_scan_layers`:
+        the leading dense layers, then the MoE layers at the pool's
+        layers after them. Returns (h, pool, moe_stats)."""
+        cos, sin = rope_cos_sin(positions, cfg.qk_rope_head_dim,
+                                theta=cfg.rope_theta)       # [S, P, rope]
+
+        def step(first, experts=None):
+            def one(blk, layer, _lr, x, pools):
+                return pangu_block(
+                    blk, x, pools[0], first + layer, positions, lens,
+                    tables, block_size, cfg, cos, sin, form=form,
+                    experts=experts,
+                    expert_layer=None if experts is None else layer)
+            return one
+
+        h, pool = _scan_layers(step(0), pangu_embed(params, ids), (pool,),
+                               params["blocks"]["dense"], None, False)
+        # the routed experts stay out of the scan's xs, whole: the
+        # grouped matmul picks the layer's by group (nn/moe.py)
+        moe = params["blocks"]["moe"]
+        rest = {**moe, "moe": {k: v for k, v in moe["moe"].items()
+                               if k != "experts"}}
+        return _scan_layers(
+            step(cfg.n_dense_layers, moe["moe"]["experts"]), h, (pool,),
+            rest, None, True)
+
+    def prefill_from(params, k_pool, v_pool, ids, start, t0, table_row,
+                     block_size, tp_axis=None, ep_axis=None, lora=None,
+                     lora_scale=None, kv_scales=None, policy=None,
+                     attn_kernel="xla"):
+        only_plain(v_pool, tp_axis, ep_axis, lora, kv_scales, attn_kernel)
+        positions = (start + jnp.arange(ids.shape[1], dtype=jnp.int32))[None]
+        h, pool, stats = run(
+            params, ids, k_pool, positions, jnp.reshape(t0 - start, (1,)),
+            table_row[None], block_size, MATERIALIZED)
+        h_last = lax.dynamic_slice_in_dim(h, t0 - 1 - start, 1, axis=1)
+        return pangu_logits(params, h_last, cfg)[:, 0, :], pool, stats
+
+    def decode(params, k_pool, v_pool, tok, pos, tables, block_size,
+               tp_axis=None, ep_axis=None, lora=None, lora_scale=None,
+               kv_scales=None, policy=None, attn_kernel="xla"):
+        only_plain(v_pool, tp_axis, ep_axis, lora, kv_scales, attn_kernel)
+        # a row whose table is all null blocks is not decoding (an empty
+        # slot, or one in the middle of a chunked prefill): its token is
+        # padding, which the router sends nowhere
+        live = (tables[:, 0] != NULL_BLOCK).astype(jnp.int32)
+        h, pool, stats = run(params, tok[:, None], k_pool, pos[:, None],
+                             live, tables, block_size, ABSORBED)
+        return pangu_logits(params, h, cfg)[:, 0, :], pool, stats
+
+    def verify(params, k_pool, v_pool, ids, starts, tail_lens, tables,
+               block_size, tp_axis=None, ep_axis=None, lora=None,
+               lora_scale=None, kv_scales=None, policy=None,
+               attn_kernel="xla"):
+        only_plain(v_pool, tp_axis, ep_axis, lora, kv_scales, attn_kernel)
+        positions = (starts[:, None]
+                     + jnp.arange(ids.shape[1], dtype=jnp.int32)[None, :])
+        h, pool, stats = run(params, ids, k_pool, positions, tail_lens,
+                             tables, block_size, ABSORBED)
+        return pangu_logits(params, h, cfg), pool, stats
+
+    return Family(
+        name="pangu_ultra_moe", cfg=cfg, n_layers=cfg.num_hidden_layers,
+        n_kv_heads=1, head_dim=cfg.latent_width,
+        max_positions=cfg.max_position_embeddings,
+        prefill_from=prefill_from, decode=decode, verify=verify,
+        partition_specs=pangu_moe_partition_specs,
+        weight_targets=WEIGHT_TARGETS, latent=cfg.latent_width,
     )

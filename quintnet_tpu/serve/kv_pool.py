@@ -57,6 +57,16 @@ the last row is the null row, the state's counterpart of block 0
 to share: the prefix index, the host tier and chain export/import know
 nothing of it, and the engine refuses them for such a family.
 
+Latent families (multi-head latent attention, ``Family.latent`` set)
+keep ONE row kind: ``[c | k_rope]``, the compressed kv and the shared
+rotary key of a token, ``latent`` features padded to whole lane rows
+like any other (576 -> 640). The pool is built with ``latent=576``:
+``k`` holds the rows, there is NO ``v`` (``pool.v is None``,
+``caches()`` is ``(k,)``), ``n_kv_heads`` is 1 and ``head_dim`` the row's
+features, so host records are ``[L, n, 1, latent]`` and carry no ``v``.
+Blocks, refcounts, the prefix index, chain export/import and the host
+tier deal in blocks and records and work unchanged.
+
 Prefix caching (the PagedAttention sharing model + SGLang-style prefix
 reuse, block-granular):
 
@@ -171,7 +181,18 @@ class KVPool:
                  prefix_cache: bool = True,
                  host_tier: Optional[HostTier] = None,
                  state: Optional[StateShapes] = None,
-                 max_slots: int = 0):
+                 max_slots: int = 0, latent: Optional[int] = None):
+        if latent is not None:
+            # one row kind a token (module docstring)
+            if n_kv_heads != 1 or head_dim != latent:
+                raise ValueError(
+                    f"a latent pool holds ONE row of {latent} features a "
+                    f"token: pass n_kv_heads=1, head_dim={latent}")
+            if sharding is not None or state is not None:
+                raise NotImplementedError(
+                    "a latent pool is neither head-sharded (all heads "
+                    "read the one row) nor kept beside recurrent state")
+        self.latent = latent
         if block_size < 1 or num_blocks < 2:
             raise ValueError(
                 f"need block_size >= 1 and num_blocks >= 2 (block 0 is "
@@ -197,9 +218,15 @@ class KVPool:
         shape = (n_layers, num_blocks * block_size,
                  self.head_shards * self._local_width)
         k = jnp.zeros(shape, self.policy.store_dtype)
-        v = jnp.zeros(shape, self.policy.store_dtype)
+        v = (None if latent is not None
+             else jnp.zeros(shape, self.policy.store_dtype))
         k_scale = v_scale = None
         if self.policy.scaled:
+            if latent is not None:
+                raise NotImplementedError(
+                    f"a latent pool under the scaled policy "
+                    f"{self.policy.name!r} is not implemented: the row "
+                    f"is one group with no per-head scale (ROADMAP M3)")
             k_scale = jnp.ones((n_layers, num_blocks, n_kv_heads),
                                jnp.float32)
             v_scale = jnp.ones((n_layers, num_blocks, n_kv_heads),
@@ -281,9 +308,11 @@ class KVPool:
         ones, so the same pool bytes hold ~4x the blocks — THE
         capacity-is-concurrency equation (tests/test_kv_quant.py
         solves it for equal bytes)."""
-        return self.policy.bytes_per_block(
+        both = self.policy.bytes_per_block(
             n_layers=self.n_layers, n_kv_heads=self.n_kv_heads,
             head_dim=self.head_dim, block_size=self.block_size)
+        # a latent pool has the one row kind (unscaled: exactly half)
+        return both // 2 if self.latent is not None else both
 
     @property
     def pool_bytes(self) -> int:
@@ -399,8 +428,8 @@ class KVPool:
         if key is None or fill <= 0:
             return False
         bs = self.block_size
-        k, v = self.read_slots(np.arange(b * bs, (b + 1) * bs))
-        rec = {"fill": int(fill), "k": k, "v": v}
+        rec = {"fill": int(fill), **self._record(
+            *self.read_slots(np.arange(b * bs, (b + 1) * bs)))}
         if self.policy.scaled:
             rec["k_scale"] = np.asarray(self.k_scale[:, b])
             rec["v_scale"] = np.asarray(self.v_scale[:, b])
@@ -788,11 +817,7 @@ class KVPool:
             idx = np.concatenate([np.arange(b * bs, (b + 1) * bs)
                                   for b in blocks])
             k, v = self.write_slots(
-                idx,
-                np.concatenate([np.asarray(r["k"]) for _, r in todo],
-                               axis=1),
-                np.concatenate([np.asarray(r["v"]) for _, r in todo],
-                               axis=1))
+                idx, *self._joined([r for _, r in todo]))
             if self.policy.scaled:
                 barr = np.asarray(blocks, np.int32)
                 ks = np.stack([np.asarray(r["k_scale"])
@@ -805,7 +830,7 @@ class KVPool:
                             self.v_scale.at[:, barr].set(
                                 jnp.asarray(vs, jnp.float32)))
             else:
-                self.update(k, v)
+                self._adopt(k, v)
             for b, (key, rec) in zip(blocks, todo):
                 self._publish_one(b, key, int(rec["fill"]))
             self.release(blocks)
@@ -861,9 +886,10 @@ class KVPool:
         for j, (kind, ref, fill) in enumerate(entries):
             if kind == "dev":
                 s = dev_slot[j]
-                rec = {"fill": int(fill),
-                       "k": k_all[:, s * bs:(s + 1) * bs],
-                       "v": v_all[:, s * bs:(s + 1) * bs]}
+                rec = {"fill": int(fill), **self._record(
+                    k_all[:, s * bs:(s + 1) * bs],
+                    None if v_all is None
+                    else v_all[:, s * bs:(s + 1) * bs])}
                 if self.policy.scaled:
                     rec["k_scale"] = ks_all[:, s]
                     rec["v_scale"] = vs_all[:, s]
@@ -949,10 +975,7 @@ class KVPool:
         # memcpys
         idx = np.concatenate([np.arange(b * bs, (b + 1) * bs)
                               for b in blocks])
-        k, v = self.write_slots(
-            idx,
-            np.concatenate([np.asarray(r["k"]) for r in records], axis=1),
-            np.concatenate([np.asarray(r["v"]) for r in records], axis=1))
+        k, v = self.write_slots(idx, *self._joined(records))
         if self.policy.scaled:
             barr = np.asarray(blocks, np.int32)
             ks = np.stack([np.asarray(r["k_scale"]) for r in records],
@@ -965,18 +988,37 @@ class KVPool:
                 jnp.asarray(vs, jnp.float32))
             self.update(k, v, k_scale, v_scale)
         else:
-            self.update(k, v)
+            self._adopt(k, v)
         tokens = np.asarray(chain["tokens"], np.int32).reshape(-1)
         self.publish(tokens, blocks, n_tokens, namespace=namespace)
         self.release(blocks)
         return n_tokens
 
     # ---- the pool's edge: host records <-> device rows ---------------
-    def read_slots(self, idx) -> Tuple[np.ndarray, np.ndarray]:
+    @staticmethod
+    def _record(k, v) -> Dict:
+        """A host record's data: ``k`` and, where the pool has one, ``v``
+        (a latent pool's records carry no ``v``)."""
+        return {"k": k} if v is None else {"k": k, "v": v}
+
+    def _joined(self, records):
+        """(k, v) of ``records`` side by side along the slot dim, ``v``
+        None for a latent pool: what :meth:`write_slots` takes."""
+        return tuple(
+            np.concatenate([np.asarray(r[n]) for r in records], axis=1)
+            if records[0].get(n) is not None else None
+            for n in ("k", "v"))
+
+    def _adopt(self, k, v) -> None:
+        """:meth:`update` with what :meth:`write_slots` returned."""
+        self.update(*((k,) if v is None else (k, v)))
+
+    def read_slots(self, idx) -> Tuple[np.ndarray, Optional[np.ndarray]]:
         """Flat slots ``idx`` [n] of every layer as HOST arrays in the
         record shape ``[L, n, H_kv, Dh]`` (k, v), exactly as stored
-        (``store_dtype``), the pad lanes dropped. One gather a pool
-        array: a chain read costs O(chain bytes), never O(pool)."""
+        (``store_dtype``), the pad lanes dropped; ``v`` is None for a
+        latent pool. One gather a pool array: a chain read costs
+        O(chain bytes), never O(pool)."""
         every = np.arange(self.n_layers)[:, None]
 
         def heads(pool):
@@ -989,9 +1031,9 @@ class KVPool:
             return rows.reshape(L, n, self.head_shards, self._local_width)[
                 ..., :local].reshape(L, n, self.n_kv_heads, self.head_dim)
 
-        return heads(self.k), heads(self.v)
+        return heads(self.k), (None if self.v is None else heads(self.v))
 
-    def write_slots(self, idx, k_new, v_new):
+    def write_slots(self, idx, k_new, v_new=None):
         """The pool arrays with records ``k_new``/``v_new``
         ``[L, n, H_kv, Dh]`` written at flat slots ``idx`` [n] — ONE
         fused scatter a pool array (a per-block ``.at[].set`` would copy
@@ -1010,7 +1052,8 @@ class KVPool:
                 jnp.asarray(rows.reshape(L, n, -1),
                             self.policy.store_dtype))
 
-        return put(self.k, k_new), put(self.v, v_new)
+        return put(self.k, k_new), (None if self.v is None
+                                    else put(self.v, v_new))
 
     # ---- device views ----------------------------------------------
     def caches(self):
@@ -1018,26 +1061,33 @@ class KVPool:
         functions (the engine writes the returned/donated results back
         via :meth:`update`): ``(k, v)`` for passthrough policies,
         ``(k, v, k_scale, v_scale)`` for scaled ones, ``(k, v, ssm,
-        conv)`` for a recurrent family — call sites splat the tuple,
-        so neither the policy nor the family changes their shape."""
+        conv)`` for a recurrent family, ``(k,)`` for a latent one —
+        call sites splat the tuple, so neither the policy nor the
+        family changes their shape."""
+        if self.latent is not None:
+            return (self.k,)
         if self.policy.scaled:
             return self.k, self.v, self.k_scale, self.v_scale
         if self.state is not None:
             return self.k, self.v, self.ssm, self.conv
         return self.k, self.v
 
-    def update(self, k, v, *rest) -> None:
+    def update(self, k, *rest) -> None:
         """Adopt what a program returned for :meth:`caches`' buffers,
         in that order."""
-        if len(rest) != len(self.caches()) - 2:
-            carries = ("scale arrays" if self.policy.scaled
+        if 1 + len(rest) != len(self.caches()):
+            carries = ("the one latent buffer" if self.latent is not None
+                       else "scale arrays" if self.policy.scaled
                        else "recurrent state buffers"
                        if self.state is not None else "no other buffers")
             raise ValueError(
                 f"policy {self.policy.name!r} carries {carries}; "
                 f"update() needs all {len(self.caches())} pool buffers, "
-                f"got {2 + len(rest)}")
-        self.k, self.v = k, v
+                f"got {1 + len(rest)}")
+        self.k = k
+        if self.latent is not None:
+            return
+        self.v, *rest = rest
         if self.policy.scaled:
             self.k_scale, self.v_scale = rest
         elif self.state is not None:

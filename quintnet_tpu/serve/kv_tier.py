@@ -46,7 +46,9 @@ from typing import Dict, List, Optional
 def record_nbytes(rec: Dict) -> int:
     """Host bytes one demoted block record holds (slot data + scale
     rows). The ledger the byte budget is enforced against."""
-    n = rec["k"].nbytes + rec["v"].nbytes
+    n = rec["k"].nbytes
+    if rec.get("v") is not None:     # a latent pool's records have none
+        n += rec["v"].nbytes
     if "k_scale" in rec:
         n += rec["k_scale"].nbytes + rec["v_scale"].nbytes
     return n

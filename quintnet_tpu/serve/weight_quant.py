@@ -45,10 +45,15 @@ param tree before the first trace (ladder pinned in
 analysis/specs.weight_layout_policies, compile bound unchanged).
 
 The targeted nodes are the family's ``weight_targets``
-(serve/families.py; gpt2: qkv/proj/fc, llama: q/k/v/o/gate/up/down).
-Embeddings, logits head, LayerNorms and MoE experts stay
-full-precision — they are either bandwidth-cheap per token or
-precision-critical (the router-ordering lesson, nn/layers.py).
+(serve/families.py; gpt2: qkv/proj/fc, llama: q/k/v/o/gate/up/down;
+pangu_ultra_moe: the latent attention's five projections, the dense
+and shared SwiGLUs and the routed experts it holds, ``[held, in,
+out]`` nodes — unscaled policies only there: the grouped matmul
+applies no per-channel scale). Embeddings, logits head, norms, the
+router and the capacity router's raw expert leaves (the tiny MoE
+families) stay full-precision — they are either bandwidth-cheap per
+token or precision-critical (the router-ordering lesson,
+nn/layers.py).
 """
 
 from __future__ import annotations
@@ -141,8 +146,8 @@ def _with_node(tree, path, node):
 def present_targets(params, targets) -> Tuple[Tuple[str, ...], ...]:
     """Filter a family's ``weight_targets`` to the paths that actually
     exist in THIS param tree — an MoE block swaps ``mlp`` for ``moe``
-    (experts stay full-precision), so the dense-mlp targets simply
-    drop out instead of KeyError-ing."""
+    (the capacity router's raw expert leaves stay full-precision), so
+    the dense-mlp targets simply drop out instead of KeyError-ing."""
     out = []
     for path in targets:
         node = params["blocks"]

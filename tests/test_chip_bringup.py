@@ -12,7 +12,10 @@ checked without a chip (PR 21, the bring-up round).
    plan must not hold a second copy of the per-slot state; and (PR 28)
    the decode and prefill programs at GPT-2 XL's widths, which must
    take the KV pool row-major and hold no copy of it or of a layer's
-   slice — nor (PR 30) a decode program of a gathered view. The parent
+   slice — nor (PR 30) a decode program of a gathered view; and (PR
+   31) the latent family's decode program at openPangu-Ultra-MoE's
+   widths: one row-major latent pool, no copy of a gathered view, the
+   experts as the chip's grouped matmul. The parent
    commit's paged kernel was
    REFUSED at every one of these shapes (16 MiB default scoped-VMEM
    budget; a (1, Hkv) scale block; a lane-splitting reshape) —
@@ -212,6 +215,71 @@ def test_hybrid_decode_holds_one_copy_of_the_state_on_v5e(chip):
         plan.temp_size_in_bytes, state_bytes)
     _assert_pool_row_major_and_uncopied(compiled.as_text(), pool,
                                         view=(slots, width, bs))
+
+
+def test_latent_decode_contracts_the_rows_as_gathered_on_v5e(chip):
+    """``pangu_moe_family(...).decode`` at openPangu-Ultra-MoE's
+    published widths (shapes only: ``jax.eval_shape``), the cell's one
+    dense and one of its four MoE layers, 8 slots of 5,120 positions,
+    the latent pool donated, compiled for the described chip. The pool
+    ``[L, slots, 640]`` (576 features padded to five lane rows) must
+    enter row-major and alias out; no copy of it, of a layer's slice or
+    of a gathered view may be planned (the absorbed form cuts no head
+    out of the view); and the experts must run as the chip's own
+    grouped matmul, not as a loop of masked dense ones."""
+    import numpy as np
+
+    from quintnet_tpu.models.pangu_moe import PanguMoEConfig, pangu_moe_init
+    from quintnet_tpu.serve import pangu_moe_family
+    from quintnet_tpu.serve.kv_pool import feature_width
+    from quintnet_tpu.serve.weight_quant import (make_weight_policy,
+                                                 present_targets,
+                                                 quantize_params)
+
+    with open(os.path.join(REPO, "benchmarks", "configs",
+                           "openpangu-ultra-moe-718b.json")) as f:
+        cfg = PanguMoEConfig.from_dict({**json.load(f),
+                                        "num_hidden_layers": 2})
+    fam = pangu_moe_family(cfg)
+    slots, bs, width = 8, 16, 320
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    params = jax.tree.map(
+        lambda x: sds(x.shape, x.dtype),
+        jax.eval_shape(lambda k: (lambda p: quantize_params(
+            p, present_targets(p, fam.weight_targets),
+            make_weight_policy("bf16")))(pangu_moe_init(k, cfg)),
+            jax.random.key(0)))
+    assert feature_width(1, fam.latent) == 640
+    pool = sds((fam.n_layers, slots * width * bs, 640), jnp.bfloat16)
+    rows = sds((slots,), jnp.int32)
+
+    def decode(params, k, tok, pos, tables):
+        return fam.decode(params, k, None, tok, pos, tables, bs)
+
+    compiled = jax.jit(decode, donate_argnums=(1,)).lower(
+        params, pool, rows, rows, sds((slots, width), jnp.int32)).compile()
+    plan = compiled.memory_analysis()
+    pool_bytes = int(np.prod(pool.shape)) * 2
+    assert plan.alias_size_in_bytes >= pool_bytes      # in and out alias
+    hlo = compiled.as_text()
+    assert "ragged-dot" in hlo and "tpu_custom_call" in hlo
+    spec = importlib.util.spec_from_file_location(
+        "pool_layout_audit", os.path.join(REPO, "tools",
+                                          "pool_layout_audit.py"))
+    audit = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(audit)
+    view_bytes = slots * width * bs * 640 * 2
+    got = audit.read_hlo(hlo, {"k": pool.shape}, view_bytes)
+    assert got["entry_layouts"] == {"k": ["{2,1,0}"]}, got
+    for c in got["big_copies"]:
+        dims = tuple(c["dims"])
+        assert dims not in {tuple(pool.shape), tuple(pool.shape[1:]),
+                            (1, *pool.shape[1:])}, c
+        assert dims[:3] != (slots, width, bs), c
+        assert dims[:2] != (slots, width * bs), c
 
 
 def _assert_pool_row_major_and_uncopied(hlo: str, pool, view=None):
