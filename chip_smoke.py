@@ -30,8 +30,10 @@ seconds it spent compiling or loading compiled programs):
   prompts — ``Family.verify`` against the engine's own pool, the seam
   ``serve/kv_quant.paged_eval_nll`` shows — against a plain dense
   ``gpt2_apply`` of the same params on the chip.
-- **kernels** — ``flash_attention`` at seq 4096 (12 heads, Dh 64,
-  causal) forward and backward against ``blockwise_attention``; and a
+- **kernels** — ``nn/attention.local_attention`` at seq 1024 and 8192
+  (12 heads, Dh 64, causal: the fused kernel's resident and streamed
+  geometries, as the chooser picks them) forward and backward against
+  ``blockwise_attention``; and a
   second ``ServeEngine(attn_kernel="pallas")`` answering two of the
   serve prompts, its logits against the first engine's. Both must
   show a ``tpu_custom_call`` in the lowered program: a kernel phase
@@ -109,7 +111,7 @@ class Size:
     dense_check: Tuple[int, int] = (1, 6)    # prompts compared with dense
     kernel_check: Tuple[int, int] = (0, 2)   # prompts the Pallas engine serves
     pallas_prefill_len: int = 128       # its prefill window (chunked beyond)
-    flash_seq: int = 4096
+    flash_seqs: Tuple[int, ...] = (1024, 8192)  # resident, streamed
 
 
 def full_size() -> Size:
@@ -495,15 +497,18 @@ def phase_serve(size: Size, seed: int) -> Tuple[Dict, Any, list]:
 # ---------------------------------------------------------------------
 # kernels
 # ---------------------------------------------------------------------
-def check_flash(size: Size, seed: int) -> Dict:
-    """``flash_attention`` fwd+bwd on its Pallas branch against
-    ``blockwise_attention`` (f32, highest matmul precision)."""
+def check_flash(size: Size, seed: int, S: int) -> Dict:
+    """``local_attention`` fwd+bwd at ``S`` positions, on the Pallas
+    branch its chooser takes there, against ``blockwise_attention``
+    (f32, highest matmul precision)."""
     import jax
     import jax.numpy as jnp
 
-    from quintnet_tpu.ops import blockwise_attention, flash_attention
+    from quintnet_tpu.nn.attention import (local_attention,
+                                           local_attention_path)
+    from quintnet_tpu.ops import blockwise_attention
 
-    H, S = size.cfg.n_head, size.flash_seq
+    H = size.cfg.n_head
     D = size.cfg.n_embd // H
     ks = jax.random.split(jax.random.key(seed + 2), 4)
     q, k, v, w = (jax.random.normal(kk, (1, H, S, D), jnp.bfloat16)
@@ -517,7 +522,7 @@ def check_flash(size: Size, seed: int) -> Dict:
         return jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True)
 
     kernel = jax.jit(fwd_bwd(
-        lambda q, k, v: flash_attention(q, k, v, causal=True))).lower(
+        lambda q, k, v: local_attention(q, k, v, causal=True))).lower(
             q, k, v)
     has_call = "tpu_custom_call" in kernel.as_text()
     (_, o_k), g_k = kernel.compile()(q, k, v)
@@ -534,6 +539,9 @@ def check_flash(size: Size, seed: int) -> Dict:
         rel[name] = round(float(jnp.max(jnp.abs(a - b))
                                 / jnp.max(jnp.abs(b))), 5)
     return {"seq": S, "heads": H, "head_dim": D, "dtype": "bfloat16",
+            "path": local_attention_path(
+                backend=jax.default_backend(), seq=S, head_dim=D,
+                dropout=False),
             "tpu_custom_call": has_call, "rel_err": rel,
             "tol": TOL["flash_vs_blockwise_rel"]}
 
@@ -578,13 +586,13 @@ def check_pallas_engine(size: Size, xla_engine, prompts, seed: int) -> Dict:
 
 
 def phase_kernels(size: Size, xla_engine, prompts, seed: int) -> Dict:
-    flash = check_flash(size, seed)
-    check(flash["tpu_custom_call"],
-          "flash_attention lowered without a tpu_custom_call: it ran "
-          "the blockwise reference, not the kernel")
-    worst = max(flash["rel_err"].values())
-    check(worst <= TOL["flash_vs_blockwise_rel"],
-          f"flash vs blockwise: {flash['rel_err']}")
+    flash = [check_flash(size, seed, S) for S in size.flash_seqs]
+    for f in flash:
+        check(f["tpu_custom_call"],
+              f"local_attention at seq {f['seq']} lowered without a "
+              f"tpu_custom_call: it ran {f['path']}, not the kernel")
+        check(max(f["rel_err"].values()) <= TOL["flash_vs_blockwise_rel"],
+              f"flash vs blockwise at seq {f['seq']}: {f['rel_err']}")
     paged = check_pallas_engine(size, xla_engine, prompts, seed)
     check(paged["tpu_custom_call"],
           "the Pallas engine's decode program has no tpu_custom_call")

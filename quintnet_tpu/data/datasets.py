@@ -318,7 +318,7 @@ def pack_documents(docs: Sequence[Sequence[int]], seq_len: int,
 
 def segments_from_tokens(rows: np.ndarray, eos_id: int) -> np.ndarray:
     """Packed rows [N, S] -> per-position document ids [N, S] int32 for
-    attention segment masking (ops/flash_attention.flash_attention
+    attention segment masking (``nn/attention.local_attention``
     ``segment_ids``): each EOS separator closes its document, so the id
     increments AFTER every eos. Ids restart at 0 per row (attention
     never crosses rows, so only within-row distinctness matters)."""

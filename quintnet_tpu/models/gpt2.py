@@ -101,7 +101,7 @@ class GPT2Config:
     # --- packed-document isolation: when set, attention segment ids are
     # derived on the fly from input_ids (a new segment starts AFTER each
     # occurrence of this token) and threaded into every attention layer
-    # incl. the Pallas flash kernel (ops/flash_attention segment_ids) —
+    # incl. the Pallas kernels (ops/pallas_attention segment_ids) —
     # positions never attend across packed-document boundaries. None =
     # the GPT-2 convention (cross-document attention accepted).
     segment_eos_id: Optional[int] = None
@@ -277,7 +277,7 @@ def gpt2_blocks(params_blocks, h, cfg: GPT2Config, *,
                 tp_axis: Optional[str] = None,
                 sp_axis: Optional[str] = None, sp_mode: str = "ring",
                 ep_axis: Optional[str] = None,
-                remat: "bool | str" = False, use_flash: bool = False,
+                remat: "bool | str" = False,
                 key=None, segment_ids=None, fsdp=None):
     """Returns ``h`` for dense configs, ``(h, moe_aux)`` when
     ``cfg.n_experts > 0``. ``key`` enables training dropout."""
@@ -293,7 +293,6 @@ def gpt2_blocks(params_blocks, h, cfg: GPT2Config, *,
             sp_axis=sp_axis,
             sp_mode=sp_mode,
             remat=remat,
-            use_flash=use_flash,
             moe_args=cfg.moe_args,
             ep_axis=ep_axis,
             attn_pdrop=attn_p,
@@ -343,7 +342,7 @@ def gpt2_hidden(params, input_ids, cfg: GPT2Config, *,
                 tp_axis: Optional[str] = None,
                 sp_axis: Optional[str] = None, sp_mode: str = "ring",
                 ep_axis: Optional[str] = None,
-                remat: "bool | str" = False, use_flash: bool = False,
+                remat: "bool | str" = False,
                 key=None, fsdp=None):
     """embed + blocks -> (final hidden states [B, T, D], moe_aux); the
     pre-lm-head half of :func:`gpt2_forward` (chunked-CE computes the
@@ -357,7 +356,7 @@ def gpt2_hidden(params, input_ids, cfg: GPT2Config, *,
     seg = segment_ids_from_input(input_ids, cfg, sp_axis=sp_axis)
     out = gpt2_blocks(params["blocks"], h, cfg, tp_axis=tp_axis,
                       sp_axis=sp_axis, sp_mode=sp_mode, ep_axis=ep_axis,
-                      remat=remat, use_flash=use_flash, key=k_blocks,
+                      remat=remat, key=k_blocks,
                       segment_ids=seg, fsdp=fsdp)
     return out if cfg.n_experts > 0 else (out, jnp.zeros((), jnp.float32))
 
@@ -366,13 +365,13 @@ def gpt2_forward(params, input_ids, cfg: GPT2Config, *,
                  tp_axis: Optional[str] = None,
                  sp_axis: Optional[str] = None, sp_mode: str = "ring",
                  ep_axis: Optional[str] = None,
-                 remat: "bool | str" = False, use_flash: bool = False,
+                 remat: "bool | str" = False,
                  key=None, fsdp=None):
     """-> (logits, moe_aux). ``moe_aux`` is 0.0 for dense configs.
     ``key``: training-dropout key (None -> deterministic/eval)."""
     h, aux = gpt2_hidden(params, input_ids, cfg, tp_axis=tp_axis,
                          sp_axis=sp_axis, sp_mode=sp_mode, ep_axis=ep_axis,
-                         remat=remat, use_flash=use_flash, key=key,
+                         remat=remat, key=key,
                          fsdp=fsdp)
     return gpt2_logits(params, h, cfg), aux
 
@@ -381,11 +380,10 @@ def gpt2_apply(params, input_ids, cfg: GPT2Config, *,
                tp_axis: Optional[str] = None,
                sp_axis: Optional[str] = None, sp_mode: str = "ring",
                ep_axis: Optional[str] = None,
-               remat: "bool | str" = False, use_flash: bool = False):
+               remat: "bool | str" = False):
     logits, _ = gpt2_forward(params, input_ids, cfg, tp_axis=tp_axis,
                              sp_axis=sp_axis, sp_mode=sp_mode,
-                             ep_axis=ep_axis, remat=remat,
-                             use_flash=use_flash)
+                             ep_axis=ep_axis, remat=remat)
     return logits
 
 
@@ -648,7 +646,7 @@ def segment_ids_from_input(input_ids, cfg: GPT2Config, *,
 def gpt2_pipeline_fns(cfg: GPT2Config, *, tp_axis: Optional[str] = None,
                       sp_axis: Optional[str] = None, sp_mode: str = "ring",
                       ep_axis: Optional[str] = None,
-                      remat: "bool | str" = False, use_flash: bool = False,
+                      remat: "bool | str" = False,
                       compute_dtype=None):
     """(embed_fn, stage_fn, head_loss_fn) for parallel/pp.py.
 
@@ -679,7 +677,7 @@ def gpt2_pipeline_fns(cfg: GPT2Config, *, tp_axis: Optional[str] = None,
     def stage_fn(blocks_local, h, key=None):
         return gpt2_blocks(_cast_tree(blocks_local, compute_dtype), h, cfg,
                            tp_axis=tp_axis, sp_axis=sp_axis, sp_mode=sp_mode,
-                           ep_axis=ep_axis, remat=remat, use_flash=use_flash,
+                           ep_axis=ep_axis, remat=remat,
                            key=key)
 
     vp = cfg.vocab_parallel and tp_axis is not None
@@ -723,7 +721,7 @@ def _fsdp_info(cfg: "GPT2Config", tp_axis, ep_axis, fsdp_axis):
 
 
 def gpt2_model_spec(cfg: GPT2Config, *, remat: "bool | str" = False,
-                    use_flash: bool = False, sp_mode: str = "ring",
+                    sp_mode: str = "ring",
                     compute_dtype=None):
     from jax.sharding import PartitionSpec as P
 
@@ -739,13 +737,13 @@ def gpt2_model_spec(cfg: GPT2Config, *, remat: "bool | str" = False,
             h, aux = gpt2_hidden(p, input_ids, cfg, tp_axis=tp_axis,
                                  sp_axis=sp_axis, sp_mode=sp_mode,
                                  ep_axis=ep_axis, remat=remat,
-                                 use_flash=use_flash, key=key, fsdp=fsdp)
+                                 key=key, fsdp=fsdp)
             return clm_loss_chunked(p, h, labels, cfg,
                                     chunk=cfg.loss_chunk) + aux
         logits, aux = gpt2_forward(p, input_ids, cfg, tp_axis=tp_axis,
                                    sp_axis=sp_axis, sp_mode=sp_mode,
                                    ep_axis=ep_axis, remat=remat,
-                                   use_flash=use_flash, key=key,
+                                   key=key,
                                    fsdp=fsdp)
         if vp:
             with jax.named_scope("loss"):
@@ -761,7 +759,7 @@ def gpt2_model_spec(cfg: GPT2Config, *, remat: "bool | str" = False,
     def pipeline_fns(tp_axis=None, sp_axis=None, ep_axis=None):
         return gpt2_pipeline_fns(cfg, tp_axis=tp_axis, sp_axis=sp_axis,
                                  sp_mode=sp_mode, ep_axis=ep_axis,
-                                 remat=remat, use_flash=use_flash,
+                                 remat=remat,
                                  compute_dtype=compute_dtype)
 
     def batch_specs(batch_axes, sp_axis=None):

@@ -32,7 +32,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from quintnet_tpu.core.pytree import tree_stack
-from quintnet_tpu.nn.attention import (_masked_sdpa, apply_rope, repeat_kv,
+from quintnet_tpu.nn.attention import (_masked_sdpa, apply_rope,
+                                       local_attention, repeat_kv,
                                        rope_cos_sin, sdpa)
 from quintnet_tpu.nn.layers import (cast_floating, linear_init,
                                     quantized_matmul, rms_norm_apply,
@@ -390,7 +391,7 @@ def llama_mlp_residual(p, x, cfg: LlamaConfig, *,
 def llama_block_apply(p, x, cfg: LlamaConfig, *, cos, sin,
                       tp_axis: Optional[str] = None,
                       sp_axis: Optional[str] = None, sp_mode: str = "ring",
-                      use_flash: bool = False, ep_axis: Optional[str] = None,
+                      ep_axis: Optional[str] = None,
                       key=None, segment_ids=None):
     """Returns ``x`` for dense configs, ``(x, aux)`` for MoE (the
     stacked-scan runner's moe path accumulates aux per layer)."""
@@ -408,7 +409,6 @@ def llama_block_apply(p, x, cfg: LlamaConfig, *, cos, sin,
 
         if sp_mode == "ulysses":
             o = ulysses_attention(q, k, v, axis=sp_axis, causal=True,
-                                  use_flash=use_flash,
                                   segment_ids=segment_ids)
         elif sp_mode == "zigzag":
             o = zigzag_ring_attention(q, k, v, axis=sp_axis, causal=True,
@@ -416,12 +416,8 @@ def llama_block_apply(p, x, cfg: LlamaConfig, *, cos, sin,
         else:
             o = ring_attention(q, k, v, axis=sp_axis, causal=True,
                                segment_ids=segment_ids)
-    elif use_flash:
-        from quintnet_tpu.ops.flash_attention import flash_attention
-
-        o = flash_attention(q, k, v, causal=True, segment_ids=segment_ids)
     else:
-        o = sdpa(q, k, v, causal=True, segment_ids=segment_ids)
+        o = local_attention(q, k, v, causal=True, segment_ids=segment_ids)
 
     x = llama_attn_residual(p["attn"], x, o, tp_axis=tp_axis)
     x, aux = llama_mlp_residual(p, x, cfg, tp_axis=tp_axis,
@@ -562,7 +558,7 @@ def llama_hidden(params, input_ids, cfg: LlamaConfig, *,
                  tp_axis: Optional[str] = None,
                  sp_axis: Optional[str] = None, sp_mode: str = "ring",
                  ep_axis: Optional[str] = None,
-                 remat: "bool | str" = False, use_flash: bool = False,
+                 remat: "bool | str" = False,
                  fsdp=None):
     """-> (final hidden states, moe aux total — 0.0 for dense)."""
     b, s = input_ids.shape
@@ -582,7 +578,7 @@ def llama_hidden(params, input_ids, cfg: LlamaConfig, *,
     seg = segment_ids_from_input(input_ids, cfg, sp_axis=sp_axis)
     body = functools.partial(llama_block_apply, cfg=cfg, cos=cos, sin=sin,
                              tp_axis=tp_axis, sp_axis=sp_axis,
-                             sp_mode=sp_mode, use_flash=use_flash,
+                             sp_mode=sp_mode,
                              ep_axis=ep_axis, segment_ids=seg)
     out = stacked_blocks_apply(
         params["blocks"], h, num_heads=0, body_fn=body, remat=remat,
@@ -611,11 +607,10 @@ def llama_apply(params, input_ids, cfg: LlamaConfig, *,
                 tp_axis: Optional[str] = None,
                 sp_axis: Optional[str] = None, sp_mode: str = "ring",
                 ep_axis: Optional[str] = None,
-                remat: "bool | str" = False, use_flash: bool = False):
+                remat: "bool | str" = False):
     h, _aux = llama_hidden(params, input_ids, cfg, tp_axis=tp_axis,
                            sp_axis=sp_axis, sp_mode=sp_mode,
-                           ep_axis=ep_axis, remat=remat,
-                           use_flash=use_flash)
+                           ep_axis=ep_axis, remat=remat)
     return llama_logits(params, h, cfg)
 
 
@@ -677,7 +672,7 @@ def _validate_tp(cfg: LlamaConfig, tp: int, params):
 
 
 def llama_model_spec(cfg: LlamaConfig, *, remat: "bool | str" = False,
-                     use_flash: bool = False, sp_mode: str = "ring",
+                     sp_mode: str = "ring",
                      compute_dtype=None):
     from jax.sharding import PartitionSpec as P
 
@@ -699,7 +694,7 @@ def llama_model_spec(cfg: LlamaConfig, *, remat: "bool | str" = False,
         h, aux = llama_hidden(cast(params), input_ids, cfg,
                               tp_axis=tp_axis, sp_axis=sp_axis,
                               sp_mode=sp_mode, ep_axis=ep_axis,
-                              remat=remat, use_flash=use_flash, fsdp=fsdp)
+                              remat=remat, fsdp=fsdp)
         logits = llama_logits(cast(params), h, cfg)
         if cfg.vocab_parallel and tp_axis is not None:
             return clm_loss_vp(
@@ -738,7 +733,7 @@ def llama_model_spec(cfg: LlamaConfig, *, remat: "bool | str" = False,
             body = functools.partial(
                 llama_block_apply, cfg=cfg, cos=cos, sin=sin,
                 tp_axis=tp_axis, sp_axis=sp_axis, sp_mode=sp_mode,
-                use_flash=use_flash, ep_axis=ep_axis)
+                ep_axis=ep_axis)
             return stacked_blocks_apply(cast(blocks_local), h, num_heads=0,
                                         body_fn=body, remat=remat,
                                         moe_args=cfg.moe_args,

@@ -8,9 +8,11 @@ utils/model.py:45-110 without the causal mask).
 
 Under shard_map the qkv weight arrives column-sharded [D, 3D/tp] and the
 proj weight row-sharded [D/tp, D]; with ``tp_axis=None`` the same code is
-plain single-device MHA. The inner attention dispatches to a Pallas flash
-kernel on TPU for long sequences (ops/flash_attention.py) and to the
-reference-equivalent jnp softmax path otherwise.
+plain single-device MHA. The inner attention of a local (not sequence-
+parallel) call is chosen from what the call can observe
+(:func:`local_attention_path`): a fused Pallas kernel on a TPU at the
+shapes it was measured to win, the reference-equivalent jnp softmax
+(:func:`sdpa`) otherwise.
 """
 
 from __future__ import annotations
@@ -289,6 +291,104 @@ def sdpa(q, k, v, *, causal: bool, softmax_dtype=jnp.float32,
     return jnp.einsum("bhst,bhtd->bhsd", probs, v)
 
 
+# ---------------------------------------------------------------------
+# Which local attention runs. The numbers are one v5e chip's, bf16,
+# causal, Dh 64 unless said; "a layer" = forward + backward + the
+# forward again (what a block under remat costs), from
+# tools/attn_microbench.py (my chip runs, PR 32; PERF.md section 6).
+# ---------------------------------------------------------------------
+# At [32, 12, 1024, 64] a layer is 6.6 ms in the resident geometry
+# against 20.9 for sdpa; at [64, 12, 512, 64] 5.8 against 11.0 (not
+# causal: 6.4 against 11.0, and 8.7 against 20.8 at 1,024); at [128,
+# 12, 256, 64] 6.3 against 5.9: sdpa keeps everything under 512.
+KERNEL_MIN_SEQ = 512
+# The head widths measured: 64 (the cells'), and 128 at [32, 6, 1024,
+# 128] 2.1 ms against 10.9. A narrower head pads its lane row further;
+# no model here has one, so none was measured and none is sent.
+KERNEL_HEAD_DIMS = (64, 128)
+# Resident beats streamed wherever both ran: 6.6 against 10.1 ms at
+# 1,024 (streamed at its best tile, 1,024), 9.1 against 24.9 at [16, 12,
+# 2048, 64], 7.5 against 18.9 at [4, 12, 4096, 64] (sdpa: 40.6, 67.2).
+# At 8,192 the compiler refuses it (146 MiB of 128 MiB VMEM) and
+# streamed runs a layer in 32 ms.
+RESIDENT_MAX_SEQ = 4096
+# Its tile, at the cells' shape: 256 6.57 ms, 128 7.37, 512 6.63, 1,024
+# 7.77 (a wider tile wastes more of the diagonal, a narrower one pays
+# the row statistics more often); within 2% of 512 at 2,048 and 4,096.
+RESIDENT_TILE = 256
+# The streamed geometry's: 512 divides every S it is sent; 1,024 is 13%
+# faster at 8,192 (32.2 against 37.0 ms) and divides fewer.
+STREAMED_TILE = 512
+# Where sdpa's [B, H, S, S] f32 scores stop being affordable: 0.8 GB a
+# batch row of 12 heads at 4,096, a layer at 67 ms for 4 rows. The
+# blockwise path holds O(S) a row.
+BLOCKWISE_MIN_SEQ = 4096
+
+
+def local_attention_path(*, backend: str, seq: int, head_dim: int,
+                         dropout: bool) -> str:
+    """Which implementation a local [B, H, seq, head_dim] attention call
+    takes: ``"resident"`` or ``"streamed"`` (the two geometries of the
+    one fused kernel, ops/pallas_attention.py), ``"sdpa"``, or
+    ``"blockwise"``. A pure function of what the call can observe; no
+    caller passes a preference. (Causal or not decides nothing: the
+    kernel won both ways where it was measured.)"""
+    resident = seq <= RESIDENT_MAX_SEQ
+    tile = RESIDENT_TILE if resident else STREAMED_TILE
+    if (backend == "tpu" and not dropout and head_dim in KERNEL_HEAD_DIMS
+            and seq >= KERNEL_MIN_SEQ and seq % tile == 0):
+        return "resident" if resident else "streamed"
+    return "blockwise" if seq >= BLOCKWISE_MIN_SEQ else "sdpa"
+
+
+def _attend(path: str, q, k, v, segment_ids, *, causal, pdrop, key):
+    if path == "sdpa":
+        return sdpa(q, k, v, causal=causal, pdrop=pdrop, key=key,
+                    segment_ids=segment_ids)
+    if path == "blockwise":
+        from quintnet_tpu.ops.flash_attention import blockwise_attention
+
+        return blockwise_attention(q, k, v, causal=causal, pdrop=pdrop,
+                                   key=key, segment_ids=segment_ids)
+    from quintnet_tpu.ops import pallas_attention
+
+    if path == "resident":
+        return pallas_attention.resident_flash_attention(
+            q, k, v, causal, RESIDENT_TILE, RESIDENT_TILE,
+            segment_ids=segment_ids)
+    return pallas_attention.pallas_flash_attention(
+        q, k, v, causal, STREAMED_TILE, STREAMED_TILE,
+        segment_ids=segment_ids)
+
+
+def local_attention(q, k, v, *, causal: bool, pdrop: float = 0.0, key=None,
+                    segment_ids=None):
+    """[B, H, S, Dh] -> [B, H, S, Dh] over a whole (local) sequence, by
+    :func:`local_attention_path`. Every path masks ``segment_ids``
+    [B, S]; the kernels carry no PRNG, so a call that asks for
+    probability dropout never takes them.
+
+    The backend is the one the program is LOWERED for: where this
+    process's default backend would choose otherwise than a TPU (a CPU
+    process compiling for a described chip — tests/test_chip_bringup.py,
+    benchmarks/tools/aot_sizes.py), both choices are traced under
+    ``lax.platform_dependent`` and the lowering keeps its own."""
+    observed = dict(seq=q.shape[-2], head_dim=q.shape[-1],
+                    dropout=key is not None and pdrop > 0.0)
+    here = local_attention_path(backend=jax.default_backend(), **observed)
+    on_tpu = local_attention_path(backend="tpu", **observed)
+    run = dict(causal=causal, pdrop=pdrop, key=key)
+    if here == on_tpu:
+        return _attend(here, q, k, v, segment_ids, **run)
+    args = (q, k, v) if segment_ids is None else (q, k, v, segment_ids)
+
+    def branch(path):
+        return lambda q, k, v, seg=None: _attend(path, q, k, v, seg, **run)
+
+    return lax.platform_dependent(*args, tpu=branch(on_tpu),
+                                  default=branch(here))
+
+
 def mha_apply(
     p,
     x,
@@ -298,7 +398,6 @@ def mha_apply(
     tp_axis: Optional[str] = None,
     sp_axis: Optional[str] = None,
     sp_mode: str = "ring",
-    use_flash: bool = False,
     return_kv: bool = False,
     attn_pdrop: float = 0.0,
     resid_pdrop: float = 0.0,
@@ -314,15 +413,16 @@ def mha_apply(
     have. ``sp_mode`` picks the algorithm: 'ring' (K/V rotation via
     ppermute, ops/ring_attention.py), 'zigzag' (load-balanced causal
     ring — ~2x less compute at high sp) or 'ulysses' (head-scatter
-    all-to-all, ops/ulysses_attention.py; composes with flash).
+    all-to-all, ops/ulysses_attention.py). Without ``sp_axis`` the path
+    is :func:`local_attention`'s choice.
 
     ``return_kv=True`` additionally returns the per-head (k, v)
     projections [B, H, S, Dh] — the prefill half of KV-cache decoding
     (models/gpt2_generate.py).
 
     Dropout (training only — pass ``key``): ``attn_pdrop`` on the
-    attention probabilities — supported on EVERY path (plain sdpa, the
-    flash blockwise fallback, ring, ulysses; the reference gets the
+    attention probabilities — supported on EVERY path (plain sdpa,
+    blockwise, ring, ulysses; the reference gets the
     same coverage from sdpa's dropout_p, gpt2_attention.py:156-161) —
     and ``resid_pdrop`` after the output projection, applied post-psum
     so the mask agrees across tp ranks (gpt2_attention.py:156-180).
@@ -330,7 +430,7 @@ def mha_apply(
     head block — head-group correlation, accepted for mask/key locality.
 
     ``segment_ids``: packed-document isolation masking on every path.
-    Local paths (sdpa + flash incl. the Pallas kernel) take [B, S]
+    Local paths (sdpa, blockwise, the Pallas kernels) take [B, S]
     directly; under ``sp_axis`` pass this rank's [B, S_local] slice of
     the GLOBAL id vector (models/gpt2.py segment_ids_from_input
     derives it sp-aware) — ring/zigzag rotate the ids alongside their
@@ -350,7 +450,6 @@ def mha_apply(
                 ulysses_attention
 
             o = ulysses_attention(q, k, v, axis=sp_axis, causal=causal,
-                                  use_flash=use_flash,
                                   segment_ids=segment_ids, **drop_kw)
         elif sp_axis is not None and sp_mode == "zigzag":
             from quintnet_tpu.ops.ring_attention import \
@@ -368,14 +467,9 @@ def mha_apply(
 
             o = ring_attention(q, k, v, axis=sp_axis, causal=causal,
                                segment_ids=segment_ids, **drop_kw)
-        elif use_flash:
-            from quintnet_tpu.ops.flash_attention import flash_attention
-
-            o = flash_attention(q, k, v, causal=causal,
-                                segment_ids=segment_ids, **drop_kw)
         else:
-            o = sdpa(q, k, v, causal=causal, pdrop=attn_pdrop,
-                     key=k_attn, segment_ids=segment_ids)
+            o = local_attention(q, k, v, causal=causal,
+                                segment_ids=segment_ids, **drop_kw)
 
     y = _proj_out(p, o, tp_axis)
     if k_resid is not None and resid_pdrop > 0.0:

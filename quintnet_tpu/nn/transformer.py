@@ -59,7 +59,6 @@ def block_apply(
     tp_axis: Optional[str] = None,
     sp_axis: Optional[str] = None,
     sp_mode: str = "ring",
-    use_flash: bool = False,
     moe_args: Optional[MoEArgs] = None,
     ep_axis: Optional[str] = None,
     attn_pdrop: float = 0.0,
@@ -84,7 +83,6 @@ def block_apply(
             tp_axis=tp_axis,
             sp_axis=sp_axis,
             sp_mode=sp_mode,
-            use_flash=use_flash,
             attn_pdrop=attn_pdrop,
             resid_pdrop=resid_pdrop,
             key=k_attn,
@@ -119,7 +117,6 @@ def stacked_blocks_apply(
     tp_axis: Optional[str] = None,
     sp_axis: Optional[str] = None,
     sp_mode: str = "ring",
-    use_flash: bool = False,
     remat: "bool | str" = False,
     moe_args: Optional[MoEArgs] = None,
     ep_axis: Optional[str] = None,
@@ -179,7 +176,6 @@ def stacked_blocks_apply(
         tp_axis=tp_axis,
         sp_axis=sp_axis,
         sp_mode=sp_mode,
-        use_flash=use_flash,
         moe_args=moe_args,
         ep_axis=ep_axis,
         attn_pdrop=attn_pdrop,

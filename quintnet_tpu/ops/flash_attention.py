@@ -1,15 +1,12 @@
-"""Flash attention: Pallas TPU kernel with an exact jnp fallback.
+"""Blockwise attention: the online-softmax recurrence in pure jnp.
 
-The reference has no fused attention of its own — it calls
-``F.scaled_dot_product_attention`` (gpt2_attention.py:156-161) and lets
-cuDNN pick a kernel. On TPU the analogue is a Pallas kernel that tiles
-Q/K/V through VMEM with an online softmax so the [S, S] score matrix
-never materialises in HBM.
-
-This module is the dispatch surface: it selects the hand-tiled Pallas
-kernel (ops/pallas_attention.py) on TPU backends and otherwise runs the
-same online-softmax recurrence in pure jnp (numerically identical to
-softmax(QK^T)V, O(S) live memory under scan).
+Numerically identical to softmax(QK^T)V with O(S) live memory under
+scan: the path ``nn/attention.local_attention`` takes where the [S, S]
+scores do not fit and the Pallas kernels (ops/pallas_attention.py)
+cannot run — a ragged sequence, probability dropout (the kernels carry
+no PRNG: ``pltpu.prng_*`` has no interpret-mode lowering in this jax,
+so an in-kernel mask could not be tested here) — and the reference the
+kernels' tests compare with.
 """
 
 from __future__ import annotations
@@ -87,7 +84,7 @@ def blockwise_attention(q, k, v, *, causal: bool,
     the reference gets this from sdpa's dropout_p in every config
     (gpt2_attention.py:156-161); here the fused paths support it too.
     ``segment_ids``: [B, S] packed-document ids; cross-segment
-    attention is masked (see flash_attention)."""
+    attention is masked."""
     b, h, s, d = q.shape
     scale = 1.0 / math.sqrt(d)
     block_q = min(block_q, s)
@@ -131,59 +128,3 @@ def blockwise_attention(q, k, v, *, causal: bool,
     f = jax.vmap(f, in_axes=(0, None, 0, 0, 0, 0, 0))          # batch
     out = f(qb, jnp.arange(nq), keys, seg_qb, kb, vb, seg_kb)
     return out.reshape(b, h, nq * block_q, d)[:, :, :s].astype(q.dtype)
-
-
-PALLAS_MIN_SEQ = 4096  # crossover measured on v5e-lite with the 512x512
-# default tiles (artifacts/flash_r04_tiles.json, round 4): sdpa wins at
-# seq 2048 (0.74x), the kernel wins 2.07x at 4096 and 23-25x at 8192
-# (~25 TFLOP/s fwd+bwd — sdpa falls off a cliff there spilling the S^2
-# scores to HBM). Tile size is the dominant kernel knob: the old 128x128
-# default measured only 6.7x at 8192 (the round-2 judge's 6.3x; an even
-# earlier 38x claim was forward-only extrapolation and wrong).
-
-# 512x512 tiles: best measured across seq 4096-8192 (within 7% of the
-# 1024x1024 best at 8192 while dividing every seq >= 512); at Dh=64 the
-# QK^T contraction half-fills the 128-wide MXU regardless, so wider
-# s-tiles amortise that bound over more columns.
-PALLAS_BLOCK_Q = 512
-PALLAS_BLOCK_K = 512
-
-
-def flash_attention(q, k, v, *, causal: bool = False,
-                    block_q: int = PALLAS_BLOCK_Q,
-                    block_k: int = PALLAS_BLOCK_K,
-                    min_seq_for_pallas: int = PALLAS_MIN_SEQ,
-                    pdrop: float = 0.0, key=None, segment_ids=None):
-    """[B, H, S, Dh] fused attention. Pallas TPU kernel when on a TPU
-    backend, the sequence divides the block size, and S is past the
-    measured crossover; exact blockwise jnp otherwise.
-
-    ``segment_ids``: optional [B, S] int32 packed-document ids —
-    cross-segment attention is masked on EVERY path, including inside
-    the Pallas kernel, so PackedLMDataset training with document
-    isolation keeps the fused kernel (round-4 verdict item: segments
-    previously forced the jnp fallback).
-
-    ``pdrop``/``key``: attention-prob dropout. The hand-tiled Pallas
-    kernel carries no PRNG, so a dropout-enabled call routes to the
-    blockwise jnp path (still O(S) live memory under scan) — correctness
-    of the requested regularisation wins over kernel speed; benches and
-    inference never pass a key so they keep the fast path. (In-kernel
-    dropout via pltpu.prng_seed/prng_random_bits was evaluated and
-    deliberately NOT shipped: those primitives have no CPU/interpret
-    lowering in this jax version, so the code path would be untestable
-    in CI — against this repo's golden-test standard — and attention
-    dropout is off in every throughput config anyway.)"""
-    s = q.shape[-2]
-    bq, bk = min(block_q, s), min(block_k, s)
-    use_drop = key is not None and pdrop > 0.0
-    if (jax.default_backend() == "tpu" and s % bq == 0 and s % bk == 0
-            and s >= min_seq_for_pallas and not use_drop):
-        from quintnet_tpu.ops.pallas_attention import pallas_flash_attention
-
-        return pallas_flash_attention(q, k, v, causal, bq, bk,
-                                      segment_ids=segment_ids)
-    return blockwise_attention(q, k, v, causal=causal,
-                               block_q=block_q, block_k=block_k,
-                               pdrop=pdrop, key=key,
-                               segment_ids=segment_ids)

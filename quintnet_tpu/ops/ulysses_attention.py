@@ -33,7 +33,6 @@ from quintnet_tpu.nn import attention as _attn
 
 
 def ulysses_attention(q, k, v, *, axis: str, causal: bool = False,
-                      use_flash: bool = False,
                       pdrop: float = 0.0, key=None, segment_ids=None):
     """Attention over sequence-sharded inputs via two all-to-alls.
 
@@ -49,7 +48,7 @@ def ulysses_attention(q, k, v, *, axis: str, causal: bool = False,
     ``segment_ids`` [B, S_local]: this rank's slice of the GLOBAL
     packed-segment ids — after the head-scatter every rank holds the
     full sequence, so one cheap [B, S] all-gather reassembles the id
-    vector and the inner attention (sdpa or the Pallas flash kernel)
+    vector and the inner attention (``nn/attention.local_attention``)
     masks cross-segment pairs natively.
     """
     sp = lax.axis_size(axis)
@@ -77,16 +76,8 @@ def ulysses_attention(q, k, v, *, axis: str, causal: bool = False,
     if key is not None and pdrop > 0.0:
         k_local = jax.random.fold_in(key, lax.axis_index(axis))
 
-    if use_flash:
-        from quintnet_tpu.ops.flash_attention import flash_attention
-
-        of = flash_attention(qf, kf, vf, causal=causal,
-                             pdrop=pdrop, key=k_local,
-                             segment_ids=seg_full)
-    else:
-        of = _attn.sdpa(qf, kf, vf, causal=causal,
-                        pdrop=pdrop, key=k_local,
-                        segment_ids=seg_full)
+    of = _attn.local_attention(qf, kf, vf, causal=causal, pdrop=pdrop,
+                               key=k_local, segment_ids=seg_full)
 
     # gather heads back, re-scatter sequence: [B, H_local, S_local, Dh]
     return cc.all_to_all(of, axis, split_dim=2, concat_dim=1)

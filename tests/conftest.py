@@ -51,9 +51,6 @@ SLOW_FILES = {
     "test_dropout.py",    # 13 tests, 100 s: seed discipline x strategies
     "test_fsdp.py",       # 12 tests, 92 s: ZeRO-3 golden matrix (spec-
                           # transform + guard tests promoted fast)
-    "test_segments.py",   # 20 tests, 92 s: packed-segment matrix incl.
-                          # sp modes (sdpa/host-helper goldens promoted)
-    "test_gpt2.py",       # 10 tests, 85 s: 3D training goldens + HF import
     "test_lora.py",       # 8 tests, 36 s: adapter goldens (identity +
                           # save/load promoted fast)
     "test_generate.py",   # 11 tests, 29 s: KV-cache + tp decode goldens

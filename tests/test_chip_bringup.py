@@ -30,6 +30,7 @@ checked without a chip (PR 21, the bring-up round).
 
 import importlib
 import json
+import math
 import os
 import subprocess
 import sys
@@ -47,15 +48,14 @@ H, D, BS, M, NB, ROWS = 12, 64, 16, 64, 512, 8
 
 
 @pytest.fixture(scope="module")
-def chip():
-    """A described (not attached) v5e chip to compile for, with the
+def topo():
+    """A described (not attached) v5e:2x2 to compile for, with the
     persistent compile cache off around the compiles — a topology
     compile is written to the cache but cannot be read back without a
     chip, so the next one would warn and compile again."""
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
-    from jax.sharding import SingleDeviceSharding
 
     try:
         topo = topologies.get_topology_desc(platform="tpu",
@@ -65,9 +65,17 @@ def chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def chip(topo):
+    """One chip of it."""
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _compile(fn, *shapes):
@@ -82,23 +90,109 @@ def _compile(fn, *shapes):
 @pytest.mark.parametrize("segments", [False, True],
                          ids=["causal", "causal+segments"])
 def test_flash_fwd_bwd_compiles_for_v5e(chip, segments):
+    from quintnet_tpu.nn.attention import STREAMED_TILE
     from quintnet_tpu.ops import pallas_flash_attention
-    from quintnet_tpu.ops.flash_attention import (PALLAS_BLOCK_K,
-                                                  PALLAS_BLOCK_Q)
 
     S = 4096
     qkv = jax.ShapeDtypeStruct((1, H, S, D), jnp.bfloat16, sharding=chip)
     seg = jax.ShapeDtypeStruct((1, S), jnp.int32, sharding=chip)
 
     def loss(q, k, v, s=None):
-        o = pallas_flash_attention(q, k, v, True, PALLAS_BLOCK_Q,
-                                   PALLAS_BLOCK_K, segment_ids=s)
+        o = pallas_flash_attention(q, k, v, True, STREAMED_TILE,
+                                   STREAMED_TILE, segment_ids=s)
         return jnp.sum(o.astype(jnp.float32))
 
     text = _compile(jax.grad(loss, argnums=(0, 1, 2)),
                     *((qkv, qkv, qkv, seg) if segments
                       else (qkv, qkv, qkv)))
     assert text.count("tpu_custom_call") >= 3     # fwd, dkv, dq
+
+
+# ---------------------------------------------------------------------
+# PR 32: the resident geometry at the training cells' local shapes, and
+# the cells' own train steps with it inside
+# ---------------------------------------------------------------------
+@pytest.mark.parametrize("shape,segments", [
+    ((32, 12, 1024, 64), False),      # gpt2-124m.train-packed-s1024
+    ((8, 10, 1024, 64), False),       # gpt2-large.train-dp2tp2-s1024, local
+    ((8, 10, 1024, 64), True),
+    ((4, 12, 2048, 64), False),       # RESIDENT_MAX_SEQ
+    ((4, 6, 1024, 128), False),       # the other head width the chooser admits
+], ids=["124m", "large-local", "large-local+segments", "s2048", "dh128"])
+def test_local_attention_is_the_resident_kernel_on_v5e(chip, shape, segments):
+    """``local_attention`` itself, from a CPU process: the lowering for
+    the described chip keeps the kernel branch (forward + one backward
+    pass), a lowering for the CPU the ``sdpa`` branch."""
+    from quintnet_tpu.nn.attention import local_attention
+
+    b, _, s, _ = shape
+    qkv = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=chip)
+    seg = jax.ShapeDtypeStruct((b, s), jnp.int32, sharding=chip)
+
+    def loss(q, k, v, ids=None):
+        o = local_attention(q, k, v, causal=True, segment_ids=ids)
+        return jnp.sum(o.astype(jnp.float32))
+
+    grad = jax.grad(loss, argnums=(0, 1, 2))
+    args = (qkv, qkv, qkv, seg) if segments else (qkv, qkv, qkv)
+    text = _compile(grad, *args)
+    assert text.count("tpu_custom_call") == 2     # fwd, bwd
+    assert f"{s},{s}]" not in text                # no [.., S, S] buffer
+    if shape[0] == 4:                             # a CPU lowering, small
+        cpu = jax.jit(grad).lower(*(jnp.zeros(a.shape, a.dtype)
+                                    for a in args)).as_text()
+        assert "tpu_custom_call" not in cpu
+
+
+@pytest.mark.parametrize("workload,calls", [
+    ("gpt2-124m.train-packed-s1024", 3),
+    ("gpt2-large.train-dp2tp2-s1024", 3),
+])
+def test_train_cells_plan_no_score_tensor_on_v5e(topo, workload, calls):
+    """The cells' own jitted step (``benchmarks/drivers/train.build``,
+    as ``benchmarks/tools/aot_sizes.py`` compiles it) for the described
+    chip(s): a layer body holds the kernel three times (forward, and
+    under remat forward again + backward), no ``[B, H, 1024, 1024]``
+    scores or probabilities anywhere, and — on the mesh — the kernel
+    traces inside ``shard_map`` with the tp all-reduces still there."""
+    from jax.sharding import NamedSharding
+
+    from quintnet_tpu.parallel.train_step import opt_state_specs
+
+    from benchmarks.lib import harness
+
+    bench = harness.Bench(REPO)
+    cell = bench.cell(workload)
+    t = cell.spec["trainer"]
+    n_dev = math.prod(t["mesh_dim"])
+    _, model, strategy, trainer = bench.driver("train").build(
+        cell.spec, cell.config, 0, devices=topo.devices[:n_dev])
+    mesh = strategy.mesh
+    p_specs = strategy.param_specs(model)
+    params = jax.eval_shape(
+        lambda k: model.to_tp_layout(model.init(k), mesh.shape.get("tp", 1)),
+        jax.random.key(0))
+    opt = jax.eval_shape(trainer.optimizer.init, params)
+    o_specs = opt_state_specs(trainer.optimizer, params, p_specs)
+    ids = jax.ShapeDtypeStruct((int(t["batch"]), 1024), jnp.int32)
+
+    def described(tree, specs):
+        return jax.tree.map(
+            lambda x, sp: jax.ShapeDtypeStruct(
+                x.shape, x.dtype, sharding=NamedSharding(mesh, sp)),
+            tree, specs)
+
+    step = trainer.step_fn.fn
+    compiled = jax.jit(lambda p, o, b: step(p, o, b, 0),
+                       donate_argnums=(0, 1)).lower(
+        described(params, p_specs), described(opt, o_specs),
+        described((ids, ids), strategy.batch_partition_specs(model))
+    ).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == calls
+    assert "1024,1024]" not in text
+    if n_dev > 1:
+        assert "all-reduce" in text
 
 
 # ---------------------------------------------------------------------

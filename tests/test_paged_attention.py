@@ -1023,7 +1023,7 @@ def test_ops_import_surface():
     empty ``__init__`` belied its own docstring)."""
     import quintnet_tpu.ops as ops
 
-    expected = {"flash_attention", "blockwise_attention",
+    expected = {"resident_flash_attention", "blockwise_attention",
                 "pallas_flash_attention", "paged_attention",
                 "paged_quant_window_update", "ring_attention",
                 "zigzag_ring_attention", "ulysses_attention"}
