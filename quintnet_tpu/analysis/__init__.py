@@ -32,6 +32,7 @@ from quintnet_tpu.analysis.jaxpr_audit import (
     dtype_report,
     gathered_view_gathers,
     pool_scan_operands,
+    store_reads,
     view_head_splits,
     widened_view_dots,
 )
@@ -75,6 +76,7 @@ __all__ = [
     "dtype_report",
     "gathered_view_gathers",
     "pool_scan_operands",
+    "store_reads",
     "view_head_splits",
     "widened_view_dots",
     "RULES",

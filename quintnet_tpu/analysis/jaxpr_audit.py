@@ -513,3 +513,31 @@ def pool_scan_operands(fn: Callable, *args, pool_shape: Tuple[int, ...],
 
     _walk_skip_kernels(closed.jaxpr, visit)
     return found
+
+
+def store_reads(fn: Callable, *args, store_shape: Tuple[int, ...],
+                **kwargs) -> List[Tuple[int, ...]]:
+    """The shapes a program READS out of a per-sequence buffer of
+    ``store_shape`` (a window store's ``wk``: ``[L_w, (slots + 1) *
+    ring, F]``): the result shape of every ``dynamic_slice``, ``slice``
+    or ``gather`` eqn whose operand 0 is store-shaped, one entry an
+    eqn. A sliding-window layer must read ``rows * ring`` rows of ONE
+    layer — ``(1, rows * ring, F)`` — and never the table's width or
+    the whole store (nn/attention.window_gather).
+
+    Structural like :func:`gathered_view_gathers` (a scan body counts
+    once; ``pallas_call`` interiors are skipped)."""
+    closed = jax.make_jaxpr(fn)(*args, **kwargs)
+    store_shape = tuple(store_shape)
+    found: List[Tuple[int, ...]] = []
+
+    def visit(eqn):
+        if eqn.primitive.name not in ("dynamic_slice", "slice", "gather"):
+            return
+        op = eqn.invars[0]
+        if tuple(getattr(getattr(op, "aval", None), "shape", ())) \
+                == store_shape:
+            found.append(tuple(eqn.outvars[0].aval.shape))
+
+    _walk_skip_kernels(closed.jaxpr, visit)
+    return found

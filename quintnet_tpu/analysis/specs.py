@@ -307,6 +307,44 @@ def expected_serve_latent_moe() -> Dict[str, object]:
             "widened_view_dots": 0, "gathered_view_gathers": 2}
 
 
+def expected_serve_window_moe(*, full_runs: int, sliding_runs: int,
+                              rows: int, ring: int, width: int,
+                              chunk: bool) -> Dict[str, object]:
+    """What one compiled serving program of a WINDOW family with the
+    dropless router (serve/families.laguna_family) reads under the
+    structural audits of analysis/jaxpr_audit.py, on one device — the
+    only place it runs: the engine refuses it a mesh. ``full_runs`` /
+    ``sliding_runs``: the model's runs of consecutive full / sliding
+    layers (a layer scan each: models/laguna.LagunaConfig.runs);
+    ``rows``: the program's sequence rows (``max_slots``, 1 for a
+    prefill bucket); ``width``: the store's lane width; ``chunk``: a
+    prefill bucket (True) or the decode / a verify program.
+
+    - ``census``: no collective at all (every expert is held here).
+    - ``pool_scan_operands`` 0, for the block pool AND the window
+      store: all four buffers ride every run's scan carry.
+    - ``gathered_view_gathers`` 2 a full run (k and v, in its scan's
+      body) in decode and verify and NONE in a sliding run's body: a
+      sliding layer never gathers at the table's width; 0 in a prefill
+      bucket, which reads a global layer's cache a block of keys at a
+      time (nn/attention._paged_attend_key_blocked), not the table.
+    - ``store_reads``: a sliding layer reads its rows' rings of ONE
+      layer, k and v, ``(1, rows * ring, width)`` each — ``ring`` =
+      ``sliding_window + block_size`` positions a row, whatever the
+      sequences' lengths.
+    - ``view_head_splits`` 0 in decode and verify, for 48 and 64 query
+      heads alike (both contract the cached rows as stored, heads on
+      the lane diagonal: nn/attention._lane_diag_sdpa) on a bf16 pool;
+      a prefill bucket splits each key block it gathers, never a view
+      of the table's width: 0 there too.
+    - ``widened_view_dots`` 0."""
+    return {"census": {}, "pool_scan_operands": 0,
+            "gathered_view_gathers": 0 if chunk else 2 * full_runs,
+            "store_reads": [(1, rows * ring, width)] * (2 * sliding_runs),
+            "view_head_splits": 0,
+            "widened_view_dots": 0}
+
+
 def expected_serve_sp_prefill(n_layers: int, sp: int, *,
                               sp_axis: str = "sp") -> CensusDict:
     """One compiled SEQUENCE-PARALLEL prefill bucket (long-context

@@ -39,24 +39,61 @@ def mha_init(key, dim: int, *, qkv_bias: bool = True, dtype=jnp.float32):
 
 
 def rope_cos_sin(positions, head_dim: int, *, theta: float = 10000.0,
-                 inv_freq=None):
+                 inv_freq=None, scale: float = 1.0):
     """Rotary tables for integer ``positions`` [...]: (cos, sin), each
     [..., head_dim] with the half-dim frequencies duplicated (HF Llama
     layout: the i-th and (i+d/2)-th lanes share a frequency).
     ``inv_freq`` overrides the plain 1/theta^(2i/d) frequencies (rope
-    scaling — models/llama.py llama3_scaled_inv_freq)."""
+    scaling — models/llama.py llama3_scaled_inv_freq,
+    :func:`yarn_inv_freq`); ``scale`` multiplies both tables (YaRN's
+    ``attention_factor``). ``head_dim`` is the number of features that
+    ROTATE: fewer than the head has under partial rotation
+    (:func:`apply_rope`)."""
     if inv_freq is None:
         inv_freq = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, jnp.float32)
                                     / head_dim))            # [d/2]
     ang = positions.astype(jnp.float32)[..., None] * inv_freq
     ang = jnp.concatenate([ang, ang], axis=-1)              # [..., d]
-    return jnp.cos(ang), jnp.sin(ang)
+    if scale == 1.0:
+        return jnp.cos(ang), jnp.sin(ang)
+    return jnp.cos(ang) * scale, jnp.sin(ang) * scale
+
+
+def yarn_inv_freq(dim: int, *, theta: float, factor: float,
+                  original_max: int, beta_fast: float = 32.0,
+                  beta_slow: float = 1.0):
+    """YaRN's frequencies (Peng et al. 2023, as Hugging Face computes
+    them) over the ``dim`` rotating features: ``extrap = theta^(-2i /
+    dim)`` where a feature turns more than ``beta_fast`` times within
+    the ``original_max`` positions, ``interp = extrap / factor`` where
+    it turns fewer than ``beta_slow``, a linear ramp between the two
+    correction dimensions (floor and ceil of ``dim ln(original_max /
+    (2 pi beta)) / (2 ln theta)``, clamped to ``[0, dim - 1]``). [dim/2]
+    f32; the tables' ``attention_factor`` is :func:`rope_cos_sin`'s
+    ``scale``."""
+    def correction_dim(turns):
+        return (dim * math.log(original_max / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    extrap = 1.0 / (theta ** (jnp.arange(0, dim, 2, jnp.float32) / dim))
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    return (extrap / factor) * ramp + extrap * (1.0 - ramp)
 
 
 def apply_rope(x, cos, sin):
     """Rotate [B, H, S, Dh] by per-position tables [S, Dh] (or any
-    broadcastable shape). HF rotate_half convention."""
-    d = x.shape[-1]
+    broadcastable shape). HF rotate_half convention. Tables NARROWER
+    than ``Dh`` rotate the first ``rot`` features (lane i pairs with
+    lane i + rot/2) and pass the rest through: partial rotation."""
+    d, rot = x.shape[-1], cos.shape[-1]
+    if rot < d:
+        return jnp.concatenate(
+            [apply_rope(x[..., :rot], cos, sin), x[..., rot:]], axis=-1)
     x1, x2 = x[..., : d // 2], x[..., d // 2:]
     rotated = jnp.concatenate([-x2, x1], axis=-1)
     return (x.astype(jnp.float32) * cos + rotated.astype(jnp.float32)
@@ -139,6 +176,16 @@ _MIN_DOT_ROWS = 8
 # model's 160) on the diagonal and every prefill bucket of a model
 # with 16 heads or more on the split.
 _MAX_DIAG_ROWS = 256
+
+
+def _as_gathered(q, store, max_diag_rows: Optional[int] = None) -> bool:
+    """Whether a program contracts its cached rows as stored, heads on
+    the lane diagonal (:func:`_lane_diag_sdpa`): the rows are stored in
+    a float narrower than ``q`` [S, Hq, rows, Dh] and the query rows a
+    sequence are few (``max_diag_rows``; None: :data:`_MAX_DIAG_ROWS`,
+    read when the program is traced)."""
+    limit = _MAX_DIAG_ROWS if max_diag_rows is None else max_diag_rows
+    return _stored_narrower(q, store) and q.shape[1] * q.shape[2] <= limit
 
 
 def _masked_sdpa(q, k_all, v_all, valid, *, page: Optional[int] = None,
@@ -522,6 +569,16 @@ def _write_index(positions, lens, block_tables, block_size: int):
                      0).reshape(S * P)
 
 
+def _put_rows(pool, layer, idx, x):
+    """Scatter a run ``x`` [S, H, P, Dh] into ``layer`` of a buffer of
+    pool rows at the flat slots ``idx`` [S * P]: heads flattened, pad
+    lanes zero, the buffer's dtype."""
+    S, H, P, Dh = x.shape
+    rows = _pool_rows(x.transpose(0, 2, 1, 3).reshape(S * P, H, Dh),
+                      pool.shape[-1])
+    return pool.at[layer, idx].set(rows.astype(pool.dtype))
+
+
 def paged_write(k_pool, v_pool, layer, k, v, positions, lens, *,
                 block_tables, block_size: int):
     """Write every row's run of (k, v) into the PAGED pool at ``layer``,
@@ -536,16 +593,10 @@ def paged_write(k_pool, v_pool, layer, k, v, positions, lens, *,
     nobody reads (their scores are masked and the engine drops their
     outputs). Duplicate slot-0 scatters are benign for the same
     reason."""
-    S, H, P, Dh = k.shape
     with jax.named_scope("kv_write"):
         idx = _write_index(positions, lens, block_tables, block_size)
-
-        def put(pool, x):
-            rows = _pool_rows(x.transpose(0, 2, 1, 3).reshape(S * P, H, Dh),
-                              pool.shape[-1])
-            return pool.at[layer, idx].set(rows.astype(pool.dtype))
-
-        return put(k_pool, k), put(v_pool, v)
+        return (_put_rows(k_pool, layer, idx, k),
+                _put_rows(v_pool, layer, idx, v))
 
 
 def paged_gather(pool, layer, block_tables, *, block_size: int,
@@ -824,7 +875,9 @@ def _paged_attend_pallas(q, k, v, pools, layer, positions, lens,
 
 def paged_attend(q, k, v, pools, layer, positions, lens, block_tables, *,
                  block_size: int, policy=None, attn_kernel: str = "xla",
-                 scale: Optional[float] = None):
+                 scale: Optional[float] = None,
+                 max_diag_rows: Optional[int] = None,
+                 key_block: Optional[int] = None):
     """THE paged attention of every serving program, family and layout
     policy: each row's run of queries against its own cached history,
     the run's keys and values written into ``layer`` of the carried
@@ -849,7 +902,15 @@ def paged_attend(q, k, v, pools, layer, positions, lens, block_tables, *,
     :func:`_lane_diag_sdpa` on the rows as gathered: the same
     arithmetic, chosen from shapes and dtypes alone); "pallas" the
     fused kernel (:func:`_paged_attend_pallas`) — same mask, same
-    softmax sequence, bit-parity-pinned against this path."""
+    softmax sequence, bit-parity-pinned against this path.
+
+    ``max_diag_rows`` overrides :data:`_MAX_DIAG_ROWS` where the caller
+    knows its program's kind better than a row count does (a family
+    with 64 query heads keeps a verify run on the diagonal, and a
+    narrow prefill bucket off it); ``key_block`` (positions, a multiple
+    of ``block_size``) scores the run a block of keys at a time, only
+    the blocks it can see (:func:`_paged_attend_key_blocked`: a prefill
+    chunk against a long table)."""
     if attn_kernel == "pallas":
         if scale is not None:
             raise NotImplementedError(
@@ -859,11 +920,17 @@ def paged_attend(q, k, v, pools, layer, positions, lens, block_tables, *,
         return _paged_attend_pallas(
             q, k, v, pools, layer, positions, lens, block_tables,
             block_size=block_size, policy=policy)
+    if key_block is not None:
+        if len(pools) != 2:
+            raise NotImplementedError(
+                "key-blocked paged attention reads a passthrough pool")
+        return _paged_attend_key_blocked(
+            q, k, v, pools, layer, positions, lens, block_tables,
+            block_size=block_size, key_block=key_block, scale=scale)
     # the rows as gathered iff they are stored in a float narrower than
     # q (what _masked_sdpa calls `stored`; a scaled or float8 pool's
     # view is a widened f32 one) and the program has few query rows
-    diag = (len(pools) == 2 and _stored_narrower(q, pools[0])
-            and q.shape[1] * q.shape[2] <= _MAX_DIAG_ROWS)
+    diag = len(pools) == 2 and _as_gathered(q, pools[0], max_diag_rows)
     k_all, v_all, pools = paged_kv_step(
         pools, layer, k, v, positions, lens, block_tables,
         block_size=block_size, policy=policy, split_heads=not diag)
@@ -879,6 +946,193 @@ def paged_attend(q, k, v, pools, layer, positions, lens, block_tables, *,
     o = _masked_sdpa(q, repeat_kv(k_all, rep), repeat_kv(v_all, rep),
                      valid, page=block_size, scale=scale)
     return o, pools
+
+
+def _paged_attend_key_blocked(q, k, v, pools, layer, positions, lens,
+                              block_tables, *, block_size: int,
+                              key_block: int, scale: Optional[float]):
+    """:func:`paged_attend` for a run with MANY query rows against a
+    LONG table (a prefill chunk: 48 heads x 1,024 tokens against 17,408
+    positions): the run is written, then its keys are read ``key_block``
+    positions at a time — that many table entries gathered, split into
+    heads, scored, and folded into a running softmax
+    (:func:`_online_merge`) — for only as many blocks as the run can
+    SEE: ``ceil((start + len) / key_block)`` trips, counted on the
+    device, whatever the table's width. One block's f32 scores are alive
+    at a time (0.2 GB where the whole table's are 3.4), and a chunk at
+    position 2,048 of a 17,408-wide table does a sixth of the work. Same
+    mask, same rounding contract as :func:`_masked_sdpa`'s stored
+    branch (q and the probabilities rounded to the pool's dtype, f32
+    sums), the softmax's sums in block order. ``q`` [S, Hkv, G*P, Dh]
+    (the query heads of a kv head as rows). A table whose width the
+    block does not divide is read whole, in one trip."""
+    S, hkv, P, dh = k.shape
+    rows = q.shape[2]
+    M = block_tables.shape[1]
+    nb = max(key_block // block_size, 1)
+    if M % nb:
+        nb = M
+    kb = nb * block_size
+    pools = paged_write(*pools, layer, k, v, positions, lens,
+                        block_tables=block_tables, block_size=block_size)
+    qs = _as_stored(q, pools[0])
+    pos_q = jnp.tile(positions, (1, rows // P))[:, None, :, None]
+    trips = jnp.clip((jnp.max(positions[:, 0] + lens) + kb - 1) // kb,
+                     1, M // nb)
+
+    def block(c, carry):
+        tables = lax.dynamic_slice_in_dim(block_tables, c * nb, nb, axis=1)
+        k_c, v_c = _gather_kv(pools, layer, None, tables,
+                              block_size=block_size, head_shape=(hkv, dh))
+        with jax.named_scope("sdpa"):
+            scores = jnp.einsum("shqd,shtd->shqt", qs, k_c,
+                                preferred_element_type=jnp.float32)
+            scores = (scores / math.sqrt(dh) if scale is None
+                      else scores * scale)
+            seen = (c * kb + jnp.arange(kb))[None, None, None, :] <= pos_q
+            scores = jnp.where(seen, scores, -jnp.inf)
+            m_new = jnp.max(scores, axis=-1)
+            p = jnp.exp(scores - jnp.where(jnp.isfinite(m_new), m_new,
+                                           0.0)[..., None])
+            o_new = jnp.einsum("shqt,shtd->shqd",
+                               _as_stored(p.astype(q.dtype), v_c), v_c,
+                               preferred_element_type=jnp.float32)
+            return _online_merge(*carry, m_new, jnp.sum(p, axis=-1), o_new)
+
+    m0 = jnp.full((S, hkv, rows), -jnp.inf, jnp.float32)
+    _, l, acc = lax.fori_loop(
+        0, trips, block,
+        (m0, jnp.zeros_like(m0), jnp.zeros((S, hkv, rows, dh), jnp.float32)))
+    with jax.named_scope("sdpa"):
+        # a pad row of the bucket sees at least its own (pad) key
+        o = acc / jnp.maximum(l, jnp.finfo(jnp.float32).tiny)[..., None]
+    return o.astype(jnp.result_type(q, pools[1])), pools
+
+
+# ---------------------------------------------------------------------
+# The WINDOW store (sliding-window attention layers): a ring of ``R =
+# window + block_size`` positions a SLOT beside the block pool,
+# ``wk``/``wv`` [L_w, (slots + 1) * R, F] — pool rows like any other (a
+# token's kv heads flattened, zero pad lanes), the last slot's ring the
+# null one (warmup, dead rows and pad columns write there). Position
+# ``p`` of the sequence in slot ``s`` lives at row ``s * R + p % R``, so
+# a sequence holds ``R`` rows a layer whatever its length, a layer reads
+# ``R`` rows of a sequence and never a table's width, and nothing is
+# allocated or freed as the sequence grows. WHICH position a ring row
+# holds is arithmetic on the positions the program is given: no length
+# is stored, and a ring's rows from an earlier owner fall outside every
+# mask of a sequence that starts at 0.
+# ---------------------------------------------------------------------
+def _ring_positions(last, ring: int):
+    """The position each of a ring's ``ring`` rows holds once every
+    position up to ``last`` [S] has been written: the largest ``p <=
+    last`` with ``p % ring == r`` — NEGATIVE where the sequence has not
+    reached row ``r`` yet (the row is an earlier owner's). [S, ring]."""
+    r = jnp.arange(ring)[None, :]
+    return last[:, None] - (last[:, None] - r) % ring
+
+
+def window_write(wk, wv, layer, k, v, positions, lens, row0, *, ring: int):
+    """Write every row's run of (k, v) [S, H, P, Dh] at ``positions``
+    [S, P] into ``layer`` of the window store, rings ``row0 +
+    arange(S)``: one scatter a buffer. Of a run longer than the ring
+    only its LAST ``ring`` real columns are written (the earlier ones
+    would be overwritten by them); those, columns at or beyond a row's
+    ``lens`` and rows of ``lens`` 0 go to the null ring, the store's
+    last."""
+    S, _, P, _ = k.shape
+    null = wk.shape[1] // ring - 1
+    with jax.named_scope("kv_write"):
+        col = jnp.arange(P)[None, :]
+        keep = (col < lens[:, None]) & (col >= lens[:, None] - ring)
+        slot = jnp.where(keep, (row0 + jnp.arange(S))[:, None], null)
+        idx = (slot * ring + jnp.where(keep, positions, col) % ring
+               ).reshape(S * P)
+        return _put_rows(wk, layer, idx, k), _put_rows(wv, layer, idx, v)
+
+
+def window_gather(buf, layer, row0, rows: int, *, ring: int):
+    """The rings ``row0 + arange(rows)`` of ``layer``, as stored:
+    [rows, ring, F]. A SLICE of the store (the rings of consecutive
+    slots are adjacent), ``ring`` rows a sequence."""
+    return lax.dynamic_slice(
+        buf, (layer, row0 * ring, 0),
+        (1, rows * ring, buf.shape[-1])).reshape(rows, ring, -1)
+
+
+def window_attend(q, k, v, bufs, layer, positions, lens, row0, *,
+                  window: int, ring: int,
+                  max_diag_rows: Optional[int] = None):
+    """:func:`paged_attend` for a SLIDING-WINDOW layer over the window
+    store: query ``i`` of a row sees key ``j`` iff ``j <= i`` and ``j >
+    i - window`` (``window`` keys, its own among them). ``q`` [S, Hq,
+    G*P, Dh], ``k``/``v`` [S, Hkv, P, Dh] at absolute CONTIGUOUS
+    ``positions`` [S, P] as there; ``bufs`` = (wk, wv) with rings of
+    ``ring`` rows; row ``s`` of the run owns ring ``row0 + s``. Returns
+    ``(o, bufs)``.
+
+    Two orders, chosen from the run's width alone:
+
+    - ``P <= ring - window + 1`` (a decode step, a verify run, a narrow
+      prefill bucket): WRITE, THEN READ, as the block pool does — after
+      the write the ring holds the ``ring`` positions up to the run's
+      last, and the query at the run's first position still finds all
+      ``window`` of its own: the ``ring - window`` spare rows are what
+      the run overwrote. The ring is contracted as stored, heads on the
+      lane diagonal, for a bf16/f16 store under ``max_diag_rows``
+      (:func:`_lane_diag_sdpa`), split into heads otherwise.
+    - wider (a prefill chunk, up to twice the window and more): READ,
+      THEN WRITE. The keys are the ring as the earlier chunks left it
+      (up to ``ring`` positions before the run's first) beside the
+      run's own, rounded to the store's dtype as a read-back would; the
+      run's last ``ring`` columns are written afterwards.
+
+    The mask is computed from positions in both (:func:`_ring_positions`):
+    a ring row that holds nothing of this sequence yet reads a negative
+    position and is masked."""
+    wk, wv = bufs
+    S, hkv, P, Dh = k.shape
+    heads = (hkv, Dh)
+    groups = q.shape[2] // P
+    start = positions[:, 0]
+    if ring < window:
+        raise ValueError(f"a ring of {ring} rows cannot hold a window of "
+                         f"{window}")
+    pos_q = jnp.tile(positions, (1, groups))[:, None, :, None]  # [S,1,GP,1]
+
+    def gathered():
+        with jax.named_scope("window_gather"):
+            return (window_gather(wk, layer, row0, S, ring=ring),
+                    window_gather(wv, layer, row0, S, ring=ring))
+
+    def split(rows):
+        return _pool_heads(rows, *heads).transpose(0, 2, 1, 3)
+
+    if P <= ring - window + 1:
+        wk, wv = window_write(wk, wv, layer, k, v, positions, lens, row0,
+                              ring=ring)
+        k_rows, v_rows = gathered()
+        held = _ring_positions(start + lens - 1, ring)[:, None, None, :]
+        valid = (held >= 0) & (held <= pos_q) & (held > pos_q - window)
+        if _as_gathered(q, wk, max_diag_rows):
+            o = _lane_diag_sdpa(q, k_rows[:, None], v_rows[:, None], valid,
+                                kv_heads=hkv)
+        else:
+            o = _masked_sdpa(q, split(k_rows), split(v_rows), valid)
+        return o, (wk, wv)
+    k_rows, v_rows = gathered()
+    held = _ring_positions(start - 1, ring)[:, None, None, :]
+    col = jnp.arange(P)[None, None, None, :]
+    row = jnp.tile(jnp.arange(P), groups)[None, None, :, None]
+    valid = jnp.concatenate(
+        [(held >= 0) & (held > pos_q - window),
+         jnp.broadcast_to((col <= row) & (col > row - window),
+                          (S, 1, groups * P, P))], axis=-1)
+    k_all = jnp.concatenate([split(k_rows), k.astype(wk.dtype)], axis=2)
+    v_all = jnp.concatenate([split(v_rows), v.astype(wv.dtype)], axis=2)
+    o = _masked_sdpa(q, k_all, v_all, valid)
+    return o, window_write(wk, wv, layer, k, v, positions, lens, row0,
+                           ring=ring)
 
 
 # ---------------------------------------------------------------------

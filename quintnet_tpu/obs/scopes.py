@@ -28,7 +28,10 @@ from typing import Dict, Iterable
 # mamba (in_proj, conv, ssd, state_update, gate_norm, out_proj); a
 # latent-attention block: mla (q_down, q_up, kv_down, kv_write,
 # kv_gather, absorb, scores, values, kv_up, proj); a dropless
-# mixture: moe (router, sort, experts, shared, combine). The
+# mixture: moe (router, sort, experts, shared, combine); a model of
+# sliding-window and global layers opens its layer's kind (full,
+# sliding) around attn (qkv, rope, kv_write, kv_gather or
+# window_gather, sdpa, gate, proj). The
 # train step:
 # grads, grad_reduce, grad_clip, optimizer. ``rematted_computation`` is
 # jax.checkpoint's own mark on what the backward pass recomputes.
@@ -40,6 +43,7 @@ SCOPES = frozenset({
     "mla", "q_down", "q_up", "kv_down", "absorb", "scores", "values",
     "kv_up",
     "moe", "router", "sort", "experts", "shared", "combine",
+    "full", "sliding", "rope", "window_gather", "gate",
     "grads", "grad_reduce", "grad_clip", "optimizer",
     "rematted_computation",
 })

@@ -63,8 +63,9 @@ from quintnet_tpu.serve.api import generate, generate_stream
 from quintnet_tpu.serve.engine import (ServeEngine, check_admissible)
 from quintnet_tpu.serve.families import (gpt2_family,
                                           granite_hybrid_family,
-                                          llama_family, pangu_moe_family)
-from quintnet_tpu.serve.kv_pool import AdmitPlan, KVPool
+                                          laguna_family, llama_family,
+                                          pangu_moe_family)
+from quintnet_tpu.serve.kv_pool import AdmitPlan, KVPool, WindowShapes
 from quintnet_tpu.serve.kv_quant import (KVLayoutPolicy, LayoutPolicy,
                                          make_policy)
 from quintnet_tpu.serve.weight_quant import (WeightLayoutPolicy,
@@ -79,6 +80,7 @@ __all__ = [
     "AdapterEntry",
     "AdapterRegistry",
     "AdmitPlan",
+    "WindowShapes",
     "ChunkState",
     "DeadlineExceeded",
     "KVLayoutPolicy",
@@ -98,6 +100,7 @@ __all__ = [
     "generate_stream",
     "gpt2_family",
     "granite_hybrid_family",
+    "laguna_family",
     "llama_family",
     "pangu_moe_family",
     "make_policy",

@@ -109,6 +109,20 @@ preemption, speculation and chain export/import work as for any other
 family. A mesh, adapters, scaled or float8 KV, scaled weights and the
 Pallas kernel are refused at construction (:func:`_refuse_for_latent`).
 
+Window families (``family.window`` set — sliding-window attention
+layers among global ones, serve/families.laguna_family): the global
+layers page into the pool like any family's; each sliding layer keeps a
+ring a SLOT beside it (serve/kv_pool.py, "Window families"). Every
+program carries the two ring buffers beside the pools (donated, updated
+in place) and prefill takes the slot's index, as for a recurrent
+family; admission takes a ring with the blocks and a cleared slot gives
+it back. What shares, moves or rolls back blocks alone — the prefix
+cache, the host tier, chain export/import, the disaggregated prefill
+phase, speculation — and a mesh, adapters, scaled or float8 KV, scaled
+weights and the Pallas kernel are refused at construction
+(:func:`_refuse_for_window`). Preemption re-prefills ``prompt +
+generated`` from position 0 through the chunk program.
+
 All host<->device traffic per step is O(max_slots) scalars plus the
 sampled tokens — the pool and parameters never leave the device. Under
 a TP mesh the whole step runs in one shard_map (head-sharded pool,
@@ -294,6 +308,70 @@ def _refuse_for_latent(family: Family, **asked) -> None:
                 f"{what} is refused: {why} (ROADMAP M1, M3)")
 
 
+def _refuse_for_window(family: Family, **asked) -> None:
+    """A window family's sequence is its blocks (global layers) AND its
+    rings (sliding layers), which hold the last positions only and are
+    overwritten in place. What the rings cannot do yet is refused here,
+    each with the piece it lacks. Any other family passes."""
+    if family.window is None:
+        return
+    missing = {
+        "prefix_cache": (
+            "prefix_cache=True",
+            "a cached block chain is reusable only with the sliding "
+            "layers' rings AS THEY STOOD at its last block's boundary, "
+            "and no ring snapshots are kept there; pass "
+            "prefix_cache=False"),
+        "kv_tier": (
+            "kv_tier_bytes > 0",
+            "the host tier spills the prefix cache, which needs ring "
+            "snapshots at block boundaries"),
+        "spec": (
+            "spec (speculative decoding)",
+            "a rejected draft's rows have overwritten the ring's oldest "
+            "positions, and nothing rolls a ring back"),
+        "adapters": (
+            "adapters",
+            "LoRA deltas are not plumbed through the two attention "
+            "shapes' projections"),
+        "mesh": (
+            "a mesh (tp, sp or ep axes)",
+            "48 and 64 query heads by layer kind are not head-sharded, "
+            "the rings are not sharded, the windowed prefill has no "
+            "ring-attention form, and the dropless router has no "
+            "exchange over an ep axis"),
+        "kv_policy": (
+            "a scaled or float8 KV layout (int8, fp8, fake_quant)",
+            "the rings carry no per-block scales and are contracted as "
+            "stored: pass kv_dtype 'f32' or 'bf16'"),
+        "weights": (
+            "a scaled weight layout (int8, fp8, fake_quant)",
+            "the grouped expert matmul applies no per-channel scale: "
+            "pass weights_dtype 'f32' or 'bf16'"),
+        "pallas": (
+            "attn_kernel='pallas'",
+            "the fused kernel walks a block table and knows neither "
+            "the window mask nor the rings"),
+        "kv_chain": (
+            "export_kv_chain / import_kv_chain",
+            "the handoff payload carries KV blocks and no rings"),
+        "prefill_only": (
+            "prefill_only (the disaggregated prefill phase)",
+            "a prefill-phase retirement hands off its KV chain, and "
+            "the chain carries no rings"),
+        "block_size": (
+            "a block_size other than the family's",
+            "the rings are sliding_window + block_size rows: build "
+            "laguna_family(cfg, block_size=...) with the engine's"),
+    }
+    for key, on in asked.items():
+        if on:
+            what, why = missing[key]
+            raise NotImplementedError(
+                f"family {family.name!r} keeps a window store beside "
+                f"its blocks; {what} is refused: {why} (ROADMAP M2, M5)")
+
+
 class ServeEngine:
     def __init__(self, family: Family, params, *, max_slots: int = 8,
                  block_size: int = 16, num_blocks: int = 64,
@@ -338,6 +416,20 @@ class ServeEngine:
             family, mesh=mesh is not None,
             adapters=adapters not in (None, False),
             pallas=attn_kernel == "pallas")
+        self._window = family.window is not None
+        _refuse_for_window(
+            family, prefix_cache=prefix_cache,
+            kv_tier=int(kv_tier_bytes) > 0,
+            spec=spec not in (None, False),
+            adapters=adapters not in (None, False),
+            mesh=mesh is not None, pallas=attn_kernel == "pallas",
+            block_size=self._window and family.window.ring
+            != family.window.window + int(block_size))
+        # per-slot buffers beside the pools (a recurrent family's state,
+        # a window family's rings): two more arguments of every program,
+        # under this name, and the slot's index to prefill
+        self._slot_buffers = ("state" if self._recurrent
+                              else "window" if self._window else None)
         self.eos_token_id = eos_token_id
         self.temperature = float(temperature)
         self.top_k = int(top_k)
@@ -634,6 +726,7 @@ class ServeEngine:
         self.weight_policy = make_weight_policy(weights_dtype)
         self.weights_dtype = self.weight_policy.name
         _refuse_for_latent(family, weights=self.weight_policy.scaled)
+        _refuse_for_window(family, weights=self.weight_policy.scaled)
         self._weight_targets = present_targets(params,
                                                family.weight_targets)
         if self.weight_policy.name != "f32" and not self._weight_targets:
@@ -654,9 +747,9 @@ class ServeEngine:
         # policy are pinned unchanged, analysis/specs.py).
         self.kv_policy = make_policy(
             kv_dtype if kv_dtype is not None else family.kv_dtype)
-        _refuse_for_latent(
-            family, kv_policy=self.kv_policy.scaled
-            or self.kv_policy.name == "fp8")
+        for refuse in (_refuse_for_latent, _refuse_for_window):
+            refuse(family, kv_policy=self.kv_policy.scaled
+                   or self.kv_policy.name == "fp8")
         if self.attn_kernel == "pallas" and self.kv_policy.name == "fp8":
             raise NotImplementedError(
                 "attn_kernel='pallas' does not yet support the fp8 KV "
@@ -695,7 +788,7 @@ class ServeEngine:
             sharding=sharding, scale_sharding=scale_sharding,
             prefix_cache=self.prefix_cache, host_tier=self.kv_tier,
             state=family.state, max_slots=self.max_slots,
-            latent=family.latent)
+            latent=family.latent, window=family.window)
         # per-step promotion budget in BLOCKS (Sarathi's budget
         # discipline applied to host->device memcpy): default 4 blocks
         # a step — enough to drain typical chains in a few steps
@@ -759,9 +852,10 @@ class ServeEngine:
         # only earn XLA's "not usable" warning.) Indices shift with the
         # pool-arg count: scaled KV policies carry 4 pool buffers
         # (k, v, k_scale, v_scale), a recurrent family 4 (k, v, ssm,
-        # conv), passthrough KV-only ones 2, a latent family 1 (the
-        # bodies then take no v_pool). (A recurrent prefill's slot
-        # index follows key_data: no index moves.)
+        # conv), a window family 4 (k, v, wk, wv), passthrough KV-only
+        # ones 2, a latent family 1 (the bodies then take no v_pool).
+        # (A recurrent or window prefill's slot index follows key_data:
+        # no index moves.)
         n_pool = len(self.pool.caches())
         pool_idx = tuple(range(1, n_pool + 1))
         self._prefills: Dict[int, RecompileSentinel] = {
@@ -842,6 +936,10 @@ class ServeEngine:
             # a recurrent family's fixed cost per slot, and the kinds
             # of its layers in model order (0 and None for the rest)
             state_bytes_per_slot=self.pool.state_bytes_per_slot,
+            # a window family's fixed cost per slot: its rings over the
+            # sliding layers (``kv_bytes_per_token`` is then the global
+            # layers' alone)
+            window_bytes_per_slot=self.pool.window_bytes_per_slot,
             layer_pattern=(None if self.family.layer_pattern is None
                            else list(self.family.layer_pattern)),
             max_slots=self.max_slots,
@@ -904,7 +1002,7 @@ class ServeEngine:
         policy = self.kv_policy
         scaled = policy.scaled
 
-        recurrent = self._recurrent
+        slot_buffers = self._slot_buffers
         latent = self._latent
 
         def body(params, k_pool, *rest):
@@ -916,13 +1014,13 @@ class ServeEngine:
             else:
                 k_scale = v_scale = None
             extra = {}
-            if recurrent:
-                ssm, conv, *rest = rest
+            if slot_buffers:
+                buf_a, buf_b, *rest = rest
             ids, start, t0, table_row, cow_src, cow_len, key_data, \
                 *rest = rest
-            if recurrent:
+            if slot_buffers:
                 slot, *rest = rest
-                extra = {"state": (ssm, conv), "slot": slot}
+                extra = {slot_buffers: (buf_a, buf_b), "slot": slot}
             lora, lora_scale = rest if use_lora else (None, None)
             # copy-on-write: when the reusable prefix chain ends inside
             # a partially-filled cached block, its first cow_len slots
@@ -983,8 +1081,8 @@ class ServeEngine:
                 return (*pools, tok.astype(jnp.int32),
                         jax.random.key_data(key2))
 
-        return self._wrap(body, name, n_rest=7 + recurrent, donate=donate,
-                          ids_sharded=True)
+        return self._wrap(body, name, n_rest=7 + bool(slot_buffers),
+                          donate=donate, ids_sharded=True)
 
     def _build_decode(self, name: str, *, donate):
         family, bs = self.family, self.pool.block_size
@@ -995,7 +1093,7 @@ class ServeEngine:
         policy = self.kv_policy
         scaled = policy.scaled
 
-        recurrent = self._recurrent
+        slot_buffers = self._slot_buffers
         latent = self._latent
 
         def body(params, k_pool, *rest):
@@ -1005,9 +1103,9 @@ class ServeEngine:
             extra = {}
             if scaled:
                 k_scale, v_scale, *rest = rest
-            if recurrent:
-                ssm, conv, *rest = rest
-                extra = {"state": (ssm, conv)}
+            if slot_buffers:
+                buf_a, buf_b, *rest = rest
+                extra = {slot_buffers: (buf_a, buf_b)}
             tok, pos, tables, key_data, *rest = rest
             lora, lora_scale = rest if use_lora else (None, None)
             out = family.decode(
@@ -1409,6 +1507,7 @@ class ServeEngine:
         self._check_admissible(prompt, max_new_tokens)
         if self._recurrent:
             _refuse_for_state(self.family, prefill_only=prefill_only)
+        _refuse_for_window(self.family, prefill_only=prefill_only)
         if deadline_s is not None and deadline_s <= 0:
             raise ValueError(
                 f"deadline_s={deadline_s} already expired at submit")
@@ -1456,6 +1555,7 @@ class ServeEngine:
         prompt = np.asarray(progress.prompt, np.int32).reshape(-1)
         if self._recurrent:
             _refuse_for_state(self.family, prefill_only=prefill_only)
+        _refuse_for_window(self.family, prefill_only=prefill_only)
         if progress.key_data is None:
             raise ValueError(
                 "progress.key_data is required to restore a request "
@@ -1535,6 +1635,7 @@ class ServeEngine:
             self.pool.release([st.cow_src])
         self._slot_chunk[slot] = None
         self._slot_req[slot] = None
+        self.pool.window_release(slot)
         self._slot_blocks[slot] = []
         self._tables[slot] = 0
         self._tok[slot] = 0
@@ -1753,10 +1854,10 @@ class ServeEngine:
             f"have rejected this request")
 
     def _state_row(self, slot: int) -> Tuple:
-        """What a recurrent family's prefill takes beside the rest: the
-        row of the state buffers its request owns (its slot). Nothing
-        for a KV-only family."""
-        return (np.int32(slot),) if self._recurrent else ()
+        """What a recurrent or window family's prefill takes beside the
+        rest: the row of the per-slot buffers its request owns (its
+        slot). Nothing for a family whose sequences are blocks only."""
+        return (np.int32(slot),) if self._slot_buffers else ()
 
     def _allocate_slot(self, slot: int, req: Request):
         """The admission prologue both prefill paths share: resolve
@@ -1775,6 +1876,9 @@ class ServeEngine:
         new = self.pool.acquire(plan.n_new_blocks)
         assert new is not None  # admission checked the budget
         blocks = plan.shared_blocks + new
+        if self._window:
+            # both kinds or neither: the ring with the blocks
+            self.pool.window_acquire(slot, req.rid)
         self._slot_req[slot] = req
         self._slot_blocks[slot] = blocks
         row = np.zeros((self.table_width,), np.int32)
@@ -2317,6 +2421,16 @@ class ServeEngine:
         # what the decode program reads of the pool this step: every
         # position the rows that ride it hold
         context_tokens = int(self._pos[decoding].sum())
+        # a window family's decode reads, in rows x layers: every
+        # position of the global layers, at most the window's of the
+        # sliding ones
+        window_attrs = {}
+        if self._window:
+            w = self.family.window
+            window_attrs = {
+                "global_rows": context_tokens * self.pool.n_layers,
+                "window_rows": int(np.minimum(
+                    self._pos[decoding], w.window).sum()) * w.n_layers}
         # bytes of recurrent state this step's programs read and wrote:
         # every decoding slot's once each way, and each prefilled
         # slot's per chunk program that ran (a chunk that starts at 0
@@ -2445,9 +2559,9 @@ class ServeEngine:
                 phases=ph.seconds, host_syncs=ph.host_syncs,
                 h2d_bytes=ph.h2d_bytes, context_tokens=context_tokens,
                 state_bytes=state_bytes,
-                attrs={k: (v.tolist() if isinstance(v, np.ndarray)
-                           else v)
-                       for k, v in moe_kw.items()} if moe_kw else {}))
+                attrs={**window_attrs,
+                       **{k: (v.tolist() if isinstance(v, np.ndarray)
+                              else v) for k, v in moe_kw.items()}}))
         if self.log_every:
             self.metrics.log_step(self.logger, every=self.log_every)
         return finished
@@ -2461,9 +2575,10 @@ class ServeEngine:
         zrow = jnp.zeros((self.table_width,), jnp.int32)
         lora_on = self.adapters is not None
         p_extra = self._lora_args("prefill", slot=0) if lora_on else ()
-        # a recurrent family's warmup prefill writes the state's null
-        # row (the one past the slots), as its KV goes to block 0
-        p_state = ((jnp.int32(self.max_slots),) if self._recurrent
+        # a recurrent or window family's warmup prefill writes its
+        # per-slot buffers' null row (the one past the slots), as its
+        # KV goes to block 0
+        p_state = ((jnp.int32(self.max_slots),) if self._slot_buffers
                    else ())
         for b, sentinel in self._prefills.items():
             yield sentinel, (
@@ -2599,6 +2714,7 @@ class ServeEngine:
         cache, not state."""
         if self._recurrent:
             _refuse_for_state(self.family, kv_chain=True)
+        _refuse_for_window(self.family, kv_chain=True)
         chain = self.pool.export_chain(tokens, namespace=namespace)
         if self.tracer is not None:
             self.tracer.event(trace_id, "kv_export",
@@ -2620,6 +2736,7 @@ class ServeEngine:
         fleet are a deployment error, not a retryable fault."""
         if self._recurrent:
             _refuse_for_state(self.family, kv_chain=True)
+        _refuse_for_window(self.family, kv_chain=True)
         n = self.pool.import_chain(chain, namespace=namespace)
         if self.tracer is not None:
             self.tracer.event(trace_id, "kv_import",

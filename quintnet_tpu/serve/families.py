@@ -100,6 +100,17 @@ their positions — ``v_pool`` is passed as None — and return ``(logits,
 k_pool, moe_stats)``. ``prefill_from`` runs the MATERIALIZED form of
 the attention, ``decode`` and ``verify`` the ABSORBED one
 (nn/attention.py, "The LATENT paged cache").
+
+Window families (``Family.window`` set: sliding-window layers among
+global ones, :func:`laguna_family`): ``n_layers`` counts the GLOBAL
+layers, which page every position into the pool; each sliding layer
+keeps a ring a slot (serve/kv_pool.WindowShapes; nn/attention.py, "The
+WINDOW store"). Every contract additionally takes ``window=(wk, wv)``
+and returns ``(logits, k_pool, v_pool, wk, wv, moe_stats)``;
+``prefill_from`` takes ``slot``, the ring its request owns, as a
+recurrent family's does. The layers differ in SHAPE, not in kind alone
+(``W_q`` is wider on a sliding layer), so the programs walk the
+model's runs of consecutive layers of one kind, a scan each.
 """
 
 from __future__ import annotations
@@ -112,7 +123,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from quintnet_tpu.nn.attention import _pool_tuple
-from quintnet_tpu.serve.kv_pool import NULL_BLOCK, StateShapes
+from quintnet_tpu.serve.kv_pool import (NULL_BLOCK, StateShapes,
+                                        WindowShapes)
 
 
 @dataclass(frozen=True)
@@ -175,6 +187,9 @@ class Family:
     # token caches; ``n_kv_heads`` is then 1 and ``head_dim`` this
     # number, and the programs take and return no ``v_pool``
     latent: Optional[int] = None
+    # window families (module docstring): the sliding layers' rings
+    # beside the pool; None = every layer pages all of a sequence
+    window: Optional[WindowShapes] = None
 
 
 # --------------------------------------------------------------------
@@ -696,4 +711,135 @@ def pangu_moe_family(cfg) -> Family:
         prefill_from=prefill_from, decode=decode, verify=verify,
         partition_specs=pangu_moe_partition_specs,
         weight_targets=WEIGHT_TARGETS, latent=cfg.latent_width,
+    )
+
+
+# --------------------------------------------------------------------
+# Laguna (sliding-window layers among global ones, more query heads on
+# the former, a rotary setting a kind, a per-head gate, every expert
+# held): two attention SHAPES in one program, a ring a slot beside the
+# pool
+# --------------------------------------------------------------------
+
+def laguna_family(cfg, *, block_size: int = 16) -> Family:
+    """``block_size``: the engine's (the rings are ``sliding_window +
+    block_size`` rows: :class:`~quintnet_tpu.serve.kv_pool.WindowShapes`);
+    the engine refuses a family built for another."""
+    from quintnet_tpu.models.laguna import (
+        FULL, SLIDING, WEIGHT_TARGETS, laguna_block, laguna_embed,
+        laguna_logits, laguna_partition_specs, laguna_rope_tables)
+
+    def only_plain(tp_axis, ep_axis, lora, kv_scales, attn_kernel, window,
+                   bs):
+        if (tp_axis is not None or ep_axis is not None or lora is not None
+                or kv_scales is not None or attn_kernel != "xla"
+                or window is None or bs != block_size):
+            raise NotImplementedError(
+                f"the laguna programs run on one device, without "
+                f"adapters, on an unscaled pool of block_size "
+                f"{block_size}, with attn_kernel='xla' and with their "
+                f"window buffers (ServeEngine refuses the rest at "
+                f"construction)")
+
+    def run(params, k_pool, v_pool, window, ids, positions, lens, tables,
+            row0, chunk):
+        """The layers in model order, a :func:`_scan_layers` a RUN
+        (consecutive layers of one attention and FFN kind, whose weights
+        stack): all four cache buffers ride every scan's carry, a full
+        layer addresses the pool at its index among the full layers, a
+        sliding one the window store at its index among the sliding
+        ones. Returns (h, k_pool, v_pool, wk, wv, moe_stats)."""
+        rope = {kind: laguna_rope_tables(positions, cfg, kind)
+                for kind in (FULL, SLIDING)}              # [S, P, rot]
+        experts = params["blocks"]["experts"]
+        h, caches, stats = laguna_embed(params, ids), (
+            k_pool, v_pool, *window), []
+        for r in cfg.runs:
+            def step(blk, layer, _lr, x, caches, r=r):
+                x, caches, *st = laguna_block(
+                    blk, x, caches, r.cache_first + layer, positions,
+                    lens, tables, row0, block_size, cfg, *rope[r.attn],
+                    attn=r.attn, chunk=chunk, experts=experts,
+                    expert_layer=(None if r.expert_first is None
+                                  else r.expert_first + layer))
+                return (x, *caches, *st)
+
+            stack = jax.tree.map(lambda a, r=r: a[r.first:r.first + r.count],
+                                 params["blocks"][r.kind])
+            sparse = r.expert_first is not None
+            h, *caches = _scan_layers(step, h, tuple(caches), stack, None,
+                                      sparse)
+            if sparse:
+                stats.append(caches.pop())
+        # runs' stats are already reduced over their layers: counts add,
+        # the entropy is a mean over the runs weighted by their layers
+        n = [r.count for r in cfg.runs if r.expert_first is not None]
+        total = {k: sum(st[k] for st in stats) for k in stats[0]
+                 if k != "entropy"}
+        total["entropy"] = sum(
+            st["entropy"] * c for st, c in zip(stats, n)) / sum(n)
+        return (h, *caches, total)
+
+    def prefill_from(params, k_pool, v_pool, ids, start, t0, table_row,
+                     block_size, tp_axis=None, ep_axis=None, lora=None,
+                     lora_scale=None, kv_scales=None, policy=None,
+                     attn_kernel="xla", window=None, slot=None):
+        only_plain(tp_axis, ep_axis, lora, kv_scales, attn_kernel, window,
+                   block_size)
+        positions = (start + jnp.arange(ids.shape[1], dtype=jnp.int32))[None]
+        h, *bufs = run(params, k_pool, v_pool, window, ids, positions,
+                       jnp.reshape(t0 - start, (1,)), table_row[None],
+                       slot, True)
+        h_last = lax.dynamic_slice_in_dim(h, t0 - 1 - start, 1, axis=1)
+        return (laguna_logits(params, h_last, cfg)[:, 0, :], *bufs)
+
+    def decode(params, k_pool, v_pool, tok, pos, tables, block_size,
+               tp_axis=None, ep_axis=None, lora=None, lora_scale=None,
+               kv_scales=None, policy=None, attn_kernel="xla",
+               window=None):
+        only_plain(tp_axis, ep_axis, lora, kv_scales, attn_kernel, window,
+                   block_size)
+        # a row whose table is all null blocks is not decoding (an empty
+        # slot, or one in the middle of a chunked prefill): its token is
+        # padding — the router sends it nowhere, and its ring (a
+        # chunked prefill's, half built) is not written
+        live = (tables[:, 0] != NULL_BLOCK).astype(jnp.int32)
+        h, *bufs = run(params, k_pool, v_pool, window, tok[:, None],
+                       pos[:, None], live, tables, 0, False)
+        return (laguna_logits(params, h, cfg)[:, 0, :], *bufs)
+
+    def verify(params, k_pool, v_pool, ids, starts, tail_lens, tables,
+               block_size, tp_axis=None, ep_axis=None, lora=None,
+               lora_scale=None, kv_scales=None, policy=None,
+               attn_kernel="xla", window=None):
+        # the decode program at P tokens a row (P <= block_size + 1:
+        # what a ring's spare rows allow, nn/attention.window_attend).
+        # Not yet a speculative verify: a rejected draft's rows have
+        # overwritten the ring's oldest, and nothing rolls that back —
+        # the engine refuses ``spec`` for this family
+        only_plain(tp_axis, ep_axis, lora, kv_scales, attn_kernel, window,
+                   block_size)
+        if ids.shape[1] > block_size + 1:
+            raise ValueError(
+                f"a verify run of {ids.shape[1]} tokens overwrites ring "
+                f"rows its own first query still needs; at most "
+                f"block_size + 1 = {block_size + 1}")
+        positions = (starts[:, None]
+                     + jnp.arange(ids.shape[1], dtype=jnp.int32)[None, :])
+        h, *bufs = run(params, k_pool, v_pool, window, ids, positions,
+                       tail_lens, tables, 0, False)
+        return (laguna_logits(params, h, cfg), *bufs)
+
+    return Family(
+        name="laguna", cfg=cfg, n_layers=cfg.n_layers_of(FULL),
+        n_kv_heads=cfg.num_key_value_heads, head_dim=cfg.head_dim,
+        max_positions=cfg.max_position_embeddings,
+        prefill_from=prefill_from, decode=decode, verify=verify,
+        partition_specs=laguna_partition_specs,
+        weight_targets=WEIGHT_TARGETS,
+        layer_pattern=tuple(f"{a}_{m}" for a, m in zip(
+            cfg.attn_kinds, cfg.mlp_layer_types)),
+        window=WindowShapes(n_layers=cfg.n_layers_of(SLIDING),
+                            window=cfg.sliding_window,
+                            ring=cfg.sliding_window + block_size),
     )

@@ -57,6 +57,29 @@ the last row is the null row, the state's counterpart of block 0
 to share: the prefix index, the host tier and chain export/import know
 nothing of it, and the engine refuses them for such a family.
 
+Window families (sliding-window attention layers among global ones,
+``Family.window`` set) keep a THIRD kind: beside the blocks of their
+global layers — which hold every position of a sequence, through the
+block table — each sliding layer keeps a RING of ``window + block_size``
+positions per engine SLOT, ``wk``/``wv`` ``[L_w, (max_slots + 1) * ring,
+F]`` in the pool's stored dtype (:class:`WindowShapes`; the last ring is
+the null one). Position ``p`` of the sequence in slot ``s`` lives at row
+``s * ring + p % ring`` (nn/attention.py, "The WINDOW store"): a
+sequence's bytes in those layers are bounded by the window whatever its
+length (:attr:`KVPool.window_bytes_per_slot`), a decode step reads
+``ring`` rows of it and never the table's width, and nothing is
+allocated or freed as it grows. Why a ring a slot and not a second set
+of blocks released behind the window: the bound then holds by
+construction instead of by a second allocator keeping up, a step ships
+no second table, and 528 rows x 2 KB x 2 x 3 layers = 6.5 MB a slot is
+1.3% of a chip at 32 slots — less than the blocks a paged window would
+keep half empty at its two ends. The pool keeps the ledger of who owns
+which ring (:meth:`KVPool.window_acquire` / :meth:`window_release`,
+``window_owner``) and counts both kinds at admission
+(:class:`AdmitPlan`.window_bytes, :meth:`can_admit`). A ring holds nothing a second sequence could
+share: the prefix index, the host tier and chain export/import are
+refused for such a family (serve/engine.py).
+
 Latent families (multi-head latent attention, ``Family.latent`` set)
 keep ONE row kind: ``[c | k_rope]``, the compressed kv and the shared
 rotary key of a token, ``latent`` features padded to whole lane rows
@@ -137,6 +160,10 @@ class AdmitPlan:
     cow_src: Optional[int] = None
     cow_len: int = 0
     n_new_blocks: int = 0
+    # a window family's fixed store beside the blocks: the bytes of the
+    # ONE ring per sliding layer the admitted slot will own (0 for every
+    # other family)
+    window_bytes: int = 0
 
     @property
     def pinned_blocks(self) -> List[int]:
@@ -162,6 +189,19 @@ class StateShapes:
     conv: Tuple[int, ...]
 
 
+@dataclass(frozen=True)
+class WindowShapes:
+    """What ONE slot keeps for the sliding-window layers beside the
+    paged blocks of the global ones: ``n_layers`` rings of ``ring``
+    positions (``window`` + one block: the spare rows are what a run of
+    up to ``block_size + 1`` tokens overwrites before it reads —
+    nn/attention.window_attend), each position one k and one v pool row."""
+
+    n_layers: int                       # sliding-window layers
+    window: int
+    ring: int
+
+
 class KVPool:
     """Refcounted block allocator + prefix cache over paged KV storage.
 
@@ -181,7 +221,8 @@ class KVPool:
                  prefix_cache: bool = True,
                  host_tier: Optional[HostTier] = None,
                  state: Optional[StateShapes] = None,
-                 max_slots: int = 0, latent: Optional[int] = None):
+                 max_slots: int = 0, latent: Optional[int] = None,
+                 window: Optional[WindowShapes] = None):
         if latent is not None:
             # one row kind a token (module docstring)
             if n_kv_heads != 1 or head_dim != latent:
@@ -264,6 +305,34 @@ class KVPool:
                                   self.policy.store_dtype)
             self._state_bytes_per_slot = int(
                 (self.ssm.nbytes + self.conv.nbytes) // rows)
+        # the sliding layers' rings (module docstring); None = every
+        # layer pages all of a sequence
+        self.window = window
+        self.wk = self.wv = None
+        self.window_owner: List[Optional[int]] = []
+        if window is not None:
+            if (self.policy.scaled or sharding is not None
+                    or state is not None or latent is not None
+                    or prefix_cache
+                    or not jnp.issubdtype(self.policy.store_dtype,
+                                          jnp.floating)
+                    or jnp.dtype(self.policy.store_dtype).itemsize < 2):
+                raise NotImplementedError(
+                    f"a window store beside a {self.policy.name!r}, "
+                    f"sharded, latent or recurrent pool, or under the "
+                    f"prefix cache, is not implemented: the rings are "
+                    f"stored in the pool's dtype (f32 or bf16), "
+                    f"unscaled, on one device, and hold nothing a "
+                    f"second sequence could share")
+            if window.ring < window.window or int(max_slots) < 1:
+                raise ValueError(
+                    f"need ring >= window and max_slots >= 1; got "
+                    f"{window}, max_slots={max_slots}")
+            rows = (int(max_slots) + 1) * window.ring
+            self.wk = jnp.zeros((window.n_layers, rows, shape[2]),
+                                self.policy.store_dtype)
+            self.wv = jnp.zeros_like(self.wk)
+            self.window_owner = [None] * int(max_slots)
         # LIFO free list: reuse recently-freed blocks first (warm pages).
         # The membership set keeps release's double-free check O(1)
         # instead of an O(free-list) scan per block.
@@ -331,6 +400,35 @@ class KVPool:
         KV-only family). ``bytes_per_token`` counts the layers that
         hold KV only."""
         return self._state_bytes_per_slot
+
+    @property
+    def window_bytes_per_slot(self) -> int:
+        """Device bytes of ONE slot's rings over all the sliding-window
+        layers, whatever the sequence's length (0 without a window
+        store). ``bytes_per_token`` counts the global layers only."""
+        if self.window is None:
+            return 0
+        w = self.window
+        return int(2 * w.n_layers * w.ring * self.n_kv_heads
+                   * self.head_dim * jnp.dtype(self.wk.dtype).itemsize)
+
+    @property
+    def window_slots_free(self) -> int:
+        """Rings no sequence owns."""
+        return sum(1 for o in self.window_owner if o is None)
+
+    def window_acquire(self, slot: int, owner: int) -> None:
+        """Slot ``slot``'s rings now belong to sequence ``owner`` (a
+        request id). Nothing is cleared: a sequence that starts at
+        position 0 masks every row it has not written."""
+        if self.window_owner[slot] is not None:
+            raise ValueError(
+                f"ring {slot} is owned by {self.window_owner[slot]}")
+        self.window_owner[slot] = int(owner)
+
+    def window_release(self, slot: int) -> None:
+        if self.window is not None:
+            self.window_owner[slot] = None
 
     @property
     def usable_blocks(self) -> int:
@@ -622,6 +720,7 @@ class KVPool:
         plan = self.lookup(tokens, max_tokens=len(tokens) - 1,
                            namespace=namespace)
         plan.n_new_blocks = n_total - len(plan.shared_blocks)
+        plan.window_bytes = self.window_bytes_per_slot
         if self.can_admit(plan) or not plan.pinned_blocks:
             return plan
         if plan.cow_src is not None:
@@ -639,6 +738,8 @@ class KVPool:
         so they must not be counted as available."""
         pinned_evictable = sum(1 for b in plan.pinned_blocks
                                if b in self._cached_free)
+        if plan.window_bytes and not self.window_slots_free:
+            return False        # both kinds, or not at all
         return plan.n_new_blocks <= self.num_available - pinned_evictable
 
     def publish(self, tokens, blocks: Sequence[int], n_tokens: int, *,
@@ -1061,7 +1162,8 @@ class KVPool:
         functions (the engine writes the returned/donated results back
         via :meth:`update`): ``(k, v)`` for passthrough policies,
         ``(k, v, k_scale, v_scale)`` for scaled ones, ``(k, v, ssm,
-        conv)`` for a recurrent family, ``(k,)`` for a latent one —
+        conv)`` for a recurrent family, ``(k, v, wk, wv)`` for a window
+        one, ``(k,)`` for a latent one —
         call sites splat the tuple, so neither the policy nor the
         family changes their shape."""
         if self.latent is not None:
@@ -1070,6 +1172,8 @@ class KVPool:
             return self.k, self.v, self.k_scale, self.v_scale
         if self.state is not None:
             return self.k, self.v, self.ssm, self.conv
+        if self.window is not None:
+            return self.k, self.v, self.wk, self.wv
         return self.k, self.v
 
     def update(self, k, *rest) -> None:
@@ -1079,7 +1183,9 @@ class KVPool:
             carries = ("the one latent buffer" if self.latent is not None
                        else "scale arrays" if self.policy.scaled
                        else "recurrent state buffers"
-                       if self.state is not None else "no other buffers")
+                       if self.state is not None
+                       else "the window store's buffers"
+                       if self.window is not None else "no other buffers")
             raise ValueError(
                 f"policy {self.policy.name!r} carries {carries}; "
                 f"update() needs all {len(self.caches())} pool buffers, "
@@ -1092,3 +1198,5 @@ class KVPool:
             self.k_scale, self.v_scale = rest
         elif self.state is not None:
             self.ssm, self.conv = rest
+        elif self.window is not None:
+            self.wk, self.wv = rest

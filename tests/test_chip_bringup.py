@@ -376,6 +376,74 @@ def test_latent_decode_contracts_the_rows_as_gathered_on_v5e(chip):
         assert dims[:2] != (slots, width * bs), c
 
 
+def test_window_decode_reads_rings_and_contracts_as_stored_on_v5e(chip):
+    """``laguna_family(...).decode`` at Laguna-XS.2's published widths
+    (shapes only: ``jax.eval_shape``), the benchmark's five layers — a
+    global dense one, three sliding sparse ones, a global sparse one —
+    8 slots of 17,408 positions, all four cache buffers donated,
+    compiled for the described chip. The block pool ``[2, slots, 1024]``
+    and the window store ``[3, 9 x 528, 1024]`` must enter row-major
+    and alias out; no copy of either, of a layer's slice of them, of a
+    gathered view or of a ring cut into heads may be planned (48 and 64
+    query heads both contract the cached rows as stored); and the 256
+    experts of four layers must run as the chip's own grouped matmul."""
+    import numpy as np
+
+    from quintnet_tpu.models.laguna import LagunaConfig, laguna_init
+    from quintnet_tpu.serve import laguna_family
+    from quintnet_tpu.serve.weight_quant import (make_weight_policy,
+                                                 present_targets,
+                                                 quantize_params)
+
+    with open(os.path.join(REPO, "benchmarks", "configs",
+                           "laguna-xs.2.json")) as f:
+        cfg = LagunaConfig.from_dict(json.load(f))
+    fam = laguna_family(cfg)
+    slots, bs, width, ring = 8, 16, 1088, fam.window.ring
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    params = jax.tree.map(
+        lambda x: sds(x.shape, x.dtype),
+        jax.eval_shape(lambda k: (lambda p: quantize_params(
+            p, present_targets(p, fam.weight_targets),
+            make_weight_policy("bf16")))(laguna_init(k, cfg)),
+            jax.random.key(0)))
+    pool = sds((fam.n_layers, slots * width * bs, 1024), jnp.bfloat16)
+    store = sds((fam.window.n_layers, (slots + 1) * ring, 1024),
+                jnp.bfloat16)
+    rows = sds((slots,), jnp.int32)
+
+    def decode(params, k, v, wk, wv, tok, pos, tables):
+        return fam.decode(params, k, v, tok, pos, tables, bs,
+                          window=(wk, wv))
+
+    compiled = jax.jit(decode, donate_argnums=(1, 2, 3, 4)).lower(
+        params, pool, pool, store, store, rows, rows,
+        sds((slots, width), jnp.int32)).compile()
+    plan = compiled.memory_analysis()
+    cache_bytes = 2 * 2 * (int(np.prod(pool.shape))
+                           + int(np.prod(store.shape)))
+    assert plan.alias_size_in_bytes >= cache_bytes     # in and out alias
+    hlo = compiled.as_text()
+    assert "ragged-dot" in hlo and "tpu_custom_call" in hlo
+    _assert_pool_row_major_and_uncopied(hlo, pool, view=(slots, width, bs))
+    spec = importlib.util.spec_from_file_location(
+        "pool_layout_audit", os.path.join(REPO, "tools",
+                                          "pool_layout_audit.py"))
+    audit = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(audit)
+    got = audit.read_hlo(hlo, {"wk": store.shape, "wv": store.shape},
+                         slots * ring * 1024 * 2)
+    for c in got["big_copies"]:
+        dims = tuple(c["dims"])
+        assert dims not in {tuple(store.shape), tuple(store.shape[1:]),
+                            (1, *store.shape[1:])}, c
+        # a ring cut into heads: [slots, ring, 8, 128] in any order
+        assert sorted(dims) != sorted((slots, ring, 8, 128)), c
+
+
 def _assert_pool_row_major_and_uncopied(hlo: str, pool, view=None):
     """The compiled program takes ``pool``-shaped parameters in the
     row-major layout and holds no ``copy`` / ``transpose`` of the whole
