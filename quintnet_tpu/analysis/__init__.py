@@ -32,6 +32,7 @@ from quintnet_tpu.analysis.jaxpr_audit import (
     dtype_report,
     gathered_view_gathers,
     pool_scan_operands,
+    row_walk_calls,
     store_reads,
     view_head_splits,
     widened_view_dots,
@@ -76,6 +77,7 @@ __all__ = [
     "dtype_report",
     "gathered_view_gathers",
     "pool_scan_operands",
+    "row_walk_calls",
     "store_reads",
     "view_head_splits",
     "widened_view_dots",

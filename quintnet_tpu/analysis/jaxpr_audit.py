@@ -337,16 +337,40 @@ def donation_report(jitted: Callable, *args, **kwargs) -> DonationReport:
 
 
 def _walk_skip_kernels(jaxpr, visit) -> None:
-    """Walk every eqn (scan/cond/pjit bodies included) EXCEPT inside
+    """Walk every eqn (scan/cond/pjit bodies included, a
+    ``pallas_call`` itself too) EXCEPT inside
     ``pallas_call`` kernels: kernel-internal memory ops act on VMEM
     blocks by construction, which is exactly the property the
     gathered-view audit exists to distinguish from HBM traffic."""
     for eqn in jaxpr.eqns:
+        visit(eqn)
         if eqn.primitive.name == "pallas_call":
             continue
-        visit(eqn)
         for sub in _subjaxprs(eqn.params):
             _walk_skip_kernels(_as_open(sub), visit)
+
+
+def row_walk_calls(fn: Callable, *args, pool_shape, **kwargs) -> int:
+    """Count the calls of the per-row walk
+    (ops/paged_attention.paged_walk_attention) that read the pool IN
+    PLACE: ``pallas_call`` eqns of that name with two operands of
+    ``pool_shape`` — the whole carried k and v, no layer's slice and no
+    gathered view. Decode and verify on a bf16/f16 pool show one a
+    layer scan's body (a scan body counts once) where
+    :func:`gathered_view_gathers` shows none; every other program none."""
+    closed = jax.make_jaxpr(fn)(*args, **kwargs)
+    found = 0
+
+    def visit(eqn):
+        nonlocal found
+        if (eqn.primitive.name == "pallas_call"
+                and eqn.params["name"] == "paged_walk_attention"
+                and sum(tuple(v.aval.shape) == tuple(pool_shape)
+                        for v in eqn.invars) == 2):
+            found += 1
+
+    _walk_skip_kernels(closed.jaxpr, visit)
+    return found
 
 
 def gathered_view_gathers(fn: Callable, *args, num_blocks: int,

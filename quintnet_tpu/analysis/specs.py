@@ -323,23 +323,27 @@ def expected_serve_window_moe(*, full_runs: int, sliding_runs: int,
     - ``census``: no collective at all (every expert is held here).
     - ``pool_scan_operands`` 0, for the block pool AND the window
       store: all four buffers ride every run's scan carry.
-    - ``gathered_view_gathers`` 2 a full run (k and v, in its scan's
-      body) in decode and verify and NONE in a sliding run's body: a
-      sliding layer never gathers at the table's width; 0 in a prefill
-      bucket, which reads a global layer's cache a block of keys at a
-      time (nn/attention._paged_attend_key_blocked), not the table.
+    - ``gathered_view_gathers`` 0 in EVERY program: decode and verify
+      walk each row's live key blocks of a full layer's pool in place
+      (``row_walk_calls`` one a full run, in its scan's body:
+      nn/attention._paged_attend_walk), a prefill bucket reads a global
+      layer's cache a block of keys at a time
+      (nn/attention._paged_attend_key_blocked: no walk), and a sliding
+      layer never reads at the table's width.
     - ``store_reads``: a sliding layer reads its rows' rings of ONE
       layer, k and v, ``(1, rows * ring, width)`` each — ``ring`` =
       ``sliding_window + block_size`` positions a row, whatever the
       sequences' lengths.
     - ``view_head_splits`` 0 in decode and verify, for 48 and 64 query
       heads alike (both contract the cached rows as stored, heads on
-      the lane diagonal: nn/attention._lane_diag_sdpa) on a bf16 pool;
+      the lane diagonal: the walk's kernel over the pool, and
+      nn/attention._lane_diag_sdpa over the rings) on a bf16 pool;
       a prefill bucket splits each key block it gathers, never a view
       of the table's width: 0 there too.
     - ``widened_view_dots`` 0."""
     return {"census": {}, "pool_scan_operands": 0,
-            "gathered_view_gathers": 0 if chunk else 2 * full_runs,
+            "gathered_view_gathers": 0,
+            "row_walk_calls": 0 if chunk else full_runs,
             "store_reads": [(1, rows * ring, width)] * (2 * sliding_runs),
             "view_head_splits": 0,
             "widened_view_dots": 0}
@@ -417,8 +421,13 @@ def attn_kernels() -> Tuple[str, ...]:
     collectives, and the sp ring all sit outside it, and a
     ``pallas_call`` carries no collectives at all). What DOES differ
     is structural and audited separately:
-    ``jaxpr_audit.gathered_view_gathers`` must be > 0 for xla programs
-    and exactly 0 for pallas ones (tests/test_paged_attention.py)."""
+    ``jaxpr_audit.gathered_view_gathers`` must be exactly 0 for pallas
+    programs, and for xla ones > 0 wherever they still gather a view:
+    every program of an f32 or scaled pool and a many-row prefill
+    bucket of a bf16 one. An xla decode or verify program of a bf16/f16
+    pool walks each row's live blocks in place instead (one
+    ``row_walk_calls`` a layer scan, no gather;
+    tests/test_paged_attention.py)."""
     return ("xla", "pallas")
 
 

@@ -17,7 +17,9 @@ shapes it was measured to win, the reference-equivalent jnp softmax
 
 from __future__ import annotations
 
+import contextlib
 import math
+import threading
 from typing import Optional
 
 import jax
@@ -180,7 +182,8 @@ _MAX_DIAG_ROWS = 256
 
 def _as_gathered(q, store, max_diag_rows: Optional[int] = None) -> bool:
     """Whether a program contracts its cached rows as stored, heads on
-    the lane diagonal (:func:`_lane_diag_sdpa`): the rows are stored in
+    the lane diagonal (:func:`_lane_diag_sdpa` over a ring,
+    :func:`_paged_attend_walk` over the pool): the rows are stored in
     a float narrower than ``q`` [S, Hq, rows, Dh] and the query rows a
     sequence are few (``max_diag_rows``; None: :data:`_MAX_DIAG_ROWS`,
     read when the program is traced)."""
@@ -286,15 +289,11 @@ def _lane_diag_sdpa(q, k_rows, v_rows, valid, *, kv_heads: int,
         b, hq, s, dh = q.shape
         _, m, bs, f = k_rows.shape
         rows, t = hq * s, m * bs
-        pad = max(_MIN_DOT_ROWS - rows, 0)
-        own = (jnp.arange(hq)[:, None] // (hq // kv_heads)
-               == jnp.arange(kv_heads)[None, :])[None, :, None, :, None]
-        qd = jnp.where(own, _as_stored(q, k_rows)[:, :, :, None, :], 0)
-        qd = jnp.pad(qd.reshape(b, rows, kv_heads * dh),
-                     ((0, 0), (0, pad), (0, f - kv_heads * dh)))
+        qd, own = _diag_queries(q, k_rows, kv_heads,
+                                max(_MIN_DOT_ROWS, rows))
         valid = jnp.pad(
             jnp.broadcast_to(valid, (b, hq, s, t)).reshape(b, rows, t),
-            ((0, 0), (0, pad), (0, 0)))
+            ((0, 0), (0, qd.shape[1] - rows), (0, 0)))
         scores = jnp.einsum("brf,btf->brt", qd, k_rows.reshape(b, t, f),
                             preferred_element_type=jnp.float32)
         scores = (scores / math.sqrt(dh) if scale is None
@@ -304,9 +303,37 @@ def _lane_diag_sdpa(q, k_rows, v_rows, valid, *, kv_heads: int,
             jax.nn.softmax(scores, axis=-1).astype(q.dtype), v_rows)
         o = jnp.einsum("brt,btf->brf", probs, v_rows.reshape(b, t, f),
                        preferred_element_type=jnp.float32)
-        o = o[:, :rows, :kv_heads * dh].reshape(b, hq, s, kv_heads, dh)
-        return jnp.where(own, o, 0).sum(axis=3).astype(
+        return _off_diagonal(o, own, q.shape).astype(
             jnp.result_type(q, v_rows))
+
+
+def _diag_queries(q, store, kv_heads: int, padded_rows: int):
+    """q [B, Hq, S, Dh] spread over the lanes of ``store``'s rows
+    [..., F]: ``(qd, own)`` — ``qd`` [B, R, F] in the store's dtype, row
+    ``h * S + i`` holding its ``Dh`` values in the lanes of the kv head
+    it reads (``h // (Hq // kv_heads)``) and exact zeros in every other
+    lane, the pad lanes included, with zero rows up to ``R =
+    padded_rows``; ``own`` the [1, Hq, 1, kv_heads, 1] mask that placed
+    them, which :func:`_off_diagonal` takes the result back with."""
+    b, hq, s, dh = q.shape
+    rows, f = hq * s, store.shape[-1]
+    own = (jnp.arange(hq)[:, None] // (hq // kv_heads)
+           == jnp.arange(kv_heads)[None, :])[None, :, None, :, None]
+    qd = jnp.where(own, _as_stored(q, store)[:, :, :, None, :], 0)
+    return jnp.pad(qd.reshape(b, rows, kv_heads * dh),
+                   ((0, 0), (0, padded_rows - rows),
+                    (0, f - kv_heads * dh))), own
+
+
+def _off_diagonal(o, own, q_shape):
+    """Each query row's own head off the diagonal of ``o`` [B, R, F]
+    (:func:`_diag_queries`'s rows, the pad rows and lanes dropped):
+    [B, Hq, S, Dh], f32 — the other heads' lanes are selected out, not
+    summed."""
+    b, hq, s, dh = q_shape
+    kv_heads = own.shape[3]
+    o = o[:, :hq * s, :kv_heads * dh].reshape(b, hq, s, kv_heads, dh)
+    return jnp.where(own, o, 0).sum(axis=3)
 
 
 def sdpa(q, k, v, *, causal: bool, softmax_dtype=jnp.float32,
@@ -535,9 +562,10 @@ def mha_apply(
 # width serve/kv_pool.py allocates (``F >= H * Dh``, a whole number of
 # 128-lane vregs, so the chip lays the buffer out row-major). Programs
 # carry the pool WHOLE through their layer loop and address it by
-# ``(layer, slot)``: one scatter a write, one gather a read, in place.
-# Only the gathered view is split back into heads, and only where a
-# program has many query rows (:data:`_MAX_DIAG_ROWS`). ``F`` is read
+# ``(layer, slot)``: one scatter a write, in place; a read is a walk of
+# each row's live blocks where a program has few query rows
+# (:data:`_MAX_DIAG_ROWS`: decode, verify) and one gather of the table's
+# width, split back into heads, where it has many. ``F`` is read
 # off the pool's shape, ``H`` and ``Dh`` off the fresh projections.
 # ---------------------------------------------------------------------
 def _pool_rows(x, width: int):
@@ -780,12 +808,10 @@ def _quant_span(p_tokens: int, block_size: int, table_width: int) -> int:
 
 
 def paged_kv_step(pools, layer, k, v, positions, lens, block_tables, *,
-                  block_size: int, policy=None, split_heads: bool = True):
+                  block_size: int, policy=None):
     """Write the rows' fresh (k, v) runs into ``layer`` of the pool and
     read every row's whole history back: ``(k_all, v_all, pools)``,
-    the views [S, H, M*bs, Dh] holding the runs just written — or,
-    with ``split_heads`` off (passthrough pools only), the rows as
-    gathered, [S, M, bs, F] (:func:`paged_gather`).
+    the views [S, H, M*bs, Dh] holding the runs just written.
 
     ``pools`` = (k, v) under a passthrough policy: scatter, then gather
     (:func:`paged_write`, :func:`_gather_kv`). ``pools`` = (k, v,
@@ -793,7 +819,7 @@ def paged_kv_step(pools, layer, k, v, positions, lens, block_tables, *,
     DEQUANT, insert the run into the f32 view, quantize exactly the
     touched blocks back (:func:`paged_quant_update`) — the scores read
     the exact f32 run, the pool its quantized bytes."""
-    head_shape = (k.shape[1], k.shape[3]) if split_heads else None
+    head_shape = (k.shape[1], k.shape[3])
     if len(pools) == 2:
         pools = paged_write(*pools, layer, k, v, positions, lens,
                             block_tables=block_tables,
@@ -895,18 +921,20 @@ def paged_attend(q, k, v, pools, layer, positions, lens, block_tables, *,
     (chunked) prefill are this one mask at different widths, which is
     what makes their tokens bit-equal.
 
-    ``attn_kernel``: "xla" is the gathered-view math
-    (:func:`paged_kv_step`, then :func:`_masked_sdpa` on the view split
-    into heads or — a bf16/f16 pool under at most
-    :data:`_MAX_DIAG_ROWS` query rows, i.e. decode and verify —
-    :func:`_lane_diag_sdpa` on the rows as gathered: the same
-    arithmetic, chosen from shapes and dtypes alone); "pallas" the
-    fused kernel (:func:`_paged_attend_pallas`) — same mask, same
-    softmax sequence, bit-parity-pinned against this path.
+    ``attn_kernel``: "xla" is the code's own best path, chosen from
+    shapes and dtypes alone: a bf16/f16 pool under at most
+    :data:`_MAX_DIAG_ROWS` query rows — decode and verify — walks each
+    row's LIVE key blocks of the pool in place, heads on the lane
+    diagonal (:func:`_paged_attend_walk`); everything else gathers the
+    table's width (:func:`paged_kv_step`) and scores the view split
+    into heads (:func:`_masked_sdpa`). The same mask and rounding
+    contract in both. "pallas" is the whole-row fused kernel
+    (:func:`_paged_attend_pallas`), bit-parity-pinned against the
+    gathered view.
 
     ``max_diag_rows`` overrides :data:`_MAX_DIAG_ROWS` where the caller
     knows its program's kind better than a row count does (a family
-    with 64 query heads keeps a verify run on the diagonal, and a
+    with 64 query heads keeps a verify run on the walk, and a
     narrow prefill bucket off it); ``key_block`` (positions, a multiple
     of ``block_size``) scores the run a block of keys at a time, only
     the blocks it can see (:func:`_paged_attend_key_blocked`: a prefill
@@ -927,25 +955,135 @@ def paged_attend(q, k, v, pools, layer, positions, lens, block_tables, *,
         return _paged_attend_key_blocked(
             q, k, v, pools, layer, positions, lens, block_tables,
             block_size=block_size, key_block=key_block, scale=scale)
-    # the rows as gathered iff they are stored in a float narrower than
-    # q (what _masked_sdpa calls `stored`; a scaled or float8 pool's
-    # view is a widened f32 one) and the program has few query rows
-    diag = len(pools) == 2 and _as_gathered(q, pools[0], max_diag_rows)
+    # a row's live blocks, in place, iff the rows are stored in a float
+    # narrower than q (what _masked_sdpa calls `stored`; a scaled or
+    # float8 pool's view is a widened f32 one) and the program has few
+    # query rows
+    if len(pools) == 2 and _as_gathered(q, pools[0], max_diag_rows):
+        return _paged_attend_walk(
+            q, k, v, pools, layer, positions, lens, block_tables,
+            block_size=block_size, scale=scale)
+    _note_read(block_tables.shape[1] * block_size)
     k_all, v_all, pools = paged_kv_step(
         pools, layer, k, v, positions, lens, block_tables,
-        block_size=block_size, policy=policy, split_heads=not diag)
+        block_size=block_size, policy=policy)
     rep = q.shape[1] // k.shape[1]
+    o = _masked_sdpa(q, repeat_kv(k_all, rep), repeat_kv(v_all, rep),
+                     _seen(positions, q, block_tables, block_size),
+                     page=block_size, scale=scale)
+    return o, pools
+
+
+def _seen(positions, q, block_tables, block_size: int):
+    """:func:`paged_attend`'s mask over a whole table: column ``t`` of a
+    row's view is valid for the query at ``positions[s, i]`` iff ``t <=
+    positions[s, i]``. [S, 1, rows of q a head, T]."""
     valid = (jnp.arange(block_tables.shape[1] * block_size)[None, None, :]
-             <= positions[:, :, None])[:, None]           # [S, 1, P, T]
-    groups = q.shape[2] // k.shape[2]
+             <= positions[:, :, None])[:, None]            # [S, 1, P, T]
+    groups = q.shape[2] // positions.shape[1]
     if groups > 1 and valid.shape[2] > 1:
         valid = jnp.tile(valid, (1, 1, groups, 1))
-    if diag:
-        return _lane_diag_sdpa(q, k_all, v_all, valid, kv_heads=k.shape[1],
-                               scale=scale), pools
-    o = _masked_sdpa(q, repeat_kv(k_all, rep), repeat_kv(v_all, rep),
-                     valid, page=block_size, scale=scale)
-    return o, pools
+    return valid
+
+
+# Positions a trip of the per-row walk reads: a row holding ``n``
+# positions reads ``ceil(n / WALK_KEY_BLOCK)`` blocks of this many, so
+# half a block a row is read for nothing, and a trip costs its DMA
+# descriptors and the recurrence's rescale whatever it carries.
+WALK_KEY_BLOCK = 256
+
+# What the paged program being TRACED on this thread inside
+# :func:`noted_reads` reads of a row's table, one entry a
+# :func:`paged_attend` call: the positions a read is rounded up to (the
+# walk's key block; the table's whole width where the view is still
+# gathered).
+_READS = threading.local()
+
+
+def _note_read(granule: int) -> None:
+    notes = getattr(_READS, "notes", None)
+    if notes is not None:
+        notes.append(granule)
+
+
+@contextlib.contextmanager
+def noted_reads():
+    """Collect what every :func:`paged_attend` call traced inside, on
+    this thread, reads of a row's table (the list yielded): a row at
+    position ``p`` reads ``ceil((p + 1) / g) * g`` positions of a call
+    that noted ``g``. The engine's ``attended_rows`` is counted from
+    it. One program body at a time: it does not nest."""
+    _READS.notes = notes = []
+    try:
+        yield notes
+    finally:
+        _READS.notes = None
+
+
+def _paged_attend_walk(q, k, v, pools, layer, positions, lens,
+                       block_tables, *, block_size: int,
+                       scale: Optional[float]):
+    """:func:`paged_attend` for a program with FEW query rows on a
+    bf16/f16 pool (decode, verify): the run is written, then each row
+    walks its OWN live key blocks of ``(pool, layer)`` where they lie —
+    ``max(positions[s]) // WALK_KEY_BLOCK + 1`` of them, one for a row
+    at position 0 — and folds them into a running softmax
+    (ops/paged_attention.paged_walk_attention). No view of the table's
+    width exists, a block's rows cross HBM once, and a short or empty
+    row costs a block where a full one costs the table. The arithmetic
+    is :func:`_lane_diag_sdpa`'s — pool rows as stored, heads on the
+    lane diagonal of the small operand (GQA without a repeat, ``scale``
+    honoured), q and the probabilities rounded to the pool's dtype, f32
+    sums, f32 softmax, the mask ``t <= positions[s, i]`` — with the
+    softmax's sums in key-block order.
+
+    The kernel is the TPU's, for pages of whole HBM tiles
+    (ops/paged_attention.walk_lowers_for): a program lowered for another
+    platform with no interpreter asked for, or over narrower pages,
+    keeps :func:`_lane_diag_sdpa` on the gathered view — both traced
+    under ``lax.platform_dependent``, as :func:`local_attention` does,
+    where this process's backend and the chip would choose apart."""
+    from quintnet_tpu.ops.paged_attention import (paged_walk_attention,
+                                                  walk_lowers_for)
+
+    _, hkv, P, dh = k.shape
+    rows = q.shape[1] * q.shape[2]
+    # whole pages a trip, the table's at most
+    key_block = block_size * max(
+        min(WALK_KEY_BLOCK // block_size, block_tables.shape[1]), 1)
+    here = walk_lowers_for(jax.default_backend(), block_size)
+    # what THIS process's program reads of a row
+    _note_read(key_block if here else block_tables.shape[1] * block_size)
+    pools = paged_write(*pools, layer, k, v, positions, lens,
+                        block_tables=block_tables, block_size=block_size)
+    # whole sublane tiles of the stored dtype (16 rows of bf16)
+    tile = 8 * 4 // pools[0].dtype.itemsize
+    padded = -(-rows // tile) * tile
+
+    def walk(q, k_pool, v_pool, layer, positions, block_tables):
+        with jax.named_scope("sdpa"):
+            qd, own = _diag_queries(q, k_pool, hkv, padded)
+            qpos = jnp.pad(jnp.tile(positions, (1, rows // P)),
+                           ((0, 0), (0, padded - rows)), constant_values=-1)
+            o = paged_walk_attention(
+                qd, qpos, k_pool, v_pool, layer, block_tables,
+                block_size=block_size, key_block=key_block, head_dim=dh,
+                scale=scale)
+            return _off_diagonal(o, own, q.shape).astype(
+                jnp.result_type(q, v_pool))
+
+    def gathered(q, k_pool, v_pool, layer, positions, block_tables):
+        k_rows, v_rows = _gather_kv((k_pool, v_pool), layer, None,
+                                    block_tables, block_size=block_size,
+                                    head_shape=None)
+        return _lane_diag_sdpa(
+            q, k_rows, v_rows, _seen(positions, q, block_tables, block_size),
+            kv_heads=hkv, scale=scale)
+
+    args = (q, *pools, layer, positions, block_tables)
+    if here == walk_lowers_for("tpu", block_size):
+        return (walk if here else gathered)(*args), pools
+    return lax.platform_dependent(*args, tpu=walk, default=gathered), pools
 
 
 def _paged_attend_key_blocked(q, k, v, pools, layer, positions, lens,

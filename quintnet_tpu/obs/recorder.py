@@ -162,18 +162,35 @@ class StepRecorder:
             return [r.to_dict() for r in window[:take]]
 
 
-_LIVE: "weakref.WeakSet[StepRecorder]" = weakref.WeakSet()
+# owner (an engine) -> its ring: an entry goes with its OWNER
+_LIVE: "weakref.WeakKeyDictionary[object, StepRecorder]" = \
+    weakref.WeakKeyDictionary()
 _LIVE_LOCK = threading.Lock()
+# the ring registered last, held strongly: what live() falls back to
+# when no owner is alive
+_NEWEST: Optional[StepRecorder] = None
 
 
-def register(recorder: StepRecorder) -> None:
-    """Make an engine's ring findable through :func:`live`. Held
-    weakly: a ring goes with the engine that owns it."""
+def register(recorder: StepRecorder, owner: object) -> None:
+    """Make ``owner``'s ring findable through :func:`live` for as long
+    as the owner lives (an owner has one ring: a later one replaces
+    it)."""
+    global _NEWEST
     with _LIVE_LOCK:
-        _LIVE.add(recorder)
+        _LIVE[owner] = recorder
+        _NEWEST = recorder
 
 
 def live() -> List[StepRecorder]:
-    """The rings of the engines alive in this process."""
+    """The rings of the engines alive in this process; when NONE is,
+    the ring registered last. The reader this exists for holds no
+    engine, so it may come when the last engine's last reference is
+    gone (a benchmark reads its per-layer metrics after its driver
+    returned): what it found then depended on whether the garbage
+    collector had reached the engine's cycles yet — a cell that made
+    more steps, hence more records a reading, lost its last metric to
+    a collection in the middle of its readers (PR 34). While any engine
+    lives a dropped one's ring is never listed beside it."""
     with _LIVE_LOCK:
-        return list(_LIVE)
+        rings = list(_LIVE.values())
+        return rings or ([_NEWEST] if _NEWEST is not None else [])

@@ -2,7 +2,8 @@
 
 The serving attention entry point (nn/attention.py ``paged_attend``,
 under ``mha_verify_paged`` and the llama and hybrid blocks) is
-gathered-view math on the XLA path: materialize every block of a row's
+gathered-view math wherever a program has many query rows or the pool
+is f32, scaled or float8: materialize every block of a row's
 block table into a position-ordered ``[S, H, T, Dh]`` HBM view
 (``paged_gather``), matmul against it, and — under a scaled KV layout
 policy — run a separate dequantize pass before the matmul ever sees a
@@ -67,6 +68,22 @@ online-softmax accumulation overlapping the walk, Flash-Decoding's
 KV-split for long single-row contexts — PAPERS.md), which trades the
 bit-parity pin for a bounded-ulp one and is ROADMAP S3's work, gated
 behind the same parity suite.
+
+**The per-row walk** (:func:`paged_walk_attention`, PR 34) is that
+flash recurrence for the programs with FEW query rows a sequence
+(decode, verify) on a bf16/f16 pool — what ``attn_kernel="xla"`` runs
+there, chosen by nn/attention.paged_attend from dtypes and shapes. It
+differs from the whole-row kernel above in every respect the walls
+above name: the pool is passed WHOLE, in HBM, addressed ``(layer,
+page)`` (no layer's view is cut out of the carried buffer); a row's
+live pages are DMA'd ``key_block`` positions at a time into a double
+buffer, the next block — or the next row's first — in flight while
+this one is scored; ``m``, ``l`` and ``acc`` run in scratch, so VMEM
+holds two key blocks and an ``[R, F]`` accumulator whatever the
+table's width (17,408 positions in the window cell); and the heads
+stay on the lane diagonal of the small operand, so a page is
+contracted as stored. It is held to the gathered form by the rounding
+bound, not to bits (tests/test_paged_attention.py ``TestRowWalk``).
 
 Interpret mode is the CALLER's decision, never the kernel's: the
 default is the compiled Mosaic kernel on whatever backend the process
@@ -460,3 +477,183 @@ def paged_quant_window_update(policy, cache, scales, vals, positions,
     cache = pool4.at[flat].set(qn).reshape(nb * bs, H, D)
     scales = scales.at[flat].set(sc.transpose(0, 2, 1).reshape(S * K, H))
     return cache, scales
+
+
+# ---------------------------------------------------------------------
+# The PER-ROW walk (decode, verify): each row reads its own live key
+# blocks of the carried pool, in place, and no more.
+# ---------------------------------------------------------------------
+def walk_lowers_for(backend: str, block_size: int) -> bool:
+    """Whether :func:`paged_walk_attention` can be lowered for
+    ``backend``: under the interpreter the caller turned on
+    (:data:`INTERPRET`) always; for a TPU when a page is whole tiles of
+    the pool in HBM (8 rows: Mosaic slices a DMA's source no finer);
+    for nothing else."""
+    return INTERPRET or (backend == "tpu" and block_size % 8 == 0)
+
+
+def _page_copy(pool, buf, sem, tbl_ref, layer, s, c, slot, i, *,
+               block_size: int, pages: int):
+    """The DMA descriptor of page ``i`` of key block ``c`` of row ``s``:
+    table entry ``c * pages + i`` — ``block_size`` pool rows, contiguous
+    in HBM — into its place in ``buf[slot]``. An entry past the table's
+    end re-reads its last (its positions are masked)."""
+    blk = tbl_ref[s, jnp.minimum(c * pages + i, tbl_ref.shape[1] - 1)]
+    return pltpu.make_async_copy(
+        pool.at[layer, pl.ds(pl.multiple_of(blk * block_size, block_size),
+                             block_size)],
+        buf.at[slot, pl.ds(pl.multiple_of(i * block_size, block_size),
+                           block_size)],
+        sem.at[slot])
+
+
+def _walk_kernel(layer_ref, tbl_ref, trips_ref, first_ref, qd_ref,
+                 qpos_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, k_sem, v_sem,
+                 m_scr, l_scr, acc_scr, *, block_size: int, pages: int,
+                 head_dim: int, scale):
+    """One row of the grid: the flash recurrence over the row's
+    ``trips_ref[s]`` live key blocks. The pool stays in HBM; block ``c
+    + 1`` (or the next row's first) is in flight into the other half of
+    ``k_buf`` / ``v_buf`` while block ``c`` is scored, so the walk never
+    waits on a row's end. ``first_ref[s]`` is the number of blocks the
+    rows before ``s`` walked: the parity of ``first + c`` is the buffer
+    half, carried across rows without a counter."""
+    s = pl.program_id(0)
+    rows = pl.num_programs(0)
+    layer = layer_ref[0]
+    trips, first = trips_ref[s], first_ref[s]
+    kb = pages * block_size
+
+    def fetch(s_, c_, slot, act):
+        """Start or wait for (``act``) the pages of key block ``c_`` of
+        row ``s_`` into half ``slot``, k and v. One page's code, traced
+        once and unrolled when the kernel is lowered: sixteen pages a
+        trip written out in Python cost the decode program's first
+        trace half a second."""
+        def page(i, _):
+            for pool, buf, sem in ((k_hbm, k_buf, k_sem),
+                                   (v_hbm, v_buf, v_sem)):
+                act(_page_copy(pool, buf, sem, tbl_ref, layer, s_, c_,
+                               slot, i, block_size=block_size,
+                               pages=pages))
+            return 0
+        lax.fori_loop(0, pages, page, 0, unroll=True)
+
+    start, wait = (lambda copy: copy.start()), (lambda copy: copy.wait())
+
+    @pl.when(s == 0)
+    def _first_block():
+        fetch(0, 0, 0, start)
+
+    neg = jnp.finfo(jnp.float32).min
+    m_scr[...] = jnp.full_like(m_scr, neg)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+    qd = qd_ref[0]                                   # [R, F], stored dtype
+    qpos = qpos_ref[0]                               # [R, 1]
+
+    def block(c, _):
+        slot = (first + c) % 2
+        more = c + 1 < trips
+
+        @pl.when(more | (s + 1 < rows))
+        def _next_block():
+            fetch(jnp.where(more, s, s + 1), jnp.where(more, c + 1, 0),
+                  1 - slot, start)
+
+        fetch(s, c, slot, wait)
+        scores = lax.dot_general(
+            qd, k_buf[slot], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)       # [R, kb]
+        scores = (scores / math.sqrt(head_dim) if scale is None
+                  else scores * scale)
+        seen = (c * kb + lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+                <= qpos)
+        scores = jnp.where(seen, scores, neg)
+        m_old = m_scr[...]
+        m_new = jnp.maximum(m_old, jnp.max(scores, axis=1, keepdims=True))
+        grow = jnp.exp(m_old - m_new)
+        p = jnp.exp(scores - m_new)
+        l_scr[...] = l_scr[...] * grow + jnp.sum(p, axis=1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * grow + lax.dot_general(
+            p.astype(v_buf.dtype), v_buf[slot], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)       # [R, F]
+        m_scr[...] = m_new
+        return 0
+
+    lax.fori_loop(0, trips, block, 0)
+    o_ref[0] = acc_scr[...] / l_scr[...]
+
+
+def paged_walk_attention(qd, qpos, k_pool, v_pool, layer, block_tables, *,
+                         block_size: int, key_block: int, head_dim: int,
+                         scale=None, interpret=None):
+    """Few query rows a sequence against each sequence's LIVE blocks of
+    the carried pool (nn/attention.paged_attend's decode and verify
+    form). ``qd`` [S, R, F]: a sequence's query rows in the pool's
+    dtype, each holding its ``head_dim`` values in the lanes of the kv
+    head it reads and exact zeros elsewhere (heads on the lane
+    diagonal: nn/attention._diag_queries), ``R`` a whole number of
+    sublane tiles; ``qpos`` [S, R]: the position each row's query sits
+    at, -1 for a pad row. ``k_pool`` / ``v_pool`` [L, slots, F] whole,
+    read at ``layer`` (a traced scalar) through ``block_tables`` [S, M].
+
+    Row ``s`` reads key blocks ``0 .. max(qpos[s]) // key_block`` and no
+    more — ``key_block`` positions, a whole number of pages, a trip —
+    straight out of the pool in HBM, double-buffered, and folds them
+    into a running softmax: scores ``[R, F] x [key_block, F]`` in the
+    stored dtype with f32 sums, the mask ``t <= qpos``, f32 ``exp``,
+    the probabilities rounded to the stored dtype before ``[R,
+    key_block] x [key_block, F]``. Returns ``o`` [S, R, F] f32,
+    normalised: row ``r``'s output is in its own head's lanes."""
+    if interpret is None:
+        interpret = INTERPRET
+    S, R, F = qd.shape
+    M = block_tables.shape[1]
+    pages = max(min(key_block // block_size, M), 1)
+    kb = pages * block_size
+    last = jnp.max(qpos, axis=1)
+    trips = jnp.clip(last // kb + 1, 1, -(-M // pages)).astype(jnp.int32)
+    first = (jnp.cumsum(trips) - trips).astype(jnp.int32)
+    vmem = (2 * 2 * _padded_bytes((kb, F), k_pool.dtype)      # k, v halves
+            + 2 * 2 * _padded_bytes((R, F), qd.dtype)         # q, pipelined
+            + 2 * _padded_bytes((R, F), jnp.float32) * 2      # o, pipelined
+            + 3 * _padded_bytes((R, F), jnp.float32)          # acc + temps
+            + 4 * _padded_bytes((R, kb), jnp.float32))        # scores, p
+    kernel = functools.partial(_walk_kernel, block_size=block_size,
+                               pages=pages, head_dim=head_dim, scale=scale)
+    row = lambda s, *_: (s, 0, 0)                             # noqa: E731
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(S,),
+        in_specs=[pl.BlockSpec((1, R, F), row),
+                  pl.BlockSpec((1, R, 1), row),
+                  # wherever the compiler keeps the carried pool: HBM
+                  # at any deployment's size (a pool of a few tens of
+                  # MB it moves into VMEM whole, scatter and all)
+                  pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, R, F), row),
+        scratch_shapes=[
+            pltpu.VMEM((2, kb, F), k_pool.dtype),
+            pltpu.VMEM((2, kb, F), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.VMEM((R, 1), jnp.float32),
+            pltpu.VMEM((R, 1), jnp.float32),
+            pltpu.VMEM((R, F), jnp.float32),
+        ])
+    with jax.named_scope("paged_walk"):
+        return pl.pallas_call(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((S, R, F), jnp.float32),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",),
+                vmem_limit_bytes=min(max(2 * vmem, 32 * 2 ** 20),
+                                     VMEM_CAP_BYTES)),
+            interpret=interpret,
+            name="paged_walk_attention",
+        )(jnp.reshape(layer, (1,)).astype(jnp.int32),
+          block_tables.astype(jnp.int32), trips, first,
+          qd, qpos[..., None].astype(jnp.int32), k_pool, v_pool)

@@ -144,6 +144,7 @@ from quintnet_tpu.analysis.recompile import (RecompileError,
 from quintnet_tpu.analysis.specs import lora_rank_buckets as _rank_buckets
 from quintnet_tpu.analysis.specs import prefill_buckets as _spec_buckets
 from quintnet_tpu.models.gpt2_generate import sample_logits
+from quintnet_tpu.nn.attention import noted_reads
 from quintnet_tpu.obs.recorder import StepRecord, StepRecorder
 from quintnet_tpu.obs.recorder import register as register_recorder
 from quintnet_tpu.obs.spans import SERVE_STEP, StepPhases
@@ -872,6 +873,10 @@ class ServeEngine:
         # largest bound adapter. Keyed by bucket; None = the
         # adapter-blind program.
         decode_donate = pool_idx + (n_pool + 1, n_pool + 4)
+        # program name -> the positions its paged layers round a row's
+        # read up to (nn/attention.noted_reads), known once the program
+        # is traced: the walk's key block, None for the table's width
+        self._read_granule: Dict[str, Optional[int]] = {}
         if self.adapters is None:
             self._decode = RecompileSentinel(
                 "serve.decode",
@@ -925,6 +930,10 @@ class ServeEngine:
             param_bytes=sum(int(x.nbytes)
                             for x in jax.tree.leaves(self.params)),
             kv_bytes_per_token=self.pool.bytes_per_token,
+            # the layers that page every position into the pool: a
+            # step's ``attended_rows`` over ``context_tokens`` x this
+            # is how many times what its rows hold the programs read
+            paged_layers=self.pool.n_layers,
             # the part of ``param_bytes`` that is routed experts' (nodes
             # under an ``experts`` key: the dropless router's): a step
             # reads only those of them that received a row
@@ -946,7 +955,7 @@ class ServeEngine:
             programs=sorted(s.fn.__name__ for s in (
                 *self._prefills.values(), *self._decodes.values(),
                 *self._verifies.values())))
-        register_recorder(rec)
+        register_recorder(rec, self)
 
     def _check_pallas_vmem(self) -> None:
         """Refuse, at construction and with the computed number, any
@@ -1108,12 +1117,14 @@ class ServeEngine:
                 extra = {slot_buffers: (buf_a, buf_b)}
             tok, pos, tables, key_data, *rest = rest
             lora, lora_scale = rest if use_lora else (None, None)
-            out = family.decode(
-                params, k_pool, v_pool, tok, pos, tables, bs,
-                tp_axis=tp_axis, ep_axis=ep_axis,
-                lora=lora, lora_scale=lora_scale,
-                kv_scales=(k_scale, v_scale) if scaled else None,
-                policy=policy, attn_kernel=attn_kernel, **extra)
+            with noted_reads() as reads:
+                out = family.decode(
+                    params, k_pool, v_pool, tok, pos, tables, bs,
+                    tp_axis=tp_axis, ep_axis=ep_axis,
+                    lora=lora, lora_scale=lora_scale,
+                    kv_scales=(k_scale, v_scale) if scaled else None,
+                    policy=policy, attn_kernel=attn_kernel, **extra)
+            self._read_granule[name] = max(reads, default=None)
             logits, pools = out[0], out[1:]
             with jax.named_scope("sample"):
                 keys = jax.random.wrap_key_data(key_data)
@@ -1153,12 +1164,14 @@ class ServeEngine:
                 k_scale, v_scale, *rest = rest
             ids, starts, tail_lens, tables, key_data, *rest = rest
             lora, lora_scale = rest if use_lora else (None, None)
-            out = family.verify(
-                params, k_pool, v_pool, ids, starts, tail_lens, tables,
-                bs, tp_axis=tp_axis, ep_axis=ep_axis, lora=lora,
-                lora_scale=lora_scale,
-                kv_scales=(k_scale, v_scale) if scaled else None,
-                policy=policy, attn_kernel=attn_kernel)
+            with noted_reads() as reads:
+                out = family.verify(
+                    params, k_pool, v_pool, ids, starts, tail_lens,
+                    tables, bs, tp_axis=tp_axis, ep_axis=ep_axis,
+                    lora=lora, lora_scale=lora_scale,
+                    kv_scales=(k_scale, v_scale) if scaled else None,
+                    policy=policy, attn_kernel=attn_kernel)
+            self._read_granule[name] = max(reads, default=None)
             logits, pools = out[0], out[1:]               # [S, P, V]
             P = ids.shape[1]
 
@@ -1910,6 +1923,18 @@ class ServeEngine:
     # ------------------------------------------------------------------
     # MoE routing-stats ledger (serve/metrics.py)
     # ------------------------------------------------------------------
+    def _attended_rows(self, sentinel, last) -> int:
+        """Pool positions x layers the paged layers of the decode or
+        verify program ``sentinel`` READ in a step whose rows' last
+        positions were ``last`` [max_slots] (0 for a row that sat out):
+        each row rounded up to the program's read granule — a key block
+        where it walks the row's live blocks, the table's whole width
+        where it still gathers a view."""
+        width = self.table_width * self.pool.block_size
+        g = self._read_granule.get(sentinel.fn.__name__) or width
+        read = np.minimum((np.asarray(last, np.int64) // g + 1) * g, width)
+        return int(read.sum()) * self.pool.n_layers
+
     def _pop_moe(self, pools, *, note: bool = True, decode: bool = False):
         """Split the trailing routing-stats dict off a MoE program's
         pool outputs (serve/families.py widens every MoE program's
@@ -2210,7 +2235,7 @@ class ServeEngine:
 
     def _verify_step(self, active: List[int],
                      drafts: Dict[int, np.ndarray],
-                     finished: List[int]) -> Tuple[int, int, int]:
+                     finished: List[int]) -> Tuple[int, int, int, int]:
         """One batched verify: write every slot's run (last token +
         draft) through the paged pool, read back per-position candidate
         tokens + the PRNG split chain, commit the longest matching
@@ -2222,7 +2247,7 @@ class ServeEngine:
         preempts); after acceptance the blocks the new committed length
         reaches are committed, the rest rolled back, so published
         chains never observe draft slots. Returns (committed tokens,
-        drafted tokens, accepted draft tokens)."""
+        drafted tokens, accepted draft tokens, attended rows)."""
         S = self.max_slots
         tentative: Dict[int, List[int]] = {}
         for slot in active:
@@ -2265,13 +2290,15 @@ class ServeEngine:
         with ph.phase("dispatch"):
             *pools, toks, chain = self._verifies[k_bucket](
                 self.params, *self.pool.caches(), *args, *extra)
+        attended = self._attended_rows(self._verifies[k_bucket],
+                                       starts + k_bucket)
         self.pool.update(*self._pop_moe(pools))
         with ph.wait(2):
             toks = np.asarray(toks)
             chain = np.asarray(chain)
         with ph.phase("commit"):
-            return self._commit_verified(active, drafts, tentative, toks,
-                                         chain, finished)
+            return (*self._commit_verified(active, drafts, tentative, toks,
+                                           chain, finished), attended)
 
     def _commit_verified(self, active, drafts, tentative, toks, chain,
                          finished) -> Tuple[int, int, int]:
@@ -2440,14 +2467,15 @@ class ServeEngine:
             len(decoding) + (prefill_chunks if self.chunked_prefill
                              else m.admitted - rec_admitted0))
         decode_tokens = 0
-        draft_tokens = accepted_draft = 0
+        draft_tokens = accepted_draft = attended_rows = 0
         spec_step = False
         if decoding:
             drafts = self._propose_drafts(decoding)
             if drafts is not None:
                 spec_step = True
-                decode_tokens, draft_tokens, accepted_draft = \
-                    self._verify_step(decoding, drafts, finished)
+                (decode_tokens, draft_tokens, accepted_draft,
+                 attended_rows) = self._verify_step(decoding, drafts,
+                                                    finished)
             else:
                 # structural tier invariant: the plain decode dispatch
                 # performs NO pool acquires, so it can never trigger a
@@ -2480,6 +2508,7 @@ class ServeEngine:
                 with ph.phase("dispatch"):
                     *pools, nxt, key2 = sentinel(
                         self.params, *self.pool.caches(), *args, *extra)
+                attended_rows = self._attended_rows(sentinel, pos)
                 self.pool.update(*self._pop_moe(pools, decode=True))
                 with ph.wait(2):
                     nxt = np.asarray(nxt)
@@ -2560,6 +2589,8 @@ class ServeEngine:
                 h2d_bytes=ph.h2d_bytes, context_tokens=context_tokens,
                 state_bytes=state_bytes,
                 attrs={**window_attrs,
+                       **({"attended_rows": attended_rows}
+                          if decoding else {}),
                        **{k: (v.tolist() if isinstance(v, np.ndarray)
                               else v) for k, v in moe_kw.items()}}))
         if self.log_every:

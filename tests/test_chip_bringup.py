@@ -78,6 +78,19 @@ def chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
+@pytest.fixture(autouse=True)
+def _kernels_compile_for_the_chip(request, monkeypatch):
+    """A test that compiles for the described chip gets the KERNELS the
+    chip would run: the session's interpreter switch (conftest.py turns
+    it on for the CPU) is off for it, so a program that chooses its
+    attention at lowering (nn/attention._paged_attend_walk) lowers the
+    per-row walk's Mosaic kernel, not its emulation."""
+    if {"chip", "topo"} & set(request.fixturenames):
+        monkeypatch.setattr(
+            importlib.import_module("quintnet_tpu.ops.paged_attention"),
+            "INTERPRET", False)
+
+
 def _compile(fn, *shapes):
     text = jax.jit(fn).lower(*shapes).compile().as_text()
     assert "tpu_custom_call" in text
@@ -238,6 +251,45 @@ def test_paged_attention_compiles_for_v5e(chip, rows, queries, scaled):
 
 
 # ---------------------------------------------------------------------
+# PR 34: the per-row walk at the serving cells' own decode shapes
+# ---------------------------------------------------------------------
+@pytest.mark.parametrize("rows,queries,lanes,layers,blocks,table,dh", [
+    (12, 32, 1664, 48, 384, 64, 64),        # gpt2-xl.serve-chat-sat
+    (12, 128, 1664, 48, 384, 64, 64),       # a verify run of four drafts
+    (64, 32, 512, 4, 2048, 64, 64),         # granite-4.0-h-micro
+    (48, 48, 1024, 2, 22528, 1088, 128),    # laguna-xs.2.serve-code-sat
+], ids=["xl-decode", "xl-verify", "hybrid-decode", "window-decode"])
+def test_paged_walk_compiles_for_v5e(chip, rows, queries, lanes, layers,
+                                     blocks, table, dh):
+    """``paged_walk_attention`` takes the WHOLE pool of each serving
+    cell (1.5 GB a buffer in the window cell) as an HBM operand, its
+    block table (52,224 entries there) as a scalar prefetch, and fits
+    its double buffer of key blocks in VMEM, at the engine's key
+    block."""
+    from quintnet_tpu.nn.attention import WALK_KEY_BLOCK
+
+    pa = importlib.import_module("quintnet_tpu.ops.paged_attention")
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    pool = sds((layers, blocks * BS, lanes), jnp.bfloat16)
+
+    def fn(qd, qpos, k, v, layer, tables):
+        return pa.paged_walk_attention(
+            qd, qpos, k, v, layer, tables, block_size=BS,
+            key_block=WALK_KEY_BLOCK, head_dim=dh, interpret=False)
+
+    text = _compile(fn, sds((rows, queries, lanes), jnp.bfloat16),
+                    sds((rows, queries), jnp.int32), pool, pool,
+                    sds((), jnp.int32), sds((rows, table), jnp.int32))
+    # the pool goes in as it is: no copy, slice or re-layout of it
+    assert not [ln for ln in text.splitlines()
+                if f"bf16[{layers},{blocks * BS},{lanes}]" in ln
+                and (" copy(" in ln or " transpose(" in ln)]
+
+
+# ---------------------------------------------------------------------
 # the compile cache is placed from outside
 # ---------------------------------------------------------------------
 @pytest.fixture
@@ -257,10 +309,13 @@ def cache_config():
 # ---------------------------------------------------------------------
 def test_hybrid_decode_holds_one_copy_of_the_state_on_v5e(chip):
     """``granite_hybrid_family(...).decode`` at the published widths
-    (shapes only: ``jax.eval_shape``), 16 slots, pools and state
-    donated, compiled for the described chip. Stacked as a scan's ys
-    the state would be planned twice (1.15 GiB each here); carried and
-    updated in place, the temporaries stay under a third of it."""
+    (shapes only: ``jax.eval_shape``), the serving cell's 64 slots,
+    pools and state donated, compiled for the described chip. Stacked
+    as a scan's ys the state would be planned twice (4.6 GiB each
+    here); carried and updated in place, the temporaries stay under a
+    third of it. (64 slots, not fewer: a pool of a few tens of MB the
+    compiler moves into VMEM whole around the layer's scatter and the
+    per-row walk, PR 34 — a copy no deployment's pool can get.)"""
     import numpy as np
 
     from quintnet_tpu.models.granite_hybrid import (GraniteHybridConfig,
@@ -276,7 +331,7 @@ def test_hybrid_decode_holds_one_copy_of_the_state_on_v5e(chip):
                            "granite-4.0-h-micro.json")) as f:
         cfg = GraniteHybridConfig.from_dict(json.load(f))
     fam = granite_hybrid_family(cfg)
-    slots, bs, width = 16, 16, 64
+    slots, bs, width = 64, 16, 64
     policy = make_policy("bf16")
 
     def sds(shape, dtype):
@@ -308,7 +363,7 @@ def test_hybrid_decode_holds_one_copy_of_the_state_on_v5e(chip):
     assert plan.temp_size_in_bytes < state_bytes / 3, (
         plan.temp_size_in_bytes, state_bytes)
     _assert_pool_row_major_and_uncopied(compiled.as_text(), pool,
-                                        view=(slots, width, bs))
+                                        view=(slots, width, bs), walks=1)
 
 
 def test_latent_decode_contracts_the_rows_as_gathered_on_v5e(chip):
@@ -428,7 +483,8 @@ def test_window_decode_reads_rings_and_contracts_as_stored_on_v5e(chip):
     assert plan.alias_size_in_bytes >= cache_bytes     # in and out alias
     hlo = compiled.as_text()
     assert "ragged-dot" in hlo and "tpu_custom_call" in hlo
-    _assert_pool_row_major_and_uncopied(hlo, pool, view=(slots, width, bs))
+    _assert_pool_row_major_and_uncopied(hlo, pool, view=(slots, width, bs),
+                                        walks=2)
     spec = importlib.util.spec_from_file_location(
         "pool_layout_audit", os.path.join(REPO, "tools",
                                           "pool_layout_audit.py"))
@@ -444,7 +500,8 @@ def test_window_decode_reads_rings_and_contracts_as_stored_on_v5e(chip):
         assert sorted(dims) != sorted((slots, ring, 8, 128)), c
 
 
-def _assert_pool_row_major_and_uncopied(hlo: str, pool, view=None):
+def _assert_pool_row_major_and_uncopied(hlo: str, pool, view=None,
+                                        walks=None):
     """The compiled program takes ``pool``-shaped parameters in the
     row-major layout and holds no ``copy`` / ``transpose`` of the whole
     pool or of one layer's slice of it (tools/pool_layout_audit.py is
@@ -452,7 +509,11 @@ def _assert_pool_row_major_and_uncopied(hlo: str, pool, view=None):
     program, of a gathered ``view`` (slots, table width, block size:
     the rows as gathered lead with these dims, and so did the view
     split into heads, which the compiler wrote out as a copy of it
-    every layer until PR 30)."""
+    every layer until PR 30). ``walks``: the calls of the per-row walk
+    the program must hold (PR 34: a decode program of a bf16 pool reads
+    each row's live blocks out of the carried pool, one call a layer
+    loop), each fed by the pool itself — the loop's carry through the
+    in-place ``kv_write`` scatter — and by no copy."""
     spec = importlib.util.spec_from_file_location(
         "pool_layout_audit", os.path.join(REPO, "tools",
                                           "pool_layout_audit.py"))
@@ -468,6 +529,15 @@ def _assert_pool_row_major_and_uncopied(hlo: str, pool, view=None):
         if view is not None:
             assert tuple(c["dims"][:3]) != tuple(view), c
             assert tuple(c["dims"][:2]) != (view[0] * view[1], view[2]), c
+    if walks is not None:
+        assert len(got["row_walks"]) == walks, got["row_walks"]
+        for call in got["row_walks"]:
+            assert len(call["pool_operands"]) == 2, call
+            assert call["copies_beside"] == [], call
+        # and no gather at the table's width is left beside them
+        if view is not None:
+            assert f"[{view[0]},{view[1]},{view[2]}," not in hlo.replace(
+                " ", "")
 
 
 @pytest.mark.parametrize("width", ("decode", "prefill"))
@@ -522,7 +592,8 @@ def test_xl_programs_take_the_pool_row_major_and_copy_none_of_it(chip,
         pool.shape[0] * pool.shape[1] * pool.shape[2] * 2)
     _assert_pool_row_major_and_uncopied(
         compiled.as_text(), pool,
-        view=(slots, table, bs) if width == "decode" else None)
+        view=(slots, table, bs) if width == "decode" else None,
+        walks=1 if width == "decode" else 0)
 
 
 def test_cache_dir_from_env_is_left_alone(monkeypatch, tmp_path,
@@ -570,7 +641,7 @@ def test_chip_smoke_refuses_a_cpu():
     assert "needs a TPU" in out.stderr
 
 
-def test_chip_smoke_cpu_rehearsal(tmp_path, capsys):
+def test_chip_smoke_cpu_rehearsal(tmp_path, capsys, monkeypatch):
     """The first rehearsal: the train and serve phases (and the Pallas
     engine's half of the kernel phase, in the interpreter conftest
     turned on) run to their end at a tiny size on the CPU. ``main`` is
@@ -584,6 +655,20 @@ def test_chip_smoke_cpu_rehearsal(tmp_path, capsys):
         max_seq_len=96, prompt_lens=(5, 9, 14, 20, 27, 35, 44, 60),
         max_new=8, dense_check=(1, 6), kernel_check=(0, 2),
         pallas_prefill_len=16)
+    # the whole-row kernel is pinned against the GATHERED view: the
+    # xla engine's side of the comparison is traced with the kernels'
+    # interpreter off, where its verify program (few rows a slot on a
+    # bf16 pool) keeps ``_lane_diag_sdpa`` on the gathered view; with
+    # it on, it would walk each row's live blocks (PR 34), another
+    # rounding of the probabilities, bounded in test_paged_attention's
+    # TestRowWalk
+    pa = importlib.import_module("quintnet_tpu.ops.paged_attention")
+    paged_logits = cs.paged_logits
+
+    def logits_of(engine, prompts, width):
+        monkeypatch.setattr(pa, "INTERPRET", engine.attn_kernel != "xla")
+        return paged_logits(engine, prompts, width)
+
     meter = cs.CompileMeter()
     with open(tmp_path / "phases.jsonl", "a") as sink:
         train = cs.run_phase(
@@ -597,6 +682,7 @@ def test_chip_smoke_cpu_rehearsal(tmp_path, capsys):
             return rec
 
         serve_rec = cs.run_phase("serve", serve, meter, sink)
+        monkeypatch.setattr(cs, "paged_logits", logits_of)
         paged = cs.check_pallas_engine(tiny, served["engine"],
                                        served["prompts"], 0)
 

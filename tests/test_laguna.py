@@ -329,8 +329,9 @@ def test_a_sequence_preempted_and_prefilled_again(params):
 
 def test_both_head_counts_keep_the_lane_diagonal_form(params):
     """On a bf16 pool the decode program and a verify run of 5 contract
-    the cached rows as stored for 6 and 8 query heads alike (no head
-    split of a gathered view, none of a ring), the census of every
+    the cached rows as stored for 6 and 8 query heads alike (the global
+    layers walk each row's live blocks of the pool in place: no view of
+    the table's width at all; no head split of a ring), the census of every
     program is the pinned one, and the bf16 programs track the f32
     reference to bf16's rounding."""
     from quintnet_tpu import analysis
@@ -367,6 +368,9 @@ def test_both_head_counts_keep_the_lane_diagonal_form(params):
         assert analysis.gathered_view_gathers(
             fn, *args, num_blocks=pool.num_blocks,
             table_width=eng.table_width) == want["gathered_view_gathers"]
+        assert analysis.row_walk_calls(
+            fn, *args, pool_shape=pool.k.shape) == want["row_walk_calls"], \
+            name
         assert analysis.store_reads(
             fn, *args, store_shape=pool.wk.shape) == want["store_reads"], name
         assert analysis.view_head_splits(fn, *args, **geometry) == \

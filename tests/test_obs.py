@@ -942,6 +942,65 @@ def test_context_tokens_is_the_sum_of_positions(params):
     assert [int(x) for x in eng._pos[:3]] == [n + 2 for n in lens]
 
 
+@pytest.mark.parametrize("kv_dtype,spec", (("bf16", False), ("f32", False),
+                                           ("bf16", True)))
+def test_attended_rows_counts_what_the_paged_layers_read(params, kv_dtype,
+                                                         spec, monkeypatch):
+    """A decode or verify step records ``attended_rows``, pool
+    positions x layers its paged layers' attention READ: on a bf16 pool
+    every row of the program — the rows that sat out too, at position 0
+    — rounded up to the walk's key block; on an f32 pool, which still
+    gathers a view, rows x the table's width. ``paged_layers`` is on
+    the ring, so a reader divides by what the rows held."""
+    import quintnet_tpu.nn.attention as attention
+    from quintnet_tpu.serve import SpecConfig
+    from tools.trace_view import chrome_trace, read_amplification
+
+    kb, slots, width = 8, 3, 48                  # max_seq_len 48
+    monkeypatch.setattr(attention, "WALK_KEY_BLOCK", kb)
+    eng = _engine(params, max_slots=slots, kv_dtype=kv_dtype,
+                  spec=SpecConfig(max_draft=4) if spec else None)
+    layers = eng.recorder.static["paged_layers"]
+    assert layers == CFG.n_layer == eng.pool.n_layers
+    lens = (5, 9)                                # the third slot sits out
+    for p in _golden_prompts()[:2]:
+        eng.submit(p, 12)
+    run = 1
+    for step in range(4):
+        before = np.array(eng._pos)
+        eng.step()
+        rec = eng.recorder.last()
+        if spec and rec["spec_step"]:
+            # a verify run reads up to its bucket's last column
+            run = 1 + max(b for b in eng.spec.buckets
+                          if f"serve_verify_b{b}" in eng._read_granule)
+        last = before + (run - 1 if rec["spec_step"] else 0)
+        if step == 0:
+            last = np.array(lens + (0,))         # admitted this step
+        assert rec["decoding"] == 2
+        if kv_dtype == "f32":
+            want = slots * width * layers
+        else:
+            want = int(((last // kb + 1) * kb).sum()) * layers
+        assert rec["attrs"]["attended_rows"] == want, (step, rec)
+    ring = eng.recorder.snapshot()
+    ratio, steps = read_amplification(ring, layers)
+    assert steps == 4
+    if kv_dtype == "f32":
+        assert ratio > 3.0                       # 144 read of 14-20 held
+    else:
+        assert 1.0 < ratio < 2.5                 # a block a row, and the
+        #                                          idle row's one
+    # a step that decoded nothing carries no counter
+    idle = _engine(params)
+    idle.step()
+    assert "attended_rows" not in idle.recorder.last()["attrs"]
+    shown = [e["args"] for e in chrome_trace(
+        ring, paged_layers=layers)["traceEvents"] if e["ph"] == "X"]
+    assert all(a["read_amplification"] == a["attended_rows"] / (
+        a["context_tokens"] * layers) for a in shown)
+
+
 def _lowered_names(eng):
     import re
 
@@ -1085,13 +1144,56 @@ def test_live_finds_an_engines_ring_and_forgets_it(params):
     assert ring.static["kv_bytes_per_token"] == eng.pool.bytes_per_token
     assert ring.static["param_bytes"] == sum(
         x.nbytes for x in jax.tree.leaves(eng.params))
-    # a ring attached later (the fleets do) is found and filled too
+    # a ring attached later (the fleets do) is found and filled too,
+    # in place of the one it replaced
     mine = StepRecorder(capacity=8, clock=eng.clock)
     eng.recorder = mine
     assert mine in live() and mine.static["max_slots"] == 2
-    del eng, ring, mine
+    assert ring not in live()
+    # an engine built LATER and dropped is never listed beside one
+    # that lives (a reader takes a number only from the one ring)
+    later = _engine(params)
+    assert later.recorder in live() and mine in live()
+    del later
     gc.collect()
-    assert set(map(id, live())) <= before
+    assert mine in live()
+    assert set(map(id, live())) <= before | {id(mine)}
+
+
+def test_live_falls_back_to_the_newest_ring_when_no_engine_is_left(
+        params, monkeypatch):
+    """A reader that holds no engine may come after the last engine's
+    last reference is gone (a benchmark reads its per-layer metrics
+    after its driver returned): it finds the ring registered last, with
+    its records and statics, whatever the garbage collector has done —
+    and only while NO engine lives."""
+    import gc
+    import weakref
+
+    from quintnet_tpu.obs import recorder
+    from quintnet_tpu.obs.recorder import StepRecord, live
+
+    # (engines of other tests of this process may live: a registry of
+    # this test's own)
+    monkeypatch.setattr(recorder, "_LIVE", weakref.WeakKeyDictionary())
+    monkeypatch.setattr(recorder, "_NEWEST", None)
+    assert live() == []
+    older, eng = _engine(params), _engine(params)
+    eng.recorder.record(StepRecord(step=1, t0=0.0, t1=1.0,
+                                   context_tokens=7))
+    kept = id(eng.recorder)
+    assert len(live()) == 2
+    del older, eng
+    gc.collect()
+    (left,) = live()
+    assert id(left) == kept
+    assert left.last()["context_tokens"] == 7
+    assert left.static["max_slots"] == 2
+    # until an engine lives again: then its ring alone
+    del left
+    other = _engine(params)
+    gc.collect()
+    assert live() == [other.recorder]
 
 
 def test_trace_view_xplane_on_the_recorded_v5e_trace(tmp_path):

@@ -31,6 +31,7 @@ The contract ladder:
    earn, and an f32 pool's program rounds and pads nothing.
 """
 
+import contextlib
 import math
 
 import jax
@@ -38,7 +39,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from quintnet_tpu.analysis import (gathered_view_gathers,
+from quintnet_tpu.analysis import (gathered_view_gathers, row_walk_calls,
                                    view_head_splits, widened_view_dots)
 from quintnet_tpu.analysis.specs import attn_kernels, kv_layout_policies
 from quintnet_tpu.models.gpt2 import GPT2Config, gpt2_init
@@ -142,6 +143,25 @@ def _assert_out(ya, yb, policy):
     np.testing.assert_allclose(ya, yb, atol=atol, rtol=0)
 
 
+@contextlib.contextmanager
+def _oracle(kernel):
+    """The "xla" side of the parity matrix is the GATHERED view, for
+    every pool: the whole-row kernel is pinned against
+    ``_lane_diag_sdpa`` / ``_masked_sdpa`` on it, to ``QUANT_ATOL``. A
+    bf16 pool under few query rows would walk each row's live blocks
+    where the kernels' interpreter is on (conftest.py) — another
+    rounding of the probabilities, bounded in TestRowWalk, not here —
+    so it is off while the xla side is traced: with no interpreter and
+    off the TPU, ``paged_attend`` keeps the gathered form."""
+    pa = _kernels()
+    was = pa.INTERPRET
+    pa.INTERPRET = was and kernel != "xla"
+    try:
+        yield
+    finally:
+        pa.INTERPRET = was
+
+
 class TestMhaParityMatrix:
     """Each scenario runs the SAME op sequence per backend from the
     same initial pool, twice back to back (history accumulates across
@@ -185,7 +205,8 @@ class TestMhaParityMatrix:
     def test_verify_and_decode_widths(self, attn, policy_name, P):
         """P=1 IS the decode shape; 3/5 are the verify buckets + 1."""
         policy = make_policy(policy_name)
-        ya, pa = self._run_verify(attn, policy, "xla", P)
+        with _oracle("xla"):
+            ya, pa = self._run_verify(attn, policy, "xla", P)
         yb, pb = self._run_verify(attn, policy, "pallas", P)
         for a, b in zip(ya, yb):
             _assert_out(a, b, policy)
@@ -224,7 +245,8 @@ class TestMhaParityMatrix:
     @pytest.mark.parametrize("policy_name", kv_layout_policies())
     def test_chunked_prefill_offsets(self, attn, policy_name):
         policy = make_policy(policy_name)
-        ya, pa = self._run_prefill(attn, policy, "xla")
+        with _oracle("xla"):
+            ya, pa = self._run_prefill(attn, policy, "xla")
         yb, pb = self._run_prefill(attn, policy, "pallas")
         for a, b in zip(ya, yb):
             _assert_out(a, b, policy)
@@ -377,17 +399,9 @@ class TestEngineGoldens:
         _ab(None, prompts, 6, family=llama_family(cfg), fam_params=lp,
             kv_dtype="int8", max_slots=2)
 
-    @pytest.mark.parametrize("family", ("gpt2", "gpt2-tp2", "llama",
-                                        "granite_hybrid"))
-    def test_bf16_kv_tokens_equal_either_form(self, params, prompts,
-                                              family, monkeypatch):
-        """Greedy tokens of a bf16-KV engine are the same whether decode
-        contracts the view as gathered (heads on the lane diagonal) or
-        split into heads, the form every program took before: the two
-        differ by the order of f32 sums alone. Under tp each rank
-        spreads its LOCAL heads over its own part of the row."""
-        import quintnet_tpu.nn.attention as attention
-
+    @staticmethod
+    def _bf16_family(family):
+        """Engine arguments of a tiny bf16-KV engine of ``family``."""
         kw = dict(kv_dtype="bf16")
         if family == "gpt2-tp2":
             from jax.sharding import Mesh
@@ -410,8 +424,33 @@ class TestEngineGoldens:
                       fam_params=granite_hybrid_init(jax.random.key(6),
                                                      cfg),
                       prefix_cache=False)
+        elif family == "laguna":
+            from quintnet_tpu.models.laguna import (LagunaConfig,
+                                                    laguna_init)
+            from quintnet_tpu.serve import laguna_family
+
+            cfg = LagunaConfig.tiny()
+            kw.update(family=laguna_family(cfg, block_size=4),
+                      fam_params=laguna_init(jax.random.key(7), cfg),
+                      prefix_cache=False, chunked_prefill=True,
+                      prefill_len=16)
+        return kw
+
+    @pytest.mark.parametrize("family", ("gpt2", "gpt2-tp2", "llama",
+                                        "granite_hybrid"))
+    def test_bf16_kv_tokens_equal_either_form(self, params, prompts,
+                                              family, monkeypatch):
+        """Greedy tokens of a bf16-KV engine are the same whether decode
+        walks each row's live blocks with heads on the lane diagonal or
+        gathers the view and splits it into heads, the form every
+        program took before either: the two differ by the order of f32
+        sums and by where a probability is rounded. Under tp each rank
+        spreads its LOCAL heads over its own part of the row."""
+        import quintnet_tpu.nn.attention as attention
+
+        kw = self._bf16_family(family)
         served = {}
-        for form, most_rows in (("diagonal", attention._MAX_DIAG_ROWS),
+        for form, most_rows in (("walk", attention._MAX_DIAG_ROWS),
                                 ("split", 0)):
             monkeypatch.setattr(attention, "_MAX_DIAG_ROWS", most_rows)
             eng = _engine(params, "xla", **kw)
@@ -421,8 +460,55 @@ class TestEngineGoldens:
             assert view_head_splits(
                 eng._decode.fn, *args, table_width=eng.table_width,
                 block_size=eng.pool.block_size) == (
-                    0 if form == "diagonal" else 2)
-        for a, b in zip(served["diagonal"], served["split"]):
+                    0 if form == "walk" else 2)
+        for a, b in zip(served["walk"], served["split"]):
+            np.testing.assert_array_equal(a, b)
+
+    @pytest.mark.parametrize("traffic", ("steady", "chunked", "preempted"))
+    @pytest.mark.parametrize("family", ("gpt2", "llama", "granite_hybrid",
+                                        "laguna"))
+    def test_bf16_kv_tokens_equal_before_and_after_the_walk(
+            self, params, family, traffic, monkeypatch):
+        """Greedy tokens of a bf16-KV engine of every paged family are
+        the same whether its decode program walks each row's live
+        blocks in place (the kernel, under the interpreter) or gathers
+        the table's width with heads on the lane diagonal, the form it
+        took before and still takes off the TPU — through a steady
+        batch, a prompt prefilled in chunks beside decoding rows, and a
+        pool small enough to preempt."""
+        kw = self._bf16_family(family)
+        vocab = (kw["family"].cfg if "family" in kw else CFG).vocab_size
+        # (a tiny model of random weights has nearly flat logits, and
+        # the forms part by a bf16 rounding of the probabilities: a
+        # near-tie may fall either way — seed 41's third prompt meets
+        # one in the GPT-2 engine. The bound on the rounding itself is
+        # TestRowWalk's.)
+        rng = np.random.default_rng(42)
+        lens, arrivals = (5, 12, 3, 12, 14), [0, 0, 0, 1, 1]
+        if traffic == "chunked":
+            lens, arrivals = (4, 21), [0, 2]
+            kw.update(chunked_prefill=True, prefill_len=8,
+                      prefill_chunk_budget=8)
+        elif traffic == "preempted":
+            kw.update(num_blocks=11)
+        prompts = [rng.integers(0, vocab, (n,)).astype(np.int32)
+                   for n in lens]
+        served = {}
+        for form, interpret in (("walk", True), ("gathered", False)):
+            monkeypatch.setattr(_kernels(), "INTERPRET", interpret)
+            eng = _engine(params, "xla", **kw)
+            served[form] = _serve(eng, prompts, 8, arrivals=arrivals)
+            args = next(a for s, a in eng._warmup_calls()
+                        if s.fn is eng._decode.fn)
+            assert row_walk_calls(
+                eng._decode.fn, *args, pool_shape=eng.pool.k.shape) == (
+                    form == "walk") * (2 if family == "laguna" else 1)
+            summary = eng.metrics.summary()
+            if traffic == "preempted":
+                assert summary["preempted"] > 0
+            if traffic == "chunked":
+                assert summary["prefill_chunks"] >= 3
+        for a, b in zip(served["walk"], served["gathered"]):
             np.testing.assert_array_equal(a, b)
 
     def test_tp2_fake_quant(self, params, prompts):
@@ -572,6 +658,14 @@ class TestStructure:
 # ---------------------------------------------------------------------
 # 5. the arithmetic contract: the view is contracted as it is stored
 # ---------------------------------------------------------------------
+
+def _kernels():
+    """The kernels' module (the ops package re-exports the
+    paged_attention FUNCTION under the submodule's name)."""
+    import importlib
+
+    return importlib.import_module("quintnet_tpu.ops.paged_attention")
+
 
 def _eqns(closed):
     """Every eqn of a traced program, sub-jaxprs (pjit, scan) included."""
@@ -745,10 +839,11 @@ class TestStoredDtypeContract:
     def test_f32_pool_rounds_and_pads_nothing(self, pool_dtype, narrowed):
         """An f32 view takes the branch it always took: no convert of q
         or of the probabilities to a 16-bit float, no pad of the query
-        row. The bf16 pool is the positive control: q and the
-        probabilities go down, and the spread query rows — two, a head
-        each, on the lane diagonal — are padded to a sublane tile, with
-        their mask."""
+        row. The bf16 pool is the positive control: q goes down before
+        the per-row walk and the probabilities inside its kernel, once
+        each, and the spread query rows — two, a head each, on the lane
+        diagonal — are padded to a sublane tile of the stored dtype,
+        with their positions."""
         from quintnet_tpu.nn.attention import mha_verify_paged
 
         attn, x, kp, vp, pos = self._decode_inputs(jnp.dtype(pool_dtype))
@@ -758,15 +853,36 @@ class TestStoredDtypeContract:
                 num_heads=H, block_tables=_tables(), block_size=BS,
                 layer=jnp.int32(LAYER)))(x, kp, vp)
         eqns = _eqns(jaxpr)
-        down = [e for e in eqns
-                if e.primitive.name == "convert_element_type"
-                and e.invars[0].aval.dtype == jnp.float32
-                and e.params["new_dtype"].itemsize == 2
-                and e.invars[0].aval.ndim >= 3]  # not the pool's rows
+
+        def down(eqns, ndim):
+            return [e for e in eqns
+                    if e.primitive.name == "convert_element_type"
+                    and e.invars[0].aval.dtype == jnp.float32
+                    and e.params["new_dtype"].itemsize == 2
+                    and e.invars[0].aval.ndim >= ndim]
+
         pads = [e for e in eqns if e.primitive.name == "pad"
-                and e.invars[0].aval.ndim >= 3]  # not the pool rows' lanes
-        assert len(down) == (2 if narrowed else 0), down
+                and e.invars[0].aval.shape[1] == H]   # the H query rows
+        kernels = [e for e in eqns if e.primitive.name == "pallas_call"]
+        # (ndim 3: not the pool's rows on their way into the scatter)
+        assert len(down(eqns, 3)) == (1 if narrowed else 0)
         assert len(pads) == (2 if narrowed else 0), pads
+        assert len(kernels) == (1 if narrowed else 0)
+        if narrowed:
+            assert {e.outvars[0].aval.shape[1] for e in pads} == {16}
+            inside = []
+            _walk_all(kernels[0].params["jaxpr"], inside.append)
+            assert len(down(inside, 2)) == 1, inside
+
+
+def _walk_all(jaxpr, visit):
+    """Every eqn of a kernel's body, loops and branches included."""
+    from quintnet_tpu.analysis.jaxpr_audit import _as_open, _subjaxprs
+
+    for e in jaxpr.eqns:
+        visit(e)
+        for sub in _subjaxprs(e.params):
+            _walk_all(_as_open(sub), visit)
 
 
 # ---------------------------------------------------------------------
@@ -850,9 +966,11 @@ class TestLaneDiagonal:
     def test_only_many_rows_split_the_view(self, engines, family, which,
                                            splits):
         """Decode (4 query rows a slot), a verify run (20) and a
-        16-wide prefill (64) contract the bf16 view as gathered; the
-        128-wide bucket's 512 rows pass the module's bound and take the
-        split, k and v; and no form widens a view."""
+        16-wide prefill (64) of a bf16 pool gather NO view: each row
+        walks its live blocks of the carried pool in place, one call a
+        layer scan; the 128-wide bucket's 512 rows pass the module's
+        bound, gather the table's width and take the split, k and v;
+        and no form widens a view."""
         from quintnet_tpu.nn.attention import _MAX_DIAG_ROWS
 
         assert 4 * 16 <= _MAX_DIAG_ROWS < 4 * 128
@@ -862,16 +980,28 @@ class TestLaneDiagonal:
                   block_size=eng.pool.block_size)
         assert view_head_splits(fn, *args, **kw) == splits
         assert widened_view_dots(fn, *args, **kw) == 0
+        assert gathered_view_gathers(
+            fn, *args, num_blocks=eng.pool.num_blocks,
+            table_width=eng.table_width) == splits
+        assert row_walk_calls(
+            fn, *args, pool_shape=eng.pool.k.shape) == (0 if splits else 1)
 
-    @pytest.mark.parametrize("kv_dtype", ("f32", "int8", "fp8"))
-    def test_other_pools_keep_the_split(self, engines, kv_dtype):
-        """Only a view stored in a float narrower than q goes on the
-        diagonal: an f32 pool, a scaled policy's dequantized view and a
-        float8 pool's widened one take the branch they always took."""
+    @pytest.mark.parametrize("kv_dtype,gathers", (("f32", 2), ("int8", 4),
+                                                  ("fp8", 2)))
+    def test_other_pools_keep_the_split(self, engines, kv_dtype, gathers):
+        """Only rows stored in a float narrower than q are walked where
+        they lie: an f32 pool, a scaled policy's dequantized view and a
+        float8 pool's widened one gather the table's width (k and v,
+        and a scaled policy's scales) and take the branch they always
+        took."""
         eng, _p = engines("gpt2", kv_dtype)
         fn, args = self._program(eng, "serve_decode")
         assert view_head_splits(fn, *args, table_width=eng.table_width,
                                 block_size=eng.pool.block_size) == 2
+        assert gathered_view_gathers(
+            fn, *args, num_blocks=eng.pool.num_blocks,
+            table_width=eng.table_width) == gathers
+        assert row_walk_calls(fn, *args, pool_shape=eng.pool.k.shape) == 0
 
     def test_counter_sees_the_old_form(self):
         """The zeroes mean something: the gathered rows cut into heads
@@ -953,13 +1083,17 @@ class TestLaneDiagonal:
     @pytest.mark.parametrize("heads", ("mha", "gqa", "grouped"))
     def test_diagonal_equals_split_inside_the_rounding_bound(
             self, heads, P, pool_dtype, monkeypatch):
-        """The two forms on the SAME narrow pool — every layer and
+        """The three forms on the SAME narrow pool — every layer and
         every pad lane full of noise — for MHA (3 heads), llama's GQA
         (4 query heads on 2 kv heads, repeated) and the hybrid's
         grouped rows (2 kv heads, the 2 query heads of each as rows of
-        one score matrix, a stated score scale): both inside the bound
-        that rounding q and the probabilities earns against f64 math on
-        the stored K and V, and each other's to the order of f32 sums;
+        one score matrix, a stated score scale): the per-row walk, the
+        view gathered whole with heads on the lane diagonal, and the
+        view split into heads, all inside the bound that rounding q and
+        the probabilities earns against f64 math on the stored K and V;
+        the two gathered forms each other's to the order of f32 sums
+        (the walk rounds a probability before the softmax's sum is
+        known: another rounding of the same size, not another order);
         the pool bytes equal to bits."""
         import quintnet_tpu.nn.attention as attention
 
@@ -978,23 +1112,31 @@ class TestLaneDiagonal:
                      + jnp.arange(P, dtype=jnp.int32)[None, :])
         lens = jnp.asarray([P, max(P - 1, 1), P], jnp.int32)
 
-        def run(most_rows):
+        def run(most_rows, interpret=True):
+            # few rows: the per-row walk under the interpreter; without
+            # one (and pages this narrow) the view gathered whole, heads
+            # on the lane diagonal — what every platform but the TPU runs
             monkeypatch.setattr(attention, "_MAX_DIAG_ROWS", most_rows)
+            monkeypatch.setattr(_kernels(), "INTERPRET", interpret)
             fn = lambda q, k, v, kp, vp: attention.paged_attend(  # noqa: E731
                 q, k, v, (kp, vp), jnp.int32(LAYER), positions, lens,
                 tables, block_size=BS, scale=scale)
             assert view_head_splits(
                 fn, q, k, v, kp, vp, table_width=M,
                 block_size=BS) == (2 if most_rows == 0 else 0)
+            assert row_walk_calls(fn, q, k, v, kp, vp, pool_shape=shape) \
+                == (1 if most_rows and interpret else 0)
             return jax.jit(fn)(q, k, v, kp, vp)
 
-        diag, pools = run(attention._MAX_DIAG_ROWS)
+        walk, pools = run(attention._MAX_DIAG_ROWS)
+        diag, pools_diag = run(attention._MAX_DIAG_ROWS, interpret=False)
         split, pools_split = run(0)
-        for a, b in zip(pools, pools_split):
-            np.testing.assert_array_equal(np.asarray(a, np.float32),
-                                          np.asarray(b, np.float32))
-        assert diag.dtype == split.dtype == jnp.float32
-        assert diag.shape == split.shape == q.shape
+        for other in (pools_diag, pools_split):
+            for a, b in zip(pools, other):
+                np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                              np.asarray(b, np.float32))
+        assert walk.dtype == diag.dtype == split.dtype == jnp.float32
+        assert walk.shape == diag.shape == split.shape == q.shape
 
         rows = (np.asarray(tables)[:, :, None] * BS
                 + np.arange(BS)[None, None, :]).reshape(S, M * BS)
@@ -1009,13 +1151,141 @@ class TestLaneDiagonal:
         want, bound = self._f64_attention(
             np.asarray(q, np.float64), ks, vs, live,
             1 / math.sqrt(D) if scale is None else scale, u)
-        for got in (diag, split):
+        for got in (walk, diag, split):
             gap = np.abs(np.asarray(got, np.float64) - want)
             assert (gap <= bound).all(), (gap.max(), bound.max())
             assert gap.max() > 1e-6 * np.abs(want).max(), gap.max()
         assert bound.max() < 0.1 * np.abs(want).max(), bound.max()
         np.testing.assert_allclose(np.asarray(diag), np.asarray(split),
                                    atol=2e-6, rtol=0)
+
+
+# ---------------------------------------------------------------------
+# 7. few query rows on a narrow pool: each row walks its LIVE key blocks
+#    of the carried pool, in place (ops/paged_attention
+#    .paged_walk_attention under the interpreter)
+# ---------------------------------------------------------------------
+
+class TestRowWalk:
+    """The per-row form against ``_lane_diag_sdpa`` on the gathered
+    view — the form it replaced — at the cells' head geometries: both
+    inside the bound rounding q and the probabilities earns against f64
+    math on the stored rows (TestLaneDiagonal's), the pool bytes equal
+    to bits, for rows of every length a walk can end at."""
+
+    ROWS, WIDTH, PAGE, KEY_BLOCK = 3, 8, 16, 32     # 128 positions a row
+    #   name: (query heads, kv heads, head width, score scale)
+    HEADS = {"xl": (25, 25, 64, None),              # 1,600 of 1,664 lanes
+             "gqa": (32, 8, 64, 0.0078125),         # the hybrid's, stated
+             "grouped": (48, 8, 128, None)}         # the window family's
+    #   name: each row's LAST position (None: an all-zero table, the run
+    #   at position 0 — an inactive, mid-prefill or warm-up row)
+    LENGTHS = {"empty": (None, None, None),
+               "edges": (KEY_BLOCK - 2, KEY_BLOCK - 1, KEY_BLOCK),
+               "full": (WIDTH * PAGE - 1,) * 3,
+               "one_live": (None, 77, None)}
+
+    @pytest.mark.parametrize("pool_dtype", ("bfloat16", "float16"))
+    @pytest.mark.parametrize("P", (1, 3, 5))
+    @pytest.mark.parametrize("lengths", tuple(LENGTHS))
+    @pytest.mark.parametrize("heads", tuple(HEADS))
+    def test_walk_equals_gathered_inside_the_rounding_bound(
+            self, heads, lengths, P, pool_dtype, monkeypatch):
+        import quintnet_tpu.nn.attention as attention
+        from quintnet_tpu.serve.kv_pool import feature_width
+
+        hq, hkv, d, scale = self.HEADS[heads]
+        g = hq // hkv
+        rows, m, bs = self.ROWS, self.WIDTH, self.PAGE
+        dt = jnp.dtype(pool_dtype)
+        rng = np.random.default_rng(31)
+        blocks = 1 + rows * m
+        shape = (2, blocks * bs, feature_width(hkv, d))
+        kp = jnp.asarray(rng.standard_normal(shape), dt)
+        vp = jnp.asarray(rng.standard_normal(shape), dt)
+        q = jnp.asarray(rng.standard_normal((rows, hkv, g * P, d)),
+                        jnp.float32)
+        k, v = (jnp.asarray(rng.standard_normal((rows, hkv, P, d)),
+                            jnp.float32) for _ in range(2))
+        lasts = self.LENGTHS[lengths]
+        tables = jnp.asarray(
+            [[0] * m if last is None else
+             [1 + r * m + j for j in range(m)]
+             for r, last in enumerate(lasts)], jnp.int32)
+        starts = [0 if last is None else last - (P - 1) for last in lasts]
+        positions = (jnp.asarray(starts, jnp.int32)[:, None]
+                     + jnp.arange(P, dtype=jnp.int32)[None, :])
+        lens = jnp.full((rows,), P, jnp.int32)
+        layer = jnp.int32(1)
+        monkeypatch.setattr(attention, "WALK_KEY_BLOCK", self.KEY_BLOCK)
+
+        def walk(q, k, v, kp, vp):
+            return attention.paged_attend(
+                q, k, v, (kp, vp), layer, positions, lens, tables,
+                block_size=bs, scale=scale, max_diag_rows=hq * P)
+
+        def gathered(q, k, v, kp, vp):
+            pools = attention.paged_write(
+                kp, vp, layer, k, v, positions, lens, block_tables=tables,
+                block_size=bs)
+            kr, vr = attention._gather_kv(pools, layer, None, tables,
+                                          block_size=bs, head_shape=None)
+            return attention._lane_diag_sdpa(
+                q, kr, vr, attention._seen(positions, q, tables, bs),
+                kv_heads=hkv, scale=scale), pools
+
+        assert row_walk_calls(walk, q, k, v, kp, vp, pool_shape=shape) == 1
+        assert gathered_view_gathers(walk, q, k, v, kp, vp,
+                                     num_blocks=blocks, table_width=m) == 0
+        got, pools = jax.jit(walk)(q, k, v, kp, vp)
+        ref, pools_ref = jax.jit(gathered)(q, k, v, kp, vp)
+        for a, b in zip(pools, pools_ref):
+            np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                          np.asarray(b, np.float32))
+        assert got.dtype == ref.dtype == jnp.float32
+        assert got.shape == ref.shape == q.shape
+
+        slots = (np.asarray(tables)[:, :, None] * bs
+                 + np.arange(bs)[None, None, :]).reshape(rows, m * bs)
+        ks, vs = (np.asarray(p[1, :, :hkv * d].astype(jnp.float32),
+                             np.float64).reshape(blocks * bs, hkv, d)[slots]
+                  .transpose(0, 2, 1, 3) for p in pools)   # [S, Hkv, T, D]
+        live = np.tile(np.arange(m * bs)[None, None, :]
+                       <= np.asarray(positions)[:, :, None], (1, g, 1))
+        u = 2.0 ** -(jnp.finfo(dt).nmant + 1)
+        want, bound = TestLaneDiagonal._f64_attention(
+            np.asarray(q, np.float64), ks, vs, live,
+            1 / math.sqrt(d) if scale is None else scale, u)
+        for out in (got, ref):
+            gap = np.abs(np.asarray(out, np.float64) - want)
+            assert (gap <= bound).all(), (gap.max(), bound.max())
+        # the bound still says something where an output is a mean over
+        # 128 positions of noise (the worst case adds every error with
+        # one sign; the mean shrinks with the root of the count)
+        assert bound.max() < 0.2 * np.abs(want).max(), bound.max()
+
+    def test_a_row_reads_its_live_key_blocks_and_no_more(self):
+        """What the kernel is asked to walk: ``last // key_block + 1``
+        blocks a row, one for a row at position 0, never more than the
+        table holds — read off the trip counts it is handed."""
+        pa = _kernels()
+        kb, bs, m = 32, 16, 8
+        last = jnp.asarray([0, kb - 2, kb - 1, kb, 77, m * bs - 1])
+        rows = len(last)
+        qpos = jnp.stack([last - 1, last], axis=1)       # a run of two
+        qpos = jnp.pad(qpos, ((0, 0), (0, 14)), constant_values=-1)
+        pool = jnp.zeros((1, 3 * bs, 128), jnp.bfloat16)
+        closed = jax.make_jaxpr(
+            lambda qp: pa.paged_walk_attention(
+                jnp.zeros((rows, 16, 128), jnp.bfloat16), qp, pool, pool,
+                jnp.int32(0), jnp.zeros((rows, m), jnp.int32),
+                block_size=bs, key_block=kb, head_dim=64))(qpos)
+        (call,) = [e for e in closed.jaxpr.eqns
+                   if e.primitive.name == "pallas_call"]
+        trips = jax.core.eval_jaxpr(
+            closed.jaxpr.replace(outvars=[call.invars[2]]), closed.consts,
+            qpos)[0]
+        np.testing.assert_array_equal(np.asarray(trips), [1, 1, 1, 2, 3, 4])
 
 
 def test_ops_import_surface():

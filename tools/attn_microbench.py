@@ -11,6 +11,21 @@ on the same bf16 inputs, as a share of the largest magnitude.
 
     chiprun -- python tools/attn_microbench.py --shape 32,12,1024,64 \
         --variants sdpa,resident:256:256,streamed:512,library:512
+
+``--decode CELL[,CELL]`` times the DECODE attention of a serving cell
+instead (PR 34; the numbers behind ``nn/attention.WALK_KEY_BLOCK``): one
+token a row written into a bf16 pool of the cell's own shape and
+scored against the row's history, every layer of the pool in one
+program — the gathered view at the table's width, heads on the lane
+diagonal (``gathered``), against the per-row walk of live key blocks
+at each ``--key-blocks`` size (``walk:<positions>``). Row lengths are
+drawn as the cell's traffic draws them; ``live_share`` is what the rows
+hold of the table, ``ms_a_layer`` the program's time over its layers,
+``o_err`` the largest deviation from ``gathered`` over the largest
+magnitude.
+
+    chiprun -- python tools/attn_microbench.py --decode xl,hybrid,window \
+        --key-blocks 128,256,512
 """
 
 from __future__ import annotations
@@ -60,6 +75,109 @@ def _variant(spec: str, causal: bool):
     raise SystemExit(f"unknown variant {spec!r}")
 
 
+# a serving cell's decode attention: rows, query heads, kv heads, head
+# width, pool layers, pool blocks, table width in blocks; row lengths
+# (median, sigma of a log-normal, low, high) as its traffic file draws
+# prompts, with half a median output on top
+DECODE_CELLS = {
+    "xl": dict(rows=12, hq=25, hkv=25, dh=64, layers=48, blocks=384,
+               table=64, lengths=(256, 0.6, 16, 768, 64), scale=None),
+    "hybrid": dict(rows=64, hq=32, hkv=8, dh=64, layers=4, blocks=2048,
+                   table=64, lengths=(256, 0.6, 16, 768, 64),
+                   scale=0.0078125),
+    "window": dict(rows=48, hq=48, hkv=8, dh=128, layers=2, blocks=22528,
+                   table=1088, lengths=(4096, 0.7, 1024, 16384, 192),
+                   scale=None),
+}
+BLOCK = 16
+
+
+def _decode_lines(cell: str, key_blocks, iters: int):
+    """One line a form of ``cell``'s decode attention (module
+    docstring)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    import quintnet_tpu.nn.attention as attention
+    from quintnet_tpu.serve.kv_pool import feature_width
+
+    c = DECODE_CELLS[cell]
+    rows, hq, hkv, dh, layers = (c[n] for n in ("rows", "hq", "hkv", "dh",
+                                                 "layers"))
+    rng = np.random.default_rng(34)
+    med, sigma, lo, hi, out = c["lengths"]
+    pos = (np.clip(rng.lognormal(np.log(med), sigma, rows), lo, hi)
+           + rng.integers(0, 2 * out, rows)).astype(np.int32)
+    pos = np.minimum(pos, c["table"] * BLOCK - 1)
+    tables = np.zeros((rows, c["table"]), np.int32)
+    free = rng.permutation(np.arange(1, c["blocks"]))
+    for r in range(rows):
+        n = pos[r] // BLOCK + 1
+        tables[r, :n], free = free[:n], free[n:]
+    f = feature_width(hkv, dh)
+    keys = jax.random.split(jax.random.key(0), 5)
+    shape = (layers, c["blocks"] * BLOCK, f)
+    kp, vp = (jax.random.normal(k, shape, jnp.bfloat16) for k in keys[:2])
+    q = jax.random.normal(keys[2], (rows, hkv, hq // hkv, dh), jnp.float32)
+    k, v = (jax.random.normal(kk, (rows, hkv, 1, dh), jnp.float32)
+            for kk in keys[3:])
+    positions, lens = jnp.asarray(pos)[:, None], jnp.ones(rows, jnp.int32)
+    tables = jnp.asarray(tables)
+
+    def program(form):
+        def layer(l, carry):
+            kp, vp, acc = carry
+            if form == "gathered":
+                kp, vp = attention.paged_write(
+                    kp, vp, l, k, v, positions, lens, block_tables=tables,
+                    block_size=BLOCK)
+                kr, vr = attention._gather_kv(
+                    (kp, vp), l, None, tables, block_size=BLOCK,
+                    head_shape=None)
+                o = attention._lane_diag_sdpa(
+                    q, kr, vr, attention._seen(positions, q, tables, BLOCK),
+                    kv_heads=hkv, scale=c["scale"])
+            else:
+                o, (kp, vp) = attention.paged_attend(
+                    q, k, v, (kp, vp), l, positions, lens, tables,
+                    block_size=BLOCK, scale=c["scale"],
+                    max_diag_rows=hq)
+            return kp, vp, acc + o
+        # the pools are donated and handed back, as the engine's are: a
+        # program that may not write them in place copies both first
+        return jax.jit(lambda kp, vp: jax.lax.fori_loop(
+            0, layers, layer, (kp, vp, jnp.zeros(q.shape, jnp.float32))),
+            donate_argnums=(0, 1))
+
+    ref = None
+    for form in ["gathered"] + [f"walk:{kb}" for kb in key_blocks]:
+        line = {"cell": cell, "form": form, "rows": rows,
+                "table_positions": c["table"] * BLOCK,
+                "live_share": float(pos.mean() + 1) / (c["table"] * BLOCK),
+                "device_kind": jax.devices()[0].device_kind}
+        try:
+            if form != "gathered":
+                attention.WALK_KEY_BLOCK = int(form.split(":")[1])
+                kb = attention.WALK_KEY_BLOCK
+                line["read_amplification"] = float(
+                    ((pos // kb + 1) * kb).sum() / (pos + 1).sum())
+            fn = program(form)
+            kp, vp, got = jax.block_until_ready(fn(kp, vp))
+            t0 = time.perf_counter()
+            for _ in range(iters):
+                kp, vp, _o = fn(kp, vp)
+            jax.block_until_ready(_o)
+            line["ms_a_layer"] = ((time.perf_counter() - t0) / iters * 1e3
+                                  / layers)
+            ref = got if ref is None else ref
+            line["o_err"] = float(jnp.max(jnp.abs(got - ref))
+                                  / jnp.max(jnp.abs(ref)))
+        except Exception as e:  # noqa: BLE001 — a refused form is a row
+            line["error"] = f"{type(e).__name__}: {e}"[:400]
+        yield line
+
+
 def _time(fn, args, iters: int) -> float:
     import jax
 
@@ -78,6 +196,10 @@ def main() -> int:
     ap.add_argument("--variants", default="sdpa,resident:256:256")
     ap.add_argument("--causal", type=int, default=1)
     ap.add_argument("--iters", type=int, default=20)
+    ap.add_argument("--decode", default="",
+                    help="serving cells whose decode attention to time "
+                         f"instead: {','.join(DECODE_CELLS)}")
+    ap.add_argument("--key-blocks", default="128,256,512")
     args = ap.parse_args()
 
     import jax
@@ -87,6 +209,13 @@ def main() -> int:
         print("attn_microbench: no TPU; a time from any other platform "
               "is not a device number", file=sys.stderr)
         return 3
+    if args.decode:
+        for cell in args.decode.split(","):
+            for line in _decode_lines(
+                    cell, [int(n) for n in args.key_blocks.split(",")],
+                    args.iters):
+                print(json.dumps(line), flush=True)
+        return 0
     b, h, s, d = (int(x) for x in args.shape.split(","))
     keys = jax.random.split(jax.random.key(0), 4)
     q, k, v, w = (jax.random.normal(kk, (b, h, s, d), jnp.bfloat16)

@@ -12,6 +12,13 @@ read, per program,
   sits in (a loop body's run once a layer); a ``transpose`` that
   permutes nothing (``dimensions={0,1,..}``, as the gather fusions
   carry) moves no byte and is not listed;
+- every call of the per-row walk (``paged_walk_attention``: decode
+  and verify on a bf16/f16 pool read each row's live blocks of the
+  carried pool in place) with what FEEDS its pool-sized operands: the
+  loop's carry or the in-place ``kv_write`` scatter is the pool itself;
+  anything else — a ``copy``, a slice of a layer, a fusion outside
+  ``kv_write`` — is a layer- or view-sized copy next to the call, and
+  is named under ``copies_beside``;
 - the sum of the compiler's own ``estimated_cycles`` over the layer
   loop's body (a guide, not a time; gathers carry no estimate);
 - the bytes the compiler plans (arguments, temporaries, aliased, live).
@@ -20,13 +27,19 @@ read, per program,
         --workload gpt2-xl.serve-chat-sat [--programs serve_decode ...] \
         [--hlo-dir /root/scratch/hlo]
 
+``--read-hlo FILE --pool-shape L,SLOTS,F`` reads a compiled
+program's text that another tool wrote (benchmarks/tools/
+aot_sizes_window_moe.py ``--hlo-dir``: the window cell compiled from
+shapes alone, 8.6 GB of weights never built) the same way.
+
 Nothing runs and no chip is needed (benchmarks/tools/aot_sizes.py is
 the same kind of compile, for sizing); the weights are made at the
 cell's real size on the CPU, so a run takes minutes. One JSON line a
 program. The bar every paged program is held to (PERF.md, PR 28 and
 30): the pools' layout row-major, and ``big_copies`` holding nothing
-pool- or view-shaped. A decode or verify program never splits the
-gathered view (nn/attention._lane_diag_sdpa); a prefill bucket does,
+pool- or view-shaped. A decode or verify program of a bf16 pool
+gathers no view at all (PR 34: ``row_walks`` one a layer loop, its
+``copies_beside`` empty); a prefill bucket splits the view it gathers
 inside its fusions, and the compiler has written no copy for it. What
 every program still lists is the f32 token table re-laid for the
 logits (``f32[vocab, width]``, outside the layer loop: PERF.md
@@ -79,6 +92,7 @@ def read_hlo(text: str, buffers: dict, layer_bytes: int) -> dict:
     for n, s in buffers.items():        # k and v share a shape
         want[tuple(s)] = "/".join(filter(None, (want.get(tuple(s)), n)))
     layouts, copies, cycles, bodies = {}, [], {}, set()
+    made, walks = {}, []      # instruction -> (opcode, scope, bytes)
     comp, entry = None, False
     for line in text.splitlines():
         head = re.match(r"^(ENTRY\s+)?%?([\w.\-]+)\s*\(.*\)\s*->.*\{\s*$",
@@ -93,6 +107,13 @@ def read_hlo(text: str, buffers: dict, layer_bytes: int) -> dict:
         if op == "while":
             bodies.update(re.findall(r"body=%?([\w.\-]+)", line))
         shape = _first_shape(result)
+        scope = re.search(r'op_name="([^"]*)"', line)
+        made[name] = (op, "/".join(scope.group(1).split("/")[-2:])
+                      if scope else "", shape[3] if shape else 0)
+        if op == "custom-call" and "paged_walk_attention" in line:
+            walks.append((name, comp, re.findall(
+                r"%([\w.\-]+)", line.split("custom-call(", 1)[1]
+                .split(")", 1)[0])))
         cyc = _CYCLES.search(line)
         if cyc:
             cycles[comp] = cycles.get(comp, 0) + int(cyc.group(1))
@@ -107,8 +128,19 @@ def read_hlo(text: str, buffers: dict, layer_bytes: int) -> dict:
             copies.append({"op": name, "in": comp, "dims": list(dims),
                            "shape": f"{dtype}{list(dims)}{layout}",
                            "MB": round(nbytes / 1e6, 1)})
+    row_walks = []
+    for name, where, operands in walks:
+        fed = [{"operand": o, "op": made[o][0], "scope": made[o][1]}
+               for o in operands if made.get(o, ("", "", 0))[2] >= layer_bytes]
+        row_walks.append({
+            "call": name, "in": where, "pool_operands": fed,
+            # the carry itself, or the scatter that wrote it in place
+            "copies_beside": [f for f in fed if not (
+                f["op"] in ("get-tuple-element", "parameter")
+                or (f["op"] == "fusion" and "kv_write" in f["scope"]))]})
     # the layer loop is the while whose body the compiler prices highest
     return {"entry_layouts": layouts, "big_copies": copies,
+            "row_walks": row_walks,
             "loop_body_Mcyc": max(
                 (round(cycles.get(b, 0) / 1e6, 3) for b in bodies),
                 default=None)}
@@ -163,7 +195,20 @@ def main() -> int:
     ap.add_argument("--num-blocks", type=int, default=None)
     ap.add_argument("--max-slots", type=int, default=None)
     ap.add_argument("--hlo-dir", default=None)
+    ap.add_argument("--read-hlo", default=None, metavar="FILE",
+                    help="read this compiled program's text instead of "
+                         "compiling (with --pool-shape)")
+    ap.add_argument("--pool-shape", default=None, metavar="L,SLOTS,F")
     args = ap.parse_args()
+    if args.read_hlo:
+        shape = tuple(int(d) for d in args.pool_shape.split(","))
+        with open(args.read_hlo) as f:
+            print(json.dumps({
+                "cell": args.workload, "hlo": args.read_hlo,
+                "buffers": {"k": list(shape), "v": list(shape)},
+                **read_hlo(f.read(), {"k": shape, "v": shape},
+                           2 * shape[1] * shape[2])}), flush=True)
+        return 0
 
     sys.path.insert(0, ROOT)
     import jax

@@ -7,7 +7,10 @@ the obs formats (quintnet_tpu/obs/):
 - a crash dump (``obs/crashdump.py``: ``{"kind": "crash_dump",
   "ring": [...], "traces": {...}}``) — the post-mortem, visualized;
 - a raw obs dump (``{"ring": [...], "traces": {...}}``:
-  ``eng.recorder.snapshot()`` beside ``eng.tracer.snapshot()``).
+  ``eng.recorder.snapshot()`` beside ``eng.tracer.snapshot()``; with
+  ``"static": eng.recorder.static`` beside them, or ``--paged-layers``,
+  the ring's read amplification is printed and shown a step:
+  :func:`read_amplification`).
 
 Mapping (the Chrome trace-event format, JSON Array/Object flavor):
 
@@ -103,14 +106,35 @@ def _base_ts(ring: List[Dict], traces: Dict[str, List[Dict]],
     return min(ts) if ts else 0.0
 
 
+def read_amplification(ring: List[Dict], paged_layers: int):
+    """(ratio, steps): how many times what its rows HELD a ring's
+    decode and verify steps READ of the paged pool — the sum of
+    ``attrs["attended_rows"]`` (pool positions x layers the paged
+    layers' attention read: a row rounded up to the walk's key block,
+    or to the table's width where a view is still gathered) over the
+    sum of ``context_tokens x paged_layers`` (the ring's static), over
+    the steps that carry the counter and held a position. 1.0 reads
+    what is live and nothing else. (None, 0) where no step counts."""
+    steps = [r for r in ring
+             if "attended_rows" in (r.get("attrs") or {})
+             and r.get("context_tokens")]
+    held = sum(r["context_tokens"] for r in steps) * paged_layers
+    if not held:
+        return None, 0
+    return sum(r["attrs"]["attended_rows"] for r in steps) / held, len(steps)
+
+
 def chrome_trace(ring: Optional[List[Dict]] = None,
                  traces: Optional[Dict[str, List[Dict]]] = None,
                  fleet_events: Optional[List[Dict]] = None,
-                 *, label: str = "quintnet-serve") -> Dict:
+                 *, label: str = "quintnet-serve",
+                 paged_layers: Optional[int] = None) -> Dict:
     """Build the Chrome trace-event JSON object (see module
     docstring). ``ring``: StepRecorder.snapshot(); ``traces``:
     Tracer.snapshot(); ``fleet_events``: EventLog.snapshot() (what a
-    crash dump's ``events`` field carries)."""
+    crash dump's ``events`` field carries); ``paged_layers``: the
+    ring's static of that name — with it a step that counted
+    ``attended_rows`` also shows its ``read_amplification``."""
     ring = ring or []
     traces = traces or {}
     fleet_events = fleet_events or []
@@ -129,6 +153,9 @@ def chrome_trace(ring: Optional[List[Dict]] = None,
         args = {k: v for k, v in rec.items()
                 if k not in ("t0", "t1", "attrs")}
         args.update(rec.get("attrs") or {})
+        ratio, _ = read_amplification([rec], paged_layers or 0)
+        if ratio is not None:
+            args["read_amplification"] = ratio
         events.append({
             "name": f"step {rec.get('step', '?')}",
             "cat": "engine", "ph": "X",
@@ -383,6 +410,11 @@ def main(argv=None) -> int:
                          "and collective tables as JSON")
     ap.add_argument("-o", "--out", default=None,
                     help="output file (default: stdout)")
+    ap.add_argument("--paged-layers", type=int, default=None,
+                    help="the ring's static of that name (default: the "
+                         "dump's static.paged_layers): print the ring's "
+                         "read amplification, attended_rows over "
+                         "context_tokens x layers, and show it a step")
     args = ap.parse_args(argv)
     if (args.dump is None) == (args.xplane is None):
         ap.error("give a DUMP.json or --xplane DIR (one of them)")
@@ -397,8 +429,17 @@ def main(argv=None) -> int:
 
     payload = _load_dump(args.dump)
     label = payload.get("replica") or "quintnet-serve"
+    layers = args.paged_layers or (payload.get("static") or {}).get(
+        "paged_layers")
     trace = chrome_trace(payload.get("ring"), payload.get("traces"),
-                         payload.get("events"), label=label)
+                         payload.get("events"), label=label,
+                         paged_layers=layers)
+    if layers:
+        ratio, steps = read_amplification(payload.get("ring") or [], layers)
+        if ratio is not None:
+            print(f"read amplification {ratio:.3f} over {steps} steps "
+                  f"(attended_rows / (context_tokens x {layers} paged "
+                  f"layers))", file=sys.stderr)
     validate_chrome_trace(trace)
     text = json.dumps(trace, indent=1)
     if args.out:
