@@ -123,44 +123,32 @@ def full_size() -> Size:
 # ---------------------------------------------------------------------
 # phase bookkeeping
 # ---------------------------------------------------------------------
-class CompileMeter:
-    """Seconds JAX spent in backend compile-or-load, and persistent
-    cache hits/misses, read from jax.monitoring."""
+def compile_totals() -> Dict:
+    """What JAX compiled or loaded in this process so far, from the
+    program's own start-up record (quintnet_tpu/obs/recorder.py: its
+    one compile listener charges every event, wherever it fell, to
+    ``totals``): seconds of backend compile-or-load, programs, and the
+    persistent cache's hits and misses."""
+    from quintnet_tpu.obs.recorder import startup
 
-    def __init__(self):
-        import jax.monitoring as mon
-
-        self.compile_s = 0.0
-        self.hits = 0
-        self.misses = 0
-        mon.register_event_duration_secs_listener(self._on_duration)
-        mon.register_event_listener(self._on_event)
-
-    def _on_duration(self, event: str, secs: float, **_kw) -> None:
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.compile_s += secs
-
-    def _on_event(self, event: str, **_kw) -> None:
-        if event == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            self.misses += 1
-
-    def read(self) -> Tuple[float, int, int]:
-        return self.compile_s, self.hits, self.misses
+    totals = startup().totals
+    return {key: totals.get(key, 0) for key in (
+        "compile_or_load_s", "programs", "cache_hits", "cache_misses")}
 
 
-def run_phase(name: str, fn, meter: CompileMeter, sink) -> Dict:
+def run_phase(name: str, fn, sink) -> Dict:
     """Run one phase; print (and append to ``sink``) its JSON line. An
     exception is not caught: it ends the script."""
-    c0, h0, m0 = meter.read()
+    before = compile_totals()
     t0 = time.perf_counter()
     rec = fn()
-    c1, h1, m1 = meter.read()
+    spent = {k: v - before[k] for k, v in compile_totals().items()}
     line = {"phase": name, "ok": True, **rec,
             "wall_s": round(time.perf_counter() - t0, 3),
-            "compile_s": round(c1 - c0, 3),
-            "cache_hits": h1 - h0, "cache_misses": m1 - m0}
+            "compile_s": round(spent["compile_or_load_s"], 3),
+            "programs": spent["programs"],
+            "cache_hits": spent["cache_hits"],
+            "cache_misses": spent["cache_misses"]}
     text = json.dumps(line)
     print(text, flush=True)
     sink.write(text + "\n")
@@ -753,7 +741,6 @@ def main() -> None:
             f"--multichip needs 4 chips; JAX found {len(devices)}")
 
     os.makedirs(args.out, exist_ok=True)
-    meter = CompileMeter()
     size = full_size()
     device = {"platform": devices[0].platform,
               "kind": devices[0].device_kind, "count": len(devices)}
@@ -764,16 +751,16 @@ def main() -> None:
             "compile_cache": cache_dir,
             "cache_from_env": bool(
                 os.environ.get("JAX_COMPILATION_CACHE_DIR"))},
-            meter, sink)
+            sink)
         if args.multichip:
             run_phase("mesh_train",
-                      lambda: phase_mesh_train(size, args.seed), meter, sink)
+                      lambda: phase_mesh_train(size, args.seed), sink)
             run_phase("mesh_serve",
-                      lambda: phase_mesh_serve(size, args.seed), meter, sink)
+                      lambda: phase_mesh_serve(size, args.seed), sink)
         else:
             run_phase("train",
                       lambda: phase_train(size, args.out, args.seed),
-                      meter, sink)
+                      sink)
             served = {}
 
             def serve():
@@ -781,10 +768,10 @@ def main() -> None:
                     size, args.seed)
                 return rec
 
-            run_phase("serve", serve, meter, sink)
+            run_phase("serve", serve, sink)
             run_phase("kernels", lambda: phase_kernels(
                 size, served["engine"], served["prompts"], args.seed),
-                meter, sink)
+                sink)
     print(json.dumps({"ok": True, "device": device}), flush=True)
 
 

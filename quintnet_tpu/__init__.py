@@ -24,10 +24,14 @@ optimizers/zero.py), Pallas TPU kernels, profiling, and a simulated
 multi-device test story that needs no real multi-host hardware.
 """
 
+import time as _time
+
+_T_IMPORT = _time.perf_counter()        # qn.setup.import opens here
+
 __version__ = "0.2.0"
 
-from quintnet_tpu.core.config import Config, load_config
-from quintnet_tpu.core.mesh import MeshSpec, build_mesh
+from quintnet_tpu.core.config import Config, load_config  # noqa: E402
+from quintnet_tpu.core.mesh import MeshSpec, build_mesh  # noqa: E402
 
 __all__ = [
     "Config",
@@ -36,3 +40,12 @@ __all__ = [
     "build_mesh",
     "__version__",
 ]
+
+# ... and closes here: the package's import, JAX's included, on the
+# start-up record (obs/recorder.startup()). Importing obs/spans also
+# registers the program's one compile listener, so what a caller
+# compiles before it builds an engine or a trainer is on the record too
+# (``unattributed``).
+from quintnet_tpu.obs.spans import stamp_import as _stamp  # noqa: E402
+
+_stamp(_T_IMPORT)

@@ -22,7 +22,10 @@ recorded:
   (phase mix, occupancy, KV pressure, chunk budget spent, speculation
   acceptance, per-step wall time via the injectable clock) — the
   flight recorder proper; ``tools/trace_view.py`` renders it as
-  Chrome trace-event JSON loadable in Perfetto;
+  Chrome trace-event JSON loadable in Perfetto — and, for what the
+  process did BEFORE its first step, the start-up record
+  (``startup()``: the ``qn.setup.*`` spans of import, build and
+  warm-up with what JAX compiled inside each);
 - :mod:`events`   — typed structured fleet lifecycle events (death,
   stall, breaker transitions, migration, restart, shed, drain) as an
   in-memory ring + optional JSONL sink;
@@ -45,11 +48,13 @@ recorded:
 
 Two modules are NOT imported here and are reached by their own path:
 
-- :mod:`spans`    — the phases of an engine step: a
-  ``jax.profiler.TraceAnnotation("qn.serve.<phase>")`` on the
-  profiler's clock plus the phase's exclusive time in the step's ring
-  record. The one module of the package that imports jax; only
-  modules that already import jax import it;
+- :mod:`spans`    — the phases of an engine step and of start-up,
+  one mechanism: a ``jax.profiler.TraceAnnotation("qn.serve.<phase>")``
+  or ``("qn.setup.<phase>")`` on the profiler's clock plus the phase's
+  exclusive time in the step's ring record or on the start-up record;
+  and the program's one ``jax.monitoring`` compile listener. The one
+  module of the package that imports jax; only modules that already
+  import jax import it;
 - :mod:`scopes`   — from a device trace's operation back to the
   ``jax.named_scope`` of the program that issued it (a map made from
   the compiled text; text in, dict out).

@@ -33,6 +33,13 @@ it in :func:`live`, the process-wide handle through which a reader that
 holds no engine (a benchmark's per-layer metric, an operator attached
 to a running replica) finds it, together with the engine's ``static``
 facts (weight bytes, KV bytes a token, slots, program names).
+
+The ring starts at the first ``engine.step()``. What the process did
+BEFORE that — importing the package, building engines and trainers,
+warming their programs up — is on the :class:`StartupRecord`, one a
+process, found through :func:`startup`: the ``qn.setup.*`` spans
+(``obs/spans.py`` opens them and charges JAX's compile events to them)
+as plain dicts, oldest first, bounded.
 """
 
 from __future__ import annotations
@@ -194,3 +201,97 @@ def live() -> List[StepRecorder]:
     with _LIVE_LOCK:
         rings = list(_LIVE.values())
         return rings or ([_NEWEST] if _NEWEST is not None else [])
+
+
+class StartupRecord:
+    """What the process did before its first step: the ``qn.setup.*``
+    spans (obs/spans.py), each a plain dict
+
+    ``{"id", "name", "t0", "t1", "parent", "exclusive_s", "attrs"}``
+
+    on ``time.perf_counter`` — ``parent`` the ``id`` of the span that
+    was open when this one opened (None at the root), ``t1`` None
+    while it is open, ``exclusive_s`` the time it was the innermost
+    open span (a span's exclusive time plus its children's ``t1 - t0``
+    is its own ``t1 - t0``), ``attrs`` what JAX traced, lowered and
+    compiled or loaded inside it (``trace_s``, ``lower_s``,
+    ``compile_or_load_s``, ``cache_retrieval_s``, ``programs``,
+    ``cache_hits``, ``cache_misses``: present only where something
+    was). Oldest first by ``t0``; bounded: past
+    ``capacity`` the oldest falls off and ``dropped`` counts it.
+
+    One a process (:func:`startup`), appended to by every engine and
+    trainer the process builds — a second engine adds spans, nothing
+    resets the record. ``unattributed`` sums the compile events that
+    fell in no span and no engine step (eager operations, a caller's
+    own programs); ``totals`` sums every event of the process,
+    wherever it was charged.
+
+    Thread-safe for its readers: a span is written by the thread that
+    opened it alone, ``snapshot`` copies under the lock."""
+
+    def __init__(self, *, capacity: int = 1024):
+        if capacity < 1:
+            raise ValueError(f"capacity must be >= 1, got {capacity}")
+        self.capacity = int(capacity)
+        self._lock = threading.Lock()
+        self._spans: "deque[Dict]" = deque(maxlen=self.capacity)
+        self._opened = 0            # spans ever opened: the next id
+        self.unattributed: Dict[str, float] = {}
+        self.totals: Dict[str, float] = {}
+
+    def open(self, name: str, t0: float, *, t1: Optional[float] = None,
+             parent: Optional[int] = None,
+             attrs: Optional[Dict] = None) -> Dict:
+        """Append a span and return it: the LIVE dict, which its owner
+        closes (``t1``) and charges in place. With ``t1`` it is closed
+        already (``qn.setup.import``: two clock readings, nothing
+        inside it is another span's)."""
+        with self._lock:
+            span = {"id": self._opened, "name": name, "t0": t0, "t1": t1,
+                    "parent": parent,
+                    "exclusive_s": 0.0 if t1 is None else t1 - t0,
+                    "attrs": dict(attrs or {})}
+            self._opened += 1
+            self._spans.append(span)
+            return span
+
+    def charge(self, key: str, amount: float,
+               sink: Optional[Dict]) -> None:
+        """Add ``amount`` of ``key`` to ``sink`` — a span's ``attrs``
+        or ``unattributed``; None where the caller charged it elsewhere
+        (to an engine step) — and to ``totals`` always."""
+        with self._lock:
+            for d in (self.totals, sink):
+                if d is not None:
+                    d[key] = d.get(key, 0) + amount
+
+    @property
+    def dropped(self) -> int:
+        """Spans that fell off the front."""
+        with self._lock:
+            return self._opened - len(self._spans)
+
+    def __len__(self) -> int:
+        with self._lock:
+            return len(self._spans)
+
+    def snapshot(self) -> Dict:
+        """``{"spans", "dropped", "unattributed", "totals"}`` as
+        JSON-able copies, spans oldest first."""
+        with self._lock:
+            return {
+                "spans": [{**s, "attrs": dict(s["attrs"])}
+                          for s in self._spans],
+                "dropped": self._opened - len(self._spans),
+                "unattributed": dict(self.unattributed),
+                "totals": dict(self.totals)}
+
+
+_STARTUP = StartupRecord()
+
+
+def startup() -> StartupRecord:
+    """The process's start-up record: what a reader that holds no
+    engine, and a training process that has no ring, both find."""
+    return _STARTUP

@@ -93,37 +93,57 @@ def scope_map(hlo_text: str) -> Dict[str, str]:
     (the ``convert`` it split off a gather's result, a ``copy`` for a
     layout): it is given the scope of the nearest operand that has one,
     through other such instructions if need be — the cast of a gathered
-    view belongs with the gather. An instruction that HAS a name, but
-    no scope in it, is outside every scope and stays out."""
+    view belongs with the gather. An instruction that HAS a name of
+    JAX's, but no scope in it, is outside every scope and stays out.
+
+    An operation the compiler REWROTE carries the compiler's own name,
+    with no path in it: on the TPU ``lax.ragged_dot`` becomes the
+    custom calls ``op_name="ragged-dot-metadata"`` (group offsets) and
+    ``op_name="ragged-dot-none"`` (the grouped matmul, most of an MoE
+    program's time), and the scope JAX gave the operation is gone. Such
+    an instruction inherits like a bare one, but takes its scope before
+    any bare instruction can inherit THROUGH another of its kind: the
+    matmul's leading operands are the bookkeeping call's results, which
+    lead back to wherever the group sizes were summed, and its scope is
+    that of the rows and weights it contracts."""
     direct: Dict[str, str] = {}
     bare: Dict[str, list] = {}       # no metadata -> operand names
+    own: Dict[str, list] = {}        # the compiler's own name -> operands
     for line in hlo_text.splitlines():
         m = _INSTRUCTION.match(line)
         if not m:
             continue
         name, rest = m.groups()
         op_name = _OP_NAME.search(rest)
-        if op_name:
+        if op_name and "/" in op_name.group(1):
             path = scope_path(op_name.group(1))
             if path:
                 direct[name] = path
         else:
             args = _OPERANDS.search(" " + rest)
-            bare[name] = _OPERAND.findall(args.group(1)) if args else []
-    out = dict(direct)
-
-    def inherit(name: str, hops: int = 8) -> str:
-        if name in out or name not in bare or hops == 0:
-            return out.get(name, "")
-        for operand in bare[name]:
-            path = inherit(operand, hops - 1)
+            (own if op_name else bare)[name] = (
+                _OPERAND.findall(args.group(1)) if args else [])
+    def nearest(operands: list, known: Dict[str, str], hops: int = 8) -> str:
+        for operand in operands:
+            path = known.get(operand, "")
+            if not path and operand in bare and hops > 1:
+                path = nearest(bare[operand], known, hops - 1)
             if path:
-                out[name] = path
                 return path
         return ""
 
-    for name in bare:
-        inherit(name)
+    def settled(instructions: Dict[str, list], known: Dict[str, str]):
+        return {name: path for name, operands in instructions.items()
+                if name not in known
+                and (path := nearest(operands, known))}
+
+    out = dict(direct)
+    out.update(settled(bare, out))
+    # the rewritten ones from what has a scope so far (none of them
+    # has), then the bare ones THEY feed: the copy of a grouped
+    # matmul's result belongs with the matmul
+    out.update(settled(own, out))
+    out.update(settled(bare, out))
     return out
 
 

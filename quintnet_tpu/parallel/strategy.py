@@ -28,6 +28,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from quintnet_tpu.core.config import Config
 from quintnet_tpu.core.mesh import MeshSpec, build_mesh
+from quintnet_tpu.obs.spans import setup_span
 from quintnet_tpu.parallel.pp import (
     PipelineSpec,
     make_afab_loss_fn,
@@ -226,6 +227,7 @@ class Strategy:
         """2 = also reduce-scatter gradients (parallel/zero.make_zero2)."""
         return 2 if self.config.training.optimizer.startswith("zero2") else 1
 
+    @setup_span("build")
     def init_opt_state(self, model: ModelSpec, optimizer, params):
         if self.zero1_axis is not None:
             state, _ = init_zero1_opt_state(
@@ -321,6 +323,7 @@ class Strategy:
         )
 
 
+@setup_span("build")
 def get_strategy(name: Optional[str] = None, config: Optional[Config] = None,
                  *, devices=None) -> Strategy:
     """Build a Strategy from a name + config (reference:

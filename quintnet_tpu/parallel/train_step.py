@@ -24,6 +24,7 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from quintnet_tpu.core import collectives as cc
+from quintnet_tpu.obs.spans import first_call
 from quintnet_tpu.parallel.dp import accumulate_grads
 
 
@@ -317,11 +318,20 @@ def make_parallel_train_step(
             )
         return compiled["fn"]
 
-    def step(params, opt_state, batch, seed=None):
+    def dispatch(params, opt_state, batch, seed):
         with jax.profiler.TraceAnnotation("qn.train.dispatch"):
             return jitted(params)(
                 params, opt_state, batch,
                 jnp.uint32(seed if seed is not None else 0))
+
+    def step(params, opt_state, batch, seed=None):
+        if "fn" in compiled:
+            return dispatch(params, opt_state, batch, seed)
+        # the first call traces, lowers and compiles or loads the
+        # program: ``qn.setup.warmup/jit_local_step`` on the start-up
+        # record (obs/spans.py), until the call returns
+        with first_call(local_step.__name__):
+            return dispatch(params, opt_state, batch, seed)
 
     def lower(params, opt_state, batch, seed=None):
         """The step program lowered for these arguments (or their

@@ -147,7 +147,8 @@ from quintnet_tpu.models.gpt2_generate import sample_logits
 from quintnet_tpu.nn.attention import noted_reads
 from quintnet_tpu.obs.recorder import StepRecord, StepRecorder
 from quintnet_tpu.obs.recorder import register as register_recorder
-from quintnet_tpu.obs.spans import SERVE_STEP, StepPhases
+from quintnet_tpu.obs.spans import (SERVE_STEP, StepPhases, setup_span,
+                                    warmup_program)
 from quintnet_tpu.serve.adapters import (AdapterRegistry, adapter_paths,
                                          nest, tree_at)
 from quintnet_tpu.serve.families import Family
@@ -374,6 +375,7 @@ def _refuse_for_window(family: Family, **asked) -> None:
 
 
 class ServeEngine:
+    @setup_span("build")
     def __init__(self, family: Family, params, *, max_slots: int = 8,
                  block_size: int = 16, num_blocks: int = 64,
                  max_seq_len: Optional[int] = None,
@@ -2591,6 +2593,10 @@ class ServeEngine:
                 attrs={**window_attrs,
                        **({"attended_rows": attended_rows}
                           if decoding else {}),
+                       # the step that recompiled, and what (the
+                       # compile listener of obs/spans.py filled it)
+                       **({"compiled": ph.compiled}
+                          if ph.compiled else {}),
                        **{k: (v.tolist() if isinstance(v, np.ndarray)
                               else v) for k, v in moe_kw.items()}}))
         if self.log_every:
@@ -2635,6 +2641,7 @@ class ServeEngine:
                 jnp.zeros((self.max_slots, self.table_width), jnp.int32),
                 jnp.asarray(self._key_data), *v_extra)
 
+    @setup_span("warmup")
     def warmup(self) -> None:
         """Compile EVERY prefill bucket and the decode step before
         serving traffic (benches call this so XLA compiles never land
@@ -2644,14 +2651,22 @@ class ServeEngine:
         slot, or metric state is touched. Sizing warmup *prompts* to
         hit each bucket cannot cover the largest bucket when
         ``prefill_len`` sits within the admission margin of the
-        previous one; calling the programs directly can."""
+        previous one; calling the programs directly can.
+
+        The whole is a ``qn.setup.warmup`` span on the start-up record
+        (obs/spans.py) and each program's call a child of it, from the
+        call until it returns: what JAX traced, lowered and compiled or
+        loaded for the program is charged to the child, and the rest of
+        its time is the dispatch of a first run nobody waits for."""
         if self.adapters is not None:
             # compile the pack-maintenance program too (a zero write is
             # a no-op on the zeroed pack): the first real bind must not
             # be the first compile
-            self._apply_pack_update(0, self._zero_slot_update())
+            with warmup_program(self._pack_update.__name__):
+                self._apply_pack_update(0, self._zero_slot_update())
         for sentinel, args in self._warmup_calls():
-            *pools, _tokens, _keys = sentinel(*args)
+            with warmup_program(sentinel.fn.__name__):
+                *pools, _tokens, _keys = sentinel(*args)
             self.pool.update(*self._pop_moe(pools, note=False))
 
     def program_texts(self) -> List[str]:

@@ -21,6 +21,7 @@ Differences from the reference worth knowing:
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import itertools
 import time
@@ -33,6 +34,7 @@ import numpy as np
 import optax
 
 from quintnet_tpu.core.config import Config
+from quintnet_tpu.obs.spans import first_call, setup_span
 from quintnet_tpu.parallel.strategy import ModelSpec, Strategy, get_strategy
 
 
@@ -228,6 +230,7 @@ class Trainer:
     'clm' (metric: perplexity).
     """
 
+    @setup_span("build")
     def __init__(self, config: Config, model: ModelSpec,
                  *, strategy: Optional[Strategy] = None,
                  optimizer: Optional[optax.GradientTransformation] = None,
@@ -555,7 +558,12 @@ class Trainer:
             for xb, yb in batches:
                 b = self.strategy.shard_batch((fresh(xb), fresh(yb)),
                                               self.model)
-                for k, v in eval_fn(params, b).items():
+                # the first call compiles or loads the program:
+                # ``qn.setup.warmup/jit_eval_step`` (obs/spans.py)
+                with (contextlib.nullcontext() if eval_fn.compile_count
+                      else first_call("eval_step")):
+                    mets = eval_fn(params, b)
+                for k, v in mets.items():
                     acc.setdefault(k, []).append(v)  # device scalars
         out = {k: float(np.mean([float(v) for v in vs]))
                for k, vs in acc.items()}

@@ -511,7 +511,7 @@ def test_zero_recompiles_as_adapters_join_and_leave(params, tenants,
     after warmup, zero backend compiles (jax.monitoring), compile
     counts pinned at the sentinel bound derived from
     analysis/specs.lora_rank_buckets."""
-    import jax.monitoring as monitoring
+    from quintnet_tpu.obs.recorder import startup
 
     reg = _registry(tenants)
     eng = _engine(params, adapters=reg)
@@ -529,30 +529,26 @@ def test_zero_recompiles_as_adapters_join_and_leave(params, tenants,
     new_path = str(tmp_path / "c.safetensors")
     save_lora(new_lora, new_cfg, new_path)
 
-    compiles = []
-    monitoring.register_event_duration_secs_listener(
-        lambda name, dur, **kw: compiles.append(name)
-        if "backend_compile" in name else None)
-    try:
-        plan = [("tenant-a", 9), (None, 6), ("tenant-b", 7)]
-        rids = [eng.submit(rng.integers(0, CFG.vocab_size, (n,))
-                           .astype(np.int32), 6, adapter_id=a)
-                for a, n in plan]
-        eng.run(max_steps=200)
-        # JOIN: a brand-new tenant registers and serves mid-session
-        reg.register("tenant-c", new_path)
-        rid_c = eng.submit(rng.integers(0, CFG.vocab_size, (5,))
-                           .astype(np.int32), 6, adapter_id="tenant-c")
-        # LEAVE: an idle tenant's weights evict; traffic continues
-        reg.evict("tenant-a")
-        rid_a = eng.submit(rng.integers(0, CFG.vocab_size, (4,))
-                           .astype(np.int32), 6, adapter_id="tenant-a")
-        eng.run(max_steps=200)
-        assert all(eng.request(r).state == "finished"
-                   for r in rids + [rid_c, rid_a])
-    finally:
-        monitoring.clear_event_listeners()
-    assert compiles == []
+    # the program's own record counts every backend compile or load
+    compiled0 = startup().totals.get("programs", 0)
+    assert compiled0 > 0                # (and it was listening)
+    plan = [("tenant-a", 9), (None, 6), ("tenant-b", 7)]
+    rids = [eng.submit(rng.integers(0, CFG.vocab_size, (n,))
+                       .astype(np.int32), 6, adapter_id=a)
+            for a, n in plan]
+    eng.run(max_steps=200)
+    # JOIN: a brand-new tenant registers and serves mid-session
+    reg.register("tenant-c", new_path)
+    rid_c = eng.submit(rng.integers(0, CFG.vocab_size, (5,))
+                       .astype(np.int32), 6, adapter_id="tenant-c")
+    # LEAVE: an idle tenant's weights evict; traffic continues
+    reg.evict("tenant-a")
+    rid_a = eng.submit(rng.integers(0, CFG.vocab_size, (4,))
+                       .astype(np.int32), 6, adapter_id="tenant-a")
+    eng.run(max_steps=200)
+    assert all(eng.request(r).state == "finished"
+               for r in rids + [rid_c, rid_a])
+    assert startup().totals.get("programs", 0) == compiled0
     assert eng.compile_stats() == stats0       # nothing new compiled
     eng.assert_compile_count(prefill=stats0["prefill"],
                              decode=stats0["decode"])

@@ -1293,3 +1293,44 @@ ENTRY %main (p: bf16[8]) -> f32[8] {
         "fusion.1": "blocks/attn/kv_gather",
         "custom-call.2": "blocks/attn/kv_gather",
         "convert.3": "blocks/attn/kv_gather", "add.5": "sample"}
+
+
+def test_scope_map_gives_a_rewritten_ragged_dot_its_operands_scope():
+    """The TPU compiler rewrites ``lax.ragged_dot`` into custom calls
+    under ITS OWN name (``op_name="ragged-dot-none"``), dropping the
+    ``experts`` scope nn/moe.py opened around it: most of an MoE
+    program's device time read ``(no scope)``. Lines captured from the
+    window cell's ``jit_serve_prefill_b16`` compiled for a described
+    v5e (backend configs cut): the grouped matmul takes the scope of
+    the rows and weights it contracts, NOT of the group sizes that
+    reach it through the bookkeeping call; the copy of its result goes
+    with it."""
+    from quintnet_tpu.obs.scopes import scope_map
+
+    text = """HloModule jit_serve_prefill_b16, is_scheduled=true
+
+ENTRY %main () -> f32[8] {
+  %fusion.604 = bf16[128,2048]{1,0:T(8,128)(2,1)S(1)} fusion(%bitcast_convert_fusion.3, %pad_clamp_fusion.8), kind=kCustom, calls=%fused_computation.6.clone.clone.clone, metadata={op_name="jit(serve_prefill_b16)/blocks/while/body/closed_call/moe/experts/gather" stack_frame_id=309}, backend_config={}
+  %get-tuple-element.1678 = s32[1024]{0:T(1024)S(1)} get-tuple-element(%fusion.603), index=3, metadata={op_name="jit(serve_prefill_b16)/blocks/while/body/closed_call/moe/sort/reduce_sum" stack_frame_id=352}
+  %ragged-dot-metadata.1 = (s32[1025]{0:T(1024)S(1)}, s32[1024]{0:T(1024)S(1)}, s32[1024]{0:T(1024)S(1)}, s32[1]{0:T(128)}) custom-call(%get-tuple-element.1678), custom_call_target="tpu_custom_call", operand_layout_constraints={s32[1024]{0}}, metadata={op_name="ragged-dot-metadata"}, backend_config={}
+  %get-tuple-element.1679 = s32[1]{0:T(128)} get-tuple-element(%ragged-dot-metadata.1), index=3
+  %get-tuple-element.1680 = s32[1025]{0:T(1024)S(1)} get-tuple-element(%ragged-dot-metadata.1), index=0
+  %bitcast.769 = bf16[1024,2048,512]{2,1,0:T(8,128)(2,1)} bitcast(%get-tuple-element.1845), metadata={op_name="jit(serve_prefill_b16)/blocks/while/body/closed_call/moe/experts/reshape" stack_frame_id=315}
+  %ragged-dot-none.4 = f32[128,512]{1,0:T(8,128)} custom-call(%get-tuple-element.1679, %get-tuple-element.1680, %get-tuple-element.1679, /*index=3*/%fusion.604, %bitcast.769), custom_call_target="tpu_custom_call", operand_layout_constraints={s32[1]{0}, s32[1025]{0}, s32[1]{0}, bf16[128,2048]{1,0}, bf16[1024,2048,512]{2,1,0}}, frontend_attributes={mosaic_fusion_entry_point="true",ragged_dot_tiling="128,512,512"}, metadata={op_name="ragged-dot-none"}, backend_config={}
+  %copy-start.7 = (f32[128,512]{1,0:T(8,128)S(1)}, f32[128,512]{1,0:T(8,128)}, u32[]{:S(2)}) copy-start(%ragged-dot-none.4)
+  %copy-done.7 = f32[128,512]{1,0:T(8,128)S(1)} copy-done(%copy-start.7)
+  %x.1 = f32[8]{0} parameter(0), metadata={op_name="x"}
+  %copy.9 = f32[8]{0} copy(%x.1)
+}
+"""
+    got = scope_map(text)
+    assert got["ragged-dot-none.4"] == "blocks/moe/experts"
+    assert got["copy-start.7"] == got["copy-done.7"] == "blocks/moe/experts"
+    # the bookkeeping call and its results belong where the sizes were
+    # summed; they cost nothing
+    assert got["ragged-dot-metadata.1"] == "blocks/moe/sort"
+    assert got["get-tuple-element.1679"] == "blocks/moe/sort"
+    # an argument's own name has no path either: nothing to inherit,
+    # and nothing for the copy of it
+    assert "x.1" not in got and "copy.9" not in got
+    assert got["fusion.604"] == got["bitcast.769"] == "blocks/moe/experts"

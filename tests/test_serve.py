@@ -561,7 +561,7 @@ def test_no_recompilation_over_20_step_trace(params, rng):
     """Admitting/retiring/preempting across a 20-step trace must hit
     the SAME two compiled programs: zero backend compiles observed via
     jax.monitoring after warmup, jit cache size stays 1 per program."""
-    import jax.monitoring as monitoring
+    from quintnet_tpu.obs.recorder import startup
 
     eng = _engine(params, max_slots=3, block_size=2, num_blocks=12,
                   max_seq_len=16)
@@ -570,30 +570,22 @@ def test_no_recompilation_over_20_step_trace(params, rng):
     eng.run()
     assert eng.compile_stats() == {"prefill": 1, "decode": 1}
 
-    compiles = []
-
-    def listener(name, **kw):
-        if "backend_compile" in name:
-            compiles.append(name)
-
-    monitoring.register_event_duration_secs_listener(
-        lambda name, dur, **kw: listener(name))
-    try:
-        prompts = _prompts(rng, (3, 5, 4, 6, 3, 5))
-        arrivals = [0, 1, 3, 6, 10, 14]
-        submitted, step = 0, 0
-        rids = []
-        for step in range(20):
-            while (submitted < len(prompts)
-                   and arrivals[submitted] <= step):
-                rids.append(eng.submit(prompts[submitted], 4))
-                submitted += 1
-            eng.step()
-        assert submitted == len(prompts)
-        assert eng.metrics.finished >= 4  # retirements happened mid-trace
-    finally:
-        monitoring.clear_event_listeners()
-    assert compiles == []
+    # the program's own record counts every backend compile or load
+    compiled0 = startup().totals.get("programs", 0)
+    assert compiled0 > 0                # (and it was listening)
+    prompts = _prompts(rng, (3, 5, 4, 6, 3, 5))
+    arrivals = [0, 1, 3, 6, 10, 14]
+    submitted, step = 0, 0
+    rids = []
+    for step in range(20):
+        while (submitted < len(prompts)
+               and arrivals[submitted] <= step):
+            rids.append(eng.submit(prompts[submitted], 4))
+            submitted += 1
+        eng.step()
+    assert submitted == len(prompts)
+    assert eng.metrics.finished >= 4  # retirements happened mid-trace
+    assert startup().totals.get("programs", 0) == compiled0
     assert eng.compile_stats() == {"prefill": 1, "decode": 1}
 
 

@@ -404,7 +404,7 @@ def test_compile_count_bounded_over_mixed_spec_trace(rep_params):
     len(prefill_buckets) prefill + len(verify_buckets) verify + 1
     decode programs — the no-recompile invariant extended to the
     verify family, enforced by assert_compile_count."""
-    import jax.monitoring as monitoring
+    from quintnet_tpu.obs.recorder import startup
 
     rng = np.random.default_rng(5)
     eng = ServeEngine(gpt2_family(CFG_REP), rep_params, max_slots=3,
@@ -420,27 +420,23 @@ def test_compile_count_bounded_over_mixed_spec_trace(rep_params):
     eng.submit(np.zeros((3,), np.int32), 2)
     eng.run(max_steps=50)
 
-    compiles = []
-    monitoring.register_event_duration_secs_listener(
-        lambda name, dur, **kw: compiles.append(name)
-        if "backend_compile" in name else None)
-    try:
-        prompts = [rng.integers(0, CFG_REP.vocab_size,
-                                (n,)).astype(np.int32)
-                   for n in (12, 7, 9, 5)]
-        arrivals = [0, 2, 5, 9]
-        submitted, step = 0, 0
-        while submitted < len(prompts) or eng.has_work:
-            while (submitted < len(prompts)
-                   and arrivals[submitted] <= step):
-                eng.submit(prompts[submitted], 40)
-                submitted += 1
-            eng.step()
-            step += 1
-            assert step < 1000
-    finally:
-        monitoring.clear_event_listeners()
-    assert compiles == []
+    # the program's own record counts every backend compile or load
+    compiled0 = startup().totals.get("programs", 0)
+    assert compiled0 > 0                # (and it was listening)
+    prompts = [rng.integers(0, CFG_REP.vocab_size,
+                            (n,)).astype(np.int32)
+               for n in (12, 7, 9, 5)]
+    arrivals = [0, 2, 5, 9]
+    submitted, step = 0, 0
+    while submitted < len(prompts) or eng.has_work:
+        while (submitted < len(prompts)
+               and arrivals[submitted] <= step):
+            eng.submit(prompts[submitted], 40)
+            submitted += 1
+        eng.step()
+        step += 1
+        assert step < 1000
+    assert startup().totals.get("programs", 0) == compiled0
     assert eng.metrics.spec_steps > 0          # speculation happened
     assert eng.metrics.decode_steps > eng.metrics.spec_steps  # mixed
     assert eng.compile_stats() == stats0       # nothing new compiled

@@ -10,7 +10,9 @@ the obs formats (quintnet_tpu/obs/):
   ``eng.recorder.snapshot()`` beside ``eng.tracer.snapshot()``; with
   ``"static": eng.recorder.static`` beside them, or ``--paged-layers``,
   the ring's read amplification is printed and shown a step:
-  :func:`read_amplification`).
+  :func:`read_amplification`; with ``"startup":
+  obs.recorder.startup().snapshot()`` beside them, what the process
+  did before its first step).
 
 Mapping (the Chrome trace-event format, JSON Array/Object flavor):
 
@@ -18,7 +20,14 @@ Mapping (the Chrome trace-event format, JSON Array/Object flavor):
   "engine steps" thread — duration = the step's clock window, args =
   the step's phase mix / occupancy / KV pressure / chunk + spec
   ledgers, so the Perfetto timeline shows exactly the prefill/decode
-  interference Sarathi argues about;
+  interference Sarathi argues about; a step inside which JAX compiled
+  or loaded a program says so in its name (``step 412 (compiled
+  jit_serve_prefill_b512)``) and in ``args["compiled"]``;
+- each closed START-UP span (``qn.setup.import`` / ``.build`` /
+  ``.warmup`` / ``.warmup/<program>``) becomes a complete slice on the
+  "start-up" thread, nested as the spans were, args = its exclusive
+  seconds and the trace / lower / compile-or-load seconds and cache
+  hits and misses charged to it;
 - each request SPAN becomes an async begin/end pair ("ph": "b"/"e",
   id = trace id) on the "requests" track, instants (t1 == t0) become
   instant events ("ph": "i") — one row per request from queue to
@@ -89,6 +98,7 @@ PID = 1
 TID_STEPS = 1
 TID_REQUESTS = 2
 TID_EVENTS = 3
+TID_SETUP = 4
 
 # fleet events drawn as FULL-HEIGHT markers ("s": "g"): the SLO
 # judgment layer's output, which the reader wants to line up against
@@ -99,8 +109,10 @@ _GLOBAL_EVENT_KINDS = frozenset({
 
 
 def _base_ts(ring: List[Dict], traces: Dict[str, List[Dict]],
-             fleet_events: Optional[List[Dict]] = None) -> float:
+             fleet_events: Optional[List[Dict]] = None,
+             setup: Optional[List[Dict]] = None) -> float:
     ts = [r["t0"] for r in ring]
+    ts += [s["t0"] for s in (setup or [])]
     ts += [s["t0"] for spans in traces.values() for s in spans]
     ts += [e["ts"] for e in (fleet_events or []) if "ts" in e]
     return min(ts) if ts else 0.0
@@ -128,17 +140,26 @@ def chrome_trace(ring: Optional[List[Dict]] = None,
                  traces: Optional[Dict[str, List[Dict]]] = None,
                  fleet_events: Optional[List[Dict]] = None,
                  *, label: str = "quintnet-serve",
-                 paged_layers: Optional[int] = None) -> Dict:
+                 paged_layers: Optional[int] = None,
+                 startup: Optional[Dict] = None) -> Dict:
     """Build the Chrome trace-event JSON object (see module
     docstring). ``ring``: StepRecorder.snapshot(); ``traces``:
     Tracer.snapshot(); ``fleet_events``: EventLog.snapshot() (what a
     crash dump's ``events`` field carries); ``paged_layers``: the
     ring's static of that name — with it a step that counted
-    ``attended_rows`` also shows its ``read_amplification``."""
+    ``attended_rows`` also shows its ``read_amplification``;
+    ``startup``: obs.recorder.startup().snapshot() — its closed
+    ``qn.setup.*`` spans on a thread of their own, each with what JAX
+    traced, lowered and compiled or loaded inside it (the ring's
+    ``time.monotonic`` and the spans' ``time.perf_counter`` are one
+    clock). A step that compiled shows the programs in its name and
+    in ``args["compiled"]``."""
     ring = ring or []
     traces = traces or {}
     fleet_events = fleet_events or []
-    t_base = _base_ts(ring, traces, fleet_events)
+    setup = [s for s in (startup or {}).get("spans", [])
+             if s.get("t1") is not None]
+    t_base = _base_ts(ring, traces, fleet_events, setup)
     events: List[Dict] = [
         {"ph": "M", "pid": PID, "name": "process_name",
          "args": {"name": label}},
@@ -156,13 +177,26 @@ def chrome_trace(ring: Optional[List[Dict]] = None,
         ratio, _ = read_amplification([rec], paged_layers or 0)
         if ratio is not None:
             args["read_amplification"] = ratio
+        compiled = args.get("compiled")
         events.append({
-            "name": f"step {rec.get('step', '?')}",
+            "name": f"step {rec.get('step', '?')}" + (
+                f" (compiled {', '.join(compiled)})" if compiled else ""),
             "cat": "engine", "ph": "X",
             "ts": (rec["t0"] - t_base) * _US,
             "dur": max(rec["t1"] - rec["t0"], 0.0) * _US,
             "pid": PID, "tid": TID_STEPS, "args": args,
         })
+    if setup:
+        events.append({"ph": "M", "pid": PID, "tid": TID_SETUP,
+                       "name": "thread_name", "args": {"name": "start-up"}})
+    for s in setup:
+        events.append({
+            "name": s["name"], "cat": "setup", "ph": "X",
+            "ts": (s["t0"] - t_base) * _US,
+            "dur": max(s["t1"] - s["t0"], 0.0) * _US,
+            "pid": PID, "tid": TID_SETUP,
+            "args": {"exclusive_s": s.get("exclusive_s"),
+                     **(s.get("attrs") or {})}})
     for trace_id, spans in sorted(traces.items()):
         for s in spans:
             common = {"cat": "request", "id": trace_id, "pid": PID,
@@ -433,7 +467,8 @@ def main(argv=None) -> int:
         "paged_layers")
     trace = chrome_trace(payload.get("ring"), payload.get("traces"),
                          payload.get("events"), label=label,
-                         paged_layers=layers)
+                         paged_layers=layers,
+                         startup=payload.get("startup"))
     if layers:
         ratio, steps = read_amplification(payload.get("ring") or [], layers)
         if ratio is not None:
