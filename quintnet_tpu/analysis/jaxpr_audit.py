@@ -350,14 +350,16 @@ def _walk_skip_kernels(jaxpr, visit) -> None:
             _walk_skip_kernels(_as_open(sub), visit)
 
 
-def row_walk_calls(fn: Callable, *args, pool_shape, **kwargs) -> int:
+def row_walk_calls(fn: Callable, *args, pool_shape, pools: int = 2,
+                   **kwargs) -> int:
     """Count the calls of the per-row walk
     (ops/paged_attention.paged_walk_attention) that read the pool IN
-    PLACE: ``pallas_call`` eqns of that name with two operands of
-    ``pool_shape`` — the whole carried k and v, no layer's slice and no
-    gathered view. Decode and verify on a bf16/f16 pool show one a
-    layer scan's body (a scan body counts once) where
-    :func:`gathered_view_gathers` shows none; every other program none."""
+    PLACE: ``pallas_call`` eqns of that name with ``pools`` operands of
+    ``pool_shape`` — the whole carried k and v (1: a latent family's
+    one pool), no layer's slice and no gathered view. Decode and verify
+    on a bf16/f16 pool show one a layer scan's body (a scan body counts
+    once) where :func:`gathered_view_gathers` shows none; every other
+    program none."""
     closed = jax.make_jaxpr(fn)(*args, **kwargs)
     found = 0
 
@@ -366,7 +368,7 @@ def row_walk_calls(fn: Callable, *args, pool_shape, **kwargs) -> int:
         if (eqn.primitive.name == "pallas_call"
                 and eqn.params["name"] == "paged_walk_attention"
                 and sum(tuple(v.aval.shape) == tuple(pool_shape)
-                        for v in eqn.invars) == 2):
+                        for v in eqn.invars) == pools):
             found += 1
 
     _walk_skip_kernels(closed.jaxpr, visit)

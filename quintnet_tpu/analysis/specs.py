@@ -283,13 +283,14 @@ def expected_serve_moe(n_layers: int, *,
     return c
 
 
-def expected_serve_latent_moe() -> Dict[str, object]:
-    """What every compiled serving program (prefill bucket, decode,
-    verify bucket; all named ``jit_serve_*`` like the others') of a
-    LATENT family with the dropless router
+def expected_serve_latent_moe(*, chunk: bool) -> Dict[str, object]:
+    """What one compiled serving program (all named ``jit_serve_*``
+    like the others') of a LATENT family with the dropless router
     (serve/families.pangu_moe_family) reads under the structural audits
     of analysis/jaxpr_audit.py, on one device — the only place it runs:
-    the engine refuses it a mesh.
+    the engine refuses it a mesh. ``chunk``: a prefill bucket (True) or
+    the decode / a verify program, on a bf16/f16 pool where the
+    per-row walk lowers (a TPU, or the interpreter the tests turn on).
 
     - ``census``: no collective at all. The expert layer is told which
       experts it holds and computes their part; nothing stands in for
@@ -298,13 +299,20 @@ def expected_serve_latent_moe() -> Dict[str, object]:
       scans' carry (the dense stack's, then the MoE stack's).
     - ``view_head_splits`` 0 in EVERY program, not in decode alone:
       all heads read the one row, so neither form of the attention
-      cuts the gathered view into heads (nn/attention.py).
+      cuts the rows into heads (nn/attention.py).
     - ``widened_view_dots`` 0: both forms round the small operand to
       a bf16 pool's dtype.
-    - ``gathered_view_gathers`` 2: one row kind, gathered once in the
-      body of each of the two layer scans."""
+    - decode and verify (the ABSORBED form) gather NOTHING: each row
+      walks its live blocks of the one pool in place —
+      ``gathered_view_gathers`` 0, ``row_walk_calls`` 2 (``pools=1``),
+      one in the body of each of the two layer scans
+      (nn/attention.latent_attend_absorbed); a prefill bucket (the
+      MATERIALIZED form) gathers the one row kind once a scan body, 2,
+      and walks nothing."""
     return {"census": {}, "pool_scan_operands": 0, "view_head_splits": 0,
-            "widened_view_dots": 0, "gathered_view_gathers": 2}
+            "widened_view_dots": 0,
+            "gathered_view_gathers": 2 if chunk else 0,
+            "row_walk_calls": 0 if chunk else 2}
 
 
 def expected_serve_window_moe(*, full_runs: int, sliding_runs: int,

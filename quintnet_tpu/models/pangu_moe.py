@@ -24,7 +24,7 @@ The cache holds ``[c | k_rope]``, one row a token (nn/attention.py,
 prefill MATERIALIZES ``k_nope`` and ``v`` from the gathered rows;
 decode and verify ABSORB ``W_uk`` into the query (``q_lat = q_nope
 W_uk^T``) and ``W_uv`` into the output, and contract the rows as
-stored.
+stored: each sequence's live blocks of the pool, in place.
 
 The config's field names are the Hugging Face keys. ``n_routed_experts``
 counts the experts HELD here (``experts_first`` on), of the router's
@@ -269,8 +269,9 @@ def mla_paged(p, x, pool, layer, positions, lens, tables, block_size: int,
     """Latent attention of a run of tokens a row over the paged latent
     pool: x [S, P, D] (normed) at ``positions`` [S, P] -> (y [S, P, D],
     pool). The run's rows ``[c | k_rope]`` are written into ``layer``
-    first, so the gathered view holds them. ``form``: ABSORBED or
-    MATERIALIZED (module docstring); ``cos``/``sin`` [S, P, rope]."""
+    first, so the pool holds them: the ABSORBED form reads each row's
+    live blocks of it in place, the MATERIALIZED one a gathered view
+    (``form``: module docstring); ``cos``/``sin`` [S, P, rope]."""
     s, t, _ = x.shape
     h = cfg.num_attention_heads
     nope, rope = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
@@ -295,18 +296,20 @@ def mla_paged(p, x, pool, layer, positions, lens, tables, block_size: int,
             rows = jnp.concatenate([c, k_rope], axis=-1)
         pool = latent_write(pool, layer, rows, positions, lens,
                             block_tables=tables, block_size=block_size)
-        with jax.named_scope("kv_gather"):
-            view = paged_gather(pool, layer, tables, block_size=block_size)
         kv_up = p["kv_up"]["w"].reshape(rank, h, nope + vd)
         if form == ABSORBED:
             with jax.named_scope("absorb"):
                 q_lat = jnp.einsum("sphn,chn->sphc", q_nope,
                                    kv_up[..., :nope])
-            o_lat = latent_attend_absorbed(q_lat, q_rope, view, positions,
-                                           scale=scale)
+            o_lat = latent_attend_absorbed(
+                q_lat, q_rope, pool, layer, positions, tables,
+                block_size=block_size, scale=scale)
             with jax.named_scope("kv_up"):
                 o = jnp.einsum("sphc,chv->sphv", o_lat, kv_up[..., nope:])
         elif form == MATERIALIZED:
+            with jax.named_scope("kv_gather"):
+                view = paged_gather(pool, layer, tables,
+                                    block_size=block_size)
             o = latent_attend_materialized(
                 q_nope, q_rope, view, kv_up, positions, scale=scale,
                 v_dim=vd, head_group=HEAD_GROUP)

@@ -1020,6 +1020,27 @@ def noted_reads():
         _READS.notes = None
 
 
+def _walk_tiles(rows: int, pool, block_tables, block_size: int):
+    """``(key_block, padded_rows)`` of a per-row walk over ``pool``: a
+    trip's positions — whole pages, :data:`WALK_KEY_BLOCK` or the
+    table's at most — and ``rows`` query rows a sequence rounded up to
+    whole sublane tiles of the stored dtype (16 rows of bf16)."""
+    key_block = block_size * max(
+        min(WALK_KEY_BLOCK // block_size, block_tables.shape[1]), 1)
+    tile = 8 * 4 // pool.dtype.itemsize
+    return key_block, -(-rows // tile) * tile
+
+
+def _walk_where_it_lowers(here: bool, on_tpu: bool, walk, gathered, *args):
+    """``walk(*args)`` where the kernel lowers, ``gathered(*args)``
+    where it does not: decided now if this process's backend (``here``)
+    and a TPU (``on_tpu``) agree, else at lowering — both traced under
+    ``lax.platform_dependent``, as :func:`local_attention` does."""
+    if here == on_tpu:
+        return (walk if here else gathered)(*args)
+    return lax.platform_dependent(*args, tpu=walk, default=gathered)
+
+
 def _paged_attend_walk(q, k, v, pools, layer, positions, lens,
                        block_tables, *, block_size: int,
                        scale: Optional[float]):
@@ -1048,17 +1069,12 @@ def _paged_attend_walk(q, k, v, pools, layer, positions, lens,
 
     _, hkv, P, dh = k.shape
     rows = q.shape[1] * q.shape[2]
-    # whole pages a trip, the table's at most
-    key_block = block_size * max(
-        min(WALK_KEY_BLOCK // block_size, block_tables.shape[1]), 1)
+    key_block, padded = _walk_tiles(rows, pools[0], block_tables, block_size)
     here = walk_lowers_for(jax.default_backend(), block_size)
     # what THIS process's program reads of a row
     _note_read(key_block if here else block_tables.shape[1] * block_size)
     pools = paged_write(*pools, layer, k, v, positions, lens,
                         block_tables=block_tables, block_size=block_size)
-    # whole sublane tiles of the stored dtype (16 rows of bf16)
-    tile = 8 * 4 // pools[0].dtype.itemsize
-    padded = -(-rows // tile) * tile
 
     def walk(q, k_pool, v_pool, layer, positions, block_tables):
         with jax.named_scope("sdpa"):
@@ -1080,10 +1096,9 @@ def _paged_attend_walk(q, k, v, pools, layer, positions, lens,
             q, k_rows, v_rows, _seen(positions, q, block_tables, block_size),
             kv_heads=hkv, scale=scale)
 
-    args = (q, *pools, layer, positions, block_tables)
-    if here == walk_lowers_for("tpu", block_size):
-        return (walk if here else gathered)(*args), pools
-    return lax.platform_dependent(*args, tpu=walk, default=gathered), pools
+    return _walk_where_it_lowers(
+        here, walk_lowers_for("tpu", block_size), walk, gathered,
+        q, *pools, layer, positions, block_tables), pools
 
 
 def _paged_attend_key_blocked(q, k, v, pools, layer, positions, lens,
@@ -1278,8 +1293,9 @@ def window_attend(q, k, v, bufs, layer, positions, lens, row0, *,
 # ``[c | k_rope]`` — the normed compressed kv (``rank`` features) and
 # the rotated shared rotary key (``rope`` features) — padded with zero
 # lanes to the pool's width like every pool row; no V pool. All heads
-# read the same row, so there is no head to split the gathered view by:
-# both forms below contract it as :func:`paged_gather` leaves it.
+# read the same row, so there is no head to split the rows by: the
+# absorbed form contracts them where they lie in the pool, the
+# materialized one as :func:`paged_gather` leaves them.
 # ---------------------------------------------------------------------
 def latent_write(pool, layer, rows, positions, lens, *, block_tables,
                  block_size: int):
@@ -1294,42 +1310,116 @@ def latent_write(pool, layer, rows, positions, lens, *, block_tables,
         return pool.at[layer, idx].set(flat.astype(pool.dtype))
 
 
-def latent_attend_absorbed(q_lat, q_rope, view, positions, *, scale: float):
+def latent_attend_absorbed(q_lat, q_rope, pool, layer, positions,
+                           block_tables, *, block_size: int, scale: float):
     """The ABSORBED form (decode, verify): the queries were carried
     into the latent space (``q_lat`` [S, P, H, rank] = ``q_nope W_uk``
-    per head, ``q_rope`` [S, P, H, rope] rotated), so scores and
-    values contract the gathered rows ``view`` [S, M, bs, F] AS STORED
-    — all ``R = P * H`` query rows of a sequence against its ``T = M *
-    bs`` rows in one matmul each, the zero pad lanes of the query
-    meeting the row's. Returns ``o_lat`` [S, P, H, rank] (``W_uv``
-    comes after). The arithmetic is :func:`_masked_sdpa`'s stored
-    branch: the query and the probabilities rounded to a bf16/f16
-    view's dtype, f32 sums, f32 softmax; the values' product also
-    covers the rotary and pad lanes of the rows (a quarter more FLOPs
-    on rows the matrix unit has idle) and drops them from the small
-    result, where cutting them off the VIEW would copy it.
+    per head, ``q_rope`` [S, P, H, rope] rotated), so all ``R = P * H``
+    query rows of a sequence ``[q_lat | q_rope | zero pad]`` contract
+    that sequence's rows of ``(pool, layer)`` AS STORED, the zero pad
+    lanes of the query meeting the row's. Returns ``o_lat`` [S, P, H,
+    rank] (``W_uv`` comes after).
 
-    The scores are laid out ``[S, T, R]``, positions on the SUBLANES
-    and the query rows on the lanes (a decode step's 128 heads fill
-    them exactly): the softmax then reduces over whole registers. Laid
-    out ``[S, R, T]`` the same reduction ran across lanes and took 11.1
-    ms a layer at the published decode shapes where this takes 2.4 (my
-    chip run, PR 31). Scopes ``scores`` / ``values``."""
+    Each sequence walks its OWN live key blocks of the pool where they
+    lie — ``max(positions[s]) // WALK_KEY_BLOCK + 1`` trips, one for a
+    row at position 0 — and folds them into a running softmax
+    (ops/paged_attention.paged_walk_attention with no v pool: the block
+    the scores read is the block the values read, its first ``rank``
+    lanes where ``rank`` is whole 128-lane tiles). No view of the
+    table's width exists; a decoding row's 128 heads are 128 query rows
+    on one latent row, a full matrix-unit tile with no diagonal. The
+    arithmetic is :func:`_masked_sdpa`'s stored branch — the query and
+    the probabilities rounded to the pool's dtype, f32 sums, f32
+    softmax, the mask ``t <= positions[s, p]`` — with the softmax's
+    sums in key-block order. Scope ``sdpa``.
+
+    Chosen as :func:`_paged_attend_walk` chooses, from what the call
+    can observe: the kernel takes a bf16/f16 pool, on a TPU with pages
+    of whole HBM tiles or under the interpreter a caller turned on
+    (ops/paged_attention.walk_lowers_for), for as many query rows as
+    its own VMEM sum allows (``walk_vmem_bytes``: a verify bucket's
+    ``R`` is 128 a drafted token). Everything else — an f32 pool,
+    another platform, narrower pages — GATHERS the table's width
+    (:func:`paged_gather`, scope ``kv_gather``) and contracts the view
+    ``[S, T, F]`` in one matmul each for scores and values (scopes
+    ``scores`` / ``values``), both traced under
+    ``lax.platform_dependent`` where this process's backend and the
+    chip would choose apart. That form lays its scores out ``[S, T,
+    R]``, positions on the SUBLANES and the query rows on the lanes:
+    the softmax then reduces over whole registers (``[S, R, T]`` took
+    11.1 ms a layer at the published decode shapes where this takes
+    2.4; my chip run, PR 31); its values' product also covers the
+    rotary and pad lanes of the rows and drops them from the small
+    result, where cutting them off the VIEW would copy it."""
+    from quintnet_tpu.ops.paged_attention import (VMEM_CAP_BYTES,
+                                                  paged_walk_attention,
+                                                  walk_lowers_for,
+                                                  walk_vmem_bytes)
+
     s, p, h, rank = q_lat.shape
-    _, m, bs, f = view.shape
-    t, r = m * bs, p * h
-    rows = view.reshape(s, t, f)
+    f, m = pool.shape[-1], block_tables.shape[1]
+    t, r = m * block_size, p * h
+    out_dtype = jnp.result_type(q_lat, pool)
+    q = jnp.concatenate([q_lat, q_rope], axis=-1).reshape(s, r, -1)
+    key_block, padded = _walk_tiles(r, pool, block_tables, block_size)
+    # the value lanes a free slice of a row gives
+    kept = rank if rank % 128 == 0 else f
+    fits = (pool.dtype in (jnp.bfloat16, jnp.float16)
+            and 2 * walk_vmem_bytes(
+                rows=padded, lanes=f, kept_lanes=kept, key_block=key_block,
+                pools=1, pool_dtype=pool.dtype, q_dtype=pool.dtype)
+            <= VMEM_CAP_BYTES)
+    here = fits and walk_lowers_for(jax.default_backend(), block_size)
+    # what THIS process's program reads of a row
+    _note_read(key_block if here else t)
+
+    def walk(q, pool, layer, positions, block_tables):
+        with jax.named_scope("sdpa"):
+            qd = jnp.pad(q.astype(pool.dtype),
+                         ((0, 0), (0, padded - r), (0, f - q.shape[-1])))
+            # query rows are laid out p * H + h
+            qpos = jnp.pad(jnp.repeat(positions, h, axis=1),
+                           ((0, 0), (0, padded - r)), constant_values=-1)
+            o = paged_walk_attention(
+                qd, qpos, pool, None, layer, block_tables,
+                block_size=block_size, key_block=key_block,
+                head_dim=q.shape[-1], scale=scale, kept_lanes=kept)
+            return o[:, :r, :rank].reshape(s, p, h, rank).astype(out_dtype)
+
+    def gathered(q, pool, layer, positions, block_tables):
+        return _latent_absorbed_gathered(
+            q, pool, layer, positions, block_tables, block_size=block_size,
+            scale=scale, heads=h, rank=rank).astype(out_dtype)
+
+    return _walk_where_it_lowers(
+        here, fits and walk_lowers_for("tpu", block_size), walk, gathered,
+        q, pool, layer, positions, block_tables)
+
+
+def _latent_absorbed_gathered(q, pool, layer, positions, block_tables, *,
+                              block_size: int, scale: float, heads: int,
+                              rank: int):
+    """:func:`latent_attend_absorbed` on a GATHERED view of the table's
+    width (its docstring says when): ``q`` [S, R, rank + rope], query
+    rows laid out ``p * heads + h``, against ``[S, T, F]`` rows of
+    ``(pool, layer)`` in one matmul each for scores ``[S, T, R]`` and
+    values. Returns ``o_lat`` [S, P, heads, rank] f32."""
+    s, r, _ = q.shape
+    p, f = r // heads, pool.shape[-1]
+    t = block_tables.shape[1] * block_size
+    with jax.named_scope("kv_gather"):
+        rows = paged_gather(pool, layer, block_tables,
+                            block_size=block_size).reshape(s, t, f)
     with jax.named_scope("scores"):
-        q = jnp.concatenate([q_lat, q_rope], axis=-1).reshape(s, r, -1)
         qs = _as_stored(q, rows)
         pad = max(_MIN_DOT_ROWS - r, 0) if qs is not q else 0
         qs = jnp.pad(qs, ((0, 0), (0, pad), (0, f - qs.shape[-1])))
         # column c of a row's view is valid for the query at
-        # positions[s, p] iff c <= positions[s, p] (paged_attend's
-        # mask); query rows are laid out p * H + h
+        # positions[s, p] iff c <= positions[s, p] (paged_attend's mask)
         ok = jnp.arange(t)[None, :, None] <= positions[:, None, :]
         valid = jnp.pad(
-            jnp.broadcast_to(ok[..., None], (s, t, p, h)).reshape(s, t, r),
+            jnp.broadcast_to(ok[..., None],
+                             (s, t, p, heads)).reshape(s, t, r),
             ((0, 0), (0, 0), (0, pad)))
         scores = jnp.einsum("stf,srf->str", rows, qs,
                             preferred_element_type=jnp.float32) * scale
@@ -1339,8 +1429,7 @@ def latent_attend_absorbed(q_lat, q_rope, view, positions, *, scale: float):
     with jax.named_scope("values"):
         o = jnp.einsum("str,stf->srf", probs, rows,
                        preferred_element_type=jnp.float32)
-        return o[:, :r, :rank].reshape(s, p, h, rank).astype(
-            jnp.result_type(q_lat, rows))
+        return o[:, :r, :rank].reshape(s, p, heads, rank)
 
 
 def latent_attend_materialized(q_nope, q_rope, view, kv_up, positions, *,

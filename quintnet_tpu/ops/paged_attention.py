@@ -83,7 +83,11 @@ holds two key blocks and an ``[R, F]`` accumulator whatever the
 table's width (17,408 positions in the window cell); and the heads
 stay on the lane diagonal of the small operand, so a page is
 contracted as stored. It is held to the gathered form by the rounding
-bound, not to bits (tests/test_paged_attention.py ``TestRowWalk``).
+bound, not to bits (tests/test_paged_attention.py ``TestRowWalk``). A
+pool with NO v buffer (PR 36: a latent family's one row a token, every
+head's key and value at once) takes the same kernel with one buffer and
+one DMA a page, the values' product against the block the scores read
+(``TestLatentRowWalk``; nn/attention.latent_attend_absorbed).
 
 Interpret mode is the CALLER's decision, never the kernel's: the
 default is the compiled Mosaic kernel on whatever backend the process
@@ -508,8 +512,7 @@ def _page_copy(pool, buf, sem, tbl_ref, layer, s, c, slot, i, *,
 
 
 def _walk_kernel(layer_ref, tbl_ref, trips_ref, first_ref, qd_ref,
-                 qpos_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, k_sem, v_sem,
-                 m_scr, l_scr, acc_scr, *, block_size: int, pages: int,
+                 qpos_ref, *refs, block_size: int, pages: int,
                  head_dim: int, scale):
     """One row of the grid: the flash recurrence over the row's
     ``trips_ref[s]`` live key blocks. The pool stays in HBM; block ``c
@@ -517,7 +520,19 @@ def _walk_kernel(layer_ref, tbl_ref, trips_ref, first_ref, qd_ref,
     ``k_buf`` / ``v_buf`` while block ``c`` is scored, so the walk never
     waits on a row's end. ``first_ref[s]`` is the number of blocks the
     rows before ``s`` walked: the parity of ``first + c`` is the buffer
-    half, carried across rows without a counter."""
+    half, carried across rows without a counter.
+
+    ``refs``: the pools in HBM, the output, a double buffer a pool, a
+    semaphore pair a pool, then ``m``, ``l`` and ``acc``. A pool with no
+    v (a latent one: a position's key and value are the same row) has
+    ONE of each: one DMA a page, and the values' product reads the block
+    the scores read, its first ``acc``-many lanes."""
+    n = (len(refs) - 4) // 3
+    pools, o_ref = refs[:n], refs[n]
+    bufs, sems = refs[n + 1:2 * n + 1], refs[2 * n + 1:3 * n + 1]
+    m_scr, l_scr, acc_scr = refs[3 * n + 1:]
+    k_buf, v_buf = bufs[0], bufs[-1]
+    kept = acc_scr.shape[1]
     s = pl.program_id(0)
     rows = pl.num_programs(0)
     layer = layer_ref[0]
@@ -526,13 +541,12 @@ def _walk_kernel(layer_ref, tbl_ref, trips_ref, first_ref, qd_ref,
 
     def fetch(s_, c_, slot, act):
         """Start or wait for (``act``) the pages of key block ``c_`` of
-        row ``s_`` into half ``slot``, k and v. One page's code, traced
+        row ``s_`` into half ``slot``, every pool's. One page's code, traced
         once and unrolled when the kernel is lowered: sixteen pages a
         trip written out in Python cost the decode program's first
         trace half a second."""
         def page(i, _):
-            for pool, buf, sem in ((k_hbm, k_buf, k_sem),
-                                   (v_hbm, v_buf, v_sem)):
+            for pool, buf, sem in zip(pools, bufs, sems):
                 act(_page_copy(pool, buf, sem, tbl_ref, layer, s_, c_,
                                slot, i, block_size=block_size,
                                pages=pages))
@@ -576,8 +590,10 @@ def _walk_kernel(layer_ref, tbl_ref, trips_ref, first_ref, qd_ref,
         p = jnp.exp(scores - m_new)
         l_scr[...] = l_scr[...] * grow + jnp.sum(p, axis=1, keepdims=True)
         acc_scr[...] = acc_scr[...] * grow + lax.dot_general(
-            p.astype(v_buf.dtype), v_buf[slot], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)       # [R, F]
+            p.astype(v_buf.dtype),
+            v_buf[slot] if kept == v_buf.shape[2] else v_buf[slot, :, :kept],
+            (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)       # [R, kept lanes]
         m_scr[...] = m_new
         return 0
 
@@ -585,9 +601,29 @@ def _walk_kernel(layer_ref, tbl_ref, trips_ref, first_ref, qd_ref,
     o_ref[0] = acc_scr[...] / l_scr[...]
 
 
+def walk_vmem_bytes(*, rows: int, lanes: int, kept_lanes: int,
+                    key_block: int, pools: int, pool_dtype,
+                    q_dtype) -> int:
+    """VMEM one grid step of :func:`paged_walk_attention` holds, from
+    its padded shapes: both halves of a key block a pool, ``rows`` query
+    rows of ``lanes`` and their ``kept_lanes``-wide f32 output
+    (pipelined: twice each), ``acc`` and its rescaled copies, the
+    scores and the probabilities. The call asks the compiler for twice
+    this; a caller whose rows a sequence grow with its program (a
+    latent family's verify bucket: 128 heads a drafted token) asks
+    whether twice this is inside :data:`VMEM_CAP_BYTES` before it takes
+    the walk."""
+    f32 = jnp.float32
+    return (2 * pools * _padded_bytes((key_block, lanes), pool_dtype)
+            + 2 * 2 * _padded_bytes((rows, lanes), q_dtype)
+            + 2 * _padded_bytes((rows, kept_lanes), f32) * 2
+            + 3 * _padded_bytes((rows, kept_lanes), f32)
+            + 4 * _padded_bytes((rows, key_block), f32))
+
+
 def paged_walk_attention(qd, qpos, k_pool, v_pool, layer, block_tables, *,
                          block_size: int, key_block: int, head_dim: int,
-                         scale=None, interpret=None):
+                         scale=None, kept_lanes=None, interpret=None):
     """Few query rows a sequence against each sequence's LIVE blocks of
     the carried pool (nn/attention.paged_attend's decode and verify
     form). ``qd`` [S, R, F]: a sequence's query rows in the pool's
@@ -598,28 +634,36 @@ def paged_walk_attention(qd, qpos, k_pool, v_pool, layer, block_tables, *,
     at, -1 for a pad row. ``k_pool`` / ``v_pool`` [L, slots, F] whole,
     read at ``layer`` (a traced scalar) through ``block_tables`` [S, M].
 
+    ``v_pool`` None is a pool of ONE row kind (a latent family's: every
+    head reads the same row, as key and as value;
+    nn/attention.latent_attend_absorbed): one buffer and one DMA a page,
+    and the values' product runs against the block the scores read.
+    ``kept_lanes``: the leading lanes of a value row the output keeps
+    (a latent row's rank where that is whole 128-lane tiles: the
+    rotary and pad lanes' products are never made); None keeps all.
+
     Row ``s`` reads key blocks ``0 .. max(qpos[s]) // key_block`` and no
     more — ``key_block`` positions, a whole number of pages, a trip —
     straight out of the pool in HBM, double-buffered, and folds them
     into a running softmax: scores ``[R, F] x [key_block, F]`` in the
     stored dtype with f32 sums, the mask ``t <= qpos``, f32 ``exp``,
     the probabilities rounded to the stored dtype before ``[R,
-    key_block] x [key_block, F]``. Returns ``o`` [S, R, F] f32,
-    normalised: row ``r``'s output is in its own head's lanes."""
+    key_block] x [key_block, F]``. Returns ``o`` [S, R, kept lanes]
+    f32, normalised: row ``r``'s output is in its own head's lanes."""
     if interpret is None:
         interpret = INTERPRET
     S, R, F = qd.shape
     M = block_tables.shape[1]
+    pools = (k_pool,) if v_pool is None else (k_pool, v_pool)
+    kept = F if kept_lanes is None else kept_lanes
     pages = max(min(key_block // block_size, M), 1)
     kb = pages * block_size
     last = jnp.max(qpos, axis=1)
     trips = jnp.clip(last // kb + 1, 1, -(-M // pages)).astype(jnp.int32)
     first = (jnp.cumsum(trips) - trips).astype(jnp.int32)
-    vmem = (2 * 2 * _padded_bytes((kb, F), k_pool.dtype)      # k, v halves
-            + 2 * 2 * _padded_bytes((R, F), qd.dtype)         # q, pipelined
-            + 2 * _padded_bytes((R, F), jnp.float32) * 2      # o, pipelined
-            + 3 * _padded_bytes((R, F), jnp.float32)          # acc + temps
-            + 4 * _padded_bytes((R, kb), jnp.float32))        # scores, p
+    vmem = walk_vmem_bytes(rows=R, lanes=F, kept_lanes=kept, key_block=kb,
+                           pools=len(pools), pool_dtype=k_pool.dtype,
+                           q_dtype=qd.dtype)
     kernel = functools.partial(_walk_kernel, block_size=block_size,
                                pages=pages, head_dim=head_dim, scale=scale)
     row = lambda s, *_: (s, 0, 0)                             # noqa: E731
@@ -631,23 +675,20 @@ def paged_walk_attention(qd, qpos, k_pool, v_pool, layer, block_tables, *,
                   # wherever the compiler keeps the carried pool: HBM
                   # at any deployment's size (a pool of a few tens of
                   # MB it moves into VMEM whole, scatter and all)
-                  pl.BlockSpec(memory_space=pl.ANY),
-                  pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=pl.BlockSpec((1, R, F), row),
+                  *[pl.BlockSpec(memory_space=pl.ANY) for _ in pools]],
+        out_specs=pl.BlockSpec((1, R, kept), row),
         scratch_shapes=[
-            pltpu.VMEM((2, kb, F), k_pool.dtype),
-            pltpu.VMEM((2, kb, F), v_pool.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((2,)),
+            *[pltpu.VMEM((2, kb, F), p.dtype) for p in pools],
+            *[pltpu.SemaphoreType.DMA((2,)) for _ in pools],
             pltpu.VMEM((R, 1), jnp.float32),
             pltpu.VMEM((R, 1), jnp.float32),
-            pltpu.VMEM((R, F), jnp.float32),
+            pltpu.VMEM((R, kept), jnp.float32),
         ])
     with jax.named_scope("paged_walk"):
         return pl.pallas_call(
             kernel,
             grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((S, R, F), jnp.float32),
+            out_shape=jax.ShapeDtypeStruct((S, R, kept), jnp.float32),
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",),
                 vmem_limit_bytes=min(max(2 * vmem, 32 * 2 ** 20),
@@ -656,4 +697,4 @@ def paged_walk_attention(qd, qpos, k_pool, v_pool, layer, block_tables, *,
             name="paged_walk_attention",
         )(jnp.reshape(layer, (1,)).astype(jnp.int32),
           block_tables.astype(jnp.int32), trips, first,
-          qd, qpos[..., None].astype(jnp.int32), k_pool, v_pool)
+          qd, qpos[..., None].astype(jnp.int32), *pools)

@@ -14,8 +14,9 @@ checked without a chip (PR 21, the bring-up round).
    take the KV pool row-major and hold no copy of it or of a layer's
    slice — nor (PR 30) a decode program of a gathered view; and (PR
    31) the latent family's decode program at openPangu-Ultra-MoE's
-   widths: one row-major latent pool, no copy of a gathered view, the
-   experts as the chip's grouped matmul. The parent
+   widths: one row-major latent pool, (PR 36) walked in place by one
+   kernel call a layer loop with no gathered view, the experts as the
+   chip's grouped matmul. The parent
    commit's paged kernel was
    REFUSED at every one of these shapes (16 MiB default scoped-VMEM
    budget; a (1, Hkv) scale block; a lane-splitting reshape) —
@@ -253,19 +254,25 @@ def test_paged_attention_compiles_for_v5e(chip, rows, queries, scaled):
 # ---------------------------------------------------------------------
 # PR 34: the per-row walk at the serving cells' own decode shapes
 # ---------------------------------------------------------------------
-@pytest.mark.parametrize("rows,queries,lanes,layers,blocks,table,dh", [
-    (12, 32, 1664, 48, 384, 64, 64),        # gpt2-xl.serve-chat-sat
-    (12, 128, 1664, 48, 384, 64, 64),       # a verify run of four drafts
-    (64, 32, 512, 4, 2048, 64, 64),         # granite-4.0-h-micro
-    (48, 48, 1024, 2, 22528, 1088, 128),    # laguna-xs.2.serve-code-sat
-], ids=["xl-decode", "xl-verify", "hybrid-decode", "window-decode"])
+@pytest.mark.parametrize("rows,queries,lanes,layers,blocks,table,dh,kept", [
+    (12, 32, 1664, 48, 384, 64, 64, None),      # gpt2-xl.serve-chat-sat
+    (12, 128, 1664, 48, 384, 64, 64, None),     # a verify run of four drafts
+    (64, 32, 512, 4, 2048, 64, 64, None),       # granite-4.0-h-micro
+    (48, 48, 1024, 2, 22528, 1088, 128, None),  # laguna-xs.2.serve-code-sat
+    # openpangu-ultra-moe-718b.serve-doc-sat (PR 36): ONE pool, 128
+    # heads a token on a 576-of-640-lane latent row, 512 lanes kept
+    (64, 128, 640, 5, 7680, 320, 576, 512),
+    (64, 640, 640, 5, 7680, 320, 576, 512),     # four drafts: 5 x 128 rows
+], ids=["xl-decode", "xl-verify", "hybrid-decode", "window-decode",
+        "latent-decode", "latent-verify"])
 def test_paged_walk_compiles_for_v5e(chip, rows, queries, lanes, layers,
-                                     blocks, table, dh):
+                                     blocks, table, dh, kept):
     """``paged_walk_attention`` takes the WHOLE pool of each serving
     cell (1.5 GB a buffer in the window cell) as an HBM operand, its
     block table (52,224 entries there) as a scalar prefetch, and fits
     its double buffer of key blocks in VMEM, at the engine's key
-    block."""
+    block. ``kept``: a latent family's call — no v pool, the values'
+    product against the key block's first ``kept`` lanes."""
     from quintnet_tpu.nn.attention import WALK_KEY_BLOCK
 
     pa = importlib.import_module("quintnet_tpu.ops.paged_attention")
@@ -274,15 +281,17 @@ def test_paged_walk_compiles_for_v5e(chip, rows, queries, lanes, layers,
         return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
 
     pool = sds((layers, blocks * BS, lanes), jnp.bfloat16)
+    pools = (pool, pool) if kept is None else (pool,)
 
-    def fn(qd, qpos, k, v, layer, tables):
+    def fn(qd, qpos, layer, tables, k, v=None):
         return pa.paged_walk_attention(
             qd, qpos, k, v, layer, tables, block_size=BS,
-            key_block=WALK_KEY_BLOCK, head_dim=dh, interpret=False)
+            key_block=WALK_KEY_BLOCK, head_dim=dh, kept_lanes=kept,
+            scale=None if kept is None else dh ** -0.5, interpret=False)
 
     text = _compile(fn, sds((rows, queries, lanes), jnp.bfloat16),
-                    sds((rows, queries), jnp.int32), pool, pool,
-                    sds((), jnp.int32), sds((rows, table), jnp.int32))
+                    sds((rows, queries), jnp.int32), sds((), jnp.int32),
+                    sds((rows, table), jnp.int32), *pools)
     # the pool goes in as it is: no copy, slice or re-layout of it
     assert not [ln for ln in text.splitlines()
                 if f"bf16[{layers},{blocks * BS},{lanes}]" in ln
@@ -366,16 +375,20 @@ def test_hybrid_decode_holds_one_copy_of_the_state_on_v5e(chip):
                                         view=(slots, width, bs), walks=1)
 
 
-def test_latent_decode_contracts_the_rows_as_gathered_on_v5e(chip):
+def test_latent_decode_walks_the_one_pool_in_place_on_v5e(chip):
     """``pangu_moe_family(...).decode`` at openPangu-Ultra-MoE's
     published widths (shapes only: ``jax.eval_shape``), the cell's one
     dense and one of its four MoE layers, 8 slots of 5,120 positions,
     the latent pool donated, compiled for the described chip. The pool
     ``[L, slots, 640]`` (576 features padded to five lane rows) must
-    enter row-major and alias out; no copy of it, of a layer's slice or
-    of a gathered view may be planned (the absorbed form cuts no head
-    out of the view); and the experts must run as the chip's own
-    grouped matmul, not as a loop of masked dense ones."""
+    enter row-major and alias out; each of the two layer loops holds
+    ONE call of the per-row walk (PR 36: the absorbed form reads each
+    row's live blocks of the one pool in place), fed by the pool itself
+    — the loop's carry through the in-place ``kv_write`` scatter — so
+    no copy of it, of a layer's slice or of a gathered view may be
+    planned, and no gather at the table's width is left; and the
+    experts must run as the chip's own grouped matmul, not as a loop of
+    masked dense ones."""
     import numpy as np
 
     from quintnet_tpu.models.pangu_moe import PanguMoEConfig, pangu_moe_init
@@ -423,6 +436,12 @@ def test_latent_decode_contracts_the_rows_as_gathered_on_v5e(chip):
     view_bytes = slots * width * bs * 640 * 2
     got = audit.read_hlo(hlo, {"k": pool.shape}, view_bytes)
     assert got["entry_layouts"] == {"k": ["{2,1,0}"]}, got
+    assert len(got["row_walks"]) == 2, got["row_walks"]
+    for call in got["row_walks"]:
+        assert len(call["pool_operands"]) == 1, call
+        assert call["copies_beside"] == [], call
+    assert f"[{slots},{width},{bs}," not in hlo.replace(" ", "")
+    assert f"[{slots},{width * bs}," not in hlo.replace(" ", "")
     for c in got["big_copies"]:
         dims = tuple(c["dims"])
         assert dims not in {tuple(pool.shape), tuple(pool.shape[1:]),

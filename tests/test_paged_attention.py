@@ -1288,6 +1288,164 @@ class TestRowWalk:
         np.testing.assert_array_equal(np.asarray(trips), [1, 1, 1, 2, 3, 4])
 
 
+class TestLatentRowWalk:
+    """The same walk over a pool of ONE row kind (a latent family's: no
+    v pool, every head reads the same row as key and as value;
+    nn/attention.latent_attend_absorbed) against the form it replaced
+    there, the view gathered at the table's width — both inside the
+    bound rounding the query and the probabilities earns against f64
+    math on the stored rows."""
+
+    WIDTH, PAGE, KEY_BLOCK = 8, 16, 32              # 128 positions a row
+    #   name: (heads, rank, rope): a latent row of rank + rope features
+    #   in 128-lane pool rows; "tiled": a rank of whole lane tiles, whose
+    #   values' product leaves the rotary and pad lanes out
+    WIDTHS = {"tiny": (4, 24, 16), "tiled": (2, 128, 16)}
+    #   each row's LAST position (None: an all-null table, the run at
+    #   position 0 — an inactive, mid-prefill or warm-up row)
+    LASTS = (0, KEY_BLOCK - 1, KEY_BLOCK, WIDTH * PAGE - 1, None, 77)
+
+    def _case(self, width, P, pool_dtype):
+        from quintnet_tpu.serve.kv_pool import feature_width
+
+        h, rank, rope = self.WIDTHS[width]
+        m, bs = self.WIDTH, self.PAGE
+        rows = len(self.LASTS)
+        dt = jnp.dtype(pool_dtype)
+        rng = np.random.default_rng(36)
+        blocks = 1 + rows * m
+        f = feature_width(1, rank + rope)
+        noise = rng.standard_normal((2, blocks * bs, f))
+        noise[..., rank + rope:] = 0.0              # every writer's pads
+        pool = jnp.asarray(noise, dt)
+        q_lat = jnp.asarray(rng.standard_normal((rows, P, h, rank)),
+                            jnp.float32)
+        q_rope = jnp.asarray(rng.standard_normal((rows, P, h, rope)),
+                             jnp.float32)
+        fresh = jnp.asarray(rng.standard_normal((rows, P, rank + rope)),
+                            jnp.float32)
+        tables = jnp.asarray(
+            [[0] * m if last is None else
+             [1 + r * m + j for j in range(m)]
+             for r, last in enumerate(self.LASTS)], jnp.int32)
+        # a run of P ends at the row's last position (one that would
+        # start before 0 starts there)
+        starts = [max((last or 0) - (P - 1), 0) for last in self.LASTS]
+        positions = (jnp.asarray(starts, jnp.int32)[:, None]
+                     + jnp.arange(P, dtype=jnp.int32)[None, :])
+        lens = jnp.full((rows,), P, jnp.int32)
+        return dict(h=h, rank=rank, rope=rope, m=m, bs=bs, rows=rows,
+                    dt=dt, blocks=blocks, f=f, pool=pool, q_lat=q_lat,
+                    q_rope=q_rope, fresh=fresh, tables=tables,
+                    positions=positions, lens=lens)
+
+    @staticmethod
+    def _attend(c, scale=0.125):
+        import quintnet_tpu.nn.attention as attention
+
+        def fn(q_lat, q_rope, fresh, pool):
+            layer = jnp.int32(1)
+            pool = attention.latent_write(
+                pool, layer, fresh, c["positions"], c["lens"],
+                block_tables=c["tables"], block_size=c["bs"])
+            return attention.latent_attend_absorbed(
+                q_lat, q_rope, pool, layer, c["positions"], c["tables"],
+                block_size=c["bs"], scale=scale), pool
+        return fn
+
+    @pytest.mark.parametrize("pool_dtype", ("bfloat16", "float16"))
+    @pytest.mark.parametrize("P", (1, 3))
+    @pytest.mark.parametrize("width", tuple(WIDTHS))
+    def test_walk_equals_gathered_inside_the_rounding_bound(
+            self, width, P, pool_dtype, monkeypatch):
+        import quintnet_tpu.nn.attention as attention
+
+        c = self._case(width, P, pool_dtype)
+        h, rank, rope, m, bs = (c[n] for n in ("h", "rank", "rope", "m",
+                                                "bs"))
+        monkeypatch.setattr(attention, "WALK_KEY_BLOCK", self.KEY_BLOCK)
+        fn, scale = self._attend(c), 0.125
+        args = (c["q_lat"], c["q_rope"], c["fresh"], c["pool"])
+        shape = c["pool"].shape
+
+        with attention.noted_reads() as reads:
+            assert row_walk_calls(fn, *args, pool_shape=shape, pools=1) == 1
+        assert reads == [self.KEY_BLOCK]
+        assert gathered_view_gathers(fn, *args, num_blocks=c["blocks"],
+                                     table_width=m) == 0
+        got, pool = jax.jit(fn)(*args)
+        # no interpreter: every platform but the TPU gathers the view
+        # (a new function: a trace is remembered by the function traced)
+        monkeypatch.setattr(_kernels(), "INTERPRET", False)
+        fn = self._attend(c)
+        with attention.noted_reads() as reads:
+            assert gathered_view_gathers(
+                fn, *args, num_blocks=c["blocks"], table_width=m) == 1
+        assert reads == [m * bs]
+        ref, pool_ref = jax.jit(fn)(*args)
+        np.testing.assert_array_equal(np.asarray(pool, np.float32),
+                                      np.asarray(pool_ref, np.float32))
+        # the rotary and pad lanes of a value row are dropped
+        assert got.shape == ref.shape == (c["rows"], P, h, rank)
+        assert got.dtype == ref.dtype == jnp.float32
+
+        slots = (np.asarray(c["tables"])[:, :, None] * bs
+                 + np.arange(bs)[None, None, :]).reshape(c["rows"], m * bs)
+        stored = np.asarray(pool[1].astype(jnp.float32), np.float64)[slots]
+        assert not stored[..., rank + rope:].any()
+        keys = np.broadcast_to(stored[:, None, :, :rank + rope],
+                               (c["rows"], h, m * bs, rank + rope))
+        q = np.concatenate([np.asarray(c["q_lat"], np.float64),
+                            np.asarray(c["q_rope"], np.float64)],
+                           axis=-1).transpose(0, 2, 1, 3)   # [S, H, P, D]
+        live = (np.arange(m * bs)[None, None, :]
+                <= np.asarray(c["positions"])[:, :, None])
+        u = 2.0 ** -(jnp.finfo(c["dt"]).nmant + 1)
+        want, bound = TestLaneDiagonal._f64_attention(
+            q, keys, keys[..., :rank], live, scale, u)
+        for out in (got, ref):
+            gap = np.abs(np.asarray(out, np.float64).transpose(0, 2, 1, 3)
+                         - want)
+            assert (gap <= bound).all(), (gap.max(), bound.max())
+        assert bound.max() < 0.2 * np.abs(want).max(), bound.max()
+
+    def test_the_kernels_own_vmem_sum_decides_which_rows_walk(
+            self, monkeypatch):
+        """A verify bucket's query rows are 128 a drafted token at the
+        published widths: the walk takes as many as TWICE its own VMEM
+        sum keeps inside the cap, the gathered form the rest; and an
+        f32 pool, which the kernel does not take."""
+        import quintnet_tpu.nn.attention as attention
+
+        pa = _kernels()
+        c = self._case("tiny", 3, "bfloat16")
+        args = (c["q_lat"], c["q_rope"], c["fresh"], c["pool"])
+        shape = c["pool"].shape
+        need = pa.walk_vmem_bytes(
+            rows=16, lanes=c["f"], kept_lanes=c["f"], key_block=128,
+            pools=1, pool_dtype=c["dt"], q_dtype=c["dt"])
+        for cap, walks in ((2 * need, 1), (2 * need - 1, 0)):
+            monkeypatch.setattr(pa, "VMEM_CAP_BYTES", cap)
+            with attention.noted_reads() as reads:
+                assert row_walk_calls(self._attend(c), *args,
+                                      pool_shape=shape, pools=1) == walks
+            assert reads == [128]                   # the table's width
+        monkeypatch.undo()
+        wide = args[:3] + (c["pool"].astype(jnp.float32),)
+        assert row_walk_calls(self._attend(c), *wide, pool_shape=shape,
+                              pools=1) == 0
+        assert gathered_view_gathers(
+            self._attend(c), *wide, num_blocks=c["blocks"],
+            table_width=c["m"]) == 1
+        # the published decode and verify shapes: 128 heads a token on
+        # 640-lane rows, 512 value lanes kept
+        for tokens in (1, 5, 9):
+            assert 2 * pa.walk_vmem_bytes(
+                rows=128 * tokens, lanes=640, kept_lanes=512,
+                key_block=256, pools=1, pool_dtype=jnp.bfloat16,
+                q_dtype=jnp.bfloat16) <= pa.VMEM_CAP_BYTES
+
+
 def test_ops_import_surface():
     """ops/ exports its public kernel entry points (the previously
     empty ``__init__`` belied its own docstring)."""

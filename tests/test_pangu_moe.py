@@ -486,16 +486,99 @@ def test_the_ring_carries_the_latent_and_expert_facts(params):
         assert a["moe_dropped_tokens"] == 0
 
 
+@pytest.mark.parametrize("traffic", ("steady", "chunked", "speculative"))
+def test_bf16_pool_tokens_equal_walked_and_gathered(params, traffic,
+                                                    monkeypatch):
+    """Greedy tokens of a bf16-pool engine are the same whether its
+    decode and verify programs walk each row's live latent rows in
+    place (the kernel, under the interpreter) or gather the table's
+    width, the form they took before and still take off the TPU —
+    through a steady batch, a prompt prefilled in chunks beside
+    decoding rows, and speculation."""
+    import importlib
+
+    from quintnet_tpu import analysis
+
+    pa = importlib.import_module("quintnet_tpu.ops.paged_attention")
+    kw = dict(kv_dtype="bf16", weights_dtype="bf16")
+    prompts = _prompts(15, [5, 12, 3])
+    if traffic == "chunked":
+        prompts = _prompts(15, [4, 37])
+        kw.update(chunked_prefill=True, prefill_len=16)
+    elif traffic == "speculative":
+        motif = np.random.default_rng(16).integers(0, CFG.vocab_size, 5)
+        prompts = [np.tile(motif, 4)[:n] for n in (17, 20)]
+        kw.update(spec=SpecConfig())
+    served = {}
+    for form, interpret in (("walk", True), ("gathered", False)):
+        monkeypatch.setattr(pa, "INTERPRET", interpret)
+        eng, served[form] = _tokens(params, prompts, n_new=8, **kw)
+        for sentinel, args in eng._warmup_calls():
+            name = sentinel.fn.__name__
+            if name.startswith("serve_prefill_b"):
+                continue
+            assert analysis.row_walk_calls(
+                sentinel.fn, *args, pool_shape=eng.pool.k.shape,
+                pools=1) == 2 * (form == "walk"), name
+    assert served["walk"] == served["gathered"]
+
+
+@pytest.mark.parametrize("spec", (False, True))
+def test_a_step_counts_the_live_key_blocks_it_read(params, spec,
+                                                   monkeypatch):
+    """On a bf16 pool the decode program and every verify bucket note
+    the walk's key block as what they read of a row
+    (``_read_granule``), so a step's ``attended_rows`` is each row of
+    the program — the one that sat out too, at position 0 — rounded up
+    to that block, x the 5 layers, not rows x the table's width."""
+    import quintnet_tpu.nn.attention as attention
+
+    kb, slots = 8, 3
+    monkeypatch.setattr(attention, "WALK_KEY_BLOCK", kb)
+    eng = _engine(params, kv_dtype="bf16", weights_dtype="bf16",
+                  spec=SpecConfig(max_draft=4) if spec else None)
+    layers = eng.recorder.static["paged_layers"]
+    assert layers == CFG.num_hidden_layers == 5
+    width = eng.table_width * eng.pool.block_size
+    rng = np.random.default_rng(17)
+    motif = rng.integers(0, CFG.vocab_size, 4)
+    lens = (9, 14)                               # the third slot sits out
+    for n in lens:
+        eng.submit(np.tile(motif, 4)[:n], 12)
+    run = 1
+    for step in range(5):
+        before = np.array(eng._pos)
+        eng.step()
+        rec = eng.recorder.last()
+        if rec["spec_step"]:
+            # a verify run reads up to its bucket's last column
+            run = 1 + max(b for b in eng.spec.buckets
+                          if f"serve_verify_b{b}" in eng._read_granule)
+        if step == 0:
+            before = np.array(lens + (0,))       # admitted this step
+        last = before + (run - 1 if rec["spec_step"] else 0)
+        want = int(((last // kb + 1) * kb).sum()) * layers
+        assert rec["attrs"]["attended_rows"] == want, (step, rec)
+        assert want < slots * width * layers
+    assert any(r["spec_step"] for r in eng.recorder.snapshot()) == spec
+    eng.warmup()
+    names = ["serve_decode"] + [f"serve_verify_b{b}"
+                                for b in (eng.spec.buckets if spec else ())]
+    assert {n: eng._read_granule[n] for n in names} == dict.fromkeys(names,
+                                                                     kb)
+
+
 def test_the_programs_are_named_and_their_census_is_pinned(params):
     """Every program is ``jit_serve_*`` like the other families'; on a
     bf16 pool none has a collective, a pool-shaped scan operand, a head
-    split of the gathered view or a widened view dot, and each of the
-    two layer scans gathers the one row kind once
+    split of the cached rows or a widened view dot; decode and every
+    verify bucket gather nothing and walk the one pool in place, once a
+    layer scan, where a prefill bucket gathers the one row kind once a
+    scan and walks nothing
     (analysis/specs.expected_serve_latent_moe)."""
     from quintnet_tpu import analysis
     from quintnet_tpu.analysis.specs import expected_serve_latent_moe
 
-    want = expected_serve_latent_moe()
     eng = _engine(params, kv_dtype="bf16", weights_dtype="bf16",
                   spec=SpecConfig(), max_seq_len=88)
     calls = list(eng._warmup_calls())
@@ -503,12 +586,15 @@ def test_the_programs_are_named_and_their_census_is_pinned(params):
     assert names[0] == "serve_decode" and all(
         n.startswith(("serve_prefill_b", "serve_verify_b", "serve_decode"))
         for n in names)
+    assert any(n.startswith("serve_verify_b") for n in names)
     text = calls[0][0].fn.lower(*calls[0][1]).as_text()
     assert "module @jit_serve_" in text
     geometry = dict(table_width=eng.table_width,
                     block_size=eng.pool.block_size)
     for sentinel, args in calls:
         fn = sentinel.fn
+        want = expected_serve_latent_moe(
+            chunk=fn.__name__.startswith("serve_prefill_b"))
         assert analysis.collective_census(fn, *args).as_dict() == \
             want["census"], fn.__name__
         assert analysis.pool_scan_operands(
@@ -520,7 +606,11 @@ def test_the_programs_are_named_and_their_census_is_pinned(params):
             want["widened_view_dots"]
         assert analysis.gathered_view_gathers(
             fn, *args, num_blocks=eng.pool.num_blocks,
-            table_width=eng.table_width) == want["gathered_view_gathers"]
+            table_width=eng.table_width) == \
+            want["gathered_view_gathers"], fn.__name__
+        assert analysis.row_walk_calls(
+            fn, *args, pool_shape=eng.pool.k.shape, pools=1) == \
+            want["row_walk_calls"], fn.__name__
         # the one pool buffer is donated and aliasable
         assert not analysis.donation_report(
             fn, *args).undonated_aliasable, fn.__name__

@@ -18,14 +18,17 @@ token a row written into a bf16 pool of the cell's own shape and
 scored against the row's history, every layer of the pool in one
 program — the gathered view at the table's width, heads on the lane
 diagonal (``gathered``), against the per-row walk of live key blocks
-at each ``--key-blocks`` size (``walk:<positions>``). Row lengths are
+at each ``--key-blocks`` size (``walk:<positions>``); for ``latent``
+(PR 36) the ONE pool of latent rows, 128 absorbed heads a row: the
+view gathered at the table's width against the same walk with no v
+pool. Row lengths are
 drawn as the cell's traffic draws them; ``live_share`` is what the rows
 hold of the table, ``ms_a_layer`` the program's time over its layers,
 ``o_err`` the largest deviation from ``gathered`` over the largest
 magnitude.
 
-    chiprun -- python tools/attn_microbench.py --decode xl,hybrid,window \
-        --key-blocks 128,256,512
+    chiprun -- python tools/attn_microbench.py \
+        --decode xl,hybrid,window,latent --key-blocks 128,256,512
 """
 
 from __future__ import annotations
@@ -88,6 +91,12 @@ DECODE_CELLS = {
     "window": dict(rows=48, hq=48, hkv=8, dh=128, layers=2, blocks=22528,
                    table=1088, lengths=(4096, 0.7, 1024, 16384, 192),
                    scale=None),
+    # ONE pool of latent rows (PR 36): hq heads of queries carried into
+    # the latent space, all on the one row a position has — rank 512 +
+    # rope 64 features in 640 lanes — as key and as value
+    "latent": dict(rows=64, hq=128, hkv=1, dh=576, rank=512, layers=5,
+                   blocks=7680, table=320,
+                   lengths=(1024, 0.8, 128, 4096, 192), scale=576 ** -0.5),
 }
 BLOCK = 16
 
@@ -118,17 +127,43 @@ def _decode_lines(cell: str, key_blocks, iters: int):
     f = feature_width(hkv, dh)
     keys = jax.random.split(jax.random.key(0), 5)
     shape = (layers, c["blocks"] * BLOCK, f)
+    latent = "rank" in c
     kp, vp = (jax.random.normal(k, shape, jnp.bfloat16) for k in keys[:2])
+    if latent:
+        # ONE pool, its pad lanes zero as every writer leaves them; no v
+        # buffer: ``vp`` is a scalar that rides the donated carry
+        kp = kp.at[..., dh:].set(0)
+        vp = jnp.zeros((), jnp.bfloat16)
     q = jax.random.normal(keys[2], (rows, hkv, hq // hkv, dh), jnp.float32)
     k, v = (jax.random.normal(kk, (rows, hkv, 1, dh), jnp.float32)
             for kk in keys[3:])
     positions, lens = jnp.asarray(pos)[:, None], jnp.ones(rows, jnp.int32)
     tables = jnp.asarray(tables)
 
+    def latent_layer(form, l, kp):
+        """One layer's decode attention over the latent pool: the row
+        written, then the absorbed form — the view gathered at the
+        table's width, or each row's live blocks walked in place."""
+        rank = c["rank"]
+        kp = attention.latent_write(kp, l, k[:, 0], positions, lens,
+                                    block_tables=tables, block_size=BLOCK)
+        if form == "gathered":
+            o = attention._latent_absorbed_gathered(
+                q.reshape(rows, hq, dh), kp, l, positions, tables,
+                block_size=BLOCK, scale=c["scale"], heads=hq, rank=rank)
+        else:
+            ql = q.reshape(rows, 1, hq, dh)
+            o = attention.latent_attend_absorbed(
+                ql[..., :rank], ql[..., rank:], kp, l, positions, tables,
+                block_size=BLOCK, scale=c["scale"])
+        return kp, o
+
     def program(form):
         def layer(l, carry):
             kp, vp, acc = carry
-            if form == "gathered":
+            if latent:
+                kp, o = latent_layer(form, l, kp)
+            elif form == "gathered":
                 kp, vp = attention.paged_write(
                     kp, vp, l, k, v, positions, lens, block_tables=tables,
                     block_size=BLOCK)
@@ -146,8 +181,9 @@ def _decode_lines(cell: str, key_blocks, iters: int):
             return kp, vp, acc + o
         # the pools are donated and handed back, as the engine's are: a
         # program that may not write them in place copies both first
+        out = (rows, 1, hq, c["rank"]) if latent else q.shape
         return jax.jit(lambda kp, vp: jax.lax.fori_loop(
-            0, layers, layer, (kp, vp, jnp.zeros(q.shape, jnp.float32))),
+            0, layers, layer, (kp, vp, jnp.zeros(out, jnp.float32))),
             donate_argnums=(0, 1))
 
     ref = None
