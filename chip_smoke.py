@@ -39,6 +39,17 @@ seconds it spent compiling or loading compiled programs):
   show a ``tpu_custom_call`` in the lowered program: a kernel phase
   that ran the reference is a failure.
 
+- **kda_moe** — the linear-attention + latent-attention family
+  (Ling 3.0's: KDA layers with a per-slot f32 state beside one MLA
+  layer's paged latent rows, a group-limited router) at a TINY size
+  through ``ServeEngine`` with chunked prefill: five requests, more
+  than slots, one preempted-free run; every generated token is, by
+  the plain f32 reference's own logits for the same prefix
+  (benchmarks/lib/reference_ling_hybrid.py), within ``TOL`` of that
+  reference's largest logit. The published widths are the
+  benchmark's (``ling-3.0-flash.serve-reason-sat``); this phase proves
+  the family's programs start on the chip.
+
 ``--multichip`` runs none of those. It takes the same train step on a
 ``dp=2 x tp=2`` mesh over four chips against a one-device mesh on the
 first of them (same seed, same global batch, three steps), a tp=2
@@ -89,6 +100,11 @@ TOL = {
     "flash_vs_blockwise_rel": 0.02,
     # dp2 x tp2 vs one-device loss, per step (bf16 compute)
     "mesh_vs_single_loss": 0.02,
+    # a greedy token of the tiny KDA + MLA engine (f32 pool and weights,
+    # the chip's default matmul precision) vs the f32 reference's best:
+    # a third of the logits' spread (0.59); 0.077 read on a v5e at seed
+    # 0 (PR 37), 0 on the CPU's exact f32
+    "kda_moe_greedy_gap": 0.2,
 }
 
 
@@ -591,6 +607,58 @@ def phase_kernels(size: Size, xla_engine, prompts, seed: int) -> Dict:
 
 
 # ---------------------------------------------------------------------
+# the linear-attention + latent family, tiny
+# ---------------------------------------------------------------------
+def phase_kda_moe(seed: int, *, slots: int = 3, max_new: int = 8,
+                  prompt_lens: Sequence[int] = (5, 20, 40, 60, 33)) -> Dict:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmarks.lib import reference_ling_hybrid as reference
+    from quintnet_tpu.models.ling_hybrid import (LingHybridConfig,
+                                                 ling_hybrid_init)
+    from quintnet_tpu.serve import ServeEngine, ling_hybrid_family
+
+    cfg = LingHybridConfig.tiny()
+    params = ling_hybrid_init(jax.random.key(seed), cfg)
+    engine = ServeEngine(
+        ling_hybrid_family(cfg), params, max_slots=slots, block_size=8,
+        num_blocks=64, max_seq_len=96, prefill_len=32,
+        chunked_prefill=True, prefix_cache=False)
+    engine.warmup()
+    rng = np.random.default_rng([seed, 9])
+    prompts = [rng.integers(0, cfg.vocab_size, (n,)).astype(np.int32)
+               for n in prompt_lens]
+    rids = [engine.submit(p, max_new) for p in prompts]
+    engine.run()
+    config = dataclasses.asdict(cfg)
+    gap, spread = 0.0, 0.0
+    for rid, prompt in zip(rids, prompts):
+        out = np.asarray(engine.result(rid))
+        check(len(out) == len(prompt) + max_new,
+              f"request {rid}: {len(out) - len(prompt)} of {max_new} tokens")
+        logits, _, _ = reference.forward(
+            params, jnp.asarray(out[None, :-1]), config,
+            positions=list(range(len(prompt) - 1, len(out) - 1)))
+        logits = np.asarray(logits[0])
+        chosen = np.take_along_axis(
+            logits, out[len(prompt):, None], axis=-1)[:, 0]
+        gap = max(gap, float((logits.max(axis=-1) - chosen).max()))
+        spread = max(spread, float(logits.std()))
+    check(gap <= TOL["kda_moe_greedy_gap"],
+          f"a greedy token lies {gap} under the reference's best logit")
+    m = engine.metrics
+    check(m.moe_dropped_tokens == 0, "the dropless router dropped")
+    return {"requests": len(rids), "new_tokens": len(rids) * max_new,
+            "greedy_gap": gap, "ref_std": spread,
+            "prefill_chunks": m.prefill_chunks, "preempted": m.preempted,
+            "state_bytes_per_slot": engine.pool.state_bytes_per_slot,
+            "programs_of_the_engine": sorted(
+                engine.recorder.static["programs"])}
+
+
+# ---------------------------------------------------------------------
 # --multichip
 # ---------------------------------------------------------------------
 def placement(tree) -> Dict:
@@ -772,6 +840,7 @@ def main() -> None:
             run_phase("kernels", lambda: phase_kernels(
                 size, served["engine"], served["prompts"], args.seed),
                 sink)
+            run_phase("kda_moe", lambda: phase_kda_moe(args.seed), sink)
     print(json.dumps({"ok": True, "device": device}), flush=True)
 
 

@@ -315,6 +315,35 @@ def expected_serve_latent_moe(*, chunk: bool) -> Dict[str, object]:
             "row_walk_calls": 0 if chunk else 2}
 
 
+def expected_serve_kda_moe(*, periods: int, chunk: bool) -> Dict[str, object]:
+    """What one compiled serving program of a LATENT AND RECURRENT
+    family with the group-limited dropless router (serve/families.
+    ling_hybrid_family) reads under the structural audits of
+    analysis/jaxpr_audit.py, on one device — the only place it runs:
+    the engine refuses it a mesh. ``periods``: the model's groups of
+    layers (the first is written out, the rest are one scan over
+    groups); ``chunk``: a prefill bucket (True) or the decode / verify
+    program, on a bf16/f16 pool where the per-row walk lowers.
+
+    - ``census``: no collective at all. The expert layer is told which
+      experts it holds and computes their part; nothing stands in for
+      the exchange with the chips that hold the others.
+    - ``pool_scan_operands`` 0, asked of the latent pool's shape AND of
+      both state buffers': all three ride every loop's carry, whole; a
+      KDA layer reads and writes its rows of its own slice in place.
+    - ``view_head_splits`` 0: all heads read the one latent row.
+    - the latent layer is traced once for the written-out group and
+      once more for the scan over the others: decode (the ABSORBED
+      form) walks each row's live blocks in place — ``row_walk_calls``
+      ``min(periods, 2)`` with ``pools=1``, ``gathered_view_gathers`` 0
+      — and a prefill bucket (the MATERIALIZED form) gathers the one
+      row kind as often and walks nothing."""
+    traced = min(periods, 2)
+    return {"census": {}, "pool_scan_operands": 0, "view_head_splits": 0,
+            "gathered_view_gathers": traced if chunk else 0,
+            "row_walk_calls": 0 if chunk else traced}
+
+
 def expected_serve_window_moe(*, full_runs: int, sliding_runs: int,
                               rows: int, ring: int, width: int,
                               chunk: bool) -> Dict[str, object]:

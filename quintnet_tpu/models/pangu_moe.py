@@ -264,6 +264,49 @@ def pangu_logits(params, h, cfg: PanguMoEConfig):
 # ---------------------------------------------------------------------
 # the layer
 # ---------------------------------------------------------------------
+def latent_rows_attend(p, x, q_nope, q_rope, pool, layer, positions, lens,
+                       tables, block_size: int, cfg, cos, sin, *, form: str):
+    """What every latent-attention layer does once it has its queries
+    (this family's come through a low-rank projection, Ling 3.0's
+    straight off ``W_q``): the run's rows ``[c | k_rope]`` from
+    ``p["kv_down"]`` / ``p["kv_norm"]`` written into ``(pool, layer)``,
+    then the attention in ``form`` (module docstring) with
+    ``p["kv_up"]``. ``x`` [S, P, D] normed, ``q_nope`` [S, P, H, nope],
+    ``q_rope`` [S, P, H, rope] rotated; ``cfg`` any config with the
+    latent widths under the Hugging Face names. Returns (o [S, P, H,
+    v_head_dim], pool). Opened inside the caller's ``mla`` scope."""
+    h = cfg.num_attention_heads
+    nope, rope = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    rank, vd = cfg.kv_lora_rank, cfg.v_head_dim
+    scale = 1.0 / math.sqrt(nope + rope)
+    with jax.named_scope("kv_down"):
+        ckv = quantized_matmul(x, p["kv_down"])
+        c = rms_norm_apply(p["kv_norm"], ckv[..., :rank],
+                           eps=cfg.rms_norm_eps)
+        k_rope = apply_rope(ckv[..., rank:], cos, sin)
+        rows = jnp.concatenate([c, k_rope], axis=-1)
+    pool = latent_write(pool, layer, rows, positions, lens,
+                        block_tables=tables, block_size=block_size)
+    kv_up = p["kv_up"]["w"].reshape(rank, h, nope + vd)
+    if form == ABSORBED:
+        with jax.named_scope("absorb"):
+            q_lat = jnp.einsum("sphn,chn->sphc", q_nope, kv_up[..., :nope])
+        o_lat = latent_attend_absorbed(
+            q_lat, q_rope, pool, layer, positions, tables,
+            block_size=block_size, scale=scale)
+        with jax.named_scope("kv_up"):
+            o = jnp.einsum("sphc,chv->sphv", o_lat, kv_up[..., nope:])
+    elif form == MATERIALIZED:
+        with jax.named_scope("kv_gather"):
+            view = paged_gather(pool, layer, tables, block_size=block_size)
+        o = latent_attend_materialized(
+            q_nope, q_rope, view, kv_up, positions, scale=scale, v_dim=vd,
+            head_group=min(HEAD_GROUP, h))
+    else:
+        raise ValueError(f"unknown latent-attention form {form!r}")
+    return o, pool
+
+
 def mla_paged(p, x, pool, layer, positions, lens, tables, block_size: int,
               cfg: PanguMoEConfig, cos, sin, *, form: str):
     """Latent attention of a run of tokens a row over the paged latent
@@ -273,10 +316,8 @@ def mla_paged(p, x, pool, layer, positions, lens, tables, block_size: int,
     live blocks of it in place, the MATERIALIZED one a gathered view
     (``form``: module docstring); ``cos``/``sin`` [S, P, rope]."""
     s, t, _ = x.shape
-    h = cfg.num_attention_heads
+    h, vd = cfg.num_attention_heads, cfg.v_head_dim
     nope, rope = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
-    rank, vd = cfg.kv_lora_rank, cfg.v_head_dim
-    scale = 1.0 / math.sqrt(nope + rope)
     with jax.named_scope("mla"):
         with jax.named_scope("q_down"):
             cq = rms_norm_apply(p["q_norm"],
@@ -288,33 +329,9 @@ def mla_paged(p, x, pool, layer, positions, lens, tables, block_size: int,
             q_nope = q[..., :nope]
             q_rope = apply_rope(q[..., nope:], cos[:, :, None],
                                 sin[:, :, None])
-        with jax.named_scope("kv_down"):
-            ckv = quantized_matmul(x, p["kv_down"])
-            c = rms_norm_apply(p["kv_norm"], ckv[..., :rank],
-                               eps=cfg.rms_norm_eps)
-            k_rope = apply_rope(ckv[..., rank:], cos, sin)
-            rows = jnp.concatenate([c, k_rope], axis=-1)
-        pool = latent_write(pool, layer, rows, positions, lens,
-                            block_tables=tables, block_size=block_size)
-        kv_up = p["kv_up"]["w"].reshape(rank, h, nope + vd)
-        if form == ABSORBED:
-            with jax.named_scope("absorb"):
-                q_lat = jnp.einsum("sphn,chn->sphc", q_nope,
-                                   kv_up[..., :nope])
-            o_lat = latent_attend_absorbed(
-                q_lat, q_rope, pool, layer, positions, tables,
-                block_size=block_size, scale=scale)
-            with jax.named_scope("kv_up"):
-                o = jnp.einsum("sphc,chv->sphv", o_lat, kv_up[..., nope:])
-        elif form == MATERIALIZED:
-            with jax.named_scope("kv_gather"):
-                view = paged_gather(pool, layer, tables,
-                                    block_size=block_size)
-            o = latent_attend_materialized(
-                q_nope, q_rope, view, kv_up, positions, scale=scale,
-                v_dim=vd, head_group=HEAD_GROUP)
-        else:
-            raise ValueError(f"unknown latent-attention form {form!r}")
+        o, pool = latent_rows_attend(
+            p, x, q_nope, q_rope, pool, layer, positions, lens, tables,
+            block_size, cfg, cos, sin, form=form)
         with jax.named_scope("proj"):
             y = quantized_matmul(o.reshape(s, t, h * vd).astype(x.dtype),
                                  p["o"])

@@ -40,7 +40,14 @@ nothing is dropped at any skew. ``experts_held = (first, count)`` tells
 the layer which experts of the router's range it holds (expert
 parallelism's share of one chip): it routes over all of them and
 computes the part of the result its own give; a routing to an absent
-expert adds nothing here. A ``shared`` SwiGLU in the params is added
+expert adds nothing here. ``n_group`` / ``topk_group`` add DeepSeek-V3's
+group-limited selection (``noaux_tc``): the experts are ``n_group``
+runs of consecutive ones, a group's score is the sum of its two largest
+SELECTION scores, only the ``topk_group`` best groups stay eligible and
+the ``top_k`` largest selection scores inside them are chosen. The
+selection score is ``s + b`` where the router's params carry a bias
+(``router.e_score_correction_bias`` [E]); the gates are always the
+UNBIASED ``s`` of the chosen. A ``shared`` SwiGLU in the params is added
 once, for every token. There the expert weights are linear NODES
 (``experts.{gate,up,down}.w`` [held, in, out]) that a weight layout
 policy packs like any other matmul (serve/weight_quant.py).
@@ -91,6 +98,10 @@ class MoEArgs(NamedTuple):
     scoring: str = "softmax"
     routed_scale: float = 1.0
     experts_held: Optional[Tuple[int, int]] = None
+    # group-limited selection (module docstring); 0 = every expert is
+    # eligible for every token
+    n_group: int = 0
+    topk_group: int = 0
 
 
 def moe_init(key, dim: int, hidden: int, n_experts: int, *,
@@ -129,12 +140,15 @@ def moe_init(key, dim: int, hidden: int, n_experts: int, *,
 
 
 def moe_held_init(key, dim: int, hidden: int, n_experts: int, *,
-                  held: int, shared_hidden: int = 0, dtype=jnp.float32):
+                  held: int, shared_hidden: int = 0, dtype=jnp.float32,
+                  selection_bias: bool = False):
     """Params of the dropless layer (module docstring): a router over
     all ``n_experts``, SwiGLU weights of the ``held`` experts this
     layer has as linear nodes ``experts.{gate,up,down}.w`` [held, in,
     out] (fan-in uniform like :func:`moe_init`), and a ``shared``
-    SwiGLU of width ``shared_hidden`` where that is not 0."""
+    SwiGLU of width ``shared_hidden`` where that is not 0.
+    ``selection_bias`` adds ``router.e_score_correction_bias`` [E] f32,
+    normal(0, 0.01)."""
     kr, kg, ku, kd, ks = jax.random.split(key, 5)
 
     def stack(k, fin, fout):
@@ -149,6 +163,9 @@ def moe_held_init(key, dim: int, hidden: int, n_experts: int, *,
                      "down": stack(kd, hidden, dim)}}
     if shared_hidden:
         p["shared"] = swiglu_init(ks, dim, shared_hidden, dtype=dtype)
+    if selection_bias:
+        p["router"]["e_score_correction_bias"] = 0.01 * jax.random.normal(
+            jax.random.fold_in(kr, 1), (n_experts,), jnp.float32)
     return p
 
 
@@ -229,9 +246,10 @@ def moe_apply(p, x, args: MoEArgs, *, ep_axis: Optional[str] = None,
                              token_mask=token_mask,
                              expert_layer=expert_layer)
     if (args.scoring != "softmax" or args.experts_held is not None
-            or token_mask is not None or expert_layer is not None):
+            or token_mask is not None or expert_layer is not None
+            or args.n_group):
         raise NotImplementedError(
-            "sigmoid scoring, experts_held, token_mask and "
+            "sigmoid scoring, experts_held, n_group, token_mask and "
             "expert_layer belong to the dropless router: pass "
             "MoEArgs(dropless=True)")
     ep = 1 if ep_axis is None else lax.axis_size(ep_axis)
@@ -346,7 +364,17 @@ def _moe_dropless(p, x, args: MoEArgs, *, return_stats: bool,
             scores = jax.nn.softmax(logits, axis=-1)
         else:
             raise ValueError(f"unknown scoring {args.scoring!r}")
-        gate_v, gate_i = lax.top_k(scores, k)                  # [S, k]
+        bias = p["router"].get("e_score_correction_bias")
+        kept_groups = None
+        if bias is None and not args.n_group:
+            gate_v, gate_i = lax.top_k(scores, k)              # [S, k]
+        else:
+            select = scores if bias is None else scores + bias
+            if args.n_group:
+                select, kept_groups = _group_limited(select, args)
+            _, gate_i = lax.top_k(select, k)
+            # the gates are the unbiased scores of the chosen
+            gate_v = jnp.take_along_axis(scores, gate_i, axis=-1)
         if args.normalize_gates:
             gate_v = gate_v / jnp.sum(gate_v, axis=-1, keepdims=True)
         gate_v = gate_v * args.routed_scale
@@ -401,7 +429,20 @@ def _moe_dropless(p, x, args: MoEArgs, *, return_stats: bool,
     if not return_stats:
         return y_out, aux
     pr = scores / jnp.sum(scores, axis=-1, keepdims=True)
+    grouped_stats = {}
+    if kept_groups is not None:
+        # live tokens none of whose kept groups has an expert held
+        # here: they get the shared expert alone from this layer
+        size = E // args.n_group
+        group_held = ((jnp.arange(args.n_group) + 1) * size > first) & (
+            jnp.arange(args.n_group) * size < first + held)
+        missed = ~jnp.any(kept_groups & group_held, axis=-1)
+        if token_mask is not None:
+            missed = missed & token_mask.reshape(S)
+        grouped_stats = {"no_held_group": jnp.sum(missed).astype(
+            jnp.float32)}
     return y_out, aux, {
+        **grouped_stats,
         "expert_tokens": counts.astype(jnp.float32),
         "dropped": jnp.zeros((), jnp.float32),
         "assigned": jnp.sum(counts).astype(jnp.float32),
@@ -410,6 +451,27 @@ def _moe_dropless(p, x, args: MoEArgs, *, return_stats: bool,
         "touched": jnp.sum(sizes > 0).astype(jnp.float32),
         "elsewhere": (jnp.sum(counts) - jnp.sum(sizes)).astype(jnp.float32),
     }
+
+
+def _group_limited(select, args: MoEArgs):
+    """DeepSeek-V3's group limit on the selection scores ``select`` [S,
+    E]: ``n_group`` runs of consecutive experts, a group's score the sum
+    of its two largest, the ``topk_group`` best groups kept. Returns
+    (``select`` with every other group's experts at -inf, the kept
+    groups [S, n_group] bool)."""
+    S, E = select.shape
+    G, keep = args.n_group, args.topk_group
+    if E % G or not 1 <= keep <= G or args.top_k > keep * (E // G):
+        raise ValueError(
+            f"n_group={G} must divide n_experts={E}, topk_group={keep} "
+            f"lie in [1, n_group] and the kept groups hold top_k="
+            f"{args.top_k} experts")
+    by_group = select.reshape(S, G, E // G)
+    group_score = jnp.sum(lax.top_k(by_group, min(2, E // G))[0], axis=-1)
+    _, best = lax.top_k(group_score, keep)                     # [S, keep]
+    kept = jnp.any(best[:, :, None] == jnp.arange(G)[None, None, :], axis=1)
+    return jnp.where(kept[:, :, None], by_group,
+                     -jnp.inf).reshape(S, E), kept
 
 
 def _routing_stats(oh, keep, probs, assigned: int):

@@ -31,7 +31,9 @@ from typing import Dict, Iterable
 # mixture: moe (router, sort, experts, shared, combine); a model of
 # sliding-window and global layers opens its layer's kind (full,
 # sliding) around attn (qkv, rope, kv_write, kv_gather or
-# window_gather, sdpa, gate, proj). The
+# window_gather, sdpa, gate, proj); a linear-attention block: kda
+# (qkv, conv, gate, delta_chunk or delta_step, state_update, norm_gate,
+# proj). The
 # train step:
 # grads, grad_reduce, grad_clip, optimizer. ``rematted_computation`` is
 # jax.checkpoint's own mark on what the backward pass recomputes.
@@ -44,6 +46,7 @@ SCOPES = frozenset({
     "kv_up",
     "moe", "router", "sort", "experts", "shared", "combine",
     "full", "sliding", "rope", "window_gather", "gate",
+    "kda", "delta_chunk", "delta_step", "norm_gate",
     "grads", "grad_reduce", "grad_clip", "optimizer",
     "rematted_computation",
 })

@@ -63,8 +63,9 @@ from quintnet_tpu.serve.api import generate, generate_stream
 from quintnet_tpu.serve.engine import (ServeEngine, check_admissible)
 from quintnet_tpu.serve.families import (gpt2_family,
                                           granite_hybrid_family,
-                                          laguna_family, llama_family,
-                                          pangu_moe_family)
+                                          laguna_family,
+                                          ling_hybrid_family,
+                                          llama_family, pangu_moe_family)
 from quintnet_tpu.serve.kv_pool import AdmitPlan, KVPool, WindowShapes
 from quintnet_tpu.serve.kv_quant import (KVLayoutPolicy, LayoutPolicy,
                                          make_policy)
@@ -101,6 +102,7 @@ __all__ = [
     "gpt2_family",
     "granite_hybrid_family",
     "laguna_family",
+    "ling_hybrid_family",
     "llama_family",
     "pangu_moe_family",
     "make_policy",

@@ -244,16 +244,18 @@ def _refuse_for_state(family: Family, **asked) -> None:
             "rolls the recurrent state back"),
         "adapters": (
             "adapters",
-            "LoRA deltas are not plumbed through the Mamba-2 "
-            "projections"),
+            "LoRA deltas are not plumbed through the recurrent "
+            "layers' projections"),
         "mesh": (
             "a tp or sp mesh",
-            "the recurrent state and the Mamba-2 projections are not "
-            "head-sharded, and the chunked scan has no ring form"),
+            "the recurrent state and the recurrent layers' projections "
+            "are not head-sharded, and the chunked scan has no ring "
+            "form"),
         "pallas": (
             "attn_kernel='pallas'",
-            "the fused kernel divides the scores by sqrt(head_dim); "
-            "this family states its own score scale"),
+            "the fused kernel walks K and V pools of heads scaled by "
+            "sqrt(head_dim); this family's attention layers state "
+            "their own score scale or cache one latent row"),
         "kv_chain": (
             "export_kv_chain / import_kv_chain",
             "the handoff payload carries KV blocks and no recurrent "
@@ -1985,6 +1987,11 @@ class ServeEngine:
             expert_rows=float(np.sum([a["held_rows"] for a in acc])),
             experts_touched=float(np.sum([a["touched"] for a in acc])),
             routed_elsewhere=float(np.sum([a["elsewhere"] for a in acc])),
+            # group-limited routers: the tokens x layers none of whose
+            # kept groups has an expert held here
+            **({"tokens_without_held_group": float(
+                np.sum([a["no_held_group"] for a in acc]))}
+               if "no_held_group" in acc[0] else {}),
             decode_expert_rows=float(np.sum([a["held_rows"] for a in dec])),
             decode_experts_touched=float(
                 np.sum([a["touched"] for a in dec])),

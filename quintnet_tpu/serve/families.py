@@ -101,6 +101,12 @@ k_pool, moe_stats)``. ``prefill_from`` runs the MATERIALIZED form of
 the attention, ``decode`` and ``verify`` the ABSORBED one
 (nn/attention.py, "The LATENT paged cache").
 
+Latent AND recurrent (``Family.latent`` and ``Family.state`` both set:
+linear-attention layers with one latent layer closing every group,
+:func:`ling_hybrid_family`): the two above at once. ``v_pool`` is None,
+``state=(ssm, conv)`` rides beside the one latent pool, and the
+contracts return ``(logits, k_pool, ssm, conv, moe_stats)``.
+
 Window families (``Family.window`` set: sliding-window layers among
 global ones, :func:`laguna_family`): ``n_layers`` counts the GLOBAL
 layers, which page every position into the pool; each sliding layer
@@ -284,8 +290,8 @@ def _reduce_moe_stats(st):
         "assigned": jnp.sum(st["assigned"]),
         "entropy": jnp.mean(st["entropy"]),
         # the dropless router's own counts (nn/moe.py), where it ran
-        **{k: jnp.sum(st[k]) for k in ("held_rows", "touched", "elsewhere")
-           if k in st},
+        **{k: jnp.sum(st[k]) for k in ("held_rows", "touched", "elsewhere",
+                                       "no_held_group") if k in st},
     }
 
 
@@ -842,4 +848,209 @@ def laguna_family(cfg, *, block_size: int = 16) -> Family:
         window=WindowShapes(n_layers=cfg.n_layers_of(SLIDING),
                             window=cfg.sliding_window,
                             ring=cfg.sliding_window + block_size),
+    )
+
+
+# --------------------------------------------------------------------
+# Ling 3.0 (Kimi-Delta-Attention layers with ONE latent layer closing
+# every group of six, leading dense layers, a group-limited mixture):
+# a per-slot state AND a latent row a position, in one sequence
+# --------------------------------------------------------------------
+
+def ling_hybrid_family(cfg) -> Family:
+    from quintnet_tpu.models.ling_hybrid import (
+        ABSORBED, MATERIALIZED, WEIGHT_TARGETS, ffn_dense, ffn_moe,
+        kda_mixer_chunk, kda_mixer_step, ling_embed,
+        ling_hybrid_partition_specs, ling_logits, mla_mixer)
+    from quintnet_tpu.nn.attention import rope_cos_sin
+
+    periods, per = cfg.periods, cfg.layer_group_size
+    before, dims = cfg.kda_per_period, cfg.kda
+
+    def only_plain(v_pool, tp_axis, ep_axis, lora, kv_scales, attn_kernel,
+                   state):
+        if (v_pool is not None or tp_axis is not None
+                or ep_axis is not None or lora is not None
+                or kv_scales is not None or attn_kernel != "xla"
+                or state is None):
+            raise NotImplementedError(
+                "the bailing_hybrid programs run on one device, on the "
+                "one unscaled latent pool (v_pool=None) with their state "
+                "buffers, without adapters, with attn_kernel='xla' "
+                "(ServeEngine refuses the rest at construction)")
+
+    def pick(stack, i):
+        return jax.tree.map(
+            lambda a: lax.dynamic_index_in_dim(a, i, keepdims=False), stack)
+
+    def run_layers(params, pool, state, h, row0, rows, lens, kda_fn,
+                   mla_fn):
+        """The walk over the layer pattern. The FIRST group is written
+        out — its leading KDA layers carry the dense SwiGLU, a short
+        scan, then the rest of its KDA layers, a second, then its latent
+        layer — and the groups after it are one scan over groups, each
+        a short scan of KDA layers and the latent layer, as the hybrid
+        family scans periods. Every per-sequence buffer — the latent
+        pool and the two state buffers — rides every loop's CARRY,
+        whole, beside the mixtures' routing counts; a KDA layer reads
+        rows ``[row0, row0 + rows)`` of its own slice and writes them
+        back in place, the latent layer writes and reads the pool at
+        ``(group, slot)``. The stacks' weights are indexed by layer
+        inside the loops; the routed experts stay whole (nn/moe.py)."""
+        blocks = params["blocks"]
+        experts = blocks["moe"]["moe"]["experts"]
+        moe_rest = {"ln2": blocks["moe"]["ln2"],
+                    "moe": {k: v for k, v in blocks["moe"]["moe"].items()
+                            if k != "experts"}}
+
+        def moe_half(x, stats, layer):
+            m = layer - cfg.n_dense_layers
+            x, st = ffn_moe(pick(moe_rest, m), x, lens, cfg, experts, m)
+            return x, jax.tree.map(jnp.add, stats, st)
+
+        def kda_run(carry, first_layer, first_kda, count, dense):
+            def body(c, j):
+                x, pool, ssm, conv, stats = c
+                ki = first_kda + j
+                s = lax.dynamic_slice(
+                    ssm, (ki, row0, 0, 0, 0), (1, rows, *ssm.shape[2:]))[0]
+                t = lax.dynamic_slice(
+                    conv, (ki, row0, 0), (1, rows, conv.shape[2]))[0]
+                x, s, t = kda_fn(
+                    pick(blocks["kda"], ki), x, s,
+                    t.reshape(rows, dims.d_conv - 1, dims.d_qkv))
+                with jax.named_scope("kda"), \
+                        jax.named_scope("state_update"):
+                    ssm = lax.dynamic_update_slice(
+                        ssm, s[None], (ki, row0, 0, 0, 0))
+                    conv = lax.dynamic_update_slice(
+                        conv, t.reshape(1, rows, -1), (ki, row0, 0))
+                if dense:
+                    x = ffn_dense(pick(blocks["dense"], first_layer + j),
+                                  x, cfg)
+                else:
+                    x, stats = moe_half(x, stats, first_layer + j)
+                return (x, pool, ssm, conv, stats), None
+
+            if count == 0:
+                return carry
+            return lax.scan(body, carry, jnp.arange(count))[0]
+
+        def group(carry, i, n_dense):
+            carry = kda_run(carry, i * per, i * before, n_dense, True)
+            x, pool, ssm, conv, stats = kda_run(
+                carry, i * per + n_dense, i * before + n_dense,
+                before - n_dense, False)
+            x, pool = mla_fn(pick(blocks["mla"], i), x, pool, i)
+            x, stats = moe_half(x, stats, i * per + before)
+            return x, pool, ssm, conv, stats
+
+        zero = jnp.zeros((), jnp.float32)
+        stats = {"expert_tokens": jnp.zeros(
+                     (cfg.num_experts_published,), jnp.float32),
+                 **{k: zero for k in (
+                     "dropped", "assigned", "entropy", "held_rows",
+                     "touched", "elsewhere", "no_held_group")}}
+        with jax.named_scope("blocks"):
+            carry = group((h, pool, *state, stats), 0, cfg.n_dense_layers)
+            if periods > 1:
+                carry, _ = lax.scan(
+                    lambda c, i: (group(c, i, 0), None), carry,
+                    jnp.arange(1, periods))
+        h, pool, ssm, conv, stats = carry
+        # counts add over the layers, the entropy is their mean
+        stats = {**stats, "entropy": stats["entropy"] / cfg.n_moe_layers}
+        return h, pool, ssm, conv, stats
+
+    def run_chunk(params, pool, state, ids, positions, lens, tables,
+                  block_size, row0, fresh, form):
+        """A run of tokens a row (prefill, chunked prefill, verify)."""
+        cos, sin = rope_cos_sin(positions, cfg.qk_rope_head_dim,
+                                theta=cfg.rope_theta)       # [S, P, rope]
+
+        def kda_fn(blk, x, s, t):
+            s = jnp.where(fresh, jnp.zeros_like(s), s)
+            t = jnp.where(fresh, jnp.zeros_like(t), t)
+            return kda_mixer_chunk(blk, x, s, t, lens, cfg)
+
+        def mla_fn(blk, x, pool, layer):
+            return mla_mixer(blk, x, pool, layer, positions, lens, tables,
+                             block_size, cfg, cos, sin, form=form)
+
+        return run_layers(params, pool, state, ling_embed(params, ids),
+                          row0, ids.shape[0], lens, kda_fn, mla_fn)
+
+    def prefill_from(params, k_pool, v_pool, ids, start, t0, table_row,
+                     block_size, tp_axis=None, ep_axis=None, lora=None,
+                     lora_scale=None, kv_scales=None, policy=None,
+                     attn_kernel="xla", state=None, slot=None):
+        only_plain(v_pool, tp_axis, ep_axis, lora, kv_scales, attn_kernel,
+                   state)
+        positions = (start + jnp.arange(ids.shape[1], dtype=jnp.int32))[None]
+        h, *bufs = run_chunk(
+            params, k_pool, state, ids, positions,
+            jnp.reshape(t0 - start, (1,)), table_row[None], block_size,
+            slot, start == 0, MATERIALIZED)
+        h_last = lax.dynamic_slice_in_dim(h, t0 - 1 - start, 1, axis=1)
+        return (ling_logits(params, h_last, cfg)[:, 0, :], *bufs)
+
+    def verify(params, k_pool, v_pool, ids, starts, tail_lens, tables,
+               block_size, tp_axis=None, ep_axis=None, lora=None,
+               lora_scale=None, kv_scales=None, policy=None,
+               attn_kernel="xla", state=None):
+        # the chunk program for P tokens a row, every row from its
+        # CURRENT state, the latent layer in its absorbed form. Not a
+        # speculative verify: nothing rolls a state back, and the
+        # engine refuses ``spec`` for this family
+        only_plain(v_pool, tp_axis, ep_axis, lora, kv_scales, attn_kernel,
+                   state)
+        positions = (starts[:, None]
+                     + jnp.arange(ids.shape[1], dtype=jnp.int32)[None, :])
+        h, *bufs = run_chunk(params, k_pool, state, ids, positions,
+                             tail_lens, tables, block_size, 0, False,
+                             ABSORBED)
+        return (ling_logits(params, h, cfg), *bufs)
+
+    def decode(params, k_pool, v_pool, tok, pos, tables, block_size,
+               tp_axis=None, ep_axis=None, lora=None, lora_scale=None,
+               kv_scales=None, policy=None, attn_kernel="xla",
+               state=None):
+        only_plain(v_pool, tp_axis, ep_axis, lora, kv_scales, attn_kernel,
+                   state)
+        # a row whose table is all null blocks is not decoding (an
+        # empty slot, or one in the middle of a chunked prefill): its
+        # state stays what it was, its latent row goes to the null
+        # block and the router sends its token nowhere
+        live = tables[:, 0] != NULL_BLOCK
+        lens = live.astype(jnp.int32)
+        cos, sin = rope_cos_sin(pos[:, None], cfg.qk_rope_head_dim,
+                                theta=cfg.rope_theta)
+
+        def kda_fn(blk, x, s, t):
+            x, s2, t2 = kda_mixer_step(blk, x, s, t, cfg)
+            return (x, jnp.where(live[:, None, None, None], s2, s),
+                    jnp.where(live[:, None, None], t2, t))
+
+        def mla_fn(blk, x, pool, layer):
+            return mla_mixer(blk, x, pool, layer, pos[:, None], lens,
+                             tables, block_size, cfg, cos, sin,
+                             form=ABSORBED)
+
+        h, *bufs = run_layers(
+            params, k_pool, state, ling_embed(params, tok[:, None]), 0,
+            tok.shape[0], lens, kda_fn, mla_fn)
+        return (ling_logits(params, h, cfg)[:, 0, :], *bufs)
+
+    return Family(
+        name="bailing_hybrid", cfg=cfg, n_layers=periods, n_kv_heads=1,
+        head_dim=cfg.latent_width,
+        max_positions=cfg.max_position_embeddings,
+        prefill_from=prefill_from, decode=decode, verify=verify,
+        partition_specs=ling_hybrid_partition_specs,
+        weight_targets=WEIGHT_TARGETS, layer_pattern=cfg.layer_types,
+        state=StateShapes(
+            n_layers=cfg.n_kda_layers,
+            ssm=(dims.n_heads, dims.d_k, dims.d_v),
+            conv=((dims.d_conv - 1) * dims.d_qkv,)),
+        latent=cfg.latent_width,
     )

@@ -90,6 +90,14 @@ features, so host records are ``[L, n, 1, latent]`` and carry no ``v``.
 Blocks, refcounts, the prefix index, chain export/import and the host
 tier deal in blocks and records and work unchanged.
 
+Both at once (linear-attention layers with a latent layer among them,
+serve/families.ling_hybrid_family): ``KVPool(latent=576,
+state=StateShapes(...))`` keeps the one latent buffer for the layers
+that cache a row a position and the two per-slot buffers for the layers
+that keep a state — ``caches()`` is ``(k, ssm, conv)`` — and what a
+recurrent family cannot share or move (the prefix index, the host tier,
+chain export/import) stays refused by the engine.
+
 Prefix caching (the PagedAttention sharing model + SGLang-style prefix
 reuse, block-granular):
 
@@ -229,10 +237,10 @@ class KVPool:
                 raise ValueError(
                     f"a latent pool holds ONE row of {latent} features a "
                     f"token: pass n_kv_heads=1, head_dim={latent}")
-            if sharding is not None or state is not None:
+            if sharding is not None:
                 raise NotImplementedError(
-                    "a latent pool is neither head-sharded (all heads "
-                    "read the one row) nor kept beside recurrent state")
+                    "a latent pool is not head-sharded: all heads read "
+                    "the one row")
         self.latent = latent
         if block_size < 1 or num_blocks < 2:
             raise ValueError(
@@ -1163,10 +1171,13 @@ class KVPool:
         via :meth:`update`): ``(k, v)`` for passthrough policies,
         ``(k, v, k_scale, v_scale)`` for scaled ones, ``(k, v, ssm,
         conv)`` for a recurrent family, ``(k, v, wk, wv)`` for a window
-        one, ``(k,)`` for a latent one —
+        one, ``(k,)`` for a latent one and ``(k, ssm, conv)`` for one
+        that is latent AND recurrent —
         call sites splat the tuple, so neither the policy nor the
         family changes their shape."""
         if self.latent is not None:
+            if self.state is not None:
+                return self.k, self.ssm, self.conv
             return (self.k,)
         if self.policy.scaled:
             return self.k, self.v, self.k_scale, self.v_scale
@@ -1180,7 +1191,11 @@ class KVPool:
         """Adopt what a program returned for :meth:`caches`' buffers,
         in that order."""
         if 1 + len(rest) != len(self.caches()):
-            carries = ("the one latent buffer" if self.latent is not None
+            carries = ("the one latent buffer and the recurrent state "
+                       "buffers" if self.latent is not None
+                       and self.state is not None
+                       else "the one latent buffer"
+                       if self.latent is not None
                        else "scale arrays" if self.policy.scaled
                        else "recurrent state buffers"
                        if self.state is not None
@@ -1192,6 +1207,8 @@ class KVPool:
                 f"got {1 + len(rest)}")
         self.k = k
         if self.latent is not None:
+            if self.state is not None:
+                self.ssm, self.conv = rest
             return
         self.v, *rest = rest
         if self.policy.scaled:

@@ -450,6 +450,85 @@ def test_latent_decode_walks_the_one_pool_in_place_on_v5e(chip):
         assert dims[:2] != (slots, width * bs), c
 
 
+def test_kda_latent_decode_holds_one_state_and_walks_the_pool_on_v5e(chip):
+    """``ling_hybrid_family(...).decode`` at Ling-3.0-flash's published
+    widths (shapes only: ``jax.eval_shape``), the benchmark's six
+    layers, 64 slots of 12,288 positions, the latent pool and both
+    state buffers donated, compiled for the described chip. The state
+    ``[5, 65, 32, 128, 128]`` f32 (1.3 GiB) must alias in and out and be
+    planned ONCE (sliced in and stacked out of a scan it would be there
+    twice); the latent pool ``[1, slots, 640]`` must enter row-major,
+    alias out and feed ONE call of the per-row walk through the
+    in-place ``kv_write`` scatter, with no gather at the table's width;
+    and the experts must run as the chip's own grouped matmul."""
+    import numpy as np
+
+    from quintnet_tpu.models.ling_hybrid import (LingHybridConfig,
+                                                 ling_hybrid_init)
+    from quintnet_tpu.serve import ling_hybrid_family
+    from quintnet_tpu.serve.kv_pool import feature_width
+    from quintnet_tpu.serve.weight_quant import (make_weight_policy,
+                                                 present_targets,
+                                                 quantize_params)
+
+    with open(os.path.join(REPO, "benchmarks", "configs",
+                           "ling-3.0-flash.json")) as f:
+        cfg = LingHybridConfig.from_dict(json.load(f))
+    fam = ling_hybrid_family(cfg)
+    slots, bs, width = 64, 16, 768
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    params = jax.tree.map(
+        lambda x: sds(x.shape, x.dtype),
+        jax.eval_shape(lambda k: (lambda p: quantize_params(
+            p, present_targets(p, fam.weight_targets),
+            make_weight_policy("bf16")))(ling_hybrid_init(k, cfg)),
+            jax.random.key(0)))
+    assert feature_width(1, fam.latent) == 640
+    pool = sds((fam.n_layers, slots * width * bs, 640), jnp.bfloat16)
+    ssm = sds((fam.state.n_layers, slots + 1, *fam.state.ssm), jnp.float32)
+    conv = sds((fam.state.n_layers, slots + 1, *fam.state.conv),
+               jnp.bfloat16)
+    rows = sds((slots,), jnp.int32)
+
+    def decode(params, k, ssm, conv, tok, pos, tables):
+        return fam.decode(params, k, None, tok, pos, tables, bs,
+                          state=(ssm, conv))
+
+    compiled = jax.jit(decode, donate_argnums=(1, 2, 3)).lower(
+        params, pool, ssm, conv, rows, rows,
+        sds((slots, width), jnp.int32)).compile()
+    plan = compiled.memory_analysis()
+    pool_bytes = int(np.prod(pool.shape)) * 2
+    state_bytes = int(np.prod(ssm.shape)) * 4 + int(np.prod(conv.shape)) * 2
+    assert plan.alias_size_in_bytes >= pool_bytes + state_bytes
+    assert plan.temp_size_in_bytes < state_bytes / 3, (
+        plan.temp_size_in_bytes, state_bytes)
+    hlo = compiled.as_text()
+    assert "ragged-dot" in hlo and "tpu_custom_call" in hlo
+    spec = importlib.util.spec_from_file_location(
+        "pool_layout_audit", os.path.join(REPO, "tools",
+                                          "pool_layout_audit.py"))
+    audit = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(audit)
+    got = audit.read_hlo(hlo, {"k": pool.shape}, slots * width * bs * 640 * 2)
+    assert got["entry_layouts"] == {"k": ["{2,1,0}"]}, got
+    assert len(got["row_walks"]) == 1, got["row_walks"]
+    for call in got["row_walks"]:
+        assert len(call["pool_operands"]) == 1, call
+        # a ONE-layer pool reaches the kernel through a bitcast of the
+        # scatter's result (a re-reading of the same bytes): no copy
+        assert all(c["op"] == "bitcast" for c in call["copies_beside"]), call
+    assert f"[{slots},{width},{bs}," not in hlo.replace(" ", "")
+    assert f"[{slots},{width * bs}," not in hlo.replace(" ", "")
+    for c in got["big_copies"]:
+        assert tuple(c["dims"]) not in {
+            tuple(pool.shape), tuple(pool.shape[1:]), (1, *pool.shape[1:]),
+            tuple(ssm.shape), tuple(ssm.shape[1:])}, c
+
+
 def test_window_decode_reads_rings_and_contracts_as_stored_on_v5e(chip):
     """``laguna_family(...).decode`` at Laguna-XS.2's published widths
     (shapes only: ``jax.eval_shape``), the benchmark's five layers — a
@@ -724,3 +803,18 @@ def test_chip_smoke_cpu_rehearsal(tmp_path, capsys, monkeypatch):
     for rec in (train, serve_rec):
         assert rec["programs"] > 0 and 0 < rec["compile_s"] < rec["wall_s"]
     assert not any('"device"' in x for x in lines)  # no success line
+
+
+def test_chip_smoke_kda_moe_phase_cpu_rehearsal():
+    """The fourth phase at its own (tiny) size on the CPU: the linear-
+    attention + latent family through ``ServeEngine`` with chunked
+    prefill, every greedy token within the tolerance of the plain
+    reference's best logit (on the CPU's exact f32: at it)."""
+    import chip_smoke as cs
+
+    rec = cs.phase_kda_moe(0)
+    assert rec["requests"] == 5 and rec["new_tokens"] == 40
+    assert rec["greedy_gap"] < 1e-4 < rec["ref_std"]
+    assert rec["prefill_chunks"] >= 2 + 2 + 2       # prompts of 40, 60, 33
+    assert rec["preempted"] == 0 and rec["state_bytes_per_slot"] > 0
+    assert rec["programs_of_the_engine"][0] == "serve_decode"
