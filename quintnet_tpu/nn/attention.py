@@ -1031,14 +1031,16 @@ def _walk_tiles(rows: int, pool, block_tables, block_size: int):
     return key_block, -(-rows // tile) * tile
 
 
-def _walk_where_it_lowers(here: bool, on_tpu: bool, walk, gathered, *args):
-    """``walk(*args)`` where the kernel lowers, ``gathered(*args)``
+def kernel_where_it_lowers(here: bool, on_tpu: bool, kernel, plain, *args):
+    """``kernel(*args)`` where a TPU kernel lowers, ``plain(*args)``
     where it does not: decided now if this process's backend (``here``)
     and a TPU (``on_tpu``) agree, else at lowering — both traced under
-    ``lax.platform_dependent``, as :func:`local_attention` does."""
+    ``lax.platform_dependent``, as :func:`local_attention` does. The
+    walk's choice against the gathered view, and the grouped matmul's
+    against ``ragged_dot`` (nn/moe.py)."""
     if here == on_tpu:
-        return (walk if here else gathered)(*args)
-    return lax.platform_dependent(*args, tpu=walk, default=gathered)
+        return (kernel if here else plain)(*args)
+    return lax.platform_dependent(*args, tpu=kernel, default=plain)
 
 
 def _paged_attend_walk(q, k, v, pools, layer, positions, lens,
@@ -1096,7 +1098,7 @@ def _paged_attend_walk(q, k, v, pools, layer, positions, lens,
             q, k_rows, v_rows, _seen(positions, q, block_tables, block_size),
             kv_heads=hkv, scale=scale)
 
-    return _walk_where_it_lowers(
+    return kernel_where_it_lowers(
         here, walk_lowers_for("tpu", block_size), walk, gathered,
         q, *pools, layer, positions, block_tables), pools
 
@@ -1391,7 +1393,7 @@ def latent_attend_absorbed(q_lat, q_rope, pool, layer, positions,
             q, pool, layer, positions, block_tables, block_size=block_size,
             scale=scale, heads=h, rank=rank).astype(out_dtype)
 
-    return _walk_where_it_lowers(
+    return kernel_where_it_lowers(
         here, fits and walk_lowers_for("tpu", block_size), walk, gathered,
         q, pool, layer, positions, block_tables)
 

@@ -322,10 +322,12 @@ def _moe_dropless(p, x, args: MoEArgs, *, return_stats: bool,
                   token_mask=None, expert_layer=None):
     """The dropless router of :func:`moe_apply` (module docstring): x
     [B, T, D] -> (y, 0[, stats]). ``S * k`` assignments, sorted so that
-    the held experts' rows come first in expert order; ``ragged_dot``
+    the held experts' rows come first in expert order; a grouped matmul
     runs each expert over exactly its rows (the rows routed elsewhere
-    trail behind every group and are skipped), and the gate-weighted
-    results are added back to their tokens. The stats keep the capacity
+    trail behind every group and are skipped: ``lax.ragged_dot``, or on
+    the TPU a kernel that reads only the touched experts,
+    :func:`_expert_rows`), and the gate-weighted results are added back
+    to their tokens. The stats keep the capacity
     path's names — ``expert_tokens`` over ALL the router's experts,
     ``dropped`` 0 by construction — and add ``held_rows`` (routings
     that landed on held experts), ``touched`` (held experts with at
@@ -334,13 +336,13 @@ def _moe_dropless(p, x, args: MoEArgs, *, return_stats: bool,
     ``combine``.
 
     With ``expert_layer`` the expert weights are a whole stack's,
-    ``[layers, held, in, out]``: the grouped matmul takes the stack as
-    ``layers * held`` groups of which only this layer's have rows. The
+    ``[layers, held, in, out]``, and the grouped matmul reads this
+    layer's experts out of it in place (:func:`_expert_rows`). The
     grouped matmul is one call on a whole operand, so a layer's slice
     of the stack (what a layer scan hands its body) would be COPIED out
     first — at the published widths 1.5 GB a layer a step, 21 of a
-    106-ms decode step (my chip run, PR 31); empty groups cost
-    nothing."""
+    106-ms decode step (my chip run, PR 31). The stats also carry
+    ``tile_visits`` (:func:`_expert_rows`)."""
     B, T, D = x.shape
     S, E, k = B * T, args.n_experts, args.top_k
     first, held = (0, E) if args.experts_held is None else args.experts_held
@@ -401,20 +403,8 @@ def _moe_dropless(p, x, args: MoEArgs, *, return_stats: bool,
         # matrix unit would round it anyway; the sums stay f32
         xs = xt.astype(w_gate.dtype)[tok] if (
             w_gate.dtype.itemsize < xt.dtype.itemsize) else xt[tok]
-
-        if expert_layer is None:
-            groups = sizes
-        else:
-            groups = lax.dynamic_update_slice(
-                jnp.zeros((w_gate.shape[0] * held,), sizes.dtype), sizes,
-                (expert_layer * held,))
-
-        def grouped(a, w):
-            return lax.ragged_dot(a, w.reshape(-1, *w.shape[-2:]), groups,
-                                  preferred_element_type=jnp.float32)
-
-        hid = jax.nn.silu(grouped(xs, w_gate)) * grouped(xs, w_up)
-        ys = grouped(hid.astype(xs.dtype), w_down)             # [S*k, D]
+        ys, tile_visits = _expert_rows(
+            xs, (w_gate, w_up, w_down), sizes, expert_layer)   # [S*k, D]
 
     with jax.named_scope("combine"):
         # rows past every group are whatever the grouped matmul left
@@ -450,7 +440,70 @@ def _moe_dropless(p, x, args: MoEArgs, *, return_stats: bool,
         "held_rows": jnp.sum(sizes).astype(jnp.float32),
         "touched": jnp.sum(sizes > 0).astype(jnp.float32),
         "elsewhere": (jnp.sum(counts) - jnp.sum(sizes)).astype(jnp.float32),
+        "tile_visits": tile_visits,
     }
+
+
+def _expert_rows(xs, weights, sizes, expert_layer):
+    """The held experts' SwiGLU over the sorted routings ``xs`` [S*k,
+    D] -> (``ys`` [S*k, D] f32, ``tile_visits``): three grouped matmuls
+    (gate, up, down) over the groups ``sizes`` [held] with ``silu *
+    up`` between, operands as given, f32 sums. Rows past every group
+    come back as whatever the grouped matmul left there.
+
+    WHICH grouped matmul is decided from shapes, where the program is
+    lowered: for a TPU, with ``D`` and the experts' width whole
+    128-lane tiles and float weights in the rows' dtype, the kernel
+    that visits only (row tile, touched expert) pairs and reads each
+    touched expert's weights once, the stack indexed in place
+    (ops/grouped_matmul.py); for anything else (another platform, a
+    tiny preset, a packed weight) ``lax.ragged_dot`` — over a stack,
+    as ``layers * held`` groups of which only this layer's have rows
+    (:func:`_moe_dropless`). Where this process's backend and a TPU
+    would choose apart both are traced under ``lax.platform_dependent``
+    (nn/attention.kernel_where_it_lowers), so a CPU process compiling
+    for a described chip sizes what the chip runs. ``tile_visits``: the
+    (row tile, expert) visits the kernel's metadata lists — over
+    ``touched``, how often a row tile boundary made an expert's
+    weights meet the matrix unit again; 0 where no kernel is traced."""
+    from quintnet_tpu.nn.attention import kernel_where_it_lowers
+    from quintnet_tpu.ops import grouped_matmul as gm
+
+    held, rows = sizes.shape[0], xs.shape[0]
+
+    def lowers_for(backend):
+        return all(gm.grouped_matmul_lowers_for(
+            backend, k=w.shape[-2], n=w.shape[-1], x_dtype=xs.dtype,
+            w_dtype=w.dtype) for w in weights)
+
+    def swiglu(grouped, xs, w_gate, w_up, w_down):
+        hid = jax.nn.silu(grouped(xs, w_gate)) * grouped(xs, w_up)
+        return grouped(hid.astype(xs.dtype), w_down)
+
+    def by_ragged_dot(xs, weights, sizes, visits, layer):
+        groups = sizes if expert_layer is None else lax.dynamic_update_slice(
+            jnp.zeros((weights[0].shape[0] * held,), sizes.dtype), sizes,
+            (layer * held,))
+        return swiglu(lambda a, w: lax.ragged_dot(
+            a, w.reshape(-1, *w.shape[-2:]), groups,
+            preferred_element_type=jnp.float32), xs, *weights)
+
+    def by_kernel(xs, weights, sizes, visits, layer):
+        return swiglu(lambda a, w: gm.grouped_matmul(
+            a, w, visits, layer=None if expert_layer is None else layer),
+            xs, *weights)
+
+    here, on_tpu = lowers_for(jax.default_backend()), lowers_for("tpu")
+    layer = jnp.asarray(0 if expert_layer is None else expert_layer,
+                        jnp.int32)
+    if not (here or on_tpu):
+        return (by_ragged_dot(xs, weights, sizes, None, layer),
+                jnp.zeros((), jnp.float32))
+    visits = gm.group_visits(sizes, rows=rows,
+                             row_tile=gm.row_tile_for(rows, xs.dtype))
+    return (kernel_where_it_lowers(here, on_tpu, by_kernel, by_ragged_dot,
+                                   xs, weights, sizes, visits, layer),
+            visits.count[0].astype(jnp.float32))
 
 
 def _group_limited(select, args: MoEArgs):

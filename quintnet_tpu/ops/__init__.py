@@ -15,6 +15,11 @@ pins it):
   pool write;
 - :func:`ring_attention` / :func:`zigzag_ring_attention` /
   :func:`ulysses_attention` — the sequence-parallel inner attentions.
+
+Beside it, imported as modules by the one layer that runs them:
+``ops.paged_attention.paged_walk_attention`` (the per-row walk of decode
+and verify, nn/attention.py) and ``ops.grouped_matmul`` (the dropless
+mixture's grouped matmul over touched experts only, nn/moe.py).
 """
 
 from quintnet_tpu.ops.flash_attention import blockwise_attention

@@ -1987,6 +1987,12 @@ class ServeEngine:
             expert_rows=float(np.sum([a["held_rows"] for a in acc])),
             experts_touched=float(np.sum([a["touched"] for a in acc])),
             routed_elsewhere=float(np.sum([a["elsewhere"] for a in acc])),
+            # the (row tile, expert) visits of the grouped-matmul
+            # kernel (nn/moe._expert_rows): over experts_touched, how
+            # often an expert's weights met the matrix unit again; 0
+            # where the programs keep ragged_dot
+            expert_tile_visits=float(
+                np.sum([a["tile_visits"] for a in acc])),
             # group-limited routers: the tokens x layers none of whose
             # kept groups has an expert held here
             **({"tokens_without_held_group": float(
@@ -1995,6 +2001,8 @@ class ServeEngine:
             decode_expert_rows=float(np.sum([a["held_rows"] for a in dec])),
             decode_experts_touched=float(
                 np.sum([a["touched"] for a in dec])),
+            decode_expert_tile_visits=float(
+                np.sum([a["tile_visits"] for a in dec])),
             decode_held_expert_tokens=np.sum(
                 [a["expert_tokens"][first:first + held] for a in dec],
                 axis=0) if dec else np.zeros((held,)))

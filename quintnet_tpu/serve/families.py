@@ -291,7 +291,8 @@ def _reduce_moe_stats(st):
         "entropy": jnp.mean(st["entropy"]),
         # the dropless router's own counts (nn/moe.py), where it ran
         **{k: jnp.sum(st[k]) for k in ("held_rows", "touched", "elsewhere",
-                                       "no_held_group") if k in st},
+                                       "tile_visits", "no_held_group")
+           if k in st},
     }
 
 
@@ -950,7 +951,8 @@ def ling_hybrid_family(cfg) -> Family:
                      (cfg.num_experts_published,), jnp.float32),
                  **{k: zero for k in (
                      "dropped", "assigned", "entropy", "held_rows",
-                     "touched", "elsewhere", "no_held_group")}}
+                     "touched", "elsewhere", "tile_visits",
+                     "no_held_group")}}
         with jax.named_scope("blocks"):
             carry = group((h, pool, *state, stats), 0, cfg.n_dense_layers)
             if periods > 1:

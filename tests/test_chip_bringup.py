@@ -15,8 +15,9 @@ checked without a chip (PR 21, the bring-up round).
    slice — nor (PR 30) a decode program of a gathered view; and (PR
    31) the latent family's decode program at openPangu-Ultra-MoE's
    widths: one row-major latent pool, (PR 36) walked in place by one
-   kernel call a layer loop with no gathered view, the experts as the
-   chip's grouped matmul. The parent
+   kernel call a layer loop with no gathered view, the experts as
+   (PR 38) the grouped-matmul kernel that reads touched experts only.
+   The parent
    commit's paged kernel was
    REFUSED at every one of these shapes (16 MiB default scoped-VMEM
    budget; a (1, Hkv) scale block; a lane-splitting reshape) —
@@ -90,6 +91,44 @@ def _kernels_compile_for_the_chip(request, monkeypatch):
         monkeypatch.setattr(
             importlib.import_module("quintnet_tpu.ops.paged_attention"),
             "INTERPRET", False)
+
+
+def _assert_experts_run_the_kernel(hlo: str, *, bodies: int):
+    """The dropless mixture's grouped matmuls in a program compiled for
+    the chip (PR 38): ``bodies`` sparse-layer bodies (a scan's body
+    once), each with the three calls of ops/grouped_matmul's kernel —
+    gate, up, down — and no ``ragged-dot`` custom call left, whose
+    padded ``layers * held`` groups read at 34-39% of the touched
+    experts' bytes' roofline. The kernel's calls carry JAX's own name,
+    so the scope map reads them ``blocks/moe/experts`` (obs/scopes.py;
+    ``ragged-dot-none``'s own-name rule stays for programs that keep
+    it)."""
+    import re
+
+    from quintnet_tpu.obs.scopes import scope_map
+
+    assert "ragged-dot" not in hlo
+    calls = re.findall(r"%(grouped_matmul[.\w]*) = ", hlo)
+    assert len(calls) == 3 * bodies, calls
+    scopes = scope_map(hlo)
+    assert {scopes.get(c) for c in calls} == {"blocks/moe/experts"}, [
+        (c, scopes.get(c)) for c in calls]
+
+
+def _bf16_param_shapes(fam, init, cfg, chip):
+    """The SHAPES of a family's parameters as a bf16 engine serves them
+    (``jax.eval_shape`` of its initialiser through the weight policy:
+    gigabytes of weights are never built), placed on the described
+    chip."""
+    from quintnet_tpu.serve.weight_quant import (make_weight_policy,
+                                                 present_targets,
+                                                 quantize_params)
+
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip),
+        jax.eval_shape(lambda k: (lambda p: quantize_params(
+            p, present_targets(p, fam.weight_targets),
+            make_weight_policy("bf16")))(init(k, cfg)), jax.random.key(0)))
 
 
 def _compile(fn, *shapes):
@@ -299,6 +338,133 @@ def test_paged_walk_compiles_for_v5e(chip, rows, queries, lanes, layers,
 
 
 # ---------------------------------------------------------------------
+# PR 38: the grouped-matmul kernel at the three MoE cells' own shapes,
+# and a prefill bucket of each family with it inside
+# ---------------------------------------------------------------------
+# cell -> (sparse layers, experts held, d, f, decode rows = slots x 8)
+GMM_CELLS = {"ling": (4, 128, 2560, 768, 1536),
+             "pangu": (4, 16, 7680, 2048, 512),
+             "laguna": (4, 256, 2048, 512, 384)}
+
+
+@pytest.mark.parametrize("side", ["up", "down"])
+@pytest.mark.parametrize("program", ["decode", "b1024"])
+@pytest.mark.parametrize("cell", sorted(GMM_CELLS))
+def test_grouped_matmul_compiles_for_v5e(chip, cell, program, side):
+    """ops/grouped_matmul at a sparse layer's call as the cell's decode
+    program and a 1,024-token chunk make it — a stack of ``layers x
+    held`` bf16 experts read at a traced layer, ``[d, f]`` (gate, up)
+    and ``[f, d]`` (down), the tiles the module chooses from the shapes
+    (openPangu's 31.5-MB expert in two column blocks) — compiled for
+    the described chip with the metadata's arithmetic beside it, and
+    planning no temporary at a weight's width."""
+    from quintnet_tpu.ops import grouped_matmul as gm
+
+    L, G, d, f, decode_rows = GMM_CELLS[cell]
+    rows = decode_rows if program == "decode" else 8192
+    K, N = (d, f) if side == "up" else (f, d)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    def call(x, w, sizes, layer):
+        visits = gm.group_visits(
+            sizes, rows=rows, row_tile=gm.row_tile_for(rows, x.dtype))
+        return gm.grouped_matmul(x, w, visits, layer=layer)
+
+    compiled = jax.jit(call).lower(
+        sds((rows, K), jnp.bfloat16), sds((L, G, K, N), jnp.bfloat16),
+        sds((G,), jnp.int32), sds((), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < K * N * 2 / 4
+
+
+def _moe_prefill(name, chip):
+    """(``prefill_from`` as a function of shapes only, its shapes,
+    sparse-layer bodies) of one MoE family at its cell's published
+    widths, for a 1,024-token chunk of a 2,048-position row."""
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    def config(file):
+        with open(os.path.join(REPO, "benchmarks", "configs", file)) as f:
+            return json.load(f)
+
+    slots, bs, width = 4, 16, 128
+    if name == "pangu":
+        from quintnet_tpu.models.pangu_moe import (PanguMoEConfig,
+                                                   pangu_moe_init as init)
+        from quintnet_tpu.serve import pangu_moe_family as family
+
+        cfg = PanguMoEConfig.from_dict({
+            **config("openpangu-ultra-moe-718b.json"),
+            "num_hidden_layers": 2})
+        bodies = 1
+    elif name == "laguna":
+        from quintnet_tpu.models.laguna import (LagunaConfig,
+                                                laguna_init as init)
+        from quintnet_tpu.serve import laguna_family as family
+
+        cfg = LagunaConfig.from_dict(config("laguna-xs.2.json"))
+        bodies = 2
+    else:
+        from quintnet_tpu.models.ling_hybrid import (
+            LingHybridConfig, ling_hybrid_init as init)
+        from quintnet_tpu.serve import ling_hybrid_family as family
+
+        cfg = LingHybridConfig.from_dict(config("ling-3.0-flash.json"))
+        bodies = 2
+    fam = family(cfg)
+    params = _bf16_param_shapes(fam, init, cfg, chip)
+    lanes = 1024 if name == "laguna" else 640
+    pool = sds((fam.n_layers, slots * width * bs, lanes), jnp.bfloat16)
+    chunk = (sds((1, 1024), jnp.int32), sds((), jnp.int32),
+             sds((), jnp.int32), sds((width,), jnp.int32))
+    slot = sds((), jnp.int32)
+    if name == "pangu":
+        def prefill(params, k, ids, start, t0, row):
+            return fam.prefill_from(params, k, None, ids, start, t0, row, bs)
+        return prefill, (params, pool, *chunk), bodies
+    if name == "laguna":
+        store = sds((fam.window.n_layers, (slots + 1) * fam.window.ring,
+                     1024), jnp.bfloat16)
+
+        def prefill(params, k, v, wk, wv, ids, start, t0, row, slot):
+            return fam.prefill_from(params, k, v, ids, start, t0, row, bs,
+                                    window=(wk, wv), slot=slot)
+        return prefill, (params, pool, pool, store, store, *chunk,
+                         slot), bodies
+    ssm = sds((fam.state.n_layers, slots + 1, *fam.state.ssm), jnp.float32)
+    conv = sds((fam.state.n_layers, slots + 1, *fam.state.conv),
+               jnp.bfloat16)
+
+    def prefill(params, k, ssm, conv, ids, start, t0, row, slot):
+        return fam.prefill_from(params, k, None, ids, start, t0, row, bs,
+                                state=(ssm, conv), slot=slot)
+    return prefill, (params, pool, ssm, conv, *chunk, slot), bodies
+
+
+@pytest.mark.parametrize("family", ["laguna", "ling", "pangu"])
+def test_moe_prefill_bucket_runs_the_kernel_where_it_lowers(chip, family):
+    """The 1,024-token prefill bucket of each MoE family at its cell's
+    published widths, traced ONCE in this CPU process: lowered for the
+    described chip it holds the grouped-matmul kernel's calls — three a
+    sparse-layer body, 8,192 sorted routings each — and no
+    ``ragged_dot``; the same function lowered for the CPU this process
+    has holds no TPU custom call at all (the choice is made where the
+    program is lowered, nn/moe._expert_rows; the decode programs: the
+    three ``*_on_v5e`` tests below, compiled)."""
+    prefill, shapes, bodies = _moe_prefill(family, chip)
+    for_chip = jax.jit(prefill).lower(*shapes).as_text()
+    assert "ragged_dot" not in for_chip
+    assert for_chip.count('kernel_name = "grouped_matmul"') == 3 * bodies
+    here = jax.jit(prefill).lower(*jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype), shapes)).as_text()
+    assert "tpu_custom_call" not in here
+
+
+# ---------------------------------------------------------------------
 # the compile cache is placed from outside
 # ---------------------------------------------------------------------
 @pytest.fixture
@@ -332,9 +498,6 @@ def test_hybrid_decode_holds_one_copy_of_the_state_on_v5e(chip):
     from quintnet_tpu.serve import granite_hybrid_family
     from quintnet_tpu.serve.kv_pool import feature_width
     from quintnet_tpu.serve.kv_quant import make_policy
-    from quintnet_tpu.serve.weight_quant import (make_weight_policy,
-                                                 present_targets,
-                                                 quantize_params)
 
     with open(os.path.join(REPO, "benchmarks", "configs",
                            "granite-4.0-h-micro.json")) as f:
@@ -346,12 +509,7 @@ def test_hybrid_decode_holds_one_copy_of_the_state_on_v5e(chip):
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
 
-    params = jax.tree.map(
-        lambda x: sds(x.shape, x.dtype),
-        jax.eval_shape(lambda k: (lambda p: quantize_params(
-            p, present_targets(p, fam.weight_targets),
-            make_weight_policy("bf16")))(granite_hybrid_init(k, cfg)),
-            jax.random.key(0)))
+    params = _bf16_param_shapes(fam, granite_hybrid_init, cfg, chip)
     pool = sds((fam.n_layers, 32 * slots * bs,
                 feature_width(fam.n_kv_heads, fam.head_dim)), jnp.bfloat16)
     ssm = sds((fam.state.n_layers, slots + 1, *fam.state.ssm), jnp.float32)
@@ -387,16 +545,13 @@ def test_latent_decode_walks_the_one_pool_in_place_on_v5e(chip):
     — the loop's carry through the in-place ``kv_write`` scatter — so
     no copy of it, of a layer's slice or of a gathered view may be
     planned, and no gather at the table's width is left; and the
-    experts must run as the chip's own grouped matmul, not as a loop of
-    masked dense ones."""
+    experts must run as the grouped-matmul KERNEL (PR 38), the stack
+    indexed in place, with no ``ragged-dot`` left."""
     import numpy as np
 
     from quintnet_tpu.models.pangu_moe import PanguMoEConfig, pangu_moe_init
     from quintnet_tpu.serve import pangu_moe_family
     from quintnet_tpu.serve.kv_pool import feature_width
-    from quintnet_tpu.serve.weight_quant import (make_weight_policy,
-                                                 present_targets,
-                                                 quantize_params)
 
     with open(os.path.join(REPO, "benchmarks", "configs",
                            "openpangu-ultra-moe-718b.json")) as f:
@@ -408,12 +563,7 @@ def test_latent_decode_walks_the_one_pool_in_place_on_v5e(chip):
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
 
-    params = jax.tree.map(
-        lambda x: sds(x.shape, x.dtype),
-        jax.eval_shape(lambda k: (lambda p: quantize_params(
-            p, present_targets(p, fam.weight_targets),
-            make_weight_policy("bf16")))(pangu_moe_init(k, cfg)),
-            jax.random.key(0)))
+    params = _bf16_param_shapes(fam, pangu_moe_init, cfg, chip)
     assert feature_width(1, fam.latent) == 640
     pool = sds((fam.n_layers, slots * width * bs, 640), jnp.bfloat16)
     rows = sds((slots,), jnp.int32)
@@ -427,7 +577,7 @@ def test_latent_decode_walks_the_one_pool_in_place_on_v5e(chip):
     pool_bytes = int(np.prod(pool.shape)) * 2
     assert plan.alias_size_in_bytes >= pool_bytes      # in and out alias
     hlo = compiled.as_text()
-    assert "ragged-dot" in hlo and "tpu_custom_call" in hlo
+    _assert_experts_run_the_kernel(hlo, bodies=1)
     spec = importlib.util.spec_from_file_location(
         "pool_layout_audit", os.path.join(REPO, "tools",
                                           "pool_layout_audit.py"))
@@ -460,16 +610,14 @@ def test_kda_latent_decode_holds_one_state_and_walks_the_pool_on_v5e(chip):
     twice); the latent pool ``[1, slots, 640]`` must enter row-major,
     alias out and feed ONE call of the per-row walk through the
     in-place ``kv_write`` scatter, with no gather at the table's width;
-    and the experts must run as the chip's own grouped matmul."""
+    and the experts must run as the grouped-matmul kernel (PR 38: the
+    three sparse KDA layers' scan body and the latent layer's)."""
     import numpy as np
 
     from quintnet_tpu.models.ling_hybrid import (LingHybridConfig,
                                                  ling_hybrid_init)
     from quintnet_tpu.serve import ling_hybrid_family
     from quintnet_tpu.serve.kv_pool import feature_width
-    from quintnet_tpu.serve.weight_quant import (make_weight_policy,
-                                                 present_targets,
-                                                 quantize_params)
 
     with open(os.path.join(REPO, "benchmarks", "configs",
                            "ling-3.0-flash.json")) as f:
@@ -480,12 +628,7 @@ def test_kda_latent_decode_holds_one_state_and_walks_the_pool_on_v5e(chip):
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
 
-    params = jax.tree.map(
-        lambda x: sds(x.shape, x.dtype),
-        jax.eval_shape(lambda k: (lambda p: quantize_params(
-            p, present_targets(p, fam.weight_targets),
-            make_weight_policy("bf16")))(ling_hybrid_init(k, cfg)),
-            jax.random.key(0)))
+    params = _bf16_param_shapes(fam, ling_hybrid_init, cfg, chip)
     assert feature_width(1, fam.latent) == 640
     pool = sds((fam.n_layers, slots * width * bs, 640), jnp.bfloat16)
     ssm = sds((fam.state.n_layers, slots + 1, *fam.state.ssm), jnp.float32)
@@ -507,7 +650,7 @@ def test_kda_latent_decode_holds_one_state_and_walks_the_pool_on_v5e(chip):
     assert plan.temp_size_in_bytes < state_bytes / 3, (
         plan.temp_size_in_bytes, state_bytes)
     hlo = compiled.as_text()
-    assert "ragged-dot" in hlo and "tpu_custom_call" in hlo
+    _assert_experts_run_the_kernel(hlo, bodies=2)
     spec = importlib.util.spec_from_file_location(
         "pool_layout_audit", os.path.join(REPO, "tools",
                                           "pool_layout_audit.py"))
@@ -539,14 +682,12 @@ def test_window_decode_reads_rings_and_contracts_as_stored_on_v5e(chip):
     and alias out; no copy of either, of a layer's slice of them, of a
     gathered view or of a ring cut into heads may be planned (48 and 64
     query heads both contract the cached rows as stored); and the 256
-    experts of four layers must run as the chip's own grouped matmul."""
+    experts of four layers must run as the grouped-matmul kernel (PR
+    38: the sliding run's scan body and the global sparse layer's)."""
     import numpy as np
 
     from quintnet_tpu.models.laguna import LagunaConfig, laguna_init
     from quintnet_tpu.serve import laguna_family
-    from quintnet_tpu.serve.weight_quant import (make_weight_policy,
-                                                 present_targets,
-                                                 quantize_params)
 
     with open(os.path.join(REPO, "benchmarks", "configs",
                            "laguna-xs.2.json")) as f:
@@ -557,12 +698,7 @@ def test_window_decode_reads_rings_and_contracts_as_stored_on_v5e(chip):
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
 
-    params = jax.tree.map(
-        lambda x: sds(x.shape, x.dtype),
-        jax.eval_shape(lambda k: (lambda p: quantize_params(
-            p, present_targets(p, fam.weight_targets),
-            make_weight_policy("bf16")))(laguna_init(k, cfg)),
-            jax.random.key(0)))
+    params = _bf16_param_shapes(fam, laguna_init, cfg, chip)
     pool = sds((fam.n_layers, slots * width * bs, 1024), jnp.bfloat16)
     store = sds((fam.window.n_layers, (slots + 1) * ring, 1024),
                 jnp.bfloat16)
@@ -580,7 +716,7 @@ def test_window_decode_reads_rings_and_contracts_as_stored_on_v5e(chip):
                            + int(np.prod(store.shape)))
     assert plan.alias_size_in_bytes >= cache_bytes     # in and out alias
     hlo = compiled.as_text()
-    assert "ragged-dot" in hlo and "tpu_custom_call" in hlo
+    _assert_experts_run_the_kernel(hlo, bodies=2)
     _assert_pool_row_major_and_uncopied(hlo, pool, view=(slots, width, bs),
                                         walks=2)
     spec = importlib.util.spec_from_file_location(

@@ -1269,6 +1269,13 @@ def test_trace_view_xplane_idle_by_span_and_exposed_collectives(
     by = out["device_ms_by_scope"]
     assert by["blocks/mlp"] > 0 and by["grad_reduce"] > 0
     assert by[tv.NO_SCOPE] == 0.0          # the while: no time of its own
+    # chip 0's operations by program: calls and own time (the while's
+    # own time is what its body leaves: nothing; the pretend overlap
+    # is clipped to the all-reduce it starts in: 30 + 10 us each)
+    ops = out["ops_by_program"]["jit_step"]
+    assert ops["psum.1"] == {"count": 2, "ms": pytest.approx(40e-3)}
+    assert ops["fusion.1"] == {"count": 2, "ms": pytest.approx(40e-3)}
+    assert ops["while.1"] == {"count": 1, "ms": 0.0}
 
 
 def test_scope_map_gives_a_bare_instruction_its_producers_scope():

@@ -484,6 +484,10 @@ def test_the_ring_carries_the_latent_and_expert_facts(params):
         assert 0 < a["experts_touched"] <= min(
             k, CFG.n_moe_layers * CFG.n_routed_experts)
         assert a["moe_dropped_tokens"] == 0
+        # the tiny preset is off the 128-lane grid: its programs keep
+        # ragged_dot and no kernel visits a tile (PR 38)
+        assert a["expert_tile_visits"] == 0
+        assert a["decode_expert_tile_visits"] == 0
 
 
 @pytest.mark.parametrize("traffic", ("steady", "chunked", "speculative"))

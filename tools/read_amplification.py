@@ -13,7 +13,13 @@ attention read: a row rounded up to the walk's key block, or to the
 table's width where a view is still gathered) over ``context_tokens x
 paged_layers`` (what the rows held). 1.0 is a program that reads what
 is live and nothing else; the gathered form read 2.5 (GPT-2 XL) and 3.4
-(the window cell) times that (PERF.md section 6, PR 34). One JSON line,
+(the window cell) times that (PERF.md section 6, PR 34). For a family
+with the dropless mixture also ``tile_visits_over_touched``: the
+grouped-matmul kernel's (row tile, expert) visits over the (layer,
+expert) pairs that received a row (nn/moe._expert_rows, PR 38), of the
+decode program's steps (``decode``) and of the steps that prefilled
+(``with_prefill``: every program of such a step) — 1.0 where each
+touched expert's weights meet the matrix unit once. One JSON line,
 also written to ``chiprun_out/bench/<cell>.reads.json``.
 """
 
@@ -71,6 +77,21 @@ def main() -> int:
             "context_tokens_a_step": a_step(lambda r: r["context_tokens"]),
             "decoding_a_step": a_step(lambda r: r["decoding"]),
             "max_slots": ring.static["max_slots"]}
+    mixed = [r for r in ring.snapshot()
+             if r["attrs"].get("experts_touched")]
+    if mixed:
+        def visits_over_touched(records, prefix=""):
+            touched = sum(r["attrs"][prefix + "experts_touched"]
+                          for r in records)
+            return sum(r["attrs"][prefix + "expert_tile_visits"]
+                       for r in records) / touched if touched else None
+
+        line["tile_visits_over_touched"] = {
+            "decode": visits_over_touched(mixed, "decode_"),
+            "with_prefill": visits_over_touched(
+                [r for r in mixed if r["attrs"]["experts_touched"]
+                 > r["attrs"]["decode_experts_touched"]]),
+            "steps": len(mixed)}
     with open(os.path.join(probe.OUT, args.workload + ".reads.json"),
               "w") as f:
         json.dump(line, f, indent=1)

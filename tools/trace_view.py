@@ -52,6 +52,11 @@ the operator's view of where a traced stretch went:
 
 - ``programs``: per jitted program (``jit_serve_decode``,
   ``jit_local_step``, ...) executions and milliseconds, chip 0;
+- ``ops_by_program``: per program its twelve largest operations by own
+  time, chip 0 (``{"jit_serve_decode": {"ragged-dot-none.3": {"count":
+  52, "ms": 76.9}}}``): ``ms / count`` is a call, ``count`` over the
+  program's executions the calls a program (what split the grouped
+  matmul by decode and prefill: PERF.md section 5, PR 38);
 - ``device_ms_by_scope``: device own-time by the program's
   ``jax.named_scope`` path (``blocks/attn/sdpa``), mean over chips,
   with ``named_share_pct``: the share that lies in some scope. An
@@ -356,17 +361,29 @@ def xplane_tables(path: str) -> Dict:
     n = reduced["chips"]
     by_scope: Dict[str, float] = {}
     coll: Dict[str, List[float]] = {}      # scope -> [ns, exposed ns]
+    by_program: Dict[str, Dict[str, List[float]]] = {}
     for d in planes:
         mods = sorted((s, e, name.split("(", 1)[0])
                       for name, s, e in d["modules"])
         mod_starts = [m[0] for m in mods]
 
+        def program_of(start: float) -> Optional[str]:
+            i = bisect.bisect_right(mod_starts, start) - 1
+            return mods[i][2] if i >= 0 and start < mods[i][1] else None
+
+        if d is planes[0]:
+            keyed = [((program_of(s), tr.short_name(nm)), s, e)
+                     for nm, s, e in d["ops"]]
+            for (prog, op), ns in tr.self_times(keyed).items():
+                by_program.setdefault(prog or NO_SCOPE, {})[op] = [0, ns]
+            for (prog, op), _s, _e in keyed:
+                by_program[prog or NO_SCOPE][op][0] += 1
+
         def scope_of(name: str, start: float) -> str:
             if maps is None:
                 return NO_MAP
-            i = bisect.bisect_right(mod_starts, start) - 1
-            prog = mods[i][2] if i >= 0 and start < mods[i][1] else None
-            return maps.get(prog, {}).get(tr.short_name(name), NO_SCOPE)
+            return maps.get(program_of(start), {}).get(
+                tr.short_name(name), NO_SCOPE)
 
         # own time by scope: the scope rides in the name self_times
         # keys on, so equal instruction names of two programs stay apart
@@ -403,6 +420,10 @@ def xplane_tables(path: str) -> Dict:
         "scope_map": scopes_path if maps is not None else None,
         "programs": {k: {"count": c, "ms": 1e3 * sec} for k, (c, sec)
                      in sorted(reduced["modules"].items())},
+        "ops_by_program": {
+            prog: {op: {"count": c, "ms": ns / 1e6} for op, (c, ns) in
+                   sorted(ops.items(), key=lambda kv: -kv[1][1])[:12]}
+            for prog, ops in sorted(by_program.items())},
         "device_ms_by_scope": table(by_scope, 1e6 * n),
         "device_named_share_pct": named_share(by_scope,
                                               (NO_SCOPE, NO_MAP)),
