@@ -369,21 +369,26 @@ def expected_serve_window_moe(*, full_runs: int, sliding_runs: int,
       layer's cache a block of keys at a time
       (nn/attention._paged_attend_key_blocked: no walk), and a sliding
       layer never reads at the table's width.
-    - ``store_reads``: a sliding layer reads its rows' rings of ONE
-      layer, k and v, ``(1, rows * ring, width)`` each — ``ring`` =
-      ``sliding_window + block_size`` positions a row, whatever the
-      sequences' lengths.
+    - ``store_reads``: a prefill bucket's sliding layer reads its row's
+      ring of ONE layer, k and v, ``(1, rows * ring, width)`` each —
+      ``ring`` = ``sliding_window + block_size`` positions a row, whatever
+      the sequence's length; decode and verify slice NOTHING out of the
+      store: each row walks its own ring's live rows in place
+      (``ring_walk_calls`` one a sliding run, :func:`row_walk_calls` with
+      the store's shape: nn/attention._window_attend_walk).
     - ``view_head_splits`` 0 in decode and verify, for 48 and 64 query
       heads alike, and for 7 a kv head (both contract the cached rows as stored, heads on
-      the lane diagonal: the walk's kernel over the pool, and
-      nn/attention._lane_diag_sdpa over the rings) on a bf16 pool;
+      the lane diagonal: the walk's kernel over the pool and over the
+      rings) on a bf16 pool;
       a prefill bucket splits each key block it gathers, never a view
       of the table's width: 0 there too.
     - ``widened_view_dots`` 0."""
     return {"census": {}, "pool_scan_operands": 0,
             "gathered_view_gathers": 0,
             "row_walk_calls": 0 if chunk else full_runs,
-            "store_reads": [(1, rows * ring, width)] * (2 * sliding_runs),
+            "ring_walk_calls": 0 if chunk else sliding_runs,
+            "store_reads": ([(1, rows * ring, width)] * (2 * sliding_runs)
+                            if chunk else []),
             "view_head_splits": 0,
             "widened_view_dots": 0}
 

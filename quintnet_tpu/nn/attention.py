@@ -1020,21 +1020,23 @@ def noted_reads():
         _READS.notes = None
 
 
-def _note_window_read(rows: int) -> None:
+def _note_window_read(granule: int) -> None:
     notes = getattr(_READS, "window", None)
     if notes is not None:
-        notes.append(rows)
+        notes.append(granule)
 
 
 @contextlib.contextmanager
 def noted_window_reads():
-    """Collect the ring rows every :func:`window_attend` call traced
-    inside, on this thread, reads of one layer (the list yielded): the
-    shape its gather takes, every ring of the run whole — in a decode
-    program ``max_slots`` rings, idle slots' among them, where a
-    decoding row holds ``min(position + 1, window)`` live rows. The
-    engine's ``window_read_rows`` is counted from it. One program body
-    at a time: it does not nest."""
+    """Collect what every :func:`window_attend` call traced inside, on
+    this thread, reads of a row's ring of one layer (the list yielded):
+    the granule ``g`` a read is rounded up to — a row that wrote up to
+    position ``p`` reads ``max(ceil(min(p + 1, ring) / g), 1) x g`` ring
+    rows, one granule for a row that wrote nothing. ``g`` is the walk's
+    key block (:func:`_window_attend_walk`), or the ring itself where the
+    rings are sliced out whole: then every row of the program, idle ones
+    too, reads its ring. The engine's ``window_read_rows`` is counted
+    from it. One program body at a time: it does not nest."""
     _READS.window = notes = []
     try:
         yield notes
@@ -1255,9 +1257,10 @@ def window_attend(q, k, v, bufs, layer, positions, lens, row0, *,
       the write the ring holds the ``ring`` positions up to the run's
       last, and the query at the run's first position still finds all
       ``window`` of its own: the ``ring - window`` spare rows are what
-      the run overwrote. The ring is contracted as stored, heads on the
-      lane diagonal, for a bf16/f16 store under ``max_diag_rows``
-      (:func:`_lane_diag_sdpa`), split into heads otherwise.
+      the run overwrote. For a bf16/f16 store under ``max_diag_rows``
+      each row walks its own ring's live rows in place, heads on the
+      lane diagonal (:func:`_window_attend_walk`); otherwise the rings
+      are sliced out whole and split into heads.
     - wider (a prefill chunk, up to twice the window and more): READ,
       THEN WRITE. The keys are the ring as the earlier chunks left it
       (up to ``ring`` positions before the run's first) beside the
@@ -1276,10 +1279,8 @@ def window_attend(q, k, v, bufs, layer, positions, lens, row0, *,
         raise ValueError(f"a ring of {ring} rows cannot hold a window of "
                          f"{window}")
     pos_q = jnp.tile(positions, (1, groups))[:, None, :, None]  # [S,1,GP,1]
-    # both orders gather the run's S rings whole
-    _note_window_read(S * ring)
 
-    def gathered():
+    def gathered(wk, wv):
         with jax.named_scope("window_gather"):
             return (window_gather(wk, layer, row0, S, ring=ring),
                     window_gather(wv, layer, row0, S, ring=ring))
@@ -1287,19 +1288,28 @@ def window_attend(q, k, v, bufs, layer, positions, lens, row0, *,
     def split(rows):
         return _pool_heads(rows, *heads).transpose(0, 2, 1, 3)
 
+    def seen():
+        held = _ring_positions(start + lens - 1, ring)[:, None, None, :]
+        return (held >= 0) & (held <= pos_q) & (held > pos_q - window)
+
+    def diag(q, wk, wv):
+        k_rows, v_rows = gathered(wk, wv)
+        return _lane_diag_sdpa(q, k_rows[:, None], v_rows[:, None], seen(),
+                               kv_heads=hkv)
+
     if P <= ring - window + 1:
         wk, wv = window_write(wk, wv, layer, k, v, positions, lens, row0,
                               ring=ring)
-        k_rows, v_rows = gathered()
-        held = _ring_positions(start + lens - 1, ring)[:, None, None, :]
-        valid = (held >= 0) & (held <= pos_q) & (held > pos_q - window)
         if _as_gathered(q, wk, max_diag_rows):
-            o = _lane_diag_sdpa(q, k_rows[:, None], v_rows[:, None], valid,
-                                kv_heads=hkv)
-        else:
-            o = _masked_sdpa(q, split(k_rows), split(v_rows), valid)
+            return _window_attend_walk(
+                q, (wk, wv), layer, positions, lens, row0, window=window,
+                ring=ring, kv_heads=hkv, gathered=diag), (wk, wv)
+        _note_window_read(ring)
+        k_rows, v_rows = gathered(wk, wv)
+        o = _masked_sdpa(q, split(k_rows), split(v_rows), seen())
         return o, (wk, wv)
-    k_rows, v_rows = gathered()
+    _note_window_read(ring)
+    k_rows, v_rows = gathered(wk, wv)
     held = _ring_positions(start - 1, ring)[:, None, None, :]
     col = jnp.arange(P)[None, None, None, :]
     row = jnp.tile(jnp.arange(P), groups)[None, None, :, None]
@@ -1312,6 +1322,114 @@ def window_attend(q, k, v, bufs, layer, positions, lens, row0, *,
     o = _masked_sdpa(q, k_all, v_all, valid)
     return o, window_write(wk, wv, layer, k, v, positions, lens, row0,
                            ring=ring)
+
+
+# Positions a trip of the walk over a sliding layer's RING aims at. A
+# trip costs its DMA descriptors and the recurrence's rescale whatever
+# it carries, and a ring is read whole once its sequence is older than
+# it, so fewer, longer trips win there: on one v5e chip, the window
+# cells' own sliding layers a decode step (`tools/attn_microbench.py
+# --rings`), 528-row rings of 48 rows 0.230 ms a
+# layer in three trips of 176, 0.213 in two of 272, 0.183 in one of
+# 528 (0.225 sliced out whole); 4,112-row rings of 128 rows, 72 of them
+# decoding, 1.32 ms in trips of 256, 1.22 of 464, 1.23 of 832 (5.01
+# sliced out whole).
+RING_KEY_BLOCK = 512
+
+
+def _ring_key_block(pages: int, block_size: int,
+                    fits=lambda key_block: True) -> int:
+    """Positions a trip of the walk over a ring of ``pages`` pages of
+    ``block_size`` reads: a whole ring takes as many trips as it holds
+    :data:`RING_KEY_BLOCK`-s, to the nearest and at least one, with its
+    pages spread evenly over them — 528 rows in one trip, 4,112 (257
+    pages, a prime) in eight of 528 — or, where a key block of that
+    many does not ``fit`` (a verify run's many query rows), the fewest
+    trips whose block does (at least a page)."""
+    trips = max((pages * block_size + RING_KEY_BLOCK // 2)
+                // RING_KEY_BLOCK, 1)
+    while True:
+        key_block = -(-pages // trips) * block_size
+        if trips >= pages or fits(key_block):
+            return key_block
+        trips += 1
+
+
+def _window_attend_walk(q, bufs, layer, positions, lens, row0, *,
+                        window: int, ring: int, kv_heads: int, gathered):
+    """:func:`window_attend`'s write-then-read order on a bf16/f16
+    store with few query rows (decode, verify): after the write each
+    row walks its OWN ring of ``(wk / wv, layer)`` where it lies and
+    folds it into a running softmax (ops/paged_attention.
+    paged_walk_attention with a ``window``). Row ``s`` owns ring ``row0
+    + s``, whose page ``j`` is store page ``(row0 + s) x ring_pages +
+    j`` (a table made on the device from an iota); it reads ``ceil(min(
+    last + 1, ring) / key_block)`` key blocks (:func:`_ring_key_block`)
+    — what it wrote while younger than its ring, the ring once after —
+    and a row of ``lens`` 0 one block, all of it masked (a finite output
+    nothing reads). A query sees ring row ``r`` iff the position it
+    holds (:func:`_ring_positions`) is this sequence's, at or before
+    the query and inside its window. No slice of the store is taken.
+
+    The arithmetic is :func:`_lane_diag_sdpa`'s — ring rows as stored,
+    heads on the lane diagonal of the small operand, q and the
+    probabilities rounded to the store's dtype, f32 sums, f32 softmax —
+    with the softmax's sums in key-block order. Where the walk does not
+    lower (ops/paged_attention.walk_lowers_for: another platform with no
+    interpreter asked for, or pages under 8 rows) or its VMEM sum does
+    not fit at a page a trip, ``gathered(q, wk, wv)`` runs instead — the
+    caller's slice of every ring whole under :func:`_lane_diag_sdpa` —
+    both traced under ``lax.platform_dependent`` where this process's
+    backend and the chip would choose apart. Notes the granule a row's
+    read is rounded up to (:func:`noted_window_reads`): the key block,
+    or the ring."""
+    from quintnet_tpu.ops.paged_attention import (VMEM_CAP_BYTES,
+                                                  paged_walk_attention,
+                                                  walk_lowers_for,
+                                                  walk_vmem_bytes)
+
+    wk, wv = bufs
+    S, hq, gp, dh = q.shape
+    P = positions.shape[1]
+    bs = ring - window
+    pages = ring // bs
+    rows = hq * gp
+    tile = 8 * 4 // wk.dtype.itemsize
+    padded = -(-rows // tile) * tile
+
+    def fits(key_block):
+        return 2 * walk_vmem_bytes(
+            rows=padded, lanes=wk.shape[-1], kept_lanes=wk.shape[-1],
+            key_block=key_block, pools=2, pool_dtype=wk.dtype,
+            q_dtype=wk.dtype) <= VMEM_CAP_BYTES
+
+    key_block = _ring_key_block(pages, bs, fits)
+    here = fits(key_block) and walk_lowers_for(jax.default_backend(), bs)
+    # what THIS process's program reads of a row's ring
+    _note_window_read(key_block if here else ring)
+
+    def walk(q, wk, wv, layer, positions, lens):
+        with jax.named_scope("sdpa"):
+            qd, own = _diag_queries(q, wk, kv_heads, padded)
+            # a row's columns at or beyond its lens wrote nothing: their
+            # queries see nothing, and the row's last written position
+            # is its largest
+            real = jnp.arange(P)[None, :] < lens[:, None]
+            qpos = jnp.pad(
+                jnp.tile(jnp.where(real, positions, -1), (1, rows // P)),
+                ((0, 0), (0, padded - rows)), constant_values=-1)
+            tables = ((row0 + jnp.arange(S))[:, None] * pages
+                      + jnp.arange(pages)[None, :])
+            o = paged_walk_attention(
+                qd, qpos, wk, wv, layer, tables, block_size=bs,
+                key_block=key_block, head_dim=dh, window=window)
+            return _off_diagonal(o, own, q.shape).astype(
+                jnp.result_type(q, wv))
+
+    return kernel_where_it_lowers(
+        here, fits(key_block) and walk_lowers_for("tpu", bs), walk,
+        lambda q, wk, wv, *_: gathered(q, wk, wv),
+        q, wk, wv, layer, positions, lens)
 
 
 # ---------------------------------------------------------------------

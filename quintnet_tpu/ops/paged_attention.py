@@ -87,7 +87,14 @@ bound, not to bits (tests/test_paged_attention.py ``TestRowWalk``). A
 pool with NO v buffer (PR 36: a latent family's one row a token, every
 head's key and value at once) takes the same kernel with one buffer and
 one DMA a page, the values' product against the block the scores read
-(``TestLatentRowWalk``; nn/attention.latent_attend_absorbed).
+(``TestLatentRowWalk``; nn/attention.latent_attend_absorbed). A
+``window`` (a sliding-window layer's ring of ``window + block_size``
+rows a slot, nn/attention.window_attend) makes a row's table its ring's
+pages in ring order: the row reads what it wrote while younger than its
+ring and the ring once after, and the mask is the position each ring
+row holds (``_ring_seen``), not the row's index
+(tests/test_window_walk.py). Both selections are read when the call is
+traced; the plain call's program is what it was.
 
 Interpret mode is the CALLER's decision, never the kernel's: the
 default is the compiled Mosaic kernel on whatever backend the process
@@ -511,9 +518,24 @@ def _page_copy(pool, buf, sem, tbl_ref, layer, s, c, slot, i, *,
         sem.at[slot])
 
 
-def _walk_kernel(layer_ref, tbl_ref, trips_ref, first_ref, qd_ref,
-                 qpos_ref, *refs, block_size: int, pages: int,
-                 head_dim: int, scale):
+def _ring_seen(r, qpos, last, *, ring: int, window: int):
+    """Which of a ring's rows ``r`` (ring-row indices, any shape) the
+    queries at ``qpos`` see once every position up to ``last`` (a
+    scalar) has been written: row ``r`` holds ``last - (last - r) %
+    ring`` (nn/attention._ring_positions) — ``base + r`` up to ``last``'s
+    own row, ``ring`` less past it — and is seen iff that position is
+    this sequence's (``>= 0``), at or before the query and inside its
+    window. Rows past the ring (a trip's pages beyond the table re-read
+    its last) are not."""
+    at = lax.rem(last, ring)                       # last >= 0
+    held = last - at + r - jnp.where(r > at, ring, 0)
+    return ((r < ring) & (held >= 0) & (held <= qpos)
+            & (held > qpos - window))
+
+
+def _walk_kernel(layer_ref, tbl_ref, trips_ref, first_ref, *refs,
+                 block_size: int, pages: int, head_dim: int, scale,
+                 window=None):
     """One row of the grid: the flash recurrence over the row's
     ``trips_ref[s]`` live key blocks. The pool stays in HBM; block ``c
     + 1`` (or the next row's first) is in flight into the other half of
@@ -522,11 +544,17 @@ def _walk_kernel(layer_ref, tbl_ref, trips_ref, first_ref, qd_ref,
     rows before ``s`` walked: the parity of ``first + c`` is the buffer
     half, carried across rows without a counter.
 
-    ``refs``: the pools in HBM, the output, a double buffer a pool, a
+    ``refs``: with a ``window``, the rows' last written positions (a
+    fifth scalar prefetch; the table is then each row's RING in ring
+    order, :func:`_ring_seen` its mask); the queries and their
+    positions; the pools in HBM, the output, a double buffer a pool, a
     semaphore pair a pool, then ``m``, ``l`` and ``acc``. A pool with no
     v (a latent one: a position's key and value are the same row) has
     ONE of each: one DMA a page, and the values' product reads the block
     the scores read, its first ``acc``-many lanes."""
+    if window is not None:
+        last_ref, *refs = refs
+    qd_ref, qpos_ref, *refs = refs
     n = (len(refs) - 4) // 3
     pools, o_ref = refs[:n], refs[n]
     bufs, sems = refs[n + 1:2 * n + 1], refs[2 * n + 1:3 * n + 1]
@@ -581,8 +609,10 @@ def _walk_kernel(layer_ref, tbl_ref, trips_ref, first_ref, qd_ref,
             preferred_element_type=jnp.float32)       # [R, kb]
         scores = (scores / math.sqrt(head_dim) if scale is None
                   else scores * scale)
-        seen = (c * kb + lax.broadcasted_iota(jnp.int32, scores.shape, 1)
-                <= qpos)
+        row = c * kb + lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+        seen = (row <= qpos if window is None else _ring_seen(
+            row, qpos, last_ref[s], ring=tbl_ref.shape[1] * block_size,
+            window=window))
         scores = jnp.where(seen, scores, neg)
         m_old = m_scr[...]
         m_new = jnp.maximum(m_old, jnp.max(scores, axis=1, keepdims=True))
@@ -623,7 +653,8 @@ def walk_vmem_bytes(*, rows: int, lanes: int, kept_lanes: int,
 
 def paged_walk_attention(qd, qpos, k_pool, v_pool, layer, block_tables, *,
                          block_size: int, key_block: int, head_dim: int,
-                         scale=None, kept_lanes=None, interpret=None):
+                         scale=None, kept_lanes=None, window=None,
+                         interpret=None):
     """Few query rows a sequence against each sequence's LIVE blocks of
     the carried pool (nn/attention.paged_attend's decode and verify
     form). ``qd`` [S, R, F]: a sequence's query rows in the pool's
@@ -649,7 +680,17 @@ def paged_walk_attention(qd, qpos, k_pool, v_pool, layer, block_tables, *,
     stored dtype with f32 sums, the mask ``t <= qpos``, f32 ``exp``,
     the probabilities rounded to the stored dtype before ``[R,
     key_block] x [key_block, F]``. Returns ``o`` [S, R, kept lanes]
-    f32, normalised: row ``r``'s output is in its own head's lanes."""
+    f32, normalised: row ``r``'s output is in its own head's lanes.
+
+    ``window`` (a sliding-window layer's ring, nn/attention.window_attend):
+    row ``s``'s table is its RING's pages in ring order, ``R = M x
+    block_size`` rows holding the last ``R`` positions up to ``max(qpos
+    [s])`` (its last written; -1 everywhere for a row that wrote
+    nothing). It reads ``ceil(min(max(qpos[s]) + 1, R) / key_block)``
+    key blocks — what it wrote while younger than its ring, the ring
+    once after, one block for a row that wrote nothing — and masks by
+    the position each ring row holds (:func:`_ring_seen`): a query sees
+    the ``window`` positions up to its own."""
     if interpret is None:
         interpret = INTERPRET
     S, R, F = qd.shape
@@ -659,16 +700,23 @@ def paged_walk_attention(qd, qpos, k_pool, v_pool, layer, block_tables, *,
     pages = max(min(key_block // block_size, M), 1)
     kb = pages * block_size
     last = jnp.max(qpos, axis=1)
-    trips = jnp.clip(last // kb + 1, 1, -(-M // pages)).astype(jnp.int32)
+    ring = []
+    if window is None:
+        trips = jnp.clip(last // kb + 1, 1, -(-M // pages)).astype(jnp.int32)
+    else:
+        held = jnp.minimum(last + 1, M * block_size)
+        trips = jnp.maximum(-(-held // kb), 1).astype(jnp.int32)
+        ring = [jnp.maximum(last, 0).astype(jnp.int32)]
     first = (jnp.cumsum(trips) - trips).astype(jnp.int32)
     vmem = walk_vmem_bytes(rows=R, lanes=F, kept_lanes=kept, key_block=kb,
                            pools=len(pools), pool_dtype=k_pool.dtype,
                            q_dtype=qd.dtype)
     kernel = functools.partial(_walk_kernel, block_size=block_size,
-                               pages=pages, head_dim=head_dim, scale=scale)
+                               pages=pages, head_dim=head_dim, scale=scale,
+                               window=window)
     row = lambda s, *_: (s, 0, 0)                             # noqa: E731
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
+        num_scalar_prefetch=4 + len(ring),
         grid=(S,),
         in_specs=[pl.BlockSpec((1, R, F), row),
                   pl.BlockSpec((1, R, 1), row),
@@ -696,5 +744,5 @@ def paged_walk_attention(qd, qpos, k_pool, v_pool, layer, block_tables, *,
             interpret=interpret,
             name="paged_walk_attention",
         )(jnp.reshape(layer, (1,)).astype(jnp.int32),
-          block_tables.astype(jnp.int32), trips, first,
+          block_tables.astype(jnp.int32), trips, first, *ring,
           qd, qpos[..., None].astype(jnp.int32), *pools)

@@ -883,9 +883,10 @@ class ServeEngine:
         # read up to (nn/attention.noted_reads), known once the program
         # is traced: the walk's key block, None for the table's width
         self._read_granule: Dict[str, Optional[int]] = {}
-        # the ring rows the decode program's sliding layers read a
-        # layer, every slot's ring whole (nn/attention.
-        # noted_window_reads), known once it is traced
+        # the granule the decode program's sliding layers round a row's
+        # ring read up to: the walk's key block, the ring where every
+        # ring is sliced out whole (nn/attention.noted_window_reads),
+        # known once it is traced
         self._ring_read: Optional[int] = None
         if self.adapters is None:
             self._decode = RecompileSentinel(
@@ -2475,8 +2476,7 @@ class ServeEngine:
         # a window family's decode reads, in rows x layers: every
         # position of the global layers, at most the window's of the
         # sliding ones LIVE — and ``window_read_rows`` the ring rows
-        # its program reads (every slot's ring whole, decoding or not:
-        # noted when the decode program was traced)
+        # its program reads (below)
         window_attrs = {}
         if self._window:
             w = self.family.window
@@ -2484,6 +2484,10 @@ class ServeEngine:
                 "global_rows": context_tokens * self.pool.n_layers,
                 "window_rows": int(np.minimum(
                     self._pos[decoding], w.window).sum()) * w.n_layers}
+            # the ring rows each slot's row of the decode program holds
+            # once it has written: none for a row that sits out
+            ring_held = np.zeros(self.max_slots, np.int64)
+            ring_held[decoding] = np.minimum(self._pos[decoding] + 1, w.ring)
         # bytes of recurrent state this step's programs read and wrote:
         # every decoding slot's once each way, and each prefilled
         # slot's per chunk program that ran (a chunk that starts at 0
@@ -2596,9 +2600,13 @@ class ServeEngine:
                    if k.startswith("moe_")})
         if self._ring_read and decoding:
             # known once the decode program is traced (the first step
-            # that decoded, where there was no warm-up)
+            # that decoded, where there was no warm-up): every row of
+            # the program reads its ring rounded up to the granule, at
+            # least one granule
+            g = self._ring_read
+            trips = np.maximum(-(-ring_held // g), 1)
             window_attrs["window_read_rows"] = (
-                self._ring_read * self.family.window.n_layers)
+                int(trips.sum()) * g * self.family.window.n_layers)
         rec_t1 = ph.end()
         if self._recorder is not None:
             self._recorder.record(StepRecord(

@@ -17,6 +17,8 @@ checked without a chip (PR 21, the bring-up round).
    widths: one row-major latent pool, (PR 36) walked in place by one
    kernel call a layer loop with no gathered view, the experts as
    (PR 38) the grouped-matmul kernel that reads touched experts only.
+   Both window families' decode programs walk each row's ring of the
+   window store in place and slice none of it out.
    The parent
    commit's paged kernel was
    REFUSED at every one of these shapes (16 MiB default scoped-VMEM
@@ -334,6 +336,44 @@ def test_paged_walk_compiles_for_v5e(chip, rows, queries, lanes, layers,
     # the pool goes in as it is: no copy, slice or re-layout of it
     assert not [ln for ln in text.splitlines()
                 if f"bf16[{layers},{blocks * BS},{lanes}]" in ln
+                and (" copy(" in ln or " transpose(" in ln)]
+
+
+@pytest.mark.parametrize("slots,rows,lanes,ring,window", [
+    (128, 32, 512, 4112, 4096),     # smallthinker: 7 heads x 4 kv heads
+    (48, 64, 1024, 528, 512),       # laguna-xs.2's sliding layers: 8 x 8
+], ids=["wide-window-decode", "window-decode"])
+def test_ring_walk_compiles_for_v5e(chip, slots, rows, lanes, ring, window):
+    """``paged_walk_attention`` with a ``window``: each row walks
+    its own ring of the cell's whole window store ``[3, (slots + 1) x
+    ring, F]`` (1.1 GB a buffer in the wide-window cell), addressed
+    through a table made from an iota on the device, the rows' last
+    positions a fifth scalar prefetch and the ring mask in the kernel, at
+    the key block the ring's width gives (:func:`_ring_key_block`: 528
+    positions, eight trips over 4,112 rows and one over 528)."""
+    from quintnet_tpu.nn.attention import _ring_key_block
+
+    pa = importlib.import_module("quintnet_tpu.ops.paged_attention")
+    pages = ring // BS
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    store = sds((3, (slots + 1) * ring, lanes), jnp.bfloat16)
+
+    def fn(qd, qpos, layer, wk, wv):
+        tables = (jnp.arange(slots)[:, None] * pages
+                  + jnp.arange(pages)[None, :])
+        return pa.paged_walk_attention(
+            qd, qpos, wk, wv, layer, tables, block_size=BS,
+            key_block=_ring_key_block(pages, BS), head_dim=128,
+            window=window, interpret=False)
+
+    text = _compile(fn, sds((slots, rows, lanes), jnp.bfloat16),
+                    sds((slots, rows), jnp.int32), sds((), jnp.int32),
+                    store, store)
+    assert not [ln for ln in text.splitlines()
+                if f"bf16[3,{(slots + 1) * ring},{lanes}]" in ln
                 and (" copy(" in ln or " transpose(" in ln)]
 
 
@@ -683,9 +723,11 @@ def test_window_decode_reads_rings_and_contracts_as_stored_on_v5e(chip):
     cut into heads may be planned (48 and 64 query heads both contract
     the cached rows as stored); and the 256 experts of four layers must
     run as the grouped-matmul kernel (PR 38: the sliding run's scan
-    body and the global sparse layer's). A layer's slice of every
-    slot's k and v rings IS materialised, by the window gather's fusion
-    (PR 39's audit counts it: a ``copy`` count never saw it)."""
+    body and the global sparse layer's). Nothing is sliced out of the
+    window store either (a slice of every slot's k and v rings, which
+    the compiler materialises as a fusion before the scores): each row
+    walks its own ring in place, one walk in the sliding run's body
+    beside the two global layers'."""
     import numpy as np
 
     from quintnet_tpu.models.laguna import LagunaConfig, laguna_init
@@ -720,25 +762,8 @@ def test_window_decode_reads_rings_and_contracts_as_stored_on_v5e(chip):
     hlo = compiled.as_text()
     _assert_experts_run_the_kernel(hlo, bodies=2)
     _assert_pool_row_major_and_uncopied(hlo, pool, view=(slots, width, bs),
-                                        walks=2)
-    spec = importlib.util.spec_from_file_location(
-        "pool_layout_audit", os.path.join(REPO, "tools",
-                                          "pool_layout_audit.py"))
-    audit = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(audit)
-    got = audit.read_hlo(hlo, {"wk": store.shape, "wv": store.shape},
-                         slots * ring * 1024 * 2)
-    for c in got["big_copies"]:
-        dims = tuple(c["dims"])
-        assert dims not in {tuple(store.shape), tuple(store.shape[1:]),
-                            (1, *store.shape[1:])}, c
-        # a ring cut into heads: [slots, ring, 8, 128] in any order
-        assert sorted(dims) != sorted((slots, ring, 8, 128)), c
-    # what the compiler does plan: the gather's slice of every slot's
-    # k and v rings, once in the sliding run's body (PR 39)
-    assert sorted((c["scope"], tuple(c["dims"]))
-                  for c in got["big_slices"]) == 2 * [
-        ("window_gather/dynamic_slice", (1, slots * ring, 1024))], got
+                                        walks=2, ring_walks=1)
+    _assert_rings_walked_in_place(hlo, store, slots=slots, heads=8)
 
 
 def test_wide_window_decode_reads_rings_and_runs_the_kernel_on_v5e(chip):
@@ -750,12 +775,13 @@ def test_wide_window_decode_reads_rings_and_runs_the_kernel_on_v5e(chip):
     or of a ring cut into heads is planned (7 query heads a kv head
     contract the rows as stored); the global layer walks its pool (one
     walk); and the 64 ReGLU experts of both runs are the grouped-matmul
-    kernel, read under each run's scope. What IS planned, and measured:
-    ``window_gather``'s slice of every slot's k and v rings of a layer,
-    idle slots' too, is materialised by a fusion before the scores —
-    two ``constant_dynamic-slice_fusion`` copies of 539 MB a layer at
-    the cell's 128 slots, about 10 of a 24.7-ms decode step (my chip
-    trace, PR 39). Bounding that read is ROADMAP M2's."""
+    kernel, read under each run's scope. Nothing is sliced out of the
+    window store: ``window_gather``'s slice of every slot's k and v
+    rings of a layer, idle slots' too, would be materialised before the
+    scores — two ``constant_dynamic-slice_fusion`` copies of 539 MB a
+    layer at the cell's 128 slots, about 10 of a 24.7-ms decode step on
+    one v5e chip — where each row walks its own ring's live rows in
+    place, one walk in the sliding run's body."""
     import re
 
     import numpy as np
@@ -798,33 +824,57 @@ def test_wide_window_decode_reads_rings_and_runs_the_kernel_on_v5e(chip):
     assert {scopes.get(c) for c in calls} == {
         "blocks/global/moe/experts", "blocks/sliding/moe/experts"}
     _assert_pool_row_major_and_uncopied(hlo, pool, view=(slots, width, bs))
+    # two walks: the global run's over the pool — a run of ONE layer is
+    # a loop the compiler unrolls, so the pool reaches it through a free
+    # bitcast where a longer run's carry is a get-tuple-element — and
+    # the sliding run's over its rings
+    walks = _audit().read_hlo(hlo, {"k": pool.shape, "v": pool.shape},
+                              pool.shape[1] * 512 * 2)["row_walks"]
+    assert len(walks) == 2, walks
+    (walk,) = [w for w in walks if w["pool_operands"]]
+    assert len(walk["pool_operands"]) == 2 and all(
+        c["op"] == "bitcast" for c in walk["copies_beside"]), walk
+    _assert_rings_walked_in_place(hlo, store, slots=slots, heads=4)
+
+
+def _audit():
     spec = importlib.util.spec_from_file_location(
         "pool_layout_audit", os.path.join(REPO, "tools",
                                           "pool_layout_audit.py"))
     audit = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(audit)
-    # one walk, the global run's; a run of ONE layer is a loop the
-    # compiler unrolls, so the pool reaches it through a free bitcast
-    # where a longer run's carry is a get-tuple-element
-    (walk,) = audit.read_hlo(hlo, {"k": pool.shape, "v": pool.shape},
-                             pool.shape[1] * 512 * 2)["row_walks"]
-    assert len(walk["pool_operands"]) == 2 and all(
-        c["op"] == "bitcast" for c in walk["copies_beside"]), walk
-    got = audit.read_hlo(hlo, {"wk": store.shape, "wv": store.shape},
-                         slots * ring * 512 * 2)
+    return audit
+
+
+def _assert_rings_walked_in_place(hlo: str, store, *, slots: int,
+                                  heads: int):
+    """A window family's decode program: the window ``store``
+    ``[L_w, (slots + 1) x ring, F]`` is neither copied (whole, a layer,
+    or a ring cut into ``heads`` kv heads) nor sliced — no fusion
+    materialises any part of it the size of every slot's ring of a
+    layer — and ONE call of the walk reads it, k and v, fed by the
+    store itself: the sliding run's carry or the in-place ``kv_write``
+    scatter of the rows just written."""
+    ring = store.shape[1] // (slots + 1)
+    got = _audit().read_hlo(hlo, {"wk": store.shape, "wv": store.shape},
+                            slots * ring * store.shape[2] * 2)
     for c in got["big_copies"]:
         dims = tuple(c["dims"])
         assert dims not in {tuple(store.shape), tuple(store.shape[1:]),
                             (1, *store.shape[1:])}, c
-        assert sorted(dims) != sorted((slots, ring, 4, 128)), c
-    # the sliding run's body, once: k's and v's slice of every ring
-    assert sorted((c["scope"], tuple(c["dims"]))
-                  for c in got["big_slices"]) == 2 * [
-        ("window_gather/dynamic_slice", (1, slots * ring, 512))], got
+        assert sorted(dims) != sorted((slots, ring, heads, 128)), c
+    assert got["big_slices"] == [], got["big_slices"]
+    rings = [w for w in got["row_walks"] if any(
+        c["dims"] == list(store.shape) for c in w["pool_operands"])]
+    assert len(rings) == 1, got["row_walks"]
+    (walk,) = rings
+    stored = [c for c in walk["pool_operands"]
+              if c["dims"] == list(store.shape)]
+    assert len(stored) == 2 and walk["copies_beside"] == [], walk
 
 
 def _assert_pool_row_major_and_uncopied(hlo: str, pool, view=None,
-                                        walks=None):
+                                        walks=None, ring_walks=0):
     """The compiled program takes ``pool``-shaped parameters in the
     row-major layout and holds no ``copy`` / ``transpose`` of the whole
     pool or of one layer's slice of it (tools/pool_layout_audit.py is
@@ -836,7 +886,9 @@ def _assert_pool_row_major_and_uncopied(hlo: str, pool, view=None,
     the program must hold (PR 34: a decode program of a bf16 pool reads
     each row's live blocks out of the carried pool, one call a layer
     loop), each fed by the pool itself — the loop's carry through the
-    in-place ``kv_write`` scatter — and by no copy."""
+    in-place ``kv_write`` scatter — and by no copy. ``ring_walks``: the
+    calls beside them that read another buffer (a window store's
+    rings), whose operands this reading does not see."""
     spec = importlib.util.spec_from_file_location(
         "pool_layout_audit", os.path.join(REPO, "tools",
                                           "pool_layout_audit.py"))
@@ -853,8 +905,10 @@ def _assert_pool_row_major_and_uncopied(hlo: str, pool, view=None,
             assert tuple(c["dims"][:3]) != tuple(view), c
             assert tuple(c["dims"][:2]) != (view[0] * view[1], view[2]), c
     if walks is not None:
-        assert len(got["row_walks"]) == walks, got["row_walks"]
-        for call in got["row_walks"]:
+        fed = [c for c in got["row_walks"] if c["pool_operands"]]
+        assert len(fed) == walks, got["row_walks"]
+        assert len(got["row_walks"]) == walks + ring_walks, got["row_walks"]
+        for call in fed:
             assert len(call["pool_operands"]) == 2, call
             assert call["copies_beside"] == [], call
         # and no gather at the table's width is left beside them

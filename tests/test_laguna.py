@@ -330,14 +330,19 @@ def test_a_sequence_preempted_and_prefilled_again(params):
 def test_both_head_counts_keep_the_lane_diagonal_form(params):
     """On a bf16 pool the decode program and a verify run of 5 contract
     the cached rows as stored for 6 and 8 query heads alike (the global
-    layers walk each row's live blocks of the pool in place: no view of
-    the table's width at all; no head split of a ring), the census of every
+    layers walk each row's live blocks of the pool in place, the sliding
+    ones each row's ring of the store: no view of the table's width and
+    no slice of a ring at all), the census of every
     program is the pinned one, and the bf16 programs track the f32
     reference to bf16's rounding."""
     from quintnet_tpu import analysis
     from quintnet_tpu.analysis.specs import expected_serve_window_moe
 
-    eng = _engine(params, kv_dtype="bf16", weights_dtype="bf16")
+    # two slots: at three, the verify run's walk output [3, 48, 128]
+    # would share the store's shape, and the shape-keyed audits could
+    # not tell them apart
+    eng = _engine(params, kv_dtype="bf16", weights_dtype="bf16",
+                  max_slots=2)
     fam, pool = eng.family, eng.pool
     runs = CFG.runs
     geometry = dict(table_width=eng.table_width, block_size=BS)
@@ -373,6 +378,9 @@ def test_both_head_counts_keep_the_lane_diagonal_form(params):
             name
         assert analysis.store_reads(
             fn, *args, store_shape=pool.wk.shape) == want["store_reads"], name
+        assert analysis.row_walk_calls(
+            fn, *args, pool_shape=pool.wk.shape) == want["ring_walk_calls"], \
+            name
         assert analysis.view_head_splits(fn, *args, **geometry) == \
             want["view_head_splits"], name
         assert analysis.widened_view_dots(fn, *args, **geometry) == \

@@ -21,6 +21,7 @@ The guide's share test does not apply: every expert, every head and
 the whole vocabulary are held; the configuration is cut in depth alone.
 """
 
+import importlib
 import importlib.util
 import os
 
@@ -427,6 +428,9 @@ def test_seven_heads_a_kv_head_on_the_lane_diagonal(params):
             name
         assert analysis.store_reads(
             fn, *args, store_shape=pool.wk.shape) == want["store_reads"], name
+        assert analysis.row_walk_calls(
+            fn, *args, pool_shape=pool.wk.shape) == want["ring_walk_calls"], \
+            name
         assert analysis.view_head_splits(fn, *args, **geometry) == \
             want["view_head_splits"], name
         assert analysis.widened_view_dots(fn, *args, **geometry) == \
@@ -459,8 +463,10 @@ def test_the_engine_serves_what_the_reference_generates(params):
     64-wide chunks, 24 tokens each (past a ring's wrap): greedy tokens
     equal the reference's full forward; the rings' ledger is empty at
     the end, and the step ring carries the window's counters — the ring
-    rows READ (every slot's ring whole, idle slots' too) beside the live
-    ones."""
+    rows READ beside the live ones. This engine's store is f32, which
+    no walk takes: every slot's ring is sliced out whole, idle slots'
+    too, and counted so (a bf16 store's walk is counted in
+    test_the_ring_walk_reads_what_it_counts)."""
     eng = _engine(params)
     prompts = _prompts(8, [90, 5, 40, 70])
     rids = [eng.submit(p, 24) for p in prompts]
@@ -477,11 +483,48 @@ def test_the_engine_serves_what_the_reference_generates(params):
             continue
         a = rec["attrs"]
         assert a["global_rows"] == rec["context_tokens"]
+        assert eng._ring_read == RING
         assert a["window_read_rows"] == eng.max_slots * RING * 3
         assert 0 < a["window_rows"] <= a["window_read_rows"]
         assert a["decode_expert_rows"] == rec["decoding"] * 6 * 4
         seen += 1
     assert seen
+
+
+def test_the_ring_walk_reads_what_it_counts(params, monkeypatch):
+    """A bf16 store: the decode program's sliding layers walk each row's
+    ring in place (in the kernel's interpreter here), trips of 16
+    positions — two pages of 8, three trips a full ring of 40
+    (``RING_KEY_BLOCK`` 16) — so a
+    step's ``window_read_rows`` is, over the program's three rows, each
+    row's ``max(ceil(min(pos + 1, 40) / 16), 1)`` trips x 16 x 3 sliding
+    layers: one request decoding from position 5 to past the ring's
+    wrap at 40 beside two idle slots (a trip each). The same engine with
+    the interpreter off (the CPU's own form: every ring sliced out
+    whole) counts ``max_slots x 40 x 3`` and serves the same greedy
+    tokens."""
+    pa = importlib.import_module("quintnet_tpu.ops.paged_attention")
+    monkeypatch.setattr(attention, "RING_KEY_BLOCK", 16)
+    (ids,) = _prompts(11, [5])
+    outs, steps = [], 0
+    for interpret in (True, False):
+        monkeypatch.setattr(pa, "INTERPRET", interpret)
+        eng = _engine(params, kv_dtype="bf16", weights_dtype="bf16")
+        rid = eng.submit(ids, 50)
+        eng.run()
+        outs.append(list(eng.result(rid)))
+        kb = eng._ring_read
+        assert kb == (16 if interpret else RING)
+        for rec in eng.recorder.snapshot():
+            if not rec["decoding"]:
+                continue
+            assert rec["decoding"] == 1
+            pos = rec["context_tokens"]            # the one row's position
+            trips = max(-(-min(pos + 1, RING) // kb), 1) + eng.max_slots - 1
+            assert rec["attrs"]["window_read_rows"] == trips * kb * 3, pos
+            steps += interpret and pos >= RING
+    assert steps                  # the walk's count past a full ring
+    assert outs[0] == outs[1]
 
 
 def test_a_sequence_preempted_and_prefilled_again(params):

@@ -29,6 +29,19 @@ magnitude.
 
     chiprun -- python tools/attn_microbench.py \
         --decode xl,hybrid,window,latent --key-blocks 128,256,512
+
+``--rings CELL[,CELL]`` times a window cell's SLIDING layers' decode
+attention: one token a row written into the cell's bf16 window
+store, every ring of a layer sliced out whole and contracted on the
+lane diagonal (``gathered``: the form the walk replaced) against each row's
+walk of its own ring in place, at the key block ``--key-blocks`` gives
+the ring (``walk:<RING_KEY_BLOCK>``; ``key_block`` is the ring's, from
+``nn/attention._ring_key_block``). Rows that sit out (``lens`` 0) in
+the share the cell's traffic leaves them; ``read_rows`` is what the
+walk reads over what the decoding rows hold live.
+
+    python tools/attn_microbench.py --rings wide,window \
+        --key-blocks 128,256,512,1024      # on a TPU
 """
 
 from __future__ import annotations
@@ -99,6 +112,101 @@ DECODE_CELLS = {
                    lengths=(1024, 0.8, 128, 4096, 192), scale=576 ** -0.5),
 }
 BLOCK = 16
+
+# a window cell's sliding layers: slots, query heads a kv head, kv heads,
+# window, sliding layers, rows that decode in a step (the rest sit out),
+# positions drawn as the cell's traffic draws prompts
+RING_CELLS = {
+    "wide": dict(rows=128, g=7, hkv=4, window=4096, layers=3, decoding=72,
+                 lengths=(3072, 1.0, 128, 15360, 192)),
+    "window": dict(rows=48, g=8, hkv=8, window=512, layers=3, decoding=47,
+                   lengths=(4096, 0.7, 1024, 16384, 192)),
+}
+
+
+def _ring_lines(cell: str, key_blocks, iters: int):
+    """One line a form of ``cell``'s sliding-layer decode attention
+    (module docstring)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    import quintnet_tpu.nn.attention as attention
+
+    c = RING_CELLS[cell]
+    rows, g, hkv, window, layers = (c[n] for n in ("rows", "g", "hkv",
+                                                   "window", "layers"))
+    ring, dh = window + BLOCK, 128
+    rng = np.random.default_rng(40)
+    med, sigma, lo, hi, out = c["lengths"]
+    pos = (np.clip(rng.lognormal(np.log(med), sigma, rows), lo, hi)
+           + rng.integers(0, 2 * out, rows)).astype(np.int32)
+    live = np.zeros(rows, np.int32)
+    live[rng.permutation(rows)[:c["decoding"]]] = 1
+    pos = np.where(live > 0, pos, 0)
+    keys = jax.random.split(jax.random.key(0), 5)
+    shape = (layers, (rows + 1) * ring, hkv * dh)
+    wk, wv = (jax.random.normal(k, shape, jnp.bfloat16) for k in keys[:2])
+    q = jax.random.normal(keys[2], (rows, hkv, g, dh), jnp.float32)
+    k, v = (jax.random.normal(kk, (rows, hkv, 1, dh), jnp.float32)
+            for kk in keys[3:])
+    positions, lens = jnp.asarray(pos)[:, None], jnp.asarray(live)
+
+    def program(form):
+        def layer(l, carry):
+            wk, wv, acc = carry
+            if form == "gathered":
+                wk, wv = attention.window_write(
+                    wk, wv, l, k, v, positions, lens, 0, ring=ring)
+                held = attention._ring_positions(
+                    positions[:, 0] + lens - 1, ring)[:, None, None, :]
+                qp = positions[:, None, :, None]
+                valid = (held >= 0) & (held <= qp) & (held > qp - window)
+                o = attention._lane_diag_sdpa(
+                    q, attention.window_gather(wk, l, 0, rows, ring=ring)[
+                        :, None],
+                    attention.window_gather(wv, l, 0, rows, ring=ring)[
+                        :, None], valid, kv_heads=hkv)
+            else:
+                o, (wk, wv) = attention.window_attend(
+                    q, k, v, (wk, wv), l, positions, lens, 0, window=window,
+                    ring=ring, max_diag_rows=hkv * g)
+            return wk, wv, acc + o
+        return jax.jit(lambda wk, wv: jax.lax.fori_loop(
+            0, layers, layer, (wk, wv, jnp.zeros(q.shape, jnp.float32))),
+            donate_argnums=(0, 1))
+
+    held = np.minimum(pos + 1, ring)[live > 0]
+    ref = None
+    for form in ["gathered"] + [f"walk:{kb}" for kb in key_blocks]:
+        line = {"cell": cell, "form": form, "rows": rows,
+                "decoding": int(live.sum()), "ring": ring,
+                "device_kind": jax.devices()[0].device_kind}
+        try:
+            if form == "gathered":
+                line["read_rows"] = rows * ring / float(held.sum())
+            else:
+                attention.RING_KEY_BLOCK = int(form.split(":")[1])
+                kb = attention._ring_key_block(ring // BLOCK, BLOCK)
+                trips = np.maximum(-(-np.minimum(pos + 1, ring) // kb), 1)
+                trips = np.where(live > 0, trips, 1)
+                line["key_block"] = kb
+                line["read_rows"] = float((trips * kb).sum() / held.sum())
+            fn = program(form)
+            wk, wv, got = jax.block_until_ready(fn(wk, wv))
+            t0 = time.perf_counter()
+            for _ in range(iters):
+                wk, wv, _o = fn(wk, wv)
+            jax.block_until_ready(_o)
+            line["ms_a_layer"] = ((time.perf_counter() - t0) / iters * 1e3
+                                  / layers)
+            got = jnp.where(lens[:, None, None, None] > 0, got, 0)
+            ref = got if ref is None else ref
+            line["o_err"] = float(jnp.max(jnp.abs(got - ref))
+                                  / jnp.max(jnp.abs(ref)))
+        except Exception as e:  # noqa: BLE001 — a refused form is a row
+            line["error"] = f"{type(e).__name__}: {e}"[:400]
+        yield line
 
 
 def _decode_lines(cell: str, key_blocks, iters: int):
@@ -235,6 +343,9 @@ def main() -> int:
     ap.add_argument("--decode", default="",
                     help="serving cells whose decode attention to time "
                          f"instead: {','.join(DECODE_CELLS)}")
+    ap.add_argument("--rings", default="",
+                    help="window cells whose sliding layers' decode "
+                         f"attention to time instead: {','.join(RING_CELLS)}")
     ap.add_argument("--key-blocks", default="128,256,512")
     args = ap.parse_args()
 
@@ -245,11 +356,13 @@ def main() -> int:
         print("attn_microbench: no TPU; a time from any other platform "
               "is not a device number", file=sys.stderr)
         return 3
-    if args.decode:
-        for cell in args.decode.split(","):
-            for line in _decode_lines(
-                    cell, [int(n) for n in args.key_blocks.split(",")],
-                    args.iters):
+    if args.decode or args.rings:
+        kbs = [int(n) for n in args.key_blocks.split(",")]
+        for cell in filter(None, args.decode.split(",")):
+            for line in _decode_lines(cell, kbs, args.iters):
+                print(json.dumps(line), flush=True)
+        for cell in filter(None, args.rings.split(",")):
+            for line in _ring_lines(cell, kbs, args.iters):
                 print(json.dumps(line), flush=True)
         return 0
     b, h, s, d = (int(x) for x in args.shape.split(","))

@@ -20,7 +20,8 @@ read, per program,
   slot's ring is one a layer and buffer, PR 39);
 - every call of the per-row walk (``paged_walk_attention``: decode
   and verify on a bf16/f16 pool read each row's live blocks of the
-  carried pool in place) with what FEEDS its pool-sized operands: the
+  carried pool, or its ring of a window store, in place) with what
+  FEEDS its pool-sized operands (and their dims): the
   loop's carry or the in-place ``kv_write`` scatter is the pool itself;
   anything else — a ``copy``, a slice of a layer, a fusion outside
   ``kv_write`` — is a layer- or view-sized copy next to the call, and
@@ -118,7 +119,8 @@ def read_hlo(text: str, buffers: dict, layer_bytes: int) -> dict:
         shape = _first_shape(result)
         scope = re.search(r'op_name="([^"]*)"', line)
         made[name] = (op, "/".join(scope.group(1).split("/")[-2:])
-                      if scope else "", shape[3] if shape else 0)
+                      if scope else "", shape[3] if shape else 0,
+                      list(shape[1]) if shape else [])
         if op == "custom-call" and "paged_walk_attention" in line:
             walks.append((name, comp, re.findall(
                 r"%([\w.\-]+)", line.split("custom-call(", 1)[1]
@@ -143,7 +145,8 @@ def read_hlo(text: str, buffers: dict, layer_bytes: int) -> dict:
                            "MB": round(nbytes / 1e6, 1)})
     row_walks = []
     for name, where, operands in walks:
-        fed = [{"operand": o, "op": made[o][0], "scope": made[o][1]}
+        fed = [{"operand": o, "op": made[o][0], "scope": made[o][1],
+                "dims": made[o][3]}
                for o in operands if made.get(o, ("", "", 0))[2] >= layer_bytes]
         row_walks.append({
             "call": name, "in": where, "pool_operands": fed,
