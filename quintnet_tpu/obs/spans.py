@@ -52,6 +52,7 @@ from typing import Callable, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from quintnet_tpu.obs import recorder
 
@@ -60,7 +61,7 @@ from quintnet_tpu.obs import recorder
 #             block allocation, grow/preempt (scheduler + kv_pool)
 #   prefill   host side of an admission's prefill (ids, bucket, table
 #             row, adapter binding), less its upload/dispatch/wait
-#   upload    jnp.asarray of the host arrays a program takes
+#   upload    jnp.asarray of a copy of the host arrays a program takes
 #   dispatch  the call of the jitted program until it returns
 #   wait      every blocking device-to-host read
 #   commit    the per-slot walk: append, callbacks, retire, metrics
@@ -177,10 +178,13 @@ class StepPhases(Phases):
         return self._mark
 
     def upload(self, *arrays):
-        """The host arrays as device arrays, counted in ``h2d_bytes``."""
+        """The host arrays as device arrays, counted in ``h2d_bytes``.
+        Copies: on the CPU a device array may share a host array's
+        memory, and the engine writes its host arrays again before the
+        step's one read, while the programs may still be reading."""
         with self.phase("upload"):
             self.h2d_bytes += sum(a.nbytes for a in arrays)
-            return tuple(jnp.asarray(a) for a in arrays)
+            return tuple(jnp.asarray(np.array(a)) for a in arrays)
 
     def wait(self, reads: int):
         """The phase around ``reads`` blocking device-to-host reads,

@@ -125,7 +125,16 @@ weights and the Pallas kernel are refused at construction
 generated`` from position 0 through the chunk program.
 
 All host<->device traffic per step is O(max_slots) scalars plus the
-sampled tokens — the pool and parameters never leave the device. Under
+sampled tokens — the pool and parameters never leave the device. A step
+reads back ONCE, after its last dispatch (:meth:`ServeEngine._fetch`):
+the decode's tokens and keys, the first token and key of every row whose
+prefill ended in the step, and every program's routing stats, in one
+``jax.device_get``. Such a row rides the step's decode all the same: its
+first token and key are written into the decode's rows ON the device
+(``serve_join_row``), and the host commits the first token before the
+decode's. An engine that speculates reads a first token at once — its
+drafter needs it before the verify — and so does one under a mesh,
+whose prefill outputs and uploaded rows are placed apart. Under
 a TP mesh the whole step runs in one shard_map (head-sharded pool,
 RowParallel psum per layer, replicated tokens), exactly the
 ``gpt2_generate_tp`` arrangement.
@@ -134,6 +143,7 @@ RowParallel psum per layer, replicated tokens), exactly the
 from __future__ import annotations
 
 import time
+from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -378,6 +388,20 @@ def _refuse_for_window(family: Family, **asked) -> None:
                 f"its blocks; {what} is refused: {why} (ROADMAP M2, M5)")
 
 
+@dataclass
+class _FirstToken:
+    """A row whose prefill ended in this step, its first token and key
+    still on the device until the step's read (``ServeEngine._fetch``).
+    ``keeps_slot``: the row holds its slot and rides the step's decode;
+    False for a row known to end on its first token, whose slot was
+    given back at the prefill."""
+    slot: int
+    req: Request
+    tok0: jax.Array
+    key2: jax.Array
+    keeps_slot: bool
+
+
 class ServeEngine:
     @setup_span("build")
     def __init__(self, family: Family, params, *, max_slots: int = 8,
@@ -512,6 +536,9 @@ class ServeEngine:
         moe = getattr(family.cfg, "moe_args", None)
         self.moe_args = moe
         self._moe_on = moe is not None
+        # routing stats of the step's programs: on the device until the
+        # step's read (_moe_dev), then on the host until folded (_moe_acc)
+        self._moe_dev: List[Dict] = []
         self._moe_acc: List[Dict] = []
         self.ep_axis: Optional[str] = None
         if moe is not None:
@@ -841,6 +868,22 @@ class ServeEngine:
         self._rid_counter = 0
         self._arrival_counter = 0
         self._admissions_paused = False
+        # rows whose prefill ended in this step, their first tokens still
+        # on the device; and the program that writes one such row's
+        # token and key into the decode's uploaded rows — the slot a
+        # traced scalar (one program for every slot), the rows donated
+        self._firsts: List[_FirstToken] = []
+
+        def serve_join_row(tok, key_data, slot, tok0, key2):
+            return tok.at[slot].set(tok0), key_data.at[slot].set(key2)
+
+        self._join_row = jax.jit(serve_join_row, donate_argnums=(0, 1))
+        # where a row's first token is read as its prefill ends instead:
+        # a drafter reads every row's tokens before the verify, and
+        # under a mesh the prefill's outputs live on the mesh while the
+        # decode's rows are uploaded to one device — joined, the decode
+        # would meet inputs placed as it was never compiled for
+        self._join_on_device = self.drafter is None and mesh is None
 
         # the bounded-compile promise, enforced at call time: every
         # bucket (and the decode step) carries its own sentinel with
@@ -1427,7 +1470,9 @@ class ServeEngine:
         """The (packed tree, scales) argument pair for one program
         call, viewed/sliced from the device-resident masters and cached
         until a binding changes (slices are device-side copies — no
-        host traffic on rebuild):
+        host traffic on rebuild; the scales are uploaded from a copy, as
+        ``StepPhases.upload`` does, since a binding rewrites the host
+        array while a program of the step may still read it):
 
         - ``decode``: full [S]-slot pack at ``rank_bucket`` width (the
           top bucket passes the masters through unsliced);
@@ -1443,7 +1488,7 @@ class ServeEngine:
                         for p, d in self._lora_dev.items()}
                 self._lora_args_cache[key] = (
                     nest(flat),
-                    jnp.asarray(self._lora_scale[slot:slot + 1]))
+                    jnp.asarray(self._lora_scale[slot:slot + 1].copy()))
             return self._lora_args_cache[key]
         R = (rank_bucket if kind == "decode" else self.lora_max_rank)
         key = (kind, R)
@@ -1454,8 +1499,8 @@ class ServeEngine:
                 flat = {p: {"a": d["a"][..., :R],
                             "b": d["b"][:, :, :R, :]}
                         for p, d in self._lora_dev.items()}
-            self._lora_args_cache[key] = (nest(flat),
-                                          jnp.asarray(self._lora_scale))
+            self._lora_args_cache[key] = (
+                nest(flat), jnp.asarray(self._lora_scale.copy()))
         return self._lora_args_cache[key]
 
     # ------------------------------------------------------------------
@@ -1688,6 +1733,10 @@ class ServeEngine:
         req = self._slot_req[slot]
         self._release_slot_blocks(slot)
         self._clear_slot(slot)
+        return self._finish(req)
+
+    def _finish(self, req: Request) -> int:
+        """The request's end, once its slot is given back."""
         req.state = FINISHED
         req.finish_time = self.clock()
         self.metrics.record_finish(req.finish_time - req.submit_time,
@@ -1845,10 +1894,9 @@ class ServeEngine:
                               preemptions=req.preemptions)
         self.scheduler.push_front(req)
 
-    def _append_token(self, slot: int, token: int) -> bool:
+    def _append_token(self, req: Request, token: int) -> bool:
         """Record one sampled token; returns True when the request is
         done (EOS or token budget)."""
-        req = self._slot_req[slot]
         req.generated.append(int(token))
         if req.adapter_id is not None:
             self.metrics.record_adapter_token(req.adapter_id)
@@ -1951,17 +1999,92 @@ class ServeEngine:
         """Split the trailing routing-stats dict off a MoE program's
         pool outputs (serve/families.py widens every MoE program's
         return by one) and bank it for the step ledger, marked with
-        whether the DECODE program produced it. Dense families pass
-        through untouched; warmup calls pass ``note=False`` so
-        compile-time probes never pollute the serving numbers."""
+        whether the DECODE program produced it — on the device, its copy
+        to the host started, read with the step's other outputs
+        (:meth:`_fetch`). Dense families pass through untouched; warmup
+        calls pass ``note=False`` so compile-time probes never pollute
+        the serving numbers."""
         if not self._moe_on:
             return pools
         *pools, st = pools
         if note:
-            with self._phases.wait(1):
-                self._moe_acc.append(
-                    {**jax.tree.map(np.asarray, st), "decode": decode})
+            for leaf in jax.tree.leaves(st):
+                leaf.copy_to_host_async()
+            self._moe_dev.append({**st, "decode": decode})
         return tuple(pools)
+
+    # ------------------------------------------------------------------
+    # the step's one read
+    # ------------------------------------------------------------------
+    def _fetch(self, outs=None):
+        """ONE blocking device-to-host read, one ``host_sync``: ``outs``
+        (the decode's or the verify's outputs), the first token and key
+        of every row whose prefill ended since the last read, and the
+        routing stats banked since (to ``_moe_acc``). Returns ``outs``
+        and ``[(first, tok0, key2)]`` on the host; commits nothing."""
+        firsts, self._firsts = self._firsts, []
+        stats, self._moe_dev = self._moe_dev, []
+        with self._phases.wait(1):
+            outs, got, stats = jax.device_get(
+                (outs, [(f.tok0, f.key2) for f in firsts], stats))
+        self._moe_acc.extend(stats)
+        return outs, [(f, int(t), np.array(k))
+                      for f, (t, k) in zip(firsts, got)]
+
+    def _prefill_ended(self, slot: int, req: Request, tok0, key2,
+                       finished: List[int]) -> None:
+        """The prefill of ``slot``'s request ended with its first token
+        ``tok0`` and key ``key2``, both on the device: queue them for
+        the step's read. A row known to end on that token (a one-token
+        budget, the prefill-only phase) gives its slot back now, blocks
+        published, as it always did — the next admission may take
+        both; it finishes at the read. Every other row keeps its slot
+        and rides this step's decode, joined on the device. An engine
+        that speculates, or runs under a mesh, reads at once
+        (``_join_on_device``)."""
+        self.metrics.record_admit()
+        tok0.copy_to_host_async()
+        key2.copy_to_host_async()
+        keeps = not (req.prefill_only or req.remaining_new_tokens <= 1)
+        if not keeps:
+            self._release_slot_blocks(slot)
+            self._clear_slot(slot)
+        self._firsts.append(_FirstToken(slot, req, tok0, key2, keeps))
+        if not self._join_on_device:
+            _, firsts = self._fetch()
+            with self._phases.phase("commit"):
+                self._commit_firsts(firsts, finished)
+
+    def _commit_firsts(self, firsts, finished: List[int]) -> List[int]:
+        """Commit the first tokens :meth:`_fetch` read, in the order the
+        prefills ended: append (EOS and the token budget end the
+        request), the prefill-only handoff, the slot's token and key.
+        Returns the slots whose row ended on its first token while it
+        held the slot (an EOS): retired at its prompt's length, so the
+        published chain holds committed positions only, and whatever
+        the step's decode computed for it is dropped."""
+        ended = []
+        for f, tok0, key2 in firsts:
+            req = f.req
+            done = self._append_token(req, tok0)
+            if not done and req.prefill_only:
+                # disaggregated prefill phase: the first token is
+                # committed and emitted with its REAL last flag above
+                # (max_new was never capped, so EOS and one-token
+                # budgets retired via ``done``); what remains is
+                # decode-pool work, and the chain its blocks published
+                # is exactly the handoff payload export_kv_chain ships
+                req.handed_off = True
+                done = True
+            if not f.keeps_slot:
+                finished.append(self._finish(req))
+                continue
+            self._tok[f.slot] = tok0
+            self._key_data[f.slot] = key2
+            if done:
+                ended.append(f.slot)
+                finished.append(self._retire(f.slot))
+        return ended
 
     def _drain_moe(self) -> Dict[str, object]:
         """Fold the routing stats banked since the last step boundary
@@ -2016,11 +2139,12 @@ class ServeEngine:
                 axis=0) if dec else np.zeros((held,)))
         return out
 
-    def _admit_one(self, slot: int, req: Request) -> Tuple[int, int]:
+    def _admit_one(self, slot: int, req: Request,
+                   finished: List[int]) -> Tuple[int, int]:
         """Admit ``req`` into ``slot``: reuse the longest cached prefix
         chain, prefill only the uncached tail in the smallest bucket
-        that holds it. Returns (tail tokens prefilled, cached tokens
-        reused)."""
+        that holds it (the first token: :meth:`_prefill_ended`).
+        Returns (tail tokens prefilled, cached tokens reused)."""
         ph = self._phases
         t0 = req.total_len
         tokens = req.output_ids()
@@ -2057,30 +2181,12 @@ class ServeEngine:
             if plan.cow_src is not None:
                 # the COW source was pinned only for the copy above
                 self.pool.release([plan.cow_src])
-            with ph.wait(2):
-                self._key_data[slot] = np.asarray(key2)
-                tok0 = int(tok0)
-            self._tok[slot] = tok0
             self._pos[slot] = t0
-        with ph.phase("commit"):
-            self.metrics.record_admit()
             if self.tracer is not None:
                 self.tracer.event(req.trace_id, "prefill",
                                   tokens=len(tail), bucket=bucket,
                                   start=int(start))
-            done = self._append_token(slot, tok0)
-            if not done and req.prefill_only:
-                # disaggregated prefill phase: the first token is
-                # committed and emitted with its REAL last flag above
-                # (max_new was never capped, so EOS and one-token
-                # budgets retired via ``done``); what remains is
-                # decode-pool work. Retire with blocks PUBLISHED — the
-                # published chain is exactly the handoff payload
-                # export_kv_chain ships.
-                req.handed_off = True
-                done = True
-            if done:
-                self._retire(slot)
+            self._prefill_ended(slot, req, tok0, key2, finished)
         return len(tail), start
 
     # ------------------------------------------------------------------
@@ -2124,7 +2230,8 @@ class ServeEngine:
         cache tail uses, at dynamic offset ``st.next``. Intermediate
         chunks discard the program's sampled token and split key (the
         chain must advance exactly once per prefill); the final chunk
-        adopts both, exactly like a single-shot admission."""
+        adopts both, exactly like a single-shot admission
+        (:meth:`_prefill_ended`)."""
         ph = self._phases
         with ph.phase("prefill"):
             tokens = req.output_ids()
@@ -2159,21 +2266,7 @@ class ServeEngine:
             if not st.done:
                 return  # intermediate chunk: tok0/key2 discarded
             self._slot_chunk[slot] = None
-            with ph.wait(2):
-                self._key_data[slot] = np.asarray(key2)
-                tok0 = int(tok0)
-            self._tok[slot] = tok0
-        with ph.phase("commit"):
-            self.metrics.record_admit()
-            done = self._append_token(slot, tok0)
-            if not done and req.prefill_only:
-                # same handoff retirement as the single-shot path in
-                # _admit_one — a chunked prefill-phase request hands
-                # off after its final chunk commits the first token
-                req.handed_off = True
-                done = True
-            if done:
-                finished.append(self._retire(slot))
+            self._prefill_ended(slot, req, tok0, key2, finished)
 
     def _feed_chunks(self, finished: List[int]) -> Tuple[int, int]:
         """Stream queued chunk work through the bucket programs — at
@@ -2203,12 +2296,16 @@ class ServeEngine:
                 break
         return tokens_done, chunks
 
-    def _grow_or_preempt(self) -> None:
+    def _grow_or_preempt(self, finished: List[int]) -> None:
         """Ensure every active slot holds the block its next write
         position needs; evict the youngest admission when the pool is
         dry (the allocator transparently evicts LRU cached blocks
         before that). Oldest requests are grown first so eviction
-        pressure lands on the youngest (least sunk work)."""
+        pressure lands on the youngest (least sunk work). Before it
+        evicts, the first tokens still on the device are read and
+        committed: the youngest is likely a row admitted this step,
+        whose checkpoint needs them, and a first token that ends its
+        request frees its blocks instead."""
         order = sorted(self._active_slots(),
                        key=lambda s: self._slot_req[s].admit_seq)
         for slot in order:
@@ -2220,6 +2317,11 @@ class ServeEngine:
                 if got is not None:
                     self._tables[slot][len(self._slot_blocks[slot])] = got[0]
                     self._slot_blocks[slot].extend(got)
+                    continue
+                if self._firsts:
+                    _, firsts = self._fetch()
+                    with self._phases.phase("commit"):
+                        self._commit_firsts(firsts, finished)
                     continue
                 running = [self._slot_req[s] for s in self._active_slots()]
                 victim = Scheduler.preempt_victim(running)
@@ -2318,10 +2420,9 @@ class ServeEngine:
         attended = self._attended_rows(self._verifies[k_bucket],
                                        starts + k_bucket)
         self.pool.update(*self._pop_moe(pools))
-        with ph.wait(2):
-            toks = np.asarray(toks)
-            chain = np.asarray(chain)
+        (toks, chain), firsts = self._fetch((toks, chain))
         with ph.phase("commit"):
+            self._commit_firsts(firsts, finished)
             return (*self._commit_verified(active, drafts, tentative, toks,
                                            chain, finished), attended)
 
@@ -2344,7 +2445,7 @@ class ServeEngine:
             c = 0
             done = False
             while c <= a and not done:
-                done = self._append_token(slot, int(t[c]))
+                done = self._append_token(self._slot_req[slot], int(t[c]))
                 c += 1
             self._tok[slot] = int(t[c - 1])
             self._pos[slot] = pos0 + c
@@ -2441,11 +2542,9 @@ class ServeEngine:
                 if self.chunked_prefill:
                     prefix_hit_tokens += self._admit_slot_chunked(slot, req)
                 else:
-                    tail, hit = self._admit_one(slot, req)
+                    tail, hit = self._admit_one(slot, req, finished)
                     prefill_tokens += tail
                     prefix_hit_tokens += hit
-                    if self._slot_req[slot] is None:  # instant retire
-                        finished.append(req.rid)
 
             # 1b. chunk feed (chunked mode): at most prefill_chunk_budget
             # prompt tokens through the bucket programs this step — the
@@ -2458,7 +2557,7 @@ class ServeEngine:
                 prefill_tokens += fed
 
             # 2. block growth / preemption for the upcoming writes
-            self._grow_or_preempt()
+            self._grow_or_preempt(finished)
 
         # 3. one decode step for every GENERATING slot (mid-prefill
         # slots sit out — their first token comes from their final
@@ -2497,7 +2596,7 @@ class ServeEngine:
             len(decoding) + (prefill_chunks if self.chunked_prefill
                              else m.admitted - rec_admitted0))
         decode_tokens = 0
-        draft_tokens = accepted_draft = attended_rows = 0
+        draft_tokens = accepted_draft = attended_rows = joined_rows = 0
         spec_step = False
         if decoding:
             drafts = self._propose_drafts(decoding)
@@ -2534,35 +2633,57 @@ class ServeEngine:
                         tok[s] = 0
                         pos[s] = 0
                         tables[s] = 0
-                args = ph.upload(tok, pos, tables, self._key_data)
+                d_tok, *args, d_keys = ph.upload(tok, pos, tables,
+                                                 self._key_data)
+                # rows whose prefill ended this step: first token and
+                # key into the decode's rows on the device
+                joined = [f for f in self._firsts if f.keeps_slot]
+                joined_rows = len(joined)
+                if joined:
+                    slots = ph.upload(*(np.int32(f.slot) for f in joined))
+                    with ph.phase("dispatch"):
+                        for f, s in zip(joined, slots):
+                            d_tok, d_keys = self._join_row(
+                                d_tok, d_keys, s, f.tok0, f.key2)
                 with ph.phase("dispatch"):
                     *pools, nxt, key2 = sentinel(
-                        self.params, *self.pool.caches(), *args, *extra)
+                        self.params, *self.pool.caches(), d_tok, *args,
+                        d_keys, *extra)
                 attended_rows = self._attended_rows(sentinel, pos)
                 self.pool.update(*self._pop_moe(pools, decode=True))
-                with ph.wait(2):
-                    nxt = np.asarray(nxt)
-                    key2 = np.array(key2)
+                (nxt, key2), firsts = self._fetch((nxt, key2))
+                key2 = np.array(key2)
                 with ph.phase("commit"):
-                    for s in prefilling:
+                    ended = self._commit_firsts(firsts, finished)
+                    for s in prefilling + ended:
                         # a mid-prefill slot's chain must not advance —
-                        # its one split happens on its final chunk
+                        # its one split happens on its final chunk; a
+                        # row that ended on its first token keeps the
+                        # prefill's key
                         key2[s] = self._key_data[s]
                     self._key_data = key2
                     for slot in decoding:
+                        if slot in ended:
+                            continue
                         token = int(nxt[slot])
                         self._tok[slot] = token
                         self._pos[slot] += 1
                         decode_tokens += 1
+                        req = self._slot_req[slot]
                         if self.tracer is not None:
                             self.tracer.event(
-                                self._slot_req[slot].trace_id, "decode",
+                                req.trace_id, "decode",
                                 token=token, pos=int(self._pos[slot]))
-                        if self._append_token(slot, token):
+                        if self._append_token(req, token):
                             finished.append(self._retire(slot))
                     if self.kv_tier is not None:
                         self._decode_blocked_demotions += (
                             self.kv_tier.demotions - demo0)
+        if self._firsts or self._moe_dev:
+            # a step that decoded nothing reads what its prefills left
+            _, firsts = self._fetch()
+            with ph.phase("commit"):
+                self._commit_firsts(firsts, finished)
 
         # 4. metrics — MoE families additionally drain the routing
         # stats their programs returned this step (per-expert demand,
@@ -2628,7 +2749,10 @@ class ServeEngine:
                 h2d_bytes=ph.h2d_bytes, context_tokens=context_tokens,
                 state_bytes=state_bytes,
                 attrs={**window_attrs,
-                       **({"attended_rows": attended_rows}
+                       # joined_rows: rows whose first token reached the
+                       # decode on the device (the step's one read)
+                       **({"attended_rows": attended_rows,
+                           "joined_rows": joined_rows}
                           if decoding else {}),
                        # the step that recompiled, and what (the
                        # compile listener of obs/spans.py filled it)
@@ -2688,7 +2812,8 @@ class ServeEngine:
         slot, or metric state is touched. Sizing warmup *prompts* to
         hit each bucket cannot cover the largest bucket when
         ``prefill_len`` sits within the admission margin of the
-        previous one; calling the programs directly can.
+        previous one; calling the programs directly can. The row-join
+        (``serve_join_row``) is compiled here too, where it can run.
 
         The whole is a ``qn.setup.warmup`` span on the start-up record
         (obs/spans.py) and each program's call a child of it, from the
@@ -2701,10 +2826,22 @@ class ServeEngine:
             # be the first compile
             with warmup_program(self._pack_update.__name__):
                 self._apply_pack_update(0, self._zero_slot_update())
+        first = None
         for sentinel, args in self._warmup_calls():
             with warmup_program(sentinel.fn.__name__):
-                *pools, _tokens, _keys = sentinel(*args)
+                *pools, tokens, keys = sentinel(*args)
             self.pool.update(*self._pop_moe(pools, note=False))
+            first = first or (tokens, keys)     # a prefill's: it is first
+        if self._join_on_device:
+            self._warmup_join(*first)
+
+    def _warmup_join(self, tok0, key2) -> None:
+        """Compile the row-join, fed a prefill's first token and key as
+        a step feeds it."""
+        with warmup_program(self._join_row.__name__):
+            self._join_row(jnp.asarray(self._tok),
+                           jnp.asarray(self._key_data),
+                           jnp.asarray(np.int32(0)), tok0, key2)
 
     def program_texts(self) -> List[str]:
         """The compiled text of every program (a second lowering: a

@@ -916,14 +916,18 @@ def test_a_pure_decode_step_counts_two_syncs_and_four_uploads(params):
     eng.step()                            # admits both: not pure
     first = eng.recorder.last()
     assert first["admitted"] == 2
-    # two reads per admission (key, first token) + two for the decode
-    assert first["host_syncs"] == 2 * 2 + 2
+    # one read a step (ServeEngine._fetch): both first tokens with the
+    # decode's tokens and keys, both rows joined to the decode on the
+    # device
+    assert first["host_syncs"] == 1
+    assert first["attrs"]["joined_rows"] == 2
     four = sum(a.nbytes for a in (eng._tok, eng._pos, eng._tables,
                                   eng._key_data))
     eng.step()
     pure = eng.recorder.last()
     assert pure["admitted"] == 0 and pure["decoding"] == 2
-    assert pure["host_syncs"] == 2
+    assert pure["host_syncs"] == 1
+    assert pure["attrs"]["joined_rows"] == 0
     assert pure["h2d_bytes"] == four
     assert "prefill" not in pure["phases"]
 

@@ -31,13 +31,13 @@ COMPILED = ("trace_s", "lower_s", "compile_or_load_s")
 
 # sampled tokens (temperature 0.8, top-k 5, key 100 + i) of the golden
 # prompts from the parent tree (commit fc43095), and the blocking reads
-# of each of its steps: the record changes neither
+# of each of its steps — one a step, every step dispatches (the engine
+# gathers a step's reads into one): the record changes neither
 GOLDEN_SAMPLED = [
     [14, 59, 95, 64, 95, 88, 64, 95], [15, 15, 64, 64, 77, 15, 95, 64],
     [117, 64, 15, 77, 77, 95, 59, 15], [88, 59, 59, 119, 64, 14, 103, 14],
     [15, 95, 115, 64, 95, 117, 95, 117]]
-GOLDEN_HOST_SYNCS = [6, 2, 2, 2, 2, 2, 2, 6, 2, 2, 2, 2, 2, 2, 4, 2, 2, 2,
-                     2, 2, 2]
+GOLDEN_HOST_SYNCS = [1] * 21
 
 
 @pytest.fixture(scope="module")
@@ -231,9 +231,12 @@ def test_warmup_yields_one_child_a_program_with_its_compile_split(
     assert [k["name"] for k in kids] == [
         "qn.setup.warmup/jit_serve_prefill_b16",
         "qn.setup.warmup/jit_serve_prefill_b48",
-        "qn.setup.warmup/jit_serve_decode"]
-    assert sorted(k["name"].split("/")[1] for k in kids) == [
-        "jit_" + n for n in eng.recorder.static["programs"]]
+        "qn.setup.warmup/jit_serve_decode",
+        "qn.setup.warmup/jit_serve_join_row"]
+    # every program a sentinel keeps, and the row-join
+    assert sorted(k["name"].split("/")[1] for k in kids) == sorted(
+        ["jit_" + n for n in eng.recorder.static["programs"]]
+        + ["jit_serve_join_row"])
     for k in kids:
         a = k["attrs"]
         assert all(a[key] > 0 for key in COMPILED), a
@@ -246,7 +249,7 @@ def test_warmup_yields_one_child_a_program_with_its_compile_split(
     assert warm["t1"] - warm["t0"] == pytest.approx(
         warm["exclusive_s"] + sum(k["t1"] - k["t0"] for k in kids))
     # an empty cache: this process compiled, and says so
-    assert sum(k["attrs"].get("cache_misses", 0) for k in kids) == 3
+    assert sum(k["attrs"].get("cache_misses", 0) for k in kids) == 4
 
 
 def test_a_second_warmup_of_the_same_engine_compiles_nothing(params, record):
@@ -259,7 +262,8 @@ def test_a_second_warmup_of_the_same_engine_compiles_nothing(params, record):
         kids = [s for s in spans_ if s["parent"] == warm["id"]]
         assert [k["name"] for k in kids] == [
             "qn.setup.warmup/jit_serve_prefill_b48",
-            "qn.setup.warmup/jit_serve_decode"]
+            "qn.setup.warmup/jit_serve_decode",
+            "qn.setup.warmup/jit_serve_join_row"]
         for k in kids:
             assert bool(k["attrs"]) is compiled, k
     assert eng.compile_stats() == {"prefill": 1, "decode": 1}
@@ -272,7 +276,7 @@ def test_a_second_engine_appends_to_the_record(params, record):
     two = _engine(params, max_slots=3, prefill_bucket_sizes=(48,))
     two.warmup()
     names = [s["name"] for s in record.snapshot()["spans"]]
-    assert names[:n] == names[n:] and n == 4
+    assert names[:n] == names[n:] and n == 5
     assert names.count("qn.setup.build") == 2
     assert record.dropped == 0
     del one, two
@@ -286,7 +290,9 @@ def test_the_pack_update_program_is_a_child_of_warmup_too(params, record):
     kids = [s["name"] for s in snap["spans"] if s["parent"] == warm["id"]]
     assert kids[0] == "qn.setup.warmup/jit_serve_pack_update"
     assert all(k.startswith("qn.setup.warmup/jit_serve_") for k in kids)
-    assert len(kids) == 1 + len(eng.recorder.static["programs"])
+    # and last the row-join, which no sentinel keeps
+    assert kids[-1] == "qn.setup.warmup/jit_serve_join_row"
+    assert len(kids) == 2 + len(eng.recorder.static["programs"])
 
 
 def test_a_step_that_compiles_says_so_and_the_next_does_not(params, record):
@@ -296,8 +302,10 @@ def test_a_step_that_compiles_says_so_and_the_next_does_not(params, record):
     eng = _engine(params, prefill_bucket_sizes=(16, 48))
     for sentinel, args in eng._warmup_calls():      # all but bucket 48
         if sentinel.fn.__name__ != "serve_prefill_b48":
-            *pools, _t, _k = sentinel(*args)
+            *pools, tok, key = sentinel(*args)
             eng.pool.update(*eng._pop_moe(pools, note=False))
+            if sentinel.fn.__name__ == "serve_prefill_b16":
+                eng._warmup_join(tok, key)
     eng.submit(_golden_prompts()[4][:5], 4)         # bucket 16: warm
     eng.step()
     assert "compiled" not in eng.recorder.last()["attrs"]
@@ -316,9 +324,9 @@ def test_a_step_that_compiles_says_so_and_the_next_does_not(params, record):
     assert after["totals"]["compile_or_load_s"] \
         > before["totals"]["compile_or_load_s"]
     assert len(after["spans"]) == len(before["spans"])
-    # the blocking reads of the step are what they were: two an
-    # admission, two for the decode
-    assert rec["host_syncs"] == 2 * 1 + 2
+    # the step reads back once, its admission's first token with the
+    # decode's outputs
+    assert rec["host_syncs"] == 1
     eng.step()
     assert "compiled" not in eng.recorder.last()["attrs"]
     eng.run()
@@ -496,6 +504,7 @@ def test_sampled_tokens_and_blocking_reads_are_the_parents(params):
     # cold programs here: the two steps that met them say so
     compiled = [r["attrs"].get("compiled", []) for r in ring]
     assert sorted(sum(compiled, [])) == ["jit_serve_decode",
+                                         "jit_serve_join_row",
                                          "jit_serve_prefill_b16"]
     assert all(not c for c in compiled[1:])
 
