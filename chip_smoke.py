@@ -33,9 +33,11 @@ seconds it spent compiling or loading compiled programs):
 - **kernels** — ``nn/attention.local_attention`` at seq 1024 and 8192
   (12 heads, Dh 64, causal: the fused kernel's resident and streamed
   geometries, as the chooser picks them) forward and backward against
-  ``blockwise_attention``; and a
-  second ``ServeEngine(attn_kernel="pallas")`` answering two of the
-  serve prompts, its logits against the first engine's. Both must
+  ``blockwise_attention``; and the serve phase's engine's decode
+  program, which must walk each row's live blocks of the pool
+  (ops/paged_attention.py), with the paged logits over the first
+  tokens of two serve prompts — few enough query rows that
+  ``Family.verify`` walks too — against the dense forward. Both must
   show a ``tpu_custom_call`` in the lowered program: a kernel phase
   that ran the reference is a failure.
 
@@ -91,8 +93,6 @@ REPO = os.path.dirname(os.path.abspath(__file__))
 TOL = {
     # paged (bf16 KV pool) vs dense f32 forward, same params
     "paged_vs_dense_logits": 0.05,
-    # Pallas paged kernel (f32 dots) vs XLA gathered view (default dots)
-    "pallas_vs_xla_logits": 0.05,
     # tp=2 engine vs mesh-less engine (the psum reassociates the sums)
     "tp_vs_single_logits": 0.05,
     # bf16 flash kernel vs f32 blockwise reference, as a share of the
@@ -125,8 +125,7 @@ class Size:
     prompt_lens: Tuple[int, ...] = (16, 48, 100, 170, 260, 390, 540, 700)
     max_new: int = 32
     dense_check: Tuple[int, int] = (1, 6)    # prompts compared with dense
-    kernel_check: Tuple[int, int] = (0, 2)   # prompts the Pallas engine serves
-    pallas_prefill_len: int = 128       # its prefill window (chunked beyond)
+    kernel_check: Tuple[int, int] = (0, 2)   # prompts the walk scores
     flash_seqs: Tuple[int, ...] = (1024, 8192)  # resident, streamed
 
 
@@ -338,8 +337,7 @@ def paged_logits(engine, prompts, width: int):
         return fam.verify(
             params, caches[0], caches[1], ids, starts, tail, tbl,
             pool.block_size, tp_axis=engine.tp_axis,
-            kv_scales=caches[2:] if scaled else None, policy=pool.policy,
-            attn_kernel=engine.attn_kernel)
+            kv_scales=caches[2:] if scaled else None, policy=pool.policy)
 
     if engine.mesh is None:
         fn = jax.jit(body)
@@ -361,6 +359,18 @@ def paged_logits(engine, prompts, width: int):
     for blocks in held:
         pool.release(blocks)
     return out[0].astype(jnp.float32)
+
+
+def dense_logits(size: Size, params, prompts, width: int):
+    """A plain dense ``gpt2_apply`` of ``prompts`` right-padded to
+    ``width``. Returns [S, width, V] float32."""
+    import jax
+    import jax.numpy as jnp
+
+    from quintnet_tpu.models.gpt2 import gpt2_apply
+
+    return jax.jit(lambda pr, ids: gpt2_apply(pr, ids, size.cfg))(
+        params, jnp.asarray(pad_rows(prompts, width))).astype(jnp.float32)
 
 
 def logits_gap(a, b, prompts) -> Dict:
@@ -417,12 +427,11 @@ def _get(host: str, port: int, path: str) -> Tuple[int, str]:
 
 def phase_serve(size: Size, seed: int) -> Tuple[Dict, Any, list]:
     """Returns (record, the engine, the prompts) — the kernels phase
-    compares its Pallas engine with this one."""
+    checks this engine's walk."""
     import jax
-    import jax.numpy as jnp
 
     from quintnet_tpu.fleet import FrontDoor, ServeFleet
-    from quintnet_tpu.models.gpt2 import gpt2_apply, gpt2_init
+    from quintnet_tpu.models.gpt2 import gpt2_init
     from quintnet_tpu.obs import parse_exposition
 
     params = gpt2_init(jax.random.key(seed), size.cfg)
@@ -479,10 +488,8 @@ def phase_serve(size: Size, seed: int) -> Tuple[Dict, Any, list]:
     # logits: the paged programs against a plain dense forward
     picked = [prompts[i] for i in size.dense_check]
     width = _width(picked, size.block_size)
-    paged = paged_logits(engine, picked, width)
-    dense = jax.jit(lambda pr, ids: gpt2_apply(pr, ids, size.cfg))(
-        params, jnp.asarray(pad_rows(picked, width))).astype(jnp.float32)
-    gap = logits_gap(paged, dense, picked)
+    gap = logits_gap(paged_logits(engine, picked, width),
+                     dense_logits(size, params, picked, width), picked)
     check(gap["max_abs_diff"] <= TOL["paged_vs_dense_logits"],
           f"paged vs dense logits: {gap} > {TOL['paged_vs_dense_logits']}")
 
@@ -550,46 +557,34 @@ def check_flash(size: Size, seed: int, S: int) -> Dict:
             "tol": TOL["flash_vs_blockwise_rel"]}
 
 
-def check_pallas_engine(size: Size, xla_engine, prompts, seed: int) -> Dict:
-    """A second engine on the fused paged kernel answers two of the
-    serve prompts; its logits (same seam) against the XLA engine's."""
-    import jax
+def check_paged_walk(size: Size, engine, prompts) -> Dict:
+    """The engine's decode program as it will run must lower to a
+    ``tpu_custom_call``: each row walks its live blocks of the pool
+    (ops/paged_attention.py). Its numbers: the paged logits over the
+    first tokens of two serve prompts, few enough query rows a row
+    (nn/attention._MAX_DIAG_ROWS) that ``Family.verify`` walks too,
+    against the dense forward."""
     import jax.numpy as jnp
 
-    from quintnet_tpu.serve import generate
+    from quintnet_tpu.nn.attention import _MAX_DIAG_ROWS
 
-    picked = [prompts[i] for i in size.kernel_check]
-    engine = build_engine(size, xla_engine.params, attn_kernel="pallas",
-                          prefill_len=size.pallas_prefill_len,
-                          chunked_prefill=True)
-    # the decode program as the engine will run it, before it runs
     lowered = engine._decode.fn.lower(
         engine.params, *engine.pool.caches(), jnp.asarray(engine._tok),
         jnp.asarray(engine._pos), jnp.asarray(engine._tables),
         jnp.asarray(engine._key_data))
-    has_call = "tpu_custom_call" in lowered.as_text()
-    outs = generate(engine, picked, max_new_tokens=size.max_new,
-                    keys=[jax.random.key(seed + i) for i in
-                          range(len(picked))])
-    for p, out in zip(picked, outs):
-        check(len(out) == len(p) + size.max_new,
-              f"pallas engine returned {len(out)} tokens for a "
-              f"{len(p)}-token prompt")
-    width = _width(picked, size.block_size)
-    check(width <= size.pallas_prefill_len,
-          f"kernel_check prompts ({width}) exceed the Pallas window")
+    bs = size.block_size
+    most = _MAX_DIAG_ROWS // size.cfg.n_head // bs * bs
+    picked = [prompts[i][:most] for i in size.kernel_check]
+    width = _width(picked, bs)
     gap = logits_gap(paged_logits(engine, picked, width),
-                     paged_logits(xla_engine, picked, width), picked)
-    return {"prompt_tokens": [len(p) for p in picked],
-            "new_tokens": len(picked) * size.max_new,
-            "prefill_len": size.pallas_prefill_len,
-            "compiled_programs": engine.compile_stats(),
-            "tpu_custom_call": has_call,
-            "pallas_vs_xla_logits": {**gap,
-                                     "tol": TOL["pallas_vs_xla_logits"]}}
+                     dense_logits(size, engine.params, picked, width),
+                     picked)
+    return {"tpu_custom_call": "tpu_custom_call" in lowered.as_text(),
+            "paged_vs_dense_logits": {
+                **gap, "tol": TOL["paged_vs_dense_logits"]}}
 
 
-def phase_kernels(size: Size, xla_engine, prompts, seed: int) -> Dict:
+def phase_kernels(size: Size, engine, prompts, seed: int) -> Dict:
     flash = [check_flash(size, seed, S) for S in size.flash_seqs]
     for f in flash:
         check(f["tpu_custom_call"],
@@ -597,12 +592,12 @@ def phase_kernels(size: Size, xla_engine, prompts, seed: int) -> Dict:
               f"tpu_custom_call: it ran {f['path']}, not the kernel")
         check(max(f["rel_err"].values()) <= TOL["flash_vs_blockwise_rel"],
               f"flash vs blockwise at seq {f['seq']}: {f['rel_err']}")
-    paged = check_pallas_engine(size, xla_engine, prompts, seed)
+    paged = check_paged_walk(size, engine, prompts)
     check(paged["tpu_custom_call"],
-          "the Pallas engine's decode program has no tpu_custom_call")
-    check(paged["pallas_vs_xla_logits"]["max_abs_diff"]
-          <= TOL["pallas_vs_xla_logits"],
-          f"pallas vs xla logits: {paged['pallas_vs_xla_logits']}")
+          "the engine's decode program has no tpu_custom_call")
+    check(paged["paged_vs_dense_logits"]["max_abs_diff"]
+          <= TOL["paged_vs_dense_logits"],
+          f"walked vs dense logits: {paged['paged_vs_dense_logits']}")
     return {"flash_attention": flash, "paged_attention": paged}
 
 

@@ -333,7 +333,7 @@ def donation_report(jitted: Callable, *args, **kwargs) -> DonationReport:
 
 
 # ---------------------------------------------------------------------------
-# gathered-view audit (fused paged attention, ops/paged_attention.py)
+# gathered-view audit (paged attention, nn/attention.paged_attend)
 
 
 def _walk_skip_kernels(jaxpr, visit) -> None:
@@ -382,20 +382,18 @@ def gathered_view_gathers(fn: Callable, *args, num_blocks: int,
     pool by block, ``[L, num_blocks, ...]``, or one layer's slice of
     it, ``[num_blocks, ...]`` — and the output carries a
     ``table_width`` dim — the `paged_gather`/`paged_gather_scales`
-    signature, the HBM round-trip the fused Pallas kernels exist to
-    delete.
+    signature, the HBM round-trip the per-row walk
+    (ops/paged_attention.py) exists to delete.
 
     The count is structural (one per eqn occurrence; a scan body
     counts once, not per trip), and the table dim is positional: a
     pool gather indexed by an [.., W]-wide table slice lands W at
     OUTPUT DIM 1 ([rows, W, slots-or-heads, ...]), so only dim 1 is
     compared — a head/feature dim that happens to equal
-    ``table_width`` cannot alias. An ``attn_kernel="xla"`` serving
-    program shows >= 2 per layer (k + v, plus both scale gathers under
-    a scaled KV policy); an ``attn_kernel="pallas"`` program must show
-    ZERO — its only pool gathers are the touched-block windows of
-    ``paged_quant_window_update``, whose table dim is the requant
-    span. CALLER CONTRACT: audit a program whose requant span is
+    ``table_width`` cannot alias. A serving program that gathers its
+    view shows >= 2 per layer (k + v, plus both scale gathers under a
+    scaled KV policy); one that walks each row's live blocks in place
+    shows ZERO. CALLER CONTRACT: audit a program whose requant span is
     strictly below ``table_width`` (decode's span is 1; for prefill
     pick a bucket well under the row length) — a run covering the
     whole row must legitimately touch every block it wrote.

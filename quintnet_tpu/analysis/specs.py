@@ -453,28 +453,6 @@ def weight_layout_policies() -> Tuple[str, ...]:
     return ("f32", "bf16", "int8", "fp8", "fake_quant")
 
 
-def attn_kernels() -> Tuple[str, ...]:
-    """THE canonical serving attention-backend ladder
-    (ops/paged_attention.py): ``xla`` is the gathered-view reference
-    oracle, ``pallas`` the fused block-table-walking kernel. Pinned
-    here for the same reason the policy ladder is: the backend must
-    NOT change any census or bound — per backend the engine compiles
-    exactly the same sentinel set, and every ``expected_serve_*``
-    census above holds verbatim (the kernel lives strictly inside the
-    per-layer attention; the RowParallel psums, the vocab-parallel
-    collectives, and the sp ring all sit outside it, and a
-    ``pallas_call`` carries no collectives at all). What DOES differ
-    is structural and audited separately:
-    ``jaxpr_audit.gathered_view_gathers`` must be exactly 0 for pallas
-    programs, and for xla ones > 0 wherever they still gather a view:
-    every program of an f32 or scaled pool and a many-row prefill
-    bucket of a bf16 one. An xla decode or verify program of a bf16/f16
-    pool walks each row's live blocks in place instead (one
-    ``row_walk_calls`` a layer scan, no gather;
-    tests/test_paged_attention.py)."""
-    return ("xla", "pallas")
-
-
 def lora_rank_buckets(max_rank: int, *, floor: int = 4) -> Tuple[int, ...]:
     """THE canonical adapter-rank ladder for multi-tenant LoRA serving
     (serve/adapters.py): powers of two from ``floor`` up to (and capped

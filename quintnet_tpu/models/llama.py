@@ -480,8 +480,7 @@ def llama_block_verify_paged(p, x, kc, vc, positions, tail_lens,
                              block_tables=None,
                              block_size: Optional[int] = None,
                              lora=None, lora_scale=None,
-                             kv_scales=None, policy=None,
-                             attn_kernel: str = "xla"):
+                             kv_scales=None, policy=None):
     """The paged block step of every serving program
     (nn/attention.paged_attend): x [S, P, D] per-slot token runs at
     absolute ``positions`` [S, P] — a decode step at P == 1, a
@@ -496,9 +495,7 @@ def llama_block_verify_paged(p, x, kc, vc, positions, tail_lens,
     [S, 1, P, hd]) must be built from the SAME absolute positions.
     ``lora``/``lora_scale``: this layer's packed per-slot adapters.
     ``kv_scales``/``policy``: scaled KV layout (serve/kv_quant.py).
-    Returns (x, (kc, vc[, k_scale, v_scale][, moe_stats])).
-    ``attn_kernel="pallas"``: the fused block-table-walking kernel
-    (ops/paged_attention.py)."""
+    Returns (x, (kc, vc[, k_scale, v_scale][, moe_stats]))."""
     from quintnet_tpu.nn.attention import _pool_tuple, paged_attend
 
     tp = 1 if tp_axis is None else lax.axis_size(tp_axis)
@@ -508,8 +505,7 @@ def llama_block_verify_paged(p, x, kc, vc, positions, tail_lens,
                         lora=attn_lora, lora_scale=lora_scale)
     o, pools = paged_attend(
         q, k, v, _pool_tuple(kc, vc, kv_scales), layer, positions,
-        tail_lens, block_tables, block_size=block_size, policy=policy,
-        attn_kernel=attn_kernel)
+        tail_lens, block_tables, block_size=block_size, policy=policy)
     x = llama_attn_residual(p["attn"], x, o, tp_axis=tp_axis,
                             lora=attn_lora, lora_scale=lora_scale)
     x, _aux, stats = llama_mlp_residual(

@@ -689,10 +689,7 @@ def _gather_kv(pools, layer, policy, block_tables, *, block_size: int,
     scaled layout policy, ``pools`` = (k, v, k_scale, v_scale) —
     dequantize with their block scales (:func:`paged_gather_dequant`).
     ``head_shape`` None (passthrough pools only): the rows as gathered,
-    [B, M, bs, F], unsplit (:func:`paged_gather`). Also the single seam
-    the fused-kernel dispatch (``attn_kernel="pallas"``,
-    ops/paged_attention.py) plugs into INSTEAD of — the Pallas path
-    never calls this."""
+    [B, M, bs, F], unsplit (:func:`paged_gather`)."""
     k_pool, v_pool, *sc = pools
     ks, vs = sc if sc else (None, None)
     with jax.named_scope("kv_gather"):
@@ -842,65 +839,8 @@ def paged_kv_step(pools, layer, k, v, positions, lens, block_tables, *,
     return k_all, v_all, (k_pool, v_pool, ks, vs)
 
 
-def _paged_attend_pallas(q, k, v, pools, layer, positions, lens,
-                         block_tables, *, block_size: int, policy):
-    """``attn_kernel="pallas"``: the fused block-table-walking kernel
-    (ops/paged_attention.py) on ONE layer's pool. SLICE-AND-RESHAPE:
-    the kernel and its touched-block requantizer take a layer's
-    ``[slots, H, Dh]`` view, cut out of the carried pool here (and,
-    under a scaled policy, put back whole) — a layer-sized copy the
-    gathered-view path does not make. No benchmark cell runs this
-    backend (ROADMAP D3 decides its fate); its parity tests pin it to
-    the gathered-view math.
-
-    Passthrough: the pool is written first and the kernel reads the
-    fresh run back like any other slot. Scaled: the kernel scores the
-    exact f32 run against the PRE-write pool (the fresh-kv override —
-    the oracle's post-insert view), then only the run's touched blocks
-    requantize, k and v symmetrically (paged_quant_window_update —
-    pool bytes byte-identical to the gathered-view oracle's)."""
-    from quintnet_tpu.ops.paged_attention import (
-        paged_attention, paged_quant_window_update)
-
-    head_shape = (k.shape[1], k.shape[3])
-
-    def view(pool):
-        return _pool_heads(
-            lax.dynamic_index_in_dim(pool, layer, keepdims=False),
-            *head_shape)
-
-    if len(pools) == 2:
-        pools = paged_write(*pools, layer, k, v, positions, lens,
-                            block_tables=block_tables,
-                            block_size=block_size)
-        o = paged_attention(q, view(pools[0]), view(pools[1]),
-                            block_tables, positions[:, 0],
-                            block_size=block_size)
-        return o, pools
-    k_pool, v_pool, ks, vs = pools
-    kc, vc, ksl, vsl = view(k_pool), view(v_pool), ks[layer], vs[layer]
-    o = paged_attention(q, kc, vc, block_tables, positions[:, 0],
-                        block_size=block_size, kv_scales=(ksl, vsl),
-                        policy=policy, fresh_kv=(k, v))
-    span = _quant_span(positions.shape[1], block_size,
-                       block_tables.shape[1])
-    out = []
-    with jax.named_scope("kv_write"):
-        for pool, cache, sc, scl, vals in ((k_pool, kc, ks, ksl, k),
-                                           (v_pool, vc, vs, vsl, v)):
-            cache, scl = paged_quant_window_update(
-                policy, cache, scl, vals, positions, lens,
-                block_tables=block_tables, block_size=block_size,
-                max_blocks=span)
-            out.append((lax.dynamic_update_slice(
-                pool, cache.reshape(1, cache.shape[0], -1), (layer, 0, 0)),
-                sc.at[layer].set(scl)))
-    (k_pool, ks), (v_pool, vs) = out
-    return o, (k_pool, v_pool, ks, vs)
-
-
 def paged_attend(q, k, v, pools, layer, positions, lens, block_tables, *,
-                 block_size: int, policy=None, attn_kernel: str = "xla",
+                 block_size: int, policy=None,
                  scale: Optional[float] = None,
                  max_diag_rows: Optional[int] = None,
                  key_block: Optional[int] = None):
@@ -921,16 +861,13 @@ def paged_attend(q, k, v, pools, layer, positions, lens, block_tables, *,
     (chunked) prefill are this one mask at different widths, which is
     what makes their tokens bit-equal.
 
-    ``attn_kernel``: "xla" is the code's own best path, chosen from
-    shapes and dtypes alone: a bf16/f16 pool under at most
-    :data:`_MAX_DIAG_ROWS` query rows — decode and verify — walks each
-    row's LIVE key blocks of the pool in place, heads on the lane
-    diagonal (:func:`_paged_attend_walk`); everything else gathers the
-    table's width (:func:`paged_kv_step`) and scores the view split
-    into heads (:func:`_masked_sdpa`). The same mask and rounding
-    contract in both. "pallas" is the whole-row fused kernel
-    (:func:`_paged_attend_pallas`), bit-parity-pinned against the
-    gathered view.
+    Its form is chosen from shapes and dtypes alone: a bf16/f16 pool
+    under at most :data:`_MAX_DIAG_ROWS` query rows — decode and verify
+    — walks each row's LIVE key blocks of the pool in place, heads on
+    the lane diagonal (:func:`_paged_attend_walk`); everything else
+    gathers the table's width (:func:`paged_kv_step`) and scores the
+    view split into heads (:func:`_masked_sdpa`). The same mask and
+    rounding contract in both.
 
     ``max_diag_rows`` overrides :data:`_MAX_DIAG_ROWS` where the caller
     knows its program's kind better than a row count does (a family
@@ -939,15 +876,6 @@ def paged_attend(q, k, v, pools, layer, positions, lens, block_tables, *,
     of ``block_size``) scores the run a block of keys at a time, only
     the blocks it can see (:func:`_paged_attend_key_blocked`: a prefill
     chunk against a long table)."""
-    if attn_kernel == "pallas":
-        if scale is not None:
-            raise NotImplementedError(
-                "the fused paged kernel scales its scores by "
-                "1/sqrt(head_dim) only; a stated score scale needs "
-                "attn_kernel='xla'")
-        return _paged_attend_pallas(
-            q, k, v, pools, layer, positions, lens, block_tables,
-            block_size=block_size, policy=policy)
     if key_block is not None:
         if len(pools) != 2:
             raise NotImplementedError(
@@ -1820,8 +1748,7 @@ def mha_verify_paged(p, x, k_cache, v_cache, positions, tail_lens, *,
                      tp_axis: Optional[str] = None,
                      block_tables=None, block_size: Optional[int] = None,
                      lora=None, lora_scale=None,
-                     kv_scales=None, policy=None,
-                     attn_kernel: str = "xla"):
+                     kv_scales=None, policy=None):
     """Paged attention of one layer, for every serving program: EVERY
     slot scores a run of tokens against its own cached row in ONE
     forward (:func:`paged_attend`) — speculative decoding's
@@ -1850,15 +1777,12 @@ def mha_verify_paged(p, x, k_cache, v_cache, positions, tail_lens, *,
     ``kv_scales``/``policy`` (serve/kv_quant.py): under a scaled layout
     policy ``kv_scales`` is the (k_scale, v_scale) pair of whole
     [L, num_blocks, H] arrays, addressed at ``layer`` like the pools,
-    and the return grows to (y, k_cache, v_cache, k_scale, v_scale).
-
-    ``attn_kernel="pallas"``: the fused block-table-walking kernel
-    instead of the gathered view (ops/paged_attention.py)."""
+    and the return grows to (y, k_cache, v_cache, k_scale, v_scale)."""
     q, k, v = _qkv_heads(p, x, num_heads, lora, lora_scale)
     o, pools = paged_attend(
         q, k, v, _pool_tuple(k_cache, v_cache, kv_scales), layer,
         positions, tail_lens, block_tables, block_size=block_size,
-        policy=policy, attn_kernel=attn_kernel)
+        policy=policy)
     return (_proj_out(p, o, tp_axis, lora, lora_scale), *pools)
 
 

@@ -302,8 +302,7 @@ def block_verify_paged(p, x, k_cache, v_cache, positions, tail_lens, *,
                        block_tables=None,
                        block_size: Optional[int] = None,
                        lora=None, lora_scale=None,
-                       kv_scales=None, policy=None,
-                       attn_kernel: str = "xla"):
+                       kv_scales=None, policy=None):
     """The paged block step of every serving program
     (nn/attention.mha_verify_paged): x [S, P, D] per-slot token runs at
     absolute ``positions`` [S, P] — a decode step at P == 1, a
@@ -324,7 +323,7 @@ def block_verify_paged(p, x, k_cache, v_cache, positions, tail_lens, *,
             positions, tail_lens, num_heads=num_heads, layer=layer,
             tp_axis=tp_axis, block_tables=block_tables,
             block_size=block_size, lora=attn_lora, lora_scale=lora_scale,
-            kv_scales=kv_scales, policy=policy, attn_kernel=attn_kernel)
+            kv_scales=kv_scales, policy=policy)
         x = x + out[0]
     x, stats = _block_mlp(
         p, x, act=act, moe_args=moe_args, ep_axis=ep_axis,

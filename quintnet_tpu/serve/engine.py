@@ -106,8 +106,8 @@ serve/families.pangu_moe_family): the pool holds ONE row a token and
 has no ``v`` buffer, so every program takes and returns one pool
 buffer. Blocks are blocks: the prefix cache, chunked prefill,
 preemption, speculation and chain export/import work as for any other
-family. A mesh, adapters, scaled or float8 KV, scaled weights and the
-Pallas kernel are refused at construction (:func:`_refuse_for_latent`).
+family. A mesh, adapters, scaled or float8 KV and scaled weights are
+refused at construction (:func:`_refuse_for_latent`).
 
 Window families (``family.window`` set — sliding-window attention
 layers among global ones, serve/families.laguna_family and
@@ -119,10 +119,10 @@ in place) and prefill takes the slot's index, as for a recurrent
 family; admission takes a ring with the blocks and a cleared slot gives
 it back. What shares, moves or rolls back blocks alone — the prefix
 cache, the host tier, chain export/import, the disaggregated prefill
-phase, speculation — and a mesh, adapters, scaled or float8 KV, scaled
-weights and the Pallas kernel are refused at construction
-(:func:`_refuse_for_window`). Preemption re-prefills ``prompt +
-generated`` from position 0 through the chunk program.
+phase, speculation — and a mesh, adapters, scaled or float8 KV and
+scaled weights are refused at construction (:func:`_refuse_for_window`).
+Preemption re-prefills ``prompt + generated`` from position 0 through
+the chunk program.
 
 All host<->device traffic per step is O(max_slots) scalars plus the
 sampled tokens — the pool and parameters never leave the device. A step
@@ -262,11 +262,6 @@ def _refuse_for_state(family: Family, **asked) -> None:
             "the recurrent state and the recurrent layers' projections "
             "are not head-sharded, and the chunked scan has no ring "
             "form"),
-        "pallas": (
-            "attn_kernel='pallas'",
-            "the fused kernel walks K and V pools of heads scaled by "
-            "sqrt(head_dim); this family's attention layers state "
-            "their own score scale or cache one latent row"),
         "kv_chain": (
             "export_kv_chain / import_kv_chain",
             "the handoff payload carries KV blocks and no recurrent "
@@ -310,10 +305,6 @@ def _refuse_for_latent(family: Family, **asked) -> None:
             "a scaled weight layout (int8, fp8, fake_quant)",
             "the grouped expert matmul applies no per-channel scale: "
             "pass weights_dtype 'f32' or 'bf16'"),
-        "pallas": (
-            "attn_kernel='pallas'",
-            "the fused kernel walks K and V pools of heads; there is "
-            "no kernel over latent rows"),
     }
     for key, on in asked.items():
         if on:
@@ -363,10 +354,6 @@ def _refuse_for_window(family: Family, **asked) -> None:
             "a scaled weight layout (int8, fp8, fake_quant)",
             "the grouped expert matmul applies no per-channel scale: "
             "pass weights_dtype 'f32' or 'bf16'"),
-        "pallas": (
-            "attn_kernel='pallas'",
-            "the fused kernel walks a block table and knows neither "
-            "the window mask nor the rings"),
         "kv_chain": (
             "export_kv_chain / import_kv_chain",
             "the handoff payload carries KV blocks and no rings"),
@@ -441,19 +428,18 @@ class ServeEngine:
                 kv_tier=int(kv_tier_bytes) > 0,
                 spec=spec not in (None, False),
                 adapters=adapters not in (None, False),
-                mesh=mesh is not None, pallas=attn_kernel == "pallas")
+                mesh=mesh is not None)
         self._latent = family.latent is not None
         _refuse_for_latent(
             family, mesh=mesh is not None,
-            adapters=adapters not in (None, False),
-            pallas=attn_kernel == "pallas")
+            adapters=adapters not in (None, False))
         self._window = family.window is not None
         _refuse_for_window(
             family, prefix_cache=prefix_cache,
             kv_tier=int(kv_tier_bytes) > 0,
             spec=spec not in (None, False),
             adapters=adapters not in (None, False),
-            mesh=mesh is not None, pallas=attn_kernel == "pallas",
+            mesh=mesh is not None,
             block_size=self._window and family.window.ring
             != family.window.window + int(block_size))
         # per-slot buffers beside the pools (a recurrent family's state,
@@ -504,23 +490,12 @@ class ServeEngine:
                 mesh is not None and tp_axis not in mesh.shape):
             # sp-only mesh: params/pool replicated, no tp collectives
             self.tp_axis = None
-        # attention backend (ops/paged_attention.py): "xla" is the
-        # gathered-view reference oracle (default), "pallas" the fused
-        # block-table-walking kernel, bit-parity-pinned against it
-        # (tests/test_paged_attention.py). Same program ladder, same
-        # compile bounds, same collective census either way. Nothing
-        # falls back from one to the other: a Pallas engine off-TPU
-        # fails at lowering, and a shape the kernel's VMEM cannot hold
-        # is refused below (_check_pallas_vmem).
-        if attn_kernel not in ("xla", "pallas"):
+        # one paged attention (nn/attention.paged_attend), its form the
+        # code's own choice from dtypes and shapes; "xla" names it
+        if attn_kernel != "xla":
             raise ValueError(
-                f"unknown attn_kernel {attn_kernel!r}; expected 'xla' "
-                f"or 'pallas'")
-        if attn_kernel == "pallas" and self.sp_axis is not None:
-            raise NotImplementedError(
-                "attn_kernel='pallas' does not yet compose with "
-                "sequence-parallel prefill (the ring path is XLA-only)"
-                " — drop sp_axis or use attn_kernel='xla'")
+                f"attn_kernel={attn_kernel!r} is refused: the only paged "
+                f"attention is the code's own choice, 'xla'")
         self.attn_kernel = attn_kernel
         # MoE serving (nn/moe.py through the family moe_args seam): an
         # ``ep`` mesh axis of size > 1 shards the experts — one
@@ -784,12 +759,6 @@ class ServeEngine:
         for refuse in (_refuse_for_latent, _refuse_for_window):
             refuse(family, kv_policy=self.kv_policy.scaled
                    or self.kv_policy.name == "fp8")
-        if self.attn_kernel == "pallas" and self.kv_policy.name == "fp8":
-            raise NotImplementedError(
-                "attn_kernel='pallas' does not yet support the fp8 KV "
-                "policy (the fused kernel dequantizes int8 on load; "
-                "float8 tiles are a future extension) — use "
-                "attn_kernel='xla' or kv_dtype='int8'")
         sharding = scale_sharding = None
         if mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec as P
@@ -845,8 +814,6 @@ class ServeEngine:
         # (always 0 by step phasing; surfaced so the bench can gate it)
         self._decode_blocked_demotions = 0
         self.table_width = self.pool.blocks_for(self.max_seq_len)
-        if self.attn_kernel == "pallas":
-            self._check_pallas_vmem()
         self.scheduler = Scheduler(self.pool, policy=policy)
         self.metrics = ServeMetrics(clock=clock)
 
@@ -1011,35 +978,6 @@ class ServeEngine:
                 *self._verifies.values())))
         register_recorder(rec, self)
 
-    def _check_pallas_vmem(self) -> None:
-        """Refuse, at construction and with the computed number, any
-        program whose fused paged-attention call cannot fit the chip's
-        VMEM (ops/paged_attention.require_vmem): decode (1 query a
-        row), each verify bucket (k + 1) and each prefill bucket.
-        Heads are the LOCAL ones under tp."""
-        from quintnet_tpu.ops.paged_attention import require_vmem
-
-        tp = (1 if self.mesh is None or self.tp_axis is None
-              else int(self.mesh.shape[self.tp_axis]))
-        cfg = self.family.cfg
-        # query heads: GPT2Config.n_head / LlamaConfig.n_heads
-        hq = cfg.n_head if hasattr(cfg, "n_head") else cfg.n_heads
-        programs = [("decode", 1)]
-        if self.spec is not None:
-            programs += [(f"verify[{k}]", k + 1)
-                         for k in self.spec.buckets]
-        programs += [(f"prefill[{b}]", b) for b in self.prefill_buckets]
-        for name, width in programs:
-            require_vmem(
-                what=f"attn_kernel='pallas' program {name}",
-                n_q_heads=hq // tp,
-                n_kv_heads=self.family.n_kv_heads // tp,
-                n_queries=width, head_dim=self.family.head_dim,
-                block_size=self.pool.block_size,
-                table_width=self.table_width,
-                pool_dtype=self.kv_policy.store_dtype,
-                scaled=self.kv_policy.scaled)
-
     # ------------------------------------------------------------------
     # compiled programs
     # ------------------------------------------------------------------
@@ -1060,7 +998,6 @@ class ServeEngine:
         tp_axis = self.tp_axis
         sp_axis = self.sp_axis
         ep_axis = self.ep_axis
-        attn_kernel = self.attn_kernel
         use_lora = self.adapters is not None
         policy = self.kv_policy
         scaled = policy.scaled
@@ -1124,7 +1061,7 @@ class ServeEngine:
                     params, k_pool, v_pool, ids, start, t0, table_row,
                     bs, tp_axis=tp_axis, ep_axis=ep_axis, lora=lora,
                     lora_scale=lora_scale, kv_scales=kv_scales,
-                    policy=policy, attn_kernel=attn_kernel, **extra)
+                    policy=policy, **extra)
             else:
                 # sequence-parallel chunk: ids arrives as this rank's
                 # [1, P/sp] slice (the shard_map below splits dim 1);
@@ -1151,7 +1088,6 @@ class ServeEngine:
         family, bs = self.family, self.pool.block_size
         tp_axis = self.tp_axis
         ep_axis = self.ep_axis
-        attn_kernel = self.attn_kernel
         use_lora = self.adapters is not None
         policy = self.kv_policy
         scaled = policy.scaled
@@ -1177,7 +1113,7 @@ class ServeEngine:
                     tp_axis=tp_axis, ep_axis=ep_axis,
                     lora=lora, lora_scale=lora_scale,
                     kv_scales=(k_scale, v_scale) if scaled else None,
-                    policy=policy, attn_kernel=attn_kernel, **extra)
+                    policy=policy, **extra)
             self._read_granule[name] = max(reads, default=None)
             self._ring_read = max(rings, default=None)
             logits, pools = out[0], out[1:]
@@ -1205,7 +1141,6 @@ class ServeEngine:
         family, bs = self.family, self.pool.block_size
         tp_axis = self.tp_axis
         ep_axis = self.ep_axis
-        attn_kernel = self.attn_kernel
         use_lora = self.adapters is not None
         policy = self.kv_policy
         scaled = policy.scaled
@@ -1225,7 +1160,7 @@ class ServeEngine:
                     tables, bs, tp_axis=tp_axis, ep_axis=ep_axis,
                     lora=lora, lora_scale=lora_scale,
                     kv_scales=(k_scale, v_scale) if scaled else None,
-                    policy=policy, attn_kernel=attn_kernel)
+                    policy=policy)
             self._read_granule[name] = max(reads, default=None)
             logits, pools = out[0], out[1:]               # [S, P, V]
             P = ids.shape[1]

@@ -58,14 +58,11 @@ block bodies dequantize inside the gathered view and quantize on
 scatter; ``kv_scales=None`` (the passthrough policies) is
 byte-identical to the pre-policy programs.
 
-Attention backend (ops/paged_attention.py): every contract
-additionally takes ``attn_kernel="xla"`` — "xla" is the gathered-view
-math above (the reference oracle), "pallas" routes each block's paged
-attention through the fused block-table-walking kernel
-(bit-parity-pinned, tests/test_paged_attention.py). The contract
-surface, collective census, and compile-count bounds are identical for
-both backends; the sp path stays XLA-only (the engine rejects the
-combination).
+Every contract additionally takes ``attn_kernel="xla"`` and reads it
+nowhere: there is one paged attention (nn/attention.paged_attend, its
+form chosen from dtypes and shapes), and the keyword stays only because
+the benchmark's drivers pass ``attn_kernel=engine.attn_kernel`` to
+these functions (ROADMAP names the debt).
 
 Multi-tenant LoRA (serve/adapters.py): every contract additionally
 takes ``lora=None, lora_scale=None`` — a nested pytree of PACKED
@@ -252,7 +249,7 @@ def _paged_contracts(run, logits):
             params, k_pool, v_pool, kv_scales, ids, positions,
             jnp.reshape(t0 - start, (1,)), table_row[None], block_size,
             tp_axis, ep_axis=ep_axis, lora=lora, lora_scale=lora_scale,
-            policy=policy, attn_kernel=attn_kernel)
+            policy=policy)
         h_last = lax.dynamic_slice_in_dim(h, t0 - 1 - start, 1, axis=1)
         return (logits(params, h_last, tp_axis)[:, 0, :], *pools)
 
@@ -263,7 +260,7 @@ def _paged_contracts(run, logits):
             params, k_pool, v_pool, kv_scales, tok[:, None], pos[:, None],
             jnp.ones(pos.shape, jnp.int32), tables, block_size, tp_axis,
             ep_axis=ep_axis, lora=lora, lora_scale=lora_scale,
-            policy=policy, attn_kernel=attn_kernel)
+            policy=policy)
         return (logits(params, h, tp_axis)[:, 0, :], *pools)
 
     def verify(params, k_pool, v_pool, ids, starts, tail_lens, tables,
@@ -275,7 +272,7 @@ def _paged_contracts(run, logits):
         h, *pools = call(
             params, k_pool, v_pool, kv_scales, ids, positions, tail_lens,
             tables, block_size, tp_axis, ep_axis=ep_axis, lora=lora,
-            lora_scale=lora_scale, policy=policy, attn_kernel=attn_kernel)
+            lora_scale=lora_scale, policy=policy)
         return (logits(params, h, tp_axis), *pools)
 
     return prefill_from, decode, verify
@@ -320,7 +317,7 @@ def gpt2_family(cfg) -> Family:
                     + jnp.take(emb["wpe"], safe_pos, axis=0))
 
     def run(params, ids, pools, positions, lens, tables, block_size, *,
-            tp_axis, ep_axis, lora, lora_scale, policy, attn_kernel):
+            tp_axis, ep_axis, lora, lora_scale, policy):
         heads = _local_heads(cfg, tp_axis)
 
         def step(blk, layer, lr, x, pools):
@@ -329,8 +326,7 @@ def gpt2_family(cfg) -> Family:
                 layer=layer, act=gelu, moe_args=cfg.moe_args,
                 ep_axis=ep_axis, tp_axis=tp_axis, block_tables=tables,
                 block_size=block_size, lora=lr, lora_scale=lora_scale,
-                kv_scales=pools[2:] or None, policy=policy,
-                attn_kernel=attn_kernel)
+                kv_scales=pools[2:] or None, policy=policy)
 
         return _scan_layers(step, embed(params, ids, positions, tp_axis),
                             pools, params["blocks"], lora, moe)
@@ -404,7 +400,7 @@ def llama_family(cfg) -> Family:
     moe = cfg.moe_args is not None
 
     def run(params, ids, pools, positions, lens, tables, block_size, *,
-            tp_axis, ep_axis, lora, lora_scale, policy, attn_kernel):
+            tp_axis, ep_axis, lora, lora_scale, policy):
         cos, sin = llama_rope_tables(positions, cfg)          # [S, P, hd]
         cos, sin = cos[:, None], sin[:, None]                 # [S,1,P,hd]
 
@@ -414,7 +410,7 @@ def llama_family(cfg) -> Family:
                 layer=layer, tp_axis=tp_axis, ep_axis=ep_axis,
                 block_tables=tables, block_size=block_size, lora=lr,
                 lora_scale=lora_scale, kv_scales=pools[2:] or None,
-                policy=policy, attn_kernel=attn_kernel)
+                policy=policy)
             return (x, *pools)
 
         return _scan_layers(step, _embed(params, ids, cfg, tp_axis), pools,
@@ -480,15 +476,13 @@ def granite_hybrid_family(cfg) -> Family:
     per = before + after
     dims = cfg.mamba
 
-    def only_plain(tp_axis, ep_axis, lora, kv_scales, attn_kernel, state):
+    def only_plain(tp_axis, ep_axis, lora, kv_scales, state):
         if (tp_axis is not None or ep_axis is not None or lora is not None
-                or kv_scales is not None or attn_kernel != "xla"
-                or state is None):
+                or kv_scales is not None or state is None):
             raise NotImplementedError(
                 "the granite hybrid programs run on one device, without "
-                "adapters, on an unscaled pool, with attn_kernel='xla' "
-                "and with their state buffers (ServeEngine refuses the "
-                "rest at construction)")
+                "adapters, on an unscaled pool and with their state "
+                "buffers (ServeEngine refuses the rest at construction)")
 
     def run_layers(params, k_pool, v_pool, state, h, row0, rows,
                    mamba_fn, attn_fn):
@@ -563,7 +557,7 @@ def granite_hybrid_family(cfg) -> Family:
                      block_size, tp_axis=None, ep_axis=None, lora=None,
                      lora_scale=None, kv_scales=None, policy=None,
                      attn_kernel="xla", state=None, slot=None):
-        only_plain(tp_axis, ep_axis, lora, kv_scales, attn_kernel, state)
+        only_plain(tp_axis, ep_axis, lora, kv_scales, state)
         P = ids.shape[1]
         positions = (start + jnp.arange(P, dtype=jnp.int32))[None]
         h, *bufs = run_chunk(
@@ -580,7 +574,7 @@ def granite_hybrid_family(cfg) -> Family:
         # the chunk program for P tokens a row, every row from its
         # CURRENT state. Not a speculative verify: nothing rolls a
         # state back, and the engine refuses ``spec`` for this family
-        only_plain(tp_axis, ep_axis, lora, kv_scales, attn_kernel, state)
+        only_plain(tp_axis, ep_axis, lora, kv_scales, state)
         P = ids.shape[1]
         positions = (starts[:, None]
                      + jnp.arange(P, dtype=jnp.int32)[None, :])
@@ -593,7 +587,7 @@ def granite_hybrid_family(cfg) -> Family:
                tp_axis=None, ep_axis=None, lora=None, lora_scale=None,
                kv_scales=None, policy=None, attn_kernel="xla",
                state=None):
-        only_plain(tp_axis, ep_axis, lora, kv_scales, attn_kernel, state)
+        only_plain(tp_axis, ep_axis, lora, kv_scales, state)
         # a row whose table is all null blocks is not decoding (an
         # empty slot, or one in the middle of a chunked prefill): its
         # state stays what it was
@@ -642,15 +636,15 @@ def pangu_moe_family(cfg) -> Family:
         pangu_logits, pangu_moe_partition_specs)
     from quintnet_tpu.nn.attention import rope_cos_sin
 
-    def only_plain(v_pool, tp_axis, ep_axis, lora, kv_scales, attn_kernel):
+    def only_plain(v_pool, tp_axis, ep_axis, lora, kv_scales):
         if (v_pool is not None or tp_axis is not None
                 or ep_axis is not None or lora is not None
-                or kv_scales is not None or attn_kernel != "xla"):
+                or kv_scales is not None):
             raise NotImplementedError(
                 "the pangu_ultra_moe programs run on one device, on the "
                 "one unscaled latent pool (v_pool=None), without "
-                "adapters, with attn_kernel='xla' (ServeEngine refuses "
-                "the rest at construction)")
+                "adapters (ServeEngine refuses the rest at "
+                "construction)")
 
     def run(params, ids, pool, positions, lens, tables, block_size, form):
         """Both stacks in turn, each a uniform :func:`_scan_layers`:
@@ -683,7 +677,7 @@ def pangu_moe_family(cfg) -> Family:
                      block_size, tp_axis=None, ep_axis=None, lora=None,
                      lora_scale=None, kv_scales=None, policy=None,
                      attn_kernel="xla"):
-        only_plain(v_pool, tp_axis, ep_axis, lora, kv_scales, attn_kernel)
+        only_plain(v_pool, tp_axis, ep_axis, lora, kv_scales)
         positions = (start + jnp.arange(ids.shape[1], dtype=jnp.int32))[None]
         h, pool, stats = run(
             params, ids, k_pool, positions, jnp.reshape(t0 - start, (1,)),
@@ -694,7 +688,7 @@ def pangu_moe_family(cfg) -> Family:
     def decode(params, k_pool, v_pool, tok, pos, tables, block_size,
                tp_axis=None, ep_axis=None, lora=None, lora_scale=None,
                kv_scales=None, policy=None, attn_kernel="xla"):
-        only_plain(v_pool, tp_axis, ep_axis, lora, kv_scales, attn_kernel)
+        only_plain(v_pool, tp_axis, ep_axis, lora, kv_scales)
         # a row whose table is all null blocks is not decoding (an empty
         # slot, or one in the middle of a chunked prefill): its token is
         # padding, which the router sends nowhere
@@ -707,7 +701,7 @@ def pangu_moe_family(cfg) -> Family:
                block_size, tp_axis=None, ep_axis=None, lora=None,
                lora_scale=None, kv_scales=None, policy=None,
                attn_kernel="xla"):
-        only_plain(v_pool, tp_axis, ep_axis, lora, kv_scales, attn_kernel)
+        only_plain(v_pool, tp_axis, ep_axis, lora, kv_scales)
         positions = (starts[:, None]
                      + jnp.arange(ids.shape[1], dtype=jnp.int32)[None, :])
         h, pool, stats = run(params, ids, k_pool, positions, tail_lens,
@@ -770,24 +764,21 @@ def _window_contracts(name: str, block_size: int, run, logits):
     -> (h, k_pool, v_pool, wk, wv, moe_stats)``, and ``logits(params,
     h)``. ``block_size``: the engine's (the rings are ``window +
     block_size`` rows)."""
-    def only_plain(tp_axis, ep_axis, lora, kv_scales, attn_kernel, window,
-                   bs):
+    def only_plain(tp_axis, ep_axis, lora, kv_scales, window, bs):
         if (tp_axis is not None or ep_axis is not None or lora is not None
-                or kv_scales is not None or attn_kernel != "xla"
-                or window is None or bs != block_size):
+                or kv_scales is not None or window is None
+                or bs != block_size):
             raise NotImplementedError(
                 f"the {name} programs run on one device, without "
                 f"adapters, on an unscaled pool of block_size "
-                f"{block_size}, with attn_kernel='xla' and with their "
-                f"window buffers (ServeEngine refuses the rest at "
-                f"construction)")
+                f"{block_size} and with their window buffers "
+                f"(ServeEngine refuses the rest at construction)")
 
     def prefill_from(params, k_pool, v_pool, ids, start, t0, table_row,
                      block_size, tp_axis=None, ep_axis=None, lora=None,
                      lora_scale=None, kv_scales=None, policy=None,
                      attn_kernel="xla", window=None, slot=None):
-        only_plain(tp_axis, ep_axis, lora, kv_scales, attn_kernel, window,
-                   block_size)
+        only_plain(tp_axis, ep_axis, lora, kv_scales, window, block_size)
         positions = (start + jnp.arange(ids.shape[1], dtype=jnp.int32))[None]
         h, *bufs = run(params, k_pool, v_pool, window, ids, positions,
                        jnp.reshape(t0 - start, (1,)), table_row[None],
@@ -799,8 +790,7 @@ def _window_contracts(name: str, block_size: int, run, logits):
                tp_axis=None, ep_axis=None, lora=None, lora_scale=None,
                kv_scales=None, policy=None, attn_kernel="xla",
                window=None):
-        only_plain(tp_axis, ep_axis, lora, kv_scales, attn_kernel, window,
-                   block_size)
+        only_plain(tp_axis, ep_axis, lora, kv_scales, window, block_size)
         # a row whose table is all null blocks is not decoding (an empty
         # slot, or one in the middle of a chunked prefill): its token is
         # padding — the router sends it nowhere, and its ring (a
@@ -819,8 +809,7 @@ def _window_contracts(name: str, block_size: int, run, logits):
         # Not yet a speculative verify: a rejected draft's rows have
         # overwritten the ring's oldest, and nothing rolls that back —
         # the engine refuses ``spec`` for a window family
-        only_plain(tp_axis, ep_axis, lora, kv_scales, attn_kernel, window,
-                   block_size)
+        only_plain(tp_axis, ep_axis, lora, kv_scales, window, block_size)
         if ids.shape[1] > block_size + 1:
             raise ValueError(
                 f"a verify run of {ids.shape[1]} tokens overwrites ring "
@@ -948,17 +937,15 @@ def ling_hybrid_family(cfg) -> Family:
     periods, per = cfg.periods, cfg.layer_group_size
     before, dims = cfg.kda_per_period, cfg.kda
 
-    def only_plain(v_pool, tp_axis, ep_axis, lora, kv_scales, attn_kernel,
-                   state):
+    def only_plain(v_pool, tp_axis, ep_axis, lora, kv_scales, state):
         if (v_pool is not None or tp_axis is not None
                 or ep_axis is not None or lora is not None
-                or kv_scales is not None or attn_kernel != "xla"
-                or state is None):
+                or kv_scales is not None or state is None):
             raise NotImplementedError(
                 "the bailing_hybrid programs run on one device, on the "
                 "one unscaled latent pool (v_pool=None) with their state "
-                "buffers, without adapters, with attn_kernel='xla' "
-                "(ServeEngine refuses the rest at construction)")
+                "buffers, without adapters (ServeEngine refuses the "
+                "rest at construction)")
 
     def pick(stack, i):
         return jax.tree.map(
@@ -1066,8 +1053,7 @@ def ling_hybrid_family(cfg) -> Family:
                      block_size, tp_axis=None, ep_axis=None, lora=None,
                      lora_scale=None, kv_scales=None, policy=None,
                      attn_kernel="xla", state=None, slot=None):
-        only_plain(v_pool, tp_axis, ep_axis, lora, kv_scales, attn_kernel,
-                   state)
+        only_plain(v_pool, tp_axis, ep_axis, lora, kv_scales, state)
         positions = (start + jnp.arange(ids.shape[1], dtype=jnp.int32))[None]
         h, *bufs = run_chunk(
             params, k_pool, state, ids, positions,
@@ -1084,8 +1070,7 @@ def ling_hybrid_family(cfg) -> Family:
         # CURRENT state, the latent layer in its absorbed form. Not a
         # speculative verify: nothing rolls a state back, and the
         # engine refuses ``spec`` for this family
-        only_plain(v_pool, tp_axis, ep_axis, lora, kv_scales, attn_kernel,
-                   state)
+        only_plain(v_pool, tp_axis, ep_axis, lora, kv_scales, state)
         positions = (starts[:, None]
                      + jnp.arange(ids.shape[1], dtype=jnp.int32)[None, :])
         h, *bufs = run_chunk(params, k_pool, state, ids, positions,
@@ -1097,8 +1082,7 @@ def ling_hybrid_family(cfg) -> Family:
                tp_axis=None, ep_axis=None, lora=None, lora_scale=None,
                kv_scales=None, policy=None, attn_kernel="xla",
                state=None):
-        only_plain(v_pool, tp_axis, ep_axis, lora, kv_scales, attn_kernel,
-                   state)
+        only_plain(v_pool, tp_axis, ep_axis, lora, kv_scales, state)
         # a row whose table is all null blocks is not decoding (an
         # empty slot, or one in the middle of a chunked prefill): its
         # state stays what it was, its latent row goes to the null

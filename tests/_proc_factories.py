@@ -23,7 +23,6 @@ def build_tiny_gpt2(*, seed: int = 0, n_layer: int = 2, max_slots: int = 2,
                     prefill_chunk_budget=None,
                     kv_dtype=None, weights_dtype=None,
                     prefix_cache: bool = True,
-                    attn_kernel: str = "xla",
                     kv_tier_bytes: int = 0,
                     n_experts: int = 0, expert_top_k: int = 2,
                     expert_capacity=None):
@@ -46,6 +45,6 @@ def build_tiny_gpt2(*, seed: int = 0, n_layer: int = 2, max_slots: int = 2,
                        prefill_chunk_budget=prefill_chunk_budget,
                        kv_dtype=kv_dtype, weights_dtype=weights_dtype,
                        prefix_cache=prefix_cache,
-                       attn_kernel=attn_kernel, temperature=temperature,
+                       temperature=temperature,
                        top_k=top_k, eos_token_id=eos_token_id,
                        kv_tier_bytes=kv_tier_bytes)

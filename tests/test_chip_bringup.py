@@ -5,9 +5,10 @@ checked without a chip (PR 21, the bring-up round).
    at GPT-2 124M's real widths — the on-chip-measurement guide's third
    rehearsal, kept as tests: ``pallas_flash_attention`` forward +
    backward at seq 4096 with and without ``segment_ids``, and
-   ``paged_attention(interpret=False)`` at decode / verify / prefill
-   shapes for 12 heads x Dh 64 x table width 64 (1024 positions),
-   passthrough and int8-scaled; and (PR 27) the decode program of the
+   ``paged_attend`` at decode / verify / prefill shapes for 12 heads x
+   Dh 64 x table width 64 (1024 positions) where it reads the gathered
+   view: an int8-scaled pool, and a bf16 prefill bucket; and (PR 27)
+   the decode program of the
    recurrent family at granite-4.0-h-micro's published widths, whose
    plan must not hold a second copy of the per-slot state; and (PR 28)
    the decode and prefill programs at GPT-2 XL's widths, which must
@@ -18,12 +19,9 @@ checked without a chip (PR 21, the bring-up round).
    kernel call a layer loop with no gathered view, the experts as
    (PR 38) the grouped-matmul kernel that reads touched experts only.
    Both window families' decode programs walk each row's ring of the
-   window store in place and slice none of it out.
-   The parent
-   commit's paged kernel was
-   REFUSED at every one of these shapes (16 MiB default scoped-VMEM
-   budget; a (1, Hkv) scale block; a lane-splitting reshape) —
-   interpret mode cannot see any of that. Nothing runs: a compile that
+   window store in place and slice none of it out. Interpret mode
+   cannot see what the chip's compiler refuses (a VMEM budget, a block
+   shape, a lane-splitting reshape). Nothing runs: a compile that
    passes is not a chip run.
 2. ``core/runtime.enable_compilation_cache`` is placed from outside.
 3. ``chip_smoke.py`` refuses to print its success line off-TPU, and
@@ -251,45 +249,40 @@ def test_train_cells_plan_no_score_tensor_on_v5e(topo, workload, calls):
 
 
 # ---------------------------------------------------------------------
-# 3-8: the paged kernel at GPT-2 124M widths, 1024 positions
+# the gathered view at GPT-2 124M widths, 1024 positions
 # ---------------------------------------------------------------------
-@pytest.mark.parametrize("scaled", [False, True],
-                         ids=["bf16-passthrough", "int8-scaled"])
-@pytest.mark.parametrize("rows,queries", [(ROWS, 1), (ROWS, 5), (1, 128)],
-                         ids=["decode", "verify", "prefill"])
-def test_paged_attention_compiles_for_v5e(chip, rows, queries, scaled):
-    pa = importlib.import_module("quintnet_tpu.ops.paged_attention")
+@pytest.mark.parametrize("kv,rows,queries", [
+    ("int8", ROWS, 1), ("int8", ROWS, 5), ("int8", 1, 128),
+    ("bf16", 1, 128)], ids=["int8-decode", "int8-verify", "int8-prefill",
+                            "bf16-prefill"])
+def test_paged_attend_compiles_for_v5e(chip, kv, rows, queries):
+    """``paged_attend`` where it reads the gathered view on the chip —
+    an int8-scaled pool, dequantized with its block scales and
+    requantized on write, at decode, verify and prefill widths; a bf16
+    pool's prefill bucket, too many query rows to walk — over the whole
+    pool ``[L, slots, F]`` it carries, compiled for the described
+    chip."""
+    from quintnet_tpu.nn.attention import paged_attend
+    from quintnet_tpu.serve.kv_quant import make_policy
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
 
-    q = sds((rows, H, queries, D), jnp.float32)
-    tables, starts = sds((rows, M), jnp.int32), sds((rows,), jnp.int32)
-    if scaled:
-        pool = sds((NB * BS, H, D), jnp.int8)
-        scale = sds((NB, H), jnp.float32)
+    policy = make_policy(kv)
+    pool = sds((2, NB * BS, H * D), policy.store_dtype)
+    scales = (sds((2, NB, H), jnp.float32),) * 2 if policy.scaled else ()
+    fresh = sds((rows, H, queries, D), jnp.float32)
 
-        def fn(q, k, v, t, s, ks, vs, fk, fv):
-            return pa.paged_attention(
-                q, k, v, t, s, block_size=BS, kv_scales=(ks, vs),
-                fresh_kv=(fk, fv), interpret=False)
+    def fn(q, k, v, positions, lens, tables, *pools):
+        return paged_attend(q, k, v, pools, jnp.int32(1), positions, lens,
+                            tables, block_size=BS,
+                            policy=policy if policy.scaled else None)
 
-        _compile(fn, q, pool, pool, tables, starts, scale, scale, q, q)
-    else:
-        pool = sds((NB * BS, H, D), jnp.bfloat16)
-
-        def fn(q, k, v, t, s):
-            return pa.paged_attention(q, k, v, t, s, block_size=BS,
-                                      interpret=False)
-
-        _compile(fn, q, pool, pool, tables, starts)
-    # the limit handed to the compiler is the kernel's own estimate,
-    # inside the cap
-    need = pa.paged_attention_vmem_bytes(
-        n_q_heads=H, n_kv_heads=H, n_queries=queries, head_dim=D,
-        block_size=BS, table_width=M, pool_dtype=pool.dtype,
-        scaled=scaled)
-    assert 16 * 2 ** 20 < need <= pa.VMEM_CAP_BYTES
+    text = jax.jit(fn).lower(
+        fresh, fresh, fresh, sds((rows, queries), jnp.int32),
+        sds((rows,), jnp.int32), sds((rows, M), jnp.int32), pool, pool,
+        *scales).compile().as_text()
+    assert "tpu_custom_call" not in text      # the view, not the walk
 
 
 # ---------------------------------------------------------------------
@@ -1018,11 +1011,11 @@ def test_chip_smoke_refuses_a_cpu():
     assert "needs a TPU" in out.stderr
 
 
-def test_chip_smoke_cpu_rehearsal(tmp_path, capsys, monkeypatch):
-    """The first rehearsal: the train and serve phases (and the Pallas
-    engine's half of the kernel phase, in the interpreter conftest
-    turned on) run to their end at a tiny size on the CPU. ``main`` is
-    never called, so the success line cannot appear."""
+def test_chip_smoke_cpu_rehearsal(tmp_path, capsys):
+    """The first rehearsal: the train and serve phases (and the paged
+    walk's half of the kernel phase, in the interpreter conftest turned
+    on) run to their end at a tiny size on the CPU. ``main`` is never
+    called, so the success line cannot appear."""
     import chip_smoke as cs
     from quintnet_tpu.models.gpt2 import GPT2Config
 
@@ -1030,21 +1023,7 @@ def test_chip_smoke_cpu_rehearsal(tmp_path, capsys, monkeypatch):
         cfg=GPT2Config.tiny(n_positions=128, n_layer=2), batch=4, seq=32,
         train_steps=5, slots=4, block_size=8, num_blocks=64,
         max_seq_len=96, prompt_lens=(5, 9, 14, 20, 27, 35, 44, 60),
-        max_new=8, dense_check=(1, 6), kernel_check=(0, 2),
-        pallas_prefill_len=16)
-    # the whole-row kernel is pinned against the GATHERED view: the
-    # xla engine's side of the comparison is traced with the kernels'
-    # interpreter off, where its verify program (few rows a slot on a
-    # bf16 pool) keeps ``_lane_diag_sdpa`` on the gathered view; with
-    # it on, it would walk each row's live blocks (PR 34), another
-    # rounding of the probabilities, bounded in test_paged_attention's
-    # TestRowWalk
-    pa = importlib.import_module("quintnet_tpu.ops.paged_attention")
-    paged_logits = cs.paged_logits
-
-    def logits_of(engine, prompts, width):
-        monkeypatch.setattr(pa, "INTERPRET", engine.attn_kernel != "xla")
-        return paged_logits(engine, prompts, width)
+        max_new=8, dense_check=(1, 6), kernel_check=(0, 2))
 
     with open(tmp_path / "phases.jsonl", "a") as sink:
         train = cs.run_phase(
@@ -1057,9 +1036,8 @@ def test_chip_smoke_cpu_rehearsal(tmp_path, capsys, monkeypatch):
             return rec
 
         serve_rec = cs.run_phase("serve", serve, sink)
-        monkeypatch.setattr(cs, "paged_logits", logits_of)
-        paged = cs.check_pallas_engine(tiny, served["engine"],
-                                       served["prompts"], 0)
+        paged = cs.check_paged_walk(tiny, served["engine"],
+                                    served["prompts"])
 
     assert len(train["losses"]) == 5
     assert train["losses"][-1] < train["losses"][0]
@@ -1067,9 +1045,9 @@ def test_chip_smoke_cpu_rehearsal(tmp_path, capsys, monkeypatch):
     assert not os.path.exists(tmp_path / "ckpt")   # scratch, removed
     assert serve_rec["requests"] == 8 and serve_rec["new_tokens"] == 64
     assert serve_rec["paged_vs_dense_logits"]["max_abs_diff"] < 0.01
-    # interpret mode: bit-parity with the gathered view, and (the
-    # reason the kernel phase is chip-only) no custom call to find
-    assert paged["pallas_vs_xla_logits"]["max_abs_diff"] == 0.0
+    # the walk's logits against the dense forward, and (the reason the
+    # kernel phase is chip-only) no custom call to find in interpret mode
+    assert paged["paged_vs_dense_logits"]["max_abs_diff"] < 0.01
     assert paged["tpu_custom_call"] is False
 
     lines = capsys.readouterr().out.strip().splitlines()

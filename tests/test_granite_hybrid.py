@@ -353,7 +353,7 @@ def test_a_reused_slot_starts_from_a_zeroed_state(params):
     (dict(adapters=True), "LoRA"),
     (dict(mesh="tp"), "head-sharded"),
     (dict(mesh="sp"), "ring form"),
-    (dict(attn_kernel="pallas"), "score scale"),
+    (dict(attn_kernel="pallas"), "attn_kernel='pallas'"),
     (dict(kv_dtype="int8"), "conv tail"),
     (dict(kv_dtype="fp8"), "conv tail"),
 ])
@@ -365,7 +365,10 @@ def test_what_assumes_kv_only_sequences_is_refused_at_construction(
         axis = kwargs.pop("mesh")
         kwargs["mesh"] = Mesh(np.array(jax.devices()[:2]), (axis,))
         kwargs["sp_axis" if axis == "sp" else "tp_axis"] = axis
-    with pytest.raises(NotImplementedError, match=match):
+    # the engine refuses every attn_kernel but its own, by value
+    refused = (ValueError if "attn_kernel" in kwargs
+               else NotImplementedError)
+    with pytest.raises(refused, match=match):
         _engine(params, **kwargs)
 
 

@@ -22,9 +22,7 @@ The contract ladder:
 4. **fp8 passthrough** — the ``fp8`` rung stores blocks as UNSCALED
    ``float8_e4m3fn`` (narrow on scatter, upcast on gather — no scale
    arrays at all), buying int8's exact 4x byte ratio WITHOUT the
-   per-block scale overhead; gated by the same paged-ppl delta, and
-   explicitly rejected by the pallas kernel path until a float8 tile
-   lands.
+   per-block scale overhead; gated by the same paged-ppl delta.
 """
 
 import jax
@@ -417,13 +415,6 @@ class TestInt8Quality:
                    for o, p in zip(outs, prompts))
         assert eng.compile_stats() == {"prefill": 1, "decode": 1}
         eng.assert_compile_count()
-
-    @needs_fp8
-    def test_fp8_pallas_rejected(self, params):
-        """The fused pallas kernels have no float8 tile yet — the
-        combination must fail loudly at build, not mis-serve."""
-        with pytest.raises(NotImplementedError, match="fp8"):
-            _engine(params, "fp8", attn_kernel="pallas")
 
 
 # ---------------------------------------------------------------------

@@ -496,7 +496,10 @@ def test_the_programs_are_named_and_their_census_is_pinned(params):
 ])
 def test_what_a_latent_and_recurrent_family_cannot_serve_is_refused(
         params, kw, match):
-    with pytest.raises(NotImplementedError, match=match):
+    # the engine refuses every attn_kernel but its own, by value
+    refused = (ValueError if "attn_kernel" in kw
+               else NotImplementedError)
+    with pytest.raises(refused, match=match):
         _engine(params, **kw)
 
 

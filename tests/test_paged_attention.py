@@ -1,29 +1,28 @@
-"""Fused paged-attention Pallas kernels (ops/paged_attention.py) vs
-the XLA gathered-view oracle.
+"""The one paged attention (nn/attention.paged_attend) of every serving
+program, against plain attention written here, and the per-row walk
+(ops/paged_attention.py) against the gathered view.
 
 The contract ladder:
 
-1. **Kernel parity matrix** — the real nn/attention entry point
-   (``mha_verify_paged`` at decode, verify and prefill widths) runs
-   once per backend from identical pool state, across every
-   ``kv_layout_policies`` entry x verify bucket widths x chunked
-   prefill offsets, in CPU interpret mode: outputs within four f32
-   ulps for f32/fake_quant (``F32_ATOL``: one op sequence compiled
-   twice may sum in two orders), within the pinned tolerance for
-   bf16/int8/fp8 (the kernel mirrors the oracle's op sequence and the
-   observed diff is at most 3e-08, but nothing guarantees it, so the
-   quantized dtypes pin a bound instead of a bit pattern), and POOL BYTES +
-   SCALES exactly equal everywhere (the write paths are one math).
-2. **GQA** — the same matrix through the llama blocks (4 query heads
-   on 2 kv heads): the kernel resolves the repeat in its index maps.
-3. **Engine goldens** — ``ServeEngine(attn_kernel="pallas")`` serves
-   prefix-cache, speculative-decode, chunked-prefill, preemption and
-   tp=2 traffic TOKEN-IDENTICAL to ``attn_kernel="xla"``, greedy and
-   sampled, f32 and int8, gpt2 and llama.
-4. **Structural win** — the jaxpr auditor
-   (analysis.gathered_view_gathers) proves the pallas programs issue
-   ZERO full-row block-table gathers where the xla ones issue 2-4 per
-   layer; compile counts and sentinels are unchanged per backend.
+1. **Reference matrix** — the real nn/attention entry point
+   (``mha_verify_paged`` at decode, verify and prefill widths), across
+   every ``kv_layout_policies`` entry x verify bucket widths x chunked
+   prefill offsets, twice back to back (requant on top of requant),
+   against softmax attention over the pool's dequantized history plus
+   the fresh run, written in the test: outputs within four f32 ulps for
+   f32/fake_quant (``F32_ATOL``), within ``QUANT_ATOL`` for
+   bf16/int8/fp8, and the written POOL BYTES equal to the policy's own
+   ``quant`` of the fresh keys and values.
+2. **GQA** — the same for the llama block's attention call on its own
+   projections and rotary (4 query heads on 2 kv heads).
+3. **Engine goldens** — the engine under each mechanism (prefix cache,
+   speculative decode, chunked prefill, preemption, GQA, tp=2) serves
+   TOKEN-IDENTICAL to the same engine without it, same policy and
+   keys, greedy and sampled, f32 and int8, gpt2 and llama.
+4. **Structure** — the jaxpr auditor (analysis.gathered_view_gathers)
+   counts 2-4 full-row block-table gathers a layer in every gathered
+   program; compile counts and sentinels are pinned; ``attn_kernel``
+   takes one value.
 5. **Arithmetic contract** — every gathered-view program contracts the
    view in the dtype it is STORED in (analysis.widened_view_dots reads
    zero for f32 and bf16 pools, gpt2 and llama), a bf16 pool's decode
@@ -31,7 +30,6 @@ The contract ladder:
    earn, and an f32 pool's program rounds and pads nothing.
 """
 
-import contextlib
 import math
 
 import jax
@@ -41,34 +39,24 @@ import pytest
 
 from quintnet_tpu.analysis import (gathered_view_gathers, row_walk_calls,
                                    view_head_splits, widened_view_dots)
-from quintnet_tpu.analysis.specs import attn_kernels, kv_layout_policies
+from quintnet_tpu.analysis.specs import kv_layout_policies
 from quintnet_tpu.models.gpt2 import GPT2Config, gpt2_init
 from quintnet_tpu.serve import ServeEngine, SpecConfig, gpt2_family
 from quintnet_tpu.serve.kv_quant import make_policy
 
 CFG = GPT2Config.tiny(n_layer=2)
 
-# quantized-dtype tolerance: the kernel mirrors the oracle op for op
-# (the OBSERVED diff is 0.0 to 3e-08 by case); the pin leaves headroom
-# only for platform-lowering drift in ops that are not exact by
-# construction
+# bf16 / int8 / fp8 outputs: the reference rounds where the path states
+# it rounds (a bf16 pool's q and probabilities, before f32-accumulating
+# products; a scaled pool's history dequantized, the fresh run exact),
+# so what is left is the order of f32 sums; the pin leaves headroom for
+# a platform's lowering of ops that are not exact by construction
 QUANT_ATOL = 1e-6
 
-# f32 / fake_quant outputs: the two backends run the SAME op sequence
-# on the same f32 values (ops/paged_attention._kernel mirrors
-# _masked_sdpa op for op, head-batched dots included), but XLA:CPU
-# compiles it twice in different surroundings — once fused into the
-# whole attention program, once as the interpreter's grid-step body —
-# and is free to vectorise a reduction (a contraction, the softmax's
+# f32 / fake_quant outputs: the same f32 math, compiled in two programs
+# that are free to vectorise a reduction (a contraction, the softmax's
 # sum) in another order each time; float addition does not associate.
-# One case shows it, on every tree since the seed: the SECOND chunk of
-# the chunked prefill (one row of 8 at offset 8, 5 real tokens over 8
-# cached) differs by 5.96e-08 in a quarter of its outputs, an ulp or
-# two at their magnitude (up to 0.34 after the output projection);
-# every other f32 case of the matrix reads 0.0 on this machine. Bit
-# equality is therefore a property of the shapes, not of the kernel:
-# hold the outputs to four ulps of [0.5, 1) and the POOL, which both
-# backends write with one scatter, to bits (_assert_pools_match).
+# Hold the outputs to four ulps of [0.5, 1) and the POOL to bits.
 F32_ATOL = 4 * 2.0 ** -24
 
 
@@ -78,7 +66,7 @@ def params():
 
 
 # ---------------------------------------------------------------------
-# 1. kernel parity matrix through the real mha entry points
+# 1. the reference matrix through the real mha entry point
 # ---------------------------------------------------------------------
 
 H, D, BS, M, NB = 2, 8, 4, 6, 20        # geometry: M collides with no
@@ -124,133 +112,164 @@ def _tables():
                         for s in range(S)], jnp.int32)
 
 
-def _assert_pools_match(pa, pb, policy, tables):
-    """Pool bytes + scales bit-equal on every REAL block (the null
-    block legitimately collects both backends' masked-pad scatters)."""
-    real = np.asarray(tables).reshape(-1)
-    for a, b in zip(pa[:2], pb[:2]):
-        np.testing.assert_array_equal(_blocks(a)[real], _blocks(b)[real])
-    if policy.scaled:
-        for a, b in zip(pa[2:], pb[2:]):
-            assert np.all(np.delete(np.asarray(a), LAYER, axis=0) == 1.0)
-            np.testing.assert_array_equal(np.asarray(a)[LAYER, real],
-                                          np.asarray(b)[LAYER, real])
-
-
 def _assert_out(ya, yb, policy):
     ya, yb = np.asarray(ya), np.asarray(yb)
     atol = F32_ATOL if policy.name in ("f32", "fake_quant") else QUANT_ATOL
     np.testing.assert_allclose(ya, yb, atol=atol, rtol=0)
 
 
-@contextlib.contextmanager
-def _oracle(kernel):
-    """The "xla" side of the parity matrix is the GATHERED view, for
-    every pool: the whole-row kernel is pinned against
-    ``_lane_diag_sdpa`` / ``_masked_sdpa`` on it, to ``QUANT_ATOL``. A
-    bf16 pool under few query rows would walk each row's live blocks
-    where the kernels' interpreter is on (conftest.py) — another
-    rounding of the probabilities, bounded in TestRowWalk, not here —
-    so it is off while the xla side is traced: with no interpreter and
-    off the TPU, ``paged_attend`` keeps the gathered form."""
-    pa = _kernels()
-    was = pa.INTERPRET
-    pa.INTERPRET = was and kernel != "xla"
-    try:
-        yield
-    finally:
-        pa.INTERPRET = was
+def _history(pool, policy, tables, h, d):
+    """Each row's cached history as ``pool`` holds it, dequantized:
+    (k, v) [S, T, h, d] f32, position-ordered through ``tables``."""
+    out = []
+    for c in range(2):
+        rows = pool[c][LAYER, :, :h * d].reshape(NB, BS, h, d)[tables]
+        scale = (pool[2 + c][LAYER][tables][:, :, None, :, None]
+                 if policy.scaled else None)
+        rows = policy.dequant(rows, scale)               # [S, M, BS, h, d]
+        out.append(rows.reshape(rows.shape[0], -1, h, d))
+    return out
+
+
+def _dense_attention(q, k, v, pool, policy, positions, lens, tables):
+    """Plain softmax attention of ``q`` [S, Hq, P, D] over each row's
+    history in ``pool`` BEFORE the call, dequantized, with the fresh
+    run ``k``/``v`` [S, Hkv, P, D] laid over it at its real positions —
+    as the pool stores it under a passthrough policy, exact under a
+    scaled one (whose scores read the f32 run). Column t is seen by the
+    query at ``positions[s, i]`` iff ``t <= positions[s, i]``; kv head j
+    serves query heads ``j * rep .. (j + 1) * rep - 1``. A bf16 pool's
+    q and probabilities are rounded to bf16 before f32-accumulated
+    products, where the path states it rounds them."""
+    hkv, d = k.shape[1], k.shape[3]
+    k_all, v_all = _history(pool, policy, tables, hkv, d)
+    P = positions.shape[1]
+    at = jnp.where(jnp.arange(P)[None, :] < lens[:, None], positions,
+                   k_all.shape[1])                       # pad: dropped
+    rows = jnp.arange(q.shape[0])[:, None]
+    for name, fresh in (("k", k), ("v", v)):
+        fresh = fresh.transpose(0, 2, 1, 3)              # [S, P, h, d]
+        if not policy.scaled:
+            fresh = policy.dequant(policy.quant(fresh))
+        if name == "k":
+            k_all = k_all.at[rows, at].set(fresh, mode="drop")
+        else:
+            v_all = v_all.at[rows, at].set(fresh, mode="drop")
+    rep = q.shape[1] // hkv
+    k_all, v_all = (jnp.repeat(a.transpose(0, 2, 1, 3), rep, axis=1)
+                    for a in (k_all, v_all))             # [S, Hq, T, d]
+    narrow = jnp.bfloat16 if policy.name == "bf16" else jnp.float32
+    scores = jnp.einsum("shpd,shtd->shpt", q.astype(narrow),
+                        k_all.astype(narrow),
+                        preferred_element_type=jnp.float32) / math.sqrt(d)
+    seen = (jnp.arange(k_all.shape[2])[None, None, None, :]
+            <= positions[:, None, :, None])
+    probs = jax.nn.softmax(jnp.where(seen, scores, -jnp.inf), axis=-1)
+    return jnp.einsum("shpt,shtd->shpd", probs.astype(narrow),
+                      v_all.astype(narrow),
+                      preferred_element_type=jnp.float32)
+
+
+def _assert_run_written(pool, policy, k, v, positions, lens, tables):
+    """The pool bytes at every real position of the run are the
+    policy's own ``quant`` of the fresh keys and values — under a scaled
+    policy at the scale the write left on their block and head."""
+    k, v, positions, lens, tables = (np.asarray(a) for a in
+                                     (k, v, positions, lens, tables))
+    hkv, d = k.shape[1], k.shape[3]
+    for c, (arr, fresh) in enumerate(zip(pool[:2], (k, v))):
+        stored = _blocks(arr, hkv, d)                    # [NB, BS, h, d]
+        for s in range(positions.shape[0]):
+            for i in range(int(lens[s])):
+                t = int(positions[s, i])
+                blk = int(tables[s, t // BS])
+                scale = (np.asarray(pool[2 + c])[LAYER, blk][:, None]
+                         if policy.scaled else None)
+                want = policy.quant(jnp.asarray(fresh[s, :, i]), scale)
+                np.testing.assert_array_equal(
+                    np.asarray(stored[blk, t % BS], np.float32),
+                    np.asarray(want, np.float32))
 
 
 class TestMhaParityMatrix:
-    """Each scenario runs the SAME op sequence per backend from the
-    same initial pool, twice back to back (history accumulates across
-    the calls, covering requant-on-top-of-requant)."""
+    """Each scenario runs the path from an empty pool twice back to back
+    (history accumulates across the calls, covering requant on top of
+    requant), each call against the reference from the pool it read."""
+
+    @pytest.fixture(autouse=True)
+    def _gathered(self, monkeypatch):
+        """The gathered view, the form every platform but the TPU runs:
+        a bf16 pool under few query rows would walk each row's live
+        blocks under the kernels' interpreter (conftest.py) — another
+        rounding of the probabilities, bounded in TestRowWalk, not
+        here."""
+        monkeypatch.setattr(_kernels(), "INTERPRET", False)
 
     @pytest.fixture(scope="class")
     def attn(self):
         return _mha_params(jax.random.key(1))
 
-    def _run_verify(self, attn, policy, kernel, P, steps=2):
+    @staticmethod
+    def _step(attn, policy, pool, x, positions, lens, tables):
+        """One call of the path and the reference from the same pool:
+        (output, reference output, the pool after)."""
+        from quintnet_tpu.nn.attention import _proj_out, _qkv_heads
         from quintnet_tpu.nn.attention import mha_verify_paged
 
-        rng = np.random.default_rng(7)
+        scaled = pool[2] is not None
+
+        def path(x, kp, vp, ks, vs):
+            return mha_verify_paged(
+                attn, x, kp, vp, positions, lens, num_heads=H,
+                layer=jnp.int32(LAYER), block_tables=tables, block_size=BS,
+                kv_scales=(ks, vs) if scaled else None,
+                policy=policy if scaled else None)
+
+        def reference(x, kp, vp, ks, vs):
+            q, k, v = _qkv_heads(attn, x, H)
+            o = _dense_attention(q, k, v, [kp, vp, ks, vs], policy,
+                                 positions, lens, tables)
+            return _proj_out(attn, o, None), k, v
+
+        out = jax.jit(path)(x, *pool)
+        want, k, v = jax.jit(reference)(x, *pool)
+        after = list(out[1:]) + ([] if scaled else [None, None])
+        _assert_run_written(after, policy, k, v, positions, lens, tables)
+        return out[0], want, after
+
+    def _run(self, attn, policy, runs, tables, seed):
+        """The path over ``runs`` [(starts [S], width P, lens [S])],
+        each output's real columns against the reference."""
+        rng = np.random.default_rng(seed)
         pool = _pool(policy)
-        tables = _tables()
-        starts = np.asarray([5, 0, 11], np.int32)
-        outs = []
-        for it in range(steps):
-            x = jnp.asarray(rng.standard_normal((S, P, H * D)),
+        for starts, P, lens in runs:
+            x = jnp.asarray(rng.standard_normal((len(starts), P, H * D)),
                             jnp.float32)
-            positions = jnp.asarray(starts)[:, None] + jnp.arange(
-                P, dtype=jnp.int32)[None, :]
-            tail_lens = jnp.asarray([P, max(P - 1, 1), P], jnp.int32)
-            kv = _scales(pool)
-            out = jax.jit(
-                lambda x, kp, vp, ks, vs: mha_verify_paged(
-                    attn, x, kp, vp, positions, tail_lens,
-                    num_heads=H, layer=jnp.int32(LAYER),
-                    block_tables=tables, block_size=BS,
-                    kv_scales=(ks, vs) if ks is not None else None,
-                    policy=policy if kv is not None else None,
-                    attn_kernel=kernel)
-            )(x, pool[0], pool[1], pool[2], pool[3])
-            outs.append(out[0])
-            pool = list(out[1:]) + ([None, None] if kv is None else [])
-            starts = starts + np.asarray(tail_lens)
-        return outs, pool
+            positions = (jnp.asarray(starts, jnp.int32)[:, None]
+                         + jnp.arange(P, dtype=jnp.int32)[None, :])
+            lens = jnp.asarray(lens, jnp.int32)
+            y, want, pool = self._step(attn, policy, pool, x, positions,
+                                       lens, tables)
+            for s, n in enumerate(np.asarray(lens)):
+                _assert_out(y[s, :n], want[s, :n], policy)
 
     @pytest.mark.parametrize("policy_name", kv_layout_policies())
     @pytest.mark.parametrize("P", (1, 3, 5))
     def test_verify_and_decode_widths(self, attn, policy_name, P):
         """P=1 IS the decode shape; 3/5 are the verify buckets + 1."""
-        policy = make_policy(policy_name)
-        with _oracle("xla"):
-            ya, pa = self._run_verify(attn, policy, "xla", P)
-        yb, pb = self._run_verify(attn, policy, "pallas", P)
-        for a, b in zip(ya, yb):
-            _assert_out(a, b, policy)
-        _assert_pools_match(pa, pb, policy, _tables())
-
-    def _run_prefill(self, attn, policy, kernel):
-        """Chunked prefill: one row, two chunks at dynamic offsets
-        (start 0 then 8) through the SAME bucket width — the
-        prefix-cache tail shape."""
-        from quintnet_tpu.nn.attention import mha_verify_paged
-
-        rng = np.random.default_rng(9)
-        pool = _pool(policy)
-        tables = _tables()[0]
-        P = 8
-        outs = []
-        for start, tail in ((0, 8), (8, 5)):
-            x = jnp.asarray(rng.standard_normal((1, P, H * D)),
-                            jnp.float32)
-            positions = start + jnp.arange(P, dtype=jnp.int32)
-            kv = _scales(pool)
-            out = jax.jit(
-                lambda x, kp, vp, ks, vs: mha_verify_paged(
-                    attn, x, kp, vp, positions[None],
-                    jnp.full((1,), tail, jnp.int32),
-                    num_heads=H, layer=jnp.int32(LAYER),
-                    block_tables=tables[None], block_size=BS,
-                    kv_scales=(ks, vs) if ks is not None else None,
-                    policy=policy if kv is not None else None,
-                    attn_kernel=kernel)
-            )(x, pool[0], pool[1], pool[2], pool[3])
-            outs.append(out[0])
-            pool = list(out[1:]) + ([None, None] if kv is None else [])
-        return outs, pool
+        starts = np.asarray([5, 0, 11])
+        lens = [P, max(P - 1, 1), P]
+        self._run(attn, make_policy(policy_name),
+                  [(starts, P, lens), (starts + lens, P, lens)],
+                  _tables(), seed=7)
 
     @pytest.mark.parametrize("policy_name", kv_layout_policies())
     def test_chunked_prefill_offsets(self, attn, policy_name):
-        policy = make_policy(policy_name)
-        with _oracle("xla"):
-            ya, pa = self._run_prefill(attn, policy, "xla")
-        yb, pb = self._run_prefill(attn, policy, "pallas")
-        for a, b in zip(ya, yb):
-            _assert_out(a, b, policy)
-        _assert_pools_match(pa, pb, policy, _tables())
+        """Chunked prefill: one row, two chunks at dynamic offsets
+        (start 0 then 8) through the SAME bucket width — the
+        prefix-cache tail shape."""
+        self._run(attn, make_policy(policy_name),
+                  [([0], 8, [8]), ([8], 8, [5])], _tables()[:1], seed=9)
 
 
 # ---------------------------------------------------------------------
@@ -261,69 +280,67 @@ class TestGQAParity:
     @pytest.mark.parametrize("policy_name", ("f32", "int8"))
     @pytest.mark.parametrize("P", (1, 3))
     def test_llama_verify_gqa(self, policy_name, P):
+        """The llama block's attention call on its own projections and
+        rotary: 4 query heads reading 2 kv heads, each kv head serving
+        its contiguous group of query heads."""
         from quintnet_tpu.models.llama import (LlamaConfig, llama_init,
-                                               llama_block_verify_paged,
-                                               llama_rope_tables)
+                                               llama_qkv, llama_rope_tables)
+        from quintnet_tpu.nn.attention import _pool_tuple, paged_attend
+        from quintnet_tpu.nn.layers import rms_norm_apply
 
         cfg = LlamaConfig.tiny()
         assert cfg.n_heads != cfg.n_kv_heads  # the point of this test
         policy = make_policy(policy_name)
         params = llama_init(jax.random.key(2), cfg)
         blk = jax.tree.map(lambda a: a[0], params["blocks"])
-        hkv, hd = cfg.n_kv_heads, cfg.head_dim
-        pool = _pool(policy, hkv, hd)
+        pool = _pool(policy, cfg.n_kv_heads, cfg.head_dim)
         tables = _tables()
         rng = np.random.default_rng(3)
         starts = np.asarray([5, 0, 11], np.int32)
-        results = {}
-        for kernel in attn_kernels():
-            p = [jnp.array(a) if a is not None else None for a in pool]
-            outs = []
-            st = starts.copy()
-            rng2 = np.random.default_rng(3)
-            for it in range(2):
-                x = jnp.asarray(rng2.standard_normal((S, P, cfg.dim)),
-                                jnp.float32)
-                positions = (jnp.asarray(st)[:, None]
-                             + jnp.arange(P, dtype=jnp.int32)[None, :])
-                tails = jnp.asarray([P, max(P - 1, 1), P], jnp.int32)
-                cos, sin = llama_rope_tables(positions, cfg)
-                cos, sin = cos[:, None], sin[:, None]
-                kv = (p[2], p[3]) if p[2] is not None else None
-                out = jax.jit(
-                    lambda x, kp, vp, ks, vs: llama_block_verify_paged(
-                        blk, x, kp, vp, positions, tails, cfg, cos,
-                        sin, layer=jnp.int32(LAYER),
-                        block_tables=tables, block_size=BS,
-                        kv_scales=(ks, vs) if ks is not None else None,
-                        policy=policy if kv is not None else None,
-                        attn_kernel=kernel)
-                )(x, p[0], p[1], p[2], p[3])
-                outs.append(out[0])
-                p = list(out[1]) + ([None, None] if kv is None else [])
-                st = st + np.asarray(tails)
-            results[kernel] = (outs, p)
-        (ya, pa), (yb, pb) = results["xla"], results["pallas"]
-        for a, b in zip(ya, yb):
-            _assert_out(a, b, policy)
-        real = np.asarray(tables).reshape(-1)
-        for a, b in zip(pa[:2], pb[:2]):
-            np.testing.assert_array_equal(_blocks(a, hkv, hd)[real],
-                                          _blocks(b, hkv, hd)[real])
+        scaled = policy.scaled
+        for _ in range(2):
+            x = jnp.asarray(rng.standard_normal((S, P, cfg.dim)),
+                            jnp.float32)
+            positions = (jnp.asarray(starts)[:, None]
+                         + jnp.arange(P, dtype=jnp.int32)[None, :])
+            lens = jnp.asarray([P, max(P - 1, 1), P], jnp.int32)
+            cos, sin = llama_rope_tables(positions, cfg)
+            q, k, v = jax.jit(lambda x: llama_qkv(
+                blk["attn"], rms_norm_apply(blk["ln1"], x, eps=cfg.rms_eps),
+                cfg, cos[:, None], sin[:, None]))(x)
+
+            def path(q, k, v, kp, vp, ks, vs):
+                return paged_attend(
+                    q, k, v, _pool_tuple(kp, vp, (ks, vs) if scaled
+                                         else None),
+                    jnp.int32(LAYER), positions, lens, tables,
+                    block_size=BS, policy=policy if scaled else None)
+
+            o, after = jax.jit(path)(q, k, v, *pool)
+            want = jax.jit(lambda *a: _dense_attention(
+                *a[:3], a[3:], policy, positions, lens, tables))(
+                    q, k, v, *pool)
+            pool = list(after) + ([] if scaled else [None, None])
+            for s, n in enumerate(np.asarray(lens)):
+                _assert_out(o[s, :, :n], want[s, :, :n], policy)
+            _assert_run_written(pool, policy, k, v, positions, lens,
+                                tables)
+            starts = starts + np.asarray(lens)
 
 
 # ---------------------------------------------------------------------
-# 3. engine goldens: pallas serves token-identical to xla
+# 3. engine goldens: each mechanism serves what the engine without it
+#    serves
 # ---------------------------------------------------------------------
 
-def _engine(params, kernel, family=None, fam_params=None, **kw):
+def _engine(params, family=None, fam_params=None, **kw):
     kw.setdefault("max_slots", 3)
     kw.setdefault("block_size", 4)
     kw.setdefault("num_blocks", 48)
     kw.setdefault("max_seq_len", 32)
     return ServeEngine(family or gpt2_family(CFG),
                        fam_params if fam_params is not None else params,
-                       attn_kernel=kernel, **kw)
+                       **kw)
 
 
 def _serve(eng, prompts, max_new, *, arrivals=None):
@@ -342,14 +359,19 @@ def _serve(eng, prompts, max_new, *, arrivals=None):
     return [np.asarray(eng.result(rids[i])) for i in range(len(prompts))]
 
 
-def _ab(params, prompts, max_new, *, arrivals=None, **kw):
-    a = _serve(_engine(params, "xla", **kw), prompts, max_new,
-               arrivals=arrivals)
-    b = _serve(_engine(params, "pallas", **kw), prompts, max_new,
-               arrivals=arrivals)
-    for x, y in zip(a, b):
+def _ab(params, prompts, max_new, on, off, *, arrivals=None, **kw):
+    """Serve ``prompts`` on the engine under a mechanism (``on``'s
+    arguments) and on the same engine without it (``off``'s), same
+    policy, same keys: the tokens must be identical. Returns the
+    ``on`` engine's metrics summary, to show the mechanism ran."""
+    served, summary = [], None
+    for extra in (on, off):
+        eng = _engine(params, **{**kw, **extra})
+        served.append(_serve(eng, prompts, max_new, arrivals=arrivals))
+        summary = summary or eng.metrics.summary()
+    for x, y in zip(*served):
         np.testing.assert_array_equal(x, y)
-    return a
+    return summary
 
 
 class TestEngineGoldens:
@@ -365,12 +387,17 @@ class TestEngineGoldens:
         return mixed + shared_tails
 
     def test_greedy_prefix_cache_f32(self, params, prompts):
-        _ab(params, prompts, 8, arrivals=[0, 1, 2, 4, 6])
+        # the second shared prompt arrives once the first has retired
+        # and published its blocks
+        summary = _ab(params, prompts, 8, dict(prefix_cache=True),
+                      dict(prefix_cache=False), arrivals=[0, 1, 2, 4, 20])
+        assert summary["prefix_hit_tokens"] > 0
 
     def test_sampled_spec_int8(self, params, prompts):
-        _ab(params, prompts, 8, arrivals=[0, 0, 2, 3, 5],
-            kv_dtype="int8", temperature=0.8,
-            spec=SpecConfig(max_draft=4))
+        summary = _ab(params, prompts, 8, dict(spec=SpecConfig(max_draft=4)),
+                      {}, arrivals=[0, 0, 2, 3, 5], kv_dtype="int8",
+                      temperature=0.8)
+        assert summary["spec_steps"] > 0
 
     def test_chunked_prefill_fake_quant(self, params):
         rng = np.random.default_rng(13)
@@ -378,25 +405,44 @@ class TestEngineGoldens:
                           np.int32)
         short = np.asarray(rng.integers(0, CFG.vocab_size, (4,)),
                            np.int32)
-        _ab(params, [long, short], 6, kv_dtype="fake_quant",
-            prefill_len=8, chunked_prefill=True, prefill_chunk_budget=8,
-            max_seq_len=32)
+        summary = _ab(params, [long, short], 6,
+                      dict(prefill_len=8, chunked_prefill=True,
+                           prefill_chunk_budget=8), {},
+                      kv_dtype="fake_quant", max_seq_len=32)
+        assert summary["prefill_chunks"] >= 3
 
     def test_preemption_pressure_int8(self, params, prompts):
         # pool sized to force growth + preemption mid-trace
-        _ab(params, prompts, 8, arrivals=[0, 0, 0, 1, 1],
-            kv_dtype="int8", num_blocks=14, max_slots=3)
+        summary = _ab(params, prompts, 8, dict(num_blocks=11),
+                      dict(num_blocks=48), arrivals=[0, 0, 0, 1, 1],
+                      kv_dtype="int8", max_slots=3)
+        assert summary["preempted"] > 0
 
     def test_llama_gqa_engine_int8(self):
+        """4 query heads reading 2 kv heads against the same model with
+        each kv head's projections repeated for its 2 query heads: the
+        repeat the GQA path makes of the view, made of the weights."""
         from quintnet_tpu.models.llama import LlamaConfig, llama_init
         from quintnet_tpu.serve import llama_family
 
         cfg = LlamaConfig.tiny()
+        rep = cfg.n_heads // cfg.n_kv_heads
+        assert rep > 1
         lp = llama_init(jax.random.key(4), cfg)
+        mha = LlamaConfig.tiny(n_kv_heads=cfg.n_heads)
+        attn = dict(lp["blocks"]["attn"])
+        for name in ("k", "v"):
+            w = attn[name]["w"]                          # [L, dim, kv*hd]
+            w = w.reshape(*w.shape[:2], cfg.n_kv_heads, cfg.head_dim)
+            attn[name] = {"w": jnp.repeat(w, rep, axis=2).reshape(
+                *w.shape[:2], -1)}
+        mp = {**lp, "blocks": {**lp["blocks"], "attn": attn}}
         rng = np.random.default_rng(17)
         prompts = [np.asarray(rng.integers(0, cfg.vocab_size, (n,)),
                               np.int32) for n in (5, 9)]
-        _ab(None, prompts, 6, family=llama_family(cfg), fam_params=lp,
+        _ab(None, prompts, 6,
+            dict(family=llama_family(cfg), fam_params=lp),
+            dict(family=llama_family(mha), fam_params=mp),
             kv_dtype="int8", max_slots=2)
 
     @staticmethod
@@ -453,7 +499,7 @@ class TestEngineGoldens:
         for form, most_rows in (("walk", attention._MAX_DIAG_ROWS),
                                 ("split", 0)):
             monkeypatch.setattr(attention, "_MAX_DIAG_ROWS", most_rows)
-            eng = _engine(params, "xla", **kw)
+            eng = _engine(params, **kw)
             served[form] = _serve(eng, prompts, 8)
             args = next(a for s, a in eng._warmup_calls()
                         if s.fn is eng._decode.fn)
@@ -500,7 +546,7 @@ class TestEngineGoldens:
         served = {}
         for form, interpret in (("walk", True), ("gathered", False)):
             monkeypatch.setattr(_kernels(), "INTERPRET", interpret)
-            eng = _engine(params, "xla", **kw)
+            eng = _engine(params, **kw)
             served[form] = _serve(eng, prompts, 8, arrivals=arrivals)
             args = next(a for s, a in eng._warmup_calls()
                         if s.fn is eng._decode.fn)
@@ -518,9 +564,12 @@ class TestEngineGoldens:
     def test_tp2_fake_quant(self, params, prompts):
         from jax.sharding import Mesh
 
+        from quintnet_tpu.models.gpt2 import gpt2_to_tp_layout
+
         mesh = Mesh(np.array(jax.devices()[:2]), ("tp",))
-        _ab(params, prompts[:3], 6, kv_dtype="fake_quant", mesh=mesh,
-            max_slots=2)
+        _ab(params, prompts[:3], 6,
+            dict(mesh=mesh, fam_params=gpt2_to_tp_layout(params, CFG, 2)),
+            {}, kv_dtype="fake_quant", max_slots=2)
 
 
 # ---------------------------------------------------------------------
@@ -555,108 +604,45 @@ class TestStructure:
     @pytest.mark.parametrize("kv_dtype", ("f32", "int8"))
     def test_pallas_issues_zero_gathered_view_gathers(self, params,
                                                       kv_dtype):
-        """THE structural gate: every xla serving program gathers the
-        full block-table row (2 pools, +2 scale arrays when scaled) per
-        layer; every pallas program gathers it ZERO times — the walk
-        happens inside the kernel. Asserted on decode, the smallest
-        prefill bucket (requant span < table width — the auditor's
-        caller contract), and a verify bucket."""
-        counts = {}
-        for kernel in attn_kernels():
-            eng = _engine(params, kernel, kv_dtype=kv_dtype,
-                          num_blocks=24, spec=SpecConfig(max_draft=4))
-            kw = dict(num_blocks=24, table_width=eng.table_width)
-            b0 = eng.prefill_buckets[0]
-            counts[kernel] = dict(
-                decode=gathered_view_gathers(
-                    eng._decode.fn, *self._args(eng, params, "decode"),
-                    **kw),
-                prefill=gathered_view_gathers(
-                    eng._prefills[b0].fn,
-                    *self._args(eng, params, "prefill", b0), **kw),
-                verify=gathered_view_gathers(
-                    eng._verifies[2].fn,
-                    *self._args(eng, params, "verify", 2), **kw),
-            )
+        """THE structural census of the gathered view: every serving
+        program of an f32 or scaled pool gathers the full block-table
+        row (2 pools, +2 scale arrays when scaled) per layer — decode,
+        the smallest prefill bucket (requant span < table width — the
+        auditor's caller contract), and a verify bucket."""
+        eng = _engine(params, kv_dtype=kv_dtype, num_blocks=24,
+                      spec=SpecConfig(max_draft=4))
+        kw = dict(num_blocks=24, table_width=eng.table_width)
+        b0 = eng.prefill_buckets[0]
+        counts = dict(
+            decode=gathered_view_gathers(
+                eng._decode.fn, *self._args(eng, params, "decode"), **kw),
+            prefill=gathered_view_gathers(
+                eng._prefills[b0].fn,
+                *self._args(eng, params, "prefill", b0), **kw),
+            verify=gathered_view_gathers(
+                eng._verifies[2].fn,
+                *self._args(eng, params, "verify", 2), **kw))
         per_layer = 4 if kv_dtype == "int8" else 2
-        for which in ("decode", "prefill", "verify"):
-            assert counts["xla"][which] == per_layer, counts
-            assert counts["pallas"][which] == 0, counts
+        assert counts == dict.fromkeys(counts, per_layer), counts
 
     def test_compile_counts_unchanged_per_backend(self, params):
-        """Same sentinel set, same bounds, either backend — the kernel
-        never adds a program."""
+        """One prefill and one decode program for this traffic, inside
+        the engine's compile bound."""
         rng = np.random.default_rng(5)
         prompts = [np.asarray(rng.integers(0, CFG.vocab_size, (n,)),
                               np.int32) for n in (3, 7)]
-        for kernel in attn_kernels():
-            eng = _engine(params, kernel)
-            _serve(eng, prompts, 5)
-            assert eng.compile_stats() == {"prefill": 1, "decode": 1}
-            eng.assert_compile_count()
+        eng = _engine(params)
+        _serve(eng, prompts, 5)
+        assert eng.compile_stats() == {"prefill": 1, "decode": 1}
+        eng.assert_compile_count()
 
     def test_unknown_kernel_rejected(self, params):
-        with pytest.raises(ValueError, match="attn_kernel"):
-            _engine(params, "triton")
-
-    def test_pallas_vmem_rejected_at_construction(self, params,
-                                                  monkeypatch):
-        """A program whose whole-row scratch cannot fit the chip's
-        VMEM must fail at ServeEngine construction, naming the program
-        and the computed number — not deep inside the first serving
-        step, and never by falling back to the gathered-view path."""
-        import importlib
-
-        # the ops package re-exports the paged_attention FUNCTION, so
-        # attribute-style module access resolves to it — go via
-        # importlib for the module object
-        pa = importlib.import_module(
-            "quintnet_tpu.ops.paged_attention")
-        monkeypatch.setattr(pa, "VMEM_CAP_BYTES", 64 * 1024)
-        with pytest.raises(ValueError,
-                           match=r"program decode .*MiB of VMEM"):
-            _engine(params, "pallas")
-        # the kernel itself refuses the same way when called directly
-        q = jnp.zeros((1, 2, 1, 8))
-        pool = jnp.zeros((4 * 8, 2, 8))
-        with pytest.raises(ValueError, match="MiB of VMEM"):
-            pa.paged_attention(q, pool, pool, jnp.zeros((1, 4), jnp.int32),
-                               jnp.zeros((1,), jnp.int32), block_size=8)
-
-    def test_pallas_sp_rejected(self, params):
-        from jax.sharding import Mesh
-
-        mesh = Mesh(np.array(jax.devices()[:2]), ("sp",))
-        with pytest.raises(NotImplementedError, match="pallas"):
-            _engine(params, "pallas", mesh=mesh, sp_axis="sp",
-                    prefill_bucket_sizes=(16, 32))
-
-    def test_pallas_refuses_a_stated_score_scale(self):
-        """The fused kernel scales its scores by 1/sqrt(head_dim) only:
-        a family that states its own scale (the hybrid's
-        ``attention_multiplier``) is refused by name, never served
-        with the wrong one."""
-        from quintnet_tpu.nn.attention import paged_attend
-
-        q = jnp.zeros((1, H, 1, D))
-        pool = jnp.zeros((1, NB * BS, H * D))
-        with pytest.raises(NotImplementedError, match="score scale"):
-            paged_attend(q, q, q, (pool, pool), 0,
-                         jnp.zeros((1, 1), jnp.int32),
-                         jnp.ones((1,), jnp.int32),
-                         jnp.zeros((1, M), jnp.int32), block_size=BS,
-                         attn_kernel="pallas", scale=0.25)
-
-    def test_scaled_kernel_requires_fresh_kv(self):
-        from quintnet_tpu.ops.paged_attention import paged_attention
-
-        q = jnp.zeros((1, H, 1, D))
-        pool = jnp.zeros((NB * BS, H, D), jnp.int8)
-        sc = jnp.ones((NB, H), jnp.float32)
-        with pytest.raises(ValueError, match="fresh_kv"):
-            paged_attention(q, pool, pool, _tables()[:1],
-                            jnp.zeros((1,), jnp.int32), block_size=BS,
-                            kv_scales=(sc, sc))
+        """One paged attention: any ``attn_kernel`` but ``"xla"`` is
+        refused, by name."""
+        for kernel in ("triton", "pallas"):
+            with pytest.raises(ValueError,
+                               match=f"attn_kernel='{kernel}'"):
+                _engine(params, attn_kernel=kernel)
 
 
 # ---------------------------------------------------------------------
@@ -664,8 +650,7 @@ class TestStructure:
 # ---------------------------------------------------------------------
 
 def _kernels():
-    """The kernels' module (the ops package re-exports the
-    paged_attention FUNCTION under the submodule's name)."""
+    """The walk's module, ops/paged_attention.py."""
     import importlib
 
     return importlib.import_module("quintnet_tpu.ops.paged_attention")
@@ -701,7 +686,7 @@ class TestStoredDtypeContract:
                     cfg = LlamaConfig.tiny()
                     fam, p = llama_family(cfg), llama_init(
                         jax.random.key(4), cfg)
-                eng = _engine(params, "xla", family=fam, fam_params=p,
+                eng = _engine(params, family=fam, fam_params=p,
                               kv_dtype=kv_dtype, max_seq_len=self.SEQ,
                               spec=SpecConfig(max_draft=4))
                 built[family, kv_dtype] = (eng, p)
@@ -934,7 +919,7 @@ class TestLaneDiagonal:
                     jax.random.key(6), cfg)
                 kw.update(prefix_cache=False, spec=None)
             built[family, kv_dtype] = (
-                _engine(None, "xla", family=fam, fam_params=p, **kw), p)
+                _engine(None, family=fam, fam_params=p, **kw), p)
             return built[family, kv_dtype]
 
         return get
@@ -1035,7 +1020,7 @@ class TestLaneDiagonal:
         them at ZERO: the allocation, the programs' scatter
         (``_pool_rows``: prefill, decode, the copy-on-write copy of
         whole rows) and the host's ``write_slots`` (import, the tier)."""
-        eng = _engine(params, "xla", kv_dtype="bf16")
+        eng = _engine(params, kv_dtype="bf16")
         pool = eng.pool
         real = pool.n_kv_heads * pool.head_dim
         assert pool.k.shape[-1] > real          # 32 features in 128 lanes
@@ -1456,13 +1441,8 @@ def test_ops_import_surface():
     import quintnet_tpu.ops as ops
 
     expected = {"resident_flash_attention", "blockwise_attention",
-                "pallas_flash_attention", "paged_attention",
-                "paged_quant_window_update", "ring_attention",
+                "pallas_flash_attention", "ring_attention",
                 "zigzag_ring_attention", "ulysses_attention"}
     assert expected == set(ops.__all__)
     for name in ops.__all__:
         assert callable(getattr(ops, name)), name
-
-
-def test_attn_kernel_ladder_pinned():
-    assert attn_kernels() == ("xla", "pallas")

@@ -292,36 +292,21 @@ class TestServe:
             census = collective_census(fn, *args)
             assert census.diff(spec) == [], census.as_dict()
 
-    def test_pallas_census_matches_xla_per_backend(self, gpt2):
-        """The attention-backend ladder (analysis/specs.attn_kernels)
-        must not move a single collective: under tp the pallas decode
-        program carries EXACTLY the xla decode census (2 row-parallel
-        psums per layer — the kernel sits strictly inside the per-layer
-        attention; a pallas_call has no collectives), for the
-        passthrough f32 pool AND the scaled int8 one. A kernel that
-        snuck a gather/psum into the wire would fail with a named
-        diff."""
-        from quintnet_tpu.analysis.specs import attn_kernels
-
+    def test_tp_decode_census_holds_on_a_scaled_pool(self, gpt2):
+        """The scaled int8 pool's decode program under tp carries the
+        census of the passthrough one: 2 row-parallel psums a layer,
+        the per-block scales read and requantized rank-locally, so
+        nothing dequantizes across the wire."""
         cfg, params = gpt2
         mesh = Mesh(np.array(jax.devices()[:2]), ("tp",))
+        eng = self._engine(cfg, params, mesh=mesh, kv_dtype="int8")
+        census = collective_census(
+            eng._decode.fn, params, *eng.pool.caches(),
+            jnp.asarray(eng._tok), jnp.asarray(eng._pos),
+            jnp.asarray(eng._tables), jnp.asarray(eng._key_data))
         spec = census_specs.expected_serve_decode(cfg.n_layer,
                                                   tp_axis="tp")
-        for kv_dtype in ("f32", "int8"):
-            per_backend = {}
-            for kernel in attn_kernels():
-                eng = self._engine(cfg, params, mesh=mesh,
-                                   kv_dtype=kv_dtype,
-                                   attn_kernel=kernel)
-                caches = eng.pool.caches()
-                args = (params, *caches, jnp.asarray(eng._tok),
-                        jnp.asarray(eng._pos), jnp.asarray(eng._tables),
-                        jnp.asarray(eng._key_data))
-                census = collective_census(eng._decode.fn, *args)
-                assert census.diff(spec) == [], (kernel, kv_dtype,
-                                                 census.as_dict())
-                per_backend[kernel] = census.as_dict()
-            assert per_backend["pallas"] == per_backend["xla"]
+        assert census.diff(spec) == [], census.as_dict()
 
     def test_one_prefill_one_decode_across_mixed_trace(self, gpt2):
         """The PR 1 serving promise as a sentinel-enforced invariant:

@@ -548,7 +548,10 @@ def test_a_sequence_preempted_and_prefilled_again(params):
     (dict(block_size=16), "block_size other than"),
 ])
 def test_what_the_window_store_cannot_do_is_refused(params, kw, match):
-    with pytest.raises(NotImplementedError, match=match):
+    # the engine refuses every attn_kernel but its own, by value
+    refused = (ValueError if "attn_kernel" in kw
+               else NotImplementedError)
+    with pytest.raises(refused, match=match):
         _engine(params, **kw)
 
 
